@@ -48,6 +48,9 @@ from ..models.generate import (
 from ..models.transformer import TransformerConfig
 
 
+PREFILL_BUCKETS = (64, 128, 256)  # padded prompt lengths: one prefill program each
+
+
 @dataclass
 class Request:
     request_id: int
@@ -183,7 +186,7 @@ class ContinuousBatcher:
         *,
         slots: int = 8,
         t_max: int = 512,
-        prefill_buckets: (tuple) = (64, 128, 256),
+        prefill_buckets: (tuple) = PREFILL_BUCKETS,
         top_k: int = 0,
         prefix_cache_entries: int = 0,
         prefix_block: int = 16,

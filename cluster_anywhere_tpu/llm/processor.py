@@ -36,7 +36,8 @@ class ByteTokenizer:
 class ModelSpec:
     """Which flagship-transformer weights to run. Presets init random weights
     deterministically (seed) — checkpoint loading goes through `params_path`
-    (an orbax/np.savez dir produced by train)."""
+    (an orbax/np.savez dir produced by train).  "custom" takes every width
+    from `config_overrides` (TransformerConfig's defaults for the rest)."""
 
     preset: str = "tiny"  # tiny | small | custom
     params_path: Optional[str] = None
@@ -49,8 +50,13 @@ class ModelSpec:
         presets = {
             "tiny": dict(d_model=64, n_layers=2, n_heads=4, n_kv_heads=4, d_head=16, d_ff=128),
             "small": dict(d_model=256, n_layers=4, n_heads=8, n_kv_heads=8, d_head=32, d_ff=512),
+            "custom": {},
         }
-        base = presets.get(self.preset, presets["tiny"])
+        if self.preset not in presets:
+            raise ValueError(
+                f"unknown model preset {self.preset!r}: one of {sorted(presets)}"
+            )
+        base = presets[self.preset]
         base.update(self.config_overrides)
         return TransformerConfig(vocab_size=vocab_size, **base)
 
@@ -74,6 +80,35 @@ class ProcessorConfig:
     prefix_block: int = 16
 
 
+def engine_placement(params) -> Dict[str, Any]:
+    """The devices that hold `params`: {"platform", "device_kind", "count"}.
+    Raises on a TPU host whose engine landed on the CPU: a replica that asked
+    for no TPU (num_tpus=0) runs in the CPU worker pool, which the head pins
+    with JAX_PLATFORMS=cpu, and would otherwise serve the model from the host
+    CPU without a word."""
+    import jax
+
+    from ..core import accelerators
+
+    devices = {d for x in jax.tree_util.tree_leaves(params) for d in x.devices()}
+    first = min(devices, key=lambda d: d.id)
+    chips = accelerators.num_tpu_chips()
+    if chips and first.platform != "tpu":
+        raise RuntimeError(
+            f"this host has {chips} TPU chip(s) but the model's parameters are "
+            f"on {first.platform!r}: the engine runs in a worker without the "
+            "accelerator.  Ask for a chip (num_tpus=1 in "
+            "build_llm_deployment / build_continuous_llm_deployment, or "
+            "ray_actor_options of your own deployment) so it is placed in the "
+            "TPU worker pool."
+        )
+    return {
+        "platform": first.platform,
+        "device_kind": first.device_kind,
+        "count": len(devices),
+    }
+
+
 class _InferenceWorker:
     """Actor-pool UDF: holds compiled model + params for its lifetime
     (reference: stages run in vLLM engine actors)."""
@@ -92,6 +127,7 @@ class _InferenceWorker:
             self.params = _params_io.load_params(cfg.model.params_path)
         else:
             self.params = init_params(jax.random.key(cfg.model.seed), self.tcfg)
+        engine_placement(self.params)
         self._step = 0
 
     def __call__(

@@ -7,7 +7,13 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from .processor import ByteTokenizer, ModelSpec, ProcessorConfig, _InferenceWorker
+from .processor import (
+    ByteTokenizer,
+    ModelSpec,
+    ProcessorConfig,
+    _InferenceWorker,
+    engine_placement,
+)
 
 
 def _parse_body(request) -> Dict[str, Any]:
@@ -83,7 +89,9 @@ def build_llm_deployment(
     num_tpus: float = 0.0,
     name: str = "LLMServer",
 ):
-    """Returns a bound serve Application for `serve.run`."""
+    """Returns a bound serve Application for `serve.run`.  num_tpus is each
+    replica's chip request; with the default 0 a replica runs in the CPU
+    worker pool, and refuses to start on a host that has TPU chips."""
     from .. import serve
 
     config = config or ProcessorConfig()
@@ -114,7 +122,7 @@ class ContinuousLLMServer:
         import jax
 
         from ..models.transformer import init_params
-        from .continuous import ContinuousBatcher
+        from .continuous import PREFILL_BUCKETS, ContinuousBatcher
 
         self.config = config
         self.tok = config.tokenizer or ByteTokenizer()
@@ -125,10 +133,22 @@ class ContinuousLLMServer:
             params = _params_io.load_params(config.model.params_path)
         else:
             params = init_params(jax.random.key(config.model.seed), tcfg)
+        self.engine_device = engine_placement(params)
+        print(
+            "[llm] engine on {platform} ({device_kind}) x{count}".format(
+                **self.engine_device
+            ),
+            flush=True,
+        )
         t_max = config.max_prompt_len + config.max_new_tokens
+        # the batcher's own bucket ladder, cut at the longest prompt this
+        # deployment admits: a short prompt prefills a short program
+        buckets = tuple(
+            b for b in PREFILL_BUCKETS if b < config.max_prompt_len
+        ) + (config.max_prompt_len,)
         self.cb = ContinuousBatcher(
             params, tcfg, slots=slots, t_max=t_max,
-            prefill_buckets=(config.max_prompt_len,), top_k=config.top_k,
+            prefill_buckets=buckets, top_k=config.top_k,
             prefix_cache_entries=getattr(config, "prefix_cache_entries", 8),
             prefix_block=getattr(config, "prefix_block", 16),
         )
@@ -182,6 +202,14 @@ class ContinuousLLMServer:
                  "continuous-batcher decode iterations"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
+            m.Gauge(
+                "ca_serve_engine_devices",
+                "devices holding this replica's model parameters",
+                tag_keys=("platform", "device_kind"),
+            ).set(
+                self.engine_device["count"],
+                tags={k: self.engine_device[k] for k in ("platform", "device_kind")},
+            )
         for key, counter in self._llm_metrics.items():
             cur = self.cb.stats.get(key, 0)
             delta = cur - self._metrics_synced.get(key, 0)
@@ -397,7 +425,8 @@ def build_continuous_llm_deployment(
     """Continuous-batching twin of build_llm_deployment: up to `slots`
     requests share every decode iteration on each replica.  `admission`
     (AdmissionPolicy/dict) arms the proxy's load-shedding gate;
-    `sse_ingress=True` serves token-streaming SSE from __call__."""
+    `sse_ingress=True` serves token-streaming SSE from __call__.  `num_tpus`
+    is each replica's chip request (see build_llm_deployment)."""
     from .. import serve
 
     config = config or ProcessorConfig()

@@ -176,8 +176,8 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int):
     k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
     v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
     # causal attention within the prompt (q already has full heads; only
-    # k/v need the GQA repeat).  Dispatches to the pad-masked Pallas flash
-    # kernel on TPU when the prompt tiles (ops/attention.py), so long-prompt
+    # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
+    # Pallas flash kernel at every prompt length (ops/attention.py), so
     # prefill never materializes the [T, T] score matrix.
     from ..ops.attention import attention as _attn
 

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention as dense_attention
+from ..ops.attention import flash_attention, reference_attention
 from ..parallel.pipeline import pipeline_apply
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
@@ -175,9 +176,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
 def shard_params(params, cfg: TransformerConfig, mesh):
     """Annotate params with their mesh shardings.  Skipped on a single-device
     mesh: NamedSharding-constrained inputs put XLA through the SPMD
-    partitioner's layout constraints, which measured 10x slower per train
-    step on one TPU chip (167 ms -> 1627 ms at the bench config) for zero
-    benefit."""
+    partitioner's layout constraints for zero benefit (10x slower per train
+    step, 167 ms -> 1627 ms at the bench config, on a runtime that no longer
+    exists; not re-measured)."""
     if mesh is None or mesh.size <= 1:
         return params
     specs = param_specs(cfg)
@@ -220,29 +221,51 @@ def _rope(q, k, positions, cfg: TransformerConfig):
     )
 
 
-def _attention(q, k, v, cfg: TransformerConfig, sp_manual: bool):
+# [B, T, H, D] as the dense block leaves it: batch over the data axes, heads
+# over tp (wq/wk/wv shard their head columns on tp)
+_ATTN_SPEC = P(("dp", "fsdp"), None, "tp", None)
+
+
+def _per_shard(attn_fn, mesh, manual_axes=frozenset()):
+    """Run attn_fn(q, k, v) on each device's own batch rows and heads.  GSPMD
+    cannot partition a Mosaic kernel ("wrap the call in a shard_map": any
+    axis left to it, even of size 1, is refused), and attention needs no
+    collective across batch or heads, so on a multi-device mesh the call is
+    manual over every axis the caller (`manual_axes`: pp/sp/ep) is not
+    already manual over.  A nested shard_map takes the mesh of its context."""
+    if mesh is None or mesh.size == 1:
+        return attn_fn
+    return jax.shard_map(
+        attn_fn,
+        mesh=None if manual_axes else mesh,
+        in_specs=(_ATTN_SPEC,) * 3,
+        out_specs=_ATTN_SPEC,
+        axis_names=frozenset(mesh.axis_names) - manual_axes,
+        check_vma=False,
+    )
+
+
+def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes):
     impl = cfg.resolved_attn()
-    if impl == "ring" and sp_manual:
-        return ring_attention(q, k, v, axis_name="sp", causal=True)
-    if impl == "ulysses" and sp_manual:
-        return ulysses_attention(q, k, v, axis_name="sp", causal=True)
-    if impl == "jnp":  # force the XLA-fused dense path (perf A/B)
-        from ..ops.attention import reference_attention
-
-        return reference_attention(q, k, v, causal=True)
-    if impl == "flash":  # force the Pallas kernel (perf A/B)
-        from ..ops.attention import flash_attention
-
-        return flash_attention(
-            q, k, v, causal=True,
+    if impl == "ring" and "sp" in manual_axes:
+        fn = functools.partial(ring_attention, axis_name="sp", causal=True)
+    elif impl == "ulysses" and "sp" in manual_axes:
+        fn = functools.partial(ulysses_attention, axis_name="sp", causal=True)
+    elif impl == "jnp":  # force the XLA-fused dense path (perf A/B)
+        fn = functools.partial(reference_attention, causal=True)
+    elif impl == "flash":  # force the Pallas kernel (perf A/B)
+        fn = functools.partial(
+            flash_attention, causal=True,
             block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
         )
-    # auto dense path: the dispatcher picks per shape/platform
-    return dense_attention(q, k, v, causal=True)
+    else:  # auto dense path: the dispatcher picks by backend
+        fn = functools.partial(dense_attention, causal=True)
+    return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
-def _block_forward(bp, x, cfg: TransformerConfig, sp_manual: bool):
-    """One transformer block. x: [B, T_local, E]."""
+def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
+    """One transformer block. x: [B, T_local, E].  manual_axes: the mesh axes
+    the caller's shard_map is already manual over (pp/sp/ep subset)."""
     b, t, e = x.shape
     h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     dt = x.dtype
@@ -252,7 +275,7 @@ def _block_forward(bp, x, cfg: TransformerConfig, sp_manual: bool):
     k = (y @ bp["wk"].astype(dt)).reshape(b, t, kv, d)
     v = (y @ bp["wv"].astype(dt)).reshape(b, t, kv, d)
 
-    if sp_manual and cfg.sp > 1:
+    if "sp" in manual_axes and cfg.sp > 1:
         offset = lax.axis_index("sp") * t
     else:
         offset = 0
@@ -264,7 +287,7 @@ def _block_forward(bp, x, cfg: TransformerConfig, sp_manual: bool):
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
 
-    attn = _attention(q, k, v, cfg, sp_manual).reshape(b, t, h * d)
+    attn = _attention(q, k, v, cfg, mesh, manual_axes).reshape(b, t, h * d)
     x = x + attn @ bp["wo"].astype(dt)
 
     y = _rms_norm(x, bp["ln2"])
@@ -288,10 +311,12 @@ def _block_forward(bp, x, cfg: TransformerConfig, sp_manual: bool):
     return x, jnp.zeros((), jnp.float32)
 
 
-def _stage_forward(stage_blocks, x, cfg: TransformerConfig, sp_manual: bool):
+def _stage_forward(stage_blocks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
     """Scan over this stage's layers. stage_blocks leaves: [L_stage, ...].
     Returns (x, aux) — aux is the summed MoE load-balance loss (0 dense)."""
-    block = functools.partial(_block_forward, cfg=cfg, sp_manual=sp_manual)
+    block = functools.partial(
+        _block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes
+    )
     if cfg.remat:
         block = jax.checkpoint(block)
 
@@ -339,7 +364,16 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
             params["blocks"], x, cfg, mesh, frozenset(manual_axes)
         )
     else:
-        x, aux = _stage_forward(params["blocks"], x, cfg, sp_manual=False)
+        if mesh is not None and mesh.size > 1:
+            # state the residual stream's layout instead of leaving it to
+            # propagation: between the embedding (columns on tp), wo/w_down
+            # (columns on fsdp) and the attention region (batch on dp x fsdp)
+            # the partitioner otherwise picks its own, and on dp2 x fsdp2 x
+            # tp2 with one row per shard that gave wrong logits
+            x = lax.with_sharding_constraint(
+                x, NamedSharding(mesh, P(("dp", "fsdp"), None, None))
+            )
+        x, aux = _stage_forward(params["blocks"], x, cfg, mesh)
 
     x = _rms_norm(x, params["ln_f"])
     logits = x @ params["lm_head"].astype(cfg.dtype)
@@ -358,7 +392,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
         if pp_manual:
             my_blocks = jax.tree_util.tree_map(lambda p: p[0], blocks_local)
             stage = functools.partial(
-                _stage_forward, cfg=cfg, sp_manual=sp_manual
+                _stage_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes
             )
             if cfg.n_experts:
                 # MoE through the pipeline: each stage's MoE layers
@@ -384,7 +418,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
                 aux = jnp.zeros((), jnp.float32)
         else:
             x_out, aux = _stage_forward(
-                blocks_local, x_local, cfg=cfg, sp_manual=sp_manual
+                blocks_local, x_local, cfg, mesh, manual_axes
             )
         # the P() out-spec claims aux is replicated across EVERY manual axis;
         # each shard computed it over its own tokens, so reduce over all
@@ -408,9 +442,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
     batch_axis = "ep" if ep_manual else None
     x_spec = P(batch_axis, "sp" if sp_manual else None, None)
     aux_spec = P()
-    from ..parallel.compat import shard_map as _shard_map
-
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(block_specs, x_spec),

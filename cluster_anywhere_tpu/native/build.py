@@ -21,9 +21,11 @@ _SO = os.path.join(_BUILD_DIR, "libca_native.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _failed = False
+last_error: Optional[str] = None  # why the last build or load failed
 
 
 def _compile(out: str = _SO, extra_flags: Optional[list] = None) -> bool:
+    global last_error
     os.makedirs(_BUILD_DIR, exist_ok=True)
     tmp = out + f".tmp{os.getpid()}"
     cmd = [
@@ -35,7 +37,9 @@ def _compile(out: str = _SO, extra_flags: Optional[list] = None) -> bool:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, out)
         return True
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        stderr = getattr(e, "stderr", None)
+        last_error = f"{' '.join(cmd)}: {e!r}\n{(stderr or b'').decode('utf-8', 'replace')}"
         try:
             os.unlink(tmp)
         except OSError:
@@ -62,7 +66,7 @@ def build_sanitized(kind: str = "thread") -> Optional[str]:
 
 def load() -> Optional[ctypes.CDLL]:
     """The shared library, building it if stale/missing. None if unavailable."""
-    global _lib, _failed
+    global _lib, _failed, last_error
     with _lock:
         if _lib is not None:
             return _lib
@@ -98,8 +102,9 @@ def load() -> Optional[ctypes.CDLL]:
             lib.ca_load_u64.restype = ctypes.c_uint64
             _lib = lib
             return _lib
-        except OSError:
+        except OSError as e:
             _failed = True
+            last_error = f"loading {_SO}: {e!r}"
             return None
 
 

@@ -15,9 +15,11 @@ compute core of the TPU-native model stack: the dense transformer path calls
 Layout contract: [B, T, H, D] inputs (time-major per head), fp32 accumulation
 regardless of input dtype.  GQA callers repeat K/V heads first.
 
-On non-TPU backends `attention()` uses the fused-jnp reference; the Pallas
-kernels themselves also run under interpret mode for tests
-(`flash_attention(..., interpret=True)` — exercised in tests/test_ops.py).
+`attention()` runs the kernel on a TPU and the fused-jnp reference on the CPU
+backend (tests, virtual meshes); it never changes algorithm by shape.  Tests
+run the kernels themselves in interpret mode by passing
+`flash_attention(..., interpret=True)` (tests/test_ops.py); nothing else
+selects it.
 """
 
 from __future__ import annotations
@@ -28,20 +30,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-try:  # pallas is part of jax, but keep import-failure graceful for CPU-only
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover
-    pl = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+    """The backend every dispatch decision asks (here and in parallel/): one
+    place for a compile-only test to steer, since under an ahead-of-time
+    compile for a described chip the default backend is still the CPU."""
+    return jax.default_backend()
 
 
 # --------------------------------------------------------------------------
@@ -52,7 +51,7 @@ def _platform() -> str:
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad):
     if has_pad:
         pad_ref, o_ref, lse_ref = refs
-        pad_val = pad_ref[0]
+        pad_val = pad_ref[pl.program_id(0)]
     else:
         (o_ref, lse_ref) = refs
         pad_val = None
@@ -130,7 +129,7 @@ def _bwd_dq_kernel(
 ):
     if has_pad:
         pad_ref, dq_ref = refs
-        pad_val = pad_ref[0]
+        pad_val = pad_ref[pl.program_id(0)]
     else:
         (dq_ref,) = refs
         pad_val = None
@@ -194,7 +193,7 @@ def _bwd_dkv_kernel(
 ):
     if has_pad:
         pad_ref, dk_ref, dv_ref = refs
-        pad_val = pad_ref[0]
+        pad_val = pad_ref[pl.program_id(0)]
     else:
         dk_ref, dv_ref = refs
         pad_val = None
@@ -276,9 +275,14 @@ def _from_bhtd(x, b, h):
 
 
 def _pad_bh(pad, h):
-    """[B] per-row left-pad counts -> [B*H, 1] int32 (one scalar per grid
-    row, matching the B*H-flattened kernel grid)."""
-    return jnp.repeat(pad.astype(jnp.int32), h)[:, None]
+    """[B] per-row left-pad counts -> [B*H] int32, one scalar per row of the
+    B*H-flattened kernel grid.  The kernels read it whole from SMEM
+    (_PAD_SPEC) indexed by program_id(0): a (1,)-wide VMEM block of it is
+    refused by the TPU lowering."""
+    return jnp.repeat(pad.astype(jnp.int32), h)
+
+
+_PAD_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret):
@@ -296,7 +300,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret):
     ]
     args = [qf, kf, vf]
     if has_pad:
-        in_specs.append(pl.BlockSpec((None, 1), lambda bi, qi: (bi, 0)))
+        in_specs.append(_PAD_SPEC)
         args.append(_pad_bh(pad, h))
     out, lse = pl.pallas_call(
         functools.partial(
@@ -334,7 +338,7 @@ def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpr
     delta = delta.reshape(bh, 1, t)
     has_pad = pad is not None
     pad_arg = [_pad_bh(pad, h)] if has_pad else []
-    pad_spec = [pl.BlockSpec((None, 1), lambda bi, qi: (bi, 0))] if has_pad else []
+    pad_spec = [_PAD_SPEC] if has_pad else []
 
     dq = pl.pallas_call(
         functools.partial(
@@ -438,7 +442,7 @@ def flash_attention(
     pad: Optional[jax.Array] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
     return_lse: bool = False,
 ):
     """Pallas flash attention.  q: [B, T, H, D]; k, v: [B, T_kv, H, D].
@@ -451,16 +455,15 @@ def flash_attention(
     capped at 256 / 512 — measured best for fwd+bwd on v5e at d_head=64
     (vs 128/128: bigger K tiles amortize the half-empty 64-lane contraction
     and cut grid-step overhead; Q tiles above 256 pay more bwd recompute
-    than they save).  Requires T % block_q == 0 and T_kv % block_k == 0 (the
-    dispatcher `attention()` falls back to the jnp reference otherwise).
+    than they save).  Requires T % block_q == 0 and T_kv % block_k == 0, and
+    on the chip blocks that are multiples of 128 (the lse row is stored by
+    lane-aligned q-blocks); the dispatcher `attention()` pads to that.
     With return_lse=True also returns the per-row log-sum-exp [B, H, T] —
     the carry ring attention needs to merge per-block results
     (merge_attention).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = _platform() == "cpu"
     if block_q is None:
         block_q = _auto_block(q.shape[1], 256)
     if block_k is None:
@@ -525,18 +528,68 @@ def reference_attention(q, k, v, causal=True, scale=None, pad=None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+_TILE = 128  # the compiled kernel's q/k blocks are multiples of the lane width
+
+
+def _left_pad_to_tile(q, k, v, pad):
+    """Left-pad a [B, T, H, D] self-attention problem to the next multiple of
+    the kernel's tile, counting the new columns as pad tokens.  Returns
+    (q, k, v, pad, extra); the caller drops the first `extra` output rows."""
+    b, t = q.shape[:2]
+    extra = (-t) % _TILE
+    widen = lambda x: jnp.pad(x, ((0, 0), (extra, 0), (0, 0), (0, 0)))
+    pad = extra + (jnp.zeros((b,), jnp.int32) if pad is None else pad.astype(jnp.int32))
+    return widen(q), widen(k), widen(v), pad, extra
+
+
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None):
-    """Dispatcher: Pallas flash kernel on TPU when shapes tile cleanly, else
-    the jnp reference (XLA still fuses that well on CPU test meshes)."""
+    """Dispatcher: the Pallas flash kernel on a TPU, the jnp reference on any
+    other backend.  A sequence that is not a multiple of the kernel's tile is
+    left-padded up to one and the new columns masked as pad tokens (their
+    query rows are dropped), so the algorithm never changes with the shape."""
+    if _platform() != "tpu":
+        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad)
     t, t_kv = q.shape[1], k.shape[1]
-    use_flash = (
-        pl is not None
-        and _platform() not in ("cpu",)
-        and t % min(128, t) == 0
-        and t_kv % min(128, t_kv) == 0
-        and t >= 128
-        and t_kv >= 128
-    )
-    if use_flash:
+    if t % _TILE == 0 and t_kv % _TILE == 0:
         return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad)
-    return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad)
+    if t != t_kv:
+        raise ValueError(
+            f"flash kernel needs T and T_kv to be multiples of {_TILE} when "
+            f"they differ, got T={t}, T_kv={t_kv}"
+        )
+    q, k, v, pad, extra = _left_pad_to_tile(q, k, v, pad)
+    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad)[:, extra:]
+
+
+def flash_numerics_errors() -> dict:
+    """Compiled (never interpret-mode) flash-vs-reference check on the device
+    this process holds, so a wrong kernel cannot ship a fast number: max abs
+    error of the causal kernel, of its left-padded variant, and of the
+    dispatcher on a length it has to pad, at the flagship head shape
+    (d_head 128).  bf16 inputs; callers hold the result to a bf16 tolerance
+    (0.05)."""
+    t = 2 * _TILE
+    ks = jax.random.split(jax.random.key(7), 3)
+    q, k, v = (jax.random.normal(kk, (2, t, 4, 128), jnp.bfloat16) for kk in ks)
+    pad = jnp.asarray([0, t // 4 + 3], jnp.int32)
+
+    def err(got, want, keep=None):
+        d = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+        if keep is not None:
+            d = jnp.where(keep[:, :, None, None], d, 0.0)
+        return float(jnp.max(d))
+
+    ref = jax.jit(reference_attention, static_argnames=("causal",))
+    real = jnp.arange(t)[None, :] >= pad[:, None]  # pad-query rows are garbage
+    odd = t - _TILE // 2
+    return {
+        "causal": err(jax.jit(flash_attention)(q, k, v), ref(q, k, v)),
+        "padded": err(
+            jax.jit(lambda q, k, v, p: flash_attention(q, k, v, pad=p))(q, k, v, pad),
+            ref(q, k, v, pad=pad), real,
+        ),
+        "dispatch_unaligned": err(
+            jax.jit(attention)(q[:, :odd], k[:, :odd], v[:, :odd]),
+            ref(q[:, :odd], k[:, :odd], v[:, :odd]),
+        ),
+    }

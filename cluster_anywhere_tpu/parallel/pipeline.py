@@ -97,8 +97,6 @@ def pipeline_sharded(stage_fn, mesh, *, axis_name="pp", num_microbatches):
     leading pp axis (params[i] = stage i); x replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map
-
     def inner(stacked_params, x):
         my_params = jax.tree_util.tree_map(lambda p: p[0], stacked_params)
         return pipeline_apply(
@@ -107,7 +105,7 @@ def pipeline_sharded(stage_fn, mesh, *, axis_name="pp", num_microbatches):
 
     def apply(stacked_params, x):
         in_param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(in_param_specs, P()),

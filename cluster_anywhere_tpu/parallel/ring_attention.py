@@ -12,8 +12,8 @@ On TPU each arriving block is processed by the Pallas flash kernel
 (ops.attention.flash_attention) — full attention for blocks from earlier
 shards, causal for the diagonal block, skipped for future shards — and the
 per-block (out, lse) partials are combined with ops.attention.merge_attention.
-On CPU test meshes (or non-tiling shapes) the same schedule runs as a pure
-jnp streaming-softmax loop; both paths are differentiable.
+On the CPU backend (test meshes) the same schedule runs as a pure jnp
+streaming-softmax loop; both paths are differentiable.
 
 Usage inside shard_map (manual over 'sp'; see tests/test_parallel.py):
     out = ring_attention(q, k, v, axis_name="sp", causal=True)
@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.attention import NEG_INF, flash_attention, merge_attention
+from ..ops.attention import NEG_INF, _platform, flash_attention, merge_attention
 
 
 def _block_attention(q, k, v, scale, mask, m_prev, l_prev, o_prev):
@@ -54,14 +54,6 @@ def _block_attention(q, k, v, scale, mask, m_prev, l_prev, o_prev):
     return m_new, l_new, o_new
 
 
-def _flash_tiles(t_local: int) -> bool:
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    return platform not in ("cpu",) and t_local >= 128 and t_local % 128 == 0
-
-
 def ring_attention(
     q: jax.Array,
     k: jax.Array,
@@ -70,11 +62,14 @@ def ring_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Exact attention over a ring of sequence shards (call inside shard_map).
 
     Shapes (per device): q, k, v: [B, T_local, H, D] -> out [B, T_local, H, D].
-    For GQA repeat K/V heads to H before calling.
+    For GQA repeat K/V heads to H before calling.  use_flash defaults to the
+    backend (kernel on a TPU, jnp loop elsewhere); tests force the kernel
+    schedule on the CPU with use_flash=True, interpret=True.
     """
     n = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
@@ -82,9 +77,9 @@ def ring_attention(
     if scale is None:
         scale = d ** -0.5
     if use_flash is None:
-        use_flash = _flash_tiles(t_local)
+        use_flash = _platform() == "tpu"
     if use_flash:
-        return _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx)
+        return _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx, interpret)
 
     m0 = jnp.full((b, h, t_local), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, h, t_local), dtype=jnp.float32)
@@ -120,7 +115,7 @@ def ring_attention(
     return out.astype(q.dtype)
 
 
-def _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx):
+def _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx, interpret):
     """Flash-kernel ring schedule: per arriving K/V block run the Pallas
     kernel in the right causality mode and merge the (out, lse) partials.
     Blocks from later shards contribute nothing under causal masking and are
@@ -130,11 +125,15 @@ def _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx):
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def _full(q, kb, vb):
-        o, lse = flash_attention(q, kb, vb, causal=False, scale=scale, return_lse=True)
+        o, lse = flash_attention(
+            q, kb, vb, causal=False, scale=scale, return_lse=True, interpret=interpret
+        )
         return o.astype(jnp.float32), lse
 
     def _causal(q, kb, vb):
-        o, lse = flash_attention(q, kb, vb, causal=True, scale=scale, return_lse=True)
+        o, lse = flash_attention(
+            q, kb, vb, causal=True, scale=scale, return_lse=True, interpret=interpret
+        )
         return o.astype(jnp.float32), lse
 
     def _skip(q, kb, vb):
@@ -164,18 +163,19 @@ def _ring_flash(q, k, v, axis_name, causal, scale, n, my_idx):
     return o.astype(q.dtype)
 
 
-def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True, use_flash=None):
+def ring_attention_sharded(
+    q, k, v, mesh, axis_name="sp", causal=True, use_flash=None, interpret=False
+):
     """Convenience wrapper: shard_map over the sp axis of `mesh` with
     [batch, seq, heads, dim] inputs sharded on seq."""
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map
-
     spec = P(None, axis_name, None, None)
     fn = functools.partial(
-        ring_attention, axis_name=axis_name, causal=causal, use_flash=use_flash
+        ring_attention, axis_name=axis_name, causal=causal, use_flash=use_flash,
+        interpret=interpret,
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )(q, k, v)
 

@@ -22,8 +22,8 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True, attn_
     sequence sharded). Requires H % sp == 0.
 
     Default attention over the gathered full sequence goes through the
-    dispatcher: the Pallas flash kernel on TPU whenever the (full) sequence
-    tiles, jnp reference otherwise."""
+    dispatcher: the Pallas flash kernel on a TPU, the jnp reference on the
+    CPU backend."""
     n = lax.psum(1, axis_name)
     if q.shape[2] % n != 0:
         raise ValueError(f"heads {q.shape[2]} not divisible by {axis_name}={n}")
@@ -46,10 +46,8 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True, attn_
 def ulysses_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True):
     from jax.sharding import PartitionSpec as P
 
-    from .compat import shard_map
-
     spec = P(None, axis_name, None, None)
     fn = functools.partial(ulysses_attention, axis_name=axis_name, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )(q, k, v)

@@ -174,3 +174,47 @@ def test_prefill_uses_pad_dispatcher():
     np.testing.assert_allclose(
         np.asarray(logits_a[0]), np.asarray(logits_b[0]), atol=1e-4, rtol=1e-4
     )
+
+
+@pytest.mark.parametrize(
+    "t, pad, grads", [(40, None, False), (96, [3, 0], True), (130, [0, 17], False)]
+)
+def test_left_pad_to_tile_matches_reference(t, pad, grads):
+    """What the dispatcher does on a TPU with a length that is not a multiple
+    of the kernel's tile: left-pad, mask the new columns, drop their rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.ops.attention import (
+        _TILE,
+        _left_pad_to_tile,
+        flash_attention,
+        reference_attention,
+    )
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(kk, (2, t, 2, 8), jnp.float32) for kk in ks)
+    pad_arr = None if pad is None else jnp.asarray(pad, jnp.int32)
+    want = reference_attention(q, k, v, causal=True, pad=pad_arr)
+
+    def padded(q, k, v):
+        q2, k2, v2, pad2, extra = _left_pad_to_tile(q, k, v, pad_arr)
+        assert q2.shape[1] % _TILE == 0 and q2.shape[1] - t == extra < _TILE
+        out = flash_attention(q2, k2, v2, causal=True, pad=pad2, interpret=True)
+        return out[:, extra:]
+
+    got = padded(q, k, v)
+    assert got.shape == q.shape
+    real = np.arange(t)[None, :] >= np.asarray(pad or [0, 0])[:, None]
+    np.testing.assert_allclose(
+        np.asarray(got)[real], np.asarray(want)[real], atol=2e-5, rtol=2e-5
+    )
+    if not grads:
+        return
+    # gradients flow through the pad and the slice
+    keep = jnp.asarray(real)[:, :, None, None]
+    g = jax.grad(lambda q: jnp.where(keep, padded(q, k, v), 0.0).sum())(q)
+    g_ref = jax.grad(
+        lambda q: jnp.where(keep, reference_attention(q, k, v, causal=True, pad=pad_arr), 0.0).sum()
+    )(q)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=2e-4, rtol=2e-4)

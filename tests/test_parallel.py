@@ -78,7 +78,7 @@ def test_ring_flash_matches_dense(causal):
         for kk in jax.random.split(key, 3)
     )
     expect = reference_attention(q, k, v, causal=causal)
-    got = ring_attention_sharded(q, k, v, mesh, causal=causal, use_flash=True)
+    got = ring_attention_sharded(q, k, v, mesh, causal=causal, use_flash=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect), rtol=2e-4, atol=2e-4)
 
 
@@ -93,7 +93,7 @@ def test_ring_flash_grads_match():
 
     def loss_ring(q, k, v):
         return jnp.sum(
-            ring_attention_sharded(q, k, v, mesh, causal=True, use_flash=True) ** 2
+            ring_attention_sharded(q, k, v, mesh, causal=True, use_flash=True, interpret=True) ** 2
         )
 
     def loss_ref(q, k, v):
