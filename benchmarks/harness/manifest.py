@@ -1,0 +1,88 @@
+"""Finds a cell's files by the names in BENCHMARK.json.
+
+A cell is `cells/<cell>.json`; it names a configuration (`configs/<config>.json`)
+and a traffic mix (`traffic/<mix>.json`).  A per-layer metric is
+`layer_metrics/<name>.json`, which names its reader
+(`layer_metrics/readers/<reader>.py`).  A later PR adds a cell, a mix or a
+metric by adding such files and one entry in BENCHMARK.json: nothing here
+lists them.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+from typing import Any, Callable, Dict, List
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def _load(path: str) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
+def check_name(name: str) -> str:
+    if not NAME_RE.match(name):
+        raise ValueError(f"not a valid name: {name!r}")
+    return name
+
+
+def load_manifest(root: str = ROOT) -> Dict[str, Any]:
+    return _load(os.path.join(root, "BENCHMARK.json"))
+
+
+def load_cell(name: str, bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
+    """The cell's file with its configuration and traffic files resolved:
+    {"name", "config", "traffic", "chips", "why", ..., "config_file": {...},
+    "traffic_file": {...}}."""
+    cell = _load(os.path.join(bench_dir, "cells", check_name(name) + ".json"))
+    cell["name"] = name
+    cell["config_file"] = _load(
+        os.path.join(bench_dir, "configs", check_name(cell["config"]) + ".json")
+    )
+    cell["traffic_file"] = _load(
+        os.path.join(bench_dir, "traffic", check_name(cell["traffic"]) + ".json")
+    )
+    return cell
+
+
+def layer_metrics_for(cell: str, bench_dir: str = BENCH_DIR) -> List[Dict[str, Any]]:
+    """Every per-layer metric file that lists this cell (or lists none: all
+    cells), sorted by name."""
+    out = []
+    d = os.path.join(bench_dir, "layer_metrics")
+    for fn in sorted(os.listdir(d)):
+        if not fn.endswith(".json"):
+            continue
+        m = _load(os.path.join(d, fn))
+        m["name"] = fn[: -len(".json")]
+        if "cells" not in m or cell in m["cells"]:
+            out.append(m)
+    return out
+
+
+def load_reader(reader: str, bench_dir: str = BENCH_DIR) -> Callable:
+    """The `read(ctx, **args)` function of layer_metrics/readers/<reader>.py."""
+    path = os.path.join(bench_dir, "layer_metrics", "readers", check_name(reader) + ".py")
+    spec = importlib.util.spec_from_file_location(f"bench_reader_{reader}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def read_layer_metrics(cell: str, ctx: Dict[str, Any], bench_dir: str = BENCH_DIR):
+    """{name: {"value", "unit"}} for every per-layer metric of the cell whose
+    reader found something to read."""
+    out = {}
+    for m in layer_metrics_for(cell, bench_dir):
+        value = load_reader(m["reader"], bench_dir)(ctx, **m.get("args", {}))
+        if value is not None:
+            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return out
