@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -46,6 +47,7 @@ from ..models.generate import (
     prefill,
 )
 from ..models.transformer import TransformerConfig
+from ..util import tracing
 
 
 PREFILL_BUCKETS = (64, 128, 256)  # padded prompt lengths: one prefill program each
@@ -60,6 +62,10 @@ class Request:
     top_k: int = 0
     top_p: float = 1.0
     eos_id: Optional[int] = None
+    # stamped by submit(): the submitter's trace context (its admit, on the
+    # pump's thread, runs under it) and time.monotonic() (queue wait)
+    trace: Optional[Dict[str, str]] = None
+    t_submit: float = 0.0
     # filled as the request runs
     out_tokens: List[int] = field(default_factory=list)
     slot: int = -1
@@ -71,21 +77,22 @@ def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
     in one decode batch carry their own knobs; a static top_k would force
     one value per compiled program).  top_k <= 0 means no truncation;
     top_p outside (0, 1) means no nucleus mask; temp <= 0 means greedy."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    t = jnp.maximum(temps, 1e-6)[:, None]
-    scaled = logits / t
-    v = logits.shape[-1]
-    # traced top-k: k-th largest per row via a descending sort
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    kth_idx = jnp.clip(top_ks - 1, 0, v - 1)[:, None]
-    kth = jnp.take_along_axis(sorted_desc, kth_idx, axis=-1)
-    scaled = jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
-    # per-row nucleus mask: [S,1] top_p broadcasts through the shared helper
-    scaled = _nucleus_mask(scaled, top_ps[:, None])
-    sampled = jax.vmap(lambda rng, row: jax.random.categorical(rng, row))(
-        rngs, scaled
-    ).astype(jnp.int32)
-    return jnp.where(temps <= 0.0, greedy, sampled)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        t = jnp.maximum(temps, 1e-6)[:, None]
+        scaled = logits / t
+        v = logits.shape[-1]
+        # traced top-k: k-th largest per row via a descending sort
+        sorted_desc = -jnp.sort(-scaled, axis=-1)
+        kth_idx = jnp.clip(top_ks - 1, 0, v - 1)[:, None]
+        kth = jnp.take_along_axis(sorted_desc, kth_idx, axis=-1)
+        scaled = jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
+        # per-row nucleus mask: [S,1] top_p broadcasts through the shared helper
+        scaled = _nucleus_mask(scaled, top_ps[:, None])
+        sampled = jax.vmap(lambda rng, row: jax.random.categorical(rng, row))(
+            rngs, scaled
+        ).astype(jnp.int32)
+        return jnp.where(temps <= 0.0, greedy, sampled)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -94,7 +101,8 @@ def _decode_step_rowpos(params, cache, tokens, pos, pads, temps, top_ks, top_ps,
     tokens/pos/pads/temps/top_ks: [S]; rngs: [S] keys.  Returns
     (next_tokens [S], cache).  The cache is donated: decode rewrites it in
     place instead of copying [L,S,Tmax,KV,D] x2 per token."""
-    x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [S,1,E]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [S,1,E]
 
     def body(x, inputs):
         bp, kc, vc = inputs
@@ -102,8 +110,10 @@ def _decode_step_rowpos(params, cache, tokens, pos, pads, temps, top_ks, top_ps,
         return x, (kc, vc)
 
     x, (k_all, v_all) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _rms_norm(x, params["ln_f"])
-    logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("norm"):
+        x = _rms_norm(x, params["ln_f"])
+    with jax.named_scope("head"):
+        logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
     nxt = _sample_rowwise(logits, rngs, temps, top_ks, top_ps)
     return nxt, {"k": k_all, "v": v_all}
 
@@ -111,10 +121,11 @@ def _decode_step_rowpos(params, cache, tokens, pos, pads, temps, top_ks, top_ps,
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _install_slot(cache, slot_k, slot_v, slot):
     """Scatter one request's prefilled rows into its slot (on device)."""
-    return {
-        "k": cache["k"].at[:, slot].set(slot_k),
-        "v": cache["v"].at[:, slot].set(slot_v),
-    }
+    with jax.named_scope("attn.cache"):
+        return {
+            "k": cache["k"].at[:, slot].set(slot_k),
+            "v": cache["v"].at[:, slot].set(slot_v),
+        }
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -240,6 +251,9 @@ class ContinuousBatcher:
         self.stats = {
             "admitted": 0, "finished": 0, "decode_steps": 0, "cancelled": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_tokens_reused": 0,
+            # counted where the spans are: requests queued, tokens handed
+            # out, and cumulative seconds queued and in admit
+            "submitted": 0, "tokens_out": 0, "queue_wait_s": 0.0, "admit_s": 0.0,
         }
 
     # ------------------------------------------------------------- interface
@@ -262,8 +276,10 @@ class ContinuousBatcher:
         req = Request(
             next(self._ids), prompt, int(max_new_tokens), float(temperature),
             self.top_k if top_k is None else int(top_k), float(top_p), eos_id,
+            tracing.current(), time.monotonic(),
         )
         self.queue.append(req)
+        self.stats["submitted"] += 1
         return req
 
     def cancel(self, request_id: int) -> bool:
@@ -294,38 +310,46 @@ class ContinuousBatcher:
         Returns {request_id: [new tokens this step]} — including the
         prefill-sampled first token of requests admitted this step, so
         streaming consumers see every token exactly once."""
-        out: Dict[int, List[int]] = {}
-        self._admit(out)
-        live = [s for s, r in enumerate(self._by_slot) if r is not None]
-        if not live:
+        sp = tracing.span("llm.step")
+        with sp:
+            out: Dict[int, List[int]] = {}
+            self._admit(out)
+            live = [s for s, r in enumerate(self._by_slot) if r is not None]
+            sp.set(live=len(live))
+            if not live:
+                return out
+            with tracing.span("llm.step.upload"):
+                self._rng, *keys = jax.random.split(self._rng, self.slots + 1)
+                inputs = (
+                    jnp.asarray(self._tokens),
+                    jnp.asarray(self._pos),
+                    jnp.asarray(self._pads),
+                    jnp.asarray(self._temps),
+                    jnp.asarray(self._topks),
+                    jnp.asarray(self._topps),
+                    jnp.stack(keys),
+                )
+            with tracing.span("llm.step.dispatch"):
+                nxt, self.cache = _decode_step_rowpos(
+                    self.params, self.cache, *inputs, cfg=self.cfg
+                )
+            with tracing.span("llm.step.readback"):
+                nxt = np.asarray(nxt)
+            self.stats["decode_steps"] += 1
+            self.stats["tokens_out"] += len(live)
+            with tracing.span("llm.step.scatter"):
+                for s in live:
+                    req = self._by_slot[s]
+                    tok = int(nxt[s])
+                    req.out_tokens.append(tok)
+                    out.setdefault(req.request_id, []).append(tok)
+                    self._tokens[s] = tok
+                    self._pos[s] += 1
+                    if len(req.out_tokens) >= req.max_new_tokens or (
+                        req.eos_id is not None and tok == req.eos_id
+                    ):
+                        self._finish(s, req)
             return out
-        self._rng, *keys = jax.random.split(self._rng, self.slots + 1)
-        nxt, self.cache = _decode_step_rowpos(
-            self.params,
-            self.cache,
-            jnp.asarray(self._tokens),
-            jnp.asarray(self._pos),
-            jnp.asarray(self._pads),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._topks),
-            jnp.asarray(self._topps),
-            jnp.stack(keys),
-            cfg=self.cfg,
-        )
-        nxt = np.asarray(nxt)
-        self.stats["decode_steps"] += 1
-        for s in live:
-            req = self._by_slot[s]
-            tok = int(nxt[s])
-            req.out_tokens.append(tok)
-            out.setdefault(req.request_id, []).append(tok)
-            self._tokens[s] = tok
-            self._pos[s] += 1
-            if len(req.out_tokens) >= req.max_new_tokens or (
-                req.eos_id is not None and tok == req.eos_id
-            ):
-                self._finish(s, req)
-        return out
 
     def pump(self) -> List[Request]:
         """Run until every submitted request finishes; returns them in
@@ -360,26 +384,28 @@ class ContinuousBatcher:
         split = ((len(prompt) - 1) // self._split_quantum) * self._split_quantum
         return split if split >= self.prefix_block else 0
 
-    def _admit_full_prefill(self, req: Request):
+    def _admit_full_prefill(self, req: Request, sp: tracing.span):
         """Cold admit: prefill the whole prompt (one bucketed batch-1
         program).  Returns (first-token logits [1,V], slot rows, pad,
-        next_pos)."""
+        next_pos).  `sp` is the request's `llm.admit` span."""
         prompt = req.prompt_ids
         bucket = self._bucket(len(prompt), req.max_new_tokens)
-        padded = np.zeros(bucket, np.int32)
-        pad = bucket - len(prompt)
-        padded[pad:] = prompt  # LEFT pad: generate.py's prefill contract
-        logits, rowcache = prefill(
-            self.params,
-            jnp.asarray(padded[None]),
-            self.cfg,
-            self.t_max,
-            pad=jnp.asarray([pad], np.int32),
-        )
-        rows = {"k": rowcache["k"][:, 0], "v": rowcache["v"][:, 0]}
+        sp.set(bucket=bucket, prefix_hit=0)
+        with tracing.span("llm.admit.prefill"):
+            padded = np.zeros(bucket, np.int32)
+            pad = bucket - len(prompt)
+            padded[pad:] = prompt  # LEFT pad: generate.py's prefill contract
+            logits, rowcache = prefill(
+                self.params,
+                jnp.asarray(padded[None]),
+                self.cfg,
+                self.t_max,
+                pad=jnp.asarray([pad], np.int32),
+            )
+            rows = {"k": rowcache["k"][:, 0], "v": rowcache["v"][:, 0]}
         return logits, rows, pad, bucket
 
-    def _admit_prefix_cached(self, req: Request, split: int):
+    def _admit_prefix_cached(self, req: Request, split: int, sp: tracing.span):
         """Chunked admit via the prefix cache: the block-aligned prefix
         comes from the cache (or prefills once, populating it); the suffix
         teacher-forces through _suffix_step token by token.  Hit and miss
@@ -392,42 +418,62 @@ class ContinuousBatcher:
         bucket = self._bucket(split, req.max_new_tokens + len(suffix))
         key = PrefixCache.key(prompt[:split], bucket)
         entry = self.prefix_cache.get(key)
+        sp.set(bucket=bucket, prefix_hit=int(entry is not None))
         if entry is None:
-            padded = np.zeros(bucket, np.int32)
-            pad = bucket - split
-            padded[pad:] = prompt[:split]
-            _, rowcache = prefill(
-                self.params,
-                jnp.asarray(padded[None]),
-                self.cfg,
-                self.t_max,
-                pad=jnp.asarray([pad], np.int32),
-            )
-            rows = {"k": rowcache["k"][:, 0:1], "v": rowcache["v"][:, 0:1]}
-            # store a snapshot BEFORE stepping: _suffix_step donates its rows
-            self.prefix_cache.put(
-                key, {k: jnp.copy(v) for k, v in rows.items()}, pad
-            )
+            with tracing.span("llm.admit.prefill"):
+                padded = np.zeros(bucket, np.int32)
+                pad = bucket - split
+                padded[pad:] = prompt[:split]
+                _, rowcache = prefill(
+                    self.params,
+                    jnp.asarray(padded[None]),
+                    self.cfg,
+                    self.t_max,
+                    pad=jnp.asarray([pad], np.int32),
+                )
+                rows = {"k": rowcache["k"][:, 0:1], "v": rowcache["v"][:, 0:1]}
+                # store a snapshot BEFORE stepping: _suffix_step donates its rows
+                self.prefix_cache.put(
+                    key, {k: jnp.copy(v) for k, v in rows.items()}, pad
+                )
             self.stats["prefix_misses"] += 1
         else:
             pad = entry["pad"]
             rows = {k: jnp.copy(v) for k, v in entry["rows"].items()}
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += split
-        pad_arr = jnp.asarray([pad], np.int32)
-        logits = None
-        for i, tok in enumerate(suffix):
-            logits, rows = _suffix_step(
-                self.params, rows,
-                jnp.asarray([int(tok)], np.int32),
-                jnp.asarray(bucket + i, np.int32),
-                pad_arr, cfg=self.cfg,
-            )
+        with tracing.span("llm.admit.suffix", tokens=len(suffix)):
+            pad_arr = jnp.asarray([pad], np.int32)
+            logits = None
+            for i, tok in enumerate(suffix):
+                logits, rows = _suffix_step(
+                    self.params, rows,
+                    jnp.asarray([int(tok)], np.int32),
+                    jnp.asarray(bucket + i, np.int32),
+                    pad_arr, cfg=self.cfg,
+                )
         return logits, {"k": rows["k"][:, 0], "v": rows["v"][:, 0]}, pad, bucket + len(suffix)
 
     def _admit(self, out: Optional[Dict[int, List[int]]] = None) -> None:
         while self.queue and None in self._by_slot:
             req = self.queue.popleft()
+            # the admit runs on the pump's thread but belongs to the request:
+            # under the submitter's trace context, as a worker runs a task
+            token = tracing.push_execution(req.trace) if req.trace else None
+            try:
+                self._admit_one(req, out)
+            finally:
+                if token is not None:
+                    tracing.pop_execution(token)
+
+    def _admit_one(self, req: Request, out: Optional[Dict[int, List[int]]]) -> None:
+        t0 = time.monotonic()
+        queue_wait = t0 - req.t_submit
+        sp = tracing.span(
+            "llm.admit", rid=req.request_id, prompt_len=len(req.prompt_ids),
+            queue_wait_ms=1e3 * queue_wait,
+        )
+        with sp:
             slot = self._by_slot.index(None)
             split = (
                 self._prefix_split(req.prompt_ids)
@@ -435,19 +481,21 @@ class ContinuousBatcher:
                 else 0
             )
             if split:
-                logits, rows, pad, next_pos = self._admit_prefix_cached(req, split)
+                logits, rows, pad, next_pos = self._admit_prefix_cached(req, split, sp)
             else:
-                logits, rows, pad, next_pos = self._admit_full_prefill(req)
-            self.cache = _install_slot(self.cache, rows["k"], rows["v"], slot)
-            self._rng, k = jax.random.split(self._rng)
-            first = int(
-                np.asarray(
-                    _sample(
-                        logits, k, jnp.float32(req.temperature), req.top_k,
-                        jnp.float32(req.top_p),
-                    )
-                )[0]
-            )
+                logits, rows, pad, next_pos = self._admit_full_prefill(req, sp)
+            with tracing.span("llm.admit.install"):
+                self.cache = _install_slot(self.cache, rows["k"], rows["v"], slot)
+            with tracing.span("llm.admit.sample"):
+                self._rng, k = jax.random.split(self._rng)
+                first = int(
+                    np.asarray(
+                        _sample(
+                            logits, k, jnp.float32(req.temperature), req.top_k,
+                            jnp.float32(req.top_p),
+                        )
+                    )[0]
+                )
             req.out_tokens.append(first)
             if out is not None:
                 out.setdefault(req.request_id, []).append(first)
@@ -460,7 +508,10 @@ class ContinuousBatcher:
             self._topks[slot] = req.top_k
             self._topps[slot] = req.top_p
             self.stats["admitted"] += 1
+            self.stats["tokens_out"] += 1
             if len(req.out_tokens) >= req.max_new_tokens or (
                 req.eos_id is not None and first == req.eos_id
             ):
                 self._finish(slot, req)
+        self.stats["queue_wait_s"] += queue_wait
+        self.stats["admit_s"] += time.monotonic() - t0

@@ -5,8 +5,10 @@ request batching over the compiled generate path).
 
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, Optional
 
+from ..util import tracing
 from .processor import (
     ByteTokenizer,
     ModelSpec,
@@ -124,6 +126,9 @@ class ContinuousLLMServer:
         from ..models.transformer import init_params
         from .continuous import PREFILL_BUCKETS, ContinuousBatcher
 
+        # jax is loaded from here on: compilations and device memory reach
+        # the cluster's metrics from the process that holds the chip
+        tracing.enable_jax_profiling()
         self.config = config
         self.tok = config.tokenizer or ByteTokenizer()
         tcfg = config.model.transformer_config(self.tok.vocab_size)
@@ -154,6 +159,9 @@ class ContinuousLLMServer:
         )
         self._metrics_synced: dict = {}
         self._lock = threading.Lock()  # batcher is single-threaded inside
+        # seconds callers waited for that lock in _submit, beside the
+        # batcher's own counts (updated under the lock)
+        self.cb.stats["lock_wait_s"] = 0.0
         self._queues: dict = {}  # request_id -> queue of token ids (+ None EOF)
         self._reqs: dict = {}  # request_id -> Request (done detection)
         self._queue_cls = queue.Queue
@@ -186,8 +194,10 @@ class ContinuousLLMServer:
 
     def _sync_engine_metrics(self):
         """Ship the batcher's counters (prefix-cache hits/misses/tokens
-        reused, decode steps) as ca_serve_* cluster metrics — the series
-        behind the envelope's "hits skip prefill" claim."""
+        reused, decode steps; requests submitted, tokens handed out, seconds
+        queued, in admit and waiting for the replica's lock) as ca_serve_*
+        cluster metrics — the series behind the envelope's "hits skip
+        prefill" claim; mean queue wait and mean admit are each two rates."""
         if not self._llm_metrics:
             from ..util import metrics as m
 
@@ -200,6 +210,16 @@ class ContinuousLLMServer:
                  "prompt tokens whose prefill was skipped via the prefix cache"),
                 ("decode_steps", "ca_serve_decode_steps_total",
                  "continuous-batcher decode iterations"),
+                ("submitted", "ca_serve_submitted_total",
+                 "requests queued on the continuous batcher"),
+                ("tokens_out", "ca_serve_tokens_out_total",
+                 "tokens the continuous batcher handed out"),
+                ("queue_wait_s", "ca_serve_queue_wait_seconds_total",
+                 "seconds requests spent queued before their admit began"),
+                ("admit_s", "ca_serve_admit_seconds_total",
+                 "seconds the decode pump spent admitting requests"),
+                ("lock_wait_s", "ca_serve_lock_wait_seconds_total",
+                 "seconds submitting callers waited for the replica's lock"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(
@@ -218,33 +238,32 @@ class ContinuousLLMServer:
                 self._metrics_synced[key] = cur
 
     def _pump_loop(self):
-        import time as _time
-
         last_sync = 0.0
         while not self._stop:
-            now = _time.monotonic()
+            now = time.monotonic()
             if now - last_sync > 1.0:
                 last_sync = now
                 try:
-                    self._sync_engine_metrics()
+                    with tracing.span("llm.pump.sync"):
+                        self._sync_engine_metrics()
                 except Exception:
                     pass  # metrics must never kill the decode pump
             try:
-                with self._lock:
+                # a span only where the pump really waits: an idle replica
+                # takes the free lock 200 times a second
+                if not self._lock.acquire(blocking=False):
+                    with tracing.span("llm.pump.lock_wait"):
+                        self._lock.acquire()
+                try:
                     work = self.cb.has_work
                     out = self.cb.step() if work else {}
-                    delivered = []
-                    for rid, toks in out.items():
-                        q = self._queues.get(rid)
-                        req = self._reqs.get(rid)
-                        if q is not None:
-                            for t in toks:
-                                q.put(t)
-                            if req is not None and req.done:
-                                q.put(None)
-                                delivered.append(rid)
-                    for rid in delivered:
-                        self._reqs.pop(rid, None)
+                    if out:
+                        self._deliver(out)
+                finally:
+                    # held on both paths above; the linter's flow analysis
+                    # does not follow a non-blocking acquire's result:
+                    # ca-lint: ignore[res-double-release]
+                    self._lock.release()
             except BaseException as e:
                 # engine failure (device OOM, shape bug): without this the
                 # pump dies silently and every request blocks to the queue
@@ -258,30 +277,53 @@ class ContinuousLLMServer:
                     self._reqs.clear()
                 return
             if not work:
-                _time.sleep(0.005)
+                time.sleep(0.005)
+
+    def _deliver(self, out: Dict[int, list]) -> None:
+        """Put a step's tokens on their requests' queues (under the lock)."""
+        with tracing.span("llm.pump.deliver", tokens=sum(map(len, out.values()))):
+            delivered = []
+            for rid, toks in out.items():
+                q = self._queues.get(rid)
+                req = self._reqs.get(rid)
+                if q is not None:
+                    for t in toks:
+                        q.put(t)
+                    if req is not None and req.done:
+                        q.put(None)
+                        delivered.append(rid)
+            for rid in delivered:
+                self._reqs.pop(rid, None)
 
     def _submit(self, body) -> tuple:
-        prompt = body.get("prompt", "")
-        ids = self.tok.encode(prompt)[: self.config.max_prompt_len]
-        mnt = int(body.get("max_new_tokens", self.config.max_new_tokens))
-        temp = float(body.get("temperature", self.config.temperature))
-        top_k = body.get("top_k")
-        top_p = float(body.get("top_p", 1.0))
-        q = self._queue_cls()
-        with self._lock:
-            if self._engine_error is not None:
-                raise RuntimeError(
-                    f"LLM engine pump died: {self._engine_error!r}"
-                ) from self._engine_error
-            # queue registered under the same lock as submit: the pump's
-            # next step (admit + decode) finds it before any token flows
-            req = self.cb.submit(
-                ids, max_new_tokens=mnt, temperature=temp,
-                top_k=None if top_k is None else int(top_k),
-                top_p=top_p,
-            )
-            self._queues[req.request_id] = q
-            self._reqs[req.request_id] = req
+        with tracing.span("llm.submit"):
+            prompt = body.get("prompt", "")
+            ids = self.tok.encode(prompt)[: self.config.max_prompt_len]
+            mnt = int(body.get("max_new_tokens", self.config.max_new_tokens))
+            temp = float(body.get("temperature", self.config.temperature))
+            top_k = body.get("top_k")
+            top_p = float(body.get("top_p", 1.0))
+            q = self._queue_cls()
+            t0 = time.monotonic()
+            with tracing.span("llm.submit.lock_wait"):
+                self._lock.acquire()
+            try:
+                self.cb.stats["lock_wait_s"] += time.monotonic() - t0
+                if self._engine_error is not None:
+                    raise RuntimeError(
+                        f"LLM engine pump died: {self._engine_error!r}"
+                    ) from self._engine_error
+                # queue registered under the same lock as submit: the pump's
+                # next step (admit + decode) finds it before any token flows
+                req = self.cb.submit(
+                    ids, max_new_tokens=mnt, temperature=temp,
+                    top_k=None if top_k is None else int(top_k),
+                    top_p=top_p,
+                )
+                self._queues[req.request_id] = q
+                self._reqs[req.request_id] = req
+            finally:
+                self._lock.release()
         return prompt, req, q
 
     def _forget(self, req):

@@ -8,6 +8,13 @@ Cache layout: one stacked pytree over layers —
 Decode steps write slot `pos` with `lax.dynamic_update_slice` and attend over
 the full T_max with a position mask (static shapes; no recompilation per
 step).
+
+Every stage runs under a `jax.named_scope` with the same name in every layer
+and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
+`attn.core`, `attn.out`, `ffn`, `head`, `sample`): the names reach each
+operation's metadata, so a device trace sums a kind of work over the depth
+whatever the compiler numbers its operations.  Metadata only: the programs
+compile to the same instructions with or without them.
 """
 
 from __future__ import annotations
@@ -40,11 +47,13 @@ def _gqa_repeat(x, cfg: TransformerConfig):
 
 def _mlp(bp, x, cfg):
     dt = x.dtype
-    y = _rms_norm(x, bp["ln2"])
-    if cfg.n_experts:
-        return x + _moe_infer(bp, y, cfg)
-    gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-    return x + gated @ bp["w_down"].astype(dt)
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln2"])
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            return x + _moe_infer(bp, y, cfg)
+        gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
+        return x + gated @ bp["w_down"].astype(dt)
 
 
 _MOE_CHUNK = 64  # prefill tokens per all-experts pass (bounds [B,c,X,F])
@@ -114,20 +123,26 @@ def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None):
     pad: [B] left-pad counts — the RoPE position of the token written at cache
     slot `pos` is `pos - pad[b]` so each row's positions count real tokens."""
     k_cache, v_cache = layer_cache
-    y = _rms_norm(x, bp["ln1"])
-    q, k, v = _project_qkv(bp, y, cfg)
-    if pad is None:
-        positions = jnp.array([0]) + pos  # [1]
-    else:
-        positions = (pos - pad)[:, None]  # [B, 1]
-    q, k = _rope(q, k, positions, cfg)
-    k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-    v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
-    attn = _masked_attention(
-        q, _gqa_repeat(k_cache, cfg), _gqa_repeat(v_cache, cfg), pos + 1, cfg, pad
-    )
-    b = x.shape[0]
-    x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(bp, y, cfg)
+    with jax.named_scope("attn.rope"):
+        if pad is None:
+            positions = jnp.array([0]) + pos  # [1]
+        else:
+            positions = (pos - pad)[:, None]  # [B, 1]
+        q, k = _rope(q, k, positions, cfg)
+    with jax.named_scope("attn.cache"):
+        k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
+        v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
+    with jax.named_scope("attn.core"):
+        attn = _masked_attention(
+            q, _gqa_repeat(k_cache, cfg), _gqa_repeat(v_cache, cfg), pos + 1, cfg, pad
+        )
+    with jax.named_scope("attn.out"):
+        b = x.shape[0]
+        x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
     return _mlp(bp, x, cfg), (k_cache, v_cache)
 
 
@@ -138,23 +153,29 @@ def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads):
     pos[b], takes RoPE position pos[b] - pads[b], and attends to cache
     slots [pads[b], pos[b]]."""
     k_cache, v_cache = layer_cache
-    y = _rms_norm(x, bp["ln1"])
-    q, k, v = _project_qkv(bp, y, cfg)
-    positions = (pos - pads)[:, None]  # [B, 1]
-    q, k = _rope(q, k, positions, cfg)
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(bp, y, cfg)
+    with jax.named_scope("attn.rope"):
+        positions = (pos - pads)[:, None]  # [B, 1]
+        q, k = _rope(q, k, positions, cfg)
     b = x.shape[0]
-    rows = jnp.arange(b)
-    k_cache = k_cache.at[rows, pos].set(k[:, 0])
-    v_cache = v_cache.at[rows, pos].set(v[:, 0])
-    attn = _masked_attention(
-        q,
-        _gqa_repeat(k_cache, cfg),
-        _gqa_repeat(v_cache, cfg),
-        (pos + 1)[:, None, None, None],  # per-row valid length
-        cfg,
-        pads,
-    )
-    x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
+    with jax.named_scope("attn.cache"):
+        rows = jnp.arange(b)
+        k_cache = k_cache.at[rows, pos].set(k[:, 0])
+        v_cache = v_cache.at[rows, pos].set(v[:, 0])
+    with jax.named_scope("attn.core"):
+        attn = _masked_attention(
+            q,
+            _gqa_repeat(k_cache, cfg),
+            _gqa_repeat(v_cache, cfg),
+            (pos + 1)[:, None, None, None],  # per-row valid length
+            cfg,
+            pads,
+        )
+    with jax.named_scope("attn.out"):
+        x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
     return _mlp(bp, x, cfg), (k_cache, v_cache)
 
 
@@ -164,34 +185,41 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int):
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
     pad-token keys (ADVICE r1: unmasked pads skewed generation)."""
     b, t, _ = x.shape
-    y = _rms_norm(x, bp["ln1"])
-    q, k, v = _project_qkv(bp, y, cfg)
-    if pad is None:
-        positions = jnp.arange(t)
-    else:
-        positions = jnp.maximum(jnp.arange(t)[None, :] - pad[:, None], 0)  # [B,T]
-    q, k = _rope(q, k, positions, cfg)
-    k_cache = jnp.zeros((b, t_max, cfg.n_kv_heads, cfg.d_head), x.dtype)
-    v_cache = jnp.zeros_like(k_cache)
-    k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
-    v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _project_qkv(bp, y, cfg)
+    with jax.named_scope("attn.rope"):
+        if pad is None:
+            positions = jnp.arange(t)
+        else:
+            positions = jnp.maximum(jnp.arange(t)[None, :] - pad[:, None], 0)  # [B,T]
+        q, k = _rope(q, k, positions, cfg)
+    with jax.named_scope("attn.cache"):
+        k_cache = jnp.zeros((b, t_max, cfg.n_kv_heads, cfg.d_head), x.dtype)
+        v_cache = jnp.zeros_like(k_cache)
+        k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
+        v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
     # causal attention within the prompt (q already has full heads; only
     # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
     # Pallas flash kernel at every prompt length (ops/attention.py), so
     # prefill never materializes the [T, T] score matrix.
     from ..ops.attention import attention as _attn
 
-    kr = _gqa_repeat(k, cfg)
-    vr = _gqa_repeat(v, cfg)
-    attn = _attn(q, kr, vr, causal=True, pad=pad).reshape(b, t, -1).astype(x.dtype)
-    x = x + attn @ bp["wo"].astype(x.dtype)
+    with jax.named_scope("attn.core"):
+        kr = _gqa_repeat(k, cfg)
+        vr = _gqa_repeat(v, cfg)
+        attn = _attn(q, kr, vr, causal=True, pad=pad).reshape(b, t, -1).astype(x.dtype)
+    with jax.named_scope("attn.out"):
+        x = x + attn @ bp["wo"].astype(x.dtype)
     return _mlp(bp, x, cfg), (k_cache, v_cache)
 
 
 def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     """ids: [B, T_prompt] -> (last-token logits [B, V], cache).
     pad: optional [B] left-pad counts (see _prefill_block)."""
-    x = params["embed"].astype(cfg.dtype)[ids]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[ids]
 
     def body(x, bp):
         x, (kc, vc) = _prefill_block(bp, x, pad, cfg, t_max)
@@ -199,14 +227,17 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
 
     blocks = params["blocks"]
     x, (k_all, v_all) = lax.scan(body, x, blocks)
-    x = _rms_norm(x, params["ln_f"])
-    logits = x[:, -1] @ params["lm_head"].astype(cfg.dtype)
-    return logits.astype(jnp.float32), {"k": k_all, "v": v_all}
+    with jax.named_scope("norm"):
+        x = _rms_norm(x, params["ln_f"])
+    with jax.named_scope("head"):
+        logits = (x[:, -1] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    return logits, {"k": k_all, "v": v_all}
 
 
 def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
     """token: [B] -> (logits [B, V], updated cache)."""
-    x = params["embed"].astype(cfg.dtype)[token][:, None, :]  # [B,1,E]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[token][:, None, :]  # [B,1,E]
 
     def body(x, inputs):
         bp, kc, vc = inputs
@@ -214,8 +245,10 @@ def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
         return x, (kc, vc)
 
     x, (k_all, v_all) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _rms_norm(x, params["ln_f"])
-    logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    with jax.named_scope("norm"):
+        x = _rms_norm(x, params["ln_f"])
+    with jax.named_scope("head"):
+        logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
     return logits, {"k": k_all, "v": v_all}
 
 
@@ -238,20 +271,21 @@ def _sample(logits, rng, temperature, top_k: int, top_p=1.0):
     """temperature/top_p are traced (no recompile per request value); top_k
     stays static (lax.top_k needs a static k). temperature <= 0 means
     greedy; top_p in (0, 1) applies nucleus truncation."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    t = jnp.maximum(temperature, 1e-6)
-    scaled = logits / t
-    if top_k > 0:
-        top = lax.top_k(scaled, top_k)[0][..., -1:]
-        scaled = jnp.where(scaled < top, -1e30, scaled)
-    # statically skip a guaranteed no-op mask (python-float defaults): the
-    # nucleus pass costs a full-vocab softmax+sort per step.  Traced top_p
-    # (streaming/continuous paths) always runs it — the mask itself gates
-    # on (0, 1) membership.
-    if not (isinstance(top_p, (int, float)) and not (0.0 < float(top_p) < 1.0)):
-        scaled = _nucleus_mask(scaled, top_p)
-    sampled = jax.random.categorical(rng, scaled).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        t = jnp.maximum(temperature, 1e-6)
+        scaled = logits / t
+        if top_k > 0:
+            top = lax.top_k(scaled, top_k)[0][..., -1:]
+            scaled = jnp.where(scaled < top, -1e30, scaled)
+        # statically skip a guaranteed no-op mask (python-float defaults): the
+        # nucleus pass costs a full-vocab softmax+sort per step.  Traced top_p
+        # (streaming/continuous paths) always runs it — the mask itself gates
+        # on (0, 1) membership.
+        if not (isinstance(top_p, (int, float)) and not (0.0 < float(top_p) < 1.0)):
+            scaled = _nucleus_mask(scaled, top_p)
+        sampled = jax.random.categorical(rng, scaled).astype(jnp.int32)
+        return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "max_new_tokens", "top_k", "top_p"))

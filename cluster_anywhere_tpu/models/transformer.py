@@ -270,44 +270,54 @@ def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     dt = x.dtype
 
-    y = _rms_norm(x, bp["ln1"])
-    q = (y @ bp["wq"].astype(dt)).reshape(b, t, h, d)
-    k = (y @ bp["wk"].astype(dt)).reshape(b, t, kv, d)
-    v = (y @ bp["wv"].astype(dt)).reshape(b, t, kv, d)
+    # the scope names of models/generate.py, the same in every layer: a
+    # device trace sums a kind of work over the depth (forward, recomputation
+    # and backward carry the name; metadata only)
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        q = (y @ bp["wq"].astype(dt)).reshape(b, t, h, d)
+        k = (y @ bp["wk"].astype(dt)).reshape(b, t, kv, d)
+        v = (y @ bp["wv"].astype(dt)).reshape(b, t, kv, d)
 
-    if "sp" in manual_axes and cfg.sp > 1:
-        offset = lax.axis_index("sp") * t
-    else:
-        offset = 0
-    positions = offset + jnp.arange(t)
-    q, k = _rope(q, k, positions, cfg)
+    with jax.named_scope("attn.rope"):
+        if "sp" in manual_axes and cfg.sp > 1:
+            offset = lax.axis_index("sp") * t
+        else:
+            offset = 0
+        positions = offset + jnp.arange(t)
+        q, k = _rope(q, k, positions, cfg)
 
-    if kv != h:  # GQA: repeat kv heads
-        rep = h // kv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    with jax.named_scope("attn.core"):
+        if kv != h:  # GQA: repeat kv heads
+            rep = h // kv
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
 
-    attn = _attention(q, k, v, cfg, mesh, manual_axes).reshape(b, t, h * d)
-    x = x + attn @ bp["wo"].astype(dt)
+        attn = _attention(q, k, v, cfg, mesh, manual_axes).reshape(b, t, h * d)
+    with jax.named_scope("attn.out"):
+        x = x + attn @ bp["wo"].astype(dt)
 
-    y = _rms_norm(x, bp["ln2"])
-    if cfg.n_experts:
-        # MoE FFN: tokens flatten, route to experts over 'ep', come back
-        # (only traced under shard_map manual over 'ep' — see forward())
-        from ..parallel.moe import moe_ffn
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln2"])
+    with jax.named_scope("ffn"):
+        if cfg.n_experts:
+            # MoE FFN: tokens flatten, route to experts over 'ep', come back
+            # (only traced under shard_map manual over 'ep' — see forward())
+            from ..parallel.moe import moe_ffn
 
-        r = moe_ffn(
-            y.reshape(b * t, e),
-            bp["router"].astype(dt),
-            bp["w_in"].astype(dt),
-            bp["w_out"].astype(dt),
-            axis_name="ep",
-            capacity_factor=cfg.capacity_factor,
-        )
-        x = x + r.out.reshape(b, t, e)
-        return x, r.aux_loss.astype(jnp.float32)
-    gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-    x = x + gated @ bp["w_down"].astype(dt)
+            r = moe_ffn(
+                y.reshape(b * t, e),
+                bp["router"].astype(dt),
+                bp["w_in"].astype(dt),
+                bp["w_out"].astype(dt),
+                axis_name="ep",
+                capacity_factor=cfg.capacity_factor,
+            )
+            x = x + r.out.reshape(b, t, e)
+            return x, r.aux_loss.astype(jnp.float32)
+        gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
+        x = x + gated @ bp["w_down"].astype(dt)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -337,7 +347,8 @@ def _stage_forward(stage_blocks, x, cfg: TransformerConfig, mesh=None, manual_ax
 def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = False):
     """ids: [B, T] int32 -> logits [B, T, V] (with the MoE load-balance aux
     loss when return_aux; 0 for dense configs)."""
-    x = params["embed"].astype(cfg.dtype)[ids]  # [B, T, E]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[ids]  # [B, T, E]
     manual_axes = set()
     if cfg.pp > 1:
         manual_axes.add("pp")
@@ -375,8 +386,10 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
             )
         x, aux = _stage_forward(params["blocks"], x, cfg, mesh)
 
-    x = _rms_norm(x, params["ln_f"])
-    logits = x @ params["lm_head"].astype(cfg.dtype)
+    with jax.named_scope("norm"):
+        x = _rms_norm(x, params["ln_f"])
+    with jax.named_scope("head"):
+        logits = x @ params["lm_head"].astype(cfg.dtype)
     return (logits, aux) if return_aux else logits
 
 
@@ -472,9 +485,10 @@ def make_loss_fn(cfg: TransformerConfig, mesh=None):
     def loss_fn(params, batch):
         ids = batch["ids"]  # [B, T+1]
         logits, aux = forward(params, ids[:, :-1], cfg, mesh, return_aux=True)
-        loss = cross_entropy_loss(logits, ids[:, 1:])
-        if cfg.n_experts:
-            loss = loss + cfg.moe_aux_weight * aux
+        with jax.named_scope("loss"):
+            loss = cross_entropy_loss(logits, ids[:, 1:])
+            if cfg.n_experts:
+                loss = loss + cfg.moe_aux_weight * aux
         return loss
 
     return loss_fn
@@ -491,8 +505,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None, learning_rate=
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     def init_state(key):

@@ -319,6 +319,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",  # the kernel's name in a device trace
     )(*args)
     return _from_bhtd(out, b, h), lse.reshape(b, h, t)
 
@@ -356,6 +357,7 @@ def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpr
         out_specs=pl.BlockSpec((None, block_q, d), lambda bi, qi: (bi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lsef, delta, *pad_arg)
 
     dk, dv = pl.pallas_call(
@@ -380,6 +382,7 @@ def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpr
             jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lsef, delta, *pad_arg)
     return _from_bhtd(dq, b, h), _from_bhtd(dk, b, h), _from_bhtd(dv, b, h)
 

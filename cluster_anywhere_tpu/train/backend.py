@@ -51,6 +51,9 @@ def ensure_cpu_collectives():
 def _init_jax_distributed(coordinator: str, num_processes: int, process_id: int):
     import jax
 
+    from ..util import tracing
+
+    tracing.enable_jax_profiling()  # the worker has jax loaded from here on
     ensure_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address=coordinator,

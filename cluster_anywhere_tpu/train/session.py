@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import os
 import shutil
+import sys
 import threading
 import time
 import uuid
@@ -134,6 +135,10 @@ class _Session:
                 rank=self.context.world_rank, seq=entry["seq"],
                 attempt=getattr(self.context, "attempt", None),
             )
+        if "jax" in sys.modules:
+            # a JAX loop has imported it by its first report: from here its
+            # compilations and device memory reach the cluster's metrics
+            _tracing.enable_jax_profiling()
         now = time.time()
         tr = _tracing.current()
         if tr is not None or _tracing.is_enabled():

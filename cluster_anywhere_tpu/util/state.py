@@ -484,6 +484,12 @@ _PHASE_ORDER = {
     "SUBMITTED": 0, "QUEUED": 1, "SCHEDULED": 2, "RUNNING": 3,
     "FINISHED": 4, "FAILED": 4,
 }
+# what tracing.record_task_event writes into every SPAN event; any other key
+# is an attribute the span was given
+_SPAN_EVENT_FIELDS = frozenset(
+    ("task_id", "name", "type", "state", "ts", "trace", "worker_id", "node_id",
+     "start", "end")
+)
 
 
 def _event_ts(e: Dict[str, Any]) -> float:
@@ -520,7 +526,7 @@ def timeline(
     enabled, the driver-side lifecycle phases (submit → queued → scheduled)
     appear as slices on the submitting process with `s`→`f` flow arrows
     connecting the submit span to the execute span across processes, and
-    `tracing.span()` / jax-compile app spans render as nested slices.  All
+    `tracing.span()` app spans render as nested slices.  All
     durations are microseconds; `ts` is wall-clock.  The output is a bare
     event array — loadable by chrome://tracing and Perfetto alike."""
     raw = _head("list_task_events", limit=limit)["events"]
@@ -629,7 +635,7 @@ def timeline(
                  "pid": exec_pid, "tid": 1}
             )
 
-    # app spans (tracing.span blocks, jax compile spans)
+    # app spans (tracing.span blocks)
     for e in spans:
         if e.get("start") is None or e.get("end") is None:
             continue
@@ -644,7 +650,11 @@ def timeline(
                 "dur": max((e["end"] - e["start"]) * 1e6, 1.0),
                 "pid": pid,
                 "tid": lane,
-                "args": {"trace": e.get("trace"), "node_id": e.get("node_id")},
+                "args": {
+                    "trace": e.get("trace"), "node_id": e.get("node_id"),
+                    # the span's own attributes (tracing.span(name, **attrs))
+                    **{k: v for k, v in e.items() if k not in _SPAN_EVENT_FIELDS},
+                },
             }
         )
 

@@ -25,18 +25,26 @@ Three planes, one buffer:
 
 * **Export.**  `util/state.timeline()` / `ca timeline` assemble the ring
   into Chrome-trace/Perfetto JSON with causal flow arrows between the
-  submit and execute spans; `span("name")` records nested app spans into
-  the same buffer (and a `ca_trace_span_seconds` histogram).
+  submit and execute spans; `span("name", **attrs)` records nested app
+  spans into the same buffer (and a `ca_trace_span_seconds` histogram).
 
-JAX hooks: `enable_jax_profiling()` (called automatically by `enable()`
-when jax is already imported) observes backend compile durations into a
-`ca_jax_compile_seconds` histogram + SPAN events, and samples per-device
-memory into `ca_device_memory_bytes` gauges at each metrics flush.
+One span API, two sinks.  The event buffer above is on the wall clock and
+takes a span while tracing is enabled or the block runs under a trace
+context.  Where jax is loaded in the process, `span()` also enters a
+`jax.profiler.TraceAnnotation` of the same name and attributes: whenever
+anybody profiles the process, the profiler writes the span into its own
+trace beside the device's operations, on one clock (a flag test while no
+profiler session runs).
+
+JAX hooks: `enable_jax_profiling()` (called by `enable()` when jax is
+already imported, by the LLM engine and by the train backend once they
+have imported it) counts backend compilations into `ca_jax_compiles_total`
+and a `ca_jax_compile_seconds` histogram, and samples per-device memory
+into `ca_device_memory_bytes` gauges at each metrics flush.
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import os
 import sys
@@ -315,49 +323,92 @@ def disable():
 
 
 # -------------------------------------------------------------------- spans
-@contextlib.contextmanager
-def span(name: str):
-    """Record a custom application span.  Attaches to the ambient trace
-    context (the executing task's trace inside a worker; spans nest), lands
-    in the lifecycle event buffer for `timeline()` assembly, and observes
-    the ca_trace_span_seconds histogram.
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
-    Active when tracing is locally enabled OR the span runs inside a traced
-    execution (worker processes never call enable(); the ambient context is
-    the signal there).  An inactive span installs NO context — otherwise a
-    disabled-tracing span block would make every nested span/remote() look
-    traced and leak events onto the wire."""
-    parent = _ctx.get()
-    active = _enabled or parent is not None
-    ctx = token = None
-    if active:
-        if parent is None:
-            ctx = {"tid": new_trace_id(), "sid": new_span_id()}
-        else:
-            ctx = {"tid": parent["tid"], "sid": new_span_id(), "psid": parent["sid"]}
-        token = _ctx.set(ctx)
-    t0 = time.time()
-    p0 = time.perf_counter()
-    try:
-        yield ctx
-    finally:
-        if token is not None:
-            _ctx.reset(token)
-        dur = time.perf_counter() - p0
-        # inactive spans touch nothing — after disable() the histogram must
-        # stop mutating too, not just the event stream
-        if active and _span_hist is not None:
-            _span_hist.observe(dur, {"name": name})
-        if active:
-            w = _current_worker()
-            record_task_event(
-                "", name, "span", "SPAN",
-                trace=ctx,
-                worker_id=w.client_id if w is not None else None,
-                node_id=w.node_id if w is not None else None,
-                start=t0,
-                end=t0 + dur,
-            )
+
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` if jax is loaded in this process (never
+    imported from here: a process without jax has no profiler to write to)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        # a half-imported jax has no profiler yet: look again next time
+        _annotation_cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    return _annotation_cls
+
+
+class span:
+    """Record a custom application span: `with span("name", key=scalar):`.
+
+    Event sink: attaches to the ambient trace context (the executing task's
+    trace inside a worker; spans nest), lands in the lifecycle event buffer
+    for `timeline()` assembly with `attrs` as extra keys, and observes the
+    ca_trace_span_seconds histogram.  Active when tracing is locally enabled
+    OR the span runs inside a traced execution (worker processes never call
+    enable(); the ambient context is the signal there).  An inactive span
+    installs NO context — otherwise a disabled-tracing span block would make
+    every nested span/remote() look traced and leak events onto the wire —
+    and takes no lock and touches no histogram or buffer.
+
+    Profiler sink: where jax is loaded, the block is also a
+    `jax.profiler.TraceAnnotation(name, **attrs)`, which the profiler writes
+    beside the device's operations while a profiler session runs and which
+    costs a flag test otherwise.
+
+    `attrs` are small scalars whose names are no field of a SPAN event
+    (name, type, state, ts, trace, start, end, worker_id, node_id);
+    `set(**attrs)` adds what is known only inside the block.  `with ... as
+    ctx` gives the span's trace context, None while the event sink is off."""
+
+    __slots__ = ("name", "attrs", "ctx", "_token", "_annotation", "_t0", "_p0")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.ctx = self._token = self._annotation = None
+
+    def set(self, **attrs: Any) -> None:
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+        if self.ctx is not None:
+            self.attrs.update(attrs)
+
+    def __enter__(self) -> Optional[Dict[str, str]]:
+        parent = _ctx.get()
+        if _enabled or parent is not None:
+            if parent is None:
+                self.ctx = {"tid": new_trace_id(), "sid": new_span_id()}
+            else:
+                self.ctx = {"tid": parent["tid"], "sid": new_span_id(), "psid": parent["sid"]}
+            self._token = _ctx.set(self.ctx)
+            self._t0 = time.time()
+            self._p0 = time.perf_counter()
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name, **self.attrs)
+            self._annotation.__enter__()
+        return self.ctx
+
+    def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        if self.ctx is None:
+            return False
+        dur = time.perf_counter() - self._p0
+        _ctx.reset(self._token)
+        # after disable() the histogram must stop mutating too, not just the
+        # event stream: _span_hist exists only once enable() has run
+        if _span_hist is not None:
+            _span_hist.observe(dur, {"name": self.name})
+        w = _current_worker()
+        record_task_event(
+            "", self.name, "span", "SPAN",
+            trace=self.ctx,
+            worker_id=w.client_id if w is not None else None,
+            node_id=w.node_id if w is not None else None,
+            **{**self.attrs, "start": self._t0, "end": self._t0 + dur},
+        )
+        return False
 
 
 # ---------------------------------------------------------------- JAX hooks
@@ -365,12 +416,15 @@ _jax_hooked = False
 
 
 def enable_jax_profiling() -> bool:
-    """Surface device-side cost in the same pipeline: a
-    `ca_jax_compile_seconds` histogram (+ SPAN timeline events while tracing
-    is enabled) fed by jax.monitoring's compile-duration events, and
-    `ca_device_memory_bytes` gauges sampled at each metrics flush.  Returns
-    False when jax (or its monitoring API) is unavailable — callers treat
-    that as "nothing to profile", never an error."""
+    """Surface device-side cost in the same pipeline: a `ca_jax_compiles_total`
+    counter and a `ca_jax_compile_seconds` histogram fed by jax.monitoring's
+    backend-compile events (a program that was not in this process yet:
+    compiled, or fetched from the persistent cache), and
+    `ca_device_memory_bytes` gauges sampled at each metrics flush.  The
+    engine and the train backend call it once they have imported jax; a
+    profiler session has the compilations themselves on its own clock.
+    Returns False when jax (or its monitoring API) is unavailable — callers
+    treat that as "nothing to profile", never an error."""
     global _jax_hooked
     if _jax_hooked:
         return True
@@ -385,24 +439,20 @@ def enable_jax_profiling() -> bool:
         "jit/pjit backend compilation time",
         tag_keys=("event",),
     )
+    compile_count = metrics.Counter(
+        "ca_jax_compiles_total",
+        "programs this process compiled or fetched from the persistent cache",
+    )
 
     def _on_duration(event: str, duration: float, **kw):
         if "compile" not in event:
             return
         try:
             compile_hist.observe(duration, {"event": event})
+            if "backend_compile" in event:
+                compile_count.inc()
         except Exception:
             return
-        if _enabled:
-            w = _current_worker()
-            now = time.time()
-            record_task_event(
-                "", f"jax:{event.rsplit('/', 1)[-1]}", "jax", "SPAN",
-                worker_id=w.client_id if w is not None else None,
-                node_id=w.node_id if w is not None else None,
-                start=now - duration,
-                end=now,
-            )
 
     try:
         monitoring.register_event_duration_secs_listener(_on_duration)

@@ -177,7 +177,7 @@ def test_trace_across_batch_envelope_under_chaos(tmp_path):
     events = state.timeline(out)
     loaded = json.load(open(out))
     assert loaded and len(loaded) == len(events)
-    assert all(e.get("ph") in ("X", "M", "s", "f", "B", "E") for e in loaded)
+    assert all(e.get("ph") in ("X", "M", "s", "f", "B", "E", "i") for e in loaded)
     opens = sum(1 for e in loaded if e.get("ph") == "B")
     closes = sum(1 for e in loaded if e.get("ph") == "E")
     assert opens == closes  # every B matched (we emit self-contained X)
