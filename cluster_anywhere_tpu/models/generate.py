@@ -7,7 +7,11 @@ Cache layout: one stacked pytree over layers —
     k, v: [L, B, T_max, H_kv, D]
 Decode steps write slot `pos` with `lax.dynamic_update_slice` and attend over
 the full T_max with a position mask (static shapes; no recompilation per
-step).
+step).  The attention reads a layer's cache once, as stored: the query
+[B, 1, H, D] is grouped to [B, 1, H_kv, H // H_kv, D] and contracted with
+k, v [B, T_max, H_kv, D] in the cache's dtype with an f32 accumulator
+(`_masked_attention`).  Nothing of the cache's size is repeated to H heads
+or copied to f32; only the prefill repeats its own k, v for the flash kernel.
 
 Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
@@ -94,20 +98,27 @@ def _moe_infer(bp, y, cfg: TransformerConfig):
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
-    """q: [B, Tq, H, D]; caches: [B, T_max, H, D]; cache slots >= valid_len are
+    """q: [B, Tq, H, D]; caches: [B, T_max, KV, D] as stored, never repeated to
+    H heads and never copied to f32.  The query is viewed as [B, Tq, KV, R, D]
+    (R = H // KV query heads share one cached head; R == 1 is multi-head
+    attention) and both contractions take the cache in its own dtype with an
+    f32 accumulator.  Cache slots >= valid_len (a scalar, or a per-row [B]) are
     masked out, as are slots < pad[b] (left-padding of the prompt; pad is a
-    per-row [B] count of pad tokens, None = no padding). For decode Tq == 1."""
-    scale = cfg.d_head ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k_cache.astype(jnp.float32))
-    logits = logits * scale
-    t_max = k_cache.shape[1]
-    slots = jnp.arange(t_max)[None, None, None, :]
-    mask = slots < valid_len
+    per-row [B] count of pad tokens, None = no padding).  For decode Tq == 1.
+    Returns [B, Tq, H, D]."""
+    b, tq, h, d = q.shape
+    t_max, kv = k_cache.shape[1:3]
+    qg = q.reshape(b, tq, kv, h // kv, d)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache, preferred_element_type=jnp.float32)
+    logits = logits * cfg.d_head ** -0.5
+    slots = jnp.arange(t_max)
+    mask = slots < jnp.reshape(valid_len, (-1, 1, 1, 1, 1))
     if pad is not None:
-        mask = mask & (slots >= pad[:, None, None, None])
+        mask = mask & (slots >= pad[:, None, None, None, None])
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache, preferred_element_type=jnp.float32)
+    return out.astype(q.dtype).reshape(b, tq, h, d)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
@@ -137,9 +148,7 @@ def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None):
         k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
         v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
     with jax.named_scope("attn.core"):
-        attn = _masked_attention(
-            q, _gqa_repeat(k_cache, cfg), _gqa_repeat(v_cache, cfg), pos + 1, cfg, pad
-        )
+        attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pad)
     with jax.named_scope("attn.out"):
         b = x.shape[0]
         x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
@@ -166,14 +175,7 @@ def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads):
         k_cache = k_cache.at[rows, pos].set(k[:, 0])
         v_cache = v_cache.at[rows, pos].set(v[:, 0])
     with jax.named_scope("attn.core"):
-        attn = _masked_attention(
-            q,
-            _gqa_repeat(k_cache, cfg),
-            _gqa_repeat(v_cache, cfg),
-            (pos + 1)[:, None, None, None],  # per-row valid length
-            cfg,
-            pads,
-        )
+        attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pads)  # per-row length
     with jax.named_scope("attn.out"):
         x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
     return _mlp(bp, x, cfg), (k_cache, v_cache)

@@ -8,7 +8,9 @@ tests that go through it steer `_platform` themselves.
 """
 
 import importlib
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -107,6 +109,52 @@ def test_padded_prefill_compiles_at_flagship_width(v5e, on_tpu, bucket):
     pad = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
     fn = lambda p, i, pad: generate.prefill(p, i, cfg, bucket + 32, pad=pad)
     assert _has_kernel(jax.jit(fn).lower(params, ids, pad).compile())
+
+
+def _buffers(compiled):
+    """(dtype, elements, instruction) of every value the optimized program
+    keeps in memory: each instruction outside a fusion's body."""
+    text = compiled.as_text()
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
+    out, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            inside = head.group(1)
+        elif inside not in fused:
+            m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]", line)
+            if m:
+                elements = math.prod(int(d) for d in m.group(2).split(","))
+                out.append((m.group(1), elements, line.strip()[:100]))
+    return out
+
+
+def test_decode_block_reads_the_cache_as_stored(v5e):
+    """One decode block at the serving benchmark's widths (Mistral-7B: 32 Q /
+    8 KV heads x 128, 32 slots, t_max 768) as the chip's compiler leaves it:
+    no buffer as large as the layer's cache repeated to 32 heads, and none in
+    f32 as large as the cache.  The jaxpr guard in test_llm.py cannot see a
+    broadcast that XLA materialises in front of a dot; this does."""
+    cfg = transformer.TransformerConfig(
+        vocab_size=259, n_layers=1, d_model=4096, n_heads=32, n_kv_heads=8, d_head=128,
+        d_ff=14336, param_dtype=jnp.bfloat16,
+    )
+    slots, t_max = 32, 768
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    blocks = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))["blocks"]
+    bp = jax.tree_util.tree_map(lambda x: on_chip(x.shape[1:], x.dtype), blocks)
+    x = on_chip((slots, 1, cfg.d_model), cfg.dtype)
+    layer_cache = on_chip((slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype)
+    rows = on_chip((slots,), jnp.int32)
+    fn = lambda bp, x, k, v, pos, pads: generate._block_decode_rowpos(bp, x, (k, v), pos, cfg, pads)
+    compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(bp, x, layer_cache, layer_cache, rows, rows).compile()
+    buffers = _buffers(compiled)
+    cache = slots * t_max * cfg.n_kv_heads * cfg.d_head
+    assert sum(1 for dt, n, _ in buffers if dt == "bf16" and n == cache) >= 2  # K and V are seen
+    widened = [b for b in buffers if b[1] >= cache * cfg.n_heads // cfg.n_kv_heads
+               or (b[0] == "f32" and b[1] >= cache)]
+    assert widened == []
 
 
 @pytest.mark.parametrize(

@@ -520,29 +520,41 @@ def _instruction_count(compiled) -> int:
     )
 
 
+def _decode_step_program(cfg, slots, t_max):
+    """`_decode_step_rowpos` unjitted, and the shapes of its arguments."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.llm import continuous
+    from cluster_anywhere_tpu.models import generate, transformer
+
+    params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
+    cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), slots))
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    # a fresh function each time: jit keeps what it traced for one it has seen
+    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
+    return fn, (params, cache, i32, i32, i32, f32, i32, f32, keys)
+
+
 def _compile_program(which):
     import jax
     import jax.numpy as jnp
     import optax
 
-    from cluster_anywhere_tpu.llm import continuous
     from cluster_anywhere_tpu.models import generate, transformer
 
     cfg = transformer.TransformerConfig(
         vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_head=16,
         d_ff=128, max_seq_len=64, remat=True,
     )
-    params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     slots, t_max = 4, 32
     if which == "decode_step":
-        cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
-        keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), slots))
-        # a fresh function each time: jit keeps what it traced for one it has seen
-        fn = jax.jit(lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg))
-        return fn.lower(params, cache, i32(slots), i32(slots), i32(slots), f32(slots),
-                        i32(slots), f32(slots), keys).compile()
+        fn, args = _decode_step_program(cfg, slots, t_max)
+        return jax.jit(fn).lower(*args).compile()
+    params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     if which == "prefill":
         fn = jax.jit(lambda p, ids, pad: generate.prefill(p, ids, cfg, t_max, pad))
         return fn.lower(params, i32(1, 16), i32(1)).compile()
@@ -570,3 +582,111 @@ def test_named_scopes_are_metadata_only(which, monkeypatch):
     without = _compile_program(which)
     assert "attn.core" not in without.as_text()
     assert _instruction_count(with_scopes) == _instruction_count(without) > 100
+
+
+def _attention_reference(q, k_cache, v_cache, valid_len, pad, n_heads):
+    """Plain f32 attention over a repeated cache: the mathematics
+    `_masked_attention` must keep, written the long way."""
+    import jax.numpy as jnp
+
+    q, k, v = (x.astype(jnp.float32) for x in (q, k_cache, v_cache))
+    rep = n_heads // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)  # [B, T, H, D]
+    b, t = k.shape[:2]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    slots = jnp.arange(t)[None, :]
+    keep = slots < jnp.broadcast_to(jnp.asarray(valid_len), (b,))[:, None]
+    if pad is not None:
+        keep &= slots >= pad[:, None]
+    scores = jnp.where(keep[:, None, None, :], scores, -jnp.inf)
+    scores = scores - scores.max(axis=-1, keepdims=True)
+    probs = jnp.exp(scores)
+    probs = probs / probs.sum(axis=-1, keepdims=True)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+@pytest.mark.parametrize("rows", ["scalar_len", "per_row_len_and_pad"])
+@pytest.mark.parametrize("n_heads,n_kv_heads", [(4, 4), (4, 2), (8, 1)])
+def test_masked_attention_matches_plain_reference(n_heads, n_kv_heads, rows):
+    """The decode attention takes the cache as stored ([B, T, KV, D], bf16)
+    and gives what f32 attention over the cache repeated to every query head
+    gives, to bf16's precision; whatever sits in the masked slots (past
+    `valid_len`, before `pad`) changes nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models import generate, transformer
+
+    b, t, d = 3, 24, 16
+    cfg = transformer.TransformerConfig(
+        vocab_size=32, d_model=n_heads * d, n_layers=1, n_heads=n_heads, n_kv_heads=n_kv_heads,
+        d_head=d, d_ff=32, max_seq_len=t,
+    )
+    kq, kk, kv, kg = jax.random.split(jax.random.key(n_heads * 10 + n_kv_heads), 4)
+    q = jax.random.normal(kq, (b, 1, n_heads, d), jnp.float32).astype(jnp.bfloat16)
+    k_cache = jax.random.normal(kk, (b, t, n_kv_heads, d), jnp.float32).astype(jnp.bfloat16)
+    v_cache = jax.random.normal(kv, (b, t, n_kv_heads, d), jnp.float32).astype(jnp.bfloat16)
+    if rows == "scalar_len":
+        valid_len, pad = 9, None
+        lens, pads = np.full(b, 9), np.zeros(b, int)
+    else:
+        lens, pads = np.array([t, 7, 13]), np.array([0, 3, 12])  # the last row sees one slot
+        valid_len, pad = jnp.asarray(lens), jnp.asarray(pads)
+    slots = np.arange(t)[None, :, None, None]
+    masked = (slots >= lens[:, None, None, None]) | (slots < pads[:, None, None, None])
+    garbage = (1e4 * jax.random.normal(kg, k_cache.shape, jnp.float32)).astype(jnp.bfloat16)
+
+    out = generate._masked_attention(q, k_cache, v_cache, valid_len, cfg, pad)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    want = _attention_reference(q, k_cache, v_cache, valid_len, pad, n_heads)
+    # the probabilities are rounded to bf16 (8 bits) before they meet V, as is the output
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), atol=3e-2, rtol=2e-2)
+    dirty = generate._masked_attention(
+        q, jnp.where(masked, garbage, k_cache), jnp.where(masked, -garbage, v_cache),
+        valid_len, cfg, pad,
+    )
+    assert bool(jnp.isfinite(dirty.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(dirty, np.float32), np.asarray(out, np.float32))
+
+
+def _jaxpr_intermediates(jaxpr):
+    """Every value a jaxpr computes, those of its nested jaxprs (the layer
+    scan's body, a closed call) included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_intermediates(sub)
+
+
+@pytest.mark.parametrize("which", ["decode_step", "decode_one"])
+def test_decode_never_widens_the_cache(which):
+    """A decode program reads each layer's cache once, as stored: nothing it
+    computes is as large as that cache repeated to every query head
+    (S x T_max x n_heads x d_head), and nothing in f32 is as large as the cache
+    itself (S x T_max x n_kv_heads x d_head).  A `jnp.repeat` of K or V, or an
+    `.astype(float32)` of them, fails here on the CPU before a chip sees it."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models import generate, transformer
+
+    # one layer, so the stacked cache is one layer's; a cache larger than any weight
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, d_model=64, n_layers=1, n_heads=4, n_kv_heads=2, d_head=16,
+        d_ff=128, max_seq_len=64,
+    )
+    slots, t_max = 4, 64
+    fn, args = _decode_step_program(cfg, slots, t_max)
+    if which == "decode_one":
+        fn = lambda p, c, tok, pos, pad: generate.decode_one(p, c, tok, pos, cfg, pad)
+        args = (*args[:3], jax.ShapeDtypeStruct((), jnp.int32), args[4])
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    layer_cache = slots * t_max * cfg.n_kv_heads * cfg.d_head
+    repeated = slots * t_max * cfg.n_heads * cfg.d_head
+    values = [v.aval for v in _jaxpr_intermediates(jaxpr.jaxpr) if hasattr(v.aval, "shape")]
+    assert len(values) > 100 and any(a.size == layer_cache for a in values)
+    too_wide = [a for a in values if a.size >= repeated]
+    f32_cache = [a for a in values if a.dtype == jnp.float32 and a.size >= layer_cache]
+    assert too_wide == [] and f32_cache == [], (too_wide, f32_cache)
