@@ -259,6 +259,30 @@ def init(
     return {"session_dir": session_dir, "node_id": w.node_id, "resources": total}
 
 
+def _kill_session_processes(session_dir: str) -> None:
+    """SIGKILL every process that was started for `session_dir` and still
+    runs (the head gives each child `CA_SESSION_DIR`).  The head kills its
+    workers when it tears down; this is for what that misses: a head that
+    had to be killed itself, whose workers would linger until they give up
+    on it.  Only the user's own processes can be read, or killed."""
+    import signal
+
+    want = b"CA_SESSION_DIR=" + os.fsencode(session_dir)
+    try:
+        pids = [int(p) for p in os.listdir("/proc") if p.isdigit()]
+    except OSError:
+        return
+    for pid in pids:
+        if pid == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{pid}/environ", "rb") as f:
+                if want in f.read().split(b"\0"):
+                    os.kill(pid, signal.SIGKILL)
+        except OSError:
+            continue
+
+
 def shutdown():
     global _head_proc, _session_dir
     w = try_global_worker()
@@ -282,6 +306,8 @@ def shutdown():
         except subprocess.TimeoutExpired:
             _head_proc.kill()
             _head_proc.wait(timeout=5)
+        if _session_dir is not None:
+            _kill_session_processes(_session_dir)
         _head_proc = None
     _session_dir = None
 

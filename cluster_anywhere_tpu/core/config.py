@@ -98,6 +98,10 @@ class CAConfig:
     # --- health / failure detection ---
     health_check_period_s: float = 2.0
     health_check_failure_threshold: int = 5
+    # the same for a worker that holds accelerator chips (the larger of the
+    # two counts): it is silent while a native call holds the interpreter
+    # lock, and replacing it costs the deployment's whole set-up
+    accel_health_check_failure_threshold: int = 30
     worker_register_timeout_s: float = 30.0
     # node memory monitor (memory_monitor.h analogue): kill a worker when
     # node used/total exceeds the threshold; 0 disables the monitor

@@ -2144,6 +2144,14 @@ class Head:
         client_state = self._clients.get(rec.worker_id)
         if client_state is not None:
             fence_close(client_state["writer"])
+        if rec.node_id == LOCAL_NODE:
+            # the head's own child: no partition lies between them, so the
+            # verdict is carried out here and does not wait for the process
+            # to read it.  Its chips go to a successor below, and a chip
+            # belongs to one process; a process that is silent because it
+            # stands still reads no closed socket and would outlive the
+            # session (teardown skips the dead).
+            self._kill_worker_rec(rec)
         node = self.nodes.get(rec.node_id)
         if node is not None:
             try:
@@ -3474,6 +3482,17 @@ class Head:
         if rec is not None:
             self._kill_worker_rec(rec)
         reply()
+
+    def _silence_threshold(self, rec: WorkerRec) -> int:
+        """Missed health-check periods after which a worker is taken for
+        dead.  A worker that holds chips gets longer: backend start-up, a
+        compile or a transfer can hold the interpreter lock, and with it the
+        heartbeat, for ten seconds, and its successor pays the whole set-up
+        (weights, compiles) again."""
+        limit = self.config.health_check_failure_threshold
+        if rec.pool != "cpu":
+            limit = max(limit, self.config.accel_health_check_failure_threshold)
+        return limit
 
     def _kill_worker_rec(self, rec: WorkerRec):
         if rec.proc is not None and rec.proc.poll() is None:
@@ -5046,9 +5065,24 @@ class Head:
         period = self.config.health_check_period_s
         from ..util import flightrec as _flightrec
 
+        tick = min(period, 0.2)
+        last_tick = time.monotonic()
         while not self._shutdown.is_set():
-            await asyncio.sleep(min(period, 0.2))
+            await asyncio.sleep(tick)
             now = time.monotonic()
+            deaf_s, last_tick = now - last_tick - tick, now
+            if deaf_s > 1.0:
+                # the head itself stood still (its loop was held, or its whole
+                # machine: a TPU backend starting up freezes a small host for
+                # ten seconds): the beats sent meanwhile wait unread in its
+                # sockets.  Silence counts only while the head listened, and
+                # the verdicts wait one tick, for what has arrived to be read.
+                for rec in self.workers.values():
+                    rec.last_heartbeat += deaf_s
+                for node in self.nodes.values():
+                    node.last_heartbeat += deaf_s
+                self._log_event("head_deaf", seconds=round(deaf_s, 3))
+                continue
             if self._flightrec_on and _flightrec.REC is not None:
                 # head-process recorder (netchaos and other shared code
                 # running here) drains straight into the merged ring — the
@@ -5104,8 +5138,7 @@ class Head:
                         pass
                 if (
                     rec.state != "starting"
-                    and now - rec.last_heartbeat
-                    > period * self.config.health_check_failure_threshold
+                    and now - rec.last_heartbeat > period * self._silence_threshold(rec)
                 ):
                     await self._on_worker_death(rec)
             for node in list(self.nodes.values()):
@@ -5369,7 +5402,7 @@ class Head:
                     pass
         for rec in self.workers.values():
             if rec.state == "dead":
-                continue
+                continue  # killed when it was declared (_on_worker_death)
             if rec.proc is not None and rec.proc.poll() is None or (
                 rec.proc is None and rec.node_id == LOCAL_NODE and rec.pid
             ):

@@ -25,7 +25,11 @@ from .ids import ObjectID
 class ReferenceCounter:
     def __init__(self, flush_cb: Optional[Callable[[List[bytes], List[bytes]], None]] = None):
         self._counts: Dict[ObjectID, int] = {}
-        self._lock = threading.Lock()
+        # re-entrant: the collector can run an ObjectRef's __del__ (which
+        # comes back into remove_local_ref) on the thread that is inside
+        # add_local_ref, at its `__hash__` call; with a plain lock that
+        # thread then waits for itself for ever
+        self._lock = threading.RLock()
         self._pending_inc: List[bytes] = []
         self._pending_dec: List[bytes] = []
         self._flush_cb = flush_cb

@@ -43,6 +43,7 @@ from ..models.generate import (
     _nucleus_mask,
     _rms_norm,
     _sample,
+    _scan_blocks,
     decode_one,
     prefill,
 )
@@ -96,26 +97,33 @@ def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _decode_step_rowpos(params, cache, tokens, pos, pads, temps, top_ks, top_ps, rngs, *, cfg):
+def _decode_step_rowpos(
+    params, cache, tokens, pos, pads, temps, top_ks, top_ps, rngs, live=None, *, cfg
+):
     """One token for every slot with PER-ROW cache positions.
-    tokens/pos/pads/temps/top_ks: [S]; rngs: [S] keys.  Returns
-    (next_tokens [S], cache).  The cache is donated: decode rewrites it in
-    place instead of copying [L,S,Tmax,KV,D] x2 per token."""
+    tokens/pos/pads/temps/top_ks: [S]; rngs: [S] keys; live: [S] bool, the
+    slots that hold a request (a mixture of experts gives the other rows no
+    expert; a dense model is not told: None).  Returns (next_tokens [S],
+    cache, experts touched): the last is the mean over the layers of the
+    experts that were given a row, None for a dense model.  The cache is
+    donated: decode rewrites it in place instead of copying [L,S,Tmax,KV,D]
+    x2 per token."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [S,1,E]
 
-    def body(x, inputs):
-        bp, kc, vc = inputs
-        x, (kc, vc) = _block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads)
-        return x, (kc, vc)
+    def body(x, bp, experts, kc, vc):
+        x, (kc, vc), touched = _block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads, live, experts)
+        return x, (kc, vc, touched)
 
-    x, (k_all, v_all) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+    x, (k_all, v_all, touched) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
+    if touched is not None:
+        touched = jnp.mean(touched.astype(jnp.float32))
     with jax.named_scope("norm"):
         x = _rms_norm(x, params["ln_f"])
     with jax.named_scope("head"):
         logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
     nxt = _sample_rowwise(logits, rngs, temps, top_ks, top_ps)
-    return nxt, {"k": k_all, "v": v_all}
+    return nxt, {"k": k_all, "v": v_all}, touched
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -254,6 +262,9 @@ class ContinuousBatcher:
             # counted where the spans are: requests queued, tokens handed
             # out, and cumulative seconds queued and in admit
             "submitted": 0, "tokens_out": 0, "queue_wait_s": 0.0, "admit_s": 0.0,
+            # (token, expert) pairs a layer's routed experts were given, in
+            # admits and steps; stays 0 for a dense model
+            "moe_assignments": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -329,12 +340,19 @@ class ContinuousBatcher:
                     jnp.asarray(self._topps),
                     jnp.stack(keys),
                 )
+                if self.cfg.n_experts:
+                    mask = np.zeros(self.slots, bool)
+                    mask[live] = True
+                    inputs += (jnp.asarray(mask),)
             with tracing.span("llm.step.dispatch"):
-                nxt, self.cache = _decode_step_rowpos(
+                nxt, self.cache, touched = _decode_step_rowpos(
                     self.params, self.cache, *inputs, cfg=self.cfg
                 )
             with tracing.span("llm.step.readback"):
-                nxt = np.asarray(nxt)
+                nxt, touched = jax.device_get((nxt, touched))
+            if touched is not None:
+                sp.set(moe_rows=len(live), moe_experts_touched=float(touched))
+                self.stats["moe_assignments"] += len(live) * self.cfg.n_experts_per_tok
             self.stats["decode_steps"] += 1
             self.stats["tokens_out"] += len(live)
             with tracing.span("llm.step.scatter"):
@@ -509,6 +527,10 @@ class ContinuousBatcher:
             self._topps[slot] = req.top_p
             self.stats["admitted"] += 1
             self.stats["tokens_out"] += 1
+            if self.cfg.n_experts:
+                assignments = len(req.prompt_ids) * self.cfg.n_experts_per_tok
+                sp.set(moe_assignments=assignments)
+                self.stats["moe_assignments"] += assignments
             if len(req.out_tokens) >= req.max_new_tokens or (
                 req.eos_id is not None and first == req.eos_id
             ):

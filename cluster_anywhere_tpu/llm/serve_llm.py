@@ -220,6 +220,8 @@ class ContinuousLLMServer:
                  "seconds the decode pump spent admitting requests"),
                 ("lock_wait_s", "ca_serve_lock_wait_seconds_total",
                  "seconds submitting callers waited for the replica's lock"),
+                ("moe_assignments", "ca_serve_moe_assignments_total",
+                 "(token, expert) pairs a layer's routed experts were given"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

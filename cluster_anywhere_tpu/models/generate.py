@@ -15,7 +15,9 @@ or copied to f32; only the prefill repeats its own k, v for the flash kernel.
 
 Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
-`attn.core`, `attn.out`, `ffn`, `head`, `sample`): the names reach each
+`attn.core`, `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
+`moe.router`, `moe.dispatch`, `moe.experts`, `moe.combine` under `ffn`,
+parallel/moe.py): the names reach each
 operation's metadata, so a device trace sums a kind of work over the depth
 whatever the compiler numbers its operations.  Metadata only: the programs
 compile to the same instructions with or without them.
@@ -30,17 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .transformer import TransformerConfig, _rms_norm, _rope
-
-
-def _project_qkv(bp, y, cfg: TransformerConfig):
-    b, t, _ = y.shape
-    h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    dt = y.dtype
-    q = (y @ bp["wq"].astype(dt)).reshape(b, t, h, d)
-    k = (y @ bp["wk"].astype(dt)).reshape(b, t, kv, d)
-    v = (y @ bp["wv"].astype(dt)).reshape(b, t, kv, d)
-    return q, k, v
+from ..parallel.moe import EXPERT_MATRICES
+from .transformer import TransformerConfig, _moe, _project_qkv, _rms_norm, _rope
 
 
 def _gqa_repeat(x, cfg: TransformerConfig):
@@ -49,52 +42,44 @@ def _gqa_repeat(x, cfg: TransformerConfig):
     return x
 
 
-def _mlp(bp, x, cfg):
+def _scan_blocks(body, x, params, cfg: TransformerConfig, *per_layer):
+    """`lax.scan` of `body(x, bp, experts, *slices) -> (x, ys)` over the
+    layers, with `per_layer` arrays (the cache) sliced beside the blocks.  A
+    mixture of experts' matrices are not scanned: the grouped matmul that reads
+    them is a kernel, and a layer's slice of the stack handed to a kernel is a
+    copy of every expert at every step.  `experts` is the whole stack and the
+    layer's index, which `routed_ffn` reads in place; None for a dense model."""
+    blocks = params["blocks"]
+    if not cfg.n_experts:
+        dense = lambda x, inputs: body(x, inputs[0], None, *inputs[1:])
+        return lax.scan(dense, x, (blocks, *per_layer))
+    stack = {k: blocks[k] for k in EXPERT_MATRICES if k in blocks}
+    sliced = {k: v for k, v in blocks.items() if k not in stack}
+    layers = jnp.arange(blocks["router"].shape[0])
+
+    def step(x, inputs):
+        bp, layer, *slices = inputs
+        return body(x, bp, (stack, layer), *slices)
+
+    return lax.scan(step, x, (sliced, layers, *per_layer))
+
+
+def _mlp(bp, x, cfg, live=None, experts=None):
+    """The block's second half: x + FFN(norm(x)).  Returns (x, experts
+    touched): for a mixture of experts the FFN is the dropless routed path
+    (parallel/moe.py routed_ffn through transformer._moe, which says what
+    `experts` is) and the second value counts the experts that were given a
+    row; `live` [B, T] marks the rows that take experts (None: all).  A dense
+    model gives None."""
     dt = x.dtype
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln2"])
     with jax.named_scope("ffn"):
         if cfg.n_experts:
-            return x + _moe_infer(bp, y, cfg)
+            out, _, touched = _moe(bp, y, cfg, live, experts)
+            return x + out, touched
         gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-        return x + gated @ bp["w_down"].astype(dt)
-
-
-_MOE_CHUNK = 64  # prefill tokens per all-experts pass (bounds [B,c,X,F])
-
-
-def _moe_infer(bp, y, cfg: TransformerConfig):
-    """MoE inference FFN delta: compute every expert and mask by the top-1
-    route.  Single-host decode has no 'ep' axis to all_to_all over; the
-    all-experts einsum stays MXU-shaped and drops nothing (capacity is a
-    train-time constraint).  The FLOP cost is n_experts x the routed path —
-    fine for the modest expert counts this serves; prefill is CHUNKED over
-    the prompt so the [B, chunk, X, F] intermediate stays bounded instead
-    of materializing [B, T, X, F] for long prompts.  (A capacity-dispatch
-    prefill like parallel/moe.py would cut the FLOPs too; do that if MoE
-    serving ever needs big expert counts.)"""
-    dt = y.dtype
-
-    def dense_pass(y_c):  # [B, c, E] -> [B, c, E]
-        logits = (y_c @ bp["router"].astype(dt)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        idx = jnp.argmax(probs, axis=-1)
-        gate = jnp.take_along_axis(probs, idx[..., None], axis=-1)[..., 0]
-        h = jax.nn.silu(jnp.einsum("bte,xef->btxf", y_c, bp["w_in"].astype(dt)))
-        out_x = jnp.einsum("btxf,xfe->btxe", h, bp["w_out"].astype(dt))
-        pick = jax.nn.one_hot(idx, out_x.shape[2], dtype=dt) * gate[..., None].astype(dt)
-        return jnp.einsum("btxe,btx->bte", out_x, pick)
-
-    b, t, e = y.shape
-    if t <= _MOE_CHUNK:
-        return dense_pass(y)
-    pad = (-t) % _MOE_CHUNK
-    yp = jnp.pad(y, ((0, 0), (0, pad), (0, 0)))
-    nc = (t + pad) // _MOE_CHUNK
-    chunks = yp.reshape(b, nc, _MOE_CHUNK, e).transpose(1, 0, 2, 3)
-    out = lax.map(dense_pass, chunks)  # [nc, B, c, E]
-    out = out.transpose(1, 0, 2, 3).reshape(b, t + pad, e)
-    return out[:, :t]
+        return x + gated @ bp["w_down"].astype(dt), None
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
@@ -129,10 +114,11 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     }
 
 
-def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None):
+def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None, experts=None):
     """One block, one token. x: [B, 1, E]; layer_cache: (k,v) [B,Tmax,KV,D].
     pad: [B] left-pad counts — the RoPE position of the token written at cache
-    slot `pos` is `pos - pad[b]` so each row's positions count real tokens."""
+    slot `pos` is `pos - pad[b]` so each row's positions count real tokens.
+    Returns (x, (k, v))."""
     k_cache, v_cache = layer_cache
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln1"])
@@ -152,15 +138,18 @@ def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None):
     with jax.named_scope("attn.out"):
         b = x.shape[0]
         x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
-    return _mlp(bp, x, cfg), (k_cache, v_cache)
+    return _mlp(bp, x, cfg, experts=experts)[0], (k_cache, v_cache)
 
 
-def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads):
+def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads, live=None,
+                         experts=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
     layer_cache: (k, v) [B, Tmax, KV, D].  Row b writes its k/v at slot
     pos[b], takes RoPE position pos[b] - pads[b], and attends to cache
-    slots [pads[b], pos[b]]."""
+    slots [pads[b], pos[b]].  live: [B] bool, the rows that hold a request:
+    an empty slot's row takes no expert (None: every row does).  Returns
+    (x, (k, v), experts touched or None: `_mlp`)."""
     k_cache, v_cache = layer_cache
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln1"])
@@ -178,10 +167,11 @@ def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads):
         attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pads)  # per-row length
     with jax.named_scope("attn.out"):
         x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
-    return _mlp(bp, x, cfg), (k_cache, v_cache)
+    x, touched = _mlp(bp, x, cfg, None if live is None else live[:, None], experts)
+    return x, (k_cache, v_cache), touched
 
 
-def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int):
+def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None):
     """One block over the whole prompt; returns padded caches [B,Tmax,KV,D].
     pad: [B] per-row left-pad counts or None. Real tokens sit at columns
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
@@ -214,7 +204,9 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int):
         attn = _attn(q, kr, vr, causal=True, pad=pad).reshape(b, t, -1).astype(x.dtype)
     with jax.named_scope("attn.out"):
         x = x + attn @ bp["wo"].astype(x.dtype)
-    return _mlp(bp, x, cfg), (k_cache, v_cache)
+    # the left padding takes no expert
+    live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
+    return _mlp(bp, x, cfg, live, experts)[0], (k_cache, v_cache)
 
 
 def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
@@ -223,12 +215,11 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
-    def body(x, bp):
-        x, (kc, vc) = _prefill_block(bp, x, pad, cfg, t_max)
+    def body(x, bp, experts):
+        x, (kc, vc) = _prefill_block(bp, x, pad, cfg, t_max, experts)
         return x, (kc, vc)
 
-    blocks = params["blocks"]
-    x, (k_all, v_all) = lax.scan(body, x, blocks)
+    x, (k_all, v_all) = _scan_blocks(body, x, params, cfg)
     with jax.named_scope("norm"):
         x = _rms_norm(x, params["ln_f"])
     with jax.named_scope("head"):
@@ -241,12 +232,11 @@ def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[token][:, None, :]  # [B,1,E]
 
-    def body(x, inputs):
-        bp, kc, vc = inputs
-        x, (kc, vc) = _block_decode(bp, x, (kc, vc), pos, cfg, pad)
+    def body(x, bp, experts, kc, vc):
+        x, (kc, vc) = _block_decode(bp, x, (kc, vc), pos, cfg, pad, experts)
         return x, (kc, vc)
 
-    x, (k_all, v_all) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+    x, (k_all, v_all) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
     with jax.named_scope("norm"):
         x = _rms_norm(x, params["ln_f"])
     with jax.named_scope("head"):

@@ -60,13 +60,24 @@ class TransformerConfig:
     # while-loop can't), worth ~12% a step on v5e; compile time grows with
     # depth, so deep stacks can turn it off
     unroll_layers: bool = True
-    # mixture-of-experts FFN (parallel/moe.py switch-style top-1): every
-    # layer's dense FFN becomes n_experts experts sharded over the 'ep' mesh
-    # axis, tokens routed via all_to_all.  0 = dense.
+    # mixture-of-experts FFN (parallel/moe.py): every layer's dense FFN
+    # becomes n_experts experts behind a softmax router.  0 = dense.  On one
+    # device (serving, and the forward and loss without a mesh) tokens go
+    # through the dropless top-k path `routed_ffn`; on a multi-device mesh the
+    # experts are sharded over the 'ep' axis and tokens travel by all_to_all
+    # (`moe_ffn`: top-1 with a capacity, ungated experts only).
     n_experts: int = 0
     ep: int = 1
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # moe_ffn only: routed_ffn drops nothing
     moe_aux_weight: float = 0.01
+    n_experts_per_tok: int = 1  # the k largest router probabilities a token
+    # gated experts (silu(x w_gate) * (x w_up)) w_down, each [X, E, F] / [X, F, E];
+    # False: silu(x w_in) w_out
+    moe_gated: bool = False
+    moe_renormalize: bool = False  # the k probabilities divided by their sum
+    # RMSNorm with a learned weight over the whole projected q and k vectors
+    # (before the split into heads and the rotary embedding): q_norm, k_norm
+    qk_norm: bool = False
 
     @property
     def layers_per_stage(self) -> int:
@@ -98,10 +109,12 @@ def _init_block(key, cfg: TransformerConfig):
         "wo": jax.random.normal(ks[3], (h * d, e), pd) * s(h * d),
         "ln2": jnp.ones((e,), pd),
     }
+    if cfg.qk_norm:
+        out.update({"q_norm": jnp.ones((h * d,), pd), "k_norm": jnp.ones((kv * d,), pd)})
     if cfg.n_experts:
         from ..parallel.moe import init_moe_params
 
-        out.update(init_moe_params(ks[4], e, f, cfg.n_experts, pd))
+        out.update(init_moe_params(ks[4], e, f, cfg.n_experts, pd, gated=cfg.moe_gated))
     else:
         out.update(
             {
@@ -148,15 +161,16 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "wo": blk("tp", "fsdp"),
         "ln2": blk(None),
     }
+    if cfg.qk_norm:
+        blocks.update({"q_norm": blk(None), "k_norm": blk(None)})
     if cfg.n_experts:
-        blocks.update(
-            {
-                # experts sharded over 'ep'; each expert's matmuls tp-sharded
-                "router": blk(None, None),
-                "w_in": blk("ep", "fsdp", "tp"),
-                "w_out": blk("ep", "tp", "fsdp"),
-            }
-        )
+        # experts sharded over 'ep'; each expert's matmuls tp-sharded
+        into, out_of = blk("ep", "fsdp", "tp"), blk("ep", "tp", "fsdp")
+        blocks["router"] = blk(None, None)
+        if cfg.moe_gated:
+            blocks.update({"w_gate": into, "w_up": into, "w_down": out_of})
+        else:
+            blocks.update({"w_in": into, "w_out": out_of})
     else:
         blocks.update(
             {
@@ -221,6 +235,42 @@ def _rope(q, k, positions, cfg: TransformerConfig):
     )
 
 
+def _project_qkv(bp, y, cfg: TransformerConfig):
+    """q [B, T, H, D], k and v [B, T, KV, D] of one block from its normed
+    input y [B, T, E]: the three projections and, with `cfg.qk_norm`, the
+    RMSNorm of q and k over the whole projected vector.  The one place every
+    forward, prefill and decode block projects."""
+    b, t, _ = y.shape
+    d, dt = cfg.d_head, y.dtype
+
+    def project(w, heads, norm=None):
+        out = y @ bp[w].astype(dt)
+        if norm is not None and cfg.qk_norm:
+            out = _rms_norm(out, bp[norm])
+        return out.reshape(b, t, heads, d)
+
+    return (project("wq", cfg.n_heads, "q_norm"), project("wk", cfg.n_kv_heads, "k_norm"),
+            project("wv", cfg.n_kv_heads))
+
+
+def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
+    """The dropless routed expert FFN (parallel/moe.py routed_ffn) of one
+    block over y [B, T, E]; `live` [B, T] marks the rows that take experts.
+    `experts`: (every layer's experts' matrices, unsliced; this layer's index)
+    from a scan that keeps them out of its slices (models/generate.py
+    _scan_blocks); None: `bp` holds this layer's own, a stack of one.  Returns
+    (out [B, T, E], aux loss, experts touched)."""
+    from ..parallel.moe import EXPERT_MATRICES, routed_ffn
+
+    b, t, e = y.shape
+    stack, layer = experts or ({k: bp[k][None] for k in EXPERT_MATRICES if k in bp}, 0)
+    r = routed_ffn(
+        y.reshape(b * t, e), bp["router"], stack, layer, k=cfg.n_experts_per_tok,
+        renormalize=cfg.moe_renormalize, live=None if live is None else live.reshape(b * t),
+    )
+    return r.out.reshape(b, t, e), r.aux_loss, r.experts_touched
+
+
 # [B, T, H, D] as the dense block leaves it: batch over the data axes, heads
 # over tp (wq/wk/wv shard their head columns on tp)
 _ATTN_SPEC = P(("dp", "fsdp"), None, "tp", None)
@@ -276,9 +326,7 @@ def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln1"])
     with jax.named_scope("attn.qkv"):
-        q = (y @ bp["wq"].astype(dt)).reshape(b, t, h, d)
-        k = (y @ bp["wk"].astype(dt)).reshape(b, t, kv, d)
-        v = (y @ bp["wv"].astype(dt)).reshape(b, t, kv, d)
+        q, k, v = _project_qkv(bp, y, cfg)
 
     with jax.named_scope("attn.rope"):
         if "sp" in manual_axes and cfg.sp > 1:
@@ -301,9 +349,13 @@ def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln2"])
     with jax.named_scope("ffn"):
+        if cfg.n_experts and "ep" not in manual_axes:
+            out, aux, _ = _moe(bp, y, cfg)
+            return x + out, aux
         if cfg.n_experts:
-            # MoE FFN: tokens flatten, route to experts over 'ep', come back
-            # (only traced under shard_map manual over 'ep' — see forward())
+            # experts sharded over 'ep': tokens flatten, travel to their
+            # expert's device, come back (traced under shard_map manual over
+            # 'ep' — see forward())
             from ..parallel.moe import moe_ffn
 
             r = moe_ffn(
@@ -354,13 +406,21 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
         manual_axes.add("pp")
     if cfg.sp > 1 and cfg.resolved_attn() in ("ring", "ulysses"):
         manual_axes.add("sp")
-    if cfg.n_experts:
+    # one device holds every expert and routes without a capacity; any larger
+    # mesh shards the experts over its 'ep' axis
+    if cfg.n_experts and mesh is not None and mesh.size > 1:
         manual_axes.add("ep")
 
     if manual_axes:
         if mesh is None:
-            raise ValueError("mesh required for pp/sp/ep execution")
+            raise ValueError("mesh required for pp/sp execution")
         if cfg.n_experts:
+            if cfg.n_experts_per_tok > 1 or cfg.moe_gated:
+                raise NotImplementedError(
+                    "experts sharded over a mesh's 'ep' axis are top-1 and ungated "
+                    f"(parallel/moe.py moe_ffn); n_experts_per_tok={cfg.n_experts_per_tok}, "
+                    f"moe_gated={cfg.moe_gated} run on one device only"
+                )
             mesh_ep = mesh.shape["ep"]
             if cfg.ep > 1 and cfg.ep != mesh_ep:
                 raise ValueError(

@@ -1,29 +1,124 @@
-"""Expert parallelism: switch-style Mixture-of-Experts FFN with capacity-based
-top-1 routing and all-to-all token exchange over the 'ep' mesh axis.
+"""Mixture-of-experts FFNs: two paths, told apart by where the experts live.
 
-Dispatch/combine use STATIC-SHAPE scatter/gather on flat slot indices
-(token n -> slot expert_idx[n] * capacity + position-within-expert), with
-dropped tokens routed to one overflow row that is sliced away.  The classic
-one-hot-einsum formulation ("nxc,ne->xce") is O(N·X·C·E) — at N=8k tokens,
-4 experts, capacity 2.5k it spends ~2.5x the expert FFN's FLOPs on routing
-alone and materialises [N, X, C] dispatch tensors (measured 3.4 s/step vs
-0.1 s dense on v5e); the scatter form is O(N·E) with the same static
-shapes, gradients, and all_to_all layout.  Experts' weights are sharded
-over 'ep'; tokens travel to their expert's device via `lax.all_to_all`.
+`routed_ffn` is the dropless top-k path of one device, which serving
+(models/generate.py: prefill and every decode step) and the one-device forward
+and loss (models/transformer.py) go through: a softmax router in float32, the
+k largest probabilities of each token (optionally renormalised), every
+(token, expert) assignment sorted by expert, one grouped matmul a projection
+over the sorted rows (`lax.ragged_dot`: on a TPU a kernel that reads the
+weights of the experts that have rows and of no other), the results weighted,
+returned to token order and summed over k.  No capacity and no drop, whatever
+the imbalance.  Rows marked not live (the empty slots of a decode batch, the
+left padding of a prompt) take no expert.  Experts are gated
+(`silu(x w_gate) * (x w_up)) w_down`, OLMoE's kind) or ungated
+(`silu(x w_in) w_out`).
+
+`moe_ffn` is expert parallelism for training over an 'ep' mesh axis:
+switch-style top-1 routing with a capacity, tokens exchanged with
+`lax.all_to_all`, ungated experts.  Its dispatch/combine use STATIC-SHAPE
+scatter/gather on flat slot indices (token n -> slot expert_idx[n] * capacity
++ position-within-expert), with dropped tokens routed to one overflow row
+that is sliced away.  The classic one-hot-einsum formulation ("nxc,ne->xce")
+is O(N*X*C*E) — at N=8k tokens, 4 experts, capacity 2.5k it spends ~2.5x the
+expert FFN's FLOPs on routing alone and materialises [N, X, C] dispatch
+tensors (measured 3.4 s/step vs 0.1 s dense on v5e); the scatter form is
+O(N*E) with the same static shapes, gradients, and all_to_all layout.
+Experts' weights are sharded over 'ep'; tokens travel to their expert's
+device via `lax.all_to_all`.  More than one expert a token, or gated experts,
+over 'ep' is not built yet: models/transformer.py refuses the combination.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 
+# the experts' matrices among a block's weights (init_moe_params), each [X, ...]
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down", "w_in", "w_out")
+
+
 class MoEOutput(NamedTuple):
     out: jax.Array
     aux_loss: jax.Array  # load-balancing loss (Switch Transformer style)
+
+
+class RoutedOutput(NamedTuple):
+    out: jax.Array  # [N, E], x's dtype
+    aux_loss: jax.Array  # load-balancing loss, as moe_ffn's
+    experts_touched: jax.Array  # int32: experts that were given at least one row
+
+
+def routed_ffn(
+    x: jax.Array,  # [N, E]
+    router: jax.Array,  # [E, X], this layer's
+    experts,  # name -> the experts' matrices of every layer, stacked: [L, X, ...]
+    layer=0,  # which of the L this call is: an int, or a scan's index
+    *,
+    k: int = 1,
+    renormalize: bool = False,  # the k probabilities divided by their sum
+    live: Optional[jax.Array] = None,  # [N] bool; None = every row
+) -> RoutedOutput:
+    """The dropless routed expert FFN of one device (module docstring).  Every
+    live row's k assignments are computed; a row that is not live is given to
+    no expert, adds to no group and comes back as zeros.  The experts are
+    gated if `experts` holds w_gate, w_up [L, X, E, F] and w_down [L, X, F, E],
+    else ungated: w_in, w_out.
+
+    The experts come as every layer's, with the layer's index, because inside
+    a scan over the layers they are best not sliced.  The grouped matmul is a
+    kernel, a kernel's operand has to be a buffer of its own, and a layer's
+    slice of the stacked [L, X, E, F] is then a copy of all X experts at every
+    step (0.7 ms a matrix and layer at OLMoE's sizes: PERF.md section 6, PR
+    27).  So the matmul runs over L * X groups of which only this layer's X
+    have rows, and reads the stack in place.  A caller that holds one layer's
+    matrices hands them over as a stack of one (`w[None]`, layer 0): the same
+    matmul over X groups."""
+    n, _ = x.shape
+    dt = x.dtype
+    n_experts = router.shape[-1]
+    gated = "w_gate" in experts
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(x, router.astype(dt), preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = lax.top_k(probs, k)  # [N, k] each, float32 / int32
+        if renormalize:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    with jax.named_scope("moe.dispatch"):
+        expert = idx.reshape(n * k)
+        if live is not None:
+            # a row that takes no expert sorts behind the last group
+            expert = jnp.where(jnp.repeat(live, k), expert, n_experts)
+        order = jnp.argsort(expert, stable=True)  # sorted row -> assignment
+        group_sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[expert].add(1)[:n_experts]
+        rows = x[order // k]  # [N*k, E], expert by expert
+    with jax.named_scope("moe.experts"):
+        n_layers = experts["w_down" if gated else "w_out"].shape[0]
+        every = jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(group_sizes).reshape(-1)
+        grouped = lambda a, w: lax.ragged_dot(a, w.reshape(-1, *w.shape[2:]).astype(dt), every)
+        if gated:
+            hidden = jax.nn.silu(grouped(rows, experts["w_gate"])) * grouped(rows, experts["w_up"])
+            out = grouped(hidden, experts["w_down"])
+        else:
+            out = grouped(jax.nn.silu(grouped(rows, experts["w_in"])), experts["w_out"])
+    with jax.named_scope("moe.combine"):
+        # rows past the last group belong to no expert: their product is not defined
+        in_a_group = jnp.arange(n * k) < jnp.sum(group_sizes)
+        out = jnp.where(in_a_group[:, None], out.astype(jnp.float32), 0.0)
+        out = out * gate.reshape(n * k)[order][:, None]
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+        out = jnp.sum(out[back].reshape(n, k, -1), axis=1).astype(dt)
+    # load-balance aux loss over the live rows: share of the assignments an
+    # expert was given times its mean probability, summed over experts
+    rows_live = jnp.ones((n,), jnp.float32) if live is None else live.astype(jnp.float32)
+    n_live = jnp.maximum(jnp.sum(rows_live), 1.0)
+    frac = group_sizes.astype(jnp.float32) / (n_live * k)
+    mean_prob = jnp.sum(probs * rows_live[:, None], axis=0) / n_live
+    aux = jnp.sum(frac * mean_prob) * n_experts
+    return RoutedOutput(out, aux, jnp.sum(group_sizes > 0).astype(jnp.int32))
 
 
 def moe_ffn(
@@ -89,7 +184,16 @@ def moe_ffn(
     return MoEOutput(out, aux)
 
 
-def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.float32):
+def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.float32,
+                    gated: bool = False):
+    if gated:
+        k1, k2, k3, k4 = jax.random.split(key, 4)
+        return {
+            "router": jax.random.normal(k1, (e_model, n_experts), dtype) * 0.02,
+            "w_gate": jax.random.normal(k2, (n_experts, e_model, f_hidden), dtype) * e_model ** -0.5,
+            "w_up": jax.random.normal(k3, (n_experts, e_model, f_hidden), dtype) * e_model ** -0.5,
+            "w_down": jax.random.normal(k4, (n_experts, f_hidden, e_model), dtype) * f_hidden ** -0.5,
+        }
     k1, k2, k3 = jax.random.split(key, 3)
     scale_in = (2.0 / e_model) ** 0.5
     scale_out = (2.0 / f_hidden) ** 0.5

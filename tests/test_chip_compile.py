@@ -157,6 +157,38 @@ def test_decode_block_reads_the_cache_as_stored(v5e):
     assert widened == []
 
 
+def test_decode_step_reads_the_experts_where_they_are(v5e):
+    """The decode step of a mixture of experts at OLMoE's widths (64 experts of
+    2048 x 1024, 8 a token, 32 slots), three layers deep: the grouped matmul is
+    a kernel of the compiler's, and the layer scan hands it the stacked
+    [L, X, E, F] matrices whole.  No buffer of one layer's experts (X * E * F
+    elements) exists: sliced out of the stack by the scan it was a copy of all
+    64 experts at every step, 0.7 ms a matrix and layer on the chip."""
+    from cluster_anywhere_tpu.llm import continuous
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=512, n_layers=3, d_model=2048, n_heads=16, n_kv_heads=16, d_head=128, d_ff=1024,
+        n_experts=64, n_experts_per_tok=8, moe_gated=True, qk_norm=True, param_dtype=jnp.bfloat16,
+    )
+    slots, t_max = 32, 768
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
+    keys = on_chip(jax.eval_shape(lambda: jax.random.split(jax.random.key(0), slots)))
+    row = lambda dtype: on_chip(jax.ShapeDtypeStruct((slots,), dtype))
+    i32, f32 = row(jnp.int32), row(jnp.float32)
+    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, i32, f32, i32, f32, keys, row(jnp.bool_)).compile()
+    assert _has_kernel(compiled)
+    layer, stack = 64 * 2048 * 1024, 3 * 64 * 2048 * 1024
+    buffers = _buffers(compiled)
+    assert sum(1 for dt, n, _ in buffers if n == stack) >= 3  # the three stacks are seen
+    assert [b for b in buffers if b[1] == layer] == []
+
+
 @pytest.mark.parametrize(
     "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2_tp2"]
 )

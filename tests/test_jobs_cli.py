@@ -244,14 +244,21 @@ def test_rest_job_submission(ca_cluster):
     assert any(j["submission_id"] == sid for j in jobs)
 
 
-def test_ca_up_down(tmp_path):
+def test_ca_up_down(tmp_path, monkeypatch):
     """`ca up <yaml>` boots head + agent nodes from a config; `ca down`
     tears the whole cluster back down (ray up/down role, local provider)."""
     import subprocess
     import sys
+    import tempfile
 
     if ca.is_initialized():
         ca.shutdown()
+    # "auto" and `ca down` take the newest session under the root: under the
+    # shared one that can be another test's, started a moment later by a
+    # parallel worker, and `down` then stops that one (its test hangs, this
+    # cluster is left).  A root of its own, short enough for a socket path.
+    root = tempfile.mkdtemp(prefix="ca_updown_")
+    monkeypatch.setenv("CA_SESSION_DIR_ROOT", root)
     cfg = tmp_path / "cluster.yaml"
     cfg.write_text(
         "head: {num_cpus: 2}\n"
@@ -282,6 +289,9 @@ def test_ca_up_down(tmp_path):
             [sys.executable, "-m", "cluster_anywhere_tpu.cli", "down"],
             capture_output=True, text=True, timeout=60, env=env,
         )
+        import shutil
+
+        shutil.rmtree(root, ignore_errors=True)
     assert down.returncode == 0, down.stdout + down.stderr
     assert "stopping cluster" in down.stdout
 

@@ -267,9 +267,12 @@ def test_continuous_llm_server_concurrent_requests():
 
 def test_moe_generate_and_continuous_batching():
     """MoE checkpoints serve: prefill/decode route each token through its
-    top-1 expert (all-experts einsum + mask — no 'ep' axis at inference),
-    greedy generation is deterministic, and the continuous batcher works
-    over an MoE model unchanged."""
+    top-1 expert (the dropless routed path of parallel/moe.py — no 'ep' axis
+    at inference), greedy generation is deterministic, and the continuous
+    batcher works over an MoE model unchanged, in the bfloat16 that is served.
+    The prompt is one whose best two logits lie 0.18 or more apart at every
+    step: after [1, 5, 9] tokens 7 and 56 tie at 2.359375 in bfloat16, and the
+    jitted `generate` and the batcher's eager prefill break a tie differently."""
     import jax
     import jax.numpy as jnp
 
@@ -282,12 +285,12 @@ def test_moe_generate_and_continuous_batching():
         d_head=8, d_ff=64, n_experts=4,
     )
     params = init_params(jax.random.key(0), cfg)
-    prompt = jnp.array([[1, 5, 9]], jnp.int32)
+    prompt = jnp.array([[7, 3, 2]], jnp.int32)
     a = generate(params, prompt, jax.random.key(1), cfg=cfg, max_new_tokens=6)
     b = generate(params, prompt, jax.random.key(2), cfg=cfg, max_new_tokens=6)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     cb = ContinuousBatcher(params, cfg, slots=2, t_max=32, prefill_buckets=(8,))
-    req = cb.submit([1, 5, 9], max_new_tokens=6)
+    req = cb.submit([7, 3, 2], max_new_tokens=6)
     cb.pump()
     assert req.done and req.out_tokens == np.asarray(a)[0].tolist()
 

@@ -1,0 +1,251 @@
+"""The dropless routed expert path (parallel/moe.py routed_ffn) against a
+per-token loop in float32, and the places the program calls it from: the
+one-device forward and loss, prefill, and the decode step with its live mask."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cluster_anywhere_tpu.llm import continuous
+from cluster_anywhere_tpu.models import generate, transformer
+from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+from cluster_anywhere_tpu.parallel.moe import EXPERT_MATRICES, init_moe_params, routed_ffn
+
+E, F, X = 16, 24, 8
+
+
+def routed(x, bp, **kw):
+    """`routed_ffn` over one layer's weights, handed over as a stack of one."""
+    return routed_ffn(x, bp["router"], {k: bp[k][None] for k in EXPERT_MATRICES if k in bp}, **kw)
+
+
+def layer(gated, seed=0):
+    return init_moe_params(jax.random.key(seed), E, F, X, jnp.float32, gated=gated)
+
+
+def tokens(n, seed=1):
+    return jax.random.normal(jax.random.key(seed), (n, E), jnp.float32)
+
+
+def expert(bp, e, x, gated):
+    if gated:
+        return (jax.nn.silu(x @ bp["w_gate"][e]) * (x @ bp["w_up"][e])) @ bp["w_down"][e]
+    return jax.nn.silu(x @ bp["w_in"][e]) @ bp["w_out"][e]
+
+
+def loop(x, bp, k, gated, renormalize, live=None):
+    """One token at a time, one expert at a time."""
+    probs = jax.nn.softmax(x @ bp["router"], axis=-1)
+    rows = []
+    for n in range(x.shape[0]):
+        if live is not None and not bool(live[n]):
+            rows.append(jnp.zeros_like(x[n]))
+            continue
+        best = jnp.argsort(-probs[n])[:k]
+        weights = probs[n][best]
+        if renormalize:
+            weights = weights / jnp.sum(weights)
+        rows.append(sum(weights[j] * expert(bp, best[j], x[n], gated) for j in range(k)))
+    return jnp.stack(rows)
+
+
+def all_experts_top1(x, bp):
+    """What `_moe_infer` gave: every expert for every token, masked by the
+    top-1 route and scaled by its probability."""
+    probs = jax.nn.softmax(x @ bp["router"], axis=-1)
+    idx = jnp.argmax(probs, axis=-1)
+    every = jnp.einsum("nxf,xfe->nxe", jax.nn.silu(jnp.einsum("ne,xef->nxf", x, bp["w_in"])), bp["w_out"])
+    pick = jax.nn.one_hot(idx, X) * jnp.max(probs, axis=-1, keepdims=True)
+    return jnp.einsum("nxe,nx->ne", every, pick)
+
+
+@pytest.mark.parametrize("k, gated, renormalize", [
+    (1, False, False), (2, False, True), (8, True, False), (8, True, True), (3, True, False),
+], ids=["top1-ungated", "top2-ungated-renorm", "top8-gated", "top8-gated-renorm", "top3-gated"])
+def test_routed_equals_the_per_token_loop(k, gated, renormalize):
+    bp, x = layer(gated), tokens(13)
+    with jax.default_matmul_precision("highest"):
+        got = routed(x, bp, k=k, renormalize=renormalize)
+        want = loop(x, bp, k, gated, renormalize)
+        assert np.max(np.abs(np.asarray(got.out - want))) < 1e-5
+        if k == 1 and not gated:
+            assert np.max(np.abs(np.asarray(got.out - all_experts_top1(x, bp)))) < 1e-5
+    assert 1 <= int(got.experts_touched) <= min(X, 13 * k)
+    if k == X:
+        assert int(got.experts_touched) == X
+    # jitted and eager are one computation
+    jitted = jax.jit(lambda x, bp: routed(x, bp, k=k, renormalize=renormalize).out)
+    assert np.max(np.abs(np.asarray(jitted(x, bp) - got.out))) < 1e-5
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_every_token_on_one_expert_drops_nothing(k):
+    """A capacity would drop all but a few of these rows; here each of the 40
+    tokens gets its experts' whole result."""
+    bp, x = layer(True), tokens(40)
+    bp["router"] = jnp.zeros((E, X), jnp.float32)
+    # a bias through a constant feature: every token prefers expert 5, then 2
+    x = x.at[:, 0].set(1.0)
+    bp["router"] = bp["router"].at[0, 5].set(60.0).at[0, 2].set(30.0)
+    with jax.default_matmul_precision("highest"):
+        got = routed(x, bp, k=k)
+        want = loop(x, bp, k, True, False)
+    assert int(got.experts_touched) == k
+    assert np.max(np.abs(np.asarray(got.out - want))) < 1e-5
+    only5 = expert(bp, 5, x, True)
+    assert np.all(np.abs(np.asarray(want)).sum(axis=1) > 0)  # no row came back empty
+    if k == 1:
+        assert np.max(np.abs(np.asarray(got.out - only5))) < 1e-4
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+def test_rows_that_are_not_live_take_no_expert(gated):
+    bp, x = layer(gated), tokens(12)
+    live = jnp.asarray([1, 0, 0, 1, 1, 0, 0, 0, 1, 0, 0, 0], bool)
+    with jax.default_matmul_precision("highest"):
+        every = routed(x, bp, k=2)
+        some = routed(x, bp, k=2, live=live)
+        # whatever the other rows hold, they reach no expert and no live row
+        junk = jnp.where(live[:, None], x, 1e4)
+        same = routed(junk, bp, k=2, live=live)
+    alive = np.asarray(live)
+    assert np.array_equal(np.asarray(some.out)[alive], np.asarray(every.out)[alive])
+    assert np.array_equal(np.asarray(same.out), np.asarray(some.out))
+    assert not np.asarray(some.out)[~alive].any()
+    # four live rows x 2 experts: at most 8 experts were read, and exactly those the rows chose
+    probs = jax.nn.softmax(x @ bp["router"], axis=-1)
+    chosen = {int(e) for n in np.flatnonzero(alive) for e in np.argsort(-np.asarray(probs[n]))[:2]}
+    assert int(some.experts_touched) == len(chosen) <= 8
+    # no live row at all: nothing is read, nothing comes back
+    none = routed(x, bp, k=2, live=jnp.zeros(12, bool))
+    assert int(none.experts_touched) == 0 and not np.asarray(none.out).any()
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+def test_a_layer_of_the_stack_is_that_layer_alone(gated):
+    """Inside a scan the experts come as every layer's with the layer's index:
+    the other layers' groups are empty, and the result is the layer's own."""
+    layers = [layer(gated, seed) for seed in (0, 1, 2)]
+    stack = {k: jnp.stack([bp[k] for bp in layers]) for k in layers[0] if k != "router"}
+    x, live = tokens(9), jnp.arange(9) != 4
+    with jax.default_matmul_precision("highest"):
+        alone = [routed(x, bp, k=2, live=live) for bp in layers]
+        _, scanned = jax.lax.scan(
+            lambda _, inputs: (None, routed_ffn(x, inputs[0], stack, inputs[1], k=2, live=live)),
+            None, (jnp.stack([bp["router"] for bp in layers]), jnp.arange(3)))
+    for i, want in enumerate(alone):
+        got = routed_ffn(x, layers[i]["router"], stack, i, k=2, live=live)
+        assert np.array_equal(np.asarray(got.out), np.asarray(want.out))
+        assert np.max(np.abs(np.asarray(scanned.out[i] - want.out))) < 1e-6
+        assert int(got.experts_touched) == int(scanned.experts_touched[i]) == int(want.experts_touched)
+
+
+# -- where the program calls it from ------------------------------------------
+
+SMALL = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4, d_head=8, d_ff=48,
+             n_experts=8, n_experts_per_tok=2, moe_gated=True, qk_norm=True,
+             dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def test_defaults_are_the_top1_ungated_model():
+    cfg = TransformerConfig(n_experts=4)
+    assert (cfg.n_experts_per_tok, cfg.moe_gated, cfg.moe_renormalize, cfg.qk_norm) == (1, False, False, False)
+    blocks = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(0))["blocks"]
+    assert {"router", "w_in", "w_out"} <= set(blocks) and not {"w_gate", "q_norm", "k_norm"} & set(blocks)
+    cfg = TransformerConfig(**SMALL)
+    blocks = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(0))["blocks"]
+    assert blocks["w_gate"].shape == blocks["w_up"].shape == (2, 8, 32, 48)
+    assert blocks["w_down"].shape == (2, 8, 48, 32) and blocks["q_norm"].shape == (2, 32)
+    assert set(transformer.param_specs(cfg)["blocks"]) == set(blocks)
+
+
+def test_one_device_loss_and_its_gradient_match_the_loops(monkeypatch):
+    cfg = TransformerConfig(**SMALL, moe_aux_weight=0.0)
+    params = init_params(jax.random.key(2), cfg)
+    # the norms' weights off 1, so a norm that is left out shows
+    params["blocks"]["q_norm"] = params["blocks"]["q_norm"] * 1.3
+    params["blocks"]["k_norm"] = params["blocks"]["k_norm"] * 0.7
+    batch = {"ids": jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 9)), jnp.int32)}
+    loss_fn = transformer.make_loss_fn(cfg)  # no mesh: one device
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+
+        def looped(bp, y, cfg, live=None, experts=None):
+            b, t, e = y.shape
+            out = loop(y.reshape(b * t, e), bp, cfg.n_experts_per_tok, cfg.moe_gated, cfg.moe_renormalize)
+            return out.reshape(b, t, e), jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)
+
+        monkeypatch.setattr(transformer, "_moe", looped)
+        want, want_grads = jax.value_and_grad(transformer.make_loss_fn(cfg))(params, batch)
+    assert float(loss) == pytest.approx(float(want), abs=1e-5)
+    for name in ("router", "w_gate", "w_up", "w_down", "q_norm", "k_norm", "wq"):
+        g, w = np.asarray(grads["blocks"][name]), np.asarray(want_grads["blocks"][name])
+        assert np.abs(w).max() > 0 and np.max(np.abs(g - w)) < 1e-5 * max(1.0, np.abs(w).max()), name
+    # the load-balance term reaches the router too
+    with_aux = jax.grad(transformer.make_loss_fn(TransformerConfig(**SMALL, moe_aux_weight=0.5)))
+    monkeypatch.undo()
+    more = with_aux(params, batch)["blocks"]["router"]
+    assert np.abs(np.asarray(more - grads["blocks"]["router"])).max() > 0
+
+
+def test_more_than_one_expert_a_token_over_ep_is_refused():
+    from cluster_anywhere_tpu.parallel import MeshSpec, make_mesh
+
+    cfg = TransformerConfig(**dict(SMALL, qk_norm=False), ep=2)
+    mesh = make_mesh(MeshSpec(dp=4, ep=2))
+    with pytest.raises(NotImplementedError, match="top-1 and ungated"):
+        transformer.forward(init_params(jax.random.key(0), cfg), jnp.zeros((8, 4), jnp.int32), cfg, mesh)
+
+
+def test_decode_step_is_told_which_slots_are_live():
+    """Empty slots take no expert: with one live row of four the step touches
+    exactly that row's two experts a layer, whatever the other rows hold."""
+    cfg = TransformerConfig(**SMALL)
+    params = init_params(jax.random.key(4), cfg)
+    slots, t_max = 4, 16
+    cache = generate.init_cache(cfg, slots, t_max)
+    i32 = lambda *v: jnp.asarray(v, jnp.int32)
+    args = (i32(5, 9, 11, 3), i32(2, 0, 0, 0), i32(0, 0, 0, 0), jnp.zeros(4), i32(0, 0, 0, 0),
+            jnp.ones(4), jax.random.split(jax.random.key(0), slots))
+    step = continuous._decode_step_rowpos.__wrapped__
+    one_live = jnp.asarray([True, False, False, False])
+    nxt, _, touched = step(params, cache, *args, one_live, cfg=cfg)
+    assert float(touched) == 2.0
+    other = (i32(5, 1, 2, 60),) + args[1:]
+    nxt2, _, touched2 = step(params, cache, *other, one_live, cfg=cfg)
+    assert int(nxt[0]) == int(nxt2[0]) and float(touched2) == 2.0
+    _, _, all_live = step(params, cache, *args, jnp.ones(4, bool), cfg=cfg)
+    assert 2.0 <= float(all_live) <= 8.0
+    # a dense model's step is not told and says nothing
+    dense = TransformerConfig(**dict(SMALL, n_experts=0, moe_gated=False))
+    out = step(init_params(jax.random.key(4), dense), cache, *args, cfg=dense)
+    assert out[2] is None
+
+
+def test_batcher_reports_rows_experts_and_assignments(monkeypatch):
+    cfg = TransformerConfig(**SMALL)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=4, t_max=32,
+                                      prefill_buckets=(8, 16))
+    seen = []
+    real = continuous.tracing.span
+
+    class Span(real):
+        def set(self, **attrs):
+            seen.append((self.name, attrs))
+            super().set(**attrs)
+
+    monkeypatch.setattr(continuous.tracing, "span", Span)
+    reqs = [cb.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (5, 11)]
+    cb.pump()
+    assert all(r.done and len(r.out_tokens) == 4 for r in reqs)
+    admits = [a["moe_assignments"] for name, a in seen if name == "llm.admit" and "moe_assignments" in a]
+    assert admits == [5 * 2, 11 * 2]
+    steps = [a for name, a in seen if name == "llm.step" and "moe_rows" in a]
+    assert len(steps) == 3 and all(s["moe_rows"] == 2 and 2.0 <= s["moe_experts_touched"] <= 4.0 for s in steps)
+    assert cb.stats["moe_assignments"] == (5 + 11) * 2 + 3 * 2 * 2
+    # the padded prefill gives what the unpadded one gives: the padding takes no expert
+    ids = jnp.asarray([[3, 1, 4, 1, 5]], jnp.int32)
+    bare, _ = generate.prefill(cb.params, ids, cfg, 32)
+    padded, _ = generate.prefill(cb.params, jnp.pad(ids, ((0, 0), (3, 0))), cfg, 32, pad=jnp.asarray([3]))
+    assert np.max(np.abs(np.asarray(bare - padded))) < 1e-5
