@@ -97,17 +97,21 @@ def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _decode_step_rowpos(
-    params, cache, tokens, pos, pads, temps, top_ks, top_ps, rngs, live=None, *, cfg
-):
+def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
     """One token for every slot with PER-ROW cache positions.
-    tokens/pos/pads/temps/top_ks: [S]; rngs: [S] keys; live: [S] bool, the
-    slots that hold a request (a mixture of experts gives the other rows no
-    expert; a dense model is not told: None).  Returns (next_tokens [S],
-    cache, experts touched): the last is the mean over the layers of the
-    experts that were given a row, None for a dense model.  The cache is
-    donated: decode rewrites it in place instead of copying [L,S,Tmax,KV,D]
-    x2 per token."""
+    ints: [4, S] int32, the rows tokens, pos, pads, top_ks, and for a mixture
+    of experts a fifth, live: 1 for the slots that hold a request (the other
+    rows are given no expert; a dense model is not told).  floats: [2, S]
+    float32, the rows temps, top_ps.  rng: the batcher's one key, split here
+    into the key it carries on and one key a row.  Returns (next_tokens [S],
+    cache, the carried key, experts touched): the last is the mean over the
+    layers of the experts that were given a row, None for a dense model.  The
+    cache is donated: decode rewrites it in place instead of copying
+    [L,S,Tmax,KV,D] x2 per token."""
+    tokens, pos, pads, top_ks, *live = ints
+    live = live[0] != 0 if live else None
+    temps, top_ps = floats
+    keys = jax.random.split(rng, ints.shape[1] + 1)
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [S,1,E]
 
@@ -122,8 +126,8 @@ def _decode_step_rowpos(
         x = _rms_norm(x, params["ln_f"])
     with jax.named_scope("head"):
         logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    nxt = _sample_rowwise(logits, rngs, temps, top_ks, top_ps)
-    return nxt, {"k": k_all, "v": v_all}, touched
+    nxt = _sample_rowwise(logits, keys[1:], temps, top_ks, top_ps)
+    return nxt, {"k": k_all, "v": v_all}, keys[0], touched
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -243,12 +247,18 @@ class ContinuousBatcher:
                 (cfg.n_layers, slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype
             ),
         }
-        self._tokens = np.zeros(slots, np.int32)
-        self._pos = np.zeros(slots, np.int32)  # cache slot of the NEXT write
-        self._pads = np.zeros(slots, np.int32)
-        self._temps = np.zeros(slots, np.float32)
-        self._topks = np.zeros(slots, np.int32)
-        self._topps = np.ones(slots, np.float32)
+        # the decode step's per-slot inputs as its program takes them: two
+        # host arrays that go to the jitted call as they are (one dispatch, no
+        # eager upload), the scheduler's vectors their rows.  The host writes
+        # them only in an admit and in llm.step.scatter, after the step's
+        # tokens are read back: the program has consumed them by then (on the
+        # CPU backend a host array may be aliased, not copied).
+        self._ints = np.zeros((5 if cfg.n_experts else 4, slots), np.int32)
+        self._floats = np.zeros((2, slots), np.float32)
+        # _pos: cache slot of the NEXT write; a mixture's fifth row: live slots
+        self._tokens, self._pos, self._pads, self._topks = self._ints[:4]
+        self._temps, self._topps = self._floats
+        self._topps[:] = 1.0
         self._by_slot: List[Optional[Request]] = [None] * slots
         self.queue: deque[Request] = deque()
         # bounded: pump() drains it; step()-driven servers track their own
@@ -330,23 +340,12 @@ class ContinuousBatcher:
             if not live:
                 return out
             with tracing.span("llm.step.upload"):
-                self._rng, *keys = jax.random.split(self._rng, self.slots + 1)
-                inputs = (
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos),
-                    jnp.asarray(self._pads),
-                    jnp.asarray(self._temps),
-                    jnp.asarray(self._topks),
-                    jnp.asarray(self._topps),
-                    jnp.stack(keys),
-                )
                 if self.cfg.n_experts:
-                    mask = np.zeros(self.slots, bool)
-                    mask[live] = True
-                    inputs += (jnp.asarray(mask),)
+                    self._ints[4] = [r is not None for r in self._by_slot]
             with tracing.span("llm.step.dispatch"):
-                nxt, self.cache, touched = _decode_step_rowpos(
-                    self.params, self.cache, *inputs, cfg=self.cfg
+                nxt, self.cache, self._rng, touched = _decode_step_rowpos(
+                    self.params, self.cache, self._ints, self._floats, self._rng,
+                    cfg=self.cfg,
                 )
             with tracing.span("llm.step.readback"):
                 nxt, touched = jax.device_get((nxt, touched))

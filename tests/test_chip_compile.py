@@ -176,12 +176,11 @@ def test_decode_step_reads_the_experts_where_they_are(v5e):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
     params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
     cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
-    keys = on_chip(jax.eval_shape(lambda: jax.random.split(jax.random.key(0), slots)))
-    row = lambda dtype: on_chip(jax.ShapeDtypeStruct((slots,), dtype))
-    i32, f32 = row(jnp.int32), row(jnp.float32)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    ints = on_chip(jax.ShapeDtypeStruct((5, slots), jnp.int32))  # the fifth row: the live slots
+    floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
     fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, i32, i32, i32, f32, i32, f32, keys, row(jnp.bool_)).compile()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
     assert _has_kernel(compiled)
     layer, stack = 64 * 2048 * 1024, 3 * 64 * 2048 * 1024
     buffers = _buffers(compiled)

@@ -366,15 +366,17 @@ def llm_spans(monkeypatch):
     ]
 
 
+_TINY = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_head=8, d_ff=64)
+_TINY_MIXTURE = dict(_TINY, n_experts=4, n_experts_per_tok=2, moe_gated=True)
+
+
 def _tiny_batcher(**kw):
     import jax
 
     from cluster_anywhere_tpu.llm import ContinuousBatcher
     from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
 
-    cfg = TransformerConfig(
-        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_head=8, d_ff=64
-    )
+    cfg = TransformerConfig(**_TINY)
     return ContinuousBatcher(
         init_params(jax.random.key(0), cfg), cfg, slots=2, t_max=64, prefill_buckets=(8, 32), **kw
     )
@@ -533,12 +535,12 @@ def _decode_step_program(cfg, slots, t_max):
 
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
     cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
-    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), slots))
-    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
-    f32 = jax.ShapeDtypeStruct((slots,), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    ints = jax.ShapeDtypeStruct((5 if cfg.n_experts else 4, slots), jnp.int32)
+    floats = jax.ShapeDtypeStruct((2, slots), jnp.float32)
     # a fresh function each time: jit keeps what it traced for one it has seen
     fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
-    return fn, (params, cache, i32, i32, i32, f32, i32, f32, keys)
+    return fn, (params, cache, ints, floats, key)
 
 
 def _compile_program(which):
@@ -684,7 +686,8 @@ def test_decode_never_widens_the_cache(which):
     fn, args = _decode_step_program(cfg, slots, t_max)
     if which == "decode_one":
         fn = lambda p, c, tok, pos, pad: generate.decode_one(p, c, tok, pos, cfg, pad)
-        args = (*args[:3], jax.ShapeDtypeStruct((), jnp.int32), args[4])
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32)
+        args = (*args[:2], row, jax.ShapeDtypeStruct((), jnp.int32), row)
     jaxpr = jax.make_jaxpr(fn)(*args)
     layer_cache = slots * t_max * cfg.n_kv_heads * cfg.d_head
     repeated = slots * t_max * cfg.n_heads * cfg.d_head
@@ -693,3 +696,99 @@ def test_decode_never_widens_the_cache(which):
     too_wide = [a for a in values if a.size >= repeated]
     f32_cache = [a for a in values if a.dtype == jnp.float32 and a.size >= layer_cache]
     assert too_wide == [] and f32_cache == [], (too_wide, f32_cache)
+
+
+@pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE], ids=["dense", "mixture"])
+def test_decode_step_inputs_reach_the_device_in_one_dispatch(model, monkeypatch):
+    """What `step` hands the device is one jitted call's arguments.  The traced
+    decode program takes ONE key and splits it itself (S + 1 ways: the key the
+    batcher carries on and one a row), and a warm `step()` on a live batcher
+    binds no primitive and puts no array eagerly: the split unpacked into keys,
+    six `jnp.asarray` and a `jnp.stack` were about forty dispatches a step,
+    20 ms on the chip with the device idle."""
+    import jax
+    from jax.extend.core import Primitive
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(**model)
+    slots = 4
+    fn, args = _decode_step_program(cfg, slots, 32)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    is_key = lambda v: jax.dtypes.issubdtype(v.aval.dtype, jax.dtypes.prng_key)
+    assert [v.aval.shape for v in jaxpr.invars if is_key(v)] == [()]
+    splits = [e for e in jaxpr.eqns if e.primitive.name == "random_split"]
+    assert [e.outvars[0].aval.shape for e in splits] == [(slots + 1,)]
+
+    cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=slots, t_max=32,
+                           prefill_buckets=(8,))
+    reqs = [cb.submit([3, 1, 4], max_new_tokens=8, temperature=0.7, top_k=5), cb.submit([1, 5], max_new_tokens=8)]
+    cb.step()
+    cb.step()  # warm: the decode program is compiled, both requests are live
+    eager = []
+    bind, put = Primitive.bind, jax._src.api.device_put
+    monkeypatch.setattr(Primitive, "bind", lambda self, *a, **k: eager.append(self.name) or bind(self, *a, **k))
+    monkeypatch.setattr(jax._src.api, "device_put", lambda *a, **k: eager.append("device_put") or put(*a, **k))
+    out = cb.step()
+    monkeypatch.undo()
+    assert sorted(out) == [r.request_id for r in reqs] and all(len(t) == 1 for t in out.values())
+    assert eager == []
+
+
+def test_sampled_streams_are_the_eager_split_and_sample():
+    """Sampled streams keep their bits: with temperature, top-k and top-p set
+    and requests admitted at different steps (so the admit's `split(rng)`
+    interleaves with the step's), every decode token is what the eager formula
+    gives: `rng, *keys = split(rng, S + 1)`, element 0 carried on, elements
+    1..S the rows' keys, `_sample_rowwise` over the step's logits."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher, continuous
+    from cluster_anywhere_tpu.models import generate
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(**_TINY, dtype=jnp.float32)
+    params = init_params(jax.random.key(3), cfg)
+    cb = ContinuousBatcher(params, cfg, slots=3, t_max=48, prefill_buckets=(8,))
+
+    def eager_step(rng):
+        """The next token of every slot and the carried key, op by op."""
+        tokens, pos, pads = (jnp.asarray(v) for v in (cb._tokens, cb._pos, cb._pads))
+        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
+        body = lambda x, bp, experts, kc, vc: (
+            generate._block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads, None, experts)[0], None)
+        x, _ = generate._scan_blocks(body, x, params, cfg, cb.cache["k"], cb.cache["v"])
+        logits = (generate._rms_norm(x, params["ln_f"])[:, 0] @ params["lm_head"]).astype(jnp.float32)
+        rng, *keys = jax.random.split(rng, cb.slots + 1)
+        nxt = continuous._sample_rowwise(
+            logits, jnp.stack(keys), jnp.asarray(cb._temps), jnp.asarray(cb._topks), jnp.asarray(cb._topps))
+        return rng, np.asarray(nxt)
+
+    arrivals = {
+        0: dict(prompt_ids=[3, 1, 4, 1, 5], max_new_tokens=9, temperature=0.8, top_k=8, top_p=0.9),
+        2: dict(prompt_ids=[2, 7], max_new_tokens=6, temperature=1.3, top_p=0.7),
+        3: dict(prompt_ids=[9, 9, 8], max_new_tokens=5),  # greedy, beside the sampled rows
+        5: dict(prompt_ids=[6, 2, 6], max_new_tokens=7, temperature=1.0, top_k=3),  # waits for a slot
+    }
+    rng, reqs, compared = cb._rng, [], 0
+    for i in range(14):
+        if i in arrivals:
+            reqs.append(cb.submit(arrivals[i].pop("prompt_ids"), **arrivals[i]))
+        admitted = cb.stats["admitted"]
+        cb._admit()  # as `step` begins; its own admit then finds the queue as this leaves it
+        for _ in range(cb.stats["admitted"] - admitted):
+            rng, _ = jax.random.split(rng)
+        live = {r.request_id: s for s, r in enumerate(cb._by_slot) if r is not None}
+        if live:
+            rng, want = eager_step(rng)  # before the step: it donates the cache
+        out = cb.step()
+        assert sorted(out) == sorted(live)
+        for rid, slot in live.items():
+            assert out[rid] == [want[slot]], (i, rid)
+            compared += 1
+    assert all(r.done for r in reqs) and compared == sum(r.max_new_tokens - 1 for r in reqs)
+    sampled = [r for r in reqs if r.temperature > 0]
+    assert any(len(set(r.out_tokens)) > 2 for r in sampled)
+    np.testing.assert_array_equal(jax.random.key_data(cb._rng), jax.random.key_data(rng))

@@ -205,22 +205,24 @@ def test_decode_step_is_told_which_slots_are_live():
     params = init_params(jax.random.key(4), cfg)
     slots, t_max = 4, 16
     cache = generate.init_cache(cfg, slots, t_max)
-    i32 = lambda *v: jnp.asarray(v, jnp.int32)
-    args = (i32(5, 9, 11, 3), i32(2, 0, 0, 0), i32(0, 0, 0, 0), jnp.zeros(4), i32(0, 0, 0, 0),
-            jnp.ones(4), jax.random.split(jax.random.key(0), slots))
-    step = continuous._decode_step_rowpos.__wrapped__
-    one_live = jnp.asarray([True, False, False, False])
-    nxt, _, touched = step(params, cache, *args, one_live, cfg=cfg)
+    floats = jnp.asarray([(0.0,) * 4, (1.0,) * 4], jnp.float32)  # temps, top_ps
+
+    def step(tokens, live, params=params, cfg=cfg):
+        # the int32 input's rows: tokens, pos, pads, top_ks and, when told, live
+        rows = [tokens, (2, 0, 0, 0), (0,) * 4, (0,) * 4] + ([live] if live else [])
+        nxt, _, _, touched = continuous._decode_step_rowpos.__wrapped__(
+            params, cache, jnp.asarray(rows, jnp.int32), floats, jax.random.key(0), cfg=cfg)
+        return nxt, touched
+
+    nxt, touched = step((5, 9, 11, 3), (1, 0, 0, 0))
     assert float(touched) == 2.0
-    other = (i32(5, 1, 2, 60),) + args[1:]
-    nxt2, _, touched2 = step(params, cache, *other, one_live, cfg=cfg)
+    nxt2, touched2 = step((5, 1, 2, 60), (1, 0, 0, 0))
     assert int(nxt[0]) == int(nxt2[0]) and float(touched2) == 2.0
-    _, _, all_live = step(params, cache, *args, jnp.ones(4, bool), cfg=cfg)
+    _, all_live = step((5, 9, 11, 3), (1,) * 4)
     assert 2.0 <= float(all_live) <= 8.0
-    # a dense model's step is not told and says nothing
+    # a dense model's step is not told (four rows, no fifth) and says nothing
     dense = TransformerConfig(**dict(SMALL, n_experts=0, moe_gated=False))
-    out = step(init_params(jax.random.key(4), dense), cache, *args, cfg=dense)
-    assert out[2] is None
+    assert step((5, 9, 11, 3), None, init_params(jax.random.key(4), dense), dense)[1] is None
 
 
 def test_batcher_reports_rows_experts_and_assignments(monkeypatch):
