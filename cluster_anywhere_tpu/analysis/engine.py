@@ -106,11 +106,11 @@ class SourceFile:
 
 
 def collect_files(root: str, subpaths: Optional[Iterable[str]] = None) -> List[SourceFile]:
-    """Parse every .py under `subpaths` (default: the package + scripts +
-    bench.py).  Tests are excluded: they exercise fake methods and sockets on
+    """Parse every .py under `subpaths` (default: the package + scripts).
+    Tests are excluded: they exercise fake methods and sockets on
     purpose, and a handler only a test reaches is still dead code."""
     if subpaths is None:
-        subpaths = ("cluster_anywhere_tpu", "scripts", "bench.py")
+        subpaths = ("cluster_anywhere_tpu", "scripts")
     def load(rel: str) -> SourceFile:
         try:
             return SourceFile(root, rel)

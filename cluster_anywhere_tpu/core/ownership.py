@@ -39,8 +39,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 # Ownership-plane counters (same plain-int discipline as protocol.WIRE_STATS
 # / worker.LEASE_STATS: owned-thread increments, flusher-only reads).
-# Shipped as ca_owner_* counters by util/metrics and summed into bench.py's
-# BENCH-json `ownerplane` block.
+# Shipped as ca_owner_* counters by util/metrics.
 OWNER_STATS: Dict[str, int] = {
     "refs_settled_local": 0,   # inc/dec applied to this process's own ledger
     "refs_sent_owner": 0,      # inc/dec sent to another process's ledger
@@ -54,11 +53,6 @@ OWNER_STATS: Dict[str, int] = {
     "syncs_sent": 0,           # owner_sync digests shipped to the head
     "syncs_full": 0,           # of those, full resyncs (reconnect)
 }
-
-
-def owner_stats() -> Dict[str, int]:
-    """Snapshot of this process's ownership-plane counters."""
-    return dict(OWNER_STATS)
 
 
 # ---------------------------------------------------------------- log helper

@@ -20,8 +20,10 @@ admitting or finishing requests never recompiles anything:
   the next step() can admit into it immediately — no head-of-line batching
   barrier, which is the whole point vs static generate() batching.
 
-Reference anchors: models/generate.py (single-position decode this
-generalizes), serve_llm.py (the deployment that drives it).
+This module is the scheduler, the per-row sampler and the jitted wrapper.
+The model's mathematics is models/generate.py's: `prefill`, and `decode_rows`,
+the decode program's body, which also owns the cache's layout.  serve_llm.py
+is the deployment that drives it.
 """
 
 from __future__ import annotations
@@ -36,17 +38,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from ..models.generate import (
-    _block_decode_rowpos,
-    _nucleus_mask,
-    _rms_norm,
-    _sample,
-    _scan_blocks,
-    decode_one,
-    prefill,
-)
+from ..models.generate import _nucleus_mask, _sample, decode_rows, prefill
 from ..models.transformer import TransformerConfig
 from ..util import tracing
 
@@ -112,22 +105,9 @@ def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
     live = live[0] != 0 if live else None
     temps, top_ps = floats
     keys = jax.random.split(rng, ints.shape[1] + 1)
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [S,1,E]
-
-    def body(x, bp, experts, kc, vc):
-        x, (kc, vc), touched = _block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads, live, experts)
-        return x, (kc, vc, touched)
-
-    x, (k_all, v_all, touched) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
-    if touched is not None:
-        touched = jnp.mean(touched.astype(jnp.float32))
-    with jax.named_scope("norm"):
-        x = _rms_norm(x, params["ln_f"])
-    with jax.named_scope("head"):
-        logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits, cache, touched = decode_rows(params, cache, tokens, pos, pads, cfg, live)
     nxt = _sample_rowwise(logits, keys[1:], temps, top_ks, top_ps)
-    return nxt, {"k": k_all, "v": v_all}, keys[0], touched
+    return nxt, cache, keys[0], touched
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -144,12 +124,12 @@ def _install_slot(cache, slot_k, slot_v, slot):
 def _suffix_step(params, rows, token, pos, pad, *, cfg):
     """One teacher-forced token over a SINGLE request's cache rows
     ([L, 1, t_max, KV, D], donated — updated in place) during chunked admit:
-    feeds a known prompt token at cache slot `pos`, returns the next-token
-    logits [1, V] and the updated rows.  The prefix-cache admit path runs
-    the un-cached tail of the prompt through this instead of prefill, so a
-    warm hit and a cold miss compute the suffix IDENTICALLY (bit-equal
-    outputs is the cache's correctness contract)."""
-    return decode_one(params, rows, token, pos, cfg, pad)
+    feeds a known prompt token at cache slot `pos` ([1], as token and pad
+    are), returns the next-token logits [1, V] and the updated rows.  The
+    prefix-cache admit path runs the un-cached tail of the prompt through this
+    instead of prefill, so a warm hit and a cold miss compute the suffix
+    IDENTICALLY (bit-equal outputs is the cache's correctness contract)."""
+    return decode_rows(params, rows, token, pos, pad, cfg)[:2]
 
 
 class PrefixCache:
@@ -401,26 +381,31 @@ class ContinuousBatcher:
         split = ((len(prompt) - 1) // self._split_quantum) * self._split_quantum
         return split if split >= self.prefix_block else 0
 
-    def _admit_full_prefill(self, req: Request, sp: tracing.span):
-        """Cold admit: prefill the whole prompt (one bucketed batch-1
-        program).  Returns (first-token logits [1,V], slot rows, pad,
-        next_pos).  `sp` is the request's `llm.admit` span."""
-        prompt = req.prompt_ids
-        bucket = self._bucket(len(prompt), req.max_new_tokens)
-        sp.set(bucket=bucket, prefix_hit=0)
+    def _prefill_padded(self, prompt: np.ndarray, bucket: int):
+        """Left-pad `prompt` to `bucket` and prefill it (one batch-1 program a
+        bucket).  Returns (first-token logits [1, V], its cache rows as a slot
+        holds them: k, v [L, t_max, KV, D], pad)."""
         with tracing.span("llm.admit.prefill"):
             padded = np.zeros(bucket, np.int32)
             pad = bucket - len(prompt)
             padded[pad:] = prompt  # LEFT pad: generate.py's prefill contract
-            logits, rowcache = prefill(
+            logits, rows = prefill(
                 self.params,
                 jnp.asarray(padded[None]),
                 self.cfg,
                 self.t_max,
                 pad=jnp.asarray([pad], np.int32),
             )
-            rows = {"k": rowcache["k"][:, 0], "v": rowcache["v"][:, 0]}
-        return logits, rows, pad, bucket
+            rows = {"k": rows["k"][:, 0], "v": rows["v"][:, 0]}
+        return logits, rows, pad
+
+    def _admit_full_prefill(self, req: Request, sp: tracing.span):
+        """Cold admit: prefill the whole prompt.  Returns (first-token logits
+        [1,V], slot rows, pad, next_pos).  `sp` is the request's `llm.admit`
+        span."""
+        bucket = self._bucket(len(req.prompt_ids), req.max_new_tokens)
+        sp.set(bucket=bucket, prefix_hit=0)
+        return (*self._prefill_padded(req.prompt_ids, bucket), bucket)
 
     def _admit_prefix_cached(self, req: Request, split: int, sp: tracing.span):
         """Chunked admit via the prefix cache: the block-aligned prefix
@@ -437,22 +422,10 @@ class ContinuousBatcher:
         entry = self.prefix_cache.get(key)
         sp.set(bucket=bucket, prefix_hit=int(entry is not None))
         if entry is None:
-            with tracing.span("llm.admit.prefill"):
-                padded = np.zeros(bucket, np.int32)
-                pad = bucket - split
-                padded[pad:] = prompt[:split]
-                _, rowcache = prefill(
-                    self.params,
-                    jnp.asarray(padded[None]),
-                    self.cfg,
-                    self.t_max,
-                    pad=jnp.asarray([pad], np.int32),
-                )
-                rows = {"k": rowcache["k"][:, 0:1], "v": rowcache["v"][:, 0:1]}
-                # store a snapshot BEFORE stepping: _suffix_step donates its rows
-                self.prefix_cache.put(
-                    key, {k: jnp.copy(v) for k, v in rows.items()}, pad
-                )
+            _, rows, pad = self._prefill_padded(prompt[:split], bucket)
+            rows = {k: v[:, None] for k, v in rows.items()}  # a batch of one again
+            # store a snapshot BEFORE stepping: _suffix_step donates its rows
+            self.prefix_cache.put(key, {k: jnp.copy(v) for k, v in rows.items()}, pad)
             self.stats["prefix_misses"] += 1
         else:
             pad = entry["pad"]
@@ -466,7 +439,7 @@ class ContinuousBatcher:
                 logits, rows = _suffix_step(
                     self.params, rows,
                     jnp.asarray([int(tok)], np.int32),
-                    jnp.asarray(bucket + i, np.int32),
+                    jnp.asarray([bucket + i], np.int32),
                     pad_arr, cfg=self.cfg,
                 )
         return logits, {"k": rows["k"][:, 0], "v": rows["v"][:, 0]}, pad, bucket + len(suffix)

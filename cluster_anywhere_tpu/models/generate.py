@@ -3,15 +3,25 @@
 static-shape cache + `lax.scan` decode loop so the whole generate compiles
 into one XLA program).
 
-Cache layout: one stacked pytree over layers —
+This module owns the cache's layout: one stacked pytree over layers,
     k, v: [L, B, T_max, H_kv, D]
-Decode steps write slot `pos` with `lax.dynamic_update_slice` and attend over
-the full T_max with a position mask (static shapes; no recompilation per
-step).  The attention reads a layer's cache once, as stored: the query
-[B, 1, H, D] is grouped to [B, 1, H_kv, H // H_kv, D] and contracted with
-k, v [B, T_max, H_kv, D] in the cache's dtype with an f32 accumulator
-(`_masked_attention`).  Nothing of the cache's size is repeated to H heads
-or copied to f32; only the prefill repeats its own k, v for the flash kernel.
+(`init_cache`), and the two attention cores that write it.  A block is
+transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
+them: the prefill core writes a prompt's k, v into zeroed rows and attends
+with the pad-masked flash kernel (`_prefill_block`); the decode core writes
+row b at slot pos[b] and attends over the full T_max with a position mask
+(`_block_decode_rowpos`: static shapes, no recompilation per step).  It reads
+a layer's cache once, as stored: the query [B, 1, H, D] is grouped to
+[B, 1, H_kv, H // H_kv, D] and contracted with k, v [B, T_max, H_kv, D] in the
+cache's dtype with an f32 accumulator (`_masked_attention`).  Nothing of the
+cache's size is repeated to H heads or copied to f32; only the prefill
+repeats its own k, v for the flash kernel.
+
+There is one decode block, with per-row positions, and one decode program
+body, `decode_rows`: `decode_one` (what `generate()` and `stream_generate`
+scan) is it with every row at the same position, and the continuous batcher's
+jitted step (llm/continuous.py) is it between unpacking its slot vectors and
+sampling.
 
 Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
@@ -32,14 +42,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.attention import attention
 from ..parallel.moe import EXPERT_MATRICES
-from .transformer import TransformerConfig, _moe, _project_qkv, _rms_norm, _rope
-
-
-def _gqa_repeat(x, cfg: TransformerConfig):
-    if cfg.n_kv_heads != cfg.n_heads:
-        x = jnp.repeat(x, cfg.n_heads // cfg.n_kv_heads, axis=2)
-    return x
+from .transformer import TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _rms_norm
 
 
 def _scan_blocks(body, x, params, cfg: TransformerConfig, *per_layer):
@@ -62,24 +67,6 @@ def _scan_blocks(body, x, params, cfg: TransformerConfig, *per_layer):
         return body(x, bp, (stack, layer), *slices)
 
     return lax.scan(step, x, (sliced, layers, *per_layer))
-
-
-def _mlp(bp, x, cfg, live=None, experts=None):
-    """The block's second half: x + FFN(norm(x)).  Returns (x, experts
-    touched): for a mixture of experts the FFN is the dropless routed path
-    (parallel/moe.py routed_ffn through transformer._moe, which says what
-    `experts` is) and the second value counts the experts that were given a
-    row; `live` [B, T] marks the rows that take experts (None: all).  A dense
-    model gives None."""
-    dt = x.dtype
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln2"])
-    with jax.named_scope("ffn"):
-        if cfg.n_experts:
-            out, _, touched = _moe(bp, y, cfg, live, experts)
-            return x + out, touched
-        gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-        return x + gated @ bp["w_down"].astype(dt), None
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
@@ -114,33 +101,6 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     }
 
 
-def _block_decode(bp, x, layer_cache, pos, cfg: TransformerConfig, pad=None, experts=None):
-    """One block, one token. x: [B, 1, E]; layer_cache: (k,v) [B,Tmax,KV,D].
-    pad: [B] left-pad counts — the RoPE position of the token written at cache
-    slot `pos` is `pos - pad[b]` so each row's positions count real tokens.
-    Returns (x, (k, v))."""
-    k_cache, v_cache = layer_cache
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln1"])
-    with jax.named_scope("attn.qkv"):
-        q, k, v = _project_qkv(bp, y, cfg)
-    with jax.named_scope("attn.rope"):
-        if pad is None:
-            positions = jnp.array([0]) + pos  # [1]
-        else:
-            positions = (pos - pad)[:, None]  # [B, 1]
-        q, k = _rope(q, k, positions, cfg)
-    with jax.named_scope("attn.cache"):
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
-    with jax.named_scope("attn.core"):
-        attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pad)
-    with jax.named_scope("attn.out"):
-        b = x.shape[0]
-        x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
-    return _mlp(bp, x, cfg, experts=experts)[0], (k_cache, v_cache)
-
-
 def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads, live=None,
                          experts=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
@@ -149,26 +109,21 @@ def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads, 
     pos[b], takes RoPE position pos[b] - pads[b], and attends to cache
     slots [pads[b], pos[b]].  live: [B] bool, the rows that hold a request:
     an empty slot's row takes no expert (None: every row does).  Returns
-    (x, (k, v), experts touched or None: `_mlp`)."""
-    k_cache, v_cache = layer_cache
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln1"])
-    with jax.named_scope("attn.qkv"):
-        q, k, v = _project_qkv(bp, y, cfg)
-    with jax.named_scope("attn.rope"):
-        positions = (pos - pads)[:, None]  # [B, 1]
-        q, k = _rope(q, k, positions, cfg)
-    b = x.shape[0]
-    with jax.named_scope("attn.cache"):
-        rows = jnp.arange(b)
-        k_cache = k_cache.at[rows, pos].set(k[:, 0])
-        v_cache = v_cache.at[rows, pos].set(v[:, 0])
-    with jax.named_scope("attn.core"):
-        attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pads)  # per-row length
-    with jax.named_scope("attn.out"):
-        x = x + attn.reshape(b, 1, -1) @ bp["wo"].astype(x.dtype)
-    x, touched = _mlp(bp, x, cfg, None if live is None else live[:, None], experts)
-    return x, (k_cache, v_cache), touched
+    (x, (k, v), experts touched or None: `_ffn_half`)."""
+
+    def core(q, k, v):
+        k_cache, v_cache = layer_cache
+        with jax.named_scope("attn.cache"):
+            rows = jnp.arange(x.shape[0])
+            k_cache = k_cache.at[rows, pos].set(k[:, 0])
+            v_cache = v_cache.at[rows, pos].set(v[:, 0])
+        with jax.named_scope("attn.core"):
+            attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pads)  # per-row length
+        return attn, (k_cache, v_cache)
+
+    x, layer_cache = _attention_half(bp, x, cfg, (pos - pads)[:, None], core)
+    x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
+    return x, layer_cache, touched
 
 
 def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None):
@@ -177,36 +132,28 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
     pad-token keys (ADVICE r1: unmasked pads skewed generation)."""
     b, t, _ = x.shape
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln1"])
-    with jax.named_scope("attn.qkv"):
-        q, k, v = _project_qkv(bp, y, cfg)
-    with jax.named_scope("attn.rope"):
-        if pad is None:
-            positions = jnp.arange(t)
-        else:
-            positions = jnp.maximum(jnp.arange(t)[None, :] - pad[:, None], 0)  # [B,T]
-        q, k = _rope(q, k, positions, cfg)
-    with jax.named_scope("attn.cache"):
-        k_cache = jnp.zeros((b, t_max, cfg.n_kv_heads, cfg.d_head), x.dtype)
-        v_cache = jnp.zeros_like(k_cache)
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
-    # causal attention within the prompt (q already has full heads; only
-    # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
-    # Pallas flash kernel at every prompt length (ops/attention.py), so
-    # prefill never materializes the [T, T] score matrix.
-    from ..ops.attention import attention as _attn
 
-    with jax.named_scope("attn.core"):
-        kr = _gqa_repeat(k, cfg)
-        vr = _gqa_repeat(v, cfg)
-        attn = _attn(q, kr, vr, causal=True, pad=pad).reshape(b, t, -1).astype(x.dtype)
-    with jax.named_scope("attn.out"):
-        x = x + attn @ bp["wo"].astype(x.dtype)
+    def core(q, k, v):
+        with jax.named_scope("attn.cache"):
+            k_cache = jnp.zeros((b, t_max, cfg.n_kv_heads, cfg.d_head), x.dtype)
+            v_cache = jnp.zeros_like(k_cache)
+            k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
+            v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
+        # causal attention within the prompt (q already has full heads; only
+        # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
+        # Pallas flash kernel at every prompt length (ops/attention.py), so
+        # prefill never materializes the [T, T] score matrix.
+        with jax.named_scope("attn.core"):
+            k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
+            return attention(q, k, v, causal=True, pad=pad).astype(x.dtype), (k_cache, v_cache)
+
+    positions = jnp.arange(t)
+    if pad is not None:
+        positions = jnp.maximum(positions[None, :] - pad[:, None], 0)  # [B, T]
+    x, layer_cache = _attention_half(bp, x, cfg, positions, core)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
-    return _mlp(bp, x, cfg, live, experts)[0], (k_cache, v_cache)
+    return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
 
 
 def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
@@ -227,21 +174,35 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     return logits, {"k": k_all, "v": v_all}
 
 
-def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
-    """token: [B] -> (logits [B, V], updated cache)."""
+def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
+    """The decode program's body: one token for every row of the cache, each
+    at its own depth.  tokens, pos, pads: [B] (`_block_decode_rowpos` says what
+    each row does with its own); live: [B] bool or None.  Returns (logits
+    [B, V], updated cache, experts touched: the mean over the layers of the
+    experts that were given a row, None for a dense model)."""
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[token][:, None, :]  # [B,1,E]
+        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
 
     def body(x, bp, experts, kc, vc):
-        x, (kc, vc) = _block_decode(bp, x, (kc, vc), pos, cfg, pad, experts)
-        return x, (kc, vc)
+        x, (kc, vc), touched = _block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads, live, experts)
+        return x, (kc, vc, touched)
 
-    x, (k_all, v_all) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
+    x, (k_all, v_all, touched) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
+    if touched is not None:
+        touched = jnp.mean(touched.astype(jnp.float32))
     with jax.named_scope("norm"):
         x = _rms_norm(x, params["ln_f"])
     with jax.named_scope("head"):
         logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": k_all, "v": v_all}
+    return logits, {"k": k_all, "v": v_all}, touched
+
+
+def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
+    """token: [B], every row at cache slot `pos` -> (logits [B, V], updated
+    cache).  pad: [B] left-pad counts, None for none."""
+    pos = jnp.broadcast_to(pos, token.shape)
+    pads = jnp.zeros_like(pos) if pad is None else pad
+    return decode_rows(params, cache, token, pos, pads, cfg)[:2]
 
 
 def _nucleus_mask(scaled, top_p):

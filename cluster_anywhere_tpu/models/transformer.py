@@ -10,8 +10,14 @@ Design points (vs. the reference, which delegates all modeling to torch):
   parallel.ulysses) under a partial-manual shard_map over {'pp','sp'}
 - bfloat16 activations, fp32 params/optimizer, RoPE, GQA, SwiGLU, RMSNorm
 
+A block is two halves, each written once for training, prefill and decode
+(`_attention_half`, `_ffn_half`): what differs between the three is the
+rotary positions and the attention core, so those are the attention half's
+arguments.  This module holds the training core (`_attention`); the two that
+write a key/value cache are in models/generate.py, which owns its layout.
+
 The model is the `entry()` / `dryrun_multichip()` flagship in
-__graft_entry__.py and the subject of bench.py's training benchmark.
+__graft_entry__.py and what benchmarks/ trains and serves.
 """
 
 from __future__ import annotations
@@ -27,7 +33,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention as dense_attention
-from ..ops.attention import flash_attention, reference_attention
 from ..parallel.pipeline import pipeline_apply
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
@@ -47,10 +52,6 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16  # activation/compute dtype
     param_dtype: Any = jnp.float32
     attn_impl: str = "auto"  # dense | ring | ulysses | auto
-    # flash kernel tile overrides (None = the kernel's measured defaults);
-    # exposed so the bench can sweep tiles per shape without forking the model
-    flash_block_q: Any = None
-    flash_block_k: Any = None
     pp: int = 1
     sp: int = 1
     num_microbatches: int = 1
@@ -301,76 +302,90 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes):
         fn = functools.partial(ring_attention, axis_name="sp", causal=True)
     elif impl == "ulysses" and "sp" in manual_axes:
         fn = functools.partial(ulysses_attention, axis_name="sp", causal=True)
-    elif impl == "jnp":  # force the XLA-fused dense path (perf A/B)
-        fn = functools.partial(reference_attention, causal=True)
-    elif impl == "flash":  # force the Pallas kernel (perf A/B)
-        fn = functools.partial(
-            flash_attention, causal=True,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-        )
-    else:  # auto dense path: the dispatcher picks by backend
+    else:  # dense: the dispatcher picks by backend
         fn = functools.partial(dense_attention, causal=True)
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
-def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
-    """One transformer block. x: [B, T_local, E].  manual_axes: the mesh axes
-    the caller's shard_map is already manual over (pp/sp/ep subset)."""
-    b, t, e = x.shape
-    h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    dt = x.dtype
+def _gqa_repeat(x, cfg: TransformerConfig):
+    """k or v [B, T, KV, D] repeated to the query's H heads, for a core that
+    wants them alike (the flash kernel, ring, Ulysses)."""
+    if cfg.n_kv_heads != cfg.n_heads:
+        x = jnp.repeat(x, cfg.n_heads // cfg.n_kv_heads, axis=2)
+    return x
 
-    # the scope names of models/generate.py, the same in every layer: a
-    # device trace sums a kind of work over the depth (forward, recomputation
-    # and backward carry the name; metadata only)
+
+def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
+    """A block's first half, for training, prefill and decode alike:
+    x + wo(core(rope(qkv(norm(x))))).  x: [B, T, E]; positions: [T] or [B, T],
+    what `_rope` takes; `core(q, k, v) -> (attn [B, T, H, D], extra)` attends
+    under its own scopes (`attn.core`, and `attn.cache` where it keeps one) and
+    hands back what its caller keeps of k and v.  Returns (x, extra).
+
+    The scope names are the same in every layer and every program: a device
+    trace sums a kind of work over the depth (forward, recomputation and
+    backward carry the name; metadata only)."""
+    b, t, _ = x.shape
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln1"])
     with jax.named_scope("attn.qkv"):
         q, k, v = _project_qkv(bp, y, cfg)
-
     with jax.named_scope("attn.rope"):
-        if "sp" in manual_axes and cfg.sp > 1:
-            offset = lax.axis_index("sp") * t
-        else:
-            offset = 0
-        positions = offset + jnp.arange(t)
         q, k = _rope(q, k, positions, cfg)
-
-    with jax.named_scope("attn.core"):
-        if kv != h:  # GQA: repeat kv heads
-            rep = h // kv
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-
-        attn = _attention(q, k, v, cfg, mesh, manual_axes).reshape(b, t, h * d)
+    attn, extra = core(q, k, v)
     with jax.named_scope("attn.out"):
-        x = x + attn @ bp["wo"].astype(dt)
+        x = x + attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
+    return x, extra
 
+
+def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
+    """A block's second half: x + FFN(norm(x)), the FFN dense SwiGLU or a
+    mixture of experts.  On one device the mixture is the dropless routed path
+    (`_moe`, which says what `live` and `experts` are); with 'ep' among the
+    caller's manual axes its experts are sharded and tokens travel to them
+    (parallel/moe.py moe_ffn).  Returns (x, aux loss, experts touched): None
+    for a dense model, and no count from moe_ffn."""
+    b, t, e = x.shape
+    dt = x.dtype
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln2"])
     with jax.named_scope("ffn"):
-        if cfg.n_experts and "ep" not in manual_axes:
-            out, aux, _ = _moe(bp, y, cfg)
-            return x + out, aux
-        if cfg.n_experts:
-            # experts sharded over 'ep': tokens flatten, travel to their
-            # expert's device, come back (traced under shard_map manual over
-            # 'ep' — see forward())
-            from ..parallel.moe import moe_ffn
+        if not cfg.n_experts:
+            gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
+            return x + gated @ bp["w_down"].astype(dt), None, None
+        if "ep" not in manual_axes:
+            out, aux, touched = _moe(bp, y, cfg, live, experts)
+            return x + out, aux, touched
+        # tokens flatten, travel to their expert's device, come back (traced
+        # under shard_map manual over 'ep': see forward())
+        from ..parallel.moe import moe_ffn
 
-            r = moe_ffn(
-                y.reshape(b * t, e),
-                bp["router"].astype(dt),
-                bp["w_in"].astype(dt),
-                bp["w_out"].astype(dt),
-                axis_name="ep",
-                capacity_factor=cfg.capacity_factor,
-            )
-            x = x + r.out.reshape(b, t, e)
-            return x, r.aux_loss.astype(jnp.float32)
-        gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-        x = x + gated @ bp["w_down"].astype(dt)
-    return x, jnp.zeros((), jnp.float32)
+        r = moe_ffn(
+            y.reshape(b * t, e),
+            bp["router"].astype(dt),
+            bp["w_in"].astype(dt),
+            bp["w_out"].astype(dt),
+            axis_name="ep",
+            capacity_factor=cfg.capacity_factor,
+        )
+        return x + r.out.reshape(b, t, e), r.aux_loss.astype(jnp.float32), None
+
+
+def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
+    """One transformer block. x: [B, T_local, E].  manual_axes: the mesh axes
+    the caller's shard_map is already manual over (pp/sp/ep subset).  Returns
+    (x, the MoE load-balance loss: 0 dense)."""
+    t = x.shape[1]
+    offset = lax.axis_index("sp") * t if "sp" in manual_axes and cfg.sp > 1 else 0
+
+    def core(q, k, v):
+        with jax.named_scope("attn.core"):
+            k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
+            return _attention(q, k, v, cfg, mesh, manual_axes), None
+
+    x, _ = _attention_half(bp, x, cfg, offset + jnp.arange(t), core)
+    x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
+    return x, jnp.zeros((), jnp.float32) if aux is None else aux
 
 
 def _stage_forward(stage_blocks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):

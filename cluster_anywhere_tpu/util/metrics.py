@@ -656,8 +656,7 @@ def get_metrics_snapshot() -> Dict[str, dict]:
 def merged_histogram(rec: Optional[dict]) -> Tuple[List[float], List[int], int]:
     """Merge a snapshot histogram's tagged cells into one
     (bounds, cumulative-ready buckets, count) triple — the shape
-    histogram_quantile() consumes.  Shared by bench.py's BENCH-json blocks
-    and util.state's plane summaries (one definition, not N copies)."""
+    histogram_quantile() consumes, for util.state's plane summaries."""
     bounds: List[float] = []
     buckets: List[int] = []
     count = 0

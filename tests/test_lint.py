@@ -997,7 +997,8 @@ def test_ca_cli_routes_lint_flags_directly(tmp_path, capsys):
 
 
 def test_unparsable_top_level_file_is_a_finding_not_a_crash(tmp_path):
-    (tmp_path / "bench.py").write_text("def broken(:\n")
+    (tmp_path / "scripts").mkdir()
+    (tmp_path / "scripts" / "probe.py").write_text("def broken(:\n")
     report = engine.run_lint(
         root=str(tmp_path), baseline_file=str(tmp_path / "b.json")
     )

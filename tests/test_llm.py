@@ -736,6 +736,86 @@ def test_decode_step_inputs_reach_the_device_in_one_dispatch(model, monkeypatch)
     assert eager == []
 
 
+@pytest.mark.parametrize("program", ["forward", "prefill", "decode_one", "decode_step"])
+@pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE], ids=["dense", "mixture"])
+def test_every_program_traces_the_one_block(model, program, monkeypatch):
+    """A decoder block is written once: training's `forward`, `prefill`,
+    `decode_one` and the batcher's `_decode_step_rowpos` all trace through
+    `transformer._attention_half` and `transformer._ffn_half`, and outside the
+    two nothing projects q, k, v or norms but the final norm.  A queued change
+    to the block (window attention, a shared expert, a new cache layout) then
+    has one site to edit."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models import generate, transformer
+
+    assert generate._attention_half is transformer._attention_half
+    assert generate._ffn_half is transformer._ffn_half
+    calls = {"_attention_half": 0, "_ffn_half": 0, "_rms_norm": 0, "_project_qkv": 0}
+    inside = []
+
+    def counted(name, is_half):
+        inner = getattr(transformer, name)
+
+        def wrapper(*a, **k):
+            if not is_half:  # a norm or a projection: counted where no half is running
+                calls[name] += not inside
+                return inner(*a, **k)
+            calls[name] += 1
+            inside.append(name)
+            try:
+                return inner(*a, **k)
+            finally:
+                inside.pop()
+
+        for module in (transformer, generate):
+            if hasattr(module, name):
+                monkeypatch.setattr(module, name, wrapper)
+
+    for name in calls:
+        counted(name, is_half=name.endswith("_half"))
+    cfg = transformer.TransformerConfig(**model)
+    slots, t_max = 4, 32
+    fn, args = _decode_step_program(cfg, slots, t_max)
+    params, cache = args[:2]
+    ids, row = jax.ShapeDtypeStruct((slots, 8), jnp.int32), jax.ShapeDtypeStruct((slots,), jnp.int32)
+    if program == "forward":
+        fn, args = lambda p, i: transformer.forward(p, i, cfg), (params, ids)
+    elif program == "prefill":
+        fn, args = lambda p, i, pad: generate.prefill(p, i, cfg, t_max, pad), (params, ids, row)
+    elif program == "decode_one":
+        fn = lambda p, c, tok, pos: generate.decode_one(p, c, tok, pos, cfg)
+        args = (params, cache, row, jax.ShapeDtypeStruct((), jnp.int32))
+    jax.eval_shape(fn, *args)
+    # the layer scan traces its body once
+    assert calls == {"_attention_half": 1, "_ffn_half": 1, "_rms_norm": 1, "_project_qkv": 0}
+
+
+def test_the_batcher_holds_no_model_mathematics():
+    """`llm/continuous.py` is the scheduler, the sampler and the jitted
+    wrapper: of `models/` it takes `prefill`, the decode program's body and the
+    two sampling helpers, and it names no block, norm or layer loop."""
+    import ast
+    import inspect
+
+    from cluster_anywhere_tpu.llm import continuous
+
+    source = inspect.getsource(continuous)
+    nodes = list(ast.walk(ast.parse(source)))
+    imported = {
+        alias.name
+        for node in nodes
+        if isinstance(node, ast.ImportFrom) and (node.module or "").startswith("models")
+        for alias in node.names
+    }
+    assert imported == {"prefill", "decode_rows", "_sample", "_nucleus_mask", "TransformerConfig"}
+    for name in ("_rms_norm", "_scan_blocks", "_block_", "_half", "_project_qkv", "_rope", "lax.scan"):
+        assert name not in source, name
+    called = [n.func.id for n in nodes if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)]
+    assert called.count("prefill") == 1  # one pad-and-prefill for both admits
+
+
 def test_sampled_streams_are_the_eager_split_and_sample():
     """Sampled streams keep their bits: with temperature, top-k and top-p set
     and requests admitted at different steps (so the admit's `split(rng)`

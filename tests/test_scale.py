@@ -2,7 +2,7 @@
 release/benchmarks single-node table (BASELINE.md) — many returns, many
 args, many objects, deep task queues, multi-GiB objects.  Bounds are
 completion deadlines (generous for shared CI hosts), not perf assertions;
-the envelope numbers themselves come from bench.py / ca microbenchmark."""
+the envelope numbers themselves come from ca microbenchmark."""
 
 import time
 
