@@ -9,9 +9,9 @@ depths decode together in ONE jitted step whose shapes never change — slot
 count and cache length are static, per-row positions are traced — so
 admitting or finishing requests never recompiles anything:
 
-- admit: a queued request prefills (batch-1 program, prompt padded to a
-  bucket length to bound compile count) and its cache rows scatter into its
-  slot between decode steps.
+- admit: a queued request prefills (a compiled batch-1 program, the prompt
+  padded to a bucket length so that there is one program a bucket) and its
+  cache rows scatter into its slot between decode steps.
 - decode: every live slot advances one token per step.  Per-row cache
   positions/pads drive RoPE and masking; finished or empty slots still
   compute (their lanes are garbage) but write only to their own frozen
@@ -111,13 +111,12 @@ def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _install_slot(cache, slot_k, slot_v, slot):
-    """Scatter one request's prefilled rows into its slot (on device)."""
+def _install_slot(cache, rows, slot):
+    """Scatter one request's prefilled rows (k, v [L, 1, t_max, KV, D], as
+    `prefill` and `_suffix_step` return them) into its slot, on the device:
+    the batch axis comes off here, not in a dispatch of its own."""
     with jax.named_scope("attn.cache"):
-        return {
-            "k": cache["k"].at[:, slot].set(slot_k),
-            "v": cache["v"].at[:, slot].set(slot_v),
-        }
+        return {kv: cache[kv].at[:, slot].set(rows[kv][:, 0]) for kv in ("k", "v")}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -209,12 +208,13 @@ class ContinuousBatcher:
             PrefixCache(prefix_cache_entries) if prefix_cache_entries > 0 else None
         )
         self.prefix_block = max(1, int(prefix_block))
-        # split granularity: prefix prefill compiles one XLA program per
-        # DISTINCT split length (the configured buckets rarely leave decode
-        # room for bucket + suffix + max_new, so the exact-split fallback is
-        # the common case).  Quantizing splits to max(block, longest
-        # bucket/8) bounds the program count at ~8 for any prompt length —
-        # a recompile stalls the shared pump thread, so an unbounded shape
+        # split granularity: the prefix goes through the same compiled
+        # `prefill` as a whole prompt, one program a DISTINCT padded length
+        # (the configured buckets rarely leave decode room for bucket +
+        # suffix + max_new, so `_bucket`'s exact-length fall-back is the
+        # common case).  Quantizing splits to max(block, longest bucket/8)
+        # bounds that family at ~8 programs for any prompt length: a
+        # compile stalls the shared pump thread, so an unbounded shape
         # family would freeze live streams on long-tail traffic.
         longest = self.prefill_buckets[-1] if self.prefill_buckets else t_max
         q = max(self.prefix_block, longest // 8)
@@ -255,6 +255,9 @@ class ContinuousBatcher:
             # (token, expert) pairs a layer's routed experts were given, in
             # admits and steps; stays 0 for a dense model
             "moe_assignments": 0,
+            # admits that found no compiled prefill for their padded length
+            # and traced one; stays where it is once every bucket is warm
+            "prefill_traces": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -366,8 +369,10 @@ class ContinuousBatcher:
 
     def _bucket(self, n: int, max_new: int) -> int:
         """Smallest bucket holding the prompt AND leaving room to decode;
-        falls back to the exact prompt length (one extra compile) when every
-        bucket would overflow the cache."""
+        falls back to the exact prompt length when every bucket would
+        overflow the cache.  The fall-back is one more prefill program for
+        each DISTINCT length, compiled at that length's first admit on the
+        pump's thread (`prefill_traces` counts them)."""
         for b in self.prefill_buckets:
             if n <= b and b + max_new <= self.t_max:
                 return b
@@ -382,27 +387,26 @@ class ContinuousBatcher:
         return split if split >= self.prefix_block else 0
 
     def _prefill_padded(self, prompt: np.ndarray, bucket: int):
-        """Left-pad `prompt` to `bucket` and prefill it (one batch-1 program a
-        bucket).  Returns (first-token logits [1, V], its cache rows as a slot
-        holds them: k, v [L, t_max, KV, D], pad)."""
+        """Left-pad `prompt` to `bucket` and prefill it: one compiled batch-1
+        program a bucket, traced at the bucket's first admit and one dispatch
+        thereafter (the padded ids and the pad count go as the host arrays
+        they are).  Returns (first-token logits [1, V], its cache rows as a
+        batch of one: k, v [L, 1, t_max, KV, D], pad)."""
         with tracing.span("llm.admit.prefill"):
-            padded = np.zeros(bucket, np.int32)
+            padded = np.zeros((1, bucket), np.int32)
             pad = bucket - len(prompt)
-            padded[pad:] = prompt  # LEFT pad: generate.py's prefill contract
+            padded[0, pad:] = prompt  # LEFT pad: generate.py's prefill contract
+            programs = prefill._cache_size()
             logits, rows = prefill(
-                self.params,
-                jnp.asarray(padded[None]),
-                self.cfg,
-                self.t_max,
-                pad=jnp.asarray([pad], np.int32),
+                self.params, padded, self.cfg, self.t_max, pad=np.asarray([pad], np.int32)
             )
-            rows = {"k": rows["k"][:, 0], "v": rows["v"][:, 0]}
+            self.stats["prefill_traces"] += prefill._cache_size() - programs
         return logits, rows, pad
 
     def _admit_full_prefill(self, req: Request, sp: tracing.span):
         """Cold admit: prefill the whole prompt.  Returns (first-token logits
-        [1,V], slot rows, pad, next_pos).  `sp` is the request's `llm.admit`
-        span."""
+        [1,V], its rows as a batch of one, pad, next_pos).  `sp` is the
+        request's `llm.admit` span."""
         bucket = self._bucket(len(req.prompt_ids), req.max_new_tokens)
         sp.set(bucket=bucket, prefix_hit=0)
         return (*self._prefill_padded(req.prompt_ids, bucket), bucket)
@@ -423,7 +427,6 @@ class ContinuousBatcher:
         sp.set(bucket=bucket, prefix_hit=int(entry is not None))
         if entry is None:
             _, rows, pad = self._prefill_padded(prompt[:split], bucket)
-            rows = {k: v[:, None] for k, v in rows.items()}  # a batch of one again
             # store a snapshot BEFORE stepping: _suffix_step donates its rows
             self.prefix_cache.put(key, {k: jnp.copy(v) for k, v in rows.items()}, pad)
             self.stats["prefix_misses"] += 1
@@ -442,7 +445,7 @@ class ContinuousBatcher:
                     jnp.asarray([bucket + i], np.int32),
                     pad_arr, cfg=self.cfg,
                 )
-        return logits, {"k": rows["k"][:, 0], "v": rows["v"][:, 0]}, pad, bucket + len(suffix)
+        return logits, rows, pad, bucket + len(suffix)
 
     def _admit(self, out: Optional[Dict[int, List[int]]] = None) -> None:
         while self.queue and None in self._by_slot:
@@ -465,6 +468,7 @@ class ContinuousBatcher:
         )
         with sp:
             slot = self._by_slot.index(None)
+            traces = self.stats["prefill_traces"]
             split = (
                 self._prefix_split(req.prompt_ids)
                 if self.prefix_cache is not None
@@ -474,8 +478,10 @@ class ContinuousBatcher:
                 logits, rows, pad, next_pos = self._admit_prefix_cached(req, split, sp)
             else:
                 logits, rows, pad, next_pos = self._admit_full_prefill(req, sp)
+            # 1 where this admit traced its bucket's prefill program
+            sp.set(traced=self.stats["prefill_traces"] - traces)
             with tracing.span("llm.admit.install"):
-                self.cache = _install_slot(self.cache, rows["k"], rows["v"], slot)
+                self.cache = _install_slot(self.cache, rows, slot)
             with tracing.span("llm.admit.sample"):
                 self._rng, k = jax.random.split(self._rng)
                 first = int(

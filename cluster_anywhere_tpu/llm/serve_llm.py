@@ -222,6 +222,8 @@ class ContinuousLLMServer:
                  "seconds submitting callers waited for the replica's lock"),
                 ("moe_assignments", "ca_serve_moe_assignments_total",
                  "(token, expert) pairs a layer's routed experts were given"),
+                ("prefill_traces", "ca_serve_prefill_traces_total",
+                 "LLM admits that traced and compiled a prefill program on the pump's thread"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

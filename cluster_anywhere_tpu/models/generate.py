@@ -23,6 +23,13 @@ scan) is it with every row at the same position, and the continuous batcher's
 jitted step (llm/continuous.py) is it between unpacking its slot vectors and
 sampling.
 
+Compiled entry points: `prefill` (one program a prompt shape: the continuous
+batcher's admit calls it as it is, a bucket a program), `generate` (prefill
+and the scanned decode loop as one program) and `_stream_fns`' pair.
+`decode_rows`, `decode_one` and `_sample` are bodies: plain functions that run
+inside their caller's program (the batcher's `_decode_step_rowpos` and
+`_suffix_step`, `generate`'s scan), or eagerly where a caller has none.
+
 Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
 `attn.core`, `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
@@ -156,9 +163,13 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
     return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "t_max"))
 def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     """ids: [B, T_prompt] -> (last-token logits [B, V], cache).
-    pad: optional [B] left-pad counts (see _prefill_block)."""
+    pad: optional [B] left-pad counts (see _prefill_block).  Compiled: one
+    program for each shape of `ids` (with or without `pad`), `cfg` and
+    `t_max`, traced at its first call and called thereafter; under another
+    jit (`generate`, `_stream_fns`) it is a nested call of that program."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 

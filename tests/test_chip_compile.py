@@ -29,6 +29,11 @@ from cluster_anywhere_tpu.parallel.mesh import AXES, MeshSpec
 attention = importlib.import_module("cluster_anywhere_tpu.ops.attention")
 
 FLAGSHIP = dict(d_model=1024, n_heads=8, n_kv_heads=4, d_head=128, d_ff=4096)
+# OLMoE's widths (64 experts of 2048 x 1024, 8 a token), three layers deep
+OLMOE3 = dict(
+    vocab_size=512, n_layers=3, d_model=2048, n_heads=16, n_kv_heads=16, d_head=128, d_ff=1024,
+    n_experts=64, n_experts_per_tok=8, moe_gated=True, qk_norm=True, param_dtype=jnp.bfloat16,
+)
 
 
 @pytest.fixture(scope="module")
@@ -96,19 +101,24 @@ def test_dispatcher_pads_to_the_kernel_tile(v5e, on_tpu, t):
     assert _has_kernel(jax.jit(fn).lower(q, q, q, pad).compile())
 
 
-@pytest.mark.parametrize("bucket", [128, 256])
-def test_padded_prefill_compiles_at_flagship_width(v5e, on_tpu, bucket):
-    """The continuous batcher's admit: a batch-1 left-padded prompt."""
-    cfg = transformer.TransformerConfig(vocab_size=259, n_layers=8, **FLAGSHIP)
-    one = SingleDeviceSharding(v5e[0])
+def _compiled_admit_prefill(cfg, bucket, t_max, device):
+    """`generate.prefill`, the jitted function itself, compiled as an admit
+    calls it: a batch-1 left-padded prompt of one bucket's length."""
+    one = SingleDeviceSharding(device)
     on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
     params = jax.tree_util.tree_map(
         on_chip, jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
     )
     ids = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one)
     pad = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
-    fn = lambda p, i, pad: generate.prefill(p, i, cfg, bucket + 32, pad=pad)
-    assert _has_kernel(jax.jit(fn).lower(params, ids, pad).compile())
+    return generate.prefill.lower(params, ids, cfg, t_max, pad=pad).compile()
+
+
+@pytest.mark.parametrize("bucket", [128, 256])
+def test_padded_prefill_compiles_at_flagship_width(v5e, on_tpu, bucket):
+    """The continuous batcher's admit: the program it runs, with the kernel."""
+    cfg = transformer.TransformerConfig(vocab_size=259, n_layers=8, **FLAGSHIP)
+    assert _has_kernel(_compiled_admit_prefill(cfg, bucket, bucket + 32, v5e[0]))
 
 
 def _buffers(compiled):
@@ -157,19 +167,25 @@ def test_decode_block_reads_the_cache_as_stored(v5e):
     assert widened == []
 
 
+def _reads_the_experts_where_they_are(compiled) -> bool:
+    """A program of `OLMOE3`: the grouped matmul is a kernel, the three stacked
+    [L, X, E, F] matrices are seen whole, and no buffer of one layer's experts
+    (X * E * F elements) exists."""
+    layer = OLMOE3["n_experts"] * OLMOE3["d_model"] * OLMOE3["d_ff"]
+    buffers = _buffers(compiled)
+    stacks = sum(1 for _, n, _ in buffers if n == OLMOE3["n_layers"] * layer)
+    return _has_kernel(compiled) and stacks >= 3 and not [b for b in buffers if b[1] == layer]
+
+
 def test_decode_step_reads_the_experts_where_they_are(v5e):
-    """The decode step of a mixture of experts at OLMoE's widths (64 experts of
-    2048 x 1024, 8 a token, 32 slots), three layers deep: the grouped matmul is
-    a kernel of the compiler's, and the layer scan hands it the stacked
-    [L, X, E, F] matrices whole.  No buffer of one layer's experts (X * E * F
-    elements) exists: sliced out of the stack by the scan it was a copy of all
-    64 experts at every step, 0.7 ms a matrix and layer on the chip."""
+    """The decode step of a mixture of experts at OLMoE's widths (32 slots):
+    the grouped matmul is a kernel of the compiler's, and the layer scan hands
+    it the stacked [L, X, E, F] matrices whole.  Sliced out of the stack by the
+    scan, a layer's experts were a copy of all 64 at every step, 0.7 ms a
+    matrix and layer on the chip."""
     from cluster_anywhere_tpu.llm import continuous
 
-    cfg = transformer.TransformerConfig(
-        vocab_size=512, n_layers=3, d_model=2048, n_heads=16, n_kv_heads=16, d_head=128, d_ff=1024,
-        n_experts=64, n_experts_per_tok=8, moe_gated=True, qk_norm=True, param_dtype=jnp.bfloat16,
-    )
+    cfg = transformer.TransformerConfig(**OLMOE3)
     slots, t_max = 32, 768
     one = SingleDeviceSharding(v5e[0])
     on_chip = lambda tree: jax.tree_util.tree_map(
@@ -181,11 +197,16 @@ def test_decode_step_reads_the_experts_where_they_are(v5e):
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
     fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
-    assert _has_kernel(compiled)
-    layer, stack = 64 * 2048 * 1024, 3 * 64 * 2048 * 1024
-    buffers = _buffers(compiled)
-    assert sum(1 for dt, n, _ in buffers if n == stack) >= 3  # the three stacks are seen
-    assert [b for b in buffers if b[1] == layer] == []
+    assert _reads_the_experts_where_they_are(compiled)
+
+
+@pytest.mark.parametrize("bucket", [64, 512])
+def test_padded_prefill_reads_the_experts_where_they_are(v5e, on_tpu, bucket):
+    """The admit's prefill of a mixture of experts at OLMoE's widths, at the
+    shortest and the longest bucket of the serving cells: the module an admit
+    runs scans its layers as the decode step does."""
+    compiled = _compiled_admit_prefill(transformer.TransformerConfig(**OLMOE3), bucket, 768, v5e[0])
+    assert _reads_the_experts_where_they_are(compiled)
 
 
 @pytest.mark.parametrize(

@@ -1,6 +1,8 @@
 """LLM library tests (batch processor over Data, generation correctness,
 serve deployment)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -561,7 +563,8 @@ def _compile_program(which):
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     if which == "prefill":
-        fn = jax.jit(lambda p, ids, pad: generate.prefill(p, ids, cfg, t_max, pad))
+        # the function under the jit: a jit keeps what it traced, scopes and all
+        fn = jax.jit(lambda p, ids, pad: generate.prefill.__wrapped__(p, ids, cfg, t_max, pad))
         return fn.lower(params, i32(1, 16), i32(1)).compile()
     step, _ = transformer.make_train_step(cfg, None)
     opt = jax.eval_shape(lambda p: optax.adamw(3e-4, weight_decay=0.01).init(p), params)
@@ -573,8 +576,6 @@ def test_named_scopes_are_metadata_only(which, monkeypatch):
     """The scope names reach the operations' metadata and change nothing
     else: the optimized CPU HLO has as many instructions with them as with
     `jax.named_scope` made a no-op."""
-    import contextlib
-
     import jax
 
     with_scopes = _compile_program(which)
@@ -698,8 +699,23 @@ def test_decode_never_widens_the_cache(which):
     assert too_wide == [] and f32_cache == [], (too_wide, f32_cache)
 
 
+@contextlib.contextmanager
+def _eager_dispatches():
+    """The names of the primitives bound and the arrays put outside any
+    compiled program while the block runs (a warm jitted call is neither)."""
+    import jax
+    from jax.extend.core import Primitive
+
+    eager = []
+    bind, put = Primitive.bind, jax._src.api.device_put
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(Primitive, "bind", lambda self, *a, **k: eager.append(self.name) or bind(self, *a, **k))
+        patch.setattr(jax._src.api, "device_put", lambda *a, **k: eager.append("device_put") or put(*a, **k))
+        yield eager
+
+
 @pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE], ids=["dense", "mixture"])
-def test_decode_step_inputs_reach_the_device_in_one_dispatch(model, monkeypatch):
+def test_decode_step_inputs_reach_the_device_in_one_dispatch(model):
     """What `step` hands the device is one jitted call's arguments.  The traced
     decode program takes ONE key and splits it itself (S + 1 ways: the key the
     batcher carries on and one a row), and a warm `step()` on a live batcher
@@ -707,7 +723,6 @@ def test_decode_step_inputs_reach_the_device_in_one_dispatch(model, monkeypatch)
     six `jnp.asarray` and a `jnp.stack` were about forty dispatches a step,
     20 ms on the chip with the device idle."""
     import jax
-    from jax.extend.core import Primitive
 
     from cluster_anywhere_tpu.llm import ContinuousBatcher
     from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
@@ -726,14 +741,118 @@ def test_decode_step_inputs_reach_the_device_in_one_dispatch(model, monkeypatch)
     reqs = [cb.submit([3, 1, 4], max_new_tokens=8, temperature=0.7, top_k=5), cb.submit([1, 5], max_new_tokens=8)]
     cb.step()
     cb.step()  # warm: the decode program is compiled, both requests are live
-    eager = []
-    bind, put = Primitive.bind, jax._src.api.device_put
-    monkeypatch.setattr(Primitive, "bind", lambda self, *a, **k: eager.append(self.name) or bind(self, *a, **k))
-    monkeypatch.setattr(jax._src.api, "device_put", lambda *a, **k: eager.append("device_put") or put(*a, **k))
-    out = cb.step()
-    monkeypatch.undo()
+    with _eager_dispatches() as eager:
+        out = cb.step()
     assert sorted(out) == [r.request_id for r in reqs] and all(len(t) == 1 for t in out.values())
     assert eager == []
+
+
+def _watch_admit(cb):
+    """Runs `cb._admit()` and says what it cost the host: (what
+    `jax.monitoring` reported, as (event, function): a `jaxpr_trace`, a
+    `jaxpr_to_mlir_module`, a `backend_compile`; the primitives bound and
+    arrays put eagerly until `_install_slot` returned; those after it, the
+    first token's sample)."""
+    from jax import monitoring
+
+    from cluster_anywhere_tpu.llm import continuous
+
+    events, installed = [], []
+    on_event = lambda event, duration, **kw: events.append(
+        (event.rsplit("/", 1)[-1].removesuffix("_duration"), kw.get("fun_name")))
+    install = continuous._install_slot
+
+    def counted_install(*a):
+        out = install(*a)
+        installed.append(len(eager))
+        return out
+
+    with _eager_dispatches() as eager, pytest.MonkeyPatch.context() as patch:
+        patch.setattr(continuous, "_install_slot", counted_install)
+        monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            cb._admit()
+        finally:
+            monitoring.unregister_event_duration_listener(on_event)
+    (at,) = installed  # one admit
+    return events, eager[:at], eager[at:]
+
+
+@pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE], ids=["dense", "mixture"])
+def test_a_warm_admit_runs_its_buckets_one_compiled_prefill(model, llm_spans):
+    """`generate.prefill` is a compiled program a bucket: a bucket's first
+    admit traces it (`prefill_traces`, the span's `traced`), and a further
+    admit in that bucket traces, lowers and compiles nothing and dispatches
+    the prefill and the install and, eagerly, nothing but the first token's
+    key split and sample.  The eager `lax.scan` was traced and lowered again at
+    every admit: 200-330 ms on the pump's thread for 14 ms of device work."""
+    import jax
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models import generate
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+    from cluster_anywhere_tpu.util import tracing
+
+    # a width and a cache length of this test's own: the programs are the
+    # process's, and another test's batcher would have warmed its buckets
+    cfg = TransformerConfig(**dict(model, d_ff=48))
+    cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=6, t_max=40,
+                           prefill_buckets=(8, 16))
+    programs = generate.prefill._cache_size()
+    token = tracing.push_execution(TRACE)
+    try:
+        for n in (3, 12):  # cold: one program a bucket
+            cb.submit(list(range(1, n + 1)), max_new_tokens=4)
+            events, _, _ = _watch_admit(cb)
+            assert {("jaxpr_trace", "prefill"), ("jaxpr_to_mlir_module", "jit(prefill)"),
+                    ("backend_compile", "jit(prefill)")} <= set(events)
+        assert generate.prefill._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
+        for n in (5, 9, 8):  # warm, the last with no padding
+            cb.submit(list(range(2, n + 2)), max_new_tokens=4)
+            events, before_sample, sample = _watch_admit(cb)
+            # the sample's two scalars (temperature, top_p) are a trivial trace each
+            assert set(events) <= {("jaxpr_trace", "convert_element_type")} and before_sample == [], (n, events)
+            assert sample[0] == "random_split" and not {"scan", "while", "jit", "pjit"} & set(sample), sample
+    finally:
+        tracing.pop_execution(token)
+    assert generate.prefill._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
+    admits = [e for e in llm_spans() if e["name"] == "llm.admit"]
+    assert [(a["bucket"], a["traced"]) for a in admits] == [(8, 1), (16, 1), (8, 0), (16, 0), (8, 0)]
+
+
+def test_the_prefix_cached_admit_prefills_through_the_same_program(llm_spans):
+    """The prefix of a cache miss goes through `_prefill_padded` too: its
+    bucket's program is traced once, a second miss of that length and a hit
+    trace nothing, and the rows stay a batch of one from the prefill through
+    the suffix steps to `_install_slot` (no eager slice or `[:, None]`)."""
+    import jax
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+    from cluster_anywhere_tpu.util import tracing
+
+    cfg = TransformerConfig(**dict(_TINY, d_ff=80))
+    cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=4, t_max=56,
+                           prefill_buckets=(8, 32), prefix_cache_entries=4, prefix_block=4)
+    token = tracing.push_execution(TRACE)
+    try:
+        seen = []
+        for first in (1, 2, 1):  # a miss, a miss of the same split, a hit
+            cb.submit(list(range(first, first + 19)), max_new_tokens=3)
+            events, before_sample, _ = _watch_admit(cb)
+            seen.append((cb.stats["prefill_traces"], ("jaxpr_trace", "prefill") in events))
+            if len(seen) > 1:
+                assert set(events) <= {("jaxpr_trace", "convert_element_type")}, events
+                # the snapshot's copy and the suffix's scalar uploads, no slice of the rows
+                assert not {"slice", "squeeze", "gather", "broadcast_in_dim", "reshape", "scan"} & set(before_sample)
+    finally:
+        tracing.pop_execution(token)
+    assert seen == [(1, True), (1, False), (1, False)]
+    assert (cb.stats["prefix_misses"], cb.stats["prefix_hits"]) == (2, 1)
+    admits = [e for e in llm_spans() if e["name"] == "llm.admit"]
+    assert [(a["prefix_hit"], a["traced"]) for a in admits] == [(0, 1), (0, 0), (1, 0)]
+    outs = [r.out_tokens for r in sorted(cb.pump(), key=lambda r: r.request_id)]
+    assert outs[0] == outs[2]  # hit against miss, bit for bit
 
 
 @pytest.mark.parametrize("program", ["forward", "prefill", "decode_one", "decode_step"])
@@ -783,7 +902,7 @@ def test_every_program_traces_the_one_block(model, program, monkeypatch):
     if program == "forward":
         fn, args = lambda p, i: transformer.forward(p, i, cfg), (params, ids)
     elif program == "prefill":
-        fn, args = lambda p, i, pad: generate.prefill(p, i, cfg, t_max, pad), (params, ids, row)
+        fn, args = lambda p, i, pad: generate.prefill.__wrapped__(p, i, cfg, t_max, pad), (params, ids, row)
     elif program == "decode_one":
         fn = lambda p, c, tok, pos: generate.decode_one(p, c, tok, pos, cfg)
         args = (params, cache, row, jax.ShapeDtypeStruct((), jnp.int32))
