@@ -3,8 +3,9 @@ reference gets from its vLLM-backed `serve.llm` deployments —
 python/ray/llm's engine does exactly this; redesigned here for the XLA
 compilation model instead of paged CUDA kernels).
 
-The scheduler owns a fixed pool of decode SLOTS over one shared KV cache
-[L, S, T_max, KV, D].  Each slot runs one request; requests at different
+The scheduler owns a fixed pool of decode SLOTS over one shared cache (keys
+and values [L, S, T_max, KV, D], and a state-space layer's recurrent state:
+models/generate.py init_cache).  Each slot runs one request; requests at different
 depths decode together in ONE jitted step whose shapes never change — slot
 count and cache length are static, per-row positions are traced — so
 admitting or finishing requests never recompiles anything:
@@ -14,8 +15,9 @@ admitting or finishing requests never recompiles anything:
   cache rows scatter into its slot between decode steps.
 - decode: every live slot advances one token per step.  Per-row cache
   positions/pads drive RoPE and masking; finished or empty slots still
-  compute (their lanes are garbage) but write only to their own frozen
-  cache rows, which the next admit fully overwrites.
+  compute (their lanes are garbage) but write only to their own cache rows
+  (a key/value row past its position; a recurrent state, which is not frozen:
+  it moves on with every step), which the next admit overwrites whole.
 - finish: a slot frees the moment its request hits max_new_tokens or eos;
   the next step() can admit into it immediately — no head-of-line batching
   barrier, which is the whole point vs static generate() batching.
@@ -39,7 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generate import _nucleus_mask, _sample, decode_rows, prefill
+from ..models.generate import (
+    _nucleus_mask, _sample, decode_rows, init_cache, install_rows, prefill, recurrent_state_bytes,
+)
 from ..models.transformer import TransformerConfig
 from ..util import tracing
 
@@ -112,17 +116,16 @@ def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _install_slot(cache, rows, slot):
-    """Scatter one request's prefilled rows (k, v [L, 1, t_max, KV, D], as
-    `prefill` and `_suffix_step` return them) into its slot, on the device:
-    the batch axis comes off here, not in a dispatch of its own."""
-    with jax.named_scope("attn.cache"):
-        return {kv: cache[kv].at[:, slot].set(rows[kv][:, 0]) for kv in ("k", "v")}
+    """Scatter one request's prefilled rows (a cache of batch one, as `prefill`
+    and `_suffix_step` return them) into its slot, on the device, every array
+    of the slot overwritten (models/generate.py install_rows)."""
+    return install_rows(cache, rows, slot)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _suffix_step(params, rows, token, pos, pad, *, cfg):
     """One teacher-forced token over a SINGLE request's cache rows
-    ([L, 1, t_max, KV, D], donated — updated in place) during chunked admit:
+    (a cache of batch one, donated — updated in place) during chunked admit:
     feeds a known prompt token at cache slot `pos` ([1], as token and pad
     are), returns the next-token logits [1, V] and the updated rows.  The
     prefix-cache admit path runs the un-cached tail of the prompt through this
@@ -132,10 +135,11 @@ def _suffix_step(params, rows, token, pos, pad, *, cfg):
 
 
 class PrefixCache:
-    """Bounded LRU of prefilled prompt-prefix KV rows, keyed by the prefix
-    token content (+ bucket shape).  A hit hands the admit path device-ready
-    rows — the shared system prompt's prefill is skipped entirely and only
-    the request's unique tail is computed."""
+    """Bounded LRU of prefilled prompt-prefix rows (a cache of batch one: the
+    prefix's keys and values, and a recurrence's state after its last token),
+    keyed by the prefix token content (+ bucket shape).  A hit hands the admit
+    path device-ready rows — the shared system prompt's prefill is skipped
+    entirely and only the request's unique tail is computed."""
 
     def __init__(self, entries: int):
         from collections import OrderedDict
@@ -170,7 +174,7 @@ class PrefixCache:
         return sum(
             int(a.size) * a.dtype.itemsize
             for e in self._d.values()
-            for a in e["rows"].values()
+            for a in jax.tree_util.tree_leaves(e["rows"])
         )
 
 
@@ -219,14 +223,11 @@ class ContinuousBatcher:
         longest = self.prefill_buckets[-1] if self.prefill_buckets else t_max
         q = max(self.prefix_block, longest // 8)
         self._split_quantum = -(-q // self.prefix_block) * self.prefix_block
-        self.cache = {
-            "k": jnp.zeros(
-                (cfg.n_layers, slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype
-            ),
-            "v": jnp.zeros(
-                (cfg.n_layers, slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype
-            ),
-        }
+        self.cache = init_cache(cfg, slots, t_max)
+        # the recurrent state a decode step reads and writes again (every slot's,
+        # live or not), and what an admit installs: 0 for attention alone
+        self._ssm_slot_bytes = recurrent_state_bytes(self.cache) // slots
+        self._ssm_step_bytes = 2 * slots * self._ssm_slot_bytes
         # the decode step's per-slot inputs as its program takes them: two
         # host arrays that go to the jitted call as they are (one dispatch, no
         # eager upload), the scheduler's vectors their rows.  The host writes
@@ -258,6 +259,9 @@ class ContinuousBatcher:
             # admits that found no compiled prefill for their padded length
             # and traced one; stays where it is once every bucket is warm
             "prefill_traces": 0,
+            # recurrent state read and written by the steps and installed by the
+            # admits; stays 0 for a model of attention layers alone
+            "ssm_state_bytes": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -300,7 +304,7 @@ class ContinuousBatcher:
         for s, r in enumerate(self._by_slot):
             if r is not None and r.request_id == request_id:
                 r.done = True
-                self._by_slot[s] = None  # lane decodes garbage; rows frozen
+                self._by_slot[s] = None  # lane decodes garbage until an admit overwrites its rows
                 self.stats["cancelled"] += 1
                 return True
         return False
@@ -335,6 +339,9 @@ class ContinuousBatcher:
             if touched is not None:
                 sp.set(moe_rows=len(live), moe_experts_touched=float(touched))
                 self.stats["moe_assignments"] += len(live) * self.cfg.n_experts_per_tok
+            if self._ssm_step_bytes:
+                sp.set(ssm_state_bytes=self._ssm_step_bytes)
+                self.stats["ssm_state_bytes"] += self._ssm_step_bytes
             self.stats["decode_steps"] += 1
             self.stats["tokens_out"] += len(live)
             with tracing.span("llm.step.scatter"):
@@ -391,7 +398,7 @@ class ContinuousBatcher:
         program a bucket, traced at the bucket's first admit and one dispatch
         thereafter (the padded ids and the pad count go as the host arrays
         they are).  Returns (first-token logits [1, V], its cache rows as a
-        batch of one: k, v [L, 1, t_max, KV, D], pad)."""
+        batch of one, pad)."""
         with tracing.span("llm.admit.prefill"):
             padded = np.zeros((1, bucket), np.int32)
             pad = bucket - len(prompt)
@@ -428,11 +435,11 @@ class ContinuousBatcher:
         if entry is None:
             _, rows, pad = self._prefill_padded(prompt[:split], bucket)
             # store a snapshot BEFORE stepping: _suffix_step donates its rows
-            self.prefix_cache.put(key, {k: jnp.copy(v) for k, v in rows.items()}, pad)
+            self.prefix_cache.put(key, jax.tree_util.tree_map(jnp.copy, rows), pad)
             self.stats["prefix_misses"] += 1
         else:
             pad = entry["pad"]
-            rows = {k: jnp.copy(v) for k, v in entry["rows"].items()}
+            rows = jax.tree_util.tree_map(jnp.copy, entry["rows"])
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += split
         with tracing.span("llm.admit.suffix", tokens=len(suffix)):
@@ -509,6 +516,9 @@ class ContinuousBatcher:
                 assignments = len(req.prompt_ids) * self.cfg.n_experts_per_tok
                 sp.set(moe_assignments=assignments)
                 self.stats["moe_assignments"] += assignments
+            if self._ssm_slot_bytes:
+                sp.set(ssm_state_bytes=self._ssm_slot_bytes)
+                self.stats["ssm_state_bytes"] += self._ssm_slot_bytes
             if len(req.out_tokens) >= req.max_new_tokens or (
                 req.eos_id is not None and first == req.eos_id
             ):

@@ -224,6 +224,8 @@ class ContinuousLLMServer:
                  "(token, expert) pairs a layer's routed experts were given"),
                 ("prefill_traces", "ca_serve_prefill_traces_total",
                  "LLM admits that traced and compiled a prefill program on the pump's thread"),
+                ("ssm_state_bytes", "ca_serve_ssm_state_bytes_total",
+                 "bytes of recurrent state the decode steps read and wrote and the admits installed"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

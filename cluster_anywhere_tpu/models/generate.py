@@ -3,9 +3,13 @@
 static-shape cache + `lax.scan` decode loop so the whole generate compiles
 into one XLA program).
 
-This module owns the cache's layout: one stacked pytree over layers,
-    k, v: [L, B, T_max, H_kv, D]
-(`init_cache`), and the two attention cores that write it.  A block is
+This module owns the cache's layout (`init_cache`, `LAYER_STATE`): every kind
+of state the configuration's layers keep, each stacked over the layers of its
+kind alone,
+    k, v: [n_attn, B, T_max, H_kv, D]
+    conv: [n_ssm, B, K-1, C], h: [n_ssm, B, C, N] (float32)
+the rows of one request written over a slot (`install_rows`), and the cores
+that write it.  An attention block is
 transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
 them: the prefill core writes a prompt's k, v into zeroed rows and attends
 with the pad-masked flash kernel (`_prefill_block`); the decode core writes
@@ -15,7 +19,11 @@ a layer's cache once, as stored: the query [B, 1, H, D] is grouped to
 [B, 1, H_kv, H // H_kv, D] and contracted with k, v [B, T_max, H_kv, D] in the
 cache's dtype with an f32 accumulator (`_masked_attention`).  Nothing of the
 cache's size is repeated to H heads or copied to f32; only the prefill
-repeats its own k, v for the flash kernel.
+repeats its own k, v for the flash kernel.  A state-space block is
+`_ssm_half`, `_ffn_half` around `_ssm_mix` from the zero state over a whole
+prompt (`_ssm_prefill_block`, which is training's block with the pads masked:
+they leave the state untouched) or from a slot's own state for one more token
+(`_ssm_block_decode`).
 
 There is one decode block, with per-row positions, and one decode program
 body, `decode_rows`: `decode_one` (what `generate()` and `stream_generate`
@@ -34,7 +42,8 @@ Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
 `attn.core`, `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
 `moe.router`, `moe.dispatch`, `moe.experts`, `moe.combine` under `ffn`,
-parallel/moe.py): the names reach each
+parallel/moe.py; a state-space layer writes `ssm.in`, `ssm.conv`, `ssm.scan`,
+`ssm.state`, `ssm.out` in place of the `attn.*`): the names reach each
 operation's metadata, so a device trace sums a kind of work over the depth
 whatever the compiler numbers its operations.  Metadata only: the programs
 compile to the same instructions with or without them.
@@ -51,29 +60,39 @@ from jax import lax
 
 from ..ops.attention import attention
 from ..parallel.moe import EXPERT_MATRICES
-from .transformer import TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _rms_norm
+from .transformer import (
+    SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
+    _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, layer_stacks,
+)
+
+# what a layer of each kind keeps of a sequence between two tokens, as the
+# cache's keys: one stacked array each over the layers of that kind
+LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h")}
+# the scope a kind's state is read, written and installed under
+STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state"}
 
 
-def _scan_blocks(body, x, params, cfg: TransformerConfig, *per_layer):
-    """`lax.scan` of `body(x, bp, experts, *slices) -> (x, ys)` over the
-    layers, with `per_layer` arrays (the cache) sliced beside the blocks.  A
-    mixture of experts' matrices are not scanned: the grouped matmul that reads
-    them is a kernel, and a layer's slice of the stack handed to a kernel is a
-    copy of every expert at every step.  `experts` is the whole stack and the
-    layer's index, which `routed_ffn` reads in place; None for a dense model."""
-    blocks = params["blocks"]
-    if not cfg.n_experts:
-        dense = lambda x, inputs: body(x, inputs[0], None, *inputs[1:])
-        return lax.scan(dense, x, (blocks, *per_layer))
-    stack = {k: blocks[k] for k in EXPERT_MATRICES if k in blocks}
-    sliced = {k: v for k, v in blocks.items() if k not in stack}
-    layers = jnp.arange(blocks["router"].shape[0])
-
-    def step(x, inputs):
-        bp, layer, *slices = inputs
-        return body(x, bp, (stack, layer), *slices)
-
-    return lax.scan(step, x, (sliced, layers, *per_layer))
+def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
+    """The layer loop (`transformer._scan_layers`) of the programs that keep a
+    cache: `bodies[kind](x, bp, experts, *state) -> (x, (state after, extra))`
+    with `state` the kind's slices of `cache` in LAYER_STATE's order (none for
+    a prefill, which makes its own).  A mixture of experts' matrices are not
+    scanned: the grouped matmul that reads them is a kernel, and a layer's
+    slice of the stack handed to a kernel is a copy of every expert at every
+    step.  `experts` is the whole stack and the layer's index, which
+    `routed_ffn` reads in place; None for a dense model.  Returns (x, the
+    cache after, {kind: extra over that kind's layers})."""
+    state = cache and {kind: [cache[name] for name in names]
+                       for kind, names in LAYER_STATE.items() if names[0] in cache}
+    body = lambda kind, *args: bodies[kind](*args)
+    # a recurrent state's way through the loop is written under its scope; the
+    # keys' and values' stays as it was, under none (PERF.md section 7)
+    x, outs = _scan_layers(body, x, layer_stacks(params), cfg, state,
+                           unsliced=EXPERT_MATRICES if cfg.n_experts else (),
+                           state_scope={"ssm": STATE_SCOPE["ssm"]})
+    after = {name: rows for kind, (kept, _) in outs.items()
+             for name, rows in zip(LAYER_STATE[kind], kept)}
+    return x, after, {kind: extra for kind, (_, extra) in outs.items()}
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
@@ -101,11 +120,42 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
 
 
 def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
-    shape = (cfg.n_layers, batch, t_max, cfg.n_kv_heads, cfg.d_head)
-    return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-    }
+    """Every kind of state the configuration's layers keep, each stacked over
+    the layers of its kind alone: k, v [n_attn, B, t_max, KV, D]; a state-space
+    layer's convolution window [n_ssm, B, K-1, C] and its h [n_ssm, B, C, N] in
+    SSM_STATE_DTYPE, whatever the context's length."""
+    kinds = cfg.layer_kinds
+    n_attn, n_ssm = kinds.count("attn"), kinds.count("ssm")
+    cache = {}
+    if n_attn:
+        shape = (n_attn, batch, t_max, cfg.n_kv_heads, cfg.d_head)
+        cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
+    if n_ssm:
+        cache.update(
+            conv=jnp.zeros((n_ssm, batch, cfg.ssm_d_conv - 1, cfg.d_inner), cfg.dtype),
+            h=jnp.zeros((n_ssm, batch, cfg.d_inner, cfg.ssm_d_state), SSM_STATE_DTYPE),
+        )
+    return cache
+
+
+def install_rows(cache, rows, slot):
+    """One request's rows (a cache of batch one, as `prefill` and a suffix's
+    `decode_rows` return it) written whole over slot `slot` of `cache`: the
+    batch axis comes off here.  A reused slot's stale key/value rows are masked
+    by position; its recurrent state is masked by nothing, so every array of
+    the slot is overwritten."""
+    out = {}
+    for kind, names in LAYER_STATE.items():
+        with jax.named_scope(STATE_SCOPE[kind]):
+            out.update({n: cache[n].at[:, slot].set(rows[n][:, 0]) for n in names if n in cache})
+    return out
+
+
+def recurrent_state_bytes(cache) -> int:
+    """The bytes of a cache's recurrent state (every slot's convolution window
+    and h over the state-space layers): what a decode step reads and writes
+    again whatever the rows' depths.  0 for a cache of keys and values alone."""
+    return sum(int(cache[n].size) * cache[n].dtype.itemsize for n in LAYER_STATE["ssm"] if n in cache)
 
 
 def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads, live=None,
@@ -163,6 +213,28 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
     return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
 
 
+def _ssm_block_decode(bp, x, layer_state, cfg: TransformerConfig, live=None, experts=None):
+    """One state-space block, one token a row, from each row's own state.
+    x: [B, 1, E]; layer_state: (window [B, K-1, C], h [B, C, N]).  A row's
+    position and pads do not enter: the state is all a recurrence knows of what
+    came before.  An empty slot's row moves its state on like any other (what
+    it holds is overwritten whole when the slot is given out: `install_rows`).
+    Returns (x, (window, h), experts touched or None)."""
+    x, layer_state = _ssm_half(bp, x, cfg, lambda xs: _ssm_mix(bp, xs, layer_state, cfg))
+    x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
+    return x, layer_state, touched
+
+
+def _ssm_prefill_block(bp, x, pad, cfg: TransformerConfig, experts=None):
+    """One state-space block over the whole prompt from the zero state;
+    returns the state after the last token, (window [B, K-1, C], h [B, C, N]).
+    pad: [B] left-pad counts or None: a pad's input and step size are zeroed, so
+    the state, and the logits, are the unpadded prompt's in any bucket."""
+    keep = None if pad is None else jnp.arange(x.shape[1])[None, :] >= pad[:, None]
+    x, _, layer_state = _ssm_block_forward(bp, x, cfg, keep, experts)
+    return x, layer_state
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "t_max"))
 def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     """ids: [B, T_prompt] -> (last-token logits [B, V], cache).
@@ -173,16 +245,16 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
-    def body(x, bp, experts):
-        x, (kc, vc) = _prefill_block(bp, x, pad, cfg, t_max, experts)
-        return x, (kc, vc)
+    def attn(x, bp, experts):
+        x, kv = _prefill_block(bp, x, pad, cfg, t_max, experts)
+        return x, (kv, None)
 
-    x, (k_all, v_all) = _scan_blocks(body, x, params, cfg)
-    with jax.named_scope("norm"):
-        x = _rms_norm(x, params["ln_f"])
-    with jax.named_scope("head"):
-        logits = (x[:, -1] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": k_all, "v": v_all}
+    def ssm(x, bp, experts):
+        x, state = _ssm_prefill_block(bp, x, pad, cfg, experts)
+        return x, (state, None)
+
+    x, cache, _ = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg)
+    return _head(params, x, cfg, row=-1), cache
 
 
 def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
@@ -194,18 +266,18 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
 
-    def body(x, bp, experts, kc, vc):
-        x, (kc, vc), touched = _block_decode_rowpos(bp, x, (kc, vc), pos, cfg, pads, live, experts)
-        return x, (kc, vc, touched)
+    def attn(x, bp, experts, *kv):
+        x, kv, touched = _block_decode_rowpos(bp, x, kv, pos, cfg, pads, live, experts)
+        return x, (kv, touched)
 
-    x, (k_all, v_all, touched) = _scan_blocks(body, x, params, cfg, cache["k"], cache["v"])
-    if touched is not None:
-        touched = jnp.mean(touched.astype(jnp.float32))
-    with jax.named_scope("norm"):
-        x = _rms_norm(x, params["ln_f"])
-    with jax.named_scope("head"):
-        logits = (x[:, 0] @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": k_all, "v": v_all}, touched
+    def ssm(x, bp, experts, *state):
+        x, state, touched = _ssm_block_decode(bp, x, state, cfg, live, experts)
+        return x, (state, touched)
+
+    x, cache, touched = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg, cache)
+    touched = [t for t in touched.values() if t is not None]
+    touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32)) if touched else None
+    return _head(params, x, cfg, row=0), cache, touched
 
 
 def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):

@@ -11,10 +11,14 @@ Design points (vs. the reference, which delegates all modeling to torch):
 - bfloat16 activations, fp32 params/optimizer, RoPE, GQA, SwiGLU, RMSNorm
 
 A block is two halves, each written once for training, prefill and decode
-(`_attention_half`, `_ffn_half`): what differs between the three is the
-rotary positions and the attention core, so those are the attention half's
-arguments.  This module holds the training core (`_attention`); the two that
-write a key/value cache are in models/generate.py, which owns its layout.
+(`_attention_half` or `_ssm_half`, then `_ffn_half`): what differs between the
+three is the rotary positions and the attention core, or the state a
+state-space mixer starts from, so those are the first half's arguments.  This
+module holds the training cores (`_attention`; `_ssm_mix` from the zero
+state); those that keep a cache are in models/generate.py, which owns its
+layout.  Which layer is which kind is the configuration's `layer_kinds`; every
+program's layer loop is `_scan_layers`, one scan a run of one kind, and every
+program's output head is `_head`.
 
 The model is the `entry()` / `dryrun_multichip()` flagship in
 __graft_entry__.py and what benchmarks/ trains and serves.
@@ -22,6 +26,7 @@ __graft_entry__.py and what benchmarks/ trains and serves.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -79,6 +84,38 @@ class TransformerConfig:
     # RMSNorm with a learned weight over the whole projected q and k vectors
     # (before the split into heads and the rotary embedding): q_norm, k_norm
     qk_norm: bool = False
+    rotary: bool = True  # False: attention takes no positional embedding at all
+    tie_embeddings: bool = False  # the head is the embedding transposed; no `lm_head`
+    # a layer pattern: layer i mixes tokens by attention where
+    # i % attn_layer_period == attn_layer_offset and by a selective state-space
+    # recurrence (`_ssm_half`) otherwise.  Period 0: every layer attends.  Each
+    # kind's parameters and cache rows are stacked on a leading axis of their own
+    # (`blocks`, `ssm_blocks`), and the layer loop (`_scan_layers`) scans each
+    # maximal run of one kind.  One device only: a larger mesh raises.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    # the state-space mixer's sizes: inner width ssm_expand * d_model, state
+    # ssm_d_state a channel, a causal depthwise convolution over ssm_d_conv
+    # positions, the step size projected through ssm_dt_rank
+    ssm_d_state: int = 16
+    ssm_d_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 16
+    ssm_conv_bias: bool = True
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer: "attn" or "ssm"."""
+        if not self.attn_layer_period:
+            return ("attn",) * self.n_layers
+        return tuple(
+            "attn" if i % self.attn_layer_period == self.attn_layer_offset else "ssm"
+            for i in range(self.n_layers)
+        )
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_expand * self.d_model
 
     @property
     def layers_per_stage(self) -> int:
@@ -97,8 +134,26 @@ class TransformerConfig:
 # ---------------------------------------------------------------------------
 
 
+def _init_ffn(ks, cfg: TransformerConfig):
+    """A block's second half from three keys: its norm and the dense SwiGLU
+    matrices, or the experts behind their router."""
+    e, f, pd = cfg.d_model, cfg.d_ff, cfg.param_dtype
+    s = lambda fan_in: fan_in ** -0.5
+    if cfg.n_experts:
+        from ..parallel.moe import init_moe_params
+
+        return {"ln2": jnp.ones((e,), pd),
+                **init_moe_params(ks[0], e, f, cfg.n_experts, pd, gated=cfg.moe_gated)}
+    return {
+        "ln2": jnp.ones((e,), pd),
+        "w_gate": jax.random.normal(ks[0], (e, f), pd) * s(e),
+        "w_up": jax.random.normal(ks[1], (e, f), pd) * s(e),
+        "w_down": jax.random.normal(ks[2], (f, e), pd) * s(f),
+    }
+
+
 def _init_block(key, cfg: TransformerConfig):
-    e, h, kv, d, f = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff
+    e, h, kv, d = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     ks = jax.random.split(key, 7)
     s = lambda fan_in: fan_in ** -0.5
     pd = cfg.param_dtype
@@ -108,47 +163,80 @@ def _init_block(key, cfg: TransformerConfig):
         "wk": jax.random.normal(ks[1], (e, kv * d), pd) * s(e),
         "wv": jax.random.normal(ks[2], (e, kv * d), pd) * s(e),
         "wo": jax.random.normal(ks[3], (h * d, e), pd) * s(h * d),
-        "ln2": jnp.ones((e,), pd),
     }
     if cfg.qk_norm:
         out.update({"q_norm": jnp.ones((h * d,), pd), "k_norm": jnp.ones((kv * d,), pd)})
-    if cfg.n_experts:
-        from ..parallel.moe import init_moe_params
-
-        out.update(init_moe_params(ks[4], e, f, cfg.n_experts, pd, gated=cfg.moe_gated))
-    else:
-        out.update(
-            {
-                "w_gate": jax.random.normal(ks[4], (e, f), pd) * s(e),
-                "w_up": jax.random.normal(ks[5], (e, f), pd) * s(e),
-                "w_down": jax.random.normal(ks[6], (f, e), pd) * s(f),
-            }
-        )
+    out.update(_init_ffn(ks[4:], cfg))
     return out
 
 
+def _init_ssm_block(key, cfg: TransformerConfig):
+    """A state-space block (Mamba-1 with Jamba's three inner norms), initialised
+    as Mamba is: A = -(1..N) in every channel, the step size's bias the inverse
+    softplus of a step log-uniform in [1e-3, 1e-1], D = 1."""
+    e, c, n, r, kw = cfg.d_model, cfg.d_inner, cfg.ssm_d_state, cfg.ssm_dt_rank, cfg.ssm_d_conv
+    ks = jax.random.split(key, 7)
+    s = lambda fan_in: fan_in ** -0.5
+    pd = cfg.param_dtype
+    k_dt, k_bias = jax.random.split(ks[3])
+    step = jnp.exp(jax.random.uniform(k_bias, (c,)) * (jnp.log(1e-1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    out = {
+        "ln1": jnp.ones((e,), pd),
+        "ssm_in": jax.random.normal(ks[0], (e, 2 * c), pd) * s(e),
+        "conv_w": jax.random.normal(ks[1], (kw, c), pd) * s(kw),
+        "ssm_x": jax.random.normal(ks[2], (c, r + 2 * n), pd) * s(c),
+        "dt_norm": jnp.ones((r,), pd),
+        "b_norm": jnp.ones((n,), pd),
+        "c_norm": jnp.ones((n,), pd),
+        "ssm_dt": jax.random.normal(k_dt, (r, c), pd) * s(r),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+        "a_log": jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)), (c, n)).astype(pd),
+        "ssm_d": jnp.ones((c,), pd),
+        "ssm_out": jax.random.normal(ks[4], (c, e), pd) * s(c),
+    }
+    if cfg.ssm_conv_bias:
+        out["conv_b"] = jnp.zeros((c,), pd)
+    out.update(_init_ffn(jax.random.split(ks[5], 3), cfg))
+    return out
+
+
+_INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block)}
+
+
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
+    """`blocks`: the attention layers' parameters stacked [n, ...]; `ssm_blocks`:
+    the state-space layers', where the pattern has any.  Layer i's key is the
+    i-th of one split whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
-    blocks = jax.vmap(lambda k: _init_block(k, cfg))(block_keys)
-    if cfg.pp > 1:
-        # restack [L, ...] -> [pp, L/pp, ...] for stage sharding
-        blocks = jax.tree_util.tree_map(
-            lambda x: x.reshape(cfg.pp, cfg.layers_per_stage, *x.shape[1:]), blocks
-        )
-    return {
+    kinds = cfg.layer_kinds
+    out = {
         "embed": jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         * 0.02,
-        "blocks": blocks,
-        "ln_f": jnp.ones((cfg.d_model,), cfg.param_dtype),
-        "lm_head": jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
-        * cfg.d_model ** -0.5,
     }
+    for kind in dict.fromkeys(kinds):
+        name, init = _INIT_KIND[kind]
+        keys = block_keys if len(set(kinds)) == 1 else block_keys[
+            jnp.asarray([i for i, k in enumerate(kinds) if k == kind])]
+        out[name] = jax.vmap(lambda k: init(k, cfg))(keys)
+    if cfg.pp > 1:
+        # restack [L, ...] -> [pp, L/pp, ...] for stage sharding
+        out["blocks"] = jax.tree_util.tree_map(
+            lambda x: x.reshape(cfg.pp, cfg.layers_per_stage, *x.shape[1:]), out["blocks"]
+        )
+    out["ln_f"] = jnp.ones((cfg.d_model,), cfg.param_dtype)
+    if not cfg.tie_embeddings:
+        out["lm_head"] = (
+            jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
+            * cfg.d_model ** -0.5
+        )
+    return out
 
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpecs: tp shards head/ff/vocab dims, fsdp shards the other
     matmul dim, pp shards the stage axis of stacked blocks."""
+    _one_device_only(cfg, "a sharding of its parameters")
     lead = ("pp", None) if cfg.pp > 1 else (None,)
 
     def blk(*spec):
@@ -180,12 +268,22 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                 "w_down": blk("tp", "fsdp"),
             }
         )
-    return {
-        "embed": P("fsdp", "tp"),
-        "blocks": blocks,
-        "ln_f": P(None),
-        "lm_head": P("fsdp", "tp"),
-    }
+    specs = {"embed": P("fsdp", "tp"), "blocks": blocks, "ln_f": P(None)}
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P("fsdp", "tp")
+    return specs
+
+
+def _one_device_only(cfg: TransformerConfig, what: str) -> None:
+    """A layer pattern's training loop over a mesh is not written (ROADMAP M1):
+    say which kind of layer stands in the way instead of sharding it wrongly."""
+    kinds = sorted(set(cfg.layer_kinds) - {"attn"})
+    if kinds:
+        raise NotImplementedError(
+            f"{what}: layers of kind {kinds} (attn_layer_period={cfg.attn_layer_period}) "
+            "run on one device only; a mesh with more than one device shards attention "
+            "blocks alone"
+        )
 
 
 def shard_params(params, cfg: TransformerConfig, mesh):
@@ -317,8 +415,9 @@ def _gqa_repeat(x, cfg: TransformerConfig):
 
 def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
     """A block's first half, for training, prefill and decode alike:
-    x + wo(core(rope(qkv(norm(x))))).  x: [B, T, E]; positions: [T] or [B, T],
-    what `_rope` takes; `core(q, k, v) -> (attn [B, T, H, D], extra)` attends
+    x + wo(core(rope(qkv(norm(x))))), the rotary embedding where the
+    configuration has one.  x: [B, T, E]; positions: [T] or [B, T], what `_rope`
+    takes; `core(q, k, v) -> (attn [B, T, H, D], extra)` attends
     under its own scopes (`attn.core`, and `attn.cache` where it keeps one) and
     hands back what its caller keeps of k and v.  Returns (x, extra).
 
@@ -330,11 +429,132 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
         y = _rms_norm(x, bp["ln1"])
     with jax.named_scope("attn.qkv"):
         q, k, v = _project_qkv(bp, y, cfg)
-    with jax.named_scope("attn.rope"):
-        q, k = _rope(q, k, positions, cfg)
+    if cfg.rotary:
+        with jax.named_scope("attn.rope"):
+            q, k = _rope(q, k, positions, cfg)
     attn, extra = core(q, k, v)
     with jax.named_scope("attn.out"):
         x = x + attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
+    return x, extra
+
+
+# the precision of the recurrence: the step size, the decay exp(dt A), the state
+# h between two tokens (in the cache too: models/generate.py init_cache) and the
+# read-out.  Weights and every other activation are in cfg.dtype.
+SSM_STATE_DTYPE = jnp.float32
+SSM_CHUNK = 16  # positions a chunk of the prefill's scan: about sqrt(T) at chat lengths
+
+
+def _selective_scan(dt, a, b, c, xc, h0):
+    """h_t = exp(dt_t a) h_{t-1} + dt_t b_t xc_t from h0; y_t = sum_n h_t c_t.
+    dt, xc: [B, T, C]; b, c: [B, T, N]; a: [C, N]; h0: [B, C, N], all of one
+    float type.  Returns (y [B, T, C], h_T).
+
+    One token is one step.  A sequence is cut into chunks of SSM_CHUNK
+    positions: every chunk's recurrence runs from zero, all chunks side by
+    side (SSM_CHUNK dependent steps over [B, T/Q, C, N], each step's read-out
+    reduced over N at once); the chunks' first states follow from their last
+    ones by T/Q dependent steps over [B, C, N]; and what a chunk's first state
+    adds to its positions' read-outs is one fused product.  Nothing of size
+    [T, C, N] is kept: the state of all chunks is read and written once a
+    step, 2 T C N values in all.  A step with dt = 0 (a pad) leaves h as it is."""
+    bsz, t, ch = dt.shape
+    decay = lambda step: jnp.exp(step[..., None] * a)  # [..., C] -> [..., C, N]
+    # elementwise and a sum over N, not a dot: 16 terms a channel fuse with what makes them
+    read_out = lambda h, c_t: jnp.sum(h * c_t[..., None, :], axis=-1)  # [..., C, N], [..., N] -> [..., C]
+    if t == 1:
+        h = decay(dt[:, 0]) * h0 + (dt[:, 0] * xc[:, 0])[..., None] * b[:, 0, None, :]
+        return read_out(h, c[:, 0])[:, None], h
+    q = SSM_CHUNK
+    g = -(-t // q)
+    # [B, T, ...] -> [Q, B, G, ...], the tail of the last chunk steps of dt = 0
+    chunked = lambda v: jnp.moveaxis(
+        jnp.pad(v, ((0, 0), (0, g * q - t), (0, 0))).reshape(bsz, g, q, v.shape[-1]), 2, 0)
+    dt_q, b_q, c_q, x_q = chunked(dt), chunked(b), chunked(c), chunked(xc)
+
+    def within(s, step):
+        dt_j, b_j, c_j, x_j = step
+        s = decay(dt_j) * s + (dt_j * x_j)[..., None] * b_j[..., None, :]
+        return s, read_out(s, c_j)
+
+    last, y = lax.scan(within, jnp.zeros((bsz, g, ch, a.shape[-1]), dt.dtype), (dt_q, b_q, c_q, x_q))
+
+    def across(h, chunk):
+        whole, last_g = chunk
+        return whole * h + last_g, h
+
+    h_t, first = lax.scan(
+        across, h0, (jnp.moveaxis(decay(jnp.sum(dt_q, axis=0)), 1, 0), jnp.moveaxis(last, 1, 0)))
+    since = jnp.cumsum(dt_q, axis=0)  # [Q, B, G, C]: the steps from the chunk's start through each position
+    y = y + read_out(decay(since) * jnp.moveaxis(first, 0, 1), c_q)
+    return jnp.moveaxis(y, 0, 2).reshape(bsz, g * q, ch)[:, :t], h_t
+
+
+def _ssm_mix(bp, xs, state, cfg: TransformerConfig, keep=None):
+    """A state-space mixer between its two projections: the causal depthwise
+    convolution, the step size and the input and output maps of each position,
+    and the recurrence.  xs: [B, T, C]; state: (the convolution's window, the
+    last ssm_d_conv - 1 inputs [B, K-1, C]; h [B, C, N]) as the tokens before
+    left it, zeros before a sequence starts; keep: [B, T] bool, False at a
+    left pad (whose xs the caller zeroed: `_ssm_half`), None for none.
+    Returns (y [B, T, C], the state after the last position).
+
+    At a pad dt = 0: exp(0) = 1 and the input term is 0, so h passes the pads
+    unchanged and the state after the last token is the unpadded prompt's."""
+    window, h = state
+    n, r, f = cfg.ssm_d_state, cfg.ssm_dt_rank, SSM_STATE_DTYPE
+    t = xs.shape[1]
+    with jax.named_scope("ssm.conv"):
+        padded = jnp.concatenate([window.astype(xs.dtype), xs], axis=1)  # [B, K-1+T, C]
+        taps = bp["conv_w"].astype(xs.dtype)
+        xc = sum(padded[:, j:j + t] * taps[j] for j in range(cfg.ssm_d_conv))
+        if cfg.ssm_conv_bias:
+            xc = xc + bp["conv_b"].astype(xs.dtype)
+        xc = jax.nn.silu(xc)
+    with jax.named_scope("ssm.state"):
+        window = padded[:, t:].astype(window.dtype)
+    with jax.named_scope("ssm.scan"):
+        low = xc @ bp["ssm_x"].astype(xs.dtype)
+        step, b, c = (
+            _rms_norm(part, bp[w]) for part, w in zip(
+                jnp.split(low, (r, r + n), axis=-1), ("dt_norm", "b_norm", "c_norm"))
+        )
+        dt = (step @ bp["ssm_dt"].astype(xs.dtype)).astype(f) + bp["dt_bias"].astype(f)
+        dt = jax.nn.softplus(dt)
+        if keep is not None:
+            dt = jnp.where(keep[..., None], dt, 0.0)
+        a = -jnp.exp(bp["a_log"].astype(f))
+        y, h_new = _selective_scan(dt, a, b.astype(f), c.astype(f), xc.astype(f), h.astype(f))
+        y = y + bp["ssm_d"].astype(f) * xc.astype(f)
+    with jax.named_scope("ssm.state"):
+        h_new = h_new.astype(h.dtype)
+    return y.astype(xs.dtype), (window, h_new)
+
+
+def _ssm_zero_state(cfg: TransformerConfig, batch: int):
+    """The state before a sequence starts: (window, h) as `_ssm_mix` takes them."""
+    with jax.named_scope("ssm.state"):
+        return (jnp.zeros((batch, cfg.ssm_d_conv - 1, cfg.d_inner), cfg.dtype),
+                jnp.zeros((batch, cfg.d_inner, cfg.ssm_d_state), SSM_STATE_DTYPE))
+
+
+def _ssm_half(bp, x, cfg: TransformerConfig, core, keep=None):
+    """A state-space block's first half, for training, prefill and decode
+    alike: x + w_out(core(xs) * silu(z)), [xs, z] = w_in(norm(x)).  x: [B, T, E];
+    `core(xs) -> (y [B, T, C], extra)` is `_ssm_mix` from the state its caller
+    holds (zeros for a whole sequence, a slot's for one more token) and hands
+    back what the caller keeps of the state after it; keep: [B, T] bool, False
+    at a left pad, whose xs are zeroed here so that the convolution sees what
+    an unpadded prompt sees before its start.  Returns (x, extra)."""
+    with jax.named_scope("norm"):
+        u = _rms_norm(x, bp["ln1"])
+    with jax.named_scope("ssm.in"):
+        xs, z = jnp.split(u @ bp["ssm_in"].astype(x.dtype), 2, axis=-1)
+        if keep is not None:
+            xs = jnp.where(keep[..., None], xs, 0)
+    y, extra = core(xs)
+    with jax.named_scope("ssm.out"):
+        x = x + (y * jax.nn.silu(z)) @ bp["ssm_out"].astype(x.dtype)
     return x, extra
 
 
@@ -388,27 +608,128 @@ def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     return x, jnp.zeros((), jnp.float32) if aux is None else aux
 
 
-def _stage_forward(stage_blocks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
-    """Scan over this stage's layers. stage_blocks leaves: [L_stage, ...].
-    Returns (x, aux) — aux is the summed MoE load-balance loss (0 dense)."""
-    block = functools.partial(
-        _block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes
-    )
-    if cfg.remat:
-        block = jax.checkpoint(block)
+def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
+    """One state-space block over whole sequences from the zero state, for
+    training and for a prompt's prefill.  keep: [B, T] bool, False at a left
+    pad, None for none (`_ssm_half`); experts: `_ffn_half`'s.  Returns (x, the
+    MoE load-balance loss: 0 dense, the state after the last position)."""
+    core = lambda xs: _ssm_mix(bp, xs, _ssm_zero_state(cfg, x.shape[0]), cfg, keep)
+    x, state = _ssm_half(bp, x, cfg, core, keep)
+    x, aux, _ = _ffn_half(bp, x, cfg, keep, experts)
+    return x, jnp.zeros((), jnp.float32) if aux is None else aux, state
 
-    def body(carry, bp):
+
+def layer_stacks(params) -> Dict[str, Any]:
+    """{kind: that kind's blocks, stacked on a leading axis of their own}."""
+    stacks = {"attn": params["blocks"], "ssm": params.get("ssm_blocks")}
+    return {kind: blocks for kind, blocks in stacks.items() if blocks is not None}
+
+
+def _layer_runs(kinds):
+    """[(kind, the run's first layer counted among its kind, its length)] for
+    each maximal run of one kind: 7 ssm, attn, 13 ssm, attn, 6 ssm."""
+    runs, seen = [], {}
+    for kind in kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def _scan_layers(body, carry, stacks, cfg: TransformerConfig, state=None, unsliced=(), unroll=1,
+                 state_scope=None):
+    """The layer loop of every program: each maximal run of one kind of layer
+    is one `lax.scan` of `body(kind, carry, bp, held, *slices) -> (carry, ys)`;
+    a model of one kind is one run.  stacks: `layer_stacks`; state: {kind: the
+    arrays sliced beside that kind's layers, [n_kind, ...]} (a cache), or None.
+    Returns (carry, {kind: ys over that kind's layers}).
+
+    A run that is its kind's whole stack scans the stacks themselves.  A
+    shorter run scans its layers' indices and reads each layer's parameters
+    and state where they lie: a slice of a stack handed to a loop is a copy of
+    those layers at every call (1.3 GB for thirteen state-space layers).  The
+    names in `unsliced` (a mixture's experts, which a kernel reads) are never
+    sliced by either: `held` is (their stacks, the layer's index), None where
+    there are none.
+
+    state_scope: {kind: a `jax.named_scope`} for what the loop itself does with
+    that kind's state (each layer's read out of the stack and write back into
+    the run's, the runs' joining): the operations of the body keep their own,
+    innermost, names."""
+    kinds = cfg.layer_kinds
+    outs: Dict[str, list] = {}
+    scoped = lambda kind: (jax.named_scope(state_scope[kind]) if kind in (state_scope or {})
+                           else contextlib.nullcontext())
+    for kind, start, n in _layer_runs(kinds):
+        blocks = stacks[kind]
+        total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        if len(set(kinds)) == 1:
+            n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
+        kept = {k: blocks[k] for k in unsliced if k in blocks}
+        rest = {k: v for k, v in blocks.items() if k not in kept}
+        stacked = tuple(state[kind]) if state else ()
+
+        def step(carry, xs, kind=kind, kept=kept, rest=rest, stacked=stacked):
+            layer = xs.get("layer")
+            slices = xs["state"] if "state" in xs else tuple(s[layer] for s in stacked)
+            bp = xs["bp"] if "bp" in xs else jax.tree_util.tree_map(lambda w: w[layer], rest)
+            return body(kind, carry, bp, (kept, layer) if kept else None, *slices)
+
+        with scoped(kind):
+            xs = {"bp": rest, "state": stacked} if n == total else {}
+            if kept or n != total:
+                xs["layer"] = jnp.arange(start, start + n)
+            carry, ys = lax.scan(step, carry, xs, unroll=unroll)
+        outs.setdefault(kind, []).append(ys)
+
+    def joined(kind):
+        join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+        with scoped(kind):
+            return jax.tree_util.tree_map(join, *outs[kind])
+
+    return carry, {kind: joined(kind) for kind in outs}
+
+
+def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
+    """The layer loop over this stage's layers.  stacks: `layer_stacks`, leaves
+    [L_stage, ...].  Returns (x, aux) — aux is the summed MoE load-balance loss
+    (0 dense)."""
+    blocks = {
+        "attn": functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes),
+        "ssm": lambda bp, x: _ssm_block_forward(bp, x, cfg)[:2],
+    }
+    if cfg.remat:
+        blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
+
+    def body(kind, carry, bp, _):
         x, aux = carry
-        x, a = block(bp, x)
+        x, a = blocks[kind](bp, x)
         return (x, aux + a), None
 
-    (x, aux), _ = lax.scan(
-        body,
-        (x, jnp.zeros((), jnp.float32)),
-        stage_blocks,
+    (x, aux), _ = _scan_layers(
+        body, (x, jnp.zeros((), jnp.float32)), stacks, cfg,
         unroll=True if cfg.unroll_layers else 1,
     )
     return x, aux
+
+
+def _head(params, x, cfg: TransformerConfig, row=None):
+    """The final norm and the output head of every program: x [B, T, E] ->
+    logits [B, T, V] in cfg.dtype, or of position `row` alone [B, V] in float32,
+    as a sampler takes them.  A tied head is the embedding, contracted over its
+    own width."""
+    with jax.named_scope("norm"):
+        x = _rms_norm(x, params["ln_f"])
+    with jax.named_scope("head"):
+        if row is not None:
+            x = x[:, row]
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("...e,ve->...v", x, params["embed"].astype(cfg.dtype))
+        else:
+            logits = x @ params["lm_head"].astype(cfg.dtype)
+        return logits if row is None else logits.astype(jnp.float32)
 
 
 def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = False):
@@ -426,6 +747,8 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
     if cfg.n_experts and mesh is not None and mesh.size > 1:
         manual_axes.add("ep")
 
+    if manual_axes or (mesh is not None and mesh.size > 1):
+        _one_device_only(cfg, f"forward on a mesh of {1 if mesh is None else mesh.size} devices")
     if manual_axes:
         if mesh is None:
             raise ValueError("mesh required for pp/sp execution")
@@ -459,12 +782,9 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
             x = lax.with_sharding_constraint(
                 x, NamedSharding(mesh, P(("dp", "fsdp"), None, None))
             )
-        x, aux = _stage_forward(params["blocks"], x, cfg, mesh)
+        x, aux = _stage_forward(layer_stacks(params), x, cfg, mesh)
 
-    with jax.named_scope("norm"):
-        x = _rms_norm(x, params["ln_f"])
-    with jax.named_scope("head"):
-        logits = x @ params["lm_head"].astype(cfg.dtype)
+    logits = _head(params, x, cfg)
     return (logits, aux) if return_aux else logits
 
 
@@ -479,8 +799,8 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
     def inner(blocks_local, x_local):
         if pp_manual:
             my_blocks = jax.tree_util.tree_map(lambda p: p[0], blocks_local)
-            stage = functools.partial(
-                _stage_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes
+            stage = lambda bp, a: _stage_forward(
+                {"attn": bp}, a, cfg=cfg, mesh=mesh, manual_axes=manual_axes
             )
             if cfg.n_experts:
                 # MoE through the pipeline: each stage's MoE layers
@@ -506,7 +826,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
                 aux = jnp.zeros((), jnp.float32)
         else:
             x_out, aux = _stage_forward(
-                blocks_local, x_local, cfg, mesh, manual_axes
+                {"attn": blocks_local}, x_local, cfg, mesh, manual_axes
             )
         # the P() out-spec claims aux is replicated across EVERY manual axis;
         # each shard computed it over its own tokens, so reduce over all

@@ -209,6 +209,54 @@ def test_padded_prefill_reads_the_experts_where_they_are(v5e, on_tpu, bucket):
     assert _reads_the_experts_where_they_are(compiled)
 
 
+# Jamba2-3B's widths (inner width 5120, state 16, rank 160, one cached head, no
+# rotary, a tied head), eight layers deep: s a s s a s s a, so the state-space
+# layers lie in runs of one and two and no run is its kind's whole stack
+JAMBA8 = dict(
+    vocab_size=512, n_layers=8, d_model=2560, n_heads=20, n_kv_heads=1, d_head=128, d_ff=8192,
+    attn_layer_period=3, attn_layer_offset=1, ssm_d_state=16, ssm_d_conv=4, ssm_expand=2,
+    ssm_dt_rank=160, rotary=False, tie_embeddings=True, param_dtype=jnp.bfloat16,
+)
+
+
+def test_decode_step_reads_a_state_space_layers_weights_where_they_are(v5e):
+    """The decode step of a layer pattern at Jamba2-3B's widths (32 slots): a
+    run of state-space layers that is not the whole stack reads each layer's
+    matrices out of the stack where they lie.  A run's slice of the stack
+    handed to the layer loop would be a copy of those layers at every step
+    (104 MB a layer, 2.7 GB a step over the published 26)."""
+    from cluster_anywhere_tpu.llm import continuous
+
+    cfg = transformer.TransformerConfig(**JAMBA8)
+    assert cfg.layer_kinds == ("ssm", "attn", "ssm", "ssm", "attn", "ssm", "ssm", "attn")
+    slots, t_max = 32, 768
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
+    assert cache["h"].shape == (5, slots, 5120, 16) and cache["k"].shape == (3, slots, t_max, 1, 128)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    ints = on_chip(jax.ShapeDtypeStruct((4, slots), jnp.int32))
+    floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
+    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
+    # every value the program keeps of a state-space layer's matrices, by its last two
+    # sizes: the whole stack of five (a parameter, or a loop's view of it), never one
+    # layer's or a run's
+    matrices = {(2560, 10240), (5120, 2560), (5120, 192), (160, 5120), (2560, 8192), (8192, 2560)}
+    assert matrices <= {tuple(v.shape[1:]) for v in params["ssm_blocks"].values()}
+    seen, copies = set(), []
+    for dtype, _, line in _buffers(compiled):
+        dims = tuple(int(d) for d in re.search(r"= \w+\[([\d,]+)\]", line).group(1).split(","))
+        if dtype == "bf16" and dims[-2:] in matrices and len(dims) <= 3:
+            if dims[:-2] == (5,):
+                seen.add(dims[-2:])
+            elif dims[:-2] != (3,):  # the three attention layers' MLPs are a stack of their own
+                copies.append(line)
+    assert seen == matrices and copies == []
+
+
 @pytest.mark.parametrize(
     "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2_tp2"]
 )
