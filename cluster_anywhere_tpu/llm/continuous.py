@@ -103,8 +103,12 @@ def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
     into the key it carries on and one key a row.  Returns (next_tokens [S],
     cache, the carried key, experts touched): the last is the mean over the
     layers of the experts that were given a row, None for a dense model.  The
-    cache is donated: decode rewrites it in place instead of copying
-    [L,S,Tmax,KV,D] x2 per token."""
+    cache is donated and is the layer loop's carry (models/generate.py), so
+    the step writes one row a slot and layer of [L,S,Tmax,KV,D] x2 in place
+    and copies nothing of that size:
+    tests/test_chip_compile.py holds the chip's program to it
+    (`test_decode_step_writes_the_cache_in_place`), tests/test_llm.py the
+    rows it may change."""
     tokens, pos, pads, top_ks, *live = ints
     live = live[0] != 0 if live else None
     temps, top_ps = floats

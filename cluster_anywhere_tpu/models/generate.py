@@ -13,17 +13,24 @@ that write it.  An attention block is
 transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
 them: the prefill core writes a prompt's k, v into zeroed rows and attends
 with the pad-masked flash kernel (`_prefill_block`); the decode core writes
-row b at slot pos[b] and attends over the full T_max with a position mask
-(`_block_decode_rowpos`: static shapes, no recompilation per step).  It reads
-a layer's cache once, as stored: the query [B, 1, H, D] is grouped to
-[B, 1, H_kv, H // H_kv, D] and contracted with k, v [B, T_max, H_kv, D] in the
-cache's dtype with an f32 accumulator (`_masked_attention`).  Nothing of the
-cache's size is repeated to H heads or copied to f32; only the prefill
-repeats its own k, v for the flash kernel.  A state-space block is
-`_ssm_half`, `_ffn_half` around `_ssm_mix` from the zero state over a whole
-prompt (`_ssm_prefill_block`, which is training's block with the pads masked:
-they leave the state untouched) or from a slot's own state for one more token
-(`_ssm_block_decode`).
+row b's new k, v at [layer, b, pos[b]] of the stacks and attends over the
+layer's full T_max with a position mask (`_block_decode_rowpos`: static
+shapes, no recompilation per step).  It reads a layer's cache once, as stored:
+the query [B, 1, H, D] is grouped to [B, 1, H_kv, H // H_kv, D] and contracted
+with k, v [B, T_max, H_kv, D] in the cache's dtype with an f32 accumulator
+(`_masked_attention`).  Nothing of the cache's size is repeated to H heads or
+copied to f32; only the prefill repeats its own k, v for the flash kernel.  A
+state-space block is `_ssm_half`, `_ffn_half` around `_ssm_mix` from the zero
+state over a whole prompt (`_ssm_prefill_block`, which is training's block
+with the pads masked: they leave the state untouched) or from a slot's own
+state for one more token (`_ssm_block_decode`, which reads the layer's state
+out of the stacks and writes the new one in its place).
+
+A program that is given a cache carries it through the layer loop
+(`_scan_blocks`): the stacks are one buffer from the program's argument to its
+result, a decode step writes one row a slot and layer of k and v in place, and
+a donated cache is never copied.  A prefill makes its own rows, as the loop's
+stacked outputs.
 
 There is one decode block, with per-row positions, and one decode program
 body, `decode_rows`: `decode_one` (what `generate()` and `stream_generate`
@@ -74,25 +81,27 @@ STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state"}
 
 def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
     """The layer loop (`transformer._scan_layers`) of the programs that keep a
-    cache: `bodies[kind](x, bp, experts, *state) -> (x, (state after, extra))`
-    with `state` the kind's slices of `cache` in LAYER_STATE's order (none for
-    a prefill, which makes its own).  A mixture of experts' matrices are not
+    cache: `bodies[kind](x, bp, experts, cache, layer) -> (x, cache, ys)`.
+    `cache` is the loop's carry beside x: every kind's whole stacks, which a
+    decode body reads and writes at `layer`, the layer's number within its
+    kind (LAYER_STATE says which arrays are a kind's).  None for a prefill,
+    which makes its own rows as `ys` (and is given no `layer` by a model that
+    has no other use for one).  A mixture of experts' matrices are not
     scanned: the grouped matmul that reads them is a kernel, and a layer's
     slice of the stack handed to a kernel is a copy of every expert at every
     step.  `experts` is the whole stack and the layer's index, which
     `routed_ffn` reads in place; None for a dense model.  Returns (x, the
-    cache after, {kind: extra over that kind's layers})."""
-    state = cache and {kind: [cache[name] for name in names]
-                       for kind, names in LAYER_STATE.items() if names[0] in cache}
-    body = lambda kind, *args: bodies[kind](*args)
-    # a recurrent state's way through the loop is written under its scope; the
-    # keys' and values' stays as it was, under none (PERF.md section 7)
-    x, outs = _scan_layers(body, x, layer_stacks(params), cfg, state,
-                           unsliced=EXPERT_MATRICES if cfg.n_experts else (),
-                           state_scope={"ssm": STATE_SCOPE["ssm"]})
-    after = {name: rows for kind, (kept, _) in outs.items()
-             for name, rows in zip(LAYER_STATE[kind], kept)}
-    return x, after, {kind: extra for kind, (_, extra) in outs.items()}
+    cache after, {kind: ys over that kind's layers})."""
+
+    def body(kind, carry, bp, held, layer):
+        x, cache = carry
+        x, cache, ys = bodies[kind](x, bp, (held, layer) if held else None, cache, layer)
+        return (x, cache), ys
+
+    (x, cache), outs = _scan_layers(body, (x, cache), layer_stacks(params), cfg,
+                                    unsliced=EXPERT_MATRICES if cfg.n_experts else (),
+                                    indexed=cache is not None)
+    return x, cache, outs
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
@@ -158,29 +167,32 @@ def recurrent_state_bytes(cache) -> int:
     return sum(int(cache[n].size) * cache[n].dtype.itemsize for n in LAYER_STATE["ssm"] if n in cache)
 
 
-def _block_decode_rowpos(bp, x, layer_cache, pos, cfg: TransformerConfig, pads, live=None,
+def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads, live=None,
                          experts=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
-    layer_cache: (k, v) [B, Tmax, KV, D].  Row b writes its k/v at slot
-    pos[b], takes RoPE position pos[b] - pads[b], and attends to cache
-    slots [pads[b], pos[b]].  live: [B] bool, the rows that hold a request:
-    an empty slot's row takes no expert (None: every row does).  Returns
-    (x, (k, v), experts touched or None: `_ffn_half`)."""
+    cache: the attention layers' stacks k, v [n_attn, B, Tmax, KV, D] (among
+    whatever else it holds) and layer: this one's number among them.  Row b
+    writes its k/v at [layer, b, pos[b]], one scatter of [B, KV, D] an array
+    and nothing else of the stacks, takes RoPE position pos[b] - pads[b], and
+    attends to slots [pads[b], pos[b]] of the layer, read where it lies.
+    live: [B] bool, the rows that hold a request: an empty slot's row takes no
+    expert (None: every row does).  Returns (x, the cache after, experts
+    touched or None: `_ffn_half`)."""
 
     def core(q, k, v):
-        k_cache, v_cache = layer_cache
-        with jax.named_scope("attn.cache"):
+        with jax.named_scope(STATE_SCOPE["attn"]):
             rows = jnp.arange(x.shape[0])
-            k_cache = k_cache.at[rows, pos].set(k[:, 0])
-            v_cache = v_cache.at[rows, pos].set(v[:, 0])
+            k_all = cache["k"].at[layer, rows, pos].set(k[:, 0])
+            v_all = cache["v"].at[layer, rows, pos].set(v[:, 0])
+            k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
         with jax.named_scope("attn.core"):
-            attn = _masked_attention(q, k_cache, v_cache, pos + 1, cfg, pads)  # per-row length
-        return attn, (k_cache, v_cache)
+            attn = _masked_attention(q, k_layer, v_layer, pos + 1, cfg, pads)  # per-row length
+        return attn, {**cache, "k": k_all, "v": v_all}
 
-    x, layer_cache = _attention_half(bp, x, cfg, (pos - pads)[:, None], core)
+    x, cache = _attention_half(bp, x, cfg, (pos - pads)[:, None], core)
     x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
-    return x, layer_cache, touched
+    return x, cache, touched
 
 
 def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None):
@@ -213,16 +225,30 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
     return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
 
 
-def _ssm_block_decode(bp, x, layer_state, cfg: TransformerConfig, live=None, experts=None):
+def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, experts=None):
     """One state-space block, one token a row, from each row's own state.
-    x: [B, 1, E]; layer_state: (window [B, K-1, C], h [B, C, N]).  A row's
-    position and pads do not enter: the state is all a recurrence knows of what
-    came before.  An empty slot's row moves its state on like any other (what
-    it holds is overwritten whole when the slot is given out: `install_rows`).
-    Returns (x, (window, h), experts touched or None)."""
-    x, layer_state = _ssm_half(bp, x, cfg, lambda xs: _ssm_mix(bp, xs, layer_state, cfg))
+    x: [B, 1, E]; cache: the state-space layers' stacks conv [n_ssm, B, K-1, C]
+    and h [n_ssm, B, C, N] (among whatever else it holds) and layer: this one's
+    number among them.  The layer's state is read out of the stacks and the
+    new one written in its place: a recurrent state has to move whole, once.
+    A row's position and pads do not enter: the state is all a recurrence
+    knows of what came before.  An empty slot's row moves its state on like
+    any other (what it holds is overwritten whole when the slot is given out:
+    `install_rows`).  Returns (x, the cache after, experts touched or None)."""
+
+    names = LAYER_STATE["ssm"]
+
+    def core(xs):
+        with jax.named_scope(STATE_SCOPE["ssm"]):
+            state = tuple(lax.dynamic_index_in_dim(cache[n], layer, keepdims=False) for n in names)
+        y, state = _ssm_mix(bp, xs, state, cfg)
+        with jax.named_scope(STATE_SCOPE["ssm"]):
+            after = {n: lax.dynamic_update_index_in_dim(cache[n], new, layer, 0) for n, new in zip(names, state)}
+        return y, {**cache, **after}
+
+    x, cache = _ssm_half(bp, x, cfg, core)
     x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
-    return x, layer_state, touched
+    return x, cache, touched
 
 
 def _ssm_prefill_block(bp, x, pad, cfg: TransformerConfig, experts=None):
@@ -245,15 +271,16 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
-    def attn(x, bp, experts):
+    def attn(x, bp, experts, _cache, _layer):
         x, kv = _prefill_block(bp, x, pad, cfg, t_max, experts)
-        return x, (kv, None)
+        return x, None, kv
 
-    def ssm(x, bp, experts):
+    def ssm(x, bp, experts, _cache, _layer):
         x, state = _ssm_prefill_block(bp, x, pad, cfg, experts)
-        return x, (state, None)
+        return x, None, state
 
-    x, cache, _ = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg)
+    x, _, rows = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg)
+    cache = {name: r for kind, kept in rows.items() for name, r in zip(LAYER_STATE[kind], kept)}
     return _head(params, x, cfg, row=-1), cache
 
 
@@ -266,13 +293,11 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
 
-    def attn(x, bp, experts, *kv):
-        x, kv, touched = _block_decode_rowpos(bp, x, kv, pos, cfg, pads, live, experts)
-        return x, (kv, touched)
+    def attn(x, bp, experts, cache, layer):
+        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts)
 
-    def ssm(x, bp, experts, *state):
-        x, state, touched = _ssm_block_decode(bp, x, state, cfg, live, experts)
-        return x, (state, touched)
+    def ssm(x, bp, experts, cache, layer):
+        return _ssm_block_decode(bp, x, cache, layer, cfg, live, experts)
 
     x, cache, touched = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg, cache)
     touched = [t for t in touched.values() if t is not None]

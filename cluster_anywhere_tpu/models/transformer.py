@@ -26,7 +26,6 @@ __graft_entry__.py and what benchmarks/ trains and serves.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple
@@ -638,58 +637,50 @@ def _layer_runs(kinds):
     return [tuple(r) for r in runs]
 
 
-def _scan_layers(body, carry, stacks, cfg: TransformerConfig, state=None, unsliced=(), unroll=1,
-                 state_scope=None):
+def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=(), unroll=1, indexed=False):
     """The layer loop of every program: each maximal run of one kind of layer
-    is one `lax.scan` of `body(kind, carry, bp, held, *slices) -> (carry, ys)`;
-    a model of one kind is one run.  stacks: `layer_stacks`; state: {kind: the
-    arrays sliced beside that kind's layers, [n_kind, ...]} (a cache), or None.
-    Returns (carry, {kind: ys over that kind's layers}).
+    is one `lax.scan` of `body(kind, carry, bp, held, layer) -> (carry, ys)`;
+    a model of one kind is one run.  stacks: `layer_stacks`.  Returns
+    (carry, {kind: ys over that kind's layers}).
+
+    What a program keeps from one call to the next (a cache) is part of
+    `carry`, through every run of every kind, as whole stacks [n_kind, ...]
+    that the body reads and writes at `layer`, the layer's number within its
+    kind (`indexed` asks for it where the loop itself has no use for it).  It
+    is never a scan's `xs` and `ys`: the stacked `ys` are a fresh buffer of the
+    cache's size, which a donated cache cannot be, and every layer's slice is
+    written back whole.  A carry is one buffer from the program's argument to
+    its result.
 
     A run that is its kind's whole stack scans the stacks themselves.  A
     shorter run scans its layers' indices and reads each layer's parameters
-    and state where they lie: a slice of a stack handed to a loop is a copy of
-    those layers at every call (1.3 GB for thirteen state-space layers).  The
-    names in `unsliced` (a mixture's experts, which a kernel reads) are never
-    sliced by either: `held` is (their stacks, the layer's index), None where
-    there are none.
-
-    state_scope: {kind: a `jax.named_scope`} for what the loop itself does with
-    that kind's state (each layer's read out of the stack and write back into
-    the run's, the runs' joining): the operations of the body keep their own,
-    innermost, names."""
+    where they lie: a slice of a stack handed to a loop is a copy of those
+    layers at every call (1.3 GB for thirteen state-space layers).  The names
+    in `unsliced` (a mixture's experts, which a kernel reads) are never sliced
+    by either: `held` is their stacks, to be read at `layer`, empty where there
+    are none.  `layer` is None where nothing asked for it."""
     kinds = cfg.layer_kinds
     outs: Dict[str, list] = {}
-    scoped = lambda kind: (jax.named_scope(state_scope[kind]) if kind in (state_scope or {})
-                           else contextlib.nullcontext())
     for kind, start, n in _layer_runs(kinds):
         blocks = stacks[kind]
         total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
         if len(set(kinds)) == 1:
             n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
-        kept = {k: blocks[k] for k in unsliced if k in blocks}
-        rest = {k: v for k, v in blocks.items() if k not in kept}
-        stacked = tuple(state[kind]) if state else ()
+        held = {k: blocks[k] for k in unsliced if k in blocks}
+        rest = {k: v for k, v in blocks.items() if k not in held}
 
-        def step(carry, xs, kind=kind, kept=kept, rest=rest, stacked=stacked):
+        def step(carry, xs, kind=kind, held=held, rest=rest):
             layer = xs.get("layer")
-            slices = xs["state"] if "state" in xs else tuple(s[layer] for s in stacked)
             bp = xs["bp"] if "bp" in xs else jax.tree_util.tree_map(lambda w: w[layer], rest)
-            return body(kind, carry, bp, (kept, layer) if kept else None, *slices)
+            return body(kind, carry, bp, held, layer)
 
-        with scoped(kind):
-            xs = {"bp": rest, "state": stacked} if n == total else {}
-            if kept or n != total:
-                xs["layer"] = jnp.arange(start, start + n)
-            carry, ys = lax.scan(step, carry, xs, unroll=unroll)
+        xs = {"bp": rest} if n == total else {}
+        if held or indexed or n != total:
+            xs["layer"] = jnp.arange(start, start + n)
+        carry, ys = lax.scan(step, carry, xs, unroll=unroll)
         outs.setdefault(kind, []).append(ys)
-
-    def joined(kind):
-        join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-        with scoped(kind):
-            return jax.tree_util.tree_map(join, *outs[kind])
-
-    return carry, {kind: joined(kind) for kind in outs}
+    join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    return carry, {kind: jax.tree_util.tree_map(join, *runs) for kind, runs in outs.items()}
 
 
 def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
@@ -703,7 +694,7 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
     if cfg.remat:
         blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
 
-    def body(kind, carry, bp, _):
+    def body(kind, carry, bp, _held, _layer):
         x, aux = carry
         x, a = blocks[kind](bp, x)
         return (x, aux + a), None

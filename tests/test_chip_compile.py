@@ -121,9 +121,10 @@ def test_padded_prefill_compiles_at_flagship_width(v5e, on_tpu, bucket):
     assert _has_kernel(_compiled_admit_prefill(cfg, bucket, bucket + 32, v5e[0]))
 
 
-def _buffers(compiled):
+def _buffers(compiled, width=100):
     """(dtype, elements, instruction) of every value the optimized program
-    keeps in memory: each instruction outside a fusion's body."""
+    keeps in memory: each instruction outside a fusion's body, its text cut to
+    `width` characters."""
     text = compiled.as_text()
     fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
     out, inside = [], None
@@ -135,7 +136,7 @@ def _buffers(compiled):
             m = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]", line)
             if m:
                 elements = math.prod(int(d) for d in m.group(2).split(","))
-                out.append((m.group(1), elements, line.strip()[:100]))
+                out.append((m.group(1), elements, line.strip()[:width]))
     return out
 
 
@@ -155,9 +156,9 @@ def test_decode_block_reads_the_cache_as_stored(v5e):
     blocks = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))["blocks"]
     bp = jax.tree_util.tree_map(lambda x: on_chip(x.shape[1:], x.dtype), blocks)
     x = on_chip((slots, 1, cfg.d_model), cfg.dtype)
-    layer_cache = on_chip((slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype)
+    layer_cache = on_chip((1, slots, t_max, cfg.n_kv_heads, cfg.d_head), cfg.dtype)  # a stack of one
     rows = on_chip((slots,), jnp.int32)
-    fn = lambda bp, x, k, v, pos, pads: generate._block_decode_rowpos(bp, x, (k, v), pos, cfg, pads)
+    fn = lambda bp, x, k, v, pos, pads: generate._block_decode_rowpos(bp, x, {"k": k, "v": v}, 0, pos, cfg, pads)
     compiled = jax.jit(fn, donate_argnums=(2, 3)).lower(bp, x, layer_cache, layer_cache, rows, rows).compile()
     buffers = _buffers(compiled)
     cache = slots * t_max * cfg.n_kv_heads * cfg.d_head
@@ -177,26 +178,33 @@ def _reads_the_experts_where_they_are(compiled) -> bool:
     return _has_kernel(compiled) and stacks >= 3 and not [b for b in buffers if b[1] == layer]
 
 
+def _compiled_decode_step(cfg, device, slots=32, t_max=768):
+    """The continuous batcher's decode step as the serving cells run it (32
+    slots, t_max 768, the cache donated), compiled for `device`.  Returns
+    (compiled, the shapes of its parameters, of its cache)."""
+    from cluster_anywhere_tpu.llm import continuous
+
+    one = SingleDeviceSharding(device)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    # a mixture of experts is told the live slots in a fifth row
+    ints = on_chip(jax.ShapeDtypeStruct((5 if cfg.n_experts else 4, slots), jnp.int32))
+    floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
+    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
+    return compiled, params, cache
+
+
 def test_decode_step_reads_the_experts_where_they_are(v5e):
     """The decode step of a mixture of experts at OLMoE's widths (32 slots):
     the grouped matmul is a kernel of the compiler's, and the layer scan hands
     it the stacked [L, X, E, F] matrices whole.  Sliced out of the stack by the
     scan, a layer's experts were a copy of all 64 at every step, 0.7 ms a
     matrix and layer on the chip."""
-    from cluster_anywhere_tpu.llm import continuous
-
-    cfg = transformer.TransformerConfig(**OLMOE3)
-    slots, t_max = 32, 768
-    one = SingleDeviceSharding(v5e[0])
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
-    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
-    cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    ints = on_chip(jax.ShapeDtypeStruct((5, slots), jnp.int32))  # the fifth row: the live slots
-    floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
-    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
+    compiled, _, _ = _compiled_decode_step(transformer.TransformerConfig(**OLMOE3), v5e[0])
     assert _reads_the_experts_where_they_are(compiled)
 
 
@@ -225,22 +233,10 @@ def test_decode_step_reads_a_state_space_layers_weights_where_they_are(v5e):
     matrices out of the stack where they lie.  A run's slice of the stack
     handed to the layer loop would be a copy of those layers at every step
     (104 MB a layer, 2.7 GB a step over the published 26)."""
-    from cluster_anywhere_tpu.llm import continuous
-
     cfg = transformer.TransformerConfig(**JAMBA8)
     assert cfg.layer_kinds == ("ssm", "attn", "ssm", "ssm", "attn", "ssm", "ssm", "attn")
-    slots, t_max = 32, 768
-    one = SingleDeviceSharding(v5e[0])
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
-    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
-    cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
-    assert cache["h"].shape == (5, slots, 5120, 16) and cache["k"].shape == (3, slots, t_max, 1, 128)
-    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    ints = on_chip(jax.ShapeDtypeStruct((4, slots), jnp.int32))
-    floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
-    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
+    compiled, params, cache = _compiled_decode_step(cfg, v5e[0])
+    assert cache["h"].shape == (5, 32, 5120, 16) and cache["k"].shape == (3, 32, 768, 1, 128)
     # every value the program keeps of a state-space layer's matrices, by its last two
     # sizes: the whole stack of five (a parameter, or a loop's view of it), never one
     # layer's or a run's
@@ -255,6 +251,46 @@ def test_decode_step_reads_a_state_space_layers_weights_where_they_are(v5e):
             elif dims[:-2] != (3,):  # the three attention layers' MLPs are a stack of their own
                 copies.append(line)
     assert seen == matrices and copies == []
+
+
+# Mistral-7B's widths (32 Q / 8 KV heads x 128), four layers deep
+MISTRAL4 = dict(
+    vocab_size=512, n_layers=4, d_model=4096, n_heads=32, n_kv_heads=8, d_head=128, d_ff=14336,
+    param_dtype=jnp.bfloat16,
+)
+
+
+@pytest.mark.parametrize("model", [MISTRAL4, OLMOE3, JAMBA8], ids=["mistral4", "olmoe3", "jamba8"])
+def test_decode_step_writes_the_cache_in_place(v5e, model):
+    """The decode step of each serving configuration's widths (32 slots, t_max
+    768, the cache donated) as the chip's compiler leaves it: the cache is the
+    layer loop's carry, one buffer from the argument to the result.  Its
+    temporaries hold nothing of a stack's size (given to the loop as a scan's
+    `xs` and taken back as its `ys` they held one more whole cache: 0.403 GB of
+    0.403 at Mistral's widths, 1.009 of 0.604 at OLMoE's); a value as large as
+    a stack of k, v, conv or h is that stack written in place or moved between
+    memory spaces, never a copy; and nothing writes a whole layer's keys or
+    values [S, T_max, KV, D], of which a step changes one row a slot."""
+    cfg = transformer.TransformerConfig(**model)
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0])
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in cache.values())
+    # a layer's new recurrent state is a value before it is written over the old one
+    a_state = generate.recurrent_state_bytes(cache) // max(cfg.layer_kinds.count("ssm"), 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10 + a_state
+    stacks = {c.size for c in cache.values()}
+    a_layers_keys = cache["k"].size // cache["k"].shape[0]
+    seen = set()
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        # in the chip's memory: a small stack that the compiler stages in fast memory
+        # (space S(1): JAMBA8's 5 and 52 MB of state; a deployment's 272 MB do not fit) is its to move
+        if n in stacks and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
+            assert in_place or op == "copy-done", line[:200]  # copy-done: back from fast memory
+            seen.add(n)
+        if n == a_layers_keys:
+            assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
+    assert cache["k"].size in seen  # the keys' write was read for what it is
 
 
 @pytest.mark.parametrize(

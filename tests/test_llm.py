@@ -973,8 +973,8 @@ def test_sampled_streams_are_the_eager_split_and_sample():
         """The next token of every slot and the carried key, op by op."""
         tokens, pos, pads = (jnp.asarray(v) for v in (cb._tokens, cb._pos, cb._pads))
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
-        attn = lambda x, bp, experts, *kv: (
-            generate._block_decode_rowpos(bp, x, kv, pos, cfg, pads, None, experts)[0], (kv, None))
+        attn = lambda x, bp, experts, cache, layer: (
+            generate._block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, None, experts)[0], cache, None)
         x, _, _ = generate._scan_blocks({"attn": attn}, x, params, cfg, cb.cache)
         logits = generate._head(params, x, cfg, row=0)
         rng, *keys = jax.random.split(rng, cb.slots + 1)
@@ -1008,6 +1008,145 @@ def test_sampled_streams_are_the_eager_split_and_sample():
     sampled = [r for r in reqs if r.temperature > 0]
     assert any(len(set(r.out_tokens)) > 2 for r in sampled)
     np.testing.assert_array_equal(jax.random.key_data(cb._rng), jax.random.key_data(rng))
+
+
+# -- the cache is the layer loop's carry: one row a slot written in place ---------
+
+
+def _float32_model(model, seed=0):
+    """(cfg, params) in float32: `_TINY_HYBRID` as `_hybrid` makes it."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+
+    if model is _TINY_HYBRID:
+        return _hybrid(seed=seed)
+    cfg = TransformerConfig(**model, dtype=jnp.float32, param_dtype=jnp.float32)
+    return cfg, init_params(jax.random.key(seed), cfg)
+
+
+def _plain_decode_rows(params, cache, tokens, pos, pads, cfg, live=None):
+    """`generate.decode_rows` layer by layer in Python: each layer's state taken
+    out of the stacks, row b's k and v written at slot pos[b] of the layer, the
+    layer put back.  Returns (logits [B, V], the cache after)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models import generate, transformer
+
+    cache, rows = dict(cache), jnp.arange(tokens.shape[0])
+    stacks, seen = transformer.layer_stacks(params), {"attn": 0, "ssm": 0}
+    x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
+    for kind in cfg.layer_kinds:
+        i = seen[kind]
+        seen[kind] += 1
+        bp = jax.tree_util.tree_map(lambda w: w[i], stacks[kind])
+        if kind == "attn":
+
+            def core(q, k, v):
+                for name, new in (("k", k), ("v", v)):
+                    cache[name] = cache[name].at[i].set(cache[name][i].at[rows, pos].set(new[:, 0]))
+                return generate._masked_attention(q, cache["k"][i], cache["v"][i], pos + 1, cfg, pads), None
+
+            x, _ = transformer._attention_half(bp, x, cfg, (pos - pads)[:, None], core)
+        else:
+
+            def core(xs):
+                y, (window, h) = transformer._ssm_mix(bp, xs, (cache["conv"][i], cache["h"][i]), cfg)
+                cache.update(conv=cache["conv"].at[i].set(window), h=cache["h"].at[i].set(h))
+                return y, None
+
+            x, _ = transformer._ssm_half(bp, x, cfg, core)
+        x = transformer._ffn_half(bp, x, cfg, None if live is None else live[:, None])[0]
+    return transformer._head(params, x, cfg, row=0), cache
+
+
+def _slots_at_different_depths(cfg, seed=1):
+    """(cache, tokens, pos, pads) of four slots, every array of the cache filled
+    with noise (what a slot holds past its depth is masked, never read)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.models import generate
+
+    cache = generate.init_cache(cfg, 4, 16)
+    keys = jax.random.split(jax.random.key(seed), len(cache))
+    cache = {n: 0.5 * jax.random.normal(k, c.shape, c.dtype) for k, (n, c) in zip(keys, cache.items())}
+    as_ints = lambda v: jnp.asarray(v, jnp.int32)
+    return cache, as_ints([3, 9, 5, 1]), as_ints([3, 12, 0, 7]), as_ints([0, 4, 0, 2])
+
+
+def _assert_one_row_a_slot_changed(before, after, pos):
+    """k and v differ in row pos[b] of slot b, in every layer, and nowhere
+    else, bit for bit; every slot's recurrent state moved, in every layer."""
+    before, after = ({n: np.asarray(a) for n, a in c.items()} for c in (before, after))
+    pos = np.asarray(pos)
+    for name in ("k", "v"):
+        changed = np.any(before[name] != after[name], axis=(-2, -1))  # [L, B, T]
+        want = np.zeros_like(changed)
+        want[:, np.arange(len(pos)), pos] = True
+        np.testing.assert_array_equal(changed, want)
+    for name in set(before) - {"k", "v"}:
+        moved = np.any(before[name] != after[name], axis=tuple(range(2, before[name].ndim)))  # [L, B]
+        assert moved.all(), name
+
+
+@pytest.mark.parametrize("program", ["decode_rows", "suffix_step", "generate"])
+@pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE, _TINY_HYBRID], ids=["dense", "mixture", "hybrid"])
+def test_decode_writes_one_row_a_slot_and_is_the_plain_layer_loop(model, program):
+    """The cache travels through the layer loop as its carry, each layer
+    reading and writing the stacks at its own number within its kind (runs of
+    two kinds, a mixture's held experts, a stack that is one run): over slots
+    at different depths `decode_rows` gives the logits and the cache of a plain
+    per-layer loop, and the cache after differs from the cache before in
+    exactly row pos[b] of each slot's k and v and in every recurrent state.
+    The same through `_suffix_step`'s cache of batch one (jitted, its rows
+    donated) and through `generate()`'s scan of decode steps.  This is what
+    holds `_decode_step_rowpos`'s "rewrites it in place" on the CPU; the
+    chip's program is held by tests/test_chip_compile.py."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.llm import continuous
+    from cluster_anywhere_tpu.models import generate
+
+    cfg, params = _float32_model(model)
+    cache, tokens, pos, pads = _slots_at_different_depths(cfg)
+    close = lambda got, want: np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    with jax.default_matmul_precision("highest"):
+        if program == "decode_rows":
+            live = jnp.asarray([True, True, False, True])  # an empty slot's row takes no expert
+            logits, after, _ = generate.decode_rows(params, cache, tokens, pos, pads, cfg, live)
+            want, want_after = _plain_decode_rows(params, cache, tokens, pos, pads, cfg, live)
+            close(logits, want)
+            _assert_one_row_a_slot_changed(cache, after, pos)
+            for name in cache:
+                close(after[name], want_after[name])
+        elif program == "suffix_step":
+            want, want_after = _plain_decode_rows(params, cache, tokens, pos, pads, cfg)
+            for b in range(tokens.shape[0]):
+                one = slice(b, b + 1)
+                rows = {n: c[:, one] for n, c in cache.items()}
+                before = {n: np.array(c) for n, c in rows.items()}  # the rows are donated
+                logits, after = continuous._suffix_step(params, rows, tokens[one], pos[one], pads[one], cfg=cfg)
+                close(logits, want[one])
+                _assert_one_row_a_slot_changed(before, after, pos[one])
+                for name in cache:
+                    close(after[name], want_after[name][:, one])
+        else:
+            prompt = jnp.asarray(np.random.default_rng(2).integers(1, cfg.vocab_size, (3, 5)), jnp.int32)
+            lens = jnp.asarray([5, 3, 4], jnp.int32)  # left-padded rows: pads of 0, 2, 1
+            n = 6
+            got = generate.generate(params, prompt, jax.random.key(0), cfg=cfg, max_new_tokens=n, prompt_lens=lens)
+            pads = 5 - lens
+            logits, cache = generate.prefill(params, prompt, cfg, 5 + n, pads)
+            want = [jnp.argmax(logits, axis=-1)]
+            for i in range(n - 1):
+                pos = jnp.full((3,), 5 + i, jnp.int32)
+                logits, cache = _plain_decode_rows(params, cache, want[-1], pos, pads, cfg)
+                want.append(jnp.argmax(logits, axis=-1))
+            np.testing.assert_array_equal(np.asarray(got), np.stack(want, axis=1))
 
 
 # -- a layer pattern: state-space layers beside attention layers ---------------
