@@ -28,6 +28,9 @@ REFERENCE_INTERFACE = (
     "param_count", "train_flops_per_step", "decode_step_bytes",
     "LOGIT_TOL", "REGRET_MAX_TOL", "REGRET_MEAN_TOL", "LOSS_TOL",
 )
+# what it may define besides: the parts of a serving cell's check that depend on
+# the architecture (reference.check_serving has a default for each it lacks)
+REFERENCE_OPTIONAL = ("chosen_logits", "program_logits", "mechanism_checks")
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")

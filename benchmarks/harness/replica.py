@@ -17,7 +17,7 @@ import glob
 import os
 import threading
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 from cluster_anywhere_tpu.llm.serve_llm import StreamingLLMIngress
 
@@ -93,14 +93,22 @@ def stop_trace(trace_dir: str) -> str:
 
 class BenchIngress(StreamingLLMIngress):
     def __init__(self, config, slots: int = 8):
-        import jax
-
         self._compiles = CompileCounter()
         super().__init__(config, slots)
-        self._steps: List[tuple] = []  # (t_end, wall_s, admit_s, tokens_out, admitted_total, decode_steps_total)
+        self._wrap_batcher()
+
+    def _wrap_batcher(self) -> None:
+        """Timers and counters around `self.cb`'s `_admit`, `step` and `submit`."""
+        import jax
+
+        # (t_end, wall_s, admit_s, tokens_out, admitted_total, decode_steps_total, requests_out)
+        self._steps: List[tuple] = []
         self._admits: List[tuple] = []  # (t_end, wall_s, requests, prompt_tokens, reused_tokens)
         self._submitted: Dict[int, tuple] = {}  # request_id -> (bench_id, t_submit)
         self._first: Dict[str, tuple] = {}  # bench_id -> (t_submit, t_first_token)
+        # bench_id -> request_id of the check streams, which `bench_check` hands to the
+        # reference; None once it has run, so the window's requests leave nothing here
+        self._check_ids: Optional[Dict[str, int]] = {}
         cb = self.cb
         admit_inner, step_inner = cb._admit, cb.step
         annotate = jax.profiler.TraceAnnotation
@@ -133,7 +141,7 @@ class BenchIngress(StreamingLLMIngress):
                     self._first[sub[0]] = (sub[1], t1)
             self._steps.append(
                 (t1, t1 - t0, self._admit_s, sum(len(v) for v in out.values()),
-                 cb.stats["admitted"], cb.stats["decode_steps"])
+                 cb.stats["admitted"], cb.stats["decode_steps"], len(out))
             )
             return out
 
@@ -145,6 +153,8 @@ class BenchIngress(StreamingLLMIngress):
             req = submit_inner(ids, **kw)
             if getattr(self._tls, "bench_id", None) is not None:
                 self._submitted[req.request_id] = (self._tls.bench_id, self._tls.t_submit)
+                if self._check_ids is not None:
+                    self._check_ids[self._tls.bench_id] = req.request_id
             return req
 
         self._admit_s = 0.0
@@ -159,17 +169,30 @@ class BenchIngress(StreamingLLMIngress):
     def bench_check(self, streams: List[Dict[str, Any]], t_begin: float,
                     reference_name: str) -> Dict[str, Any]:
         """The check streams against the configuration's reference
-        (references/<reference_name>.py through reference.check_serving), with
-        the batch the decode program ran them at: the tokens a step handed
-        out, averaged over the steps since `t_begin` that handed out any."""
+        (references/<reference_name>.py through reference.check_serving).
+        Each stream comes with the `bench_id` it was sent under and goes on
+        with the batcher's own `request_id`, by which a reference can ask the
+        batcher what it recorded of that request; a stream that was never
+        submitted here is an error.  The report says at what batch the
+        streams were served, over the steps since `t_begin` that handed out
+        any token: the tokens a step handed out (`decode_batch_mean`) and the
+        requests it handed them to (`decode_requests_mean`).  Several streams
+        have to have overlapped: a step may hand one request several tokens,
+        so it is the requests that say so."""
         from . import manifest, reference
 
-        report = reference.check_serving(self.cb, streams, manifest.load_reference(reference_name))
         with self._lock:
-            handed = [s[3] for s in self._steps if s[0] >= t_begin and s[3] > 0]
-        report["decode_batch_mean"] = sum(handed) / len(handed) if handed else 0.0
-        if len(streams) > 1 and not report["decode_batch_mean"] > 1.0:
-            report["ok"] = False  # the streams did not overlap: the batch decode was not checked
+            ids, self._check_ids = self._check_ids or {}, None
+            steps = [s for s in self._steps if s[0] >= t_begin and s[3] > 0]
+        missing = [s["bench_id"] for s in streams if s["bench_id"] not in ids]
+        if missing:
+            raise RuntimeError(f"check streams {missing} were never submitted to this replica")
+        streams = [dict(s, request_id=ids[s["bench_id"]]) for s in streams]
+        report = reference.check_serving(self.cb, streams, manifest.load_reference(reference_name))
+        report["decode_batch_mean"] = sum(s[3] for s in steps) / len(steps) if steps else 0.0
+        report["decode_requests_mean"] = sum(s[6] for s in steps) / len(steps) if steps else 0.0
+        if len(streams) > 1 and not report["decode_requests_mean"] > 1.0:
+            report["ok"] = False  # the streams did not overlap: the batch programs were not checked
         return report
 
     def bench_trace(self, action: str, trace_dir: str) -> str:

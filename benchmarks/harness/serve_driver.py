@@ -65,8 +65,8 @@ def _warm_up_and_check(cell, handle, port: int, seed: int) -> Dict[str, Any]:
     streams (`check.stream_prompt_lens`, `check.stream_new_tokens` tokens each)
     are sent together, so the decode program serves them as one batch, and
     held to the reference in the replica (reference.check_serving says which
-    program each part of the check holds).  One prompt sent alone twice has to
-    answer identically."""
+    program each part of the check holds), which finds each by the id it was
+    sent under.  One prompt sent alone twice has to answer identically."""
     traffic, vocab = cell["traffic_file"], cell["config_file"]["config"]["vocab_size"]
     rng = np.random.default_rng(seed + 1)
     for n in traffic["warmup_prompt_lens"]:
@@ -83,8 +83,8 @@ def _warm_up_and_check(cell, handle, port: int, seed: int) -> Dict[str, Any]:
     bad = [r for r in recs if r["error"] is not None or r["status"] != 200]
     if bad:
         raise RuntimeError(f"check stream failed: {bad[0]['status']} {bad[0]['error']}")
-    streams = [{"prompt_ids": [int(t) for t in q["prompt_ids"]], "served": r["tokens"]}
-               for q, r in zip(plan, recs)]
+    streams = [{"prompt_ids": [int(t) for t in q["prompt_ids"]], "served": r["tokens"],
+                "bench_id": r["bench_id"]} for q, r in zip(plan, recs)]
     report = handle.bench_check.remote(
         streams, t_begin, cell["config_file"]["reference"]).result(timeout_s=600)
     prompt = rng.integers(0, vocab, chk["repeat_prompt_len"])

@@ -20,8 +20,9 @@ at a time in a loop: every expert is computed for every token and weighted by
 the token's router probability if the expert is among its k, by 0 otherwise, so
 that only one expert's weights are upcast at a time and nothing of the size
 [T, k, E, F] is gathered.  It shares no code with `cluster_anywhere_tpu/models/`
-or `parallel/`; it reads the same parameter tree.  The training auxiliary and
-z losses are no part of serving and no part of `loss`.
+or `parallel/`; it reads the same parameter tree.  (`mechanism_checks`, at the
+end, calls the program's `_moe` as what it checks, not as a reference.)
+The training auxiliary and z losses are no part of serving and no part of `loss`.
 
 Departures from the published model, which the configuration file lists: the
 RMSNorm epsilon is the program's 1e-6 (published: 1e-5), and the rotary
@@ -37,6 +38,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 RMS_EPS = 1e-6
@@ -83,7 +85,8 @@ def _rope(x, theta: float):
 
 def _experts(y, lp, k: int, renormalize: bool):
     """MoE(y) for y [T, E]: the router's k largest probabilities of each token,
-    then one expert after the other over every token."""
+    then one expert after the other over every token.  Returns (MoE(y), the
+    tokens' weights [T, X])."""
     probs = jax.nn.softmax(y @ lp["router"].astype(jnp.float32), axis=-1)  # [T, X]
     top, idx = lax.top_k(probs, k)
     if renormalize:
@@ -97,13 +100,14 @@ def _experts(y, lp, k: int, renormalize: bool):
         return acc + w_e[:, None] * ((jax.nn.silu(y @ wg) * (y @ wu)) @ wd), None
 
     out, _ = lax.scan(one_expert, jnp.zeros_like(y), (lp["w_gate"], lp["w_up"], lp["w_down"], weight.T))
-    return out
+    return out, weight
 
 
 @functools.partial(jax.jit, static_argnames=("dims",))
 def _layer(x, lp, *, dims):
     """One block over one sequence.  x: [T, E] float32; lp: this layer's
-    weights in whatever type they are stored in."""
+    weights in whatever type they are stored in.  Returns (the block's output,
+    what its experts were given: the normed stream [T, E])."""
     h, kv, d, theta, k, renormalize = dims
     f32 = lambda name: lp[name].astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -122,22 +126,73 @@ def _layer(x, lp, *, dims):
             p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
             outs.append(jnp.einsum("hqk,khd->qhd", p, v[:hi]))
         x = x + jnp.concatenate(outs, axis=0).reshape(t, h * d) @ f32("wo")
-        return x + _experts(_rms_norm(x, f32("ln2")), lp, k, renormalize)
+        y = _rms_norm(x, f32("ln2"))
+        return x + _experts(y, lp, k, renormalize)[0], y
+
+
+def _blocks(params: Dict[str, Any], ids, cfg):
+    """ids: [T] through the stack.  Yields, a layer at a time, (the block's
+    output [T, E], what its experts were given [T, E])."""
+    dims = (cfg.n_heads, cfg.n_kv_heads, cfg.d_head, float(cfg.rope_theta),
+            cfg.n_experts_per_tok, bool(cfg.moe_renormalize))
+    x = params["embed"][jnp.asarray(ids)].astype(jnp.float32)
+    for i in range(params["blocks"]["wq"].shape[0]):
+        x, y = _layer(x, _layer_of(params, i), dims=dims)
+        yield x, y
+
+
+def _layer_of(params, i: int):
+    return jax.tree_util.tree_map(lambda w: w[i], params["blocks"])
+
+
+def _head(params, x):
+    with jax.default_matmul_precision("highest"):
+        return _rms_norm(x, params["ln_f"].astype(jnp.float32)) @ params["lm_head"].astype(jnp.float32)
 
 
 def forward(params: Dict[str, Any], ids, cfg):
     """ids: [T] -> logits [T, V], float32.  `cfg`: the program's
     TransformerConfig, read for its head counts, head size, rope_theta, the
     experts a token takes and whether their probabilities are renormalised."""
-    dims = (cfg.n_heads, cfg.n_kv_heads, cfg.d_head, float(cfg.rope_theta),
-            cfg.n_experts_per_tok, bool(cfg.moe_renormalize))
-    x = params["embed"][jnp.asarray(ids)].astype(jnp.float32)
-    n_layers = params["blocks"]["wq"].shape[0]
-    for i in range(n_layers):
-        x = _layer(x, jax.tree_util.tree_map(lambda w: w[i], params["blocks"]), dims=dims)
-    with jax.default_matmul_precision("highest"):
-        x = _rms_norm(x, params["ln_f"].astype(jnp.float32))
-        return x @ params["lm_head"].astype(jnp.float32)
+    for x, _ in _blocks(params, ids, cfg):
+        pass
+    return _head(params, x)
+
+
+# -- what chose a served token ------------------------------------------------------
+# One causal token a step from the last position's logits, which is the harness's
+# default (harness/reference.py default_chosen_logits), by this file's own pass:
+# the same pass says what every layer's experts were given, which
+# `mechanism_checks` (at the end of this file) reads again.  A stream is padded on
+# the right to a multiple of ROW_BLOCK and not to the longest stream, and the
+# head takes the rows that chose a token alone: the float32 arithmetic of a row
+# is the default's, the compiled shapes are not, so the numbers are the
+# default's to float32's rounding.
+ROW_BLOCK = 128
+# a stream's ids -> [layer] of [T, E]: kept by `chosen_logits` until `mechanism_checks` takes it
+_given: Dict[bytes, list] = {}
+
+
+def _stream_ids(stream) -> np.ndarray:
+    return np.asarray(stream["prompt_ids"] + stream["served"][:-1], np.int32)
+
+
+def _given_of(params, ids, cfg):
+    """ids [T] through the stack, padded on the right (a causal model's earlier
+    positions do not see what follows).  Returns (the last block's output
+    [T, E], what each layer's experts were given: [layer] of [T, E])."""
+    given = []
+    for x, y in _blocks(params, np.pad(ids, (0, -len(ids) % ROW_BLOCK)), cfg):
+        given.append(y[:len(ids)])
+    return x[:len(ids)], given
+
+
+def chosen_logits(cb, stream) -> np.ndarray:
+    """Row i: the logits at position len(prompt) - 1 + i of prompt +
+    served[:-1], which chose served[i]."""
+    ids, n = _stream_ids(stream), len(stream["prompt_ids"])
+    x, _given[ids.tobytes()] = _given_of(cb.params, ids, cb.cfg)
+    return np.asarray(_head(cb.params, x[n - 1:]))
 
 
 def loss(params, ids, cfg) -> float:
@@ -236,13 +291,15 @@ def decode_step_bytes(c: Dict[str, Any], slots: int, t_max: int, bytes_per: int 
 # (token, layer) pairs (0.9-1.3%).  Each swaps two experts whose probabilities
 # are equal to 3 digits, and all of them together move the prompt row's logits by
 # 0.005-0.056 and flip no token: inside the program's own 0.04-0.11.  What no
-# bound here can see, measured the same way: the router's softmax in bf16
-# (106-154 swaps, logits 0.012-0.054) and float8 in the experts alone (logits
-# 0.056-0.087, regrets 0.025-0.077 and 0.00025-0.0025 over 12 seeds, the
-# program's own range; an expert's result enters the stream times a probability
-# of 0.02-0.05).  Both sit inside bf16's own noise at this depth: they need a
-# check of the expert layer's output by itself, which is the harness's to add
-# (PERF.md section 7, ROADMAP R0b).
+# bound on the logits or the regrets can see, measured the same way: the
+# router's softmax in bf16 (106-154 swaps, logits 0.012-0.054) and float8 in the
+# experts alone (PR 27: logits 0.056-0.087, regrets 0.025-0.077 and
+# 0.00025-0.0025 over 12 seeds; again in PR 33, the streams served from experts
+# rounded to e4m3: regrets 0.020-0.068 and 0.00033-0.0015 over 13 seeds, 168-256
+# of 256 tokens the program's own, `correct` by these three bounds in all 13;
+# an expert's result enters the stream times a probability of 0.02-0.05).  Both
+# sit inside bf16's own noise at this depth.  `mechanism_checks`, at the end of
+# this file, holds the expert layer by itself and fails both in every seed.
 LOGIT_TOL = 0.2
 # The regret of the served tokens, which alone holds the batch decode: the best
 # two of 50,304 logits lie 0.06-0.33 apart at the median position, and bf16
@@ -275,3 +332,194 @@ REGRET_MEAN_TOL = 0.002
 # through the stack moves a mean over thousands of positions by 1e-3 at most)
 # holds here as well.
 LOSS_TOL = 0.01
+
+
+# -- the expert layer by itself ---------------------------------------------------
+# What the logits cannot see (above: float8 in the experts alone, a bf16 router
+# softmax): the program's routed expert layer against this reference's loop over
+# the experts, on the same rows, with nothing else of the model between them.
+#
+# Rows: what every layer's experts are given at every position of the check
+# streams, as this reference computes it in float32, so attention's error does
+# not enter: prompts and served tokens, 748 + 4 x 63 = 1,000 rows a layer in the
+# chat mixes.  `chosen_logits` keeps them from the one pass that the logits
+# come from.  Every layer the configuration holds: the program reads a layer's
+# experts out of the stack by the layer's index, so a fault can sit in one layer,
+# and ten layers are ten times the rows for the router's share.
+#
+# The program: the block's own entry to its expert layer, `models/transformer.py`
+# `_moe`, which `_ffn_half` calls in the prefill and the decode programs and
+# which calls `parallel/moe.py` `routed_ffn`, at the shapes the window's
+# programs give it (`program_shapes`, below).  The compiled window programs hand
+# out logits, tokens and the cache and no layer's result, so the layer cannot be
+# read out of them without a change to the program; the check enters one call
+# below them, with their arguments:
+#   prefill   a prompt alone, [1, bucket, E], padded on the left to the bucket
+#       the batcher admits it in (`cb._bucket`), the pads not live; every layer's
+#       experts as stored and the layer's index.
+#   decode    [slots, 1, E]: step j holds row j of each stream's served tokens
+#       in slots 0, 1, ... (the batcher fills its free slots in order), the
+#       other slots not live: 4 live rows of 32 in the chat mixes, whose windows
+#       run at 4 to 6.  The steps are a `lax.map` over that one shape.
+# tests/benchmark/test_benchmark_olmoe.py holds that a batcher serving the
+# streams traces `_moe` with exactly these shapes and no others, and that each
+# reaches `routed_ffn`.  Each call is made twice in one compiled program, so
+# that both share one routing: once with the model's experts, and once with
+# probe experts of the ungated kind (every expert the same first matrix, expert
+# e's second matrix all in column e), whose result is non-zero in column e of a
+# row exactly where the program's router gave that row to expert e.  So the
+# program's own choice of experts is read without a change to the program.
+#
+# Both sides are given the same rows: the reference's float32 rows rounded to
+# the program's activation type, which is how a block hands them to its expert
+# layer (the rounding of its input is not the layer's error; with the reference
+# on the unrounded rows the program's router read 1.00-1.40% other sets and a
+# bf16 softmax 2.32-2.88%, 1.7 x apart: PR 33's first call).
+#
+#   moe_router_other_set      the share of (row, layer) pairs in which the
+#       program's set of k is not this reference's.
+#   moe_experts_rel_err       over the rows whose sets agree, the largest
+#       |program - reference| / |reference| of a row's result (2-norms over the
+#       model width).  A row whose sets differ swaps two experts of all but equal
+#       probability, which moves its result by a third of its norm: that is the
+#       router's number, and it would drown the experts' in a maximum.
+#
+# The tolerances, from the chip at the cell's own size (my chip runs, PR 33: 13
+# seeds in one process, each the four check streams served together by a batcher
+# alone, 10,000 (row, layer) pairs a seed, every verdict `check_serving`'s own;
+# `_archive/precision33b.py`; the readings in brackets are the check's first
+# form on 13 other seeds, `routed_ffn` by hand on the rows as one block of
+# 1,024).  Lower reading: the program's largest.  Upper: the least of the lower
+# precision that is this number's to catch, planted in `routed_ffn` once the
+# streams are served, so the three numbers on the logits are the program's and
+# pass while `ok` comes out false: in all 13 seeds for each of the two.
+#   The router: the program **0 of 10,000 in all 13 seeds** [0 in 13] (a float32
+# softmax over float32 sums of the same bf16 products picks the reference's
+# eight, at 32 rows as at 512); the softmax in bf16 **2.12-2.53%** [2.22-2.54%]
+# (212-253 pairs: bf16 has 8 bits for 64 probabilities near 1/64, ties go to the
+# lower index).  The bound allows 50 pairs: rows at which two float32 sums tie to
+# the last bit are the program's right, and 4.2 x lies between the bound and the
+# control's least.
+#   The experts: the program **0.00446-0.00478** [0.00443-0.00504], and in the
+# cell itself 0.00445-0.00470 in eight runs on eight more seeds and **0.00524**
+# in a ninth (bf16's 2^-9 through three matmuls and the cast of the result; a
+# maximum over 10,000 rows, which has a tail); the experts' matrices rounded to
+# float8 e4m3 **0.0599-0.0629** [0.0595-0.0633] (2^-4 a weight, subnormal under
+# 2^-6, which half of these weights are).  The bound is 3.8 x over the program's
+# largest of the 35 and 3.0 x under float8's least.  The bf16 softmax reads
+# 0.0096-0.0110 here (its gates carry 8 bits), under the bound: it is the
+# router's number that fails it.  Float8 inside one compiled program is rounded
+# by arithmetic in the control: the TPU's compiler takes
+# `astype(float8_e4m3fn).astype(bfloat16)` for nothing (every element that the
+# rounding moves, 8.13 M of 8.39 M, came back unmoved).
+PROBE_WIDTH = 128
+MOE_ROUTER_SET_TOL = 0.005
+MOE_EXPERTS_ERR_TOL = 0.02
+
+
+@functools.partial(jax.jit, static_argnames=("k", "renormalize"))
+def _layer_errors(rows, lp, got, chosen, *, k, renormalize):
+    """One layer's two numbers on the device: (the rows whose set of k,
+    `chosen` [N, X], is not this reference's; the largest relative error of a
+    row's result `got` among the others).  `rows` [N, E] come in the program's
+    activation type and are this reference's input as they are."""
+    with jax.default_matmul_precision("highest"):
+        want, weight = _experts(rows.astype(jnp.float32), lp, k, renormalize)
+    same = jnp.all(chosen == (weight > 0), axis=-1)
+    err = jnp.linalg.norm(got.astype(jnp.float32) - want, axis=-1) / jnp.linalg.norm(want, axis=-1)
+    return jnp.sum(~same), jnp.max(jnp.where(same, err, 0.0))
+
+
+def _probe_experts(e_model: int, n_experts: int, dtype):
+    """Ungated experts whose result says which of them a row was given to:
+    silu(x W) summed into column e by expert e.  W's entries are +-2/sqrt(E), so
+    a row of unit RMS gives PROBE_WIDTH pre-activations of deviation 2 and a sum
+    of their silu near 85: never rounding's zero.  Made on the host and laid out
+    inside the compiled program that uses it."""
+    assert n_experts <= e_model, "an expert's column has to lie inside the model width"
+    signs = np.random.default_rng(0).integers(0, 2, (e_model, PROBE_WIDTH)) * 4.0 - 2.0
+    w_in = jnp.broadcast_to(jnp.asarray(signs * e_model ** -0.5, dtype), (1, n_experts, e_model, PROBE_WIDTH))
+    w_out = jnp.broadcast_to(jax.nn.one_hot(jnp.arange(n_experts), e_model, dtype=dtype)[:, None, :],
+                             (1, n_experts, PROBE_WIDTH, e_model))
+    return {"w_in": w_in, "w_out": w_out}
+
+
+def program_shapes(cb, streams):
+    """How the rows of the check streams (stream by stream, a stream's prompt
+    and then its served tokens but the last) lie in the calls of `_moe` that
+    serving the streams together makes.  Returns (prefills: a (first row, rows,
+    pads on the left) a stream; decode: [steps, slots], the row a slot holds at a
+    step, or the number of rows where the slot is not live)."""
+    assert len(streams) <= cb.slots, "the check streams are served together, a slot each"
+    prompts = [len(s["prompt_ids"]) for s in streams]
+    steps = [len(s["served"]) - 1 for s in streams]
+    first = np.cumsum([0] + [n + t for n, t in zip(prompts, steps)])
+    prefills = [(int(off), n, cb._bucket(n, len(s["served"])) - n) for off, n, s in zip(first, prompts, streams)]
+    decode = np.full((max(steps), cb.slots), first[-1], np.int32)
+    for slot, (off, n, t) in enumerate(zip(first, prompts, steps)):
+        decode[:t, slot] = off + n + np.arange(t)
+    return prefills, decode
+
+
+def _program(cfg, prefills, decode, n: int):
+    """The compiled program of one layer's calls of `_moe`, laid out by
+    `program_shapes`: (given: [stream] of [T, E] float32, every layer's routers,
+    every layer's experts, the layer's index) -> (the n rows as a block hands
+    them over, in the activations' type; what `_moe` makes of them in the
+    prefill's and the decode's shapes; which experts it gave each to)."""
+    from cluster_anywhere_tpu.models.transformer import _moe
+
+    held = np.nonzero(decode.reshape(-1) < n)[0]  # the (step, slot) pairs that hold a row
+    at = decode.reshape(-1)[held]
+
+    @jax.jit
+    def program(given, routers, experts, layer):
+        rows = jnp.concatenate(given).astype(cfg.dtype)
+        bp = {"router": routers[layer]}
+        probe = _probe_experts(cfg.d_model, cfg.n_experts, cfg.dtype)
+
+        def both(y, live):
+            return (_moe(bp, y, cfg, live, (experts, layer))[0],
+                    _moe(bp, y, cfg, live, (probe, 0))[0][..., :cfg.n_experts] != 0)
+
+        got, chosen = jnp.zeros_like(rows), jnp.zeros((n, cfg.n_experts), bool)
+        for off, t, pad in prefills:
+            out, marks = both(jnp.pad(rows[off:off + t], ((pad, 0), (0, 0)))[None],
+                              jnp.asarray(np.arange(pad + t) >= pad)[None])
+            got, chosen = got.at[off:off + t].set(out[0, pad:]), chosen.at[off:off + t].set(marks[0, pad:])
+        steps = jnp.pad(rows, ((0, 1), (0, 0)))[decode][:, :, None, :]  # [steps, slots, 1, E]
+        out, marks = lax.map(lambda step: both(*step), (steps, jnp.asarray(decode < n)[:, :, None]))
+        got = got.at[at].set(out.reshape(-1, out.shape[-1])[held])
+        return rows, got, chosen.at[at].set(marks.reshape(-1, cfg.n_experts)[held])
+
+    return program
+
+
+def mechanism_checks(cb, streams):
+    """The two numbers above, over every layer and every position of the check
+    streams (references/__init__.py says what the harness does with them)."""
+    from cluster_anywhere_tpu.parallel.moe import EXPERT_MATRICES
+
+    params, cfg = cb.params, cb.cfg
+    given = [_given.pop(ids.tobytes(), None) or _given_of(params, ids, cfg)[1] for ids in map(_stream_ids, streams)]
+    n = sum(len(g[0]) for g in given)
+    program = _program(cfg, *program_shapes(cb, streams), n)
+    experts = {name: params["blocks"][name] for name in EXPERT_MATRICES if name in params["blocks"]}
+    numbers = []
+    for layer in range(len(given[0])):
+        if numbers:
+            # one layer's copy out of the stack (0.84 GB at the cell's size) at a time: the
+            # host runs ahead of the device, and every copy it has asked for is allocated
+            jax.block_until_ready(numbers[-1])
+        rows, got, chosen = program([g[layer] for g in given], params["blocks"]["router"], experts, layer)
+        numbers.append(_layer_errors(rows, _layer_of(params, layer), got, chosen,
+                                     k=cfg.n_experts_per_tok, renormalize=bool(cfg.moe_renormalize)))
+    other_sets, worst = (np.asarray(x) for x in zip(*numbers))
+    pairs = n * len(numbers)
+    return [
+        {"name": "moe_router_other_set", "error": int(other_sets.sum()) / pairs, "tolerance": MOE_ROUTER_SET_TOL,
+         "why": f"(row, layer) pairs of {pairs} in which the program's set of {cfg.n_experts_per_tok} is not "
+                "the float32 reference's"},
+        {"name": "moe_experts_rel_err", "error": float(worst.max()), "tolerance": MOE_EXPERTS_ERR_TOL,
+         "why": "largest relative error of a row's expert-layer result, over the rows whose sets agree"},
+    ]

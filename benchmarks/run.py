@@ -62,7 +62,6 @@ def result_line(cell, driver, ctx, trace: bool):
             if cell["name"] in m.get("workloads", [cell["name"]])
         }
     out["device"] = device
-    out["check"] = ctx["check"]
     if hasattr(driver, "knee_stats"):
         out["knee"] = driver.knee_stats(ctx)
     for extra in ("restarts", "stalls"):
@@ -70,6 +69,7 @@ def result_line(cell, driver, ctx, trace: bool):
             out[extra] = ctx[extra]
     if first_failure:
         out["first_failure"] = first_failure
+    out["check"] = ctx["check"]  # every number compared beside its limit: the line's last key
     return out
 
 
@@ -105,6 +105,7 @@ def main(argv=None) -> int:
         # what a builder reads when a number looks wrong; nothing reads it back
         with open(os.path.join(cluster.out_dir(), f"{cell['name']}.last.json"), "w") as f:
             json.dump(driver.dump(ctx), f)
+    say(correct=line["correct"], check=line["check"])  # and standard error's last line
     print(json.dumps(line), flush=True)
     return 0
 

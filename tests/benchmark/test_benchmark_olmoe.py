@@ -115,6 +115,120 @@ def test_serving_check_holds_prefill_and_the_batch_decode_to_the_reference(dtype
     assert not bad["ok"] and bad["regret_max"] > reference.REGRET_MAX_TOL
 
 
+def _float8_experts(routed_ffn):
+    """The program's expert layer with the layer's experts rounded to float8 e4m3
+    (3 bits of mantissa), the experts alone."""
+    def routed(x, router, experts, layer=0, **kw):
+        rounded = {n: w[layer][None].astype(jnp.float8_e4m3fn).astype(w.dtype) for n, w in experts.items()}
+        return routed_ffn(x, router, rounded, 0, **kw)
+    return routed
+
+
+def _bf16_softmax(routed_ffn):
+    """The program's expert layer with the router's softmax computed in bf16."""
+    def routed(*args, **kw):
+        with pytest.MonkeyPatch.context() as m:
+            softmax = jax.nn.softmax
+            m.setattr(jax.nn, "softmax", lambda v, axis=-1: softmax(v.astype(jnp.bfloat16), axis=axis))
+            return routed_ffn(*args, **kw)
+    return routed
+
+
+def _served_together(cfg, params, lens=(11, 40, 70), new_tokens=9):
+    """A batcher alone and the streams it served together, as `bench_check`
+    hands them to `check_serving`."""
+    cb = ContinuousBatcher(params, cfg, slots=4, t_max=128, prefill_buckets=(32, 64, 96))
+    rng = np.random.default_rng(5)
+    reqs = [cb.submit(rng.integers(0, cfg.vocab_size, n), max_new_tokens=new_tokens) for n in lens]
+    cb.pump()
+    return cb, [{"prompt_ids": r.prompt_ids.tolist(), "served": list(r.out_tokens),
+                 "request_id": r.request_id} for r in reqs]
+
+
+@pytest.mark.parametrize("variant, fails", [
+    (None, set()), (_float8_experts, {"moe_experts_rel_err"}), (_bf16_softmax, {"moe_router_other_set"}),
+], ids=["program", "float8-experts", "bf16-softmax"])
+def test_the_expert_layer_is_held_by_itself(variant, fails, monkeypatch):
+    """The serving check at a test's widths with float32 weights: the program
+    passes, and each of the two lower precisions that the logits cannot see on
+    the chip, planted in `routed_ffn` once the streams are served, comes out as
+    not correct by `check_serving`'s own verdict: it fails the mechanism's
+    number that is its own while the three numbers on the logits pass."""
+    from cluster_anywhere_tpu.parallel import moe
+
+    cfg, params = program(jnp.float32, num_experts=32, num_experts_per_tok=4)
+    cb, streams = _served_together(cfg, params)
+    if variant is not None:
+        monkeypatch.setattr(moe, "routed_ffn", variant(moe.routed_ffn))
+    rep = check_serving(cb, streams, reference)
+    got = {m["name"]: m for m in rep["mechanism"]}
+    assert list(got) == ["moe_router_other_set", "moe_experts_rel_err"]
+    assert got["moe_router_other_set"]["tolerance"] == reference.MOE_ROUTER_SET_TOL
+    assert got["moe_experts_rel_err"]["tolerance"] == reference.MOE_EXPERTS_ERR_TOL
+    # every position of every stream at every layer: (11 + 40 + 70 + 3 * 8) rows x 2 layers
+    assert "of 290 in which" in got["moe_router_other_set"]["why"]
+    assert {n for n, m in got.items() if not m["error"] <= m["tolerance"]} == fails, got
+    # the streams were served by the program as it is, in float32: the logits see nothing
+    assert rep["logit_max_abs_err"] < 1e-3 and rep["regret_max"] < 1e-3 and rep["regret_mean"] < 1e-3, rep
+    assert rep["ok"] is (not fails), rep
+    assert not reference._given  # what `chosen_logits` kept, `mechanism_checks` took
+    if variant is None:
+        # float32 on both sides: the same sets in every row, the results to rounding
+        assert got["moe_router_other_set"]["error"] == 0.0 and got["moe_experts_rel_err"]["error"] < 1e-5
+        # asked by itself it makes the pass that `chosen_logits` did not keep for it
+        alone = reference.mechanism_checks(cb, streams)
+        assert [m["error"] for m in alone] == [m["error"] for m in rep["mechanism"]]
+        monkeypatch.setattr(reference, "MOE_EXPERTS_ERR_TOL", rep["mechanism"][1]["error"] / 2)
+        assert not check_serving(cb, streams, reference)["ok"]
+
+
+def test_the_mechanism_enters_the_expert_layer_as_the_served_programs_do(monkeypatch):
+    """The compiled prefill and decode programs hand out no layer's result, so
+    `mechanism_checks` calls the block's entry to its expert layer,
+    `transformer._moe`, itself.  What holds it to the timed path: a batcher
+    that serves the check streams traces `_moe` with the shapes, the types and
+    the unsliced stack that the check gives it, and no others; each of those
+    calls reaches `routed_ffn`."""
+    from cluster_anywhere_tpu.models import transformer
+    from cluster_anywhere_tpu.parallel import moe
+
+    # a configuration no other test of this process has traced: the calls are seen at trace time
+    cfg, params = program(jnp.bfloat16, num_experts=16, num_experts_per_tok=3, intermediate_size=40)
+    calls, reached = [], []
+    inner_moe, inner_routed = transformer._moe, moe.routed_ffn
+
+    def seen_moe(bp, y, cfg_, live=None, experts=None):
+        stack, layer = experts
+        calls.append((y.shape, str(y.dtype), live.shape, str(live.dtype), isinstance(layer, jax.core.Tracer),
+                      tuple(sorted((n, w.shape) for n, w in stack.items() if "w_in" not in stack))))
+        return inner_moe(bp, y, cfg_, live, experts)
+
+    def seen_routed(x, *a, **kw):
+        reached.append(x.shape)
+        return inner_routed(x, *a, **kw)
+
+    monkeypatch.setattr(transformer, "_moe", seen_moe)
+    monkeypatch.setattr(moe, "routed_ffn", seen_routed)
+    cb, streams = _served_together(cfg, params)
+    served, served_reached = set(calls), set(reached)
+    # three prompts alone in their buckets, and every decode step over the four slots
+    stack = tuple(sorted((n, params["blocks"][n].shape) for n in moe.EXPERT_MATRICES if n in params["blocks"]))
+    assert served == {((1, b, 64), "bfloat16", (1, b), "bool", True, stack) for b in (32, 64, 96)} | {
+        ((4, 1, 64), "bfloat16", (4, 1), "bool", True, stack)}
+    assert served_reached == {(32, 64), (64, 64), (96, 64), (4, 64)}
+    del calls[:], reached[:]
+    numbers = reference.mechanism_checks(cb, streams)
+    assert all(m["error"] <= m["tolerance"] for m in numbers), numbers
+    # the model's experts as the programs hand them over; the probe experts are the check's own
+    assert {c for c in calls if c[-1]} == served and {c[:4] for c in calls} == {c[:4] for c in served}
+    assert set(reached) == served_reached
+    prefills, decode = reference.program_shapes(cb, streams)
+    assert [(t, pad) for _, t, pad in prefills] == [(11, 21), (40, 24), (70, 26)]
+    # a step holds the three streams' rows in the first slots; the fourth slot is not live
+    assert decode.shape == (8, 4) and decode[0].tolist() == [11, 19 + 40, 19 + 48 + 70, 145]
+    assert (decode[:, 3] == 145).all() and (np.diff(decode[:, :3], axis=0) == 1).all()
+
+
 def test_counts_against_hand_counts():
     c = dict(hidden_size=8, num_attention_heads=2, num_key_value_heads=2, head_dim=4, intermediate_size=16,
              num_hidden_layers=3, vocab_size=32, num_experts=4, num_experts_per_tok=2)
@@ -215,7 +329,12 @@ def test_serve_rehearsal_of_olmoe_closed6():
     check = ctx["check"]
     assert check["streams"] == 3 and check["positions"] == 24 and check["decode_batch_mean"] > 1.0, check
     assert check["ok"] and check["repeat_identical"] and out["correct"], check
-    assert check["logit_tolerance"] == reference.LOGIT_TOL
+    assert check["logit_tolerance"] == reference.LOGIT_TOL and check["decode_requests_mean"] > 1.0
+    # the expert layer by itself, over every position of the three streams in both layers
+    mechanism = {m["name"]: m for m in check["mechanism"]}
+    assert set(mechanism) == {"moe_router_other_set", "moe_experts_rel_err"}
+    assert all(m["error"] <= m["tolerance"] for m in mechanism.values()), mechanism
+    assert f"of {2 * (12 + 30 + 70 + 3 * 7)} in which" in mechanism["moe_router_other_set"]["why"]
     # the batcher counted the routed assignments: the replica ran the expert path
     assert ctx["replica"]["stats"]["moe_assignments"] > 0
     layer = manifest.read_layer_metrics(CELL, ctx)

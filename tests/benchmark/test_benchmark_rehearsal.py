@@ -60,7 +60,10 @@ def test_serve_rehearsal(name):
     # the check streams were one batch of the decode program, and the reference passed them
     check = ctx["check"]
     assert check["streams"] == 3 and check["positions"] == 24 and check["decode_batch_mean"] > 1.0, check
+    # a step hands a request one token, two in the step that admits it
+    assert 1.0 < check["decode_requests_mean"] <= min(3.0, check["decode_batch_mean"]), check
     assert check["ok"] and check["repeat_identical"] and out["correct"], check
+    assert "mechanism" not in check  # the dense decoder's reference brings none
     e2e = serve_driver.end_to_end(ctx)
     assert all(v > 0 for v in e2e.values()), e2e
     assert {"setup_s", "gap_p50_s", "gap_mean_s", "ttft_p90_s", "serve_out_tok_s"} <= set(e2e)
@@ -75,9 +78,9 @@ def test_serve_rehearsal(name):
             assert all(b["due"] == a["token_times"][-1] for a, b in zip(recs, recs[1:]))
         assert max(r["due"] for r in ctx["records"]) < ctx["t_open"] + ctx["seconds"]
     layer = manifest.read_layer_metrics(name, ctx)
-    # the program's admit re-traces its eager `prefill` scan for every request
-    # (PERF.md, PR 23), so this is the count of admits and not yet 0
-    assert closed or layer["compiles_in_window"]["value"] >= 0
+    # an admit runs its bucket's compiled prefill program (PERF.md, PR 30), and the
+    # warm-up compiled every bucket the mix reaches: nothing compiles in the window
+    assert closed or layer["compiles_in_window"]["value"] == 0
     suffix = ".closed" if closed else ""
     assert layer["decode_batch_mean" + suffix]["value"] >= 1.0
     # no trace: the reader returns nothing
@@ -92,6 +95,8 @@ def test_serve_rehearsal(name):
     ctx["device"].update(platform="tpu", kind="TPU v5 lite", count=1)
     line = bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    # every number compared beside its limit, as the line's last key
+    assert list(line)[-1] == "check" and line["check"] is ctx["check"]
     assert set(line["metrics"]) == (
         {"setup_s", "serve_out_tok_s"} if closed else {"setup_s", "gap_p50_s", "gap_mean_s"})
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
