@@ -22,6 +22,24 @@ admitting or finishing requests never recompiles anything:
   the next step() can admit into it immediately — no head-of-line batching
   barrier, which is the whole point vs static generate() batching.
 
+A model that generates by blocks (`cfg.block_length` B > 1: an answer is made
+B positions at a time, by passes that fix the most confident masked positions)
+goes through the same scheduler, cache and admit; what differs is the step:
+- a step is one PASS of every live slot's own block, slots in different passes
+  of different blocks in one program (`_pass_step_rowpos`).  The slot vectors
+  carry a block's B tokens and fixed flags; the program runs the B positions
+  against the cache and themselves, writes the block's keys and values in
+  place, and chooses on the device what to fix (`_choose_block`).
+- a pass that finds every position of a block fixed has thereby stored the
+  block's keys and values as those of its tokens; the host moves the slot on to
+  its next block.  So a block of B costs its passes and one more.
+- a step hands a request 0 to B tokens, in position order: a token goes out
+  once every position before it is fixed.  An admit prefills the prompt's
+  whole blocks and hands out nothing; the prompt's tail is the fixed part of
+  the first block.
+- `fixed_at(request_id)` is the record of the pass of its block at which each
+  served token was fixed, which the tokens do not say.
+
 This module is the scheduler, the per-row sampler and the jitted wrapper.
 The model's mathematics is models/generate.py's: `prefill`, and `decode_rows`,
 the decode program's body, which also owns the cache's layout.  serve_llm.py
@@ -40,6 +58,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..models.generate import (
     _nucleus_mask, _sample, decode_rows, init_cache, install_rows, prefill, recurrent_state_bytes,
@@ -49,6 +68,10 @@ from ..util import tracing
 
 
 PREFILL_BUCKETS = (64, 128, 256)  # padded prompt lengths: one prefill program each
+_NO_TRUNCATION = (
+    "{what}: a replica that generates by blocks of {b} chooses each position's token and its "
+    "confidence without sorting the vocabulary; temperature alone is served, top-k and top-p are not"
+)
 
 
 @dataclass
@@ -68,6 +91,16 @@ class Request:
     out_tokens: List[int] = field(default_factory=list)
     slot: int = -1
     done: bool = False
+    # a model that generates by blocks: the pass of its block at which each of
+    # out_tokens was fixed; of the current block, the passes made, the pass
+    # that fixed each position (-1: a prompt token, or masked yet) and the
+    # positions handed out or of the prompt; and what was fixed past the
+    # answer's end when the request finished: [(position in the block, pass, token)]
+    fixed_at: List[int] = field(default_factory=list)
+    block_pass: int = 0
+    pass_of: List[int] = field(default_factory=list)
+    block_out: int = 0
+    block_tail: List[tuple] = field(default_factory=list)
 
 
 def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
@@ -116,6 +149,79 @@ def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
     logits, cache, touched = decode_rows(params, cache, tokens, pos, pads, cfg, live)
     nxt = _sample_rowwise(logits, keys[1:], temps, top_ks, top_ps)
     return nxt, cache, keys[0], touched
+
+
+def _choose_block(logits, fixed, live, temps, rng, cfg: TransformerConfig):
+    """What one pass fixes, on the device, without a sort of the vocabulary.
+    logits: [S, B, V] float32, each position's own; fixed: [S, B] bool; live:
+    [S] bool; temps: [S].  Every masked position (not fixed, in a live row)
+    proposes a token, the largest logit (temperature 0) or a sample of
+    softmax(logits / temperature) (a Gumbel maximum), with its confidence, the
+    token's probability under that distribution: a maximum and a log-sum-exp.
+    Fixed are the masked positions whose confidence passes
+    cfg.confidence_threshold, or, where fewer than m = B / denoise_steps do,
+    the m most confident (ties to the lower position), found by B x B
+    comparisons.  Returns (tokens [S, B] int32, newly fixed [S, B] bool)."""
+    with jax.named_scope("block.choose"):
+        b = logits.shape[1]
+
+        def greedy(_):
+            return jnp.argmax(logits, axis=-1), jnp.max(logits, axis=-1) - jax.nn.logsumexp(logits, axis=-1)
+
+        def sampled(rng):
+            t = temps[:, None, None]
+            scaled = jnp.where(t > 0.0, logits / jnp.maximum(t, 1e-6), logits)
+            noise = jnp.where(t > 0.0, jax.random.gumbel(rng, logits.shape, logits.dtype), 0.0)
+            tok = jnp.argmax(scaled + noise, axis=-1)
+            at_tok = jnp.take_along_axis(scaled, tok[..., None], axis=-1)[..., 0]
+            return tok, at_tok - jax.nn.logsumexp(scaled, axis=-1)
+
+        # the noise is as large as the logits: made only where a row asks for it
+        tok, log_conf = lax.cond(jnp.any((temps > 0.0) & live), sampled, greedy, rng)
+        masked = ~fixed & live[:, None]
+        conf = jnp.where(masked, log_conf, -jnp.inf)
+        high = conf > jnp.log(jnp.float32(cfg.confidence_threshold))
+        m = b // cfg.denoise_steps
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None]) & (jnp.arange(b)[None, :] < jnp.arange(b)[:, None]))
+        most = masked & (jnp.sum(ahead, axis=-1) < m)
+        fix = jnp.where(jnp.sum(high, axis=-1, keepdims=True) >= m, high, most)
+        return tok.astype(jnp.int32), fix
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def _pass_step_rowpos(params, cache, ints, floats, rng, *, cfg):
+    """One pass of every slot's own block (a model that generates by blocks of
+    B = cfg.block_length).  ints: [3 + 2B, S] int32, the rows pos (the cache
+    slot of the block's first position), pads, live, then the block's B tokens
+    and its B fixed flags; floats: [2, S], the rows temps, top_ps (the second
+    unused: a request that asks top-p is refused).  A position that is not
+    fixed goes in as cfg.mask_token_id whatever its token says, and fixedness
+    is the flag alone.  Returns (the blocks after the pass [2B, S]: tokens, then
+    flags; cache; the carried key; experts touched).  The B rows of keys and
+    values a slot and layer are written in place at [layer, b, pos : pos + B]
+    (models/generate.py, the decode block), as the causal step writes its
+    one: tests/test_chip_compile.py holds the chip's program to that and to no
+    sort of the vocabulary."""
+    b = cfg.block_length
+    pos, pads, live = ints[0], ints[1], ints[2] != 0
+    tokens, fixed = ints[3:3 + b].T, ints[3 + b:].T != 0
+    key, sub = jax.random.split(rng)
+    ids = jnp.where(fixed, tokens, cfg.mask_token_id)
+    logits, cache, touched = decode_rows(params, cache, ids, pos, pads, cfg, live)
+    chosen, fix = _choose_block(logits, fixed, live, floats[0], sub, cfg)
+    after = jnp.concatenate([jnp.where(fix, chosen, tokens).T, (fixed | fix).T.astype(jnp.int32)])
+    return after, cache, key, touched
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def _pass_logits(params, rows, ids, pos, pad, *, cfg):
+    """One pass over a SINGLE request's block against its own cache rows (a
+    cache of batch one, donated): ids [1, B] as the step would feed them, pos
+    and pad [1].  Returns (every position's logits [1, B, V] float32, the
+    rows).  What the step's program computes before it chooses, for a check
+    that wants the logits themselves (the step hands out tokens)."""
+    return decode_rows(params, rows, ids, pos, pad, cfg)[:2]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -206,6 +312,19 @@ class ContinuousBatcher:
         self.slots = slots
         self.t_max = t_max
         self.top_k = top_k
+        # B where the model generates by blocks of B positions, else 0
+        self._block = cfg.block_length if cfg.generates_blocks else 0
+        if self._block:
+            if prefix_cache_entries > 0:
+                raise ValueError(
+                    f"prefix_cache_entries={prefix_cache_entries}: a replica that generates by blocks "
+                    f"of {self._block} keeps no prefix cache (its split would have to fall on a block's "
+                    "edge and its suffix run as passes: not built); pass 0"
+                )
+            if top_k:
+                raise ValueError(_NO_TRUNCATION.format(what=f"a default top_k of {top_k}", b=self._block))
+            if t_max % self._block:
+                raise ValueError(f"a cache of {t_max} slots is no whole number of blocks of {self._block}")
         self.prefill_buckets = tuple(sorted(prefill_buckets))
         # prefix/KV reuse (0 entries = off, the pre-cache admit path
         # verbatim).  When on, admit splits the prompt at the largest
@@ -238,10 +357,17 @@ class ContinuousBatcher:
         # them only in an admit and in llm.step.scatter, after the step's
         # tokens are read back: the program has consumed them by then (on the
         # CPU backend a host array may be aliased, not copied).
-        self._ints = np.zeros((5 if cfg.n_experts else 4, slots), np.int32)
         self._floats = np.zeros((2, slots), np.float32)
-        # _pos: cache slot of the NEXT write; a mixture's fifth row: live slots
-        self._tokens, self._pos, self._pads, self._topks = self._ints[:4]
+        if self._block:
+            # _pos: the cache slot of the block's first position; _blk_tokens,
+            # _blk_fixed: [B, S], the block's tokens and which of them are fixed
+            self._ints = np.zeros((3 + 2 * self._block, slots), np.int32)
+            self._pos, self._pads, self._live = self._ints[:3]
+            self._blk_tokens, self._blk_fixed = self._ints[3:3 + self._block], self._ints[3 + self._block:]
+        else:
+            self._ints = np.zeros((5 if cfg.n_experts else 4, slots), np.int32)
+            # _pos: cache slot of the NEXT write; a mixture's fifth row: live slots
+            self._tokens, self._pos, self._pads, self._topks = self._ints[:4]
         self._temps, self._topps = self._floats
         self._topps[:] = 1.0
         self._by_slot: List[Optional[Request]] = [None] * slots
@@ -266,6 +392,10 @@ class ContinuousBatcher:
             # recurrent state read and written by the steps and installed by the
             # admits; stays 0 for a model of attention layers alone
             "ssm_state_bytes": 0,
+            # a model that generates by blocks: passes of one slot's block (a
+            # step is one for every live slot) and the positions they fixed;
+            # stay 0 for one causal token a step
+            "block_passes": 0, "block_tokens_fixed": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -285,10 +415,12 @@ class ContinuousBatcher:
                 f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
                 f"exceeds the cache length {self.t_max}"
             )
+        top_k = self.top_k if top_k is None else int(top_k)
+        if self._block and (top_k > 0 or 0.0 < float(top_p) < 1.0):
+            raise ValueError(_NO_TRUNCATION.format(what=f"top_k={top_k}, top_p={top_p}", b=self._block))
         req = Request(
             next(self._ids), prompt, int(max_new_tokens), float(temperature),
-            self.top_k if top_k is None else int(top_k), float(top_p), eos_id,
-            tracing.current(), time.monotonic(),
+            top_k, float(top_p), eos_id, tracing.current(), time.monotonic(),
         )
         self.queue.append(req)
         self.stats["submitted"] += 1
@@ -330,6 +462,9 @@ class ContinuousBatcher:
             sp.set(live=len(live))
             if not live:
                 return out
+            if self._block:
+                self._step_blocks(live, out, sp)
+                return out
             with tracing.span("llm.step.upload"):
                 if self.cfg.n_experts:
                     self._ints[4] = [r is not None for r in self._by_slot]
@@ -361,6 +496,83 @@ class ContinuousBatcher:
                     ):
                         self._finish(s, req)
             return out
+
+    def _step_blocks(self, live: List[int], out: Dict[int, List[int]], sp: tracing.span) -> None:
+        """One pass of every live slot's block (module doc): the step of a
+        model that generates by blocks, between the admits and the return."""
+        b = self._block
+        with tracing.span("llm.step.upload"):
+            self._live[:] = [r is not None for r in self._by_slot]
+        with tracing.span("llm.step.dispatch"):
+            after, self.cache, self._rng, touched = _pass_step_rowpos(
+                self.params, self.cache, self._ints, self._floats, self._rng, cfg=self.cfg,
+            )
+        with tracing.span("llm.step.readback"):
+            after, touched = jax.device_get((after, touched))
+        fixed_now = handed = stored = 0
+        with tracing.span("llm.step.scatter"):
+            for s in live:
+                req = self._by_slot[s]
+                if self._blk_fixed[:, s].all():
+                    # the pass found nothing masked: it stored the block
+                    stored += 1
+                    self._pos[s] += b
+                    self._blk_tokens[:, s] = 0
+                    self._blk_fixed[:, s] = 0
+                    req.block_pass, req.block_out, req.pass_of = 0, 0, [-1] * b
+                    continue
+                for i in np.nonzero(after[b:, s] != self._blk_fixed[:, s])[0]:
+                    req.pass_of[i] = req.block_pass
+                    fixed_now += 1
+                self._blk_tokens[:, s], self._blk_fixed[:, s] = after[:b, s], after[b:, s]
+                req.block_pass += 1
+                new = out.setdefault(req.request_id, [])
+                while req.block_out < b and self._blk_fixed[req.block_out, s] and not req.done:
+                    tok = int(self._blk_tokens[req.block_out, s])
+                    req.out_tokens.append(tok)
+                    req.fixed_at.append(req.pass_of[req.block_out])
+                    new.append(tok)
+                    req.block_out += 1
+                    if len(req.out_tokens) >= req.max_new_tokens or (
+                        req.eos_id is not None and tok == req.eos_id
+                    ):
+                        req.block_tail = [
+                            (i, req.pass_of[i], int(self._blk_tokens[i, s]))
+                            for i in range(req.block_out, b) if self._blk_fixed[i, s]
+                        ]
+                        self._finish(s, req)
+                handed += len(new)
+                if not new:
+                    del out[req.request_id]
+        said = dict(block_rows=len(live) * b, tokens_fixed=fixed_now, tokens_out=handed, store_rows=stored)
+        if touched is not None:
+            said.update(moe_rows=len(live) * b, moe_experts_touched=float(touched))
+            self.stats["moe_assignments"] += len(live) * b * self.cfg.n_experts_per_tok
+        sp.set(**said)
+        self.stats["decode_steps"] += 1
+        self.stats["tokens_out"] += handed
+        self.stats["block_passes"] += len(live)
+        self.stats["block_tokens_fixed"] += fixed_now
+
+    def fixed_at(self, request_id: int) -> List[int]:
+        """The pass of its block (0: the block's first) at which each token
+        handed out for this request so far was fixed, for a model that
+        generates by blocks; of a request in a slot, or among the last that
+        finished.  With `block_tail` all that the tokens leave open of how
+        they came about."""
+        return list(self._request(request_id).fixed_at)
+
+    def block_tail(self, request_id: int) -> List[tuple]:
+        """[(position in the block, pass, token)]: what a finished request's
+        last block held fixed past the answer's end, which the passes that
+        fixed the answer's last tokens saw and the answer does not hold."""
+        return list(self._request(request_id).block_tail)
+
+    def _request(self, request_id: int) -> Request:
+        for r in itertools.chain(self._by_slot, reversed(self._completed)):
+            if r is not None and r.request_id == request_id:
+                return r
+        raise KeyError(f"request {request_id} is in no slot and not among the last {self._completed.maxlen} finished")
 
     def pump(self) -> List[Request]:
         """Run until every submitted request finishes; returns them in
@@ -413,6 +625,40 @@ class ContinuousBatcher:
             )
             self.stats["prefill_traces"] += prefill._cache_size() - programs
         return logits, rows, pad
+
+    def block_plan(self, n: int, max_new: int):
+        """How a prompt of n tokens enters a model that generates by blocks of
+        B: (the tokens of its whole blocks, which prefill; the bucket they are
+        padded to on the left; the pad).  The other n % B are the fixed part of
+        the first answer block.  The bucket leaves room for the answer's last
+        block to its end, which may lie past n + max_new."""
+        b = self._block
+        whole = n - n % b
+        if not whole:
+            return 0, 0, 0  # nothing to prefill: the first block starts the cache
+        bucket = self._bucket(whole, -(-(n % b + max_new) // b) * b)
+        return whole, bucket, bucket - whole
+
+    def _admit_blocks(self, req: Request, slot: int, sp: tracing.span) -> int:
+        """The admit of a model that generates by blocks: the prompt's whole
+        blocks prefill (under the block mask) into the slot, its tail is the
+        fixed part of the slot's first block, and no token is handed out.
+        Returns the tokens prefilled."""
+        b, prompt = self._block, req.prompt_ids
+        whole, bucket, pad = self.block_plan(len(prompt), req.max_new_tokens)
+        tail = len(prompt) - whole
+        sp.set(bucket=bucket, prefix_hit=0, block_tail=tail)
+        if whole:
+            _, rows, _ = self._prefill_padded(prompt[:whole], bucket)
+            with tracing.span("llm.admit.install"):
+                self.cache = _install_slot(self.cache, rows, slot)
+        self._blk_tokens[:, slot] = 0
+        self._blk_tokens[:tail, slot] = prompt[whole:]
+        self._blk_fixed[:, slot] = np.arange(b) < tail
+        req.block_pass, req.block_out, req.pass_of = 0, tail, [-1] * b
+        self._pos[slot] = bucket
+        self._pads[slot] = pad
+        return whole
 
     def _admit_full_prefill(self, req: Request, sp: tracing.span):
         """Cold admit: prefill the whole prompt.  Returns (first-token logits
@@ -480,52 +726,64 @@ class ContinuousBatcher:
         with sp:
             slot = self._by_slot.index(None)
             traces = self.stats["prefill_traces"]
-            split = (
-                self._prefix_split(req.prompt_ids)
-                if self.prefix_cache is not None
-                else 0
-            )
-            if split:
-                logits, rows, pad, next_pos = self._admit_prefix_cached(req, split, sp)
+            prefilled = len(req.prompt_ids)
+            if self._block:
+                prefilled = self._admit_blocks(req, slot, sp)
             else:
-                logits, rows, pad, next_pos = self._admit_full_prefill(req, sp)
+                first = self._admit_first_token(req, slot, sp)
+                req.out_tokens.append(first)
+                if out is not None:
+                    out.setdefault(req.request_id, []).append(first)
+                self.stats["tokens_out"] += 1
             # 1 where this admit traced its bucket's prefill program
             sp.set(traced=self.stats["prefill_traces"] - traces)
-            with tracing.span("llm.admit.install"):
-                self.cache = _install_slot(self.cache, rows, slot)
-            with tracing.span("llm.admit.sample"):
-                self._rng, k = jax.random.split(self._rng)
-                first = int(
-                    np.asarray(
-                        _sample(
-                            logits, k, jnp.float32(req.temperature), req.top_k,
-                            jnp.float32(req.top_p),
-                        )
-                    )[0]
-                )
-            req.out_tokens.append(first)
-            if out is not None:
-                out.setdefault(req.request_id, []).append(first)
             req.slot = slot
             self._by_slot[slot] = req
-            self._tokens[slot] = first
-            self._pos[slot] = next_pos  # next write lands after the prompt
-            self._pads[slot] = pad
             self._temps[slot] = req.temperature
-            self._topks[slot] = req.top_k
             self._topps[slot] = req.top_p
             self.stats["admitted"] += 1
-            self.stats["tokens_out"] += 1
             if self.cfg.n_experts:
-                assignments = len(req.prompt_ids) * self.cfg.n_experts_per_tok
+                assignments = prefilled * self.cfg.n_experts_per_tok
                 sp.set(moe_assignments=assignments)
                 self.stats["moe_assignments"] += assignments
             if self._ssm_slot_bytes:
                 sp.set(ssm_state_bytes=self._ssm_slot_bytes)
                 self.stats["ssm_state_bytes"] += self._ssm_slot_bytes
-            if len(req.out_tokens) >= req.max_new_tokens or (
+            if not self._block and (len(req.out_tokens) >= req.max_new_tokens or (
                 req.eos_id is not None and first == req.eos_id
-            ):
+            )):
                 self._finish(slot, req)
         self.stats["queue_wait_s"] += queue_wait
         self.stats["admit_s"] += time.monotonic() - t0
+
+    def _admit_first_token(self, req: Request, slot: int, sp: tracing.span) -> int:
+        """The admit of a model that yields one causal token a step: the prompt
+        prefills (through the prefix cache where there is one) into the slot,
+        and the prefill's logits choose the request's first token, which the
+        slot's next step feeds.  Returns that token."""
+        split = (
+            self._prefix_split(req.prompt_ids)
+            if self.prefix_cache is not None
+            else 0
+        )
+        if split:
+            logits, rows, pad, next_pos = self._admit_prefix_cached(req, split, sp)
+        else:
+            logits, rows, pad, next_pos = self._admit_full_prefill(req, sp)
+        with tracing.span("llm.admit.install"):
+            self.cache = _install_slot(self.cache, rows, slot)
+        with tracing.span("llm.admit.sample"):
+            self._rng, k = jax.random.split(self._rng)
+            first = int(
+                np.asarray(
+                    _sample(
+                        logits, k, jnp.float32(req.temperature), req.top_k,
+                        jnp.float32(req.top_p),
+                    )
+                )[0]
+            )
+        self._tokens[slot] = first
+        self._pos[slot] = next_pos  # next write lands after the prompt
+        self._pads[slot] = pad
+        self._topks[slot] = req.top_k
+        return first

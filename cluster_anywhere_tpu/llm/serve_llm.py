@@ -226,6 +226,10 @@ class ContinuousLLMServer:
                  "LLM admits that traced and compiled a prefill program on the pump's thread"),
                 ("ssm_state_bytes", "ca_serve_ssm_state_bytes_total",
                  "bytes of recurrent state the decode steps read and wrote and the admits installed"),
+                ("block_passes", "ca_serve_block_passes_total",
+                 "passes of one slot's block by a model that generates by blocks (a step is one a live slot)"),
+                ("block_tokens_fixed", "ca_serve_block_tokens_fixed_total",
+                 "positions those passes fixed, served or past an answer's end"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

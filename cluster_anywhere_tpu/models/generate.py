@@ -178,20 +178,35 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     attends to slots [pads[b], pos[b]] of the layer, read where it lies.
     live: [B] bool, the rows that hold a request: an empty slot's row takes no
     expert (None: every row does).  Returns (x, the cache after, experts
-    touched or None: `_ffn_half`)."""
+    touched or None: `_ffn_half`).
+
+    x: [B, T, E] with T > 1 is one pass of a model that generates by blocks
+    (`cfg.block_length` = T): row b's T positions lie at slots pos[b] ..
+    pos[b] + T - 1, their k/v are written there (again at every pass of the
+    block: only the pass that finds every position fixed writes those of the
+    tokens), and each attends to slots [pads[b], pos[b] + T): every earlier
+    block and the whole of its own, in both directions."""
+    t = x.shape[1]
 
     def core(q, k, v):
         with jax.named_scope(STATE_SCOPE["attn"]):
             rows = jnp.arange(x.shape[0])
-            k_all = cache["k"].at[layer, rows, pos].set(k[:, 0])
-            v_all = cache["v"].at[layer, rows, pos].set(v[:, 0])
+            if t == 1:
+                at, new = (layer, rows, pos), lambda a: a[:, 0]
+            else:
+                at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
+            k_all = cache["k"].at[at].set(new(k))
+            v_all = cache["v"].at[at].set(new(v))
             k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
         with jax.named_scope("attn.core"):
-            attn = _masked_attention(q, k_layer, v_layer, pos + 1, cfg, pads)  # per-row length
+            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads)  # per-row length
         return attn, {**cache, "k": k_all, "v": v_all}
 
-    x, cache = _attention_half(bp, x, cfg, (pos - pads)[:, None], core)
-    x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
+    positions = (pos - pads)[:, None]
+    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core)
+    if live is not None:
+        live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
+    x, _, touched = _ffn_half(bp, x, cfg, live, experts)
     return x, cache, touched
 
 
@@ -214,7 +229,10 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
         # prefill never materializes the [T, T] score matrix.
         with jax.named_scope("attn.core"):
             k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
-            return attention(q, k, v, causal=True, pad=pad).astype(x.dtype), (k_cache, v_cache)
+            # a model that generates by blocks prefills under its block mask:
+            # the prompt, and with it the pad, is then a multiple of the block
+            attn = attention(q, k, v, causal=True, pad=pad, block=cfg.block_length)
+            return attn.astype(x.dtype), (k_cache, v_cache)
 
     positions = jnp.arange(t)
     if pad is not None:
@@ -267,7 +285,10 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     pad: optional [B] left-pad counts (see _prefill_block).  Compiled: one
     program for each shape of `ids` (with or without `pad`), `cfg` and
     `t_max`, traced at its first call and called thereafter; under another
-    jit (`generate`, `_stream_fns`) it is a nested call of that program."""
+    jit (`generate`, `_stream_fns`) it is a nested call of that program.
+    A model that generates by blocks is given whole blocks of the prompt (pad
+    and T_prompt multiples of `cfg.block_length`) and no logits come back: its
+    last position's logits are that position's own token, which is known."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
@@ -281,7 +302,7 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
 
     x, _, rows = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg)
     cache = {name: r for kind, kept in rows.items() for name, r in zip(LAYER_STATE[kind], kept)}
-    return _head(params, x, cfg, row=-1), cache
+    return None if cfg.generates_blocks else _head(params, x, cfg, row=-1), cache
 
 
 def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
@@ -289,9 +310,18 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     at its own depth.  tokens, pos, pads: [B] (`_block_decode_rowpos` says what
     each row does with its own); live: [B] bool or None.  Returns (logits
     [B, V], updated cache, experts touched: the mean over the layers of the
-    experts that were given a row, None for a dense model)."""
+    experts that were given a row, None for a dense model).
+
+    tokens [B, T]: one pass of each row's own block of T positions, the first
+    of them at pos[b] (a model that generates by blocks).  Returns the logits
+    of every position [B, T, V], each its own position's token."""
+    blocks = tokens.ndim == 2
+    if blocks and (not cfg.generates_blocks or set(cfg.layer_kinds) != {"attn"}):
+        raise NotImplementedError("a pass over blocks of positions: attention layers, cfg.block_length > 1")
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]  # [B,1,E]
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if not blocks:
+            x = x[:, None, :]  # [B,1,E]
 
     def attn(x, bp, experts, cache, layer):
         return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts)
@@ -302,7 +332,8 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     x, cache, touched = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg, cache)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32)) if touched else None
-    return _head(params, x, cfg, row=0), cache, touched
+    logits = _head(params, x, cfg).astype(jnp.float32) if blocks else _head(params, x, cfg, row=0)
+    return logits, cache, touched
 
 
 def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
@@ -311,6 +342,16 @@ def decode_one(params, cache, token, pos, cfg: TransformerConfig, pad=None):
     pos = jnp.broadcast_to(pos, token.shape)
     pads = jnp.zeros_like(pos) if pad is None else pad
     return decode_rows(params, cache, token, pos, pads, cfg)[:2]
+
+
+def _one_token_a_step(cfg: TransformerConfig, what: str) -> None:
+    """`generate` and `stream_generate` yield one causal token a step; a model
+    that generates by blocks is served by llm/continuous.py's batcher alone."""
+    if cfg.generates_blocks:
+        raise NotImplementedError(
+            f"{what}: block_length={cfg.block_length} generates by passes over blocks, "
+            "which ContinuousBatcher runs (llm/continuous.py); this path yields one token a step"
+        )
 
 
 def _nucleus_mask(scaled, top_p):
@@ -367,6 +408,7 @@ def generate(
     prompt_lens: optional [B] int32 count of real (rightmost) tokens per row
     when prompts are left-padded to a fixed T_prompt; pads are masked out of
     attention and RoPE positions count real tokens only."""
+    _one_token_a_step(cfg, "generate")
     b, t_prompt = prompt_ids.shape
     t_max = t_prompt + max_new_tokens
     pad = None if prompt_lens is None else (t_prompt - prompt_lens).astype(jnp.int32)
@@ -393,6 +435,7 @@ def _stream_fns(cfg: TransformerConfig, t_prompt: int, t_max: int, top_k: int):
     """Jitted prefill+sample and single-decode-step closures for streaming
     decoding (compiled once per shape/config/top_k; temperature and top_p
     are TRACED operands, so per-request values never recompile)."""
+    _one_token_a_step(cfg, "stream_generate")
 
     def _prefill(params, ids, pad, rng, temperature, top_p):
         logits, cache = prefill(params, ids, cfg, t_max, pad)

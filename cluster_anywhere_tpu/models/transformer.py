@@ -83,6 +83,9 @@ class TransformerConfig:
     # RMSNorm with a learned weight over the whole projected q and k vectors
     # (before the split into heads and the rotary embedding): q_norm, k_norm
     qk_norm: bool = False
+    # with qk_norm: the RMSNorm runs over each head's d_head instead, after the
+    # split into heads, one weight [d_head] shared by the heads
+    qk_norm_per_head: bool = False
     rotary: bool = True  # False: attention takes no positional embedding at all
     tie_embeddings: bool = False  # the head is the embedding transposed; no `lm_head`
     # a layer pattern: layer i mixes tokens by attention where
@@ -101,6 +104,29 @@ class TransformerConfig:
     ssm_expand: int = 2
     ssm_dt_rank: int = 16
     ssm_conv_bias: bool = True
+    # how the model generates.  block_length 0 or 1: one causal token a step
+    # from the logits at a row's last position.  block_length B > 1: an answer
+    # is made B positions at a time, aligned to absolute positions; attention
+    # sees every earlier block and the whole of a position's own block, in both
+    # directions; the logits at a position give the token at that position; a
+    # block starts as mask_token_id and each pass fixes the masked positions
+    # whose confidence passes confidence_threshold, or the B / denoise_steps
+    # most confident where fewer do (llm/continuous.py `_choose_block`)
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoise_steps: int = 1
+    confidence_threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.generates_blocks and self.block_length % self.denoise_steps:
+            raise ValueError(
+                f"block_length {self.block_length} is not a multiple of denoise_steps "
+                f"{self.denoise_steps}: every pass fixes the same number of positions"
+            )
+
+    @property
+    def generates_blocks(self) -> bool:
+        return self.block_length > 1
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -164,7 +190,9 @@ def _init_block(key, cfg: TransformerConfig):
         "wo": jax.random.normal(ks[3], (h * d, e), pd) * s(h * d),
     }
     if cfg.qk_norm:
-        out.update({"q_norm": jnp.ones((h * d,), pd), "k_norm": jnp.ones((kv * d,), pd)})
+        per_head = cfg.qk_norm_per_head
+        out.update({"q_norm": jnp.ones((d if per_head else h * d,), pd),
+                    "k_norm": jnp.ones((d if per_head else kv * d,), pd)})
     out.update(_init_ffn(ks[4:], cfg))
     return out
 
@@ -336,16 +364,20 @@ def _rope(q, k, positions, cfg: TransformerConfig):
 def _project_qkv(bp, y, cfg: TransformerConfig):
     """q [B, T, H, D], k and v [B, T, KV, D] of one block from its normed
     input y [B, T, E]: the three projections and, with `cfg.qk_norm`, the
-    RMSNorm of q and k over the whole projected vector.  The one place every
-    forward, prefill and decode block projects."""
+    RMSNorm of q and k: over the whole projected vector, or over each head's
+    own d_head (`cfg.qk_norm_per_head`).  The one place every forward, prefill
+    and decode block projects."""
     b, t, _ = y.shape
     d, dt = cfg.d_head, y.dtype
 
     def project(w, heads, norm=None):
         out = y @ bp[w].astype(dt)
-        if norm is not None and cfg.qk_norm:
+        if norm is not None and cfg.qk_norm and not cfg.qk_norm_per_head:
             out = _rms_norm(out, bp[norm])
-        return out.reshape(b, t, heads, d)
+        out = out.reshape(b, t, heads, d)
+        if norm is not None and cfg.qk_norm and cfg.qk_norm_per_head:
+            out = _rms_norm(out, bp[norm])
+        return out
 
     return (project("wq", cfg.n_heads, "q_norm"), project("wk", cfg.n_kv_heads, "k_norm"),
             project("wv", cfg.n_kv_heads))
@@ -395,12 +427,13 @@ def _per_shard(attn_fn, mesh, manual_axes=frozenset()):
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes):
     impl = cfg.resolved_attn()
-    if impl == "ring" and "sp" in manual_axes:
-        fn = functools.partial(ring_attention, axis_name="sp", causal=True)
-    elif impl == "ulysses" and "sp" in manual_axes:
-        fn = functools.partial(ulysses_attention, axis_name="sp", causal=True)
+    over_sp = impl in ("ring", "ulysses") and "sp" in manual_axes
+    if over_sp and cfg.generates_blocks:
+        raise NotImplementedError(f"the block mask (block_length={cfg.block_length}) with {impl} attention")
+    if over_sp:
+        fn = functools.partial(ring_attention if impl == "ring" else ulysses_attention, axis_name="sp", causal=True)
     else:  # dense: the dispatcher picks by backend
-        fn = functools.partial(dense_attention, causal=True)
+        fn = functools.partial(dense_attention, causal=True, block=cfg.block_length)
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 

@@ -48,7 +48,7 @@ def _platform() -> str:
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mask_block=0):
     if has_pad:
         pad_ref, o_ref, lse_ref = refs
         pad_val = pad_ref[pl.program_id(0)]
@@ -93,6 +93,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad):
                 q_pos = qi * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0
                 )
+                if mask_block > 1:
+                    # the block mask: a query sees its own block to the end
+                    q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
                 ok = q_pos >= k_pos
             if has_pad:
                 # left-padded rows: keys before pad_val are pad tokens
@@ -285,7 +288,7 @@ def _pad_bh(pad, h):
 _PAD_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret):
+def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0):
     b, t, h, d = q.shape
     t_kv = k.shape[1]
     qf, kf, vf = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
@@ -304,7 +307,8 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret):
         args.append(_pad_bh(pad, h))
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, block_k=block_k, has_pad=has_pad
+            _fwd_kernel, scale=scale, causal=causal, block_k=block_k, has_pad=has_pad,
+            mask_block=mask_block,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -447,8 +451,15 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
     return_lse: bool = False,
+    block: int = 0,
 ):
     """Pallas flash attention.  q: [B, T, H, D]; k, v: [B, T_kv, H, D].
+
+    block: B > 1 widens the causal mask to the block mask of a model that
+    generates by blocks: query i sees key j where j // B <= i // B, every
+    earlier block and the whole of its own.  Indices, not positions: a left pad
+    has to be a multiple of B, so that block edges fall on index edges.
+    Forward only (no custom_vjp: a gradient through it is refused by JAX).
 
     pad: optional [B] int32 per-row LEFT-pad counts — keys at positions
     < pad[b] are masked out (the left-padded-prompt mask the LLM prefill
@@ -473,6 +484,15 @@ def flash_attention(
         block_k = _auto_block(k.shape[1], 512)
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
+    if block > 1:
+        # forward only, and the kernel's skip of the key blocks above the
+        # diagonal takes a query block to end on a mask block's edge
+        if not causal or return_lse or block_q % block:
+            raise ValueError(
+                f"the block mask (block={block}) is the causal mask widened to a block's end: "
+                f"causal=True, no lse, block_q ({block_q}) a multiple of block"
+            )
+        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, block)[0]
     if return_lse:
         return _flash_with_lse(q, k, v, pad, causal, scale, block_q, block_k, interpret)
     return _flash(q, k, v, pad, causal, scale, block_q, block_k, interpret)
@@ -508,9 +528,10 @@ def merge_attention(o1, lse1, o2, lse2):
     return o, jnp.where(tot == 0.0, NEG_INF, lse)
 
 
-def reference_attention(q, k, v, causal=True, scale=None, pad=None):
+def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0):
     """Dense jnp attention (fallback + test oracle): [B,T,H,D] -> [B,T,H,D].
-    pad: optional [B] left-pad counts (keys < pad[b] masked)."""
+    pad: optional [B] left-pad counts (keys < pad[b] masked).  block: B > 1
+    widens the causal mask to the block mask (`flash_attention`)."""
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
@@ -520,7 +541,9 @@ def reference_attention(q, k, v, causal=True, scale=None, pad=None):
     )
     t_q, t_k = s.shape[-2], s.shape[-1]
     mask = None
-    if causal:
+    if causal and block > 1:
+        mask = (jnp.arange(t_q)[:, None] // block >= jnp.arange(t_k)[None, :] // block)[None, None]
+    elif causal:
         mask = jnp.tril(jnp.ones((t_q, t_k), dtype=bool))[None, None]
     if pad is not None:
         key_ok = (jnp.arange(t_k)[None, :] >= pad[:, None])[:, None, None, :]
@@ -545,23 +568,27 @@ def _left_pad_to_tile(q, k, v, pad):
     return widen(q), widen(k), widen(v), pad, extra
 
 
-def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None):
+def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None, block: int = 0):
     """Dispatcher: the Pallas flash kernel on a TPU, the jnp reference on any
     other backend.  A sequence that is not a multiple of the kernel's tile is
     left-padded up to one and the new columns masked as pad tokens (their
-    query rows are dropped), so the algorithm never changes with the shape."""
+    query rows are dropped), so the algorithm never changes with the shape.
+    block: the block mask (`flash_attention`); the columns added on the left
+    then have to be whole blocks, which they are for a sequence of whole blocks."""
     if _platform() != "tpu":
-        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad)
+        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)
     t, t_kv = q.shape[1], k.shape[1]
     if t % _TILE == 0 and t_kv % _TILE == 0:
-        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad)
+        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)
     if t != t_kv:
         raise ValueError(
             f"flash kernel needs T and T_kv to be multiples of {_TILE} when "
             f"they differ, got T={t}, T_kv={t_kv}"
         )
     q, k, v, pad, extra = _left_pad_to_tile(q, k, v, pad)
-    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad)[:, extra:]
+    if block > 1 and extra % block:
+        raise ValueError(f"a sequence of {t} under the block mask of {block}: {extra} columns on the left shift its blocks")
+    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)[:, extra:]
 
 
 def flash_numerics_errors() -> dict:

@@ -190,10 +190,13 @@ def _compiled_decode_step(cfg, device, slots=32, t_max=768):
     params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
     cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    # a mixture of experts is told the live slots in a fifth row
-    ints = on_chip(jax.ShapeDtypeStruct((5 if cfg.n_experts else 4, slots), jnp.int32))
+    # a mixture of experts is told the live slots in a fifth row; a model that generates
+    # by blocks of B takes a block's B tokens and B fixed flags a slot, and its own step
+    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 5 if cfg.n_experts else 4
+    step = continuous._pass_step_rowpos if cfg.generates_blocks else continuous._decode_step_rowpos
+    ints = on_chip(jax.ShapeDtypeStruct((rows, slots), jnp.int32))
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
-    fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
+    fn = lambda *a: step.__wrapped__(*a, cfg=cfg)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
     return compiled, params, cache
 
@@ -291,6 +294,60 @@ def test_decode_step_writes_the_cache_in_place(v5e, model):
         if n == a_layers_keys:
             assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
     assert cache["k"].size in seen  # the keys' write was read for what it is
+
+
+# SDAR-30B-A3B's widths (128 experts of 2048 x 768, 8 a token, 32 Q / 4 KV heads
+# x 128 normalised a head, the whole vocabulary of 151,936), three layers deep:
+# an answer is made a block of 4 positions at a time
+SDAR3 = dict(
+    vocab_size=151936, n_layers=3, d_model=2048, n_heads=32, n_kv_heads=4, d_head=128, d_ff=768,
+    n_experts=128, n_experts_per_tok=8, moe_gated=True, moe_renormalize=True, qk_norm=True,
+    qk_norm_per_head=True, rope_theta=1e6, param_dtype=jnp.bfloat16,
+    block_length=4, mask_token_id=151669, denoise_steps=4,
+)
+
+
+def test_block_step_writes_its_rows_in_place_and_sorts_no_vocabulary(v5e):
+    """The step of a model that generates by blocks (one pass of every slot's
+    block of 4, 32 slots, t_max 768, the cache donated) as the chip's compiler
+    leaves it: the cache is the layer loop's carry as in the causal step, the 4
+    rows a slot and layer are written into the whole stacks in place, nothing
+    is a copy of a stack or writes a whole layer's keys, and the choice of what
+    a pass fixes (a maximum and a log-sum-exp a position) sorts nothing of the
+    vocabulary's size: the sorts are the expert path's, the router's k largest of
+    128 probabilities a row and the 32 x 4 x 8 assignments by expert."""
+    cfg = transformer.TransformerConfig(**SDAR3)
+    slots, b = 32, cfg.block_length
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], slots=slots)
+    assert _has_kernel(compiled)
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in cache.values())
+    logits_bytes = slots * b * cfg.vocab_size * 4
+    # the logits of every position in float32, and the noise of a sampled pass beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10 + 3 * logits_bytes
+    a_layers_keys = cache["k"].size // cache["k"].shape[0]
+    seen = set()
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if n == cache["k"].size and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
+            assert in_place or op == "copy-done", line[:200]
+            seen.add(n)
+        if n == a_layers_keys:
+            assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
+    assert cache["k"].size in seen
+    sorts = [int(math.prod(int(d) for d in m.split(",")))
+             for m in re.findall(r"= \(?\w+\[([\d,]+)\][^=]*? sort\(", compiled.as_text())]
+    assert sorts and max(sorts) <= slots * b * cfg.n_experts < cfg.vocab_size, sorts
+
+
+@pytest.mark.parametrize("bucket", [64, 512])
+def test_block_masked_prefill_compiles(v5e, on_tpu, bucket):
+    """The admit's prefill of a model that generates by blocks, at the shortest
+    and the longest bucket of the serving cell: the flash kernel under the block
+    mask (at 64 through the dispatcher's left pad to a tile) and the experts
+    read where they are."""
+    compiled = _compiled_admit_prefill(transformer.TransformerConfig(**SDAR3), bucket, 768, v5e[0])
+    assert _has_kernel(compiled)
 
 
 @pytest.mark.parametrize(
