@@ -61,7 +61,7 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, _sample, decode_rows, init_cache, install_rows, prefill, recurrent_state_bytes,
+    _nucleus_mask, decode_rows, init_cache, install_rows, prefill, recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
 from ..util import tracing
@@ -107,23 +107,51 @@ def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
     """Per-row sampling with TRACED temperature, top-k, and top-p (requests
     in one decode batch carry their own knobs; a static top_k would force
     one value per compiled program).  top_k <= 0 means no truncation;
-    top_p outside (0, 1) means no nucleus mask; temp <= 0 means greedy."""
+    top_p outside (0, 1) means no nucleus mask; temp <= 0 means greedy.
+
+    It does the work its rows ask for and no more, chosen on the device from
+    the three vectors: the largest logit alone where no row samples; one draw a
+    row from `logits / temp` where some row samples and no sampling row
+    truncates; the two sorts of the vocabulary (top-k's k-th largest, the
+    nucleus's cumulative mass) only where a sampling row asks top-k or top-p.
+    A row's token is the same in every branch that may serve it: the masks are
+    no-ops for a row that does not truncate, and rows are independent."""
     with jax.named_scope("sample"):
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        t = jnp.maximum(temps, 1e-6)[:, None]
-        scaled = logits / t
-        v = logits.shape[-1]
-        # traced top-k: k-th largest per row via a descending sort
-        sorted_desc = -jnp.sort(-scaled, axis=-1)
-        kth_idx = jnp.clip(top_ks - 1, 0, v - 1)[:, None]
-        kth = jnp.take_along_axis(sorted_desc, kth_idx, axis=-1)
-        scaled = jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
-        # per-row nucleus mask: [S,1] top_p broadcasts through the shared helper
-        scaled = _nucleus_mask(scaled, top_ps[:, None])
-        sampled = jax.vmap(lambda rng, row: jax.random.categorical(rng, row))(
-            rngs, scaled
-        ).astype(jnp.int32)
-        return jnp.where(temps <= 0.0, greedy, sampled)
+        samples = temps > 0.0
+        truncates = samples & ((top_ks > 0) | ((top_ps > 0.0) & (top_ps < 1.0)))
+
+        def draw(scaled):
+            sampled = jax.vmap(lambda rng, row: jax.random.categorical(rng, row))(rngs, scaled)
+            return jnp.where(samples, sampled.astype(jnp.int32), greedy)
+
+        def scale():
+            return logits / jnp.maximum(temps, 1e-6)[:, None]
+
+        def truncated():
+            scaled = scale()
+            v = logits.shape[-1]
+            # traced top-k: k-th largest per row via a descending sort
+            sorted_desc = -jnp.sort(-scaled, axis=-1)
+            kth_idx = jnp.clip(top_ks - 1, 0, v - 1)[:, None]
+            kth = jnp.take_along_axis(sorted_desc, kth_idx, axis=-1)
+            scaled = jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
+            # per-row nucleus mask: [S,1] top_p broadcasts through the shared helper
+            return draw(_nucleus_mask(scaled, top_ps[:, None]))
+
+        asked = jnp.any(samples).astype(jnp.int32) + jnp.any(truncates)
+        return lax.switch(asked, (lambda: greedy, lambda: draw(scale()), truncated))
+
+
+@jax.jit
+def _sample_first(logits, rng, temp, top_k, top_p):
+    """An admit's first token from its prefill's logits [1, V], by the step's
+    own sampler in one program (op by op the nucleus mask alone was twenty
+    dispatches on the pump's thread).  rng: the batcher's key, split once an
+    admit into the key it carries on and the draw's; temp, top_k, top_p: [1],
+    the request's.  Returns (the token, the carried key)."""
+    rng, key = jax.random.split(rng)
+    return _sample_rowwise(logits, key[None], temp, top_k, top_p)[0], rng
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -354,9 +382,9 @@ class ContinuousBatcher:
         # the decode step's per-slot inputs as its program takes them: two
         # host arrays that go to the jitted call as they are (one dispatch, no
         # eager upload), the scheduler's vectors their rows.  The host writes
-        # them only in an admit and in llm.step.scatter, after the step's
-        # tokens are read back: the program has consumed them by then (on the
-        # CPU backend a host array may be aliased, not copied).
+        # them only between steps (an admit, a cancel) and in llm.step.scatter,
+        # after the step's tokens are read back: the program has consumed them
+        # by then (on the CPU backend a host array may be aliased, not copied).
         self._floats = np.zeros((2, slots), np.float32)
         if self._block:
             # _pos: the cache slot of the block's first position; _blk_tokens,
@@ -364,12 +392,18 @@ class ContinuousBatcher:
             self._ints = np.zeros((3 + 2 * self._block, slots), np.int32)
             self._pos, self._pads, self._live = self._ints[:3]
             self._blk_tokens, self._blk_fixed = self._ints[3:3 + self._block], self._ints[3 + self._block:]
+            self._topks = np.zeros(slots, np.int32)  # top-k is refused: the row stays 0 and is not uploaded
         else:
             self._ints = np.zeros((5 if cfg.n_experts else 4, slots), np.int32)
             # _pos: cache slot of the NEXT write; a mixture's fifth row: live slots
             self._tokens, self._pos, self._pads, self._topks = self._ints[:4]
         self._temps, self._topps = self._floats
         self._topps[:] = 1.0
+        # of the live slots, those that sample (temperature > 0) and those of them
+        # that truncate (top-k or top-p), as the step's sampler reads the three
+        # vectors: kept at admit and at release.  A free slot asks nothing (0, 0,
+        # 1.0), so what the sampler sees in all its rows is what the live ones ask
+        self._sample_rows = self._truncate_rows = 0
         self._by_slot: List[Optional[Request]] = [None] * slots
         self.queue: deque[Request] = deque()
         # bounded: pump() drains it; step()-driven servers track their own
@@ -396,6 +430,9 @@ class ContinuousBatcher:
             # step is one for every live slot) and the positions they fixed;
             # stay 0 for one causal token a step
             "block_passes": 0, "block_tokens_fixed": 0,
+            # decode steps that sorted the vocabulary: a live row sampled with
+            # top-k or top-p; stays 0 under greedy or temperature-only traffic
+            "sort_steps": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -440,7 +477,7 @@ class ContinuousBatcher:
         for s, r in enumerate(self._by_slot):
             if r is not None and r.request_id == request_id:
                 r.done = True
-                self._by_slot[s] = None  # lane decodes garbage until an admit overwrites its rows
+                self._release(s)  # lane decodes garbage until an admit overwrites its rows
                 self.stats["cancelled"] += 1
                 return True
         return False
@@ -465,6 +502,8 @@ class ContinuousBatcher:
             if self._block:
                 self._step_blocks(live, out, sp)
                 return out
+            sp.set(sample_rows=self._sample_rows, truncate_rows=self._truncate_rows)
+            self.stats["sort_steps"] += self._truncate_rows > 0
             with tracing.span("llm.step.upload"):
                 if self.cfg.n_experts:
                     self._ints[4] = [r is not None for r in self._by_slot]
@@ -586,9 +625,25 @@ class ContinuousBatcher:
     # ------------------------------------------------------------- internals
     def _finish(self, slot: int, req: Request) -> None:
         req.done = True
-        self._by_slot[slot] = None  # slot frees for the next admit
+        self._release(slot)
         self._completed.append(req)
         self.stats["finished"] += 1
+
+    def _asks(self, slot: int):
+        """(samples, truncates): what the slot's knobs ask of the step's
+        sampler, by `_sample_rowwise`'s own predicate on the rows as uploaded."""
+        samples = bool(self._temps[slot] > 0.0)
+        return samples, samples and bool(self._topks[slot] > 0 or 0.0 < self._topps[slot] < 1.0)
+
+    def _release(self, slot: int) -> None:
+        """The slot frees for the next admit and asks nothing of the sampler
+        until then: a dense model's step is not told which rows are live, and
+        one finished top-p request would leave every later step sorting."""
+        samples, truncates = self._asks(slot)
+        self._sample_rows -= samples
+        self._truncate_rows -= truncates
+        self._temps[slot], self._topks[slot], self._topps[slot] = 0.0, 0, 1.0
+        self._by_slot[slot] = None
 
     def _bucket(self, n: int, max_new: int) -> int:
         """Smallest bucket holding the prompt AND leaving room to decode;
@@ -741,6 +796,9 @@ class ContinuousBatcher:
             self._by_slot[slot] = req
             self._temps[slot] = req.temperature
             self._topps[slot] = req.top_p
+            samples, truncates = self._asks(slot)
+            self._sample_rows += samples
+            self._truncate_rows += truncates
             self.stats["admitted"] += 1
             if self.cfg.n_experts:
                 assignments = prefilled * self.cfg.n_experts_per_tok
@@ -773,15 +831,11 @@ class ContinuousBatcher:
         with tracing.span("llm.admit.install"):
             self.cache = _install_slot(self.cache, rows, slot)
         with tracing.span("llm.admit.sample"):
-            self._rng, k = jax.random.split(self._rng)
-            first = int(
-                np.asarray(
-                    _sample(
-                        logits, k, jnp.float32(req.temperature), req.top_k,
-                        jnp.float32(req.top_p),
-                    )
-                )[0]
+            first, self._rng = _sample_first(
+                logits, self._rng, np.float32([req.temperature]), np.int32([req.top_k]),
+                np.float32([req.top_p]),
             )
+            first = int(first)
         self._tokens[slot] = first
         self._pos[slot] = next_pos  # next write lands after the prompt
         self._pads[slot] = pad

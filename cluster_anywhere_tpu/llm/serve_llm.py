@@ -230,6 +230,8 @@ class ContinuousLLMServer:
                  "passes of one slot's block by a model that generates by blocks (a step is one a live slot)"),
                 ("block_tokens_fixed", "ca_serve_block_tokens_fixed_total",
                  "positions those passes fixed, served or past an answer's end"),
+                ("sort_steps", "ca_serve_sort_steps_total",
+                 "decode steps that sorted the vocabulary: a live request sampled with top-k or top-p"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

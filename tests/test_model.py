@@ -204,3 +204,74 @@ def test_rowwise_nucleus_sampling_per_request():
         assert out[0] == 0
         seen_row1.add(int(out[1]))
     assert len(seen_row1) > 1  # row 1 still samples the tail
+
+
+def _two_sort_sample_rowwise(logits, rngs, temps, top_ks, top_ps):
+    """`_sample_rowwise` as it stood before its work followed what the rows ask
+    (PR 34), kept here letter for letter with its nucleus mask: both sorts of
+    the vocabulary and a draw for every row, thrown away where `temps <= 0`."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    t = jnp.maximum(temps, 1e-6)[:, None]
+    scaled = logits / t
+    v = logits.shape[-1]
+    sorted_desc = -jnp.sort(-scaled, axis=-1)
+    kth_idx = jnp.clip(top_ks - 1, 0, v - 1)[:, None]
+    kth = jnp.take_along_axis(sorted_desc, kth_idx, axis=-1)
+    scaled = jnp.where((top_ks[:, None] > 0) & (scaled < kth), -1e30, scaled)
+    top_p = top_ps[:, None]
+    probs = jax.nn.softmax(scaled, axis=-1)
+    sorted_p = -jnp.sort(-probs, axis=-1)
+    cum_excl = jnp.cumsum(sorted_p, axis=-1) - sorted_p
+    thresh = jnp.min(jnp.where(cum_excl < top_p, sorted_p, jnp.inf), axis=-1, keepdims=True)
+    scaled = jnp.where((top_p > 0.0) & (top_p < 1.0) & (probs < thresh), -1e30, scaled)
+    sampled = jax.vmap(lambda rng, row: jax.random.categorical(rng, row))(rngs, scaled).astype(jnp.int32)
+    return jnp.where(temps <= 0.0, greedy, sampled)
+
+
+# (temps, top_ks, top_ps) of six rows, and the branch of the sampler they ask for:
+# 0 the largest logit alone, 1 a draw without a sort, 2 the two sorts
+_ROW_KNOBS = {
+    "all_greedy": ([0.0] * 6, [0] * 6, [1.0] * 6, 0),
+    "greedy_with_stale_truncation": ([0.0] * 6, [5, 0, 3, 0, 0, 40], [0.9, 1.0, 0.5, 0.2, 1.0, 1.0], 0),
+    "temperature_alone": ([0.7, 1.0, 1.3, 0.2, 2.0, 1e-3], [0] * 6, [1.0, 1.0, 0.0, 1.5, -1.0, 1.0], 1),
+    "top_k": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0], [5, 1, 40, 200, 3, 64], [1.0] * 6, 2),
+    "top_p": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0], [0] * 6, [0.9, 0.5, 0.1, 0.99, 0.7, 0.3], 2),
+    "top_k_and_top_p": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0], [5, 0, 40, 0, 3, 64], [0.9, 0.5, 1.0, 1.0, 0.7, 0.3], 2),
+    "greedy_and_sampled_mixed": ([0.0, 1.0, 0.0, 0.8, 0.0, 1.5], [0, 7, 0, 0, 0, 0], [1.0, 1.0, 1.0, 0.6, 1.0, 1.0], 2),
+    "sampled_beside_a_stale_top_k": ([0.9, 0.0, 1.4, 0.0, 0.0, 0.0], [0, 12, 0, 0, 3, 0], [1.0, 1.0, 1.0, 0.4, 1.0, 1.0], 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_KNOBS))
+def test_rowwise_sampler_draws_the_two_sort_formulas_tokens(case):
+    """The sampler does what its rows ask and no more (the largest logit
+    alone; a draw without a sort; the two sorts only for a sampling row that
+    truncates), and whichever it does, every row's token is the two-sort
+    formula's for the same key and knobs, bit for bit, compiled as the decode
+    step compiles it: a greedy row is an argmax in each, the masks are no-ops
+    on a row that does not truncate, and rows are independent.  A stale top-k
+    or top-p on a row with temperature 0 asks nothing."""
+    from cluster_anywhere_tpu.llm.continuous import _sample_rowwise
+
+    temps, top_ks, top_ps, branch = _ROW_KNOBS[case]
+    knobs = (jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32), jnp.asarray(top_ps, jnp.float32))
+    gated, frozen = jax.jit(_sample_rowwise), jax.jit(_two_sort_sample_rowwise)
+    drawn = []
+    for seed in range(8):
+        k_logits, k_rows = jax.random.split(jax.random.key(seed))
+        logits = 3.0 * jax.random.normal(k_logits, (6, 257), jnp.float32)
+        logits = logits.at[:, 100].set(logits[:, 7])  # a tie, at the top of row 2
+        logits = logits.at[2, 7].add(20.0).at[2, 100].add(20.0)
+        rngs = jax.random.split(k_rows, 6)
+        want = np.asarray(frozen(logits, rngs, *knobs))
+        np.testing.assert_array_equal(np.asarray(gated(logits, rngs, *knobs)), want)
+        drawn.append(want)
+    # which branch runs is the switch's index in the traced program, and the sorts are the third's
+    traced = jax.make_jaxpr(_sample_rowwise)(logits, rngs, *knobs)
+    (switch,) = [e for e in traced.jaxpr.eqns if e.primitive.name == "cond"]
+    assert ["sort" in str(b) for b in switch.params["branches"]] == [False, False, True]
+    index = jax.core.eval_jaxpr(traced.jaxpr.replace(outvars=[switch.invars[0]]), traced.consts, logits, rngs, *knobs)
+    assert int(index[0]) == branch
+    greedy_rows = np.asarray(temps) <= 0
+    if branch:  # the case does sample: its rows' draws move with the key
+        assert len({tuple(d[~greedy_rows]) for d in drawn}) > 1
