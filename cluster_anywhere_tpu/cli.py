@@ -157,8 +157,43 @@ def cmd_down(args):
     cmd_stop(args)
 
 
+def _print_serve_trace(limit: int) -> None:
+    """`ca serve trace`: the traced requests of the head's ring, each with
+    its phases and their self times, then p50 / p99 by phase."""
+    from cluster_anywhere_tpu.util.state import serve_requests
+
+    out = serve_requests(limit=limit)
+    for r in out["requests"]:
+        print(
+            f"{r['name']}  trace={r['trace']}  {r['dur_ms']:.2f} ms"
+            f"  status={r.get('status')} tokens={r.get('tokens')}"
+            f" ttfb_ms={r.get('ttfb_ms', 0.0):.2f}"
+        )
+        for ph in r["phases"][1:]:
+            attrs = " ".join(
+                f"{k}={v:.3g}" if isinstance(v, float) else f"{k}={v}"
+                for k, v in ph.items()
+                if k not in ("name", "depth", "offset_ms", "dur_ms", "self_ms")
+            )
+            print(
+                f"  {'  ' * (ph['depth'] - 1)}{ph['name']:<{44 - 2 * ph['depth']}}"
+                f" +{ph['offset_ms']:9.2f}  {ph['dur_ms']:9.2f} ms  self {ph['self_ms']:9.2f}  {attrs}"
+            )
+    if out["phases"]:
+        print(f"== {len(out['requests'])} requests: ms by phase ==")
+        print(f"  {'phase':<40} {'n':>5} {'p50':>9} {'p99':>9} {'self p50':>9} {'self p99':>9}")
+        for name, q in out["phases"].items():
+            print(
+                f"  {name:<40} {q['count']:>5} {q['p50_ms']:>9.2f} {q['p99_ms']:>9.2f}"
+                f" {q['self_p50_ms']:>9.2f} {q['self_p99_ms']:>9.2f}"
+            )
+    else:
+        print("no traced serve request in the ring (send a traceparent header, or enable tracing)")
+
+
 def cmd_serve(args):
-    """`ca serve deploy <yaml>` / `ca serve status` (reference serve CLI)."""
+    """`ca serve deploy <yaml>` / `ca serve status` (reference serve CLI);
+    `ca serve trace` reads the traced requests' phases from the head's ring."""
     import cluster_anywhere_tpu as ca
     from cluster_anywhere_tpu import serve
 
@@ -175,6 +210,8 @@ def cmd_serve(args):
     elif args.action == "shutdown":
         serve.shutdown()
         print("serve shut down")
+    elif args.action == "trace":
+        _print_serve_trace(getattr(args, "limit", 20))
     from cluster_anywhere_tpu.core import api as _api
     from cluster_anywhere_tpu.core.worker import global_worker
 
@@ -371,6 +408,8 @@ def cmd_status(args):
                     )
             for k, v in sorted(sp["counters"].items()):
                 print(f"  {k}: {v}")
+            for k, v in sorted(sp.get("gauges", {}).items()):
+                print(f"  {k}: {v:g}")
             for k, v in sorted(sp["quantiles"].items()):
                 print(f"  {k}: {v:.4g}" if isinstance(v, float) else f"  {k}: {v}")
     except Exception:
@@ -1159,9 +1198,11 @@ def main(argv=None):
     addr(sp)
     sp.set_defaults(fn=cmd_down)
 
-    sp = sub.add_parser("serve", help="serve deploy <yaml> / status / shutdown")
-    sp.add_argument("action", choices=["deploy", "status", "shutdown"])
+    sp = sub.add_parser("serve", help="serve deploy <yaml> / status / shutdown / trace")
+    sp.add_argument("action", choices=["deploy", "status", "shutdown", "trace"])
     sp.add_argument("config", nargs="?", help="YAML for deploy")
+    sp.add_argument("--limit", type=int, default=20,
+                    help="trace: the newest N traced requests of the ring")
     addr(sp)
     sp.set_defaults(fn=cmd_serve)
 

@@ -465,11 +465,15 @@ class WorkerProcess:
     def _record_running(self, task_id: bytes, name: Optional[str], kind: str, tr: dict):
         """Lifecycle RUNNING phase (only for traced tasks: `tr` came over
         the wire, so tracing was enabled at the submitter)."""
+        # called with the execution's context just installed: `exec_sid` is the
+        # id the task's own spans name as their parent, which a reader of the
+        # ring maps back to the task (util/state.serve_requests)
         tracing.record_task_event(
             task_id.hex(), name, kind, "RUNNING",
             trace=tr,
             worker_id=self.worker_id,
             node_id=self.worker.node_id if self.worker is not None else None,
+            exec_sid=(tracing.current() or {}).get("sid"),
         )
 
     async def _execute(self, msg, is_actor_call: bool) -> List[dict]:

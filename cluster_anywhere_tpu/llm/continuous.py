@@ -411,6 +411,9 @@ class ContinuousBatcher:
         self._completed: deque[Request] = deque(maxlen=4096)
         self._ids = itertools.count(1)
         self._rng = jax.random.key(0)
+        # a server's hook, `(phase, seconds)`: each admit's queue wait and its
+        # own time, counted over the whole window (ca_serve_phase_seconds)
+        self.observe_phase = None
         self.stats = {
             "admitted": 0, "finished": 0, "decode_steps": 0, "cancelled": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_tokens_reused": 0,
@@ -811,8 +814,12 @@ class ContinuousBatcher:
                 req.eos_id is not None and first == req.eos_id
             )):
                 self._finish(slot, req)
+        admit_s = time.monotonic() - t0
         self.stats["queue_wait_s"] += queue_wait
-        self.stats["admit_s"] += time.monotonic() - t0
+        self.stats["admit_s"] += admit_s
+        if self.observe_phase is not None:
+            self.observe_phase("llm.admit.queue_wait", queue_wait)
+            self.observe_phase("llm.admit", admit_s)
 
     def _admit_first_token(self, req: Request, slot: int, sp: tracing.span) -> int:
         """The admit of a model that yields one causal token a step: the prompt

@@ -5,8 +5,10 @@ request batching over the compiled generate path).
 
 from __future__ import annotations
 
+import contextvars
+import functools
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 from ..util import tracing
 from .processor import (
@@ -107,6 +109,38 @@ def build_llm_deployment(
     return dep.bind(config)
 
 
+class _StreamMeter:
+    """What one token stream sums on its way out of the replica: no span a
+    token, two stamps (before the write or the `yield`, after it)."""
+
+    __slots__ = ("rid", "transport", "t0", "tokens", "first_token_s", "write_wait_s",
+                 "write_wait_max_s", "cancelled")
+
+    def __init__(self, rid: int, transport: str):
+        self.rid, self.transport = rid, transport
+        self.t0 = time.monotonic()  # submit's return
+        self.tokens = 0
+        self.first_token_s = self.write_wait_s = self.write_wait_max_s = 0.0
+        self.cancelled = True  # until the stream's end is reached
+
+    def wrote(self, t_before: float) -> None:
+        """The consumer took a token: `ch.write` or the `yield` returned."""
+        waited = time.monotonic() - t_before
+        self.tokens += 1
+        self.write_wait_s += waited
+        if waited > self.write_wait_max_s:
+            self.write_wait_max_s = waited
+
+    def attrs(self) -> Dict[str, Any]:
+        return {
+            "rid": self.rid, "transport": self.transport, "tokens": self.tokens,
+            "first_token_ms": 1e3 * self.first_token_s,
+            "write_wait_ms": 1e3 * self.write_wait_s,
+            "write_wait_max_ms": 1e3 * self.write_wait_max_s,
+            "cancelled": self.cancelled,
+        }
+
+
 class ContinuousLLMServer:
     """LLM deployment with ITERATION-LEVEL scheduling (the vLLM-engine role
     of the reference's serve.llm): concurrent requests share the decode loop
@@ -126,9 +160,15 @@ class ContinuousLLMServer:
         from ..models.transformer import init_params
         from .continuous import PREFILL_BUCKETS, ContinuousBatcher
 
+        from ..serve.replica import get_request_context, observe_phase, phase
+
         # jax is loaded from here on: compilations and device memory reach
         # the cluster's metrics from the process that holds the chip
         tracing.enable_jax_profiling()
+        # "<app>/<deployment>" where a replica builds this ("" in-process):
+        # the tag its requests' phases are counted under
+        self._deployment = get_request_context().deployment
+        self._phase = functools.partial(phase, deployment=self._deployment)
         self.config = config
         self.tok = config.tokenizer or ByteTokenizer()
         tcfg = config.model.transformer_config(self.tok.vocab_size)
@@ -157,6 +197,7 @@ class ContinuousLLMServer:
             prefix_cache_entries=getattr(config, "prefix_cache_entries", 8),
             prefix_block=getattr(config, "prefix_block", 16),
         )
+        self.cb.observe_phase = functools.partial(observe_phase, self._deployment)
         self._metrics_synced: dict = {}
         self._lock = threading.Lock()  # batcher is single-threaded inside
         # seconds callers waited for that lock in _submit, beside the
@@ -256,7 +297,13 @@ class ContinuousLLMServer:
             if now - last_sync > 1.0:
                 last_sync = now
                 try:
-                    with tracing.span("llm.pump.sync"):
+                    # the clock beacon: once a second, in both sinks, the
+                    # wall clock and the monotonic at one instant, so a reader
+                    # maps a profile's own nanoseconds to the ring's wall clock
+                    # and to a load generator's monotonic stamps
+                    with tracing.span(
+                        "llm.pump.sync", wall_ns=time.time_ns(), mono_ns=time.monotonic_ns()
+                    ):
                         self._sync_engine_metrics()
                 except Exception:
                     pass  # metrics must never kill the decode pump
@@ -308,7 +355,8 @@ class ContinuousLLMServer:
                 self._reqs.pop(rid, None)
 
     def _submit(self, body) -> tuple:
-        with tracing.span("llm.submit"):
+        sp = self._phase("llm.submit")
+        with sp:
             prompt = body.get("prompt", "")
             ids = self.tok.encode(prompt)[: self.config.max_prompt_len]
             mnt = int(body.get("max_new_tokens", self.config.max_new_tokens))
@@ -317,7 +365,7 @@ class ContinuousLLMServer:
             top_p = float(body.get("top_p", 1.0))
             q = self._queue_cls()
             t0 = time.monotonic()
-            with tracing.span("llm.submit.lock_wait"):
+            with self._phase("llm.submit.lock_wait"):
                 self._lock.acquire()
             try:
                 self.cb.stats["lock_wait_s"] += time.monotonic() - t0
@@ -336,6 +384,7 @@ class ContinuousLLMServer:
                 self._reqs[req.request_id] = req
             finally:
                 self._lock.release()
+            sp.set(rid=req.request_id, prompt_len=len(ids))
         return prompt, req, q
 
     def _forget(self, req):
@@ -368,24 +417,47 @@ class ContinuousLLMServer:
             "num_generated_tokens": len(toks),
         }
 
-    def stream(self, request):
-        """Per-token streaming while other requests decode in the same loop."""
+    def _frames(self, q, meter: _StreamMeter, own=None) -> Iterator[dict]:
+        """A stream's {"token_id", "text"} frames off the request's queue, to
+        its end.  The wait for the first is the phase
+        `llm.stream.first_token` (queue wait + admit + deliver), a child of
+        `own`, the stream's span, where that is not the ambient one."""
         import numpy as np
 
-        prompt, req, q = self._submit(_parse_body(request))
-        try:
-            while True:
+        while True:
+            if not meter.first_token_s:
+                with tracing.under(own), self._phase("llm.stream.first_token", rid=meter.rid):
+                    t = q.get(timeout=120)
+                meter.first_token_s = time.monotonic() - meter.t0
+            else:
                 t = q.get(timeout=120)
-                if t is None:
-                    return
-                if isinstance(t, BaseException):
-                    raise RuntimeError(f"LLM engine pump died: {t!r}") from t
-                yield {
-                    "token_id": int(t),
-                    "text": self.tok.decode(np.asarray([t], np.int32)),
-                }
+            if t is None:
+                meter.cancelled = False
+                return
+            if isinstance(t, BaseException):
+                raise RuntimeError(f"LLM engine pump died: {t!r}") from t
+            yield {"token_id": int(t), "text": self.tok.decode(np.asarray([t], np.int32))}
+
+    def stream(self, request):
+        """Per-token streaming while other requests decode in the same loop.
+        `llm.stream` is written from stamps when the generator ends: a
+        generator holds no span across its `yield`s."""
+        from ..serve.replica import emit_phase
+
+        prompt, req, q = self._submit(_parse_body(request))
+        meter = _StreamMeter(req.request_id, "rpc")
+        own = tracing.child_context()
+        try:
+            for frame in self._frames(q, meter, own):
+                t_yield = time.monotonic()
+                yield frame
+                meter.wrote(t_yield)
         finally:
             self._forget(req)
+            emit_phase(
+                self._deployment, "llm.stream", meter.t0, time.monotonic(), own=own,
+                **meter.attrs(),
+            )
 
     def dag_stream(self, request) -> dict:
         """Compiled-DAG streaming: decode-step -> detokenize -> stream-out
@@ -393,10 +465,9 @@ class ContinuousLLMServer:
         channel, and returns its spec; a forwarder thread pushes
         {"token_id","text"} frames into the channel and the proxy-side
         DagStreamReader futex-waits on them.  The only RPC left on the hot
-        path is this handshake."""
+        path is this handshake.  The forwarder's whole life is the span
+        `llm.stream`, under the request's trace."""
         import threading
-
-        import numpy as np
 
         from ..channel.shm_channel import BufferedShmChannel, ChannelClosedError
         from ..core.config import get_config
@@ -404,36 +475,31 @@ class ContinuousLLMServer:
 
         cfg = get_config()
         prompt, req, q = self._submit(_parse_body(request))
+        meter = _StreamMeter(req.request_id, "dag")
         ch = BufferedShmChannel(
             num_readers=1, num_buffers=max(2, cfg.serve_dag_stream_buffers)
         )
         spec = ch.spec()
 
         def forward():
+            sp = self._phase("llm.stream", rid=meter.rid, transport="dag")
             try:
-                while True:
-                    t = q.get(timeout=120)
-                    if t is None:
+                with sp:
+                    try:
+                        for frame in self._frames(q, meter):
+                            # 120s matches the RPC path's queue timeout: a consumer
+                            # stalled longer than that loses the stream either way
+                            t_write = time.monotonic()
+                            ch.write(frame, timeout=120)
+                            meter.wrote(t_write)
                         ch.write(DAG_EOF, timeout=30)
-                        # drain barrier: release() unlinks the segment, so
-                        # wait until the proxy acked the EOF frame first
-                        ch.wait_consumed(30.0)
-                        return
-                    if isinstance(t, BaseException):
-                        ch.write(
-                            {DAG_ERR: f"LLM engine pump died: {t!r}"}, timeout=30
-                        )
-                        ch.wait_consumed(30.0)
-                        return
-                    # 120s matches the RPC path's queue timeout: a consumer
-                    # stalled longer than that loses the stream either way
-                    ch.write(
-                        {
-                            "token_id": int(t),
-                            "text": self.tok.decode(np.asarray([t], np.int32)),
-                        },
-                        timeout=120,
-                    )
+                    except RuntimeError as e:  # the pump died: say so, then end
+                        ch.write({DAG_ERR: str(e)}, timeout=30)
+                    finally:
+                        sp.set(**meter.attrs())
+                    # drain barrier: release() unlinks the segment, so
+                    # wait until the proxy acked the last frame first
+                    ch.wait_consumed(30.0)
             except (ChannelClosedError, TimeoutError):
                 pass  # proxy abandoned the stream; free the decode slot below
             except Exception:
@@ -442,8 +508,10 @@ class ContinuousLLMServer:
                 self._forget(req)
                 ch.release()
 
+        # the thread starts with the caller's contextvars: the request's trace
         threading.Thread(
-            target=forward, daemon=True, name="ca-dag-stream"
+            target=contextvars.copy_context().run, args=(forward,),
+            daemon=True, name="ca-dag-stream",
         ).start()
         return spec
 

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
 import threading
 import time
 import traceback
@@ -16,6 +17,7 @@ from urllib.parse import parse_qs, unquote, urlparse
 from ..util import flightrec
 from ..util import tracing as _tracing
 from ..util.aio import drain, spawn_logged
+from .replica import emit_phase, observe_phase, run_in_pool
 
 _proxy_metrics = {}
 
@@ -38,7 +40,64 @@ def _shed_metrics():
             "serve SSE streams abandoned by their client mid-stream",
             tag_keys=("deployment",),
         )
+        # what an operator alerts on before the front stops: every live SSE
+        # stream parks pool threads, and the pool is a function of the
+        # host's core count
+        _proxy_metrics["streams_open"] = m.Gauge(
+            "ca_serve_proxy_streams_open", "SSE streams this proxy is carrying"
+        )
+        _proxy_metrics["executor_pending"] = m.Gauge(
+            "ca_serve_proxy_executor_pending",
+            "work the proxy handed to its loop's executor that no pool thread has started",
+        )
+        _proxy_metrics["executor_threads"] = m.Gauge(
+            "ca_serve_proxy_executor_threads", "size of the proxy loop's default executor"
+        )
     return _proxy_metrics
+
+
+_tally_lock = threading.Lock()
+
+
+class _RequestTrace:
+    """One request's way through this proxy: its trace context (None when
+    untraced), the deployment its phases are counted under, and the sums the
+    request event carries.  A coroutine holds no span open across an await:
+    every phase here is two `time.monotonic()` stamps and `emit_phase`."""
+
+    __slots__ = ("ctx", "dep", "t_accept", "status", "streamed", "tokens", "ttfb_s",
+                 "executor_wait_s", "write_wait_s")
+
+    def __init__(self, ctx, t_accept: float):
+        self.ctx = ctx  # the request span's own context: its phases are its children
+        self.dep = ""
+        self.t_accept = t_accept
+        self.status = 0
+        self.streamed = False
+        self.tokens = 0
+        self.ttfb_s = 0.0
+        self.executor_wait_s = 0.0
+        self.write_wait_s = 0.0
+
+    def phase(self, name: str, t0: float, t1: float, ctx=None, **attrs):
+        return emit_phase(self.dep, name, t0, t1, ctx or self.ctx, **attrs)
+
+    def in_pool(self, loop, fn, what: Optional[str], ctx=None):
+        """Run `fn` on a pool thread under the request's trace; the wait for
+        the thread is added to the request's `executor_wait_ms` and, where
+        `what` names it, is a `serve.proxy.executor_wait` of its own."""
+        ctx = ctx or self.ctx
+
+        def on_wait(t_submit: float, t_start: float) -> None:
+            with _tally_lock:  # the pump's and the first get's threads may start together
+                self.executor_wait_s += t_start - t_submit
+            if what is not None:
+                self.phase("serve.proxy.executor_wait", t_submit, t_start, ctx, what=what)
+
+        return run_in_pool(
+            loop, fn, on_wait=on_wait, ctx=ctx,
+            pending_gauge=_shed_metrics()["executor_pending"],
+        )
 
 
 class _Shed(Exception):
@@ -103,6 +162,8 @@ class ProxyActor:
         # avoids paying a doomed extra RPC on every subsequent SSE request
         self._dag_stream_ok: Dict[str, bool] = {}
         self._refresh_gen = 0
+        self._streams_open = 0  # touched by the loop's thread alone
+        self._pool_size = 0
         self._loop = global_worker().loop
         self._server = None
         self._started = threading.Event()
@@ -118,6 +179,17 @@ class ProxyActor:
         self._server = await asyncio.start_server(
             self._handle_conn, self.host, self.port
         )
+        self._report_pool_size()
+
+    def _report_pool_size(self) -> None:
+        """ca_serve_proxy_executor_threads: the loop's default executor once it
+        exists (its first use makes it), asyncio's own rule for it before."""
+        pool = getattr(self._loop, "_default_executor", None)
+        cpus = getattr(os, "process_cpu_count", os.cpu_count)() or 1
+        size = getattr(pool, "_max_workers", None) or min(32, cpus + 4)
+        if size != self._pool_size:
+            self._pool_size = size
+            _shed_metrics()["executor_threads"].set(size)
 
     def ready(self) -> str:
         return f"http://{self.host}:{self.port}"
@@ -192,6 +264,7 @@ class ProxyActor:
     async def _handle_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
         """One request per connection (responses carry Connection: close)."""
         req = None
+        t_accept = time.monotonic()
         try:
             req = await self._read_request(reader)
         except asyncio.CancelledError:
@@ -209,7 +282,7 @@ class ProxyActor:
             except Exception:
                 pass
             return
-        spawn_logged(self._dispatch(req, writer), "serve-proxy-dispatch")
+        spawn_logged(self._dispatch(req, writer, t_accept), "serve-proxy-dispatch")
 
     # request-size guards (ADVICE r1: unbounded header/body reads let a
     # client exhaust proxy memory); generous defaults, overridable per proxy
@@ -342,7 +415,7 @@ class ProxyActor:
                 adm.inflight -= 1
                 adm.tokens -= tokens
 
-    async def _dispatch(self, req: Request, writer: asyncio.StreamWriter):
+    async def _dispatch(self, req: Request, writer: asyncio.StreamWriter, t_accept: float):
         admitted = None
         # cross-plane trace (tentpole): adopt the client's W3C traceparent
         # header, or mint a root when tracing is enabled — the request span
@@ -360,26 +433,35 @@ class ProxyActor:
             tr_req = None
         wire = {"tid": tr_req["tid"], "sid": tr_req["sid"]} if tr_req else None
         tr_hdr = {"traceparent": _tracing.format_traceparent(wire)} if wire else None
-        t0 = time.time()
+        rt = _RequestTrace(tr_req, t_accept)
+        loop = asyncio.get_running_loop()
         try:
+            t0 = time.monotonic()
             match = self._match(req.path)
-            if match is None:
+            miss = match is None
+            if miss:
                 # a route deployed milliseconds ago may not have reached the
                 # 0.5s poller yet: EVERY miss gets one fresh look at the
                 # controller before 404ing, serialized through one lock so a
                 # 404 burst (scanners, favicon probes) queues behind a
                 # single in-flight RPC instead of flooding the controller
-                loop = asyncio.get_running_loop()
-                await loop.run_in_executor(None, self._miss_refresh)
+                await rt.in_pool(loop, self._miss_refresh, "miss_refresh")
                 match = self._match(req.path)
+            if match is not None:
+                rt.dep = f"{match[1].app}/{match[1].deployment}"
+            rt.phase("serve.proxy.route", t0, time.monotonic(), miss=miss)
             if match is None:
+                rt.status = 404
                 await self._respond(writer, 404, {"error": f"no route for {req.path}"})
                 return
             prefix, handle = match
-            dep_tag = {"deployment": f"{handle.app}/{handle.deployment}"}
+            dep_tag = {"deployment": rt.dep}
+            t0 = time.monotonic()
             try:
                 admitted = self._try_admit(prefix, req)
+                rt.phase("serve.proxy.admit", t0, time.monotonic(), shed="")
             except _Shed as s:
+                rt.phase("serve.proxy.admit", t0, time.monotonic(), shed=s.reason)
                 # load-shedding: refuse NOW with Retry-After instead of
                 # queueing unboundedly — past the saturation knee a bounded
                 # queue is the only way p99 stays bounded
@@ -391,35 +473,27 @@ class ProxyActor:
                         code=s.code, limit=s.limit, path=req.path,
                         **({"trace": wire} if wire else {}),
                     )
+                rt.status = s.code
                 await self._respond(
                     writer, s.code,
                     {"error": "request shed", "reason": s.reason, "limit": s.limit},
                     extra_headers={"Retry-After": f"{s.retry_after:g}", **(tr_hdr or {})},
                 )
                 return
-            loop = asyncio.get_running_loop()
             if "text/event-stream" in req.headers.get("accept", ""):
                 # SSE: iterate the deployment's generator, one event per item
                 # (reference proxy StreamingResponse path; LLM token streams)
-                await self._respond_sse(
-                    writer, handle, req, loop, dep_tag, wire=wire, tr_hdr=tr_hdr
-                )
+                rt.streamed, rt.status = True, 200
+                await self._respond_sse(writer, handle, req, loop, rt, wire=wire, tr_hdr=tr_hdr)
                 return
 
             # handle.remote() blocks briefly (routing) and result() blocks
-            # until done — run both off the event loop.  run_in_executor does
-            # NOT propagate contextvars, so the request trace is installed
-            # inside the worker thread, around the submission.
-            def _call():
-                if wire is None:
-                    return handle.remote(req).result(timeout_s=60)
-                tok = _tracing.push_execution(wire)
-                try:
-                    return handle.remote(req).result(timeout_s=60)
-                finally:
-                    _tracing.pop_execution(tok)
-
-            result = await loop.run_in_executor(None, _call)
+            # until done — run both off the event loop, under the request's
+            # trace (run_in_executor does NOT propagate contextvars)
+            result = await rt.in_pool(
+                loop, lambda: handle.remote(req).result(timeout_s=60), "call"
+            )
+            rt.status = 200
             await self._respond(writer, 200, result, extra_headers=tr_hdr)
         except asyncio.CancelledError:
             try:
@@ -429,21 +503,23 @@ class ProxyActor:
             raise  # proxy shutdown: don't dress cancellation up as a 500
         except Exception as e:
             traceback.print_exc()
+            rt.status = 500
             await self._respond(writer, 500, {"error": repr(e)})
         finally:
             if admitted is not None:
                 self._release(*admitted)
+            # accept to last byte, with what the stream summed on its way
+            t_end = time.monotonic()
+            observe_phase(rt.dep, "serve.proxy.request", t_end - rt.t_accept)
             if tr_req is not None:
-                w = _tracing._current_worker()
-                _tracing.record_task_event(
-                    "", f"serve:{req.method} {req.path}", "span", "SPAN",
-                    trace=tr_req,
-                    worker_id=w.client_id if w is not None else None,
-                    node_id=w.node_id if w is not None else None,
-                    start=t0, end=time.time(),
+                _tracing.emit(
+                    f"serve:{req.method} {req.path}", rt.t_accept, t_end, own=tr_req,
+                    status=rt.status, streamed=rt.streamed, tokens=rt.tokens,
+                    ttfb_ms=1e3 * rt.ttfb_s, executor_wait_ms=1e3 * rt.executor_wait_s,
+                    write_wait_ms=1e3 * rt.write_wait_s,
                 )
 
-    async def _open_stream(self, handle, req: Request, loop, wire=None):
+    async def _open_stream(self, handle, req: Request, loop, rt: _RequestTrace):
         """Pick the token transport for one SSE request.
 
         Compiled-DAG path (config.serve_compiled_dag, default on): ONE RPC
@@ -453,52 +529,51 @@ class ProxyActor:
         per-token streaming-RPC path when the deployment has no dag_stream
         method or the segment can't be mapped (cross-host replica), and
         remembers the failure per deployment.
+
+        The whole of it, until a reader is in hand, is the phase
+        `serve.proxy.open_stream`; the handshake's wait for a pool thread and
+        the router's phases are its children.
         """
         from ..core.config import get_config
 
-        def _traced(fn):
-            # executor threads start with a fresh context: install the
-            # request trace around the submission so the replica-side spans
-            # chain under the proxy's span
-            if wire is None:
-                return fn
-
-            def wrapped():
-                tok = _tracing.push_execution(wire)
+        t0 = time.monotonic()
+        own = _tracing.child_context(rt.ctx) if rt.ctx is not None else None
+        transport, fallback = "rpc", False
+        try:
+            if get_config().serve_compiled_dag and self._dag_stream_ok.get(rt.dep, True):
                 try:
-                    return fn()
-                finally:
-                    _tracing.pop_execution(tok)
-
-            return wrapped
-
-        dep_key = f"{handle.app}/{handle.deployment}"
-        if get_config().serve_compiled_dag and self._dag_stream_ok.get(dep_key, True):
-            try:
-                spec = await loop.run_in_executor(
-                    None,
-                    _traced(
+                    spec = await rt.in_pool(
+                        loop,
                         lambda: handle.options(method_name="dag_stream")
                         .remote(req)
-                        .result(timeout_s=30)
-                    ),
-                )
-                from .dag_stream import open_dag_stream
+                        .result(timeout_s=30),
+                        "dag_stream", own,
+                    )
+                    from .dag_stream import open_dag_stream
 
-                return open_dag_stream(spec)
-            except asyncio.CancelledError:
-                raise
-            except Exception:
-                self._dag_stream_ok[dep_key] = False
-        return await loop.run_in_executor(
-            None, _traced(lambda: handle.options(stream=True).remote(req))
-        )
+                    reader = open_dag_stream(spec)
+                    transport = "dag"
+                    return reader
+                except asyncio.CancelledError:
+                    raise
+                except Exception:
+                    self._dag_stream_ok[rt.dep] = False
+                    fallback = True
+            return await rt.in_pool(
+                loop, lambda: handle.options(stream=True).remote(req), "rpc_stream", own
+            )
+        finally:
+            emit_phase(
+                rt.dep, "serve.proxy.open_stream", t0, time.monotonic(), own=own,
+                transport=transport, fallback=fallback,
+            )
 
-    async def _respond_sse(self, writer, handle, req: Request, loop, dep_tag=None,
+    async def _respond_sse(self, writer, handle, req: Request, loop, rt: _RequestTrace,
                            wire=None, tr_hdr=None):
         import json as _json
         import queue as _queue
 
+        mets = _shed_metrics()
         extras = "".join(f"{k}: {v}\r\n" for k, v in (tr_hdr or {}).items())
         writer.write(
             b"HTTP/1.1 200 OK\r\nContent-Type: text/event-stream\r\n"
@@ -509,7 +584,16 @@ class ProxyActor:
         q: _queue.Queue = _queue.Queue(maxsize=64)
         _END = object()
         abandoned = threading.Event()
-        resp_gen = await self._open_stream(handle, req, loop, wire=wire)
+        self._streams_open += 1
+        mets["streams_open"].set(self._streams_open)
+        self._report_pool_size()
+        try:
+            resp_gen = await self._open_stream(handle, req, loop, rt)
+        except BaseException:
+            self._streams_open -= 1
+            mets["streams_open"].set(self._streams_open)
+            raise
+        t_reader = time.monotonic()
 
         def qput(item) -> bool:
             # abandonment-aware put: a dead consumer stops reading the
@@ -536,10 +620,15 @@ class ProxyActor:
             finally:
                 qput(_END)
 
-        loop.run_in_executor(None, pump)
+        # two pool threads a live stream: the pump for its whole life, a
+        # `q.get` for nearly all of it
+        rt.in_pool(loop, pump, "pump")
+        first = True
         try:
             while True:
-                item = await loop.run_in_executor(None, q.get)
+                item = await rt.in_pool(loop, q.get, "first_get" if first else None)
+                if first:
+                    rt.phase("serve.proxy.first_token", t_reader, time.monotonic())
                 if item is _END:
                     break
                 if isinstance(item, bytes):
@@ -552,7 +641,13 @@ class ProxyActor:
                     writer.write(f"data: {data}\n\n".encode())
                     # bounded: a consumer that stops reading mid-stream must
                     # not pin this coroutine (or the replica's generator)
+                    t_write = time.monotonic()
                     await drain(writer)
+                    t_written = time.monotonic()
+                    rt.write_wait_s += t_written - t_write
+                    rt.tokens += 1
+                    if first:
+                        rt.ttfb_s = t_written - rt.t_accept
                 except asyncio.CancelledError:
                     raise
                 except Exception:
@@ -563,25 +658,23 @@ class ProxyActor:
                     # unresolved routing future, so it runs off-loop.
                     abandoned.set()
                     loop.run_in_executor(None, resp_gen.cancel)
-                    _shed_metrics()["abandoned"].inc(
-                        1, tags=dep_tag or {"deployment": f"{handle.app}/{handle.deployment}"}
-                    )
+                    mets["abandoned"].inc(1, tags={"deployment": rt.dep})
                     if flightrec.REC is not None:
                         flightrec.REC.record(
                             "serve", "serve_stream_abandoned",
-                            deployment=(dep_tag or {}).get(
-                                "deployment", f"{handle.app}/{handle.deployment}"
-                            ),
-                            path=req.path,
+                            deployment=rt.dep, path=req.path,
                             **({"trace": wire} if wire else {}),
                         )
                     return
+                first = False
         except asyncio.CancelledError:
             # proxy shutdown: stop the upstream too, then stay cancelled
             abandoned.set()
             loop.run_in_executor(None, resp_gen.cancel)
             raise
         finally:
+            self._streams_open -= 1
+            mets["streams_open"].set(self._streams_open)
             try:
                 writer.close()
             except Exception:

@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional
 from ..core import api as ca
 from ..core.actor import get_actor
 from .controller import CONTROLLER_NAME
+from .replica import emit_phase, phase
 
 _REFRESH_PERIOD_S = 1.0
 
@@ -142,7 +143,29 @@ class Router:
         # until the watch loop's completion decrements free capacity
         self._capacity_cv = threading.Condition(self._lock)
         self._watcher: Optional[threading.Thread] = None
-        self._metric_tags = {"deployment": f"{app}/{deployment}"}
+        self._dep = f"{app}/{deployment}"
+        self._metric_tags = {"deployment": self._dep}
+
+    def submit(self, streaming: bool, meta: Dict[str, Any], args, kwargs):
+        """Hand one call to the dispatch thread (under the caller's
+        contextvars: ambient trace, log attribution); returns the future of
+        what `route` / `route_streaming` returns.  The wait for that one
+        thread is the phase `serve.router.dispatch_wait`, stamped here and
+        written by the thread as its first act on the call."""
+        ctx = contextvars.copy_context()
+        t_call = time.monotonic()
+        # items ahead of this one: the executor's own queue (its length is
+        # all that is read of it; 0 where an implementation has none)
+        queued = getattr(getattr(self._dispatch, "_work_queue", None), "qsize", int)()
+        return self._dispatch.submit(
+            ctx.run, self._dispatched, t_call, queued, streaming, meta, args, kwargs
+        )
+
+    def _dispatched(self, t_call: float, queued: int, streaming: bool, meta, args, kwargs):
+        emit_phase(
+            self._dep, "serve.router.dispatch_wait", t_call, time.monotonic(), queued=queued
+        )
+        return (self.route_streaming if streaming else self.route)(meta, args, kwargs)
 
     def _controller(self):
         return get_actor(CONTROLLER_NAME)
@@ -209,34 +232,38 @@ class Router:
     def _acquire_replica(self, meta: Dict[str, Any]) -> Dict[str, Any]:
         """Pick a replica with free capacity, waiting on the capacity
         condition when saturated (bounded waits, visible in the
-        ca_serve_backpressure_seconds histogram) instead of spinning."""
+        ca_serve_backpressure_seconds histogram) instead of spinning.  The
+        pick and the wait together are the phase `serve.router.acquire`."""
         deadline = time.monotonic() + 30.0
         t_wait0 = None
-        while True:
-            self._refresh()
-            with self._capacity_cv:
-                pick = self._pick_locked()
-                if (
-                    pick is not None
-                    and self._inflight.get(pick["replica_id"], 0) < self._max_ongoing
-                ):
-                    if t_wait0 is not None:
-                        _backpressure_metric().observe(
-                            time.monotonic() - t_wait0, tags=self._metric_tags
+        sp = phase("serve.router.acquire", self._dep)
+        with sp:
+            while True:
+                self._refresh()
+                with self._capacity_cv:
+                    pick = self._pick_locked()
+                    inflight = self._inflight.get(pick["replica_id"], 0) if pick else 0
+                    if pick is not None and inflight < self._max_ongoing:
+                        waited = 0.0 if t_wait0 is None else time.monotonic() - t_wait0
+                        if t_wait0 is not None:
+                            _backpressure_metric().observe(waited, tags=self._metric_tags)
+                        sp.set(
+                            waited_ms=1e3 * waited, inflight=inflight,
+                            max_ongoing=self._max_ongoing, replicas=len(self._replicas),
                         )
-                    return pick
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    raise RuntimeError(
-                        f"no available replica for {self.app}/{self.deployment}"
-                    )
-                if t_wait0 is None:
-                    t_wait0 = time.monotonic()
-                # bounded: completions notify; the cap also forces a
-                # periodic membership refresh while saturated/empty
-                self._capacity_cv.wait(timeout=min(0.25, remaining))
-            if pick is None:
-                self._refresh(force=True)
+                        return pick
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        raise RuntimeError(
+                            f"no available replica for {self.app}/{self.deployment}"
+                        )
+                    if t_wait0 is None:
+                        t_wait0 = time.monotonic()
+                    # bounded: completions notify; the cap also forces a
+                    # periodic membership refresh while saturated/empty
+                    self._capacity_cv.wait(timeout=min(0.25, remaining))
+                if pick is None:
+                    self._refresh(force=True)
 
     def route(self, meta: Dict[str, Any], args, kwargs):
         """Blocking routing + submission; runs on the dispatch thread only.
@@ -247,7 +274,8 @@ class Router:
         with self._lock:
             self._inflight[rid] = self._inflight.get(rid, 0) + 1
         try:
-            ref = h.handle_request.remote(meta, *args, **kwargs)
+            with phase("serve.router.submit", self._dep, streaming=False):
+                ref = h.handle_request.remote(meta, *args, **kwargs)
         except Exception:
             with self._lock:
                 self._inflight[rid] -= 1
@@ -265,9 +293,10 @@ class Router:
         the stream's whole life — so P2C and drain retirement see them.)"""
         pick = self._acquire_replica(meta)
         h = self._handle_for(pick["replica_id"], pick["actor_name"])
-        return h.handle_request_streaming.options(num_returns="streaming").remote(
-            meta, *args, **kwargs
-        )
+        with phase("serve.router.submit", self._dep, streaming=True):
+            return h.handle_request_streaming.options(num_returns="streaming").remote(
+                meta, *args, **kwargs
+            )
 
     def _watch_completion(self, rid: str, ref):
         """One watcher thread per router drains completions in batches (a
@@ -383,20 +412,8 @@ class DeploymentHandle:
             "method": self._method,
             "multiplexed_model_id": self._multiplexed_model_id,
         }
-        # the dispatch thread starts with an empty context: carry the
-        # caller's contextvars (ambient trace, log attribution) across so
-        # the replica call joins the request's trace instead of losing it
-        # at the thread hop
-        ctx = contextvars.copy_context()
-        if self._stream:
-            fut = self._router._dispatch.submit(
-                ctx.run, self._router.route_streaming, meta, args, kwargs
-            )
-            return DeploymentResponseGenerator(fut)
-        fut = self._router._dispatch.submit(
-            ctx.run, self._router.route, meta, args, kwargs
-        )
-        return DeploymentResponse(fut)
+        fut = self._router.submit(self._stream, meta, args, kwargs)
+        return DeploymentResponseGenerator(fut) if self._stream else DeploymentResponse(fut)
 
     def to_spec(self) -> Dict[str, str]:
         return {

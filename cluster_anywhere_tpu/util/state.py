@@ -243,7 +243,9 @@ def serve_plane() -> Dict[str, Any]:
     per-replica node/queue/draining state and the last autoscale decision
     (live from the controller, falling back to its ~1s head-KV digest when
     the controller is busy/unreachable), plus the cluster-aggregated
-    ca_serve_* counters and request/backpressure latency quantiles — the
+    ca_serve_* counters and gauges, the request/backpressure latency
+    quantiles, and p50 / p99 / count of every phase of a request's way
+    (`ca_serve_phase_seconds`, over the whole window and every request) — the
     one-call view of admission, routing, prefix reuse, and drain health."""
     from .metrics import get_metrics_snapshot, histogram_quantile, merged_histogram
 
@@ -266,6 +268,7 @@ def serve_plane() -> Dict[str, Any]:
         except Exception:
             pass
     counters: Dict[str, int] = {}
+    gauges: Dict[str, float] = {}
     quantiles: Dict[str, float] = {}
     try:
         snap = get_metrics_snapshot()
@@ -274,11 +277,23 @@ def serve_plane() -> Dict[str, Any]:
                 counters[name[len("ca_serve_"):]] = int(
                     sum(rec.get("data", {}).values())
                 )
-        for name, label in (
-            ("ca_serve_request_latency_seconds", "request_latency"),
-            ("ca_serve_backpressure_seconds", "backpressure"),
-        ):
-            b, bk, n = merged_histogram(snap.get(name))
+            elif name.startswith("ca_serve_") and rec.get("type") == "gauge":
+                # the proxy's streams open, executor work pending and pool
+                # size; the replicas' engine devices (summed over their tags)
+                gauges[name[len("ca_serve_"):]] = float(sum(rec.get("data", {}).values()))
+        series = [
+            (snap.get("ca_serve_request_latency_seconds"), "request_latency"),
+            (snap.get("ca_serve_backpressure_seconds"), "backpressure"),
+        ]
+        # every phase of a request's way (the span of the same name), over the
+        # whole window and every request, traced or not
+        series += [
+            ({"data": cells}, phase)
+            for phase, cells in sorted(_cells_by_tag(
+                snap.get("ca_serve_phase_seconds"), "phase").items())
+        ]
+        for rec, label in series:
+            b, bk, n = merged_histogram(rec)
             if n:
                 quantiles[f"{label}_p50_s"] = histogram_quantile(b, bk, n, 0.50)
                 quantiles[f"{label}_p99_s"] = histogram_quantile(b, bk, n, 0.99)
@@ -289,8 +304,151 @@ def serve_plane() -> Dict[str, Any]:
         "deployments": deployments,
         "source": source,
         "counters": counters,
+        "gauges": gauges,
         "quantiles": quantiles,
     }
+
+
+def _cells_by_tag(rec: Optional[dict], tag: str) -> Dict[str, Dict[str, Any]]:
+    """A snapshot metric's cells grouped by one tag's value:
+    {value: {tags_key: cell}} (a cell's key is its tags as a JSON list of
+    pairs)."""
+    out: Dict[str, Dict[str, Any]] = defaultdict(dict)
+    for key, cell in (rec or {}).get("data", {}).items():
+        try:
+            value = dict(json.loads(key)).get(tag)
+        except Exception:
+            continue
+        if value is not None:
+            out[value][key] = cell
+    return out
+
+
+def _covered(intervals: List[tuple], lo: float, hi: float) -> float:
+    """Length of the union of `intervals`, each clipped to [lo, hi]."""
+    total, reach = 0.0, lo
+    for a, b in sorted((max(a, lo), min(b, hi)) for a, b in intervals):
+        if b > max(a, reach):
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+def _nearest_rank(values: List[float], q: float) -> float:
+    values = sorted(values)
+    return values[min(len(values) - 1, int(q * len(values)))] if values else 0.0
+
+
+def serve_requests(limit: int = 200, *, events: Optional[List[dict]] = None) -> Dict[str, Any]:
+    """The operator's reading of traced serve requests: the ring's SPAN
+    events (and the terminal events of traced tasks) grouped by trace into
+    requests, a request being a trace that holds the proxy's `serve:<method>
+    <path>` event.
+
+    Each request lists its phases in the order they began, every one with its
+    depth under the request, its offset from the accept, its duration and its
+    **self time**: the duration less what its children cover of it (children
+    are clipped to their parent: one that outlives it, as a stream outlives
+    the handshake that opened it, counts only while the parent ran).  A span's
+    parent is the one whose id it names; a task's own spans name the
+    execution's id, which the task's RUNNING event carries as `exec_sid`;
+    a span of the replica that names an id nobody wrote (an admit runs on the
+    pump's thread) hangs under the `llm.stream` of the same `rid`, and is
+    marked `detached` where there is none.  `phases` gives p50 / p99 of
+    duration and self time by span name over the newest `limit` requests.
+    `events` is a ring to read in place of the head's (a test's)."""
+    raw = events if events is not None else _head("list_task_events", limit=100_000)["events"]
+    by_trace: Dict[str, List[dict]] = defaultdict(list)
+    for e in raw:
+        tid = (e.get("trace") or {}).get("tid")
+        if tid:
+            by_trace[tid].append(e)
+    requests = []
+    for tid, evs in by_trace.items():
+        nodes: Dict[str, dict] = {}  # span id -> node
+        alias: Dict[str, str] = {}  # an execution's id -> its task's span id
+        tasks: Dict[str, dict] = defaultdict(dict)
+        for e in evs:
+            tr = e["trace"]
+            if e.get("state") == "SPAN" and e.get("start") is not None and e.get("end") is not None:
+                nodes[tr["sid"]] = {
+                    "name": e.get("name") or "span", "sid": tr["sid"], "psid": tr.get("psid"),
+                    "start": e["start"], "end": e["end"],
+                    "attrs": {k: v for k, v in e.items() if k not in _SPAN_EVENT_FIELDS},
+                    "mono": e.get("mono"),
+                }
+            elif e.get("task_id"):
+                t = tasks[e["task_id"]]
+                t.setdefault("sid", tr["sid"])
+                t.setdefault("name", e.get("name"))
+                if tr.get("psid"):
+                    t["psid"] = tr["psid"]
+                if e.get("exec_sid"):
+                    alias[e["exec_sid"]] = tr["sid"]
+                if e.get("end") is not None:
+                    t.update(start=e["start"], end=e["end"])
+        for t in tasks.values():
+            if "end" in t and t["sid"] not in nodes:
+                nodes[t["sid"]] = {
+                    "name": f"task:{t.get('name') or '?'}", "sid": t["sid"],
+                    "psid": t.get("psid"), "start": t["start"], "end": t["end"],
+                    "attrs": {}, "mono": None,
+                }
+        root = next((n for n in nodes.values() if n["name"].startswith("serve:")), None)
+        if root is None:
+            continue
+        streams = {n["attrs"].get("rid"): n for n in nodes.values() if n["name"] == "llm.stream"}
+        children: Dict[str, List[dict]] = defaultdict(list)
+        detached = []
+        for n in nodes.values():
+            if n is root:
+                continue
+            parent = nodes.get(alias.get(n["psid"], n["psid"]))
+            if parent is None and n["attrs"].get("rid") is not None:
+                parent = streams.get(n["attrs"]["rid"])
+            if parent is None or parent is n:
+                detached.append(n)
+            else:
+                children[parent["sid"]].append(n)
+        phases: List[dict] = []
+
+        def walk(n: dict, depth: int, off: bool) -> None:
+            kids = sorted(children.get(n["sid"], ()), key=lambda k: k["start"])
+            dur = n["end"] - n["start"]
+            covered = _covered([(k["start"], k["end"]) for k in kids], n["start"], n["end"])
+            phases.append({
+                "name": n["name"], "depth": depth,
+                "offset_ms": 1e3 * (n["start"] - root["start"]), "dur_ms": 1e3 * dur,
+                "self_ms": 1e3 * max(0.0, dur - covered),
+                **({"detached": True} if off else {}), **n["attrs"],
+            })
+            for k in kids:
+                walk(k, depth + 1, off)
+
+        walk(root, 0, False)
+        for n in sorted(detached, key=lambda k: k["start"]):
+            walk(n, 1, True)
+        requests.append({
+            "trace": tid, "name": root["name"], "start": root["start"], "mono": root["mono"],
+            "dur_ms": 1e3 * (root["end"] - root["start"]), **root["attrs"], "phases": phases,
+        })
+    requests.sort(key=lambda r: r["start"])
+    requests = requests[-limit:] if limit else requests
+    by_name: Dict[str, List[dict]] = defaultdict(list)
+    for r in requests:
+        for ph in r["phases"]:
+            by_name[ph["name"]].append(ph)
+    summary = {
+        name: {
+            "count": len(phs),
+            "p50_ms": _nearest_rank([p["dur_ms"] for p in phs], 0.50),
+            "p99_ms": _nearest_rank([p["dur_ms"] for p in phs], 0.99),
+            "self_p50_ms": _nearest_rank([p["self_ms"] for p in phs], 0.50),
+            "self_p99_ms": _nearest_rank([p["self_ms"] for p in phs], 0.99),
+        }
+        for name, phs in sorted(by_name.items())
+    }
+    return {"requests": requests, "phases": summary}
 
 
 def train_plane() -> Dict[str, Any]:
@@ -488,7 +646,7 @@ _PHASE_ORDER = {
 # is an attribute the span was given
 _SPAN_EVENT_FIELDS = frozenset(
     ("task_id", "name", "type", "state", "ts", "trace", "worker_id", "node_id",
-     "start", "end")
+     "start", "end", "mono")
 )
 
 

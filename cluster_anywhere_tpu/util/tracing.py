@@ -36,6 +36,27 @@ anybody profiles the process, the profiler writes the span into its own
 trace beside the device's operations, on one clock (a flag test while no
 profiler session runs).
 
+Spans from stamps.  A `with span(...)` block belongs to one thread and one
+unbroken stretch of it: it installs its context in a contextvar and, where
+jax is loaded, enters a `TraceAnnotation` on the thread's own stack.  **A span
+is never held across an `await`** (nor across a generator's `yield`): the
+loop runs other coroutines on the same thread meanwhile, and both would nest
+wrongly.  A coroutine, and a wait that has no block to wrap (the wait for an
+executor's thread), stamps `time.monotonic()` where the span starts and ends
+and calls `emit(name, t0, t1, ctx=..., **attrs)`: a finished span of the
+event sink, a child of `ctx` or of the ambient context, a no-op unless
+tracing is enabled or a context is there.  Where the span's children have to
+name it while it is still open, `child_context()` mints its context first
+(`under(ctx)` installs it on the thread that does the work) and `emit(...,
+own=ctx)` writes the span under that id.
+
+One clock besides the wall's.  Every SPAN event, from `span` and from
+`emit`, carries **`mono`**: `time.monotonic()` at its start, beside the
+wall-clock `start`.  On Linux that is one clock for every process of a host,
+and the clock of a load generator's own stamps, so a reader puts the ring's
+spans and a client's token times on one axis without trusting two wall
+clocks to agree (`util/state.serve_requests`).
+
 JAX hooks: `enable_jax_profiling()` (called by `enable()` when jax is
 already imported, by the LLM engine and by the train backend once they
 have imported it) counts backend compilations into `ca_jax_compiles_total`
@@ -356,7 +377,7 @@ class span:
     costs a flag test otherwise.
 
     `attrs` are small scalars whose names are no field of a SPAN event
-    (name, type, state, ts, trace, start, end, worker_id, node_id);
+    (name, type, state, ts, trace, start, end, mono, worker_id, node_id);
     `set(**attrs)` adds what is known only inside the block.  `with ... as
     ctx` gives the span's trace context, None while the event sink is off."""
 
@@ -382,7 +403,7 @@ class span:
                 self.ctx = {"tid": parent["tid"], "sid": new_span_id(), "psid": parent["sid"]}
             self._token = _ctx.set(self.ctx)
             self._t0 = time.time()
-            self._p0 = time.perf_counter()
+            self._p0 = time.monotonic()
         annotation = _trace_annotation()
         if annotation is not None:
             self._annotation = annotation(self.name, **self.attrs)
@@ -394,20 +415,88 @@ class span:
             self._annotation.__exit__(*exc)
         if self.ctx is None:
             return False
-        dur = time.perf_counter() - self._p0
+        dur = time.monotonic() - self._p0
         _ctx.reset(self._token)
-        # after disable() the histogram must stop mutating too, not just the
-        # event stream: _span_hist exists only once enable() has run
-        if _span_hist is not None:
-            _span_hist.observe(dur, {"name": self.name})
-        w = _current_worker()
-        record_task_event(
-            "", self.name, "span", "SPAN",
-            trace=self.ctx,
-            worker_id=w.client_id if w is not None else None,
-            node_id=w.node_id if w is not None else None,
-            **{**self.attrs, "start": self._t0, "end": self._t0 + dur},
-        )
+        _record_span(self.name, self.ctx, self._t0, dur, self._p0, self.attrs)
+        return False
+
+
+def _record_span(name: str, ctx: Dict[str, str], start: float, dur: float,
+                 mono: float, attrs: Dict[str, Any]) -> None:
+    """One finished span into the event buffer (and the span histogram):
+    `start` on the wall clock, `mono` the same instant on the monotonic."""
+    # after disable() the histogram must stop mutating too, not just the
+    # event stream: _span_hist exists only once enable() has run
+    if _span_hist is not None:
+        _span_hist.observe(dur, {"name": name})
+    w = _current_worker()
+    record_task_event(
+        "", name, "span", "SPAN",
+        trace=ctx,
+        worker_id=w.client_id if w is not None else None,
+        node_id=w.node_id if w is not None else None,
+        **{**attrs, "start": start, "end": start + dur, "mono": mono},
+    )
+
+
+def child_context(parent: Optional[Dict[str, str]] = None) -> Optional[Dict[str, str]]:
+    """Mint the context of a span under `parent` (default: the ambient
+    context; a fresh trace where there is none and tracing is enabled), for
+    a span that `emit(..., own=ctx)` writes once it has ended.  None when
+    there is nothing to trace."""
+    if parent is None:
+        parent = _ctx.get()
+    if parent is None:
+        if not _enabled:
+            return None
+        return {"tid": new_trace_id(), "sid": new_span_id()}
+    return {"tid": parent["tid"], "sid": new_span_id(), "psid": parent["sid"]}
+
+
+def emit(name: str, t0: float, t1: float, ctx: Optional[Dict[str, str]] = None,
+         *, own: Optional[Dict[str, str]] = None, **attrs: Any) -> Optional[Dict[str, str]]:
+    """Write a finished span from two `time.monotonic()` stamps: what a
+    coroutine, or a wait with no block to wrap, uses in place of `with
+    span(...)`.  The span is a child of `ctx`, or of the ambient context; or
+    it is `own`, a context `child_context()` minted when the span began.
+    Event sink only (the profiler takes no event after the fact).  Returns the
+    span's context, None when nothing was written: tracing is not enabled
+    and no context is there."""
+    if own is None:
+        own = child_context(ctx)
+        if own is None:
+            return None
+    now_mono, now_wall = time.monotonic(), time.time()
+    _record_span(name, own, now_wall - (now_mono - t0), max(0.0, t1 - t0), t0, attrs)
+    return own
+
+
+class under:
+    """`with under(ctx):` installs a context as the ambient one of this
+    thread for the block: the own context of a span that is still open
+    (`child_context`), so that what the block does becomes its child.  An
+    executor's thread does not inherit its submitter's contextvars; this is
+    how a request's trace reaches it.  `under(None)` does nothing."""
+
+    __slots__ = ("ctx", "_token")
+
+    def __init__(self, ctx: Optional[Dict[str, str]]):
+        self.ctx = ctx
+        self._token = None
+
+    def __enter__(self) -> Optional[Dict[str, str]]:
+        if self.ctx is not None:
+            _ensure_hook()
+            self._token = _ctx.set(self.ctx)
+        return self.ctx
+
+    def __exit__(self, *exc) -> bool:
+        if self._token is not None:
+            try:
+                _ctx.reset(self._token)
+            except ValueError:
+                pass  # a generator finalised on another thread than it ran on
+            self._token = None
         return False
 
 
