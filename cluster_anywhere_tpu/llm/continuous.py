@@ -18,9 +18,22 @@ admitting or finishing requests never recompiles anything:
   compute (their lanes are garbage) but write only to their own cache rows
   (a key/value row past its position; a recurrent state, which is not frozen:
   it moves on with every step), which the next admit overwrites whole.
-- finish: a slot frees the moment its request hits max_new_tokens or eos;
-  the next step() can admit into it immediately — no head-of-line batching
-  barrier, which is the whole point vs static generate() batching.
+- a step is read one step behind: a row's next input is the token the step
+  before it made, which stays on the device (`prev`; an admitted slot's first
+  token is the host's, marked `fresh`), so step() dispatches step N+1 and only
+  then reads step N: the device has its next program queued while the host
+  reads, scatters and delivers.  A call returns the tokens of the step it
+  READ (and its own admits' first tokens) and counts that step.  A request
+  that reaches max_new_tokens with the step in flight is not dispatched again
+  (the host knows the count at dispatch) and keeps its slot until that step is
+  read; an eos is in the token, so the step in flight holds the row once more:
+  that row is computed late, dropped at its read (`late_rows`), never handed out.
+- finish: a slot frees the moment its request's last token (max_new_tokens or
+  eos) is read, or at its cancel; the next step() can admit into it
+  immediately, even while a step that still holds the old row runs (the
+  install is ordered after it on the device and overwrites the slot whole) —
+  no head-of-line batching barrier, which is the whole point vs static
+  generate() batching.
 
 A model that generates by blocks (`cfg.block_length` B > 1: an answer is made
 B positions at a time, by passes that fix the most confident masked positions)
@@ -37,6 +50,9 @@ goes through the same scheduler, cache and admit; what differs is the step:
   once every position before it is fixed.  An admit prefills the prompt's
   whole blocks and hands out nothing; the prompt's tail is the fixed part of
   the first block.
+- a pass is read by the call that dispatched it: what a slot feeds next (which
+  positions go out, an answer that ends inside a block, the move to the next
+  block) is the host's to decide from what it read, not the pass's output.
 - `fixed_at(request_id)` is the record of the pass of its block at which each
   served token was fixed, which the tokens do not say.
 
@@ -103,6 +119,20 @@ class Request:
     block_tail: List[tuple] = field(default_factory=list)
 
 
+@dataclass
+class _StepInFlight:
+    """A causal decode step that was dispatched and is not read yet."""
+    nxt: Any  # [S] int32 on the device: every slot's next token
+    touched: Any  # experts touched, on the device; None for a dense model
+    # (slot, request) of the rows the step holds, as the slots were at dispatch:
+    # a row's token goes to THAT request, or nowhere if it has ended since
+    rows: List[tuple]
+    # of the slots that held a request then (a request whose last token was in flight
+    # among them), as the sampler saw their knobs: how many sample, how many truncate
+    sample_rows: int
+    truncate_rows: int
+
+
 def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
     """Per-row sampling with TRACED temperature, top-k, and top-p (requests
     in one decode batch carry their own knobs; a static top_k would force
@@ -155,24 +185,28 @@ def _sample_first(logits, rng, temp, top_k, top_p):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _decode_step_rowpos(params, cache, ints, floats, rng, *, cfg):
+def _decode_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     """One token for every slot with PER-ROW cache positions.
-    ints: [4, S] int32, the rows tokens, pos, pads, top_ks, and for a mixture
-    of experts a fifth, live: 1 for the slots that hold a request (the other
-    rows are given no expert; a dense model is not told).  floats: [2, S]
-    float32, the rows temps, top_ps.  rng: the batcher's one key, split here
-    into the key it carries on and one key a row.  Returns (next_tokens [S],
-    cache, the carried key, experts touched): the last is the mean over the
-    layers of the experts that were given a row, None for a dense model.  The
-    cache is donated and is the layer loop's carry (models/generate.py), so
-    the step writes one row a slot and layer of [L,S,Tmax,KV,D] x2 in place
-    and copies nothing of that size:
+    ints: [5, S] int32, the rows tokens, pos, pads, top_ks, fresh, and for a
+    mixture of experts a sixth, live: 1 for the slots this step holds a request
+    in (the other rows are given no expert; a dense model is not told).
+    floats: [2, S] float32, the rows temps, top_ps.  prev: [S] int32, the step
+    before's own result, still on the device: a slot feeds its token of that
+    step, which the host may not have read yet, except where fresh is set (a
+    slot admitted since: its first token is the admit's, the host's row).  rng:
+    the batcher's one key, split here into the key it carries on and one key a
+    row.  Returns (next_tokens [S], cache, the carried key, experts touched):
+    the last is the mean over the layers of the experts that were given a row,
+    None for a dense model.  The cache is donated and is the layer loop's carry
+    (models/generate.py), so the step writes one row a slot and layer of
+    [L,S,Tmax,KV,D] x2 in place and copies nothing of that size:
     tests/test_chip_compile.py holds the chip's program to it
     (`test_decode_step_writes_the_cache_in_place`), tests/test_llm.py the
     rows it may change."""
-    tokens, pos, pads, top_ks, *live = ints
+    host_tokens, pos, pads, top_ks, fresh, *live = ints
     live = live[0] != 0 if live else None
     temps, top_ps = floats
+    tokens = jnp.where(fresh != 0, host_tokens, prev)
     keys = jax.random.split(rng, ints.shape[1] + 1)
     logits, cache, touched = decode_rows(params, cache, tokens, pos, pads, cfg, live)
     nxt = _sample_rowwise(logits, keys[1:], temps, top_ks, top_ps)
@@ -320,8 +354,9 @@ class ContinuousBatcher:
     """Iteration-level scheduler over a fixed slot pool (see module doc).
 
     Drive it with submit() + step() (one decode iteration), or pump() until
-    a request finishes.  step() returns per-request newly produced tokens,
-    enabling token streaming per request while others keep decoding."""
+    every request has finished.  step() returns per-request newly produced
+    tokens (of the step it read: one behind the step it dispatched), enabling
+    token streaming per request while others keep decoding."""
 
     def __init__(
         self,
@@ -379,12 +414,13 @@ class ContinuousBatcher:
         # live or not), and what an admit installs: 0 for attention alone
         self._ssm_slot_bytes = recurrent_state_bytes(self.cache) // slots
         self._ssm_step_bytes = 2 * slots * self._ssm_slot_bytes
-        # the decode step's per-slot inputs as its program takes them: two
-        # host arrays that go to the jitted call as they are (one dispatch, no
-        # eager upload), the scheduler's vectors their rows.  The host writes
-        # them only between steps (an admit, a cancel) and in llm.step.scatter,
-        # after the step's tokens are read back: the program has consumed them
-        # by then (on the CPU backend a host array may be aliased, not copied).
+        # the decode step's per-slot inputs as its program takes them: two host
+        # arrays, the scheduler's vectors their rows, written between steps (an
+        # admit, a cancel) and as a step is dispatched or read.  A causal step
+        # is handed a copy of each (one dispatch, no eager upload), since it may
+        # still be reading them when the scheduler writes next (on the CPU
+        # backend a host array may be aliased, not copied); a pass of blocks is
+        # read back before anything is written, and takes them as they are.
         self._floats = np.zeros((2, slots), np.float32)
         if self._block:
             # _pos: the cache slot of the block's first position; _blk_tokens,
@@ -394,9 +430,17 @@ class ContinuousBatcher:
             self._blk_tokens, self._blk_fixed = self._ints[3:3 + self._block], self._ints[3 + self._block:]
             self._topks = np.zeros(slots, np.int32)  # top-k is refused: the row stays 0 and is not uploaded
         else:
-            self._ints = np.zeros((5 if cfg.n_experts else 4, slots), np.int32)
-            # _pos: cache slot of the NEXT write; a mixture's fifth row: live slots
-            self._tokens, self._pos, self._pads, self._topks = self._ints[:4]
+            self._ints = np.zeros((6 if cfg.n_experts else 5, slots), np.int32)
+            # _tokens: an admit's first token, which _fresh marks for the slot's
+            # first step (a later step feeds the device's own, `_prev`); _pos:
+            # cache slot of the NEXT write, moved on as a step is dispatched; a
+            # mixture's sixth row: the slots the step holds
+            self._tokens, self._pos, self._pads, self._topks, self._fresh = self._ints[:5]
+            # the last dispatched step's tokens, on the device
+            self._prev = jnp.zeros(slots, jnp.int32)
+        # a causal step while it is dispatched and unread: each step() dispatches
+        # one and reads the one before (a pass of blocks is read where it is dispatched)
+        self._flight: Optional[_StepInFlight] = None
         self._temps, self._topps = self._floats
         self._topps[:] = 1.0
         # of the live slots, those that sample (temperature > 0) and those of them
@@ -436,6 +480,10 @@ class ContinuousBatcher:
             # decode steps that sorted the vocabulary: a live row sampled with
             # top-k or top-p; stays 0 under greedy or temperature-only traffic
             "sort_steps": 0,
+            # causal steps dispatched while the one before was unread (the device
+            # had its next program queued), and rows such a step computed for a
+            # request that had ended meanwhile (by eos or a cancel: dropped)
+            "steps_ahead": 0, "late_rows": 0,
         }
 
     # ------------------------------------------------------------- interface
@@ -487,57 +535,88 @@ class ContinuousBatcher:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.queue) or any(r is not None for r in self._by_slot)
+        return bool(self.queue) or any(r is not None for r in self._by_slot) or self._flight is not None
 
     def step(self) -> Dict[int, List[int]]:
-        """Admit into free slots, then decode one token on every live slot.
-        Returns {request_id: [new tokens this step]} — including the
-        prefill-sampled first token of requests admitted this step, so
-        streaming consumers see every token exactly once."""
+        """Admit into free slots, dispatch the next decode step of every live
+        slot, then read the step the call before dispatched.  Returns
+        {request_id: [new tokens]}: the tokens of the step that was read and
+        the prefill-sampled first token of requests admitted in this call, so
+        streaming consumers see every token exactly once.  (A model that
+        generates by blocks reads the pass it dispatched: `_step_blocks`.)"""
         sp = tracing.span("llm.step")
         with sp:
             out: Dict[int, List[int]] = {}
             self._admit(out)
-            live = [s for s, r in enumerate(self._by_slot) if r is not None]
-            sp.set(live=len(live))
-            if not live:
-                return out
             if self._block:
-                self._step_blocks(live, out, sp)
+                live = [s for s, r in enumerate(self._by_slot) if r is not None]
+                sp.set(live=len(live))
+                if live:
+                    self._step_blocks(live, out, sp)
                 return out
-            sp.set(sample_rows=self._sample_rows, truncate_rows=self._truncate_rows)
-            self.stats["sort_steps"] += self._truncate_rows > 0
-            with tracing.span("llm.step.upload"):
-                if self.cfg.n_experts:
-                    self._ints[4] = [r is not None for r in self._by_slot]
-            with tracing.span("llm.step.dispatch"):
-                nxt, self.cache, self._rng, touched = _decode_step_rowpos(
-                    self.params, self.cache, self._ints, self._floats, self._rng,
-                    cfg=self.cfg,
-                )
-            with tracing.span("llm.step.readback"):
-                nxt, touched = jax.device_get((nxt, touched))
-            if touched is not None:
-                sp.set(moe_rows=len(live), moe_experts_touched=float(touched))
-                self.stats["moe_assignments"] += len(live) * self.cfg.n_experts_per_tok
-            if self._ssm_step_bytes:
-                sp.set(ssm_state_bytes=self._ssm_step_bytes)
-                self.stats["ssm_state_bytes"] += self._ssm_step_bytes
-            self.stats["decode_steps"] += 1
-            self.stats["tokens_out"] += len(live)
-            with tracing.span("llm.step.scatter"):
-                for s in live:
-                    req = self._by_slot[s]
-                    tok = int(nxt[s])
-                    req.out_tokens.append(tok)
-                    out.setdefault(req.request_id, []).append(tok)
-                    self._tokens[s] = tok
-                    self._pos[s] += 1
-                    if len(req.out_tokens) >= req.max_new_tokens or (
-                        req.eos_id is not None and tok == req.eos_id
-                    ):
-                        self._finish(s, req)
+            landing = self._flight
+            self._flight = self._dispatch(landing)
+            ahead = landing is not None and self._flight is not None
+            sp.set(live=len(landing.rows) if landing is not None else 0, ahead=int(ahead))
+            self.stats["steps_ahead"] += ahead
+            if landing is not None:
+                self._land(landing, out, sp)
             return out
+
+    def _dispatch(self, landing: Optional[_StepInFlight]) -> Optional[_StepInFlight]:
+        """Dispatch one token more for every slot whose request has not reached
+        its length once `landing` (the step in flight, unread) is read; None
+        where there is no such slot.  A row's input is the device's own token
+        of the step before, so the host has nothing to wait for: a request that
+        ends by eos in `landing` is in this step too, one row computed late."""
+        flying = {r.request_id for _, r in landing.rows} if landing is not None else ()
+        rows = [
+            (s, r) for s, r in enumerate(self._by_slot)
+            if r is not None and len(r.out_tokens) + (r.request_id in flying) < r.max_new_tokens
+        ]
+        if not rows:
+            return None
+        slots = [s for s, _ in rows]
+        with tracing.span("llm.step.upload"):
+            if self.cfg.n_experts:
+                self._ints[5] = 0
+                self._ints[5, slots] = 1
+            ints, floats = self._ints.copy(), self._floats.copy()
+            self._fresh[:] = 0
+            self._pos[slots] += 1
+        with tracing.span("llm.step.dispatch"):
+            self._prev, self.cache, self._rng, touched = _decode_step_rowpos(
+                self.params, self.cache, ints, floats, self._prev, self._rng, cfg=self.cfg,
+            )
+        return _StepInFlight(self._prev, touched, rows, self._sample_rows, self._truncate_rows)
+
+    def _land(self, step: _StepInFlight, out: Dict[int, List[int]], sp: tracing.span) -> None:
+        """Read a dispatched step and hand each row's token to the request that
+        held the slot at dispatch, unless that request has ended since."""
+        with tracing.span("llm.step.readback"):
+            nxt, touched = jax.device_get((step.nxt, step.touched))
+        sp.set(sample_rows=step.sample_rows, truncate_rows=step.truncate_rows)
+        self.stats["sort_steps"] += step.truncate_rows > 0
+        if touched is not None:
+            sp.set(moe_rows=len(step.rows), moe_experts_touched=float(touched))
+            self.stats["moe_assignments"] += len(step.rows) * self.cfg.n_experts_per_tok
+        if self._ssm_step_bytes:
+            sp.set(ssm_state_bytes=self._ssm_step_bytes)
+            self.stats["ssm_state_bytes"] += self._ssm_step_bytes
+        self.stats["decode_steps"] += 1
+        with tracing.span("llm.step.scatter"):
+            for s, req in step.rows:
+                if req.done:
+                    self.stats["late_rows"] += 1
+                    continue
+                tok = int(nxt[s])
+                req.out_tokens.append(tok)
+                out.setdefault(req.request_id, []).append(tok)
+                self.stats["tokens_out"] += 1
+                if len(req.out_tokens) >= req.max_new_tokens or (
+                    req.eos_id is not None and tok == req.eos_id
+                ):
+                    self._finish(s, req)
 
     def _step_blocks(self, live: List[int], out: Dict[int, List[int]], sp: tracing.span) -> None:
         """One pass of every live slot's block (module doc): the step of a
@@ -843,7 +922,7 @@ class ContinuousBatcher:
                 np.float32([req.top_p]),
             )
             first = int(first)
-        self._tokens[slot] = first
+        self._tokens[slot], self._fresh[slot] = first, 1
         self._pos[slot] = next_pos  # next write lands after the prompt
         self._pads[slot] = pad
         self._topks[slot] = req.top_k

@@ -273,6 +273,10 @@ class ContinuousLLMServer:
                  "positions those passes fixed, served or past an answer's end"),
                 ("sort_steps", "ca_serve_sort_steps_total",
                  "decode steps that sorted the vocabulary: a live request sampled with top-k or top-p"),
+                ("steps_ahead", "ca_serve_steps_ahead_total",
+                 "causal decode steps dispatched while the step before was unread: the device had its next program queued"),
+                ("late_rows", "ca_serve_late_rows_total",
+                 "rows a decode step computed for a request that had ended while the step was in flight: dropped"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

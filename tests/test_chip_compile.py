@@ -192,14 +192,16 @@ def _compiled_decode_step(cfg, device, slots=32, t_max=768):
     params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
     cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
-    # a mixture of experts is told the live slots in a fifth row; a model that generates
+    # the causal step takes the step before's tokens from the device beside the host's rows
+    # (five, and a mixture of experts is told the live slots in a sixth); a model that generates
     # by blocks of B takes a block's B tokens and B fixed flags a slot, and its own step
-    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 5 if cfg.n_experts else 4
+    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 6 if cfg.n_experts else 5
     step = continuous._pass_step_rowpos if cfg.generates_blocks else continuous._decode_step_rowpos
     ints = on_chip(jax.ShapeDtypeStruct((rows, slots), jnp.int32))
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
+    prev = () if cfg.generates_blocks else (on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),)
     fn = lambda *a: step.__wrapped__(*a, cfg=cfg)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, key).compile()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, *prev, key).compile()
     return compiled, params, cache
 
 

@@ -412,10 +412,14 @@ def test_batcher_spans_form_the_tree_under_the_requests_trace(llm_spans, prefix_
     assert events and {e["trace"]["tid"] for e in events} == {TRACE["tid"]}
     steps = [e for e in events if e["name"] == "llm.step"]
     admits = [e for e in events if e["name"] == "llm.admit"]
-    # both admits ran inside the first step; each step decoded both slots
-    assert [e["live"] for e in steps] == [2, 2]
+    # both admits ran inside the first call, which dispatched the first step of both slots
+    # and had none to read; the second dispatched the second and read the first; the third
+    # read the second, which brought both requests to their length: no step for nothing
+    assert [e["live"] for e in steps] == [0, 2, 2] and [e["ahead"] for e in steps] == [0, 1, 0]
     inner = ["llm.step.dispatch", "llm.step.readback", "llm.step.scatter", "llm.step.upload"]
-    assert [n for n in _children(events, steps[1]) if n.startswith("llm.step.")] == inner
+    parts = [[n for n in _children(events, e) if n.startswith("llm.step.")] for e in steps]
+    assert parts == [inner[:1] + inner[3:], inner, inner[1:3]]
+    assert (cb.stats["decode_steps"], cb.stats["steps_ahead"], cb.stats["late_rows"]) == (2, 1, 0)
     assert [a["rid"] for a in admits] == [r.request_id for r in reqs]
     for i, a in enumerate(admits):
         assert a["prompt_len"] == 19 and a["queue_wait_ms"] >= 0.0
@@ -542,11 +546,12 @@ def _decode_step_program(cfg, slots, t_max):
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
     cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
     key = jax.eval_shape(lambda: jax.random.key(0))
-    ints = jax.ShapeDtypeStruct((5 if cfg.n_experts else 4, slots), jnp.int32)
+    ints = jax.ShapeDtypeStruct((6 if cfg.n_experts else 5, slots), jnp.int32)
     floats = jax.ShapeDtypeStruct((2, slots), jnp.float32)
+    prev = jax.ShapeDtypeStruct((slots,), jnp.int32)
     # a fresh function each time: jit keeps what it traced for one it has seen
     fn = lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg)
-    return fn, (params, cache, ints, floats, key)
+    return fn, (params, cache, ints, floats, prev, key)
 
 
 def _compile_program(which):
@@ -954,12 +959,23 @@ def test_the_batcher_holds_no_model_mathematics():
     assert called.count("prefill") == 1  # one pad-and-prefill for both admits
 
 
+def _rows_of_the_next_step(cb):
+    """[(slot, request)] that the batcher's next dispatch holds, counted by hand:
+    the slots whose request is short of its length even once the step in flight,
+    if it holds the request, has landed."""
+    flying = [r for _, r in cb._flight.rows] if cb._flight is not None else []
+    return [(s, r) for s, r in enumerate(cb._by_slot)
+            if r is not None and len(r.out_tokens) + sum(r is f for f in flying) < r.max_new_tokens]
+
+
 def test_sampled_streams_are_the_eager_split_and_sample():
     """Sampled streams keep their bits: with temperature, top-k and top-p set
     and requests admitted at different steps (so the admit's `split(rng)`
     interleaves with the step's), every decode token is what the eager formula
     gives: `rng, *keys = split(rng, S + 1)`, element 0 carried on, elements
-    1..S the rows' keys, `_sample_rowwise` over the step's logits."""
+    1..S the rows' keys, `_sample_rowwise` over the step's logits, a row's
+    input the step before's own token unless the slot was admitted since.  A
+    call hands out the step the call before dispatched."""
     import jax
     import jax.numpy as jnp
 
@@ -973,7 +989,8 @@ def test_sampled_streams_are_the_eager_split_and_sample():
 
     def eager_step(rng):
         """The next token of every slot and the carried key, op by op."""
-        tokens, pos, pads = (jnp.asarray(v) for v in (cb._tokens, cb._pos, cb._pads))
+        tokens = jnp.where(jnp.asarray(cb._fresh) != 0, jnp.asarray(cb._tokens), cb._prev)
+        pos, pads = jnp.asarray(cb._pos), jnp.asarray(cb._pads)
         x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
         attn = lambda x, bp, experts, cache, layer: (
             generate._block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, None, experts)[0], cache, None)
@@ -990,36 +1007,42 @@ def test_sampled_streams_are_the_eager_split_and_sample():
         3: dict(prompt_ids=[9, 9, 8], max_new_tokens=5),  # greedy, beside the sampled rows
         5: dict(prompt_ids=[6, 2, 6], max_new_tokens=7, temperature=1.0, top_k=3),  # waits for a slot
     }
-    rng, reqs, compared = cb._rng, [], 0
-    for i in range(14):
+    rng, reqs, compared, flying = cb._rng, [], 0, {}
+    for i in range(16):
         if i in arrivals:
             reqs.append(cb.submit(arrivals[i].pop("prompt_ids"), **arrivals[i]))
         admitted = cb.stats["admitted"]
         cb._admit()  # as `step` begins; its own admit then finds the queue as this leaves it
         for _ in range(cb.stats["admitted"] - admitted):
             rng, _ = jax.random.split(rng)
-        live = {r.request_id: s for s, r in enumerate(cb._by_slot) if r is not None}
-        if live:
+        rows = {r.request_id: s for s, r in _rows_of_the_next_step(cb)}
+        if rows:
             rng, want = eager_step(rng)  # before the step: it donates the cache
         out = cb.step()
-        assert sorted(out) == sorted(live)
-        for rid, slot in live.items():
-            assert out[rid] == [want[slot]], (i, rid)
+        # the call dispatched `rows` and handed out the step in flight before it
+        assert sorted(out) == sorted(flying)
+        for rid, (slot, token) in flying.items():
+            assert out[rid] == [token], (i, rid)
             compared += 1
+        flying = {rid: (slot, want[slot]) for rid, slot in rows.items()}
     assert all(r.done for r in reqs) and compared == sum(r.max_new_tokens - 1 for r in reqs)
+    assert not cb.has_work and cb.stats["late_rows"] == 0
     sampled = [r for r in reqs if r.temperature > 0]
     assert any(len(set(r.out_tokens)) > 2 for r in sampled)
     np.testing.assert_array_equal(jax.random.key_data(cb._rng), jax.random.key_data(rng))
 
 
 def test_the_step_sorts_only_while_a_truncating_request_lives(llm_spans):
-    """The sampler's sorts follow the live rows' knobs: `llm.step` says how many
-    live rows sample and how many of those truncate, `stats["sort_steps"]`
-    counts the steps in which one did, and a slot that frees, by its request's
-    end or its cancel, asks nothing from then on (temperature 0, top-k 0, top-p
-    1.0): a dense model's step is not told which rows are live, so a finished
-    top-p request's knobs left in its slot would keep every later step
-    sorting.  A stale top-k or top-p beside temperature 0 never counts."""
+    """The sampler's sorts follow the knobs of the slots that hold a request:
+    `llm.step` says of the step it read how many rows it held, and how many of
+    the held slots sampled and how many of those truncated as it was dispatched;
+    `stats["sort_steps"]` counts the steps in which one did, and a slot that
+    frees, by its request's end or its cancel, asks nothing from then on
+    (temperature 0, top-k 0, top-p 1.0): a dense model's step is not told which
+    rows are live, so a finished top-p request's knobs left in its slot would
+    keep every later step sorting.  A request whose last token is in flight
+    holds its slot until that step is read, so its knobs are in one step more
+    than its rows are.  A stale top-k or top-p beside temperature 0 never counts."""
     import jax
 
     from cluster_anywhere_tpu.llm import ContinuousBatcher
@@ -1044,14 +1067,15 @@ def test_the_step_sorts_only_while_a_truncating_request_lives(llm_spans):
             for knobs in arrivals.get(i, ()):
                 reqs[i] = cb.submit([3, 1, 4, 1, 5], **knobs)  # the step's last: 9 and 13 are asked for below
             if i == 12:
-                assert cb.cancel(reqs[9].request_id)
+                assert cb.cancel(reqs[9].request_id)  # while the step of call 11 holds its row
             cb._admit()  # as `step` begins; its own admit then finds the queue empty
-            live = [r for r in cb._by_slot if r is not None]
-            if not live:
-                continue
-            want.append((len(live), sum(r.temperature > 0 for r in live), sum(map(truncates, live))))
+            holding = [r for r in cb._by_slot if r is not None]
+            rows = _rows_of_the_next_step(cb)
+            if rows:
+                want.append((len(rows), sum(r.temperature > 0 for r in holding), sum(map(truncates, holding))))
             cb.step()
-            assert cb.stats["sort_steps"] == sum(w[2] > 0 for w in want), i
+            read = want[:len(want) - (cb._flight is not None)]
+            assert cb.stats["decode_steps"] == len(read) and cb.stats["sort_steps"] == sum(w[2] > 0 for w in read), i
             assert all(free(s) for s, r in enumerate(cb._by_slot) if r is None), i
     finally:
         tracing.pop_execution(token)
@@ -1059,10 +1083,15 @@ def test_the_step_sorts_only_while_a_truncating_request_lives(llm_spans):
     assert (cb._sample_rows, cb._truncate_rows) == (0, 0)
     steps = [e for e in llm_spans() if e["name"] == "llm.step" and e["live"]]
     assert [(e["live"], e["sample_rows"], e["truncate_rows"]) for e in steps] == want
-    # the two truncating requests' lives and nothing else: 2 steps (the first of three
-    # tokens is the admit's), then the steps 9, 10, 11
-    assert [w[2] for w in want].count(1) == 5 == cb.stats["sort_steps"] and max(w[2] for w in want) == 1
+    # the two truncating requests' lives and nothing else.  The first: the steps of calls 4
+    # and 5 hold its row (the first of three tokens is the admit's) and the step of call 6
+    # its knobs, while its last token is in flight.  The second: the steps of calls 9, 10, 11;
+    # the cancel before call 12 resets the knobs at once, and the row of step 11 is dropped
+    at = [i for i, w in enumerate(want) if w[2]]
+    assert at == [4, 5, 6, 9, 10, 11] and cb.stats["sort_steps"] == 6 and max(w[2] for w in want) == 1
+    assert [w[0] for w in want[4:7]] == [4, 4, 3] and cb.stats["late_rows"] == 1
     assert max(w[1] for w in want) == 2 and reqs[13].done and len(reqs[13].out_tokens) == 1
+    assert len(reqs[9].out_tokens) == 3 and cb.stats["tokens_out"] == 16 + 14 + 9 + 3 + 3 + 1
     import inspect
 
     from cluster_anywhere_tpu.llm import serve_llm
@@ -1465,6 +1494,166 @@ def test_prefix_cache_hit_and_miss_are_bit_identical_with_a_recurrent_state(llm_
     # an admit installs one slot's recurrent state; a step reads and writes both slots'
     slot_bytes = 4 * (3 * 64 * 4 + 64 * 8 * 4)
     assert {e["ssm_state_bytes"] for e in admits} == {slot_bytes}
-    assert {e["ssm_state_bytes"] for e in events if e["name"] == "llm.step"} == {2 * 2 * slot_bytes}
+    # (the call that read it says so: the first call of the two admits dispatched one and read none)
+    steps = [e for e in events if e["name"] == "llm.step"]
+    assert [e["live"] for e in steps] == [0] + [2] * 5 and "ssm_state_bytes" not in steps[0]
+    assert {e["ssm_state_bytes"] for e in steps[1:]} == {2 * 2 * slot_bytes}
     for prompt, req in ((prompts[0], hit), (prompts[1], other)):
         assert list(req.out_tokens) == _alone(params, cfg, prompt, 6)
+
+
+# -- the causal step reads one step behind ------------------------------------------
+
+
+def _watch_dispatches(monkeypatch):
+    """Records what every `_decode_step_rowpos` call was handed: [(ints, a copy
+    of it as it was, floats, a copy)]."""
+    from cluster_anywhere_tpu.llm import continuous
+
+    handed, real = [], continuous._decode_step_rowpos
+
+    def spy(params, cache, ints, floats, prev, rng, *, cfg):
+        handed.append((ints, ints.copy(), floats, floats.copy()))
+        return real(params, cache, ints, floats, prev, rng, cfg=cfg)
+
+    monkeypatch.setattr(continuous, "_decode_step_rowpos", spy)
+    return handed
+
+
+@pytest.mark.parametrize("model", [_TINY, _TINY_MIXTURE, _TINY_HYBRID], ids=["dense", "mixture", "hybrid"])
+def test_a_batcher_that_reads_one_step_behind_answers_as_generate_does(model, monkeypatch):
+    """Requests of unlike lengths through three slots, each step dispatched
+    before the one before is read: one ends by eos in mid-stream (the step in
+    flight holds its row once more: computed late, dropped), a waiting request
+    takes its slot at the very next call, while that late step still runs; one
+    is cancelled while a step holds its row, and its slot is taken likewise; one
+    fills its cache rows to the last (`bucket + max_new_tokens == t_max`).
+    Every greedy token is `generate()`'s, one by one; nothing is handed out past
+    an eos, a length or a cancel; `tokens_out` is what was handed out; and no
+    step, late ones included, was given a position outside the cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models.generate import generate
+
+    cfg, params = _float32_model(model)
+    t_max = 32
+    rng = np.random.default_rng(7)
+    answer = lambda prompt, n: np.asarray(generate(
+        params, jnp.asarray([prompt], jnp.int32), jax.random.key(0), cfg=cfg, max_new_tokens=n))[0].tolist()
+    prompt = lambda n: rng.integers(1, cfg.vocab_size, n).tolist()
+    # a prompt whose greedy answer brings a token it has not held before as its 3rd to 6th:
+    # that token as the request's eos ends it there, in mid-stream
+    for _ in range(20):
+        stopped = prompt(4)
+        full = answer(stopped, 10)
+        at = next((j for j in range(2, 6) if full[j] not in full[:j]), None)
+        if at is not None:
+            break
+    assert at is not None
+    handed = _watch_dispatches(monkeypatch)
+    cb = ContinuousBatcher(params, cfg, slots=3, t_max=t_max, prefill_buckets=(8,))
+    sent = {}  # name -> (request, the tokens it is to be handed)
+
+    def submit(name, ids, n, keep=None, **kw):
+        sent[name] = (cb.submit(ids, max_new_tokens=n, **kw), answer(ids, n)[:keep])
+        return sent[name][0]
+
+    edge = submit("edge", prompt(5), 24)  # admitted in bucket 8: 8 + 24 is the cache's length
+    assert cb._bucket(5, 24) + 24 == t_max
+    stops = submit("stops", stopped, 10, keep=at + 1, eos_id=full[at])
+    submit("short", prompt(6), 4)
+    submit("waits", prompt(3), 7)  # these two take the first two slots that free
+    gone = submit("gone", prompt(7), 12, keep=3)
+    streams, calls, ahead, took_over = {}, 0, 0, {}
+    while cb.has_work:
+        landing, slots_before = cb._flight, list(cb._by_slot)
+        out = cb.step()
+        calls += 1
+        ahead += landing is not None and cb._flight is not None
+        for rid, toks in out.items():
+            streams.setdefault(rid, []).extend(toks)
+        for name, late in (("stops", stops), ("gone", gone)):
+            # the call after its end: its slot was free as the call began, the step then in
+            # flight still held its row, and the call's admit put the next request into the slot
+            if (late.done and slots_before[late.slot] is None and landing is not None
+                    and any(r is late for _, r in landing.rows)):
+                took_over.setdefault(name, cb._by_slot[late.slot])
+        if len(gone.out_tokens) == 3 and not gone.done:
+            assert any(r is gone for _, r in cb._flight.rows)  # a step holds its row: computed for nothing
+            assert cb.cancel(gone.request_id)
+            submit("last", prompt(2), 5)
+    assert calls < 60 and all(r.done for r, _ in sent.values())
+    for name, (req, want) in sent.items():
+        assert req.out_tokens == want and streams[req.request_id] == want, name
+    assert stops.out_tokens[-1] == full[at] and len(stops.out_tokens) < 10
+    assert cb.stats["tokens_out"] == sum(len(t) for t in streams.values())
+    # each of the two was in one step more than it was handed tokens of, and its slot was
+    # given away while that step ran
+    assert cb.stats["late_rows"] == 2 and cb.stats["cancelled"] == 1
+    assert took_over["stops"] is not None and took_over["gone"] is sent["last"][0]
+    # every call but the first read a step, and every call but the last dispatched one before it read
+    assert cb.stats["decode_steps"] == len(handed) == calls - 1 and cb.stats["steps_ahead"] == ahead == calls - 2
+    # every position any step was given lies in the cache; the request that fills its rows
+    # was last dispatched at the last but one, and its idle row rests on the last
+    assert all(0 <= was[1].min() and was[1].max() < t_max for _, was, _, _ in handed)
+    assert max(was[1][edge.slot] for _, was, _, _ in handed) == t_max - 2 and cb._pos[edge.slot] == t_max - 1
+
+
+def test_a_step_is_dispatched_before_the_step_before_it_is_read(llm_spans, monkeypatch):
+    """The order is held: in a call that has a step in flight and dispatches
+    another (`ahead=1`), `llm.step.dispatch` closes before `llm.step.readback`
+    opens; the arrays a step was handed are its own, unchanged when the
+    scheduler has written its vectors again; and `steps_ahead`, `late_rows` and
+    the two series they are shipped as count what the calls below come to."""
+    from cluster_anywhere_tpu.llm import serve_llm
+    from cluster_anywhere_tpu.util import metrics, tracing
+
+    handed = _watch_dispatches(monkeypatch)
+    cb = _tiny_batcher()
+    token = tracing.push_execution(TRACE)
+    try:
+        a, b = cb.submit([1, 2, 3], max_new_tokens=5), cb.submit([4, 5], max_new_tokens=3)
+        outs = [cb.step() for _ in range(3)]
+        # call 0 admitted both and dispatched step 0; call 1 dispatched step 1 and read step 0;
+        # call 2 dispatched step 2 for `a` alone (`b` reaches its length with step 1) and read step 1
+        assert [sorted(map(len, o.values())) for o in outs] == [[1, 1], [1, 1], [1, 1]]
+        assert b.done and not a.done and cb._flight.rows == [(a.slot, a)]
+        assert cb.cancel(a.request_id)  # while step 2 holds its row
+        assert cb.has_work and cb.step() == {} and not cb.has_work  # call 3 read step 2 and dropped the row
+        assert cb.step() == {}  # nothing in flight, nothing live: no step
+    finally:
+        tracing.pop_execution(token)
+    assert (len(a.out_tokens), len(b.out_tokens)) == (3, 3)
+    counted = dict(decode_steps=3, steps_ahead=2, late_rows=1, tokens_out=6, finished=1, cancelled=1)
+    assert {k: cb.stats[k] for k in counted} == counted
+    events = llm_spans()
+    steps = [e for e in events if e["name"] == "llm.step"]
+    assert [(e["live"], e["ahead"]) for e in steps] == [(0, 0), (2, 1), (2, 1), (1, 0), (0, 0)]
+    part = lambda step, name: [e for e in events if e["name"] == name and e["trace"].get("psid") == step["trace"]["sid"]]
+    for step, dispatched, read in zip(steps, (1, 1, 1, 0, 0), (0, 1, 1, 1, 0)):
+        dispatch, readback = part(step, "llm.step.dispatch"), part(step, "llm.step.readback")
+        assert (len(dispatch), len(readback)) == (dispatched, read)
+        if step["ahead"]:
+            closes = dispatch[0]["mono"] + (dispatch[0]["end"] - dispatch[0]["start"])
+            assert closes <= readback[0]["mono"]
+    # three dispatches, each handed arrays of its own: the scheduler moved its positions on and
+    # took the fresh marks back right after each, and what the step was handed still reads as it did
+    assert len(handed) == 3
+    for ints, was, floats, floats_was in handed:
+        assert not np.shares_memory(ints, cb._ints) and not np.shares_memory(floats, cb._floats)
+        assert np.array_equal(ints, was) and np.array_equal(floats, floats_was)
+    fresh, pos = [h[1][4].tolist() for h in handed], [h[1][1].tolist() for h in handed]
+    assert fresh == [[1, 1], [0, 0], [0, 0]] and cb._fresh.tolist() == [0, 0]
+    assert pos[1] == [p + 1 for p in pos[0]] and pos[2][a.slot] == pos[0][a.slot] + 2
+    # shipped beside the batcher's other counters, as deltas of `cb.stats`
+    shipped = []
+    monkeypatch.setattr(metrics.Counter, "inc", lambda self, value=1.0, tags=None: shipped.append((self.name, value)))
+    server = object.__new__(serve_llm.ContinuousLLMServer)  # the method's own needs, no pump's thread
+    server.cb, server._metrics_synced = cb, {}
+    server.engine_device = {"count": 1, "platform": "cpu", "device_kind": "cpu"}
+    server._sync_engine_metrics()
+    shipped = dict(shipped)
+    assert shipped["ca_serve_steps_ahead_total"] == 2 and shipped["ca_serve_late_rows_total"] == 1
+    assert shipped["ca_serve_decode_steps_total"] == 3

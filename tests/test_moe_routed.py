@@ -208,10 +208,12 @@ def test_decode_step_is_told_which_slots_are_live():
     floats = jnp.asarray([(0.0,) * 4, (1.0,) * 4], jnp.float32)  # temps, top_ps
 
     def step(tokens, live, params=params, cfg=cfg):
-        # the int32 input's rows: tokens, pos, pads, top_ks and, when told, live
-        rows = [tokens, (2, 0, 0, 0), (0,) * 4, (0,) * 4] + ([live] if live else [])
+        # the int32 input's rows: tokens, pos, pads, top_ks, fresh (every slot feeds the
+        # host's token, not the step before's) and, when told, live
+        rows = [tokens, (2, 0, 0, 0), (0,) * 4, (0,) * 4, (1,) * 4] + ([live] if live else [])
         nxt, _, _, touched = continuous._decode_step_rowpos.__wrapped__(
-            params, cache, jnp.asarray(rows, jnp.int32), floats, jax.random.key(0), cfg=cfg)
+            params, cache, jnp.asarray(rows, jnp.int32), floats, jnp.zeros(4, jnp.int32),
+            jax.random.key(0), cfg=cfg)
         return nxt, touched
 
     nxt, touched = step((5, 9, 11, 3), (1, 0, 0, 0))
