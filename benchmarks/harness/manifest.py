@@ -3,8 +3,9 @@
 A cell is `cells/<cell>.json`; it names a configuration (`configs/<config>.json`)
 and a traffic mix (`traffic/<mix>.json`).  A per-layer metric is
 `layer_metrics/<name>.json`, which names its reader
-(`layer_metrics/readers/<reader>.py`).  A configuration names its
-architecture's file (`references/<reference>.py`: the plain reference, the
+(`layer_metrics/readers/<reader>.py`) and where it is read: the `cells` it
+lists, or the `family` that a cell's file joins through its `families`.  A
+configuration names its architecture's file (`references/<reference>.py`: the plain reference, the
 mapping to the program's fields, the scope names, the counts, the tolerances).
 A later PR adds a cell, a mix, a metric, a configuration or an architecture by
 adding such files and entries in BENCHMARK.json: nothing here lists them.
@@ -67,17 +68,30 @@ def load_cell(name: str, bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
 
 
 def layer_metrics_for(cell: str, bench_dir: str = BENCH_DIR) -> List[Dict[str, Any]]:
-    """Every per-layer metric file that lists this cell (or lists none: all
-    cells), sorted by name."""
-    out = []
+    """Every per-layer metric file that is the cell's, sorted by name: one that
+    lists the cell under `cells`, one whose `family` is among the `families`
+    of the cell's own file (so a new cell joins a family by its own file, and
+    no file that is there changes), and one with neither key (all cells)."""
+    cell_file = _load(os.path.join(bench_dir, "cells", check_name(cell) + ".json"))
+    families = {check_name(f) for f in cell_file.get("families", [])}
+    out, known = [], set()
     d = os.path.join(bench_dir, "layer_metrics")
     for fn in sorted(os.listdir(d)):
         if not fn.endswith(".json"):
             continue
         m = _load(os.path.join(d, fn))
         m["name"] = fn[: -len(".json")]
-        if "cells" not in m or cell in m["cells"]:
+        if "cells" in m and "family" in m:
+            raise ValueError(f"layer_metrics/{fn} has both `cells` and `family`")
+        if "family" in m:
+            known.add(check_name(m["family"]))
+            mine = m["family"] in families
+        else:
+            mine = "cells" not in m or cell in m["cells"]
+        if mine:
             out.append(m)
+    if families - known:
+        raise ValueError(f"cells/{cell}.json names families no metric has: {sorted(families - known)}")
     return out
 
 

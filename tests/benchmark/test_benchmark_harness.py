@@ -519,6 +519,124 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_only(tmp_path):
     assert all(p.read_bytes() == data for p, data in before.items())  # no file was edited
 
 
+# -- families: one entry a metric, a cell joins from its own file (PR 38) -------
+
+with open(os.path.join(DATA, "per_layer_renames_pr38.json")) as _f:
+    RENAMES = json.load(_f)
+SAME_KEYS = ("reader", "args", "unit", "layer", "moves", "source")
+# the committed files' metrics by cell and name, read once for the 130 cases below
+METRICS_OF = {cell: {m["name"]: m for m in manifest.layer_metrics_for(cell)} for cell in CELLS}
+
+
+@pytest.mark.parametrize("row", RENAMES, ids=[r["name"] for r in RENAMES])
+def test_a_retired_name_is_read_under_its_new_one(row):
+    """PR 38 retired 58 per-cell copies.  In the cell each was read in, the name
+    the table gives now reads the same quantity: the same reader, arguments,
+    unit, layer, moved metric and source that the retired file had."""
+    assert set(row) <= {"name", "cell", "now", *SAME_KEYS}
+    by_name = METRICS_OF[row["cell"]]
+    assert row["name"] not in by_name and row["name"] not in {m["name"] for m in DOC["per_layer"]}
+    now = by_name[row["now"]]
+    assert {k: now.get(k) for k in SAME_KEYS} == {k: row.get(k) for k in SAME_KEYS}
+    # no second file of the cell reads the same thing
+    assert [m["name"] for m in by_name.values()
+            if (m["reader"], m.get("args")) == (row["reader"], row.get("args"))] == [row["now"]]
+
+
+def test_the_rename_table_is_whole():
+    assert len(RENAMES) == 58 == len({r["name"] for r in RENAMES})
+    assert {r["cell"] for r in RENAMES} == {"olmoe-closed6", "jamba-closed6", "sdar-closed6"}
+    assert len({r["now"] for r in RENAMES}) == 15 + 1 + 2 + 5
+    # a metric is one entry and one file (74 of each when the copies went), and the list has room
+    files = [f for f in os.listdir(os.path.join(manifest.BENCH_DIR, "layer_metrics")) if f.endswith(".json")]
+    assert 74 <= len(DOC["per_layer"]) == len(files) <= 128
+
+
+@pytest.mark.parametrize("entry", DOC["per_layer"], ids=[m["name"] for m in DOC["per_layer"]])
+def test_an_entrys_workloads_are_what_the_manifest_resolves(entry):
+    resolved = [c for c in CELLS if entry["name"] in METRICS_OF[c]]
+    assert entry.get("workloads", CELLS) == resolved
+    assert os.path.isfile(os.path.join(manifest.BENCH_DIR, "layer_metrics", entry["name"] + ".json"))
+
+
+def _toy_bench(tmp_path, metrics, families=None):
+    """A benchmarks/ of two cells and the given metric files."""
+    bench = tmp_path / "benchmarks"
+    for d in ("cells", "layer_metrics"):
+        (bench / d).mkdir(parents=True)
+    (bench / "cells" / "one.json").write_text(json.dumps(
+        {"config": "c", "traffic": "t", "chips": 1, **({} if families is None else {"families": families})}))
+    (bench / "cells" / "two.json").write_text(json.dumps({"config": "c", "traffic": "t", "chips": 1}))
+    for name, keys in metrics.items():
+        (bench / "layer_metrics" / (name + ".json")).write_text(json.dumps(dict(unit="ms", reader="r", **keys)))
+    return str(bench)
+
+
+@pytest.mark.parametrize("case", ["cells", "family", "neither", "both", "unknown_family", "bad_name"])
+def test_where_a_metric_is_read(tmp_path, case):
+    names = lambda cell, bench: [m["name"] for m in manifest.layer_metrics_for(cell, bench)]
+    if case == "cells":
+        bench = _toy_bench(tmp_path, {"b": {"cells": ["one"]}, "a": {"cells": ["one", "two"]}, "c": {"cells": ["two"]}})
+        assert names("one", bench) == ["a", "b"] and names("two", bench) == ["a", "c"]
+    elif case == "family":  # the cell's own file enrols it; a cell without the key joins none
+        bench = _toy_bench(tmp_path, {"a.x": {"family": "x"}, "b.y": {"family": "y"}, "c.x": {"family": "x"}},
+                           families=["x"])
+        assert names("one", bench) == ["a.x", "c.x"] and names("two", bench) == []
+    elif case == "neither":
+        bench = _toy_bench(tmp_path, {"all": {}, "a.x": {"family": "x"}}, families=[])
+        assert names("one", bench) == ["all"] == names("two", bench)
+    elif case == "both":
+        bench = _toy_bench(tmp_path, {"a.x": {"family": "x", "cells": ["one"]}}, families=["x"])
+        with pytest.raises(ValueError, match=r"a\.x\.json has both `cells` and `family`"):
+            manifest.layer_metrics_for("two", bench)
+    elif case == "unknown_family":
+        bench = _toy_bench(tmp_path, {"a.x": {"family": "x"}}, families=["x", "z"])
+        with pytest.raises(ValueError, match=r"cells/one\.json names families no metric has: \['z'\]"):
+            manifest.layer_metrics_for("one", bench)
+        assert names("two", bench) == []
+    else:
+        bench = _toy_bench(tmp_path, {"a.x": {"family": "x"}}, families=["x", "no such/name"])
+        with pytest.raises(ValueError, match="not a valid name: 'no such/name'"):
+            manifest.layer_metrics_for("one", bench)
+        bench = _toy_bench(tmp_path / "2", {"a": {"family": "x y"}})
+        with pytest.raises(ValueError, match="not a valid name: 'x y'"):
+            manifest.layer_metrics_for("two", bench)
+
+
+def test_a_closed_cell_joins_the_families_from_its_own_file(tmp_path):
+    """What the next `model_config` PR depends on: a fifth closed serving cell
+    reads the shared sixteen by naming their families in its own new file, and
+    brings one metric of its own; no file that was there changed."""
+    bench = tmp_path / "benchmarks"
+    shutil.copytree(manifest.BENCH_DIR, bench, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    (bench / "cells" / "toy-closed6.json").write_text(json.dumps(
+        {"config": "mistral-7b-v0.3-serve1", "traffic": "chat-closed", "chips": 1, "callers": 6,
+         "families": ["closed", "causal"], "why": "a fifth closed cell"}))
+    (bench / "layer_metrics" / "latent_share.toy.json").write_text(json.dumps(
+        {"unit": "%", "layer": "decode/prefill programs (models/generate.py)", "moves": "serve_out_tok_s",
+         "source": "device_trace", "cells": ["toy-closed6"], "reader": "scope_share",
+         "args": {"scopes": ["attn.latent"]}}))
+    got = {m["name"]: m for m in manifest.layer_metrics_for("toy-closed6", str(bench))}
+    chat = {m["name"]: m for m in manifest.layer_metrics_for("chat-closed6", str(bench))}
+    assert list(got) == sorted(got)
+    shared = {n for n, m in got.items() if m.get("family") == "closed"}
+    assert {n[: -len(".closed")] for n in shared} >= {
+        "admit_ms_mean", "admit_prefill_ms_mean", "submit_lock_wait_ms_mean", "step_upload_ms_p50",
+        "decode_step_ms_p50", "gap_p50_s", "gap_p99_s", "ttft_p50_s", "gen_late_p99_ms", "front_overhead_p50_ms",
+        "device_idle", "idle_in.admit", "idle_in.readback", "idle_in.step_host", "idle_in.between_steps",
+        "stream_first_token_ms_p50", "stream_write_wait_ms_mean"}
+    assert "decode_batch_mean.closed" in got and got["decode_batch_mean.closed"]["family"] == "causal"
+    assert {"latent_share.toy", "hbm_peak_gb"} <= set(got)
+    # they are the files chat-closed6 reads, the same readers with the same arguments
+    assert {n: m for n, m in got.items() if n != "latent_share.toy"} == chat
+    assert "latent_share.toy" not in chat
+    # the cells that were there read what they read
+    for cell in CELLS:
+        assert [m["name"] for m in manifest.layer_metrics_for(cell, str(bench))] == list(METRICS_OF[cell])
+    assert all(p.read_bytes() == data for p, data in before.items())  # no file was edited
+
+
 def test_a_configuration_of_another_architecture_is_added_as_files_only(tmp_path):
     """What a `model_config` PR does: a reference, a configuration of other
     widths that names it, a cell and a metric over its own scope, as new files in

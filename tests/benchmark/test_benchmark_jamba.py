@@ -232,15 +232,17 @@ def test_the_mixer_reader_counts_the_steps_bytes_over_the_time_under_the_scopes(
     assert read(dict(ctx, program_trace=unscoped)) is None
     assert read(dict(ctx, program_trace=None)) is None
     assert read(dict(ctx, cell=manifest.load_cell("chat-closed6"))) is None
-    # the cell reads chat-closed6's sixteen under its own suffix, and seven of its own
+    # the cell reads what chat-closed6 reads through the families `closed` and `causal`, the
+    # two of `attn`, and five of its own
     names = {m["name"] for m in manifest.layer_metrics_for(CELL)}
     closed = {m["name"] for m in manifest.layer_metrics_for("chat-closed6") if m["name"].endswith(".closed")}
-    assert len(closed) == 16 and {n[: -len("closed")] + "ssm" for n in closed} <= names
-    assert {n for n in names if n.endswith(".ssm")} - {n[: -len("closed")] + "ssm" for n in closed} == {
-        "attn_share.ssm", "cache_share.ssm", "ffn_share.ssm", "ssm_proj_share.ssm",
-        "ssm_scan_share.ssm", "ssm_state_bytes.ssm", "ssm_hbm_share.ssm"}
+    assert len(closed) >= 16 and closed <= names
+    assert names - closed >= {
+        "attn_share.closed", "cache_share.closed", "ffn_share.ssm", "ssm_proj_share.ssm",
+        "ssm_scan_share.ssm", "ssm_state_bytes.ssm", "ssm_hbm_share.ssm", "hbm_peak_gb"}
     listed = {m["name"]: m for m in manifest.load_manifest()["per_layer"]}
     assert all(listed[n]["workloads"] == [CELL] for n in names if n.endswith(".ssm"))
+    assert all(CELL in listed[n]["workloads"] for n in names - {"hbm_peak_gb"})
 
 
 def test_serve_rehearsal_of_jamba_closed6():
@@ -274,11 +276,11 @@ def test_serve_rehearsal_of_jamba_closed6():
     # the batcher counted the recurrent state it moved: the replica ran the state-space layers
     assert ctx["replica"]["stats"]["ssm_state_bytes"] > 0
     layer = manifest.read_layer_metrics(CELL, ctx)
-    assert layer["decode_batch_mean.ssm"]["value"] >= 1.0
-    assert {n + ".ssm" for n in ("gen_late_p99_ms", "front_overhead_p50_ms", "admit_ms_mean",
+    assert layer["decode_batch_mean.closed"]["value"] >= 1.0
+    assert {n + ".closed" for n in ("gen_late_p99_ms", "front_overhead_p50_ms", "admit_ms_mean",
                                  "decode_step_ms_p50", "gap_p99_s", "ttft_p50_s")} <= set(layer)
     # no trace: the readers of the trace return nothing
-    assert not {"device_idle.ssm", "ssm_scan_share.ssm", "ssm_hbm_share.ssm", "ssm_state_bytes.ssm",
+    assert not {"device_idle.closed", "ssm_scan_share.ssm", "ssm_hbm_share.ssm", "ssm_state_bytes.ssm",
                 "ffn_share.ssm"} & set(layer)
     with pytest.raises(RuntimeError, match="need 1 tpu"):
         bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)

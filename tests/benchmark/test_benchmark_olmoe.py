@@ -338,11 +338,11 @@ def test_serve_rehearsal_of_olmoe_closed6():
     # the batcher counted the routed assignments: the replica ran the expert path
     assert ctx["replica"]["stats"]["moe_assignments"] > 0
     layer = manifest.read_layer_metrics(CELL, ctx)
-    assert layer["decode_batch_mean.moe"]["value"] >= 1.0
-    assert {n + ".moe" for n in ("gen_late_p99_ms", "front_overhead_p50_ms", "admit_ms_mean",
+    assert layer["decode_batch_mean.closed"]["value"] >= 1.0
+    assert {n + ".closed" for n in ("gen_late_p99_ms", "front_overhead_p50_ms", "admit_ms_mean",
                                  "decode_step_ms_p50", "gap_p99_s", "ttft_p50_s")} <= set(layer)
     # no trace: the readers of the trace return nothing
-    assert not {"device_idle.moe", "moe_experts_share.moe", "experts_hbm_share.moe",
+    assert not {"device_idle.closed", "attn_share.closed", "moe_experts_share.moe", "experts_hbm_share.moe",
                 "experts_touched_mean.moe", "moe_dispatch_share.moe"} & set(layer)
     with pytest.raises(RuntimeError, match="need 1 tpu"):
         bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)
