@@ -77,13 +77,26 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, decode_rows, init_cache, install_rows, prefill, recurrent_state_bytes,
+    _nucleus_mask, cache_bytes_per_token, decode_rows, init_cache, install_rows, prefill,
+    recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
 from ..util import tracing
 
 
 PREFILL_BUCKETS = (64, 128, 256)  # padded prompt lengths: one prefill program each
+
+
+def prefill_buckets_for(max_prompt_len: int) -> tuple:
+    """The bucket ladder of a deployment that admits prompts up to
+    `max_prompt_len`: PREFILL_BUCKETS, continued by powers of two (512, 1024,
+    2048, ...) below that length, and the length itself.  A 700-token prompt of
+    a 4,096-token deployment prefills 1,024 positions, not 4,096; a deployment
+    of 512 has the four programs 64, 128, 256, 512."""
+    ladder = list(PREFILL_BUCKETS)
+    while 2 * ladder[-1] < max_prompt_len:
+        ladder.append(2 * ladder[-1])
+    return tuple(b for b in ladder if b < max_prompt_len) + (max_prompt_len,)
 _NO_TRUNCATION = (
     "{what}: a replica that generates by blocks of {b} chooses each position's token and its "
     "confidence without sorting the vocabulary; temperature alone is served, top-k and top-p are not"
@@ -484,6 +497,9 @@ class ContinuousBatcher:
             # had its next program queued), and rows such a step computed for a
             # request that had ended meanwhile (by eos or a cancel: dropped)
             "steps_ahead": 0, "late_rows": 0,
+            # what one token of a context takes in the cache over all the layers
+            # that attend, by the cache's own shapes: a constant of the deployment
+            "cache_bytes_per_token": cache_bytes_per_token(self.cache),
         }
 
     # ------------------------------------------------------------- interface
@@ -598,7 +614,12 @@ class ContinuousBatcher:
         sp.set(sample_rows=step.sample_rows, truncate_rows=step.truncate_rows)
         self.stats["sort_steps"] += step.truncate_rows > 0
         if touched is not None:
+            # a replica that holds a share of the experts reads two numbers: the held
+            # experts given a row, and the assignments that fell on them (layer means)
+            touched, *held = np.ravel(touched)
             sp.set(moe_rows=len(step.rows), moe_experts_touched=float(touched))
+            if held:
+                sp.set(moe_held_assignments=float(held[0]))
             self.stats["moe_assignments"] += len(step.rows) * self.cfg.n_experts_per_tok
         if self._ssm_step_bytes:
             sp.set(ssm_state_bytes=self._ssm_step_bytes)

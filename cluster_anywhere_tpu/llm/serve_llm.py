@@ -158,7 +158,7 @@ class ContinuousLLMServer:
         import jax
 
         from ..models.transformer import init_params
-        from .continuous import PREFILL_BUCKETS, ContinuousBatcher
+        from .continuous import ContinuousBatcher, prefill_buckets_for
 
         from ..serve.replica import get_request_context, observe_phase, phase
 
@@ -186,14 +186,11 @@ class ContinuousLLMServer:
             flush=True,
         )
         t_max = config.max_prompt_len + config.max_new_tokens
-        # the batcher's own bucket ladder, cut at the longest prompt this
+        # the batcher's own bucket ladder, up to the longest prompt this
         # deployment admits: a short prompt prefills a short program
-        buckets = tuple(
-            b for b in PREFILL_BUCKETS if b < config.max_prompt_len
-        ) + (config.max_prompt_len,)
         self.cb = ContinuousBatcher(
             params, tcfg, slots=slots, t_max=t_max,
-            prefill_buckets=buckets, top_k=config.top_k,
+            prefill_buckets=prefill_buckets_for(config.max_prompt_len), top_k=config.top_k,
             prefix_cache_entries=getattr(config, "prefix_cache_entries", 8),
             prefix_block=getattr(config, "prefix_block", 16),
         )

@@ -8,6 +8,9 @@ of state the configuration's layers keep, each stacked over the layers of its
 kind alone,
     k, v: [n_attn, B, T_max, H_kv, D]
     conv: [n_ssm, B, K-1, C], h: [n_ssm, B, C, N] (float32)
+    ckv: [n_attn, B, T_max, R], kr: [n_attn, B, T_max, rope up to 128s]  (latent
+        attention: a token is one latent row and the one rotated key every
+        head shares, no heads axis; `LATENT_LANES` says why the key is padded)
 the rows of one request written over a slot (`install_rows`), and the cores
 that write it.  An attention block is
 transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
@@ -25,6 +28,18 @@ state over a whole prompt (`_ssm_prefill_block`, which is training's block
 with the pads masked: they leave the state untouched) or from a slot's own
 state for one more token (`_ssm_block_decode`, which reads the layer's state
 out of the stacks and writes the new one in its place).
+
+Latent attention (`cfg.latent`) has the same two blocks with cores of its own.
+The prefill core EXPANDS: every head's key and value are made of the prompt's
+latents (`transformer._latent_expand`) and go through the same flash kernel, at
+a q/k width of nope + rope and a value width of v; what it stores is the
+latents.  The decode core ABSORBS: the query's nope part is carried into the
+latent space (q W_UK^T, a head at a time), the scores are taken against the
+cached rows as they lie (`_latent_attention`, which is `_masked_attention`
+with all H query heads on the one cached "head": q_lat . c_kv + q_rope .
+k_rope), the probabilities weigh the latent rows, and W_UV brings each head's
+weighted latent out.  The same numbers as the expanded form by associativity;
+nothing of the cache's size is ever expanded to heads.
 
 A program that is given a cache carries it through the layer loop
 (`_scan_blocks`): the stacks are one buffer from the program's argument to its
@@ -49,8 +64,11 @@ Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
 `attn.core`, `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
 `moe.router`, `moe.dispatch`, `moe.experts`, `moe.combine` under `ffn`,
-parallel/moe.py; a state-space layer writes `ssm.in`, `ssm.conv`, `ssm.scan`,
-`ssm.state`, `ssm.out` in place of the `attn.*`): the names reach each
+parallel/moe.py, and `moe.shared` for its shared experts; a state-space layer
+writes `ssm.in`, `ssm.conv`, `ssm.scan`, `ssm.state`, `ssm.out` in place of the
+`attn.*`; latent attention writes `attn.mla.q`, `attn.mla.kv` in place of
+`attn.qkv`, `attn.mla.expand` in a prefill and `attn.mla.absorb` in a decode
+step): the names reach each
 operation's metadata, so a device trace sums a kind of work over the depth
 whatever the compiler numbers its operations.  Metadata only: the programs
 compile to the same instructions with or without them.
@@ -69,14 +87,36 @@ from ..ops.attention import attention
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
     SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
-    _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, layer_stacks,
+    _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, layer_stacks,
 )
 
-# what a layer of each kind keeps of a sequence between two tokens, as the
-# cache's keys: one stacked array each over the layers of that kind
-LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h")}
+# what a layer of each kind of state keeps of a sequence between two tokens, as
+# the cache's keys: one stacked array each over the layers that keep that kind
+LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr")}
 # the scope a kind's state is read, written and installed under
-STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state"}
+STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache"}
+
+
+# The rotated key's last axis in the cache is padded with zeros to a multiple
+# of the chip's 128 lanes.  An array whose last axis is 64 wide (or 576: latent
+# and key in one row) is laid out by the chip's compiler with T_max as its minor
+# axis, and a decode step then copies the whole stack in and out of the layout
+# its dots want: 0.25 GB a step for the keys alone at 7 x 32 x 4352 x 64, 2.2 GB
+# for one array of 576 (what the compiler made of each is in PERF.md section 4,
+# Model 5).  A row of 128 is read as it lies; the upper lanes meet zeros of the query.
+LATENT_LANES = 128
+
+
+def _lanes(a):
+    """`a` with its last axis padded with zeros to a multiple of LATENT_LANES."""
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, -a.shape[-1] % LATENT_LANES)])
+
+
+def _state_kind(kind: str, cfg: TransformerConfig) -> str:
+    """The kind of state (LAYER_STATE's key) a layer of `kind` keeps."""
+    if kind == "ssm":
+        return kind
+    return "latent" if cfg.latent else "attn"
 
 
 def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
@@ -89,22 +129,27 @@ def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
     has no other use for one).  A mixture of experts' matrices are not
     scanned: the grouped matmul that reads them is a kernel, and a layer's
     slice of the stack handed to a kernel is a copy of every expert at every
-    step.  `experts` is the whole stack and the layer's index, which
-    `routed_ffn` reads in place; None for a dense model.  Returns (x, the
+    step.  `experts` is the whole stack and the layer's index among the expert
+    layers, which `routed_ffn` reads in place; None for a dense model or layer.  Returns (x, the
     cache after, {kind: ys over that kind's layers})."""
 
     def body(kind, carry, bp, held, layer):
         x, cache = carry
-        x, cache, ys = bodies[kind](x, bp, (held, layer) if held else None, cache, layer)
+        # a mixture's leading dense layers come first among the attention
+        # layers' state, so an expert layer's state lies that many further on
+        at = layer + cfg.n_dense_layers if kind == "attn" and cfg.n_dense_layers and layer is not None else layer
+        x, cache, ys = bodies[kind](x, bp, (held, layer) if held else None, cache, at)
         return (x, cache), ys
 
-    (x, cache), outs = _scan_layers(body, (x, cache), layer_stacks(params), cfg,
-                                    unsliced=EXPERT_MATRICES if cfg.n_experts else (),
+    stacks = layer_stacks(params)
+    (x, cache), outs = _scan_layers(body, (x, cache), stacks, cfg,
+                                    unsliced={kind: EXPERT_MATRICES for kind, b in stacks.items() if "router" in b},
                                     indexed=cache is not None)
     return x, cache, outs
 
 
-def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None):
+def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None, scale=None,
+                      also=None):
     """q: [B, Tq, H, D]; caches: [B, T_max, KV, D] as stored, never repeated to
     H heads and never copied to f32.  The query is viewed as [B, Tq, KV, R, D]
     (R = H // KV query heads share one cached head; R == 1 is multi-head
@@ -112,12 +157,19 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
     f32 accumulator.  Cache slots >= valid_len (a scalar, or a per-row [B]) are
     masked out, as are slots < pad[b] (left-padding of the prompt; pad is a
     per-row [B] count of pad tokens, None = no padding).  For decode Tq == 1.
-    Returns [B, Tq, H, D]."""
+    scale: what the scores are multiplied by, d_head^-0.5 unless given.  also:
+    (q2 [B, Tq, H, D2], k2 [B, T_max, KV, D2]), a second part of every query and
+    key kept in a cache of its own, whose products add to the scores.
+    Returns [B, Tq, H, Dv], Dv the cached values' width."""
     b, tq, h, d = q.shape
     t_max, kv = k_cache.shape[1:3]
     qg = q.reshape(b, tq, kv, h // kv, d)
     logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache, preferred_element_type=jnp.float32)
-    logits = logits * cfg.d_head ** -0.5
+    if also is not None:
+        q2, k2 = also
+        logits = logits + jnp.einsum("bqgrd,bkgd->bgrqk", q2.reshape(b, tq, kv, h // kv, -1), k2,
+                                     preferred_element_type=jnp.float32)
+    logits = logits * (cfg.d_head ** -0.5 if scale is None else scale)
     slots = jnp.arange(t_max)
     mask = slots < jnp.reshape(valid_len, (-1, 1, 1, 1, 1))
     if pad is not None:
@@ -125,18 +177,38 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache, preferred_element_type=jnp.float32)
-    return out.astype(q.dtype).reshape(b, tq, h, d)
+    return out.astype(q.dtype).reshape(b, tq, h, v_cache.shape[-1])
+
+
+def _latent_attention(q_lat, q_rope, ckv, kr, valid_len, cfg: TransformerConfig, pad=None):
+    """Attention in the latent space: `_masked_attention` with every query
+    head on the one cached "head" that a latent cache has.  q_lat: [B, Tq, H, R],
+    the queries' nope parts carried through W_UK; q_rope: [B, Tq, H, rope];
+    ckv [B, T_max, R] and kr [B, T_max, rope up to LATENT_LANES] as stored.  A
+    head's score against a slot is q_lat . ckv + q_rope . kr and the values
+    are the latent rows themselves.  Returns [B, Tq, H, R]: each head's
+    weighted latent, which W_UV brings out."""
+    one = lambda a: a[:, :, None, :]  # [B, T_max, 1, .]: the one cached "head"
+    return _masked_attention(q_lat, one(ckv), one(ckv), valid_len, cfg, pad, scale=cfg.attn_scale,
+                             also=(_lanes(q_rope), one(kr)))
 
 
 def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     """Every kind of state the configuration's layers keep, each stacked over
-    the layers of its kind alone: k, v [n_attn, B, t_max, KV, D]; a state-space
-    layer's convolution window [n_ssm, B, K-1, C] and its h [n_ssm, B, C, N] in
-    SSM_STATE_DTYPE, whatever the context's length."""
+    the layers that keep it alone: k, v [n_attn, B, t_max, KV, D], or under
+    latent attention ckv [n_attn, B, t_max, R] and kr [n_attn, B, t_max, rope up
+    to LATENT_LANES] (a token's latent and its rotated key);
+    a state-space layer's convolution window [n_ssm, B, K-1, C] and its h
+    [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length."""
     kinds = cfg.layer_kinds
-    n_attn, n_ssm = kinds.count("attn"), kinds.count("ssm")
+    n_ssm = kinds.count("ssm")
+    n_attn = len(kinds) - n_ssm
     cache = {}
-    if n_attn:
+    if n_attn and cfg.latent:
+        rope = -(-cfg.qk_rope_head_dim // LATENT_LANES) * LATENT_LANES
+        cache.update(ckv=jnp.zeros((n_attn, batch, t_max, cfg.kv_lora_rank), cfg.dtype),
+                     kr=jnp.zeros((n_attn, batch, t_max, rope), cfg.dtype))
+    elif n_attn:
         shape = (n_attn, batch, t_max, cfg.n_kv_heads, cfg.d_head)
         cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
     if n_ssm:
@@ -167,12 +239,46 @@ def recurrent_state_bytes(cache) -> int:
     return sum(int(cache[n].size) * cache[n].dtype.itemsize for n in LAYER_STATE["ssm"] if n in cache)
 
 
+def cache_bytes_per_token(cache) -> int:
+    """The bytes one token of one sequence takes in a cache over all the layers
+    that attend (its keys and values, or its latent row and rotated key):
+    what grows with a context.  0 for a cache of recurrent state alone."""
+    names = [n for kind in ("attn", "latent") for n in LAYER_STATE[kind] if n in cache]
+    return sum(int(cache[n].size) * cache[n].dtype.itemsize // (cache[n].shape[1] * cache[n].shape[2])
+               for n in names)
+
+
+def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, k_rope, c_kv):
+    """The decode block's core under latent attention, one token a row: row b's
+    latent c_kv [B, 1, R] and rotated key k_rope [B, 1, rope] are written at
+    [layer, b, pos[b]] of the stacks ckv and kr, the query's nope part is
+    absorbed into the latent space (q_lat = q_nope W_UK^T), attention runs over
+    the layer's latent rows as stored (`_latent_attention`) and W_UV brings each
+    head's weighted latent out.  q: [B, 1, H, nope + rope].  Returns
+    (attn [B, 1, H, v], the cache after)."""
+    dn = cfg.qk_nope_head_dim
+    with jax.named_scope(STATE_SCOPE["latent"]):
+        at = (layer, jnp.arange(q.shape[0]), pos)
+        ckv_all = cache["ckv"].at[at].set(c_kv[:, 0])
+        kr_all = cache["kr"].at[at].set(_lanes(k_rope[:, 0]))
+        ckv, kr = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (ckv_all, kr_all))
+    with jax.named_scope("attn.mla.absorb"):
+        w_uk, w_uv = _latent_up(bp, cfg, q.dtype)
+        q_lat = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_uk)
+    with jax.named_scope("attn.core"):
+        o_lat = _latent_attention(q_lat, q[..., dn:], ckv, kr, pos + 1, cfg, pads)
+    with jax.named_scope("attn.mla.absorb"):
+        attn = jnp.einsum("bthr,rhv->bthv", o_lat, w_uv)
+    return attn, {**cache, "ckv": ckv_all, "kr": kr_all}
+
+
 def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads, live=None,
                          experts=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
     cache: the attention layers' stacks k, v [n_attn, B, Tmax, KV, D] (among
-    whatever else it holds) and layer: this one's number among them.  Row b
+    whatever else it holds; ckv, kr under latent attention, whose core absorbs
+    the up-projections: module docstring) and layer: this one's number among them.  Row b
     writes its k/v at [layer, b, pos[b]], one scatter of [B, KV, D] an array
     and nothing else of the stacks, takes RoPE position pos[b] - pads[b], and
     attends to slots [pads[b], pos[b]] of the layer, read where it lies.
@@ -203,7 +309,9 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
         return attn, {**cache, "k": k_all, "v": v_all}
 
     positions = (pos - pads)[:, None]
-    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core)
+    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t),
+                               functools.partial(_latent_decode_core, bp, cache, layer, pos, pads, cfg)
+                               if cfg.latent else core)
     if live is not None:
         live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
     x, _, touched = _ffn_half(bp, x, cfg, live, experts)
@@ -211,11 +319,22 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
 
 
 def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None):
-    """One block over the whole prompt; returns padded caches [B,Tmax,KV,D].
+    """One block over the whole prompt; returns padded caches [B,Tmax,KV,D]
+    (under latent attention the latents [B,Tmax,R] and rotated keys [B,Tmax,rope up to 128s]).
     pad: [B] per-row left-pad counts or None. Real tokens sit at columns
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
     pad-token keys (ADVICE r1: unmasked pads skewed generation)."""
     b, t, _ = x.shape
+
+    def latent_core(q, k_rope, c_kv):
+        with jax.named_scope("attn.cache"):
+            stored = tuple(
+                lax.dynamic_update_slice(jnp.zeros((b, t_max, a.shape[-1]), x.dtype), a, (0, 0, 0))
+                for a in (c_kv, _lanes(k_rope)))
+        k, v = _latent_expand(bp, k_rope, c_kv, cfg)
+        with jax.named_scope("attn.core"):
+            attn = attention(q, k, v, causal=True, pad=pad, scale=cfg.attn_scale)
+            return attn.astype(x.dtype), stored
 
     def core(q, k, v):
         with jax.named_scope("attn.cache"):
@@ -237,7 +356,7 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
     positions = jnp.arange(t)
     if pad is not None:
         positions = jnp.maximum(positions[None, :] - pad[:, None], 0)  # [B, T]
-    x, layer_cache = _attention_half(bp, x, cfg, positions, core)
+    x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
     return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
@@ -300,8 +419,11 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
         x, state = _ssm_prefill_block(bp, x, pad, cfg, experts)
         return x, None, state
 
-    x, _, rows = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg)
-    cache = {name: r for kind, kept in rows.items() for name, r in zip(LAYER_STATE[kind], kept)}
+    x, _, rows = _scan_blocks({"attn": attn, "attn_dense": attn, "ssm": ssm}, x, params, cfg)
+    cache: Dict[str, Any] = {}
+    for kind, kept in rows.items():  # in the layers' order: a mixture's dense layers lead
+        for name, r in zip(LAYER_STATE[_state_kind(kind, cfg)], kept):
+            cache[name] = r if name not in cache else jnp.concatenate([cache[name], r])
     return None if cfg.generates_blocks else _head(params, x, cfg, row=-1), cache
 
 
@@ -309,8 +431,10 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     """The decode program's body: one token for every row of the cache, each
     at its own depth.  tokens, pos, pads: [B] (`_block_decode_rowpos` says what
     each row does with its own); live: [B] bool or None.  Returns (logits
-    [B, V], updated cache, experts touched: the mean over the layers of the
-    experts that were given a row, None for a dense model).
+    [B, V], updated cache, experts touched: the mean over the expert layers of
+    the experts that were given a row, None for a dense model; where this
+    device holds a share of the experts, [2]: that of the held, and the mean of
+    the assignments that fell on them).
 
     tokens [B, T]: one pass of each row's own block of T positions, the first
     of them at pos[b] (a model that generates by blocks).  Returns the logits
@@ -329,9 +453,9 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     def ssm(x, bp, experts, cache, layer):
         return _ssm_block_decode(bp, x, cache, layer, cfg, live, experts)
 
-    x, cache, touched = _scan_blocks({"attn": attn, "ssm": ssm}, x, params, cfg, cache)
+    x, cache, touched = _scan_blocks({"attn": attn, "attn_dense": attn, "ssm": ssm}, x, params, cfg, cache)
     touched = [t for t in touched.values() if t is not None]
-    touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32)) if touched else None
+    touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None
     logits = _head(params, x, cfg).astype(jnp.float32) if blocks else _head(params, x, cfg, row=0)
     return logits, cache, touched
 

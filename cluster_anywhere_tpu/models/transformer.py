@@ -20,6 +20,15 @@ layout.  Which layer is which kind is the configuration's `layer_kinds`; every
 program's layer loop is `_scan_layers`, one scan a run of one kind, and every
 program's output head is `_head`.
 
+Latent attention (`cfg.latent`) is the first half with a second way to q, k, v
+(`_project_latent`: a low-rank query, one rotated key that every head shares
+and a latent of which each head's keys and values are up-projections) and, in
+training, every head's keys and values expanded for the same dense core
+(`_latent_expand`); YaRN's frequencies are `_rope_freqs`.  A mixture may keep
+leading dense layers (kind "attn_dense": a run of its own in the loop), shared
+experts beside the routed ones (`_ffn_half`) and, on one chip of several that
+divide a layer's experts, a held share of them (parallel/moe.py routed_ffn).
+
 The model is the `entry()` / `dryrun_multichip()` flagship in
 __graft_entry__.py and what benchmarks/ trains and serves.
 """
@@ -28,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -116,6 +126,50 @@ class TransformerConfig:
     mask_token_id: int = 0
     denoise_steps: int = 1
     confidence_threshold: float = 0.9
+    # multi-head latent attention (kv_lora_rank > 0; `_project_latent`): a token
+    # is cached as one latent row of kv_lora_rank values and one rotated key of
+    # qk_rope_head_dim shared by every head, and each head's key (nope part)
+    # and value are up-projections of the latent (`wkv_b`); the query goes
+    # through a low rank of q_lora_rank.  A head's q and k are qk_nope_head_dim
+    # + qk_rope_head_dim wide, its value v_head_dim; d_head and n_kv_heads are
+    # not read.  0: keys and values are projected and cached a head (`_project_qkv`).
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (rope_factor > 1): the rotary frequencies are the unscaled ones where
+    # a dimension turns more than rope_beta_fast times over
+    # rope_original_max_len positions, those divided by rope_factor where it
+    # turns fewer than rope_beta_slow times, a linear blend between; cos and
+    # sin are scaled by mscale(rope_mscale) / mscale(rope_mscale_all_dim) and
+    # the softmax scale by mscale(rope_mscale_all_dim)^2, mscale(m) = 0.1 m
+    # ln(rope_factor) + 1 (`_rope_freqs`, `attn_scale`)
+    rope_factor: float = 1.0
+    rope_original_max_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # the FFN by layer: the first n_dense_layers layers of a mixture keep a
+    # dense gated MLP of d_ff (kind "attn_dense", stacked apart as
+    # `dense_blocks`); the others' experts are d_expert wide (0: d_ff)
+    n_dense_layers: int = 0
+    d_expert: int = 0
+    # a gated MLP of n_shared_experts * d_expert that every token takes beside
+    # its routed experts (scope `moe.shared`)
+    n_shared_experts: int = 0
+    # "softmax": the k largest of softmax(logits); "sigmoid": the k largest of
+    # sigmoid(logits), divided by their sum (+1e-20) under moe_renormalize.
+    # Either way times moe_routed_scale.
+    moe_scoring: str = "softmax"
+    moe_routed_scale: float = 1.0
+    # (first, count): of every layer's n_experts this device holds experts
+    # first .. first + count - 1 (its share under expert parallelism).  The
+    # router stays n_experts wide and a token takes its k of all of them; what
+    # the experts held here add for the tokens routed to them is computed, the
+    # rest is left out (parallel/moe.py routed_ffn).  None: all are held.
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.generates_blocks and self.block_length % self.denoise_steps:
@@ -123,16 +177,52 @@ class TransformerConfig:
                 f"block_length {self.block_length} is not a multiple of denoise_steps "
                 f"{self.denoise_steps}: every pass fixes the same number of positions"
             )
+        if self.latent and not (self.q_lora_rank and self.qk_nope_head_dim and self.qk_rope_head_dim
+                                and self.v_head_dim):
+            raise ValueError(
+                f"kv_lora_rank={self.kv_lora_rank}: latent attention takes q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim as well"
+            )
+        if self.latent and self.generates_blocks:
+            raise NotImplementedError("a pass over blocks of positions through a latent cache")
+        if self.n_dense_layers and (not self.n_experts or self.attn_layer_period):
+            raise NotImplementedError(
+                f"n_dense_layers={self.n_dense_layers}: leading dense layers stand before the expert "
+                "layers of a mixture whose layers all attend"
+            )
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            object.__setattr__(self, "experts_held", (int(first), int(count)))  # hashable whatever carried it
+            if not (0 <= first and count > 0 and first + count <= self.n_experts):
+                raise ValueError(f"experts_held={self.experts_held} of n_experts={self.n_experts}")
 
     @property
     def generates_blocks(self) -> bool:
         return self.block_length > 1
 
     @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """The width the rotary embedding turns: a head's, or its rotary part."""
+        return self.qk_rope_head_dim if self.latent else self.d_head
+
+    @property
+    def attn_scale(self) -> float:
+        """What the scores are multiplied by before the softmax."""
+        d = self.qk_nope_head_dim + self.qk_rope_head_dim if self.latent else self.d_head
+        m = _yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return d ** -0.5 * m * m
+
+    @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Each layer's mixer: "attn" or "ssm"."""
+        """Each layer's kind: its mixer, "attn" or "ssm", and "attn_dense" for
+        an attention layer that keeps a dense FFN in a mixture."""
         if not self.attn_layer_period:
-            return ("attn",) * self.n_layers
+            dense = min(self.n_dense_layers, self.n_layers)
+            return ("attn_dense",) * dense + ("attn",) * (self.n_layers - dense)
         return tuple(
             "attn" if i % self.attn_layer_period == self.attn_layer_offset else "ssm"
             for i in range(self.n_layers)
@@ -154,6 +244,10 @@ class TransformerConfig:
         return "ring" if self.sp > 1 else "dense"
 
 
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1.0 and m else 1.0
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -167,8 +261,18 @@ def _init_ffn(ks, cfg: TransformerConfig):
     if cfg.n_experts:
         from ..parallel.moe import init_moe_params
 
-        return {"ln2": jnp.ones((e,), pd),
-                **init_moe_params(ks[0], e, f, cfg.n_experts, pd, gated=cfg.moe_gated)}
+        fx = cfg.d_expert or f
+        out = {"ln2": jnp.ones((e,), pd),
+               **init_moe_params(ks[0], e, fx, cfg.n_experts, pd, gated=cfg.moe_gated,
+                                 held=cfg.experts_held and cfg.experts_held[1])}
+        if cfg.n_shared_experts:
+            fs, kg, ku, kd = cfg.n_shared_experts * fx, *jax.random.split(ks[1], 3)
+            out.update(
+                shared_gate=jax.random.normal(kg, (e, fs), pd) * s(e),
+                shared_up=jax.random.normal(ku, (e, fs), pd) * s(e),
+                shared_down=jax.random.normal(kd, (fs, e), pd) * s(fs),
+            )
+        return out
     return {
         "ln2": jnp.ones((e,), pd),
         "w_gate": jax.random.normal(ks[0], (e, f), pd) * s(e),
@@ -182,6 +286,22 @@ def _init_block(key, cfg: TransformerConfig):
     ks = jax.random.split(key, 7)
     s = lambda fan_in: fan_in ** -0.5
     pd = cfg.param_dtype
+    if cfg.latent:
+        rq, r, dn, dr, dv = (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+        ka = jax.random.split(ks[0], 5)
+        out = {
+            "ln1": jnp.ones((e,), pd),
+            "wq_a": jax.random.normal(ka[0], (e, rq), pd) * s(e),
+            "q_a_norm": jnp.ones((rq,), pd),
+            "wq_b": jax.random.normal(ka[1], (rq, h * (dn + dr)), pd) * s(rq),
+            "wkv_a": jax.random.normal(ka[2], (e, r + dr), pd) * s(e),
+            "kv_a_norm": jnp.ones((r,), pd),
+            "wkv_b": jax.random.normal(ka[3], (r, h * (dn + dv)), pd) * s(r),
+            "wo": jax.random.normal(ka[4], (h * dv, e), pd) * s(h * dv),
+        }
+        out.update(_init_ffn(ks[4:], cfg))
+        return out
     out = {
         "ln1": jnp.ones((e,), pd),
         "wq": jax.random.normal(ks[0], (e, h * d), pd) * s(e),
@@ -227,13 +347,20 @@ def _init_ssm_block(key, cfg: TransformerConfig):
     return out
 
 
-_INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block)}
+def _init_dense_block(key, cfg: TransformerConfig):
+    """An attention block of a mixture that keeps the dense FFN of d_ff."""
+    return _init_block(key, dataclasses.replace(cfg, n_experts=0, n_dense_layers=0, experts_held=None))
+
+
+_INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block),
+              "attn_dense": ("dense_blocks", _init_dense_block)}
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """`blocks`: the attention layers' parameters stacked [n, ...]; `ssm_blocks`:
-    the state-space layers', where the pattern has any.  Layer i's key is the
-    i-th of one split whatever its kind."""
+    the state-space layers', where the pattern has any; `dense_blocks`: a
+    mixture's leading dense layers'.  Layer i's key is the i-th of one split
+    whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
     kinds = cfg.layer_kinds
@@ -304,12 +431,12 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
 def _one_device_only(cfg: TransformerConfig, what: str) -> None:
     """A layer pattern's training loop over a mesh is not written (ROADMAP M1):
     say which kind of layer stands in the way instead of sharding it wrongly."""
-    kinds = sorted(set(cfg.layer_kinds) - {"attn"})
+    kinds = sorted(set(cfg.layer_kinds) - {"attn"}) + ["latent attention"] * cfg.latent
     if kinds:
         raise NotImplementedError(
-            f"{what}: layers of kind {kinds} (attn_layer_period={cfg.attn_layer_period}) "
-            "run on one device only; a mesh with more than one device shards attention "
-            "blocks alone"
+            f"{what}: layers of kind {kinds} (attn_layer_period={cfg.attn_layer_period}, "
+            f"n_dense_layers={cfg.n_dense_layers}) run on one device only; a mesh with more than "
+            "one device shards attention blocks that cache keys and values alone"
         )
 
 
@@ -338,17 +465,39 @@ def _rms_norm(x, w, eps=1e-6):
     return (x * lax.rsqrt(var + eps).astype(x.dtype)) * w.astype(x.dtype)
 
 
+def _rope_freqs(cfg: TransformerConfig):
+    """(the rotary frequencies [rope_dim / 2], what cos and sin are scaled by).
+    With YaRN (`cfg.rope_factor` > 1; the configuration says what each size
+    is) dimension i keeps its frequency where it turns more than beta_fast
+    times over the original length, is interpolated (/ factor) where it turns
+    fewer than beta_slow times, and is blended linearly between the two
+    correction dimensions."""
+    d = cfg.rope_dim
+    freqs = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if cfg.rope_factor == 1.0:
+        return freqs, 1.0
+    # the dimension that turns `turns` times over the original length
+    at = lambda turns: d * math.log(cfg.rope_original_max_len / (turns * 2 * math.pi)) / (
+        2 * math.log(cfg.rope_theta))
+    low, high = max(math.floor(at(cfg.rope_beta_fast)), 0), min(math.ceil(at(cfg.rope_beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    freqs = freqs / cfg.rope_factor * ramp + freqs * (1.0 - ramp)
+    return freqs, (_yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                   / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+
+
 def _rope(q, k, positions, cfg: TransformerConfig):
     """Rotary embeddings; q,k: [B, T, H, D]. positions: [T] global positions,
     or [B, T] per-row positions (left-padded prompts shift each row's real
     tokens to start at position 0)."""
-    d = cfg.d_head
-    freqs = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freqs, magnitude = _rope_freqs(cfg)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, D/2]
     if angles.ndim == 2:
         angles = angles[None]  # broadcast over batch
     cos = jnp.cos(angles)[:, :, None, :]  # [B|1, T, 1, D/2]
     sin = jnp.sin(angles)[:, :, None, :]
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
 
     def rot(x):
         x1, x2 = x[..., 0::2], x[..., 1::2]
@@ -383,13 +532,57 @@ def _project_qkv(bp, y, cfg: TransformerConfig):
             project("wv", cfg.n_kv_heads))
 
 
+def _project_latent(bp, y, cfg: TransformerConfig, positions):
+    """Latent attention's way to a block's q, k, v from its normed input y
+    [B, T, E]: q [B, T, H, nope + rope] through the low rank c_q =
+    norm(y wq_a), its rotary part rotated; and what a token is cached as, the
+    rotated key k_rope [B, T, rope] that every head shares and the latent
+    c_kv = norm((y wkv_a)[:kv_lora_rank]) [B, T, kv_lora_rank], of which each
+    head's keys and values are up-projections (`_latent_expand`, or absorbed
+    into the query and the output: models/generate.py).  Returns
+    (q, k_rope, c_kv): what `_attention_half`'s core is given as q, k, v."""
+    b, t, _ = y.shape
+    dt = y.dtype
+    h, dn, dr, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("attn.mla.q"):
+        c_q = _rms_norm(y @ bp["wq_a"].astype(dt), bp["q_a_norm"])
+        q = (c_q @ bp["wq_b"].astype(dt)).reshape(b, t, h, dn + dr)
+    with jax.named_scope("attn.mla.kv"):
+        kv = y @ bp["wkv_a"].astype(dt)
+        c_kv = _rms_norm(kv[..., :r], bp["kv_a_norm"])
+    with jax.named_scope("attn.rope"):
+        q_rope, k_rope = _rope(q[..., dn:], kv[:, :, None, r:], positions, cfg)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    return q, k_rope[:, :, 0], c_kv
+
+
+def _latent_up(bp, cfg: TransformerConfig, dt):
+    """`wkv_b` as (W_UK [R, H, nope], W_UV [R, H, v]): each head's key and value
+    up-projections of the latent."""
+    w = bp["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _latent_expand(bp, k_rope, c_kv, cfg: TransformerConfig):
+    """Every head's own key and value of whole sequences, for a core that
+    attends head by head (training, a prompt's prefill): k [B, T, H, nope +
+    rope] = [c_kv W_UK | k_rope], v [B, T, H, v] = c_kv W_UV."""
+    b, t, _ = c_kv.shape
+    with jax.named_scope("attn.mla.expand"):
+        w_uk, w_uv = _latent_up(bp, cfg, c_kv.dtype)
+        k_nope = jnp.einsum("btr,rhn->bthn", c_kv, w_uk)
+        k_rope = jnp.broadcast_to(k_rope[:, :, None, :], (b, t, cfg.n_heads, k_rope.shape[-1]))
+        return jnp.concatenate([k_nope, k_rope], axis=-1), jnp.einsum("btr,rhv->bthv", c_kv, w_uv)
+
+
 def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
     """The dropless routed expert FFN (parallel/moe.py routed_ffn) of one
     block over y [B, T, E]; `live` [B, T] marks the rows that take experts.
     `experts`: (every layer's experts' matrices, unsliced; this layer's index)
     from a scan that keeps them out of its slices (models/generate.py
     _scan_blocks); None: `bp` holds this layer's own, a stack of one.  Returns
-    (out [B, T, E], aux loss, experts touched)."""
+    (out [B, T, E], aux loss, experts touched: with `cfg.experts_held` [2], the
+    held experts given a row and the assignments that fell on them)."""
     from ..parallel.moe import EXPERT_MATRICES, routed_ffn
 
     b, t, e = y.shape
@@ -397,8 +590,13 @@ def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
     r = routed_ffn(
         y.reshape(b * t, e), bp["router"], stack, layer, k=cfg.n_experts_per_tok,
         renormalize=cfg.moe_renormalize, live=None if live is None else live.reshape(b * t),
+        scoring=cfg.moe_scoring, scale=cfg.moe_routed_scale, held=cfg.experts_held,
     )
-    return r.out.reshape(b, t, e), r.aux_loss, r.experts_touched
+    touched = r.experts_touched
+    if cfg.experts_held is not None:
+        # beside the held experts given a row, the assignments that fell on them
+        touched = jnp.stack([touched, r.assignments])
+    return r.out.reshape(b, t, e), r.aux_loss, touched
 
 
 # [B, T, H, D] as the dense block leaves it: batch over the data axes, heads
@@ -433,7 +631,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes):
     if over_sp:
         fn = functools.partial(ring_attention if impl == "ring" else ulysses_attention, axis_name="sp", causal=True)
     else:  # dense: the dispatcher picks by backend
-        fn = functools.partial(dense_attention, causal=True, block=cfg.block_length)
+        fn = functools.partial(dense_attention, causal=True, block=cfg.block_length, scale=cfg.attn_scale)
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
@@ -452,6 +650,8 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
     takes; `core(q, k, v) -> (attn [B, T, H, D], extra)` attends
     under its own scopes (`attn.core`, and `attn.cache` where it keeps one) and
     hands back what its caller keeps of k and v.  Returns (x, extra).
+    Latent attention (`cfg.latent`) makes them its own way: the core is given
+    the query, the shared rotated key and the latent (`_project_latent`).
 
     The scope names are the same in every layer and every program: a device
     trace sums a kind of work over the depth (forward, recomputation and
@@ -459,11 +659,14 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
     b, t, _ = x.shape
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln1"])
-    with jax.named_scope("attn.qkv"):
-        q, k, v = _project_qkv(bp, y, cfg)
-    if cfg.rotary:
-        with jax.named_scope("attn.rope"):
-            q, k = _rope(q, k, positions, cfg)
+    if cfg.latent:
+        q, k, v = _project_latent(bp, y, cfg, positions)
+    else:
+        with jax.named_scope("attn.qkv"):
+            q, k, v = _project_qkv(bp, y, cfg)
+        if cfg.rotary:
+            with jax.named_scope("attn.rope"):
+                q, k = _rope(q, k, positions, cfg)
     attn, extra = core(q, k, v)
     with jax.named_scope("attn.out"):
         x = x + attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
@@ -592,7 +795,8 @@ def _ssm_half(bp, x, cfg: TransformerConfig, core, keep=None):
 
 def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
     """A block's second half: x + FFN(norm(x)), the FFN dense SwiGLU or a
-    mixture of experts.  On one device the mixture is the dropless routed path
+    mixture of experts (by what `bp` holds: a router or none), the mixture with
+    the shared experts' gated MLP beside it where the configuration has any.  On one device the mixture is the dropless routed path
     (`_moe`, which says what `live` and `experts` are); with 'ep' among the
     caller's manual axes its experts are sharded and tokens travel to them
     (parallel/moe.py moe_ffn).  Returns (x, aux loss, experts touched): None
@@ -602,11 +806,15 @@ def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axe
     with jax.named_scope("norm"):
         y = _rms_norm(x, bp["ln2"])
     with jax.named_scope("ffn"):
-        if not cfg.n_experts:
+        if "router" not in bp:  # a dense model's block, or a mixture's leading dense layer
             gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
             return x + gated @ bp["w_down"].astype(dt), None, None
         if "ep" not in manual_axes:
             out, aux, touched = _moe(bp, y, cfg, live, experts)
+            if cfg.n_shared_experts:
+                with jax.named_scope("moe.shared"):
+                    shared = jax.nn.silu(y @ bp["shared_gate"].astype(dt)) * (y @ bp["shared_up"].astype(dt))
+                    out = out + shared @ bp["shared_down"].astype(dt)
             return x + out, aux, touched
         # tokens flatten, travel to their expert's device, come back (traced
         # under shard_map manual over 'ep': see forward())
@@ -632,7 +840,10 @@ def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozens
 
     def core(q, k, v):
         with jax.named_scope("attn.core"):
-            k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
+            if cfg.latent:
+                k, v = _latent_expand(bp, k, v, cfg)
+            else:
+                k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
             return _attention(q, k, v, cfg, mesh, manual_axes), None
 
     x, _ = _attention_half(bp, x, cfg, offset + jnp.arange(t), core)
@@ -653,7 +864,8 @@ def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
 
 def layer_stacks(params) -> Dict[str, Any]:
     """{kind: that kind's blocks, stacked on a leading axis of their own}."""
-    stacks = {"attn": params["blocks"], "ssm": params.get("ssm_blocks")}
+    stacks = {"attn": params.get("blocks"), "ssm": params.get("ssm_blocks"),
+              "attn_dense": params.get("dense_blocks")}
     return {kind: blocks for kind, blocks in stacks.items() if blocks is not None}
 
 
@@ -670,7 +882,7 @@ def _layer_runs(kinds):
     return [tuple(r) for r in runs]
 
 
-def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=(), unroll=1, indexed=False):
+def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unroll=1, indexed=False):
     """The layer loop of every program: each maximal run of one kind of layer
     is one `lax.scan` of `body(kind, carry, bp, held, layer) -> (carry, ys)`;
     a model of one kind is one run.  stacks: `layer_stacks`.  Returns
@@ -689,9 +901,10 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=(), unrol
     shorter run scans its layers' indices and reads each layer's parameters
     where they lie: a slice of a stack handed to a loop is a copy of those
     layers at every call (1.3 GB for thirteen state-space layers).  The names
-    in `unsliced` (a mixture's experts, which a kernel reads) are never sliced
-    by either: `held` is their stacks, to be read at `layer`, empty where there
-    are none.  `layer` is None where nothing asked for it."""
+    `unsliced` lists for a kind ({kind: names}: a mixture's experts, which a
+    kernel reads) are never sliced by either: `held` is their stacks, to be
+    read at `layer`, empty where there are none.  `layer` is None where nothing
+    asked for it."""
     kinds = cfg.layer_kinds
     outs: Dict[str, list] = {}
     for kind, start, n in _layer_runs(kinds):
@@ -699,7 +912,7 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=(), unrol
         total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
         if len(set(kinds)) == 1:
             n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
-        held = {k: blocks[k] for k in unsliced if k in blocks}
+        held = {k: blocks[k] for k in (unsliced or {}).get(kind, ()) if k in blocks}
         rest = {k: v for k, v in blocks.items() if k not in held}
 
         def step(carry, xs, kind=kind, held=held, rest=rest):
@@ -724,6 +937,7 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
         "attn": functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes),
         "ssm": lambda bp, x: _ssm_block_forward(bp, x, cfg)[:2],
     }
+    blocks["attn_dense"] = blocks["attn"]  # the same block: its FFN is what its weights hold
     if cfg.remat:
         blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
 
@@ -777,11 +991,13 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
         if mesh is None:
             raise ValueError("mesh required for pp/sp execution")
         if cfg.n_experts:
-            if cfg.n_experts_per_tok > 1 or cfg.moe_gated:
+            if (cfg.n_experts_per_tok > 1 or cfg.moe_gated or cfg.n_shared_experts
+                    or cfg.moe_scoring != "softmax" or cfg.experts_held is not None):
                 raise NotImplementedError(
                     "experts sharded over a mesh's 'ep' axis are top-1 and ungated "
                     f"(parallel/moe.py moe_ffn); n_experts_per_tok={cfg.n_experts_per_tok}, "
-                    f"moe_gated={cfg.moe_gated} run on one device only"
+                    f"moe_gated={cfg.moe_gated} run on one device only, as do shared experts, "
+                    "sigmoid scores and a held share of the experts"
                 )
             mesh_ep = mesh.shape["ep"]
             if cfg.ep > 1 and cfg.ep != mesh_ep:

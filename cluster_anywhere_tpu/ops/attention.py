@@ -13,7 +13,10 @@ compute core of the TPU-native model stack: the dense transformer path calls
 `merge_attention` (parallel/ring_attention.py).
 
 Layout contract: [B, T, H, D] inputs (time-major per head), fp32 accumulation
-regardless of input dtype.  GQA callers repeat K/V heads first.
+regardless of input dtype.  GQA callers repeat K/V heads first.  The values may
+be of another width than the queries and keys ([B, T_kv, H, Dv]: latent
+attention's heads are 192 wide against values of 128); the output is as wide
+as the values.
 
 `attention()` runs the kernel on a TPU and the fused-jnp reference on the CPU
 backend (tests, virtual meshes); it never changes algorithm by shape.  Tests
@@ -62,7 +65,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mas
 
     m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
 
     if causal:
         # skip key blocks fully above the diagonal
@@ -255,7 +258,7 @@ def _bwd_dkv_kernel(
         return dk_new, dv_new
 
     dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
+    dv0 = jnp.zeros((block_k, v_ref.shape[-1]), jnp.float32)
     dk, dv = lax.fori_loop(lo, nq, body, (dk0, dv0))
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
@@ -290,7 +293,7 @@ _PAD_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0):
     b, t, h, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[-1]
     qf, kf, vf = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
     bh = b * h
     nq = t // block_q
@@ -299,7 +302,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
     in_specs = [
         pl.BlockSpec((None, block_q, d), lambda bi, qi: (bi, qi, 0)),
         pl.BlockSpec((None, t_kv, d), lambda bi, qi: (bi, 0, 0)),
-        pl.BlockSpec((None, t_kv, d), lambda bi, qi: (bi, 0, 0)),
+        pl.BlockSpec((None, t_kv, dv), lambda bi, qi: (bi, 0, 0)),
     ]
     args = [qf, kf, vf]
     if has_pad:
@@ -313,13 +316,13 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bi, qi: (bi, qi, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda bi, qi: (bi, qi, 0)),
             # (1, t) full-row blocks: TPU lowering requires the last two block
             # dims divisible by (8, 128) OR equal to the array dims
             pl.BlockSpec((None, 1, t), lambda bi, qi: (bi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
@@ -330,7 +333,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
 
 def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpret, dlse=None):
     b, t, h, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, dv = k.shape[1], v.shape[-1]
     qf, kf, vf = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
     dof, of = _to_bhtd(do), _to_bhtd(o)
     bh = b * h
@@ -353,8 +356,8 @@ def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpr
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bi, qi: (bi, qi, 0)),
             pl.BlockSpec((None, t_kv, d), lambda bi, qi: (bi, 0, 0)),
-            pl.BlockSpec((None, t_kv, d), lambda bi, qi: (bi, 0, 0)),
-            pl.BlockSpec((None, block_q, d), lambda bi, qi: (bi, qi, 0)),
+            pl.BlockSpec((None, t_kv, dv), lambda bi, qi: (bi, 0, 0)),
+            pl.BlockSpec((None, block_q, dv), lambda bi, qi: (bi, qi, 0)),
             pl.BlockSpec((None, 1, t), lambda bi, qi: (bi, 0, 0)),
             pl.BlockSpec((None, 1, t), lambda bi, qi: (bi, 0, 0)),
         ] + pad_spec,
@@ -372,18 +375,18 @@ def _bwd_impl(q, k, v, o, lse, do, pad, causal, scale, block_q, block_k, interpr
         in_specs=[
             pl.BlockSpec((None, t, d), lambda bi, ki: (bi, 0, 0)),
             pl.BlockSpec((None, block_k, d), lambda bi, ki: (bi, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bi, ki: (bi, ki, 0)),
-            pl.BlockSpec((None, t, d), lambda bi, ki: (bi, 0, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda bi, ki: (bi, ki, 0)),
+            pl.BlockSpec((None, t, dv), lambda bi, ki: (bi, 0, 0)),
             pl.BlockSpec((None, 1, t), lambda bi, ki: (bi, 0, 0)),
             pl.BlockSpec((None, 1, t), lambda bi, ki: (bi, 0, 0)),
         ] + pad_spec,
         out_specs=[
             pl.BlockSpec((None, block_k, d), lambda bi, ki: (bi, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bi, ki: (bi, ki, 0)),
+            pl.BlockSpec((None, block_k, dv), lambda bi, ki: (bi, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_kv, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_kv, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, t_kv, dv), v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -453,7 +456,8 @@ def flash_attention(
     return_lse: bool = False,
     block: int = 0,
 ):
-    """Pallas flash attention.  q: [B, T, H, D]; k, v: [B, T_kv, H, D].
+    """Pallas flash attention.  q: [B, T, H, D]; k: [B, T_kv, H, D]; v:
+    [B, T_kv, H, Dv], of the keys' width or another; returns [B, T, H, Dv].
 
     block: B > 1 widens the causal mask to the block mask of a model that
     generates by blocks: query i sees key j where j // B <= i // B, every

@@ -11,7 +11,14 @@ returned to token order and summed over k.  No capacity and no drop, whatever
 the imbalance.  Rows marked not live (the empty slots of a decode batch, the
 left padding of a prompt) take no expert.  Experts are gated
 (`silu(x w_gate) * (x w_up)) w_down`, OLMoE's kind) or ungated
-(`silu(x w_in) w_out`).
+(`silu(x w_in) w_out`).  The scores may be a sigmoid's in place of the
+softmax's, and the k weights scaled.  A device that holds a share of the
+experts (`held`: expert parallelism's share, here without the exchange) is
+told which: the router stays as wide as the model's experts and a token takes
+its k of all of them; the assignments that fall on the experts held are
+computed, the others sort behind the last group with the rows that are not
+live and add nothing.  What the other devices' experts would add is theirs to
+compute: nothing here stands in for it.
 
 `moe_ffn` is expert parallelism for training over an 'ep' mesh axis:
 switch-style top-1 routing with a capacity, tokens exchanged with
@@ -30,7 +37,7 @@ over 'ep' is not built yet: models/transformer.py refuses the combination.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +56,8 @@ class MoEOutput(NamedTuple):
 class RoutedOutput(NamedTuple):
     out: jax.Array  # [N, E], x's dtype
     aux_loss: jax.Array  # load-balancing loss, as moe_ffn's
-    experts_touched: jax.Array  # int32: experts that were given at least one row
+    experts_touched: jax.Array  # int32: experts (of those held) that were given at least one row
+    assignments: jax.Array  # int32: (row, expert) pairs that were computed: live rows x k, of which on experts held
 
 
 def routed_ffn(
@@ -61,6 +69,9 @@ def routed_ffn(
     k: int = 1,
     renormalize: bool = False,  # the k probabilities divided by their sum
     live: Optional[jax.Array] = None,  # [N] bool; None = every row
+    scoring: str = "softmax",  # or "sigmoid": the scores the k largest are taken of
+    scale: float = 1.0,  # what the k weights are multiplied by
+    held: Optional[Tuple[int, int]] = None,  # (first, count) of the router's experts in `experts`; None = all
 ) -> RoutedOutput:
     """The dropless routed expert FFN of one device (module docstring).  Every
     live row's k assignments are computed; a row that is not live is given to
@@ -76,19 +87,39 @@ def routed_ffn(
     27).  So the matmul runs over L * X groups of which only this layer's X
     have rows, and reads the stack in place.  A caller that holds one layer's
     matrices hands them over as a stack of one (`w[None]`, layer 0): the same
-    matmul over X groups."""
+    matmul over X groups.
+
+    `held` = (first, count): `experts` holds experts first .. first + count - 1
+    of the router's X, as [L, count, ...]; the groups are those count.  The
+    load-balance loss is then the held experts' terms of the sum."""
     n, _ = x.shape
     dt = x.dtype
-    n_experts = router.shape[-1]
+    n_routed = router.shape[-1]
+    first, n_experts = (0, n_routed) if held is None else held  # n_experts: those with a group here
     gated = "w_gate" in experts
     with jax.named_scope("moe.router"):
         logits = jnp.dot(x, router.astype(dt), preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, idx = lax.top_k(probs, k)  # [N, k] each, float32 / int32
-        if renormalize:
-            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate, idx = lax.top_k(probs, k)  # [N, k] each, float32 / int32
+            if renormalize:
+                gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            gate, idx = lax.top_k(scores, k)
+            if renormalize:
+                gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)  # the load-balance loss's
+        else:
+            raise ValueError(f"scoring is 'softmax' or 'sigmoid', not {scoring!r}")
+        if scale != 1.0:
+            gate = gate * scale
     with jax.named_scope("moe.dispatch"):
         expert = idx.reshape(n * k)
+        if held is not None:
+            # an assignment to an expert that lives elsewhere sorts behind the last group
+            expert = jnp.where((expert >= first) & (expert < first + n_experts), expert - first, n_experts)
+            probs = probs[:, first:first + n_experts]
         if live is not None:
             # a row that takes no expert sorts behind the last group
             expert = jnp.where(jnp.repeat(live, k), expert, n_experts)
@@ -117,8 +148,8 @@ def routed_ffn(
     n_live = jnp.maximum(jnp.sum(rows_live), 1.0)
     frac = group_sizes.astype(jnp.float32) / (n_live * k)
     mean_prob = jnp.sum(probs * rows_live[:, None], axis=0) / n_live
-    aux = jnp.sum(frac * mean_prob) * n_experts
-    return RoutedOutput(out, aux, jnp.sum(group_sizes > 0).astype(jnp.int32))
+    aux = jnp.sum(frac * mean_prob) * n_routed
+    return RoutedOutput(out, aux, jnp.sum(group_sizes > 0).astype(jnp.int32), jnp.sum(group_sizes))
 
 
 def moe_ffn(
@@ -185,11 +216,14 @@ def moe_ffn(
 
 
 def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.float32,
-                    gated: bool = False):
+                    gated: bool = False, held: Optional[int] = None):
+    """A router over n_experts and the experts' matrices: all n_experts of them,
+    or the `held` that this device keeps."""
+    n_routed, n_experts = n_experts, held or n_experts
     if gated:
         k1, k2, k3, k4 = jax.random.split(key, 4)
         return {
-            "router": jax.random.normal(k1, (e_model, n_experts), dtype) * 0.02,
+            "router": jax.random.normal(k1, (e_model, n_routed), dtype) * 0.02,
             "w_gate": jax.random.normal(k2, (n_experts, e_model, f_hidden), dtype) * e_model ** -0.5,
             "w_up": jax.random.normal(k3, (n_experts, e_model, f_hidden), dtype) * e_model ** -0.5,
             "w_down": jax.random.normal(k4, (n_experts, f_hidden, e_model), dtype) * f_hidden ** -0.5,
@@ -198,7 +232,7 @@ def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.
     scale_in = (2.0 / e_model) ** 0.5
     scale_out = (2.0 / f_hidden) ** 0.5
     return {
-        "router": jax.random.normal(k1, (e_model, n_experts), dtype) * 0.02,
+        "router": jax.random.normal(k1, (e_model, n_routed), dtype) * 0.02,
         "w_in": jax.random.normal(k2, (n_experts, e_model, f_hidden), dtype) * scale_in,
         "w_out": jax.random.normal(k3, (n_experts, f_hidden, e_model), dtype) * scale_out,
     }

@@ -300,6 +300,64 @@ def test_decode_step_writes_the_cache_in_place(v5e, model):
     assert cache["k"].size in seen  # the keys' write was read for what it is
 
 
+# A.X-K1's widths as one chip of 16 holds a layer (latent attention 64 heads of 128 + 64 over a
+# latent of 512, YaRN, a leading dense layer of 18,432, 12 held of 192 sigmoid-routed experts of
+# 2,048 and a shared one), three layers deep: benchmarks/configs/a.x-k1-ep16-serve1.json
+AXK13 = dict(
+    vocab_size=512, n_layers=3, d_model=7168, n_heads=64, d_ff=18432, kv_lora_rank=512, q_lora_rank=1536,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, rope_factor=32.0, rope_original_max_len=4096,
+    rope_mscale_all_dim=1.0, n_dense_layers=1, d_expert=2048, n_experts=192, n_experts_per_tok=8,
+    experts_held=(0, 12), moe_gated=True, moe_renormalize=True, moe_scoring="sigmoid", moe_routed_scale=2.5,
+    n_shared_experts=1, param_dtype=jnp.bfloat16,
+)
+
+
+@pytest.mark.parametrize("variant", ["fwd", "padded_fwd", "fwd_bwd"])
+def test_flash_kernel_compiles_with_a_value_width_of_its_own(v5e, variant):
+    """Latent attention's expanded heads: queries and keys 192 wide (128 + the
+    rotary 64, not a multiple of the lane width), values 128."""
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((1, 1024, 8, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 1024, 8, 128), jnp.bfloat16, sharding=one)
+    pad = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
+    flash = attention.flash_attention
+    fn = {"fwd": lambda q, k, v, pad: flash(q, k, v, scale=0.13),
+          "padded_fwd": lambda q, k, v, pad: flash(q, k, v, pad=pad, scale=0.13),
+          "fwd_bwd": jax.grad(lambda q, k, v, pad: flash(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))}[variant]
+    out = jax.eval_shape(fn, q, q, v, pad)
+    assert (out.shape if variant != "fwd_bwd" else out[2].shape) == v.shape
+    assert _has_kernel(jax.jit(fn).lower(q, q, v, pad).compile())
+
+
+def test_latent_decode_step_reads_and_writes_the_cache_where_it_lies(v5e):
+    """The decode step at A.X-K1's widths and the cell's cache (32 slots x
+    4,352): the latent rows and the rotated keys are the layer loop's carry,
+    written a row a slot and layer in place and read by the absorbed core as
+    stored.  Its temporaries hold nothing of a stack's size: with the rotated
+    keys 64 wide (or latent and key in one row of 576) the chip's compiler gave
+    the stack a layout of its own and the step copied it in and out, 0.25 GB
+    (2.2 GB) a step; padded to the 128 lanes it is read as it lies
+    (models/generate.py LATENT_LANES).  The held experts' stacks are seen whole."""
+    cfg = transformer.TransformerConfig(**AXK13)
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], 32, 4352)
+    assert set(cache) == {"ckv", "kr"} and cache["kr"].shape == (3, 32, 4352, 128)
+    # less than the smaller of the two stacks: no copy of either (58 MB whatever the depth)
+    assert compiled.memory_analysis().temp_size_in_bytes < min(c.size * c.dtype.itemsize for c in cache.values())
+    stacks = {c.size for c in cache.values()}
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if n in stacks and op not in ("parameter", "get-tuple-element", "bitcast"):
+            assert op == "fusion" and '"aliasing_operands":{"lists":[{' in line, line[:200]
+    held = 2 * 12 * 7168 * 2048
+    assert _has_kernel(compiled) and sum(1 for _, n, _ in _buffers(compiled) if n == held) >= 3
+
+
+def test_latent_prefill_expands_through_the_flash_kernel(v5e, on_tpu):
+    """An admit's prefill at A.X-K1's widths in the 1,024 bucket: every head's
+    keys and values are made of the latents and go through the flash kernel."""
+    assert _has_kernel(_compiled_admit_prefill(transformer.TransformerConfig(**AXK13), 1024, 4352, v5e[0]))
+
+
 def _computations(text):
     """({name: its instructions' lines}, the entry's name) of an optimized program's text."""
     comps, entry, inside = {}, None, None
