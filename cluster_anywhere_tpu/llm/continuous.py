@@ -14,8 +14,11 @@ admitting or finishing requests never recompiles anything:
   padded to a bucket length so that there is one program a bucket) and its
   cache rows scatter into its slot between decode steps.
 - decode: every live slot advances one token per step.  Per-row cache
-  positions/pads drive RoPE and masking; finished or empty slots still
-  compute (their lanes are garbage) but write only to their own cache rows
+  positions/pads drive RoPE and masking, and the step is told which slots it
+  holds: on a TPU its attention reads the live rows' own slots of the cache
+  and nothing else (`cache_rows_read` of `cache_rows` on `llm.step` and in
+  `stats`).  Finished or empty slots still compute the rest (their lanes are
+  garbage) but write only to their own cache rows
   (a key/value row past its position; a recurrent state, which is not frozen:
   it moves on with every step), which the next admit overwrites whole.
 - a step is read one step behind: a row's next input is the token the step
@@ -77,7 +80,7 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, cache_bytes_per_token, decode_rows, init_cache, install_rows, prefill,
+    _nucleus_mask, cache_bytes_per_token, decode_rows, init_cache, install_rows, key_slots, prefill,
     recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
@@ -144,6 +147,7 @@ class _StepInFlight:
     # among them), as the sampler saw their knobs: how many sample, how many truncate
     sample_rows: int
     truncate_rows: int
+    cache_rows_read: int  # `ContinuousBatcher._rows_read` of those slots, as their rows stood then
 
 
 def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
@@ -200,9 +204,11 @@ def _sample_first(logits, rng, temp, top_k, top_p):
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _decode_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     """One token for every slot with PER-ROW cache positions.
-    ints: [5, S] int32, the rows tokens, pos, pads, top_ks, fresh, and for a
-    mixture of experts a sixth, live: 1 for the slots this step holds a request
-    in (the other rows are given no expert; a dense model is not told).
+    ints: [6, S] int32, the rows tokens, pos, pads, top_ks, fresh, live: the
+    last 1 for the slots this step holds a request in.  Every model is told: the
+    attention core reads the live rows' own slots of the cache and no others
+    (a released slot keeps its last pos and pads), and a mixture of experts
+    gives the other rows no expert.
     floats: [2, S] float32, the rows temps, top_ps.  prev: [S] int32, the step
     before's own result, still on the device: a slot feeds its token of that
     step, which the host may not have read yet, except where fresh is set (a
@@ -216,12 +222,11 @@ def _decode_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     tests/test_chip_compile.py holds the chip's program to it
     (`test_decode_step_writes_the_cache_in_place`), tests/test_llm.py the
     rows it may change."""
-    host_tokens, pos, pads, top_ks, fresh, *live = ints
-    live = live[0] != 0 if live else None
+    host_tokens, pos, pads, top_ks, fresh, live = ints
     temps, top_ps = floats
     tokens = jnp.where(fresh != 0, host_tokens, prev)
     keys = jax.random.split(rng, ints.shape[1] + 1)
-    logits, cache, touched = decode_rows(params, cache, tokens, pos, pads, cfg, live)
+    logits, cache, touched = decode_rows(params, cache, tokens, pos, pads, cfg, live != 0)
     nxt = _sample_rowwise(logits, keys[1:], temps, top_ks, top_ps)
     return nxt, cache, keys[0], touched
 
@@ -427,6 +432,9 @@ class ContinuousBatcher:
         # live or not), and what an admit installs: 0 for attention alone
         self._ssm_slot_bytes = recurrent_state_bytes(self.cache) // slots
         self._ssm_step_bytes = 2 * slots * self._ssm_slot_bytes
+        # the slots of a layer's keys that a step could read (as many of its values, or its
+        # latent rows): what `cache_rows_read` is a share of; 0 for recurrent state alone
+        self._cache_rows = key_slots(self.cache)
         # the decode step's per-slot inputs as its program takes them: two host
         # arrays, the scheduler's vectors their rows, written between steps (an
         # admit, a cancel) and as a step is dispatched or read.  A causal step
@@ -443,12 +451,12 @@ class ContinuousBatcher:
             self._blk_tokens, self._blk_fixed = self._ints[3:3 + self._block], self._ints[3 + self._block:]
             self._topks = np.zeros(slots, np.int32)  # top-k is refused: the row stays 0 and is not uploaded
         else:
-            self._ints = np.zeros((6 if cfg.n_experts else 5, slots), np.int32)
+            self._ints = np.zeros((6, slots), np.int32)
             # _tokens: an admit's first token, which _fresh marks for the slot's
             # first step (a later step feeds the device's own, `_prev`); _pos:
-            # cache slot of the NEXT write, moved on as a step is dispatched; a
-            # mixture's sixth row: the slots the step holds
-            self._tokens, self._pos, self._pads, self._topks, self._fresh = self._ints[:5]
+            # cache slot of the NEXT write, moved on as a step is dispatched;
+            # _live: the slots the step holds, written as it is dispatched
+            self._tokens, self._pos, self._pads, self._topks, self._fresh, self._live = self._ints
             # the last dispatched step's tokens, on the device
             self._prev = jnp.zeros(slots, jnp.int32)
         # a causal step while it is dispatched and unread: each step() dispatches
@@ -497,6 +505,11 @@ class ContinuousBatcher:
             # had its next program queued), and rows such a step computed for a
             # request that had ended meanwhile (by eos or a cancel: dropped)
             "steps_ahead": 0, "late_rows": 0,
+            # of a layer's keys, the slots the steps' attention fetches (the live rows' own
+            # [pads, pos + its tokens), in whole key blocks: ops/attention.py
+            # decode_attention; a latent core reads every slot) and the slots the cache
+            # holds, a step; both stay 0 for a cache of recurrent state alone
+            "cache_rows_read": 0, "cache_rows": 0,
             # what one token of a context takes in the cache over all the layers
             # that attend, by the cache's own shapes: a constant of the deployment
             "cache_bytes_per_token": cache_bytes_per_token(self.cache),
@@ -594,17 +607,31 @@ class ContinuousBatcher:
             return None
         slots = [s for s, _ in rows]
         with tracing.span("llm.step.upload"):
-            if self.cfg.n_experts:
-                self._ints[5] = 0
-                self._ints[5, slots] = 1
+            self._live[:] = 0
+            self._live[slots] = 1
             ints, floats = self._ints.copy(), self._floats.copy()
+            rows_read = self._rows_read(slots, 1)
             self._fresh[:] = 0
             self._pos[slots] += 1
         with tracing.span("llm.step.dispatch"):
             self._prev, self.cache, self._rng, touched = _decode_step_rowpos(
                 self.params, self.cache, ints, floats, self._prev, self._rng, cfg=self.cfg,
             )
-        return _StepInFlight(self._prev, touched, rows, self._sample_rows, self._truncate_rows)
+        return _StepInFlight(self._prev, touched, rows, self._sample_rows, self._truncate_rows, rows_read)
+
+    def _rows_read(self, slots: List[int], tokens: int) -> int:
+        """Of a layer's keys, the cache slots the attention fetches in a step
+        that gives each of `slots` `tokens` positions from its `_pos` on: the
+        rows' own [pads, pos + tokens) in whole key blocks, by the kernel's own
+        helper (models/generate.py key_slots).  The host's arithmetic on its
+        own vectors."""
+        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens)
+
+    def _count_rows_read(self, rows_read: int, sp: tracing.span) -> None:
+        if self._cache_rows:
+            sp.set(cache_rows_read=rows_read, cache_rows=self._cache_rows)
+            self.stats["cache_rows_read"] += rows_read
+            self.stats["cache_rows"] += self._cache_rows
 
     def _land(self, step: _StepInFlight, out: Dict[int, List[int]], sp: tracing.span) -> None:
         """Read a dispatched step and hand each row's token to the request that
@@ -624,6 +651,7 @@ class ContinuousBatcher:
         if self._ssm_step_bytes:
             sp.set(ssm_state_bytes=self._ssm_step_bytes)
             self.stats["ssm_state_bytes"] += self._ssm_step_bytes
+        self._count_rows_read(step.cache_rows_read, sp)
         self.stats["decode_steps"] += 1
         with tracing.span("llm.step.scatter"):
             for s, req in step.rows:
@@ -645,6 +673,7 @@ class ContinuousBatcher:
         b = self._block
         with tracing.span("llm.step.upload"):
             self._live[:] = [r is not None for r in self._by_slot]
+            rows_read = self._rows_read(live, b)
         with tracing.span("llm.step.dispatch"):
             after, self.cache, self._rng, touched = _pass_step_rowpos(
                 self.params, self.cache, self._ints, self._floats, self._rng, cfg=self.cfg,
@@ -691,6 +720,7 @@ class ContinuousBatcher:
             said.update(moe_rows=len(live) * b, moe_experts_touched=float(touched))
             self.stats["moe_assignments"] += len(live) * b * self.cfg.n_experts_per_tok
         sp.set(**said)
+        self._count_rows_read(rows_read, sp)
         self.stats["decode_steps"] += 1
         self.stats["tokens_out"] += handed
         self.stats["block_passes"] += len(live)
@@ -740,8 +770,8 @@ class ContinuousBatcher:
 
     def _release(self, slot: int) -> None:
         """The slot frees for the next admit and asks nothing of the sampler
-        until then: a dense model's step is not told which rows are live, and
-        one finished top-p request would leave every later step sorting."""
+        until then: the sampler reads every row's knobs, live or not, and one
+        finished top-p request would leave every later step sorting."""
         samples, truncates = self._asks(slot)
         self._sample_rows -= samples
         self._truncate_rows -= truncates

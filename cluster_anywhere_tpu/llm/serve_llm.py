@@ -274,6 +274,10 @@ class ContinuousLLMServer:
                  "causal decode steps dispatched while the step before was unread: the device had its next program queued"),
                 ("late_rows", "ca_serve_late_rows_total",
                  "rows a decode step computed for a request that had ended while the step was in flight: dropped"),
+                ("cache_rows_read", "ca_serve_cache_rows_read_total",
+                 "slots of a layer's keys the decode steps' attention fetched: the live rows' own, in whole key blocks"),
+                ("cache_rows", "ca_serve_cache_rows_total",
+                 "slots of a layer's keys the cache held over those steps: what cache_rows_read is a share of"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             m.Gauge(

@@ -16,13 +16,20 @@ that write it.  An attention block is
 transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
 them: the prefill core writes a prompt's k, v into zeroed rows and attends
 with the pad-masked flash kernel (`_prefill_block`); the decode core writes
-row b's new k, v at [layer, b, pos[b]] of the stacks and attends over the
-layer's full T_max with a position mask (`_block_decode_rowpos`: static
-shapes, no recompilation per step).  It reads a layer's cache once, as stored:
-the query [B, 1, H, D] is grouped to [B, 1, H_kv, H // H_kv, D] and contracted
-with k, v [B, T_max, H_kv, D] in the cache's dtype with an f32 accumulator
-(`_masked_attention`).  Nothing of the cache's size is repeated to H heads or
-copied to f32; only the prefill repeats its own k, v for the flash kernel.  A
+row b's new k, v at [layer, b, pos[b]] of the stacks and attends to the row's
+own slots [pads[b], pos[b]] (`_block_decode_rowpos`: static shapes, no
+recompilation per step).  On a TPU, over a cache of more than one row
+(`_on_kernel`), it attends through a kernel (ops/attention.py
+`decode_attention`) that is handed the stacks as they lie
+and the layer's index, and fetches, of the layer's k and v, the key blocks
+that hold those slots of the rows that hold a request and no others: what a
+step reads of the cache follows what is live, not the cache's size.  Anywhere
+else (and as the kernel's reference) the layer is taken out of the stacks and
+the query [B, 1, H, D], grouped to [B, 1, H_kv, H // H_kv, D], is contracted
+with all of k, v [B, T_max, H_kv, D] in the cache's dtype with an f32
+accumulator under a position mask (`_masked_attention`).  Nothing of the
+cache's size is repeated to H heads or copied to f32 on either path; only the
+prefill repeats its own k, v for the flash kernel.  A
 state-space block is `_ssm_half`, `_ffn_half` around `_ssm_mix` from the zero
 state over a whole prompt (`_ssm_prefill_block`, which is training's block
 with the pads masked: they leave the state untouched) or from a slot's own
@@ -83,7 +90,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.attention import attention
+from ..ops.attention import attention, decode_attention, decode_on_kernel, decode_rows_read, decode_span
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
     SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
@@ -248,6 +255,41 @@ def cache_bytes_per_token(cache) -> int:
                for n in names)
 
 
+def key_slots(cache, first=None, last=None) -> int:
+    """The slots of one layer's keys (and as many of its values, or its latent
+    rows) in a cache that attends: every row's T_max, what a decode step's
+    attention may read of a layer; given first and last (numpy [rows], the
+    host's), the slots the step fetches for rows that attend to [first, last)
+    of their own: whole key blocks of those rows under the decode kernel
+    (ops/attention.py decode_rows_read), every slot of every row under latent
+    attention, whose core contracts with the layer whole.  0 for a cache of
+    recurrent state alone."""
+    name = next((n for n in ("k", "ckv") if n in cache), None)
+    if name is None:
+        return 0
+    _, rows, t_max, *heads = cache[name].shape
+    if first is None or name == "ckv":
+        return rows * t_max
+    return int(decode_rows_read(first, last, t_max, heads[0]).sum())
+
+
+def _on_kernel(cache) -> bool:
+    """Whether a decode step over `cache` attends through the decode kernel: on
+    a TPU, keys and values, more than one row.  A cache of one row (an admit's
+    suffix step, a check's pass over one request) keeps the dense contraction:
+    there is no dead row to spare it, and with a batch axis of one the chip's
+    compiler gives the carried stacks a layout of its own and copies them to
+    the kernel's at every layer (16 x 2 x 25 MB a token at Mistral's widths;
+    from two rows on the stacks are read where they lie)."""
+    return decode_on_kernel() and "k" in cache and cache["k"].shape[1] > 1
+
+
+def _span(cache, valid_len, pads, live):
+    """`decode_span` of a decode step's rows over this cache's keys and values."""
+    _, _, t_max, kv, _ = cache["k"].shape
+    return decode_span(pads, valid_len, live, t_max, kv)
+
+
 def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, k_rope, c_kv):
     """The decode block's core under latent attention, one token a row: row b's
     latent c_kv [B, 1, R] and rotated key k_rope [B, 1, rope] are written at
@@ -273,7 +315,7 @@ def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, 
 
 
 def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads, live=None,
-                         experts=None):
+                         experts=None, span=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
     cache: the attention layers' stacks k, v [n_attn, B, Tmax, KV, D] (among
@@ -282,9 +324,11 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     writes its k/v at [layer, b, pos[b]], one scatter of [B, KV, D] an array
     and nothing else of the stacks, takes RoPE position pos[b] - pads[b], and
     attends to slots [pads[b], pos[b]] of the layer, read where it lies.
-    live: [B] bool, the rows that hold a request: an empty slot's row takes no
-    expert (None: every row does).  Returns (x, the cache after, experts
-    touched or None: `_ffn_half`).
+    live: [B] bool, the rows that hold a request: an empty slot's row reads
+    nothing of the cache on a TPU (the kernel returns it zeros) and takes no
+    expert (None: every row does both).  span: `decode_span` of the rows, made
+    once a step by `decode_rows`; None: made here.  Returns (x, the cache
+    after, experts touched or None: `_ffn_half`).
 
     x: [B, T, E] with T > 1 is one pass of a model that generates by blocks
     (`cfg.block_length` = T): row b's T positions lie at slots pos[b] ..
@@ -303,9 +347,16 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
                 at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
             k_all = cache["k"].at[at].set(new(k))
             v_all = cache["v"].at[at].set(new(v))
-            k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
-        with jax.named_scope("attn.core"):
-            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads)  # per-row length
+        if _on_kernel(cache):
+            # the stacks and the layer's index: a layer's slice handed to a kernel is a copy of it
+            with jax.named_scope("attn.core"):
+                attn = decode_attention(q, k_all, v_all, layer,
+                                        _span(cache, pos + t, pads, live) if span is None else span)
+        else:
+            with jax.named_scope(STATE_SCOPE["attn"]):
+                k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
+            with jax.named_scope("attn.core"):
+                attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads)  # per-row length
         return attn, {**cache, "k": k_all, "v": v_all}
 
     positions = (pos - pads)[:, None]
@@ -430,7 +481,9 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
 def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
     """The decode program's body: one token for every row of the cache, each
     at its own depth.  tokens, pos, pads: [B] (`_block_decode_rowpos` says what
-    each row does with its own); live: [B] bool or None.  Returns (logits
+    each row does with its own); live: [B] bool, the rows that hold a request
+    (what an empty slot's row is spared: `_block_decode_rowpos`), or None, every
+    row (`generate`, `decode_one`, a suffix step).  Returns (logits
     [B, V], updated cache, experts touched: the mean over the expert layers of
     the experts that were given a row, None for a dense model; where this
     device holds a share of the experts, [2]: that of the held, and the mean of
@@ -447,8 +500,11 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
         if not blocks:
             x = x[:, None, :]  # [B,1,E]
 
+    # what the attention kernel is told of the rows: made once, every layer reads the same
+    span = _span(cache, pos + (tokens.shape[1] if blocks else 1), pads, live) if _on_kernel(cache) else None
+
     def attn(x, bp, experts, cache, layer):
-        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts)
+        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts, span)
 
     def ssm(x, bp, experts, cache, layer):
         return _ssm_block_decode(bp, x, cache, layer, cfg, live, experts)

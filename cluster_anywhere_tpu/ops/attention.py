@@ -23,6 +23,14 @@ backend (tests, virtual meshes); it never changes algorithm by shape.  Tests
 run the kernels themselves in interpret mode by passing
 `flash_attention(..., interpret=True)` (tests/test_ops.py); nothing else
 selects it.
+
+A decode step has a kernel of its own, `decode_attention`: a few query
+positions a row against the row's own slots of a key/value cache, which it
+reads where it lies (the whole stacks and a layer's index) and only as far as
+rows hold a request and their contexts reach.  `decode_on_kernel()` is its
+dispatch, by the same rule; its dense counterpart is the caller's
+(models/generate.py `_masked_attention`), and tests/test_decode_attention.py
+runs it interpreted.
 """
 
 from __future__ import annotations
@@ -593,6 +601,182 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=N
     if block > 1 and extra % block:
         raise ValueError(f"a sequence of {t} under the block mask of {block}: {extra} columns on the left shift its blocks")
     return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)[:, extra:]
+
+
+# --------------------------------------------------------------------------
+# decode: a few query positions a row against the row's own part of a cache
+# --------------------------------------------------------------------------
+
+# A grid step of the decode kernel fetches DECODE_BLOCK_K cache slots, or as many as hold
+# DECODE_BLOCK_ROWS cached heads where that is more: a step costs about 0.35 us whatever it
+# fetches, so few cached heads a slot take more slots a step (one cached head: the whole row)
+DECODE_BLOCK_K = 256
+DECODE_BLOCK_ROWS = 2048
+
+
+def decode_key_block(t_max: int, kv: int) -> int:
+    """The decode kernel's key block over a cache of `t_max` slots of `kv`
+    cached heads: the largest divisor of t_max that is a multiple of 8 and
+    within the cap above, t_max itself where there is none."""
+    cap = max(DECODE_BLOCK_K, DECODE_BLOCK_ROWS // kv)
+    return max((b for b in range(8, min(cap, t_max) + 1, 8) if t_max % b == 0), default=t_max)
+
+
+def decode_block_span(first, last, block_k: int, t_max: int):
+    """(lo, hi): the key blocks that hold slots [first, last) of a row, both
+    inclusive and inside the cache whatever a stale row says.  Arrays or scalars
+    of numpy's (on the host) or of jax's (in a program): the kernel's index
+    maps, its body and the batcher's count of the rows a step reads share it."""
+    top = t_max // block_k - 1
+    lo = (first // block_k).clip(0, top)
+    return lo, ((last - 1) // block_k).clip(lo, top)
+
+
+def decode_rows_read(first, last, t_max: int, kv: int):
+    """The cache slots the decode kernel fetches, a layer's K (and as many of
+    its V), for rows that attend to [first, last): whole key blocks."""
+    block_k = decode_key_block(t_max, kv)
+    lo, hi = decode_block_span(first, last, block_k, t_max)
+    return (hi - lo + 1) * block_k
+
+
+def decode_on_kernel() -> bool:
+    """Whether a decode step's attention runs `decode_attention` (on a TPU) or
+    the caller's dense contraction (anywhere else): `attention()`'s rule."""
+    return _platform() == "tpu"
+
+
+def decode_span(first, last, live, t_max: int, kv: int):
+    """What `decode_attention` is told of a step's rows, made once a step (every
+    layer's call reads the same): int32 [5, B * key blocks a row].  Rows 0 and 1:
+    first, last (row b's queries see slots [first[b], last[b]) of its own cache
+    of `t_max` slots of `kv` cached heads).  Rows 2 and 3: the kernel's work, one
+    entry a grid step: the (row, key block) pairs that hold a slot of a row's
+    [first, last), the rows that hold a request only (live: [B], None: every
+    row) and in their order, a row's blocks ascending; behind them the last
+    entry again (where the index maps look one step ahead).  [4, 0]: how many."""
+    b = first.shape[0]
+    block_k = decode_key_block(t_max, kv)
+    first, last = first.astype(jnp.int32), last.astype(jnp.int32)
+    lo, hi = decode_block_span(first, last, block_k, t_max)
+    blocks = hi - lo + 1 if live is None else jnp.where(live, hi - lo + 1, 0)
+    ends = jnp.cumsum(blocks)
+    step = jnp.arange(b * (t_max // block_k), dtype=jnp.int32)
+    step = jnp.minimum(step, jnp.maximum(ends[-1] - 1, 0))
+    row = jnp.minimum(jnp.sum(ends[None, :] <= step[:, None], axis=1), b - 1)
+    block = (lo + blocks - ends)[row] + step  # the row's first block, and the steps into the row
+    wide = lambda a: jnp.zeros_like(step).at[:a.shape[0]].set(a)
+    return jnp.stack([wide(first), wide(last), row, block, wide(ends[-1:])]).astype(jnp.int32)
+
+
+def _decode_kernel(layer_ref, span_ref, q_ref, k_ref, v_ref, zeros_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   scale, block_k, t_max, kv, heads):
+    """One (row, key block) of the work.  q_ref [Tq * H, D]; k_ref, v_ref
+    [block_k * KV, D]: the block's slots as stored, a slot's KV cached heads one
+    after the other.  Every query head meets every cached head of the block in
+    one contraction and the mask keeps its own: the stationary operand of both
+    contractions is the block itself whatever the grouping, and no head is
+    taken out of a tile.  zeros_ref is the output before the kernel (aliased):
+    the rows the work does not name."""
+    del zeros_ref
+    i = pl.program_id(0)
+    b, j = span_ref[2, i], span_ref[3, i]
+    first, last = span_ref[0, b], span_ref[1, b]
+    lo, hi = decode_block_span(first, last, block_k, t_max)
+
+    @pl.when(j == lo)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(whole: bool):
+        """The block's part of the online softmax.  whole: every slot of the
+        block lies in [first, last); else the slots outside are masked out of
+        the scores and their values zeroed (a probability of 0 times whatever
+        lies there is not 0 if it is no number)."""
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        col = lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+        row = lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0)
+        ok = col % kv == row % heads // (heads // kv)  # a query head's own cached head
+        if not whole:
+            slot = j * block_k + col // kv
+            ok = ok & (slot >= first) & (slot < last)
+            at = j * block_k + lax.broadcasted_iota(jnp.int32, (v.shape[0], 1), 0) // kv
+            v = jnp.where((at >= first) & (at < last), v, jnp.zeros_like(v))
+        s = jnp.where(ok, s, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # the block holds a slot of [first, last), so m_new is a score's, and a masked one's p is 0
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    whole = (j * block_k >= first) & ((j + 1) * block_k <= last)
+    pl.when(whole)(functools.partial(block, True))
+    pl.when(~whole)(functools.partial(block, False))
+
+    @pl.when(j == hi)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, interpret: bool = False):
+    """Attention of a decode step, as a Pallas kernel over the caches where they
+    lie.  q: [B, Tq, H, D]; k, v: the WHOLE stacks [n_attn, B, T_max, KV, D(v)]
+    as `models.generate.init_cache` makes them, and `layer`, the index of the
+    one to read (a layer's slice of a stack handed to a kernel would be a copy
+    of the layer).  span: `decode_span`'s, of this cache: row b's Tq queries all
+    see slots [first[b], last[b]) of its own cache, in both directions, and a
+    row that holds no request returns zeros.  Returns [B, Tq, H, Dv].
+
+    The grid is the work `span` lists and no longer: a step a key block
+    (`decode_key_block(T_max, KV)` slots, all their cached heads) that holds a
+    slot of a live row's [first, last), about 0.35 us a step and the block's
+    read.  A block outside, and every block of a row that holds no request, is
+    neither fetched nor computed nor a step.  Online softmax in f32 over a
+    row's blocks; the probabilities go into the second contraction in the
+    cache's dtype."""
+    b, tq, h, d = q.shape
+    n, _, t_max, kv, dv = v.shape
+    block_k = decode_key_block(t_max, kv)
+
+    def kv_map(i, layer_ref, span_ref):
+        return layer_ref[0], span_ref[2, i], span_ref[3, i], 0
+
+    def q_map(i, layer_ref, span_ref):
+        return span_ref[2, i], 0, 0
+
+    # a slot's cached heads lie one after the other: [T_max, KV, D] is [T_max * KV, D] as stored
+    flat = lambda a: a.reshape(n, b, t_max * kv, a.shape[-1])
+    m = tq * h
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=d ** -0.5 if scale is None else scale, block_k=block_k,
+                          t_max=t_max, kv=kv, heads=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(span[4, 0],),
+            in_specs=[
+                pl.BlockSpec((None, m, d), q_map),
+                pl.BlockSpec((None, None, block_k * kv, d), kv_map),
+                pl.BlockSpec((None, None, block_k * kv, dv), kv_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, m, dv), q_map),
+            scratch_shapes=[pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, 1), jnp.float32),
+                            pltpu.VMEM((m, dv), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, m, dv), q.dtype),
+        input_output_aliases={5: 0},  # the zeros: what a row without work returns
+        interpret=interpret,
+        name="decode_attn",  # the kernel's name in a device trace
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), span, q.reshape(b, m, d), flat(k), flat(v),
+      jnp.zeros((b, m, dv), q.dtype))
+    return out.reshape(b, tq, h, dv)
 
 
 def flash_numerics_errors() -> dict:

@@ -180,10 +180,13 @@ def _reads_the_experts_where_they_are(compiled) -> bool:
 
 
 @functools.lru_cache(maxsize=None)  # a model's step compiles once for the tests that read it
-def _compiled_decode_step(cfg, device, slots=32, t_max=768):
+def _compiled_decode_step(cfg, device, slots=32, t_max=768, on_kernel=False):
     """The continuous batcher's decode step as the serving cells run it (32
-    slots, t_max 768, the cache donated), compiled for `device`.  Returns
-    (compiled, the shapes of its parameters, of its cache)."""
+    slots, t_max 768, the cache donated), compiled for `device`.  on_kernel:
+    with the dispatchers' answer of a TPU, so that the step attends through
+    the decode kernel as it does on the chip (without, it takes the CPU's dense
+    contraction, compiled for the chip: what the chip ran before the kernel).
+    Returns (compiled, the shapes of its parameters, of its cache)."""
     from cluster_anywhere_tpu.llm import continuous
 
     one = SingleDeviceSharding(device)
@@ -193,15 +196,18 @@ def _compiled_decode_step(cfg, device, slots=32, t_max=768):
     cache = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max)))
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     # the causal step takes the step before's tokens from the device beside the host's rows
-    # (five, and a mixture of experts is told the live slots in a sixth); a model that generates
+    # (six: the last says which slots the step holds); a model that generates
     # by blocks of B takes a block's B tokens and B fixed flags a slot, and its own step
-    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 6 if cfg.n_experts else 5
+    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 6
     step = continuous._pass_step_rowpos if cfg.generates_blocks else continuous._decode_step_rowpos
     ints = on_chip(jax.ShapeDtypeStruct((rows, slots), jnp.int32))
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
     prev = () if cfg.generates_blocks else (on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),)
     fn = lambda *a: step.__wrapped__(*a, cfg=cfg)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, *prev, key).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        if on_kernel:
+            patch.setattr(attention, "_platform", lambda: "tpu")
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, *prev, key).compile()
     return compiled, params, cache
 
 
@@ -260,6 +266,30 @@ def test_decode_step_reads_a_state_space_layers_weights_where_they_are(v5e):
     assert seen == matrices and copies == []
 
 
+def _assert_the_stacks_are_written_in_place(compiled, cache):
+    """A value as large as one of the cache's stacks is that stack written in
+    place or moved between memory spaces, never a copy, and nothing writes a
+    whole layer's keys or values.  Returns the buffers of a layer's keys' size
+    in the chip's memory."""
+    stacks = {c.size for c in cache.values()}
+    a_layers_keys = cache["k"].size // cache["k"].shape[0]
+    seen, a_layers = set(), []
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        # in the chip's memory: a small stack that the compiler stages in fast memory
+        # (space S(1): JAMBA8's 5 and 52 MB of state; a deployment's 272 MB do not fit) is its to move
+        if n in stacks and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
+            assert in_place or op == "copy-done", line[:200]  # copy-done: back from fast memory
+            seen.add(n)
+        if n == a_layers_keys:
+            assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
+            if "S(1)" not in shape:  # a small stack on its way to fast memory goes a layer at a time
+                a_layers.append(line[:200])
+    assert cache["k"].size in seen  # the keys' write was read for what it is
+    return a_layers
+
+
 # Mistral-7B's widths (32 Q / 8 KV heads x 128), four layers deep
 MISTRAL4 = dict(
     vocab_size=512, n_layers=4, d_model=4096, n_heads=32, n_kv_heads=8, d_head=128, d_ff=14336,
@@ -284,20 +314,7 @@ def test_decode_step_writes_the_cache_in_place(v5e, model):
     # a layer's new recurrent state is a value before it is written over the old one
     a_state = generate.recurrent_state_bytes(cache) // max(cfg.layer_kinds.count("ssm"), 1)
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10 + a_state
-    stacks = {c.size for c in cache.values()}
-    a_layers_keys = cache["k"].size // cache["k"].shape[0]
-    seen = set()
-    for _, n, line in _buffers(compiled, width=None):
-        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
-        # in the chip's memory: a small stack that the compiler stages in fast memory
-        # (space S(1): JAMBA8's 5 and 52 MB of state; a deployment's 272 MB do not fit) is its to move
-        if n in stacks and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
-            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
-            assert in_place or op == "copy-done", line[:200]  # copy-done: back from fast memory
-            seen.add(n)
-        if n == a_layers_keys:
-            assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
-    assert cache["k"].size in seen  # the keys' write was read for what it is
+    _assert_the_stacks_are_written_in_place(compiled, cache)
 
 
 # A.X-K1's widths as one chip of 16 holds a layer (latent attention 64 heads of 128 + 64 over a
@@ -439,20 +456,56 @@ def test_block_step_writes_its_rows_in_place_and_sorts_no_vocabulary(v5e):
     logits_bytes = slots * b * cfg.vocab_size * 4
     # the logits of every position in float32, and the noise of a sampled pass beside them
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10 + 3 * logits_bytes
-    a_layers_keys = cache["k"].size // cache["k"].shape[0]
-    seen = set()
-    for _, n, line in _buffers(compiled, width=None):
-        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
-        if n == cache["k"].size and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
-            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
-            assert in_place or op == "copy-done", line[:200]
-            seen.add(n)
-        if n == a_layers_keys:
-            assert "dynamic-update-slice" not in line and "scatter" not in line, line[:200]
-    assert cache["k"].size in seen
+    _assert_the_stacks_are_written_in_place(compiled, cache)
     sorts = [int(math.prod(int(d) for d in m.split(",")))
              for m in re.findall(r"= \(?\w+\[([\d,]+)\][^=]*? sort\(", compiled.as_text())]
     assert sorts and max(sorts) <= slots * b * cfg.n_experts < cfg.vocab_size, sorts
+
+
+@pytest.mark.parametrize("model", ["MISTRAL4", "OLMOE3", "SDAR3", "JAMBA8"])
+def test_decode_step_attends_through_the_kernel_over_the_stacks_as_stored(v5e, model):
+    """The decode step of each serving configuration's widths as the chip runs
+    it (32 slots x 768; SDAR's is a pass of blocks of 4): the attention core is
+    the decode kernel (`ops/attention.py decode_attention`), which takes the
+    whole stacks of keys and values and the layer's index.  No buffer of a
+    layer's K or V [S, T_max, KV, D] exists (read out of the stack for the
+    dense contraction it was 50-200 MB a layer and step), and none of a stack's
+    size but the donated one, written in place: the kernel's view of a slot's
+    cached heads as rows one after the other, [T_max * KV, D], is the stack as
+    it lies, whatever tiling the compiler gave KV of 16, 8, 4 and 1."""
+    cfg = transformer.TransformerConfig(**globals()[model])
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], on_kernel=True)
+    kernels = re.findall(r"%(decode_attn[\w.]*) = \S+ custom-call\(", compiled.as_text())
+    assert kernels and _has_kernel(compiled)
+    assert _assert_the_stacks_are_written_in_place(compiled, cache) == []
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in cache.values())
+    logits = 32 * cfg.block_length * cfg.vocab_size * 4 if cfg.generates_blocks else 0
+    a_state = generate.recurrent_state_bytes(cache) // max(cfg.layer_kinds.count("ssm"), 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10 + a_state + 3 * logits
+    # the dense contraction's program, which the other tests of the step read, has no such kernel
+    dense, _, _ = _compiled_decode_step(cfg, v5e[0])
+    assert "decode_attn" not in dense.as_text()
+
+
+def test_a_cache_of_one_row_keeps_the_dense_contraction(v5e, on_tpu):
+    """An admit's suffix step (one request's rows, a cache of batch one) at
+    Mistral's widths: no decode kernel, and nothing of a stack's size beside
+    the donated rows.  Through the kernel the chip's compiler gave the carried
+    stacks of batch one a layout of its own and copied both to the kernel's at
+    every layer (0.8 GB of temporaries at 16 layers)."""
+    from cluster_anywhere_tpu.llm import continuous
+
+    cfg = transformer.TransformerConfig(**MISTRAL4)
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    params = on_chip(jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0)))
+    rows = on_chip(jax.eval_shape(lambda: generate.init_cache(cfg, 1, 768)))
+    row = on_chip(jax.ShapeDtypeStruct((1,), jnp.int32))
+    fn = lambda *a: continuous._suffix_step.__wrapped__(*a, cfg=cfg)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, rows, row, row, row).compile()
+    assert "decode_attn" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < rows["k"].size * 2 / 2
 
 
 @pytest.mark.parametrize("bucket", [64, 512])

@@ -546,7 +546,7 @@ def _decode_step_program(cfg, slots, t_max):
     params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
     cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
     key = jax.eval_shape(lambda: jax.random.key(0))
-    ints = jax.ShapeDtypeStruct((6 if cfg.n_experts else 5, slots), jnp.int32)
+    ints = jax.ShapeDtypeStruct((6, slots), jnp.int32)
     floats = jax.ShapeDtypeStruct((2, slots), jnp.float32)
     prev = jax.ShapeDtypeStruct((slots,), jnp.int32)
     # a fresh function each time: jit keeps what it traced for one it has seen
@@ -951,7 +951,8 @@ def test_the_batcher_holds_no_model_mathematics():
     # and the cache's layout, which is `generate.py`'s: what a slot holds, how one
     # request's rows are written over it, how much of it is recurrent state
     assert imported == {"prefill", "decode_rows", "_nucleus_mask", "TransformerConfig",
-                        "init_cache", "install_rows", "recurrent_state_bytes", "cache_bytes_per_token"}
+                        "init_cache", "install_rows", "recurrent_state_bytes", "cache_bytes_per_token",
+                        "key_slots"}
     for name in ("_rms_norm", "_scan_blocks", "_scan_layers", "_block_", "_half", "_ssm_mix", "_project_qkv",
                  "_rope", "lax.scan", '"k"', '"v"', '"h"', "n_kv_heads", "d_inner"):
         assert name not in source, name
@@ -1038,8 +1039,8 @@ def test_the_step_sorts_only_while_a_truncating_request_lives(llm_spans):
     the held slots sampled and how many of those truncated as it was dispatched;
     `stats["sort_steps"]` counts the steps in which one did, and a slot that
     frees, by its request's end or its cancel, asks nothing from then on
-    (temperature 0, top-k 0, top-p 1.0): a dense model's step is not told which
-    rows are live, so a finished top-p request's knobs left in its slot would
+    (temperature 0, top-k 0, top-p 1.0): the sampler reads every row's knobs,
+    live or not, so a finished top-p request's knobs left in its slot would
     keep every later step sorting.  A request whose last token is in flight
     holds its slot until that step is read, so its knobs are in one step more
     than its rows are.  A stale top-k or top-p beside temperature 0 never counts."""
@@ -1098,6 +1099,68 @@ def test_the_step_sorts_only_while_a_truncating_request_lives(llm_spans):
 
     shipped = inspect.getsource(serve_llm.ContinuousLLMServer._sync_engine_metrics)
     assert '"sort_steps", "ca_serve_sort_steps_total"' in shipped
+
+
+@pytest.mark.parametrize("model", ["causal", "blocks"])
+def test_a_step_says_how_much_of_the_cache_its_live_rows_could_reach(model, llm_spans):
+    """`cache_rows_read` on `llm.step` and in `cb.stats`: of a layer's keys, the
+    slots the step's attention kernel fetches, the live rows' own [pads, pos +
+    the step's tokens) in whole key blocks, by the kernel's own helper on the
+    host's vectors as the step was dispatched; `cache_rows`, the slots x t_max
+    it is a share of.  After an admit, a request's end and a cancel, a freed
+    slot's stale pos and pads count for nothing."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+    from cluster_anywhere_tpu.util import tracing
+
+    attention = importlib.import_module("cluster_anywhere_tpu.ops.attention")
+    blocks = model == "blocks"
+    cfg = TransformerConfig(**dict(_TINY, **(dict(
+        vocab_size=251, n_experts=8, n_experts_per_tok=2, moe_gated=True, block_length=4, mask_token_id=250,
+        denoise_steps=4, dtype=jnp.float32, param_dtype=jnp.float32) if blocks else {})))
+    slots, t_max, tokens = 4, 64, 4 if blocks else 1
+    assert attention.decode_key_block(t_max, cfg.n_kv_heads) == t_max  # one key block a row here ...
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "DECODE_BLOCK_K", 8)  # ... so rows of eight slots
+        patch.setattr(attention, "DECODE_BLOCK_ROWS", 8)
+        cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=slots, t_max=t_max,
+                               prefill_buckets=(8, 32))
+        want, reqs = [], []
+        token = tracing.push_execution(TRACE)
+        try:
+            for i in range(40):
+                if i in (0, 3, 5):
+                    reqs.append(cb.submit(list(range(1, 4 + 5 * len(reqs))), max_new_tokens=(6, 30, 14)[len(reqs)]))
+                if i == 12:
+                    assert cb.cancel(reqs[1].request_id)
+                cb._admit()
+                held = [s for s, _ in _rows_of_the_next_step(cb)] if not blocks else \
+                    [s for s, r in enumerate(cb._by_slot) if r is not None]
+                if held:
+                    first, last = cb._pads[held], cb._pos[held] + tokens
+                    want.append(int(sum((-(-l // 8) - f // 8) * 8 for f, l in zip(first, last))))
+                cb.step()
+        finally:
+            tracing.pop_execution(token)
+    assert not cb.has_work and reqs[1].done and len(reqs[1].out_tokens) < 30 and cb.stats["cancelled"] == 1
+    steps = [e for e in llm_spans() if e["name"] == "llm.step" and e["live"]]
+    assert [e["cache_rows_read"] for e in steps] == want and len(want) == cb.stats["decode_steps"] > 12
+    assert {e["cache_rows"] for e in steps} == {slots * t_max}
+    assert cb.stats["cache_rows_read"] == sum(want) and cb.stats["cache_rows"] == len(want) * slots * t_max
+    # a row's share grows with its depth, one live row reads less than three, and never the cache
+    assert min(want) >= 8 and max(want) < slots * t_max / 2 and len(set(want)) > 3
+    import inspect
+
+    from cluster_anywhere_tpu.llm import serve_llm
+
+    shipped = inspect.getsource(serve_llm.ContinuousLLMServer._sync_engine_metrics)
+    assert '"cache_rows_read", "ca_serve_cache_rows_read_total"' in shipped
+    assert '"cache_rows", "ca_serve_cache_rows_total"' in shipped
 
 
 # -- the cache is the layer loop's carry: one row a slot written in place ---------

@@ -209,8 +209,8 @@ def test_decode_step_is_told_which_slots_are_live():
 
     def step(tokens, live, params=params, cfg=cfg):
         # the int32 input's rows: tokens, pos, pads, top_ks, fresh (every slot feeds the
-        # host's token, not the step before's) and, when told, live
-        rows = [tokens, (2, 0, 0, 0), (0,) * 4, (0,) * 4, (1,) * 4] + ([live] if live else [])
+        # host's token, not the step before's), live
+        rows = [tokens, (2, 0, 0, 0), (0,) * 4, (0,) * 4, (1,) * 4, live]
         nxt, _, _, touched = continuous._decode_step_rowpos.__wrapped__(
             params, cache, jnp.asarray(rows, jnp.int32), floats, jnp.zeros(4, jnp.int32),
             jax.random.key(0), cfg=cfg)
@@ -222,9 +222,10 @@ def test_decode_step_is_told_which_slots_are_live():
     assert int(nxt[0]) == int(nxt2[0]) and float(touched2) == 2.0
     _, all_live = step((5, 9, 11, 3), (1,) * 4)
     assert 2.0 <= float(all_live) <= 8.0
-    # a dense model's step is not told (four rows, no fifth) and says nothing
+    # a dense model's step is told too (its attention reads the live rows' slots alone)
+    # and says nothing of experts
     dense = TransformerConfig(**dict(SMALL, n_experts=0, moe_gated=False))
-    assert step((5, 9, 11, 3), None, init_params(jax.random.key(4), dense), dense)[1] is None
+    assert step((5, 9, 11, 3), (1, 0, 0, 0), init_params(jax.random.key(4), dense), dense)[1] is None
 
 
 def test_batcher_reports_rows_experts_and_assignments(monkeypatch):
