@@ -4,8 +4,9 @@ python/ray/llm's engine does exactly this; redesigned here for the XLA
 compilation model instead of paged CUDA kernels).
 
 The scheduler owns a fixed pool of decode SLOTS over one shared cache (keys
-and values [L, S, T_max, KV, D], and a state-space layer's recurrent state:
-models/generate.py init_cache).  Each slot runs one request; requests at different
+and values [L, S, T_max, KV, D], a window layer's ring [L, S, W, KV, D], latent
+rows, and a state-space layer's recurrent state: models/generate.py
+init_cache; rows are a pytree here, and no array's time axis is assumed).  Each slot runs one request; requests at different
 depths decode together in ONE jitted step whose shapes never change — slot
 count and cache length are static, per-row positions are traced — so
 admitting or finishing requests never recompiles anything:
@@ -80,7 +81,7 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, cache_bytes_per_token, decode_rows, init_cache, install_rows, key_slots, prefill,
+    _nucleus_mask, cache_bytes_per_token, cache_kind_bytes, decode_rows, init_cache, install_rows, key_slots, prefill,
     recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
@@ -147,7 +148,7 @@ class _StepInFlight:
     # among them), as the sampler saw their knobs: how many sample, how many truncate
     sample_rows: int
     truncate_rows: int
-    cache_rows_read: int  # `ContinuousBatcher._rows_read` of those slots, as their rows stood then
+    cache_rows_read: tuple  # `ContinuousBatcher._rows_read` of those slots, as their rows stood then
 
 
 def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
@@ -433,8 +434,10 @@ class ContinuousBatcher:
         self._ssm_slot_bytes = recurrent_state_bytes(self.cache) // slots
         self._ssm_step_bytes = 2 * slots * self._ssm_slot_bytes
         # the slots of a layer's keys that a step could read (as many of its values, or its
-        # latent rows): what `cache_rows_read` is a share of; 0 for recurrent state alone
-        self._cache_rows = key_slots(self.cache)
+        # latent rows; over layers of two extents, their mean): what `cache_rows_read` is a
+        # share of; 0 for recurrent state alone
+        self._cache_rows = key_slots(self.cache)[0]
+        self._cache_bytes = cache_kind_bytes(self.cache)
         # the decode step's per-slot inputs as its program takes them: two host
         # arrays, the scheduler's vectors their rows, written between steps (an
         # admit, a cancel) and as a step is dispatched or read.  A causal step
@@ -510,6 +513,14 @@ class ContinuousBatcher:
             # decode_attention; a latent core reads every slot) and the slots the cache
             # holds, a step; both stay 0 for a cache of recurrent state alone
             "cache_rows_read": 0, "cache_rows": 0,
+            # of cache_rows_read, the part read in window layers' rings (a live row's whole
+            # ring a layer); stays 0 without window layers
+            "window_rows_read": 0,
+            # the cache's bytes by the extent of its rows: the stacks as long as a context
+            # (keys and values, latent rows), and the window layers' rings
+            "cache_full_bytes": self._cache_bytes["full"], "cache_window_bytes": self._cache_bytes["window"],
+            # the rings' share of the two, percent
+            "cache_window_share": 100.0 * self._cache_bytes["window"] / max(sum(self._cache_bytes.values()), 1),
             # what one token of a context takes in the cache over all the layers
             # that attend, by the cache's own shapes: a constant of the deployment
             "cache_bytes_per_token": cache_bytes_per_token(self.cache),
@@ -619,19 +630,24 @@ class ContinuousBatcher:
             )
         return _StepInFlight(self._prev, touched, rows, self._sample_rows, self._truncate_rows, rows_read)
 
-    def _rows_read(self, slots: List[int], tokens: int) -> int:
+    def _rows_read(self, slots: List[int], tokens: int) -> tuple:
         """Of a layer's keys, the cache slots the attention fetches in a step
         that gives each of `slots` `tokens` positions from its `_pos` on: the
         rows' own [pads, pos + tokens) in whole key blocks, by the kernel's own
-        helper (models/generate.py key_slots).  The host's arithmetic on its
-        own vectors."""
-        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens)
+        helper, and the window layers' part of them (models/generate.py
+        key_slots: the mean over the attention layers where their extents
+        differ).  The host's arithmetic on its own vectors."""
+        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens, self.cfg.attn_window)
 
-    def _count_rows_read(self, rows_read: int, sp: tracing.span) -> None:
+    def _count_rows_read(self, rows_read: tuple, sp: tracing.span) -> None:
         if self._cache_rows:
-            sp.set(cache_rows_read=rows_read, cache_rows=self._cache_rows)
-            self.stats["cache_rows_read"] += rows_read
+            read, window = rows_read
+            sp.set(cache_rows_read=read, cache_rows=self._cache_rows)
+            self.stats["cache_rows_read"] += read
             self.stats["cache_rows"] += self._cache_rows
+            if self._cache_bytes["window"]:
+                sp.set(window_rows_read=window)
+                self.stats["window_rows_read"] += window
 
     def _land(self, step: _StepInFlight, out: Dict[int, List[int]], sp: tracing.span) -> None:
         """Read a dispatched step and hand each row's token to the request that

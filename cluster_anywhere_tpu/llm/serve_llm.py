@@ -278,8 +278,18 @@ class ContinuousLLMServer:
                  "slots of a layer's keys the decode steps' attention fetched: the live rows' own, in whole key blocks"),
                 ("cache_rows", "ca_serve_cache_rows_total",
                  "slots of a layer's keys the cache held over those steps: what cache_rows_read is a share of"),
+                ("window_rows_read", "ca_serve_window_rows_read_total",
+                 "of the slots fetched, those in window layers' rings"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
+            # the cache's bytes by the extent of its rows: constants of the deployment
+            for key, name, desc in (
+                ("cache_full_bytes", "ca_serve_cache_full_bytes",
+                 "bytes of the cache's stacks as long as a context: keys and values, latent rows"),
+                ("cache_window_bytes", "ca_serve_cache_window_bytes",
+                 "bytes of the window layers' rings of keys and values"),
+            ):
+                m.Gauge(name, desc).set(self.cb.stats[key])
             m.Gauge(
                 "ca_serve_engine_devices",
                 "devices holding this replica's model parameters",

@@ -11,6 +11,8 @@ kind alone,
     ckv: [n_attn, B, T_max, R], kr: [n_attn, B, T_max, rope up to 128s]  (latent
         attention: a token is one latent row and the one rotated key every
         head shares, no heads axis; `LATENT_LANES` says why the key is padded)
+    kw, vw: [n_win, B, W, H_kv, D]  (window layers: a ring of W = `window_extent`
+        slots a row whatever the context's length, position p at slot p mod W)
 the rows of one request written over a slot (`install_rows`), and the cores
 that write it.  An attention block is
 transformer.py's two halves (`_attention_half`, `_ffn_half`) around one of
@@ -35,6 +37,20 @@ state over a whole prompt (`_ssm_prefill_block`, which is training's block
 with the pads masked: they leave the state untouched) or from a slot's own
 state for one more token (`_ssm_block_decode`, which reads the layer's state
 out of the stacks and writes the new one in its place).
+
+A window layer (`transformer.is_window(kind)`: its queries see the last
+`cfg.attn_window` positions) has the same two blocks over stacks of its own:
+the prefill attends under the banded mask (ops/attention.py `window=`: key
+blocks before the band are skipped) and keeps the last W columns of its bucket
+round the ring; the decode core (`_kv_decode_core`, one for both kinds of
+stack) writes row b's k, v at slot pos[b] mod W and attends to the slots whose
+position, the newest that falls on them, lies in [max(pads[b], pos[b] + 1 -
+window), pos[b]]: through the same kernel told `ring=True` (a live row is one
+key block: one fetch), or `_masked_attention` under `_ring_seen`.  Keys are
+stored turned, so their order in the ring does not matter to the softmax.
+Which layers' rows share a stack, and where a layer's lie in it, is
+`_state_index`: the model's order among the layers that keep that kind of
+state, whatever their kind's FFN.
 
 Latent attention (`cfg.latent`) has the same two blocks with cores of its own.
 The prefill core EXPANDS: every head's key and value are made of the prompt's
@@ -69,7 +85,7 @@ inside their caller's program (the batcher's `_decode_step_rowpos` and
 
 Every stage runs under a `jax.named_scope` with the same name in every layer
 and every program (`embed`, `norm`, `attn.qkv`, `attn.rope`, `attn.cache`,
-`attn.core`, `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
+`attn.core` (`attn.core.window` in a window layer), `attn.out`, `ffn`, `head`, `sample`; a mixture of experts adds
 `moe.router`, `moe.dispatch`, `moe.experts`, `moe.combine` under `ffn`,
 parallel/moe.py, and `moe.shared` for its shared experts; a state-space layer
 writes `ssm.in`, `ssm.conv`, `ssm.scan`, `ssm.state`, `ssm.out` in place of the
@@ -88,20 +104,25 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from ..ops.attention import attention, decode_attention, decode_on_kernel, decode_rows_read, decode_span
+from ..ops.attention import (
+    DECODE_BLOCK_K, DECODE_BLOCK_ROWS, attention, decode_attention, decode_on_kernel, decode_rows_read, decode_span,
+)
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
-    SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
-    _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, layer_stacks,
+    _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
+    _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, core_scope, is_window,
+    layer_stacks,
 )
 
 # what a layer of each kind of state keeps of a sequence between two tokens, as
-# the cache's keys: one stacked array each over the layers that keep that kind
-LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr")}
+# the cache's keys: one stacked array each over the layers that keep that kind.
+# "attn_win": a window layer's keys and values, a ring of `window_extent` slots a row
+LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr"), "attn_win": ("kw", "vw")}
 # the scope a kind's state is read, written and installed under
-STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache"}
+STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache", "attn_win": "attn.cache"}
 
 
 # The rotated key's last axis in the cache is padded with zeros to a multiple
@@ -123,7 +144,31 @@ def _state_kind(kind: str, cfg: TransformerConfig) -> str:
     """The kind of state (LAYER_STATE's key) a layer of `kind` keeps."""
     if kind == "ssm":
         return kind
+    if is_window(kind):
+        return "attn_win"
     return "latent" if cfg.latent else "attn"
+
+
+def window_extent(cfg: TransformerConfig, t_max: int) -> int:
+    """The slots a window layer's cache keeps of a row: `cfg.attn_ring`, or
+    the least the decode kernel's key block allows that holds the window (256
+    at 8 cached heads: ops/attention.py decode_key_block); the whole context
+    where that is no longer.  Position p lies at slot p mod the extent."""
+    block = max(DECODE_BLOCK_K, DECODE_BLOCK_ROWS // cfg.n_kv_heads)
+    return min(cfg.attn_ring or -(-cfg.attn_window // block) * block, t_max)
+
+
+def _state_index(cfg: TransformerConfig):
+    """{kind: for each of the kind's layers, in order, its index in the stacks
+    of the state it keeps}: kinds that keep one kind of state (a mixture's
+    leading dense layers and its expert layers) share those stacks in the
+    model's order."""
+    seen, out = {}, {}
+    for kind in cfg.layer_kinds:
+        state = _state_kind(kind, cfg)
+        out.setdefault(kind, []).append(seen.get(state, 0))
+        seen[state] = seen.get(state, 0) + 1
+    return out
 
 
 def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
@@ -140,11 +185,17 @@ def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
     layers, which `routed_ffn` reads in place; None for a dense model or layer.  Returns (x, the
     cache after, {kind: ys over that kind's layers})."""
 
+    index = _state_index(cfg)
+
     def body(kind, carry, bp, held, layer):
         x, cache = carry
-        # a mixture's leading dense layers come first among the attention
-        # layers' state, so an expert layer's state lies that many further on
-        at = layer + cfg.n_dense_layers if kind == "attn" and cfg.n_dense_layers and layer is not None else layer
+        # where the layer's state lies in its stacks: a mixture's leading dense
+        # layers come first among the attention layers' state, so an expert
+        # layer's lies that many further on
+        at = layer
+        ahead = {a - i for i, a in enumerate(index[kind])}
+        if layer is not None and ahead != {0}:
+            at = layer + ahead.pop() if len(ahead) == 1 else jnp.asarray(index[kind], jnp.int32)[layer]
         x, cache, ys = bodies[kind](x, bp, (held, layer) if held else None, cache, at)
         return (x, cache), ys
 
@@ -156,7 +207,7 @@ def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None, scale=None,
-                      also=None):
+                      also=None, seen=None):
     """q: [B, Tq, H, D]; caches: [B, T_max, KV, D] as stored, never repeated to
     H heads and never copied to f32.  The query is viewed as [B, Tq, KV, R, D]
     (R = H // KV query heads share one cached head; R == 1 is multi-head
@@ -166,7 +217,9 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
     per-row [B] count of pad tokens, None = no padding).  For decode Tq == 1.
     scale: what the scores are multiplied by, d_head^-0.5 unless given.  also:
     (q2 [B, Tq, H, D2], k2 [B, T_max, KV, D2]), a second part of every query and
-    key kept in a cache of its own, whose products add to the scores.
+    key kept in a cache of its own, whose products add to the scores.  seen:
+    [B, T_max] bool, the slots a row's queries see, in place of valid_len and
+    pad (a window layer's ring: `_ring_seen`).
     Returns [B, Tq, H, Dv], Dv the cached values' width."""
     b, tq, h, d = q.shape
     t_max, kv = k_cache.shape[1:3]
@@ -178,9 +231,12 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
                                      preferred_element_type=jnp.float32)
     logits = logits * (cfg.d_head ** -0.5 if scale is None else scale)
     slots = jnp.arange(t_max)
-    mask = slots < jnp.reshape(valid_len, (-1, 1, 1, 1, 1))
-    if pad is not None:
-        mask = mask & (slots >= pad[:, None, None, None, None])
+    if seen is not None:
+        mask = seen[:, None, None, None, :]
+    else:
+        mask = slots < jnp.reshape(valid_len, (-1, 1, 1, 1, 1))
+        if pad is not None:
+            mask = mask & (slots >= pad[:, None, None, None, None])
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache, preferred_element_type=jnp.float32)
@@ -200,17 +256,31 @@ def _latent_attention(q_lat, q_rope, ckv, kr, valid_len, cfg: TransformerConfig,
                              also=(_lanes(q_rope), one(kr)))
 
 
+def _ring_seen(first, last, extent: int):
+    """[B, extent] bool: the slots of a ring of `extent`, position p at slot
+    p mod extent, whose position (the newest that falls on the slot, up to
+    last - 1) lies in [first, last).  first, last: [B]."""
+    behind = (last[:, None] - 1 - jnp.arange(extent)) % extent  # how far behind the newest a slot's position lies
+    return behind < (last - first)[:, None]
+
+
 def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     """Every kind of state the configuration's layers keep, each stacked over
     the layers that keep it alone: k, v [n_attn, B, t_max, KV, D], or under
     latent attention ckv [n_attn, B, t_max, R] and kr [n_attn, B, t_max, rope up
     to LATENT_LANES] (a token's latent and its rotated key);
+    the window layers' kw, vw [n_win, B, W, KV, D], W = `window_extent`: an
+    extent of their own, whatever the context's length;
     a state-space layer's convolution window [n_ssm, B, K-1, C] and its h
     [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length."""
     kinds = cfg.layer_kinds
     n_ssm = kinds.count("ssm")
-    n_attn = len(kinds) - n_ssm
+    n_win = sum(map(is_window, kinds))
+    n_attn = len(kinds) - n_ssm - n_win
     cache = {}
+    if n_win:
+        shape = (n_win, batch, window_extent(cfg, t_max), cfg.n_kv_heads, cfg.d_head)
+        cache.update(kw=jnp.zeros(shape, cfg.dtype), vw=jnp.zeros(shape, cfg.dtype))
     if n_attn and cfg.latent:
         rope = -(-cfg.qk_rope_head_dim // LATENT_LANES) * LATENT_LANES
         cache.update(ckv=jnp.zeros((n_attn, batch, t_max, cfg.kv_lora_rank), cfg.dtype),
@@ -248,29 +318,50 @@ def recurrent_state_bytes(cache) -> int:
 
 def cache_bytes_per_token(cache) -> int:
     """The bytes one token of one sequence takes in a cache over all the layers
-    that attend (its keys and values, or its latent row and rotated key):
-    what grows with a context.  0 for a cache of recurrent state alone."""
-    names = [n for kind in ("attn", "latent") for n in LAYER_STATE[kind] if n in cache]
+    that attend (its keys and values, or its latent row and rotated key; in a
+    window layer too, while the layer holds it: each array by its own extent).
+    0 for a cache of recurrent state alone."""
+    names = [n for kind in ("attn", "latent", "attn_win") for n in LAYER_STATE[kind] if n in cache]
     return sum(int(cache[n].size) * cache[n].dtype.itemsize // (cache[n].shape[1] * cache[n].shape[2])
                for n in names)
 
 
-def key_slots(cache, first=None, last=None) -> int:
-    """The slots of one layer's keys (and as many of its values, or its latent
-    rows) in a cache that attends: every row's T_max, what a decode step's
-    attention may read of a layer; given first and last (numpy [rows], the
-    host's), the slots the step fetches for rows that attend to [first, last)
-    of their own: whole key blocks of those rows under the decode kernel
-    (ops/attention.py decode_rows_read), every slot of every row under latent
-    attention, whose core contracts with the layer whole.  0 for a cache of
-    recurrent state alone."""
-    name = next((n for n in ("k", "ckv") if n in cache), None)
-    if name is None:
-        return 0
-    _, rows, t_max, *heads = cache[name].shape
-    if first is None or name == "ckv":
-        return rows * t_max
-    return int(decode_rows_read(first, last, t_max, heads[0]).sum())
+def cache_kind_bytes(cache) -> Dict[str, int]:
+    """{"full": the bytes of the stacks whose extent is the context's (keys and
+    values, latent rows), "window": those of the window layers' rings}."""
+    size = lambda kinds: sum(int(cache[n].size) * cache[n].dtype.itemsize
+                             for kind in kinds for n in LAYER_STATE[kind] if n in cache)
+    return {"full": size(("attn", "latent")), "window": size(("attn_win",))}
+
+
+def key_slots(cache, first=None, last=None, window: int = 0):
+    """(the slots of one layer's keys (and as many of its values, or its latent
+    rows) in a cache that attends, the window layers' part of that number).
+    Where the layers' extents differ (window layers keep a ring beside the full
+    layers' T_max) it is the sum over the attention layers divided by their
+    number, so one extent reads as ever.  Without first and last: every row's
+    extent, what a decode step's attention may read of a layer.  Given first
+    and last (numpy [rows], the host's: the rows attend to [first, last) of
+    their own, a window layer to the last `window` of those), the slots the
+    step fetches: whole key blocks of those rows under the decode kernel
+    (ops/attention.py decode_rows_read; a window layer's row is one block),
+    every slot of every row under latent attention, whose core contracts with
+    the layer whole.  (0, 0) for a cache of recurrent state alone."""
+    per_layer = {}  # the stack's name -> (its layers, the slots of one)
+    for name in ("k", "ckv", "kw"):
+        if name not in cache:
+            continue
+        n, rows, t_max, *heads = cache[name].shape
+        if first is None or name == "ckv":
+            per_layer[name] = n, rows * t_max
+        else:
+            lo = np.maximum(first, last - window) if name == "kw" else first
+            per_layer[name] = n, int(decode_rows_read(lo, last, t_max, heads[0]).sum())
+    layers = sum(n for n, _ in per_layer.values())
+    if not layers:
+        return 0, 0
+    ring = per_layer.pop("kw", (0, 0))
+    return (sum(n * slots for n, slots in per_layer.values()) + ring[0] * ring[1]) // layers, ring[0] * ring[1] // layers
 
 
 def _on_kernel(cache) -> bool:
@@ -281,13 +372,22 @@ def _on_kernel(cache) -> bool:
     compiler gives the carried stacks a layout of its own and copies them to
     the kernel's at every layer (16 x 2 x 25 MB a token at Mistral's widths;
     from two rows on the stacks are read where they lie)."""
-    return decode_on_kernel() and "k" in cache and cache["k"].shape[1] > 1
+    name = next((n for n in ("k", "kw") if n in cache), None)
+    return decode_on_kernel() and name is not None and cache[name].shape[1] > 1
 
 
-def _span(cache, valid_len, pads, live):
-    """`decode_span` of a decode step's rows over this cache's keys and values."""
-    _, _, t_max, kv, _ = cache["k"].shape
-    return decode_span(pads, valid_len, live, t_max, kv)
+def _span(cache, valid_len, pads, live, cfg: TransformerConfig):
+    """{kind of state: `decode_span` of a decode step's rows over that kind's
+    keys and values}: the full layers' [pads, valid_len) of T_max, the window
+    layers' last `cfg.attn_window` of those, as positions, over their ring."""
+    spans = {}
+    if "k" in cache:
+        _, _, t_max, kv, _ = cache["k"].shape
+        spans["attn"] = decode_span(pads, valid_len, live, t_max, kv)
+    if "kw" in cache:
+        _, _, extent, kv, _ = cache["kw"].shape
+        spans["attn_win"] = decode_span(jnp.maximum(pads, valid_len - cfg.attn_window), valid_len, live, extent, kv)
+    return spans
 
 
 def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, k_rope, c_kv):
@@ -314,8 +414,47 @@ def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, 
     return attn, {**cache, "ckv": ckv_all, "kr": kr_all}
 
 
+def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, live=None, span=None, kind: str = "attn"):
+    """The decode block's core over cached keys and values.  q: [B, T, H, D],
+    k, v: [B, T, KV, D] of the step's own positions (T = 1, or a block's): they
+    are written at [layer, b, pos[b] ...] of the stacks of the layer's state
+    (`_state_kind`: k, v, or a window layer's ring kw, vw, at pos[b] mod its
+    extent), and row b attends to the slots its kind sees, through the decode
+    kernel over the stacks as they lie (`_on_kernel`) or the dense contraction
+    over the layer taken out of them.  live, span, kind: `_block_decode_rowpos`'s.
+    Returns (attn [B, T, H, D], the cache after)."""
+    t = q.shape[1]
+    state = _state_kind(kind, cfg)
+    ring = state == "attn_win"
+    if ring and t > 1:
+        raise NotImplementedError("a pass over a block of positions through a window layer's ring")
+    k_name, v_name = LAYER_STATE[state]
+    with jax.named_scope(STATE_SCOPE[state]):
+        rows = jnp.arange(q.shape[0])
+        if t == 1:
+            at, new = (layer, rows, pos % cache[k_name].shape[2] if ring else pos), lambda a: a[:, 0]
+        else:
+            at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
+        k_all = cache[k_name].at[at].set(new(k))
+        v_all = cache[v_name].at[at].set(new(v))
+    if _on_kernel(cache):
+        # the stacks and the layer's index: a layer's slice handed to a kernel is a copy of it
+        with jax.named_scope(core_scope(kind)):
+            attn = decode_attention(q, k_all, v_all, layer,
+                                    (_span(cache, pos + t, pads, live, cfg) if span is None else span)[state],
+                                    ring=ring)
+    else:
+        with jax.named_scope(STATE_SCOPE[state]):
+            k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
+        with jax.named_scope(core_scope(kind)):
+            seen = _ring_seen(jnp.maximum(pads, pos + 1 - cfg.attn_window), pos + 1,
+                              k_layer.shape[1]) if ring else None
+            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads, seen=seen)  # per-row length
+    return attn, {**cache, k_name: k_all, v_name: v_all}
+
+
 def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads, live=None,
-                         experts=None, span=None):
+                         experts=None, span=None, kind: str = "attn"):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
     cache: the attention layers' stacks k, v [n_attn, B, Tmax, KV, D] (among
@@ -326,9 +465,14 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     attends to slots [pads[b], pos[b]] of the layer, read where it lies.
     live: [B] bool, the rows that hold a request: an empty slot's row reads
     nothing of the cache on a TPU (the kernel returns it zeros) and takes no
-    expert (None: every row does both).  span: `decode_span` of the rows, made
+    expert (None: every row does both).  span: `_span` of the rows, made
     once a step by `decode_rows`; None: made here.  Returns (x, the cache
     after, experts touched or None: `_ffn_half`).
+
+    kind: the layer's.  A window layer (`is_window`) keeps the stacks kw, vw
+    [n_win, B, W, KV, D], a ring: row b writes at [layer, b, pos[b] mod W] and
+    attends to the slots that hold positions [max(pads[b], pos[b] + 1 -
+    cfg.attn_window), pos[b]], under scope `attn.core.window`.
 
     x: [B, T, E] with T > 1 is one pass of a model that generates by blocks
     (`cfg.block_length` = T): row b's T positions lie at slots pos[b] ..
@@ -337,41 +481,23 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     tokens), and each attends to slots [pads[b], pos[b] + T): every earlier
     block and the whole of its own, in both directions."""
     t = x.shape[1]
-
-    def core(q, k, v):
-        with jax.named_scope(STATE_SCOPE["attn"]):
-            rows = jnp.arange(x.shape[0])
-            if t == 1:
-                at, new = (layer, rows, pos), lambda a: a[:, 0]
-            else:
-                at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
-            k_all = cache["k"].at[at].set(new(k))
-            v_all = cache["v"].at[at].set(new(v))
-        if _on_kernel(cache):
-            # the stacks and the layer's index: a layer's slice handed to a kernel is a copy of it
-            with jax.named_scope("attn.core"):
-                attn = decode_attention(q, k_all, v_all, layer,
-                                        _span(cache, pos + t, pads, live) if span is None else span)
-        else:
-            with jax.named_scope(STATE_SCOPE["attn"]):
-                k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
-            with jax.named_scope("attn.core"):
-                attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads)  # per-row length
-        return attn, {**cache, "k": k_all, "v": v_all}
-
     positions = (pos - pads)[:, None]
-    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t),
-                               functools.partial(_latent_decode_core, bp, cache, layer, pos, pads, cfg)
-                               if cfg.latent else core)
+    if cfg.latent:
+        core = functools.partial(_latent_decode_core, bp, cache, layer, pos, pads, cfg)
+    else:
+        core = functools.partial(_kv_decode_core, cache, layer, pos, pads, cfg, live=live, span=span, kind=kind)
+    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core, kind)
     if live is not None:
         live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
     x, _, touched = _ffn_half(bp, x, cfg, live, experts)
     return x, cache, touched
 
 
-def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None):
+def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None, kind: str = "attn"):
     """One block over the whole prompt; returns padded caches [B,Tmax,KV,D]
-    (under latent attention the latents [B,Tmax,R] and rotated keys [B,Tmax,rope up to 128s]).
+    (under latent attention the latents [B,Tmax,R] and rotated keys [B,Tmax,rope up to 128s];
+    of a window layer, `kind`, the ring [B,W,KV,D] of its last W = `window_extent`
+    columns, column j at slot j mod W, attended under the banded mask).
     pad: [B] per-row left-pad counts or None. Real tokens sit at columns
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
     pad-token keys (ADVICE r1: unmasked pads skewed generation)."""
@@ -387,27 +513,35 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None)
             attn = attention(q, k, v, causal=True, pad=pad, scale=cfg.attn_scale)
             return attn.astype(x.dtype), stored
 
+    window = is_window(kind)
+    extent = window_extent(cfg, t_max) if window else t_max
+
+    def stored(a):
+        """a [B, T, KV, D] as the cache keeps it: from slot 0 of T_max on, or
+        the last `extent` columns round a window layer's ring."""
+        if t >= extent:  # only a ring is shorter than a prompt
+            return jnp.roll(a[:, t - extent:], (t - extent) % extent, axis=1)
+        return lax.dynamic_update_slice(jnp.zeros((b, extent, *a.shape[2:]), x.dtype), a, (0, 0, 0, 0))
+
     def core(q, k, v):
         with jax.named_scope("attn.cache"):
-            k_cache = jnp.zeros((b, t_max, cfg.n_kv_heads, cfg.d_head), x.dtype)
-            v_cache = jnp.zeros_like(k_cache)
-            k_cache = lax.dynamic_update_slice(k_cache, k, (0, 0, 0, 0))
-            v_cache = lax.dynamic_update_slice(v_cache, v, (0, 0, 0, 0))
+            k_cache, v_cache = stored(k), stored(v)
         # causal attention within the prompt (q already has full heads; only
         # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
         # Pallas flash kernel at every prompt length (ops/attention.py), so
         # prefill never materializes the [T, T] score matrix.
-        with jax.named_scope("attn.core"):
+        with jax.named_scope(core_scope(kind)):
             k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
             # a model that generates by blocks prefills under its block mask:
             # the prompt, and with it the pad, is then a multiple of the block
-            attn = attention(q, k, v, causal=True, pad=pad, block=cfg.block_length)
+            attn = attention(q, k, v, causal=True, pad=pad, block=cfg.block_length,
+                             window=cfg.attn_window * window)
             return attn.astype(x.dtype), (k_cache, v_cache)
 
     positions = jnp.arange(t)
     if pad is not None:
         positions = jnp.maximum(positions[None, :] - pad[:, None], 0)  # [B, T]
-    x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core)
+    x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core, kind)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
     return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
@@ -462,20 +596,30 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
-    def attn(x, bp, experts, _cache, _layer):
-        x, kv = _prefill_block(bp, x, pad, cfg, t_max, experts)
+    def attn(kind, x, bp, experts, _cache, _layer):
+        x, kv = _prefill_block(bp, x, pad, cfg, t_max, experts, kind)
         return x, None, kv
 
     def ssm(x, bp, experts, _cache, _layer):
         x, state = _ssm_prefill_block(bp, x, pad, cfg, experts)
         return x, None, state
 
-    x, _, rows = _scan_blocks({"attn": attn, "attn_dense": attn, "ssm": ssm}, x, params, cfg)
+    x, _, rows = _scan_blocks(_bodies(attn, ssm), x, params, cfg)
+    # each kind's rows into the stacks of the state it keeps, at its layers' places there
+    index = _state_index(cfg)
     cache: Dict[str, Any] = {}
-    for kind, kept in rows.items():  # in the layers' order: a mixture's dense layers lead
-        for name, r in zip(LAYER_STATE[_state_kind(kind, cfg)], kept):
-            cache[name] = r if name not in cache else jnp.concatenate([cache[name], r])
+    for state, names in LAYER_STATE.items():
+        kinds = [kind for kind in rows if _state_kind(kind, cfg) == state]
+        order = np.argsort(np.concatenate([index[kind] for kind in kinds])) if kinds else None
+        for i, name in enumerate(names if kinds else ()):
+            joined = rows[kinds[0]][i] if len(kinds) == 1 else jnp.concatenate([rows[kind][i] for kind in kinds])
+            cache[name] = joined if np.array_equal(order, np.arange(len(order))) else joined[order]
     return None if cfg.generates_blocks else _head(params, x, cfg, row=-1), cache
+
+
+def _bodies(attn, ssm):
+    """`_scan_blocks`' bodies: every attention kind's is `attn(kind, ...)`."""
+    return {kind: ssm if kind == "ssm" else functools.partial(attn, kind) for kind in _INIT_KIND}
 
 
 def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
@@ -501,15 +645,15 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
             x = x[:, None, :]  # [B,1,E]
 
     # what the attention kernel is told of the rows: made once, every layer reads the same
-    span = _span(cache, pos + (tokens.shape[1] if blocks else 1), pads, live) if _on_kernel(cache) else None
+    span = _span(cache, pos + (tokens.shape[1] if blocks else 1), pads, live, cfg) if _on_kernel(cache) else None
 
-    def attn(x, bp, experts, cache, layer):
-        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts, span)
+    def attn(kind, x, bp, experts, cache, layer):
+        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts, span, kind)
 
     def ssm(x, bp, experts, cache, layer):
         return _ssm_block_decode(bp, x, cache, layer, cfg, live, experts)
 
-    x, cache, touched = _scan_blocks({"attn": attn, "attn_dense": attn, "ssm": ssm}, x, params, cfg, cache)
+    x, cache, touched = _scan_blocks(_bodies(attn, ssm), x, params, cfg, cache)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None
     logits = _head(params, x, cfg).astype(jnp.float32) if blocks else _head(params, x, cfg, row=0)

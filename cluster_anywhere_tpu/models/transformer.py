@@ -29,6 +29,12 @@ leading dense layers (kind "attn_dense": a run of its own in the loop), shared
 experts beside the routed ones (`_ffn_half`) and, on one chip of several that
 divide a layer's experts, a held share of them (parallel/moe.py routed_ffn).
 
+Which layer mixes how may also be a tuple (`cfg.layer_mixers`): window layers
+("attn_win": the last `cfg.attn_window` positions, `_attention(window=)`)
+beside full ones, each kind's weights a stack of its own (`_INIT_KIND`); which
+kinds turn q and k is `cfg.rotates`, and `cfg.norm_output` norms each half's
+output in place of its input.
+
 The model is the `entry()` / `dryrun_multichip()` flagship in
 __graft_entry__.py and what benchmarks/ trains and serves.
 """
@@ -170,6 +176,22 @@ class TransformerConfig:
     # the experts held here add for the tokens routed to them is computed, the
     # rest is left out (parallel/moe.py routed_ffn).  None: all are held.
     experts_held: Optional[Tuple[int, int]] = None
+    # each layer's mixer, in the model's order: "attn" (every earlier position),
+    # "attn_win" (the last attn_window positions: itself and attn_window - 1
+    # before it) or "ssm".  None: the period and offset above.  `layer_kinds`
+    # says how a mixer and a leading dense FFN make one kind.
+    layer_mixers: Optional[Tuple[str, ...]] = None
+    attn_window: int = 0
+    # the slots a window layer's cache keeps of a row, written round (position p
+    # at slot p mod attn_ring); 0: the decode kernel's key block that holds the
+    # window (models/generate.py window_extent)
+    attn_ring: int = 0
+    # False: among window layers the full-attention layers take no positional
+    # embedding at all (the window layers turn under `rotary` as ever)
+    rotary_full: bool = True
+    # each half's OUTPUT is normed, not its input: x + norm(attn(x)), then
+    # x + norm(ffn(x)); `ln1` and `ln2` are those norms' weights
+    norm_output: bool = False
 
     def __post_init__(self):
         if self.generates_blocks and self.block_length % self.denoise_steps:
@@ -185,7 +207,22 @@ class TransformerConfig:
             )
         if self.latent and self.generates_blocks:
             raise NotImplementedError("a pass over blocks of positions through a latent cache")
-        if self.n_dense_layers and (not self.n_experts or self.attn_layer_period):
+        if self.layer_mixers is not None:
+            object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))  # hashable whatever carried it
+            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm"})
+            if unknown or len(self.layer_mixers) != self.n_layers or self.attn_layer_period:
+                raise ValueError(
+                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm for each of the "
+                    f"{self.n_layers} layers, and no attn_layer_period beside it"
+                )
+            window = "attn_win" in self.layer_mixers
+            if window and not 0 < self.attn_window <= (self.attn_ring or self.attn_window):
+                raise ValueError(f"window layers see attn_window={self.attn_window} positions, "
+                                 f"within attn_ring={self.attn_ring} slots")
+            if window and (self.latent or self.generates_blocks):
+                raise NotImplementedError("a window layer under latent attention, or in a model that generates by blocks")
+        if self.n_dense_layers and (not self.n_experts or self.attn_layer_period
+                                    or "ssm" in (self.layer_mixers or ())[:self.n_dense_layers]):
             raise NotImplementedError(
                 f"n_dense_layers={self.n_dense_layers}: leading dense layers stand before the expert "
                 "layers of a mixture whose layers all attend"
@@ -218,15 +255,30 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Each layer's kind: its mixer, "attn" or "ssm", and "attn_dense" for
-        an attention layer that keeps a dense FFN in a mixture."""
-        if not self.attn_layer_period:
-            dense = min(self.n_dense_layers, self.n_layers)
-            return ("attn_dense",) * dense + ("attn",) * (self.n_layers - dense)
-        return tuple(
-            "attn" if i % self.attn_layer_period == self.attn_layer_offset else "ssm"
-            for i in range(self.n_layers)
-        )
+        """Each layer's kind, in the model's order.  A kind is what a layer
+        loop scans as one run and what is stacked on a leading axis of its
+        own, parameters (`_INIT_KIND`) and cache rows (models/generate.py
+        LAYER_STATE) alike.  It is the layer's mixer, "attn", "attn_win" (a
+        window layer) or "ssm", from `layer_mixers` or, without one, from the
+        period and offset; and a mixture's leading `n_dense_layers`, which keep
+        a dense FFN, are a kind of their mixer's with "_dense" behind it:
+        "attn_dense", "attn_win_dense".  The two properties are independent:
+        the suffix decides the FFN's weights and nothing else, the mixer the
+        core, the rotary embedding and the cache the layer writes (an
+        "attn_win_dense" layer's rows lie in the window layers' stacks, before
+        those of the "attn_win" layers that follow it)."""
+        if self.layer_mixers is not None:
+            mixers = self.layer_mixers
+        elif self.attn_layer_period:
+            mixers = tuple("attn" if i % self.attn_layer_period == self.attn_layer_offset else "ssm"
+                           for i in range(self.n_layers))
+        else:
+            mixers = ("attn",) * self.n_layers
+        return tuple(m + "_dense" if i < self.n_dense_layers else m for i, m in enumerate(mixers))
+
+    def rotates(self, kind: str) -> bool:
+        """Whether a layer of `kind` turns its queries and keys."""
+        return self.rotary and (self.rotary_full or is_window(kind))
 
     @property
     def d_inner(self) -> int:
@@ -242,6 +294,11 @@ class TransformerConfig:
         if self.attn_impl != "auto":
             return self.attn_impl
         return "ring" if self.sp > 1 else "dense"
+
+
+def is_window(kind: str) -> bool:
+    """Whether a layer of `kind` attends to a window of the last positions."""
+    return kind.startswith("attn_win")
 
 
 def _yarn_mscale(factor: float, m: float) -> float:
@@ -353,14 +410,16 @@ def _init_dense_block(key, cfg: TransformerConfig):
 
 
 _INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block),
-              "attn_dense": ("dense_blocks", _init_dense_block)}
+              "attn_dense": ("dense_blocks", _init_dense_block),
+              "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _init_dense_block)}
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """`blocks`: the attention layers' parameters stacked [n, ...]; `ssm_blocks`:
     the state-space layers', where the pattern has any; `dense_blocks`: a
-    mixture's leading dense layers'.  Layer i's key is the i-th of one split
-    whatever its kind."""
+    mixture's leading dense layers'; `win_blocks`, `win_dense_blocks`: the
+    window layers' of either FFN (`_INIT_KIND`: a stack a kind).  Layer i's
+    key is the i-th of one split whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
     kinds = cfg.layer_kinds
@@ -435,7 +494,7 @@ def _one_device_only(cfg: TransformerConfig, what: str) -> None:
     if kinds:
         raise NotImplementedError(
             f"{what}: layers of kind {kinds} (attn_layer_period={cfg.attn_layer_period}, "
-            f"n_dense_layers={cfg.n_dense_layers}) run on one device only; a mesh with more than "
+            f"n_dense_layers={cfg.n_dense_layers}, layer_mixers={cfg.layer_mixers}) run on one device only; a mesh with more than "
             "one device shards attention blocks that cache keys and values alone"
         )
 
@@ -623,15 +682,17 @@ def _per_shard(attn_fn, mesh, manual_axes=frozenset()):
     )
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes):
+def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes, window: int = 0):
     impl = cfg.resolved_attn()
     over_sp = impl in ("ring", "ulysses") and "sp" in manual_axes
-    if over_sp and cfg.generates_blocks:
-        raise NotImplementedError(f"the block mask (block_length={cfg.block_length}) with {impl} attention")
+    if over_sp and (cfg.generates_blocks or window):
+        raise NotImplementedError(
+            f"the block mask (block_length={cfg.block_length}) or a window ({window}) with {impl} attention")
     if over_sp:
         fn = functools.partial(ring_attention if impl == "ring" else ulysses_attention, axis_name="sp", causal=True)
     else:  # dense: the dispatcher picks by backend
-        fn = functools.partial(dense_attention, causal=True, block=cfg.block_length, scale=cfg.attn_scale)
+        fn = functools.partial(dense_attention, causal=True, block=cfg.block_length, scale=cfg.attn_scale,
+                               window=window)
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
@@ -643,10 +704,11 @@ def _gqa_repeat(x, cfg: TransformerConfig):
     return x
 
 
-def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
+def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = "attn"):
     """A block's first half, for training, prefill and decode alike:
     x + wo(core(rope(qkv(norm(x))))), the rotary embedding where the
-    configuration has one.  x: [B, T, E]; positions: [T] or [B, T], what `_rope`
+    configuration has one for a layer of `kind` (`cfg.rotates`); under
+    `cfg.norm_output` x + norm(wo(core(rope(qkv(x))))).  x: [B, T, E]; positions: [T] or [B, T], what `_rope`
     takes; `core(q, k, v) -> (attn [B, T, H, D], extra)` attends
     under its own scopes (`attn.core`, and `attn.cache` where it keeps one) and
     hands back what its caller keeps of k and v.  Returns (x, extra).
@@ -657,20 +719,25 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core):
     trace sums a kind of work over the depth (forward, recomputation and
     backward carry the name; metadata only)."""
     b, t, _ = x.shape
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln1"])
+    y = x
+    if not cfg.norm_output:
+        with jax.named_scope("norm"):
+            y = _rms_norm(x, bp["ln1"])
     if cfg.latent:
         q, k, v = _project_latent(bp, y, cfg, positions)
     else:
         with jax.named_scope("attn.qkv"):
             q, k, v = _project_qkv(bp, y, cfg)
-        if cfg.rotary:
+        if cfg.rotates(kind):
             with jax.named_scope("attn.rope"):
                 q, k = _rope(q, k, positions, cfg)
     attn, extra = core(q, k, v)
     with jax.named_scope("attn.out"):
-        x = x + attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
-    return x, extra
+        out = attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
+    if cfg.norm_output:
+        with jax.named_scope("norm"):
+            out = _rms_norm(out, bp["ln1"])
+    return x + out, extra
 
 
 # the precision of the recurrence: the step size, the decay exp(dt A), the state
@@ -793,29 +860,28 @@ def _ssm_half(bp, x, cfg: TransformerConfig, core, keep=None):
     return x, extra
 
 
-def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
-    """A block's second half: x + FFN(norm(x)), the FFN dense SwiGLU or a
-    mixture of experts (by what `bp` holds: a router or none), the mixture with
-    the shared experts' gated MLP beside it where the configuration has any.  On one device the mixture is the dropless routed path
-    (`_moe`, which says what `live` and `experts` are); with 'ep' among the
-    caller's manual axes its experts are sharded and tokens travel to them
-    (parallel/moe.py moe_ffn).  Returns (x, aux loss, experts touched): None
-    for a dense model, and no count from moe_ffn."""
-    b, t, e = x.shape
-    dt = x.dtype
-    with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln2"])
+def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
+    """A block's FFN over what the block gives it, y [B, T, E]: dense SwiGLU or
+    a mixture of experts (by what `bp` holds: a router or none), the mixture
+    with the shared experts' gated MLP beside it where the configuration has
+    any.  On one device the mixture is the dropless routed path (`_moe`, which
+    says what `live` and `experts` are); with 'ep' among the caller's manual
+    axes its experts are sharded and tokens travel to them (parallel/moe.py
+    moe_ffn).  Returns (out [B, T, E], aux loss, experts touched): None for a
+    dense model, and no count from moe_ffn."""
+    b, t, e = y.shape
+    dt = y.dtype
     with jax.named_scope("ffn"):
         if "router" not in bp:  # a dense model's block, or a mixture's leading dense layer
             gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
-            return x + gated @ bp["w_down"].astype(dt), None, None
+            return gated @ bp["w_down"].astype(dt), None, None
         if "ep" not in manual_axes:
             out, aux, touched = _moe(bp, y, cfg, live, experts)
             if cfg.n_shared_experts:
                 with jax.named_scope("moe.shared"):
                     shared = jax.nn.silu(y @ bp["shared_gate"].astype(dt)) * (y @ bp["shared_up"].astype(dt))
                     out = out + shared @ bp["shared_down"].astype(dt)
-            return x + out, aux, touched
+            return out, aux, touched
         # tokens flatten, travel to their expert's device, come back (traced
         # under shard_map manual over 'ep': see forward())
         from ..parallel.moe import moe_ffn
@@ -828,25 +894,45 @@ def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axe
             axis_name="ep",
             capacity_factor=cfg.capacity_factor,
         )
-        return x + r.out.reshape(b, t, e), r.aux_loss.astype(jnp.float32), None
+        return r.out.reshape(b, t, e), r.aux_loss.astype(jnp.float32), None
 
 
-def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
+def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
+    """A block's second half: x + FFN(norm(x)), or under `cfg.norm_output`
+    x + norm(FFN(x)); `_ffn` says what the FFN is and what the other arguments
+    are.  Returns (x, aux loss, experts touched)."""
+    if cfg.norm_output:
+        out, aux, touched = _ffn(bp, x, cfg, live, experts, manual_axes)
+        with jax.named_scope("norm"):
+            return x + _rms_norm(out, bp["ln2"]), aux, touched
+    with jax.named_scope("norm"):
+        y = _rms_norm(x, bp["ln2"])
+    out, aux, touched = _ffn(bp, y, cfg, live, experts, manual_axes)
+    return x + out, aux, touched
+
+
+def core_scope(kind: str) -> str:
+    """The scope a layer of `kind` attends under: a window layer's beside the others'."""
+    return "attn.core.window" if is_window(kind) else "attn.core"
+
+
+def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), kind: str = "attn"):
     """One transformer block. x: [B, T_local, E].  manual_axes: the mesh axes
-    the caller's shard_map is already manual over (pp/sp/ep subset).  Returns
-    (x, the MoE load-balance loss: 0 dense)."""
+    the caller's shard_map is already manual over (pp/sp/ep subset); kind: the
+    layer's (a window layer attends to its window: forward only on a TPU).
+    Returns (x, the MoE load-balance loss: 0 dense)."""
     t = x.shape[1]
     offset = lax.axis_index("sp") * t if "sp" in manual_axes and cfg.sp > 1 else 0
 
     def core(q, k, v):
-        with jax.named_scope("attn.core"):
+        with jax.named_scope(core_scope(kind)):
             if cfg.latent:
                 k, v = _latent_expand(bp, k, v, cfg)
             else:
                 k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
-            return _attention(q, k, v, cfg, mesh, manual_axes), None
+            return _attention(q, k, v, cfg, mesh, manual_axes, cfg.attn_window * is_window(kind)), None
 
-    x, _ = _attention_half(bp, x, cfg, offset + jnp.arange(t), core)
+    x, _ = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind)
     x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
     return x, jnp.zeros((), jnp.float32) if aux is None else aux
 
@@ -864,8 +950,7 @@ def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
 
 def layer_stacks(params) -> Dict[str, Any]:
     """{kind: that kind's blocks, stacked on a leading axis of their own}."""
-    stacks = {"attn": params.get("blocks"), "ssm": params.get("ssm_blocks"),
-              "attn_dense": params.get("dense_blocks")}
+    stacks = {kind: params.get(name) for kind, (name, _) in _INIT_KIND.items()}
     return {kind: blocks for kind, blocks in stacks.items() if blocks is not None}
 
 
@@ -933,11 +1018,12 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
     """The layer loop over this stage's layers.  stacks: `layer_stacks`, leaves
     [L_stage, ...].  Returns (x, aux) — aux is the summed MoE load-balance loss
     (0 dense)."""
+    # an attention kind's block is the same whatever its FFN: that is what its weights hold
     blocks = {
-        "attn": functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes),
-        "ssm": lambda bp, x: _ssm_block_forward(bp, x, cfg)[:2],
+        kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind)
+        for kind in _INIT_KIND if kind != "ssm"
     }
-    blocks["attn_dense"] = blocks["attn"]  # the same block: its FFN is what its weights hold
+    blocks["ssm"] = lambda bp, x: _ssm_block_forward(bp, x, cfg)[:2]
     if cfg.remat:
         blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
 

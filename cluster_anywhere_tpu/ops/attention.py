@@ -24,10 +24,15 @@ run the kernels themselves in interpret mode by passing
 `flash_attention(..., interpret=True)` (tests/test_ops.py); nothing else
 selects it.
 
+A window layer's band (`window=`: query i sees keys i - W + 1 .. i) is the same
+forward kernel under the name `swa_flash`, with the key blocks before a query
+block's band skipped as those above the diagonal are; forward only.
+
 A decode step has a kernel of its own, `decode_attention`: a few query
 positions a row against the row's own slots of a key/value cache, which it
 reads where it lies (the whole stacks and a layer's index) and only as far as
-rows hold a request and their contexts reach.  `decode_on_kernel()` is its
+rows hold a request and their contexts reach; told `ring=True` it reads a
+window layer's stack, written round, one key block a live row.  `decode_on_kernel()` is its
 dispatch, by the same rule; its dense counterpart is the caller's
 (models/generate.py `_masked_attention`), and tests/test_decode_attention.py
 runs it interpreted.
@@ -59,7 +64,7 @@ def _platform() -> str:
 # --------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mask_block=0):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mask_block=0, window=0):
     if has_pad:
         pad_ref, o_ref, lse_ref = refs
         pad_val = pad_ref[pl.program_id(0)]
@@ -75,9 +80,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mas
     l0 = jnp.zeros((block_q,), jnp.float32)
     acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
 
+    first_k = 0
     if causal:
         # skip key blocks fully above the diagonal
         num_k = lax.div((qi + 1) * block_q + block_k - 1, block_k)
+        if window:
+            # and those wholly before the band: the block's first query sees no key before its window's start
+            first_k = lax.div(jnp.maximum(qi * block_q - (window - 1), 0), block_k)
     else:
         num_k = t_kv // block_k
 
@@ -108,6 +117,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mas
                     # the block mask: a query sees its own block to the end
                     q_pos = q_pos // mask_block * mask_block + (mask_block - 1)
                 ok = q_pos >= k_pos
+                if window:
+                    ok = ok & (q_pos - k_pos < window)  # itself and the window - 1 before it
             if has_pad:
                 # left-padded rows: keys before pad_val are pad tokens
                 k_ok = k_pos >= pad_val
@@ -125,7 +136,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_k, has_pad, mas
         )
         return m_new, l_new, acc_new
 
-    m, l, acc = lax.fori_loop(0, num_k, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(first_k, num_k, body, (m0, l0, acc0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
     o_ref[...] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     # lse_ref is the full (1, t) row; each grid step writes its q-block slice
@@ -299,7 +310,14 @@ def _pad_bh(pad, h):
 _PAD_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0):
+# the banded forward kernel's name in a device trace: a window layer's prefill, beside `flash_fwd`
+WINDOW_KERNEL = "swa_flash"
+# the banded kernel's query and key blocks: the lane width, the least the chip's tiling allows,
+# so that a query block's band of W + 127 keys lies in two key blocks at W = 128
+WINDOW_BLOCK = 128
+
+
+def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0, window=0):
     b, t, h, d = q.shape
     t_kv, dv = k.shape[1], v.shape[-1]
     qf, kf, vf = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
@@ -319,7 +337,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_k=block_k, has_pad=has_pad,
-            mask_block=mask_block,
+            mask_block=mask_block, window=window,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -334,7 +352,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",  # the kernel's name in a device trace
+        name=WINDOW_KERNEL if window else "flash_fwd",  # the kernel's name in a device trace
     )(*args)
     return _from_bhtd(out, b, h), lse.reshape(b, h, t)
 
@@ -463,9 +481,19 @@ def flash_attention(
     interpret: bool = False,
     return_lse: bool = False,
     block: int = 0,
+    window: int = 0,
 ):
     """Pallas flash attention.  q: [B, T, H, D]; k: [B, T_kv, H, D]; v:
     [B, T_kv, H, Dv], of the keys' width or another; returns [B, T, H, Dv].
+
+    window: W > 0 narrows the causal mask to a band: query i sees key j where
+    0 <= i - j < W, itself and the W - 1 before it (a left pad masks on top of
+    it: indices and positions differ by the same shift on both sides).  The key
+    blocks wholly before a query block's band are skipped like those above the
+    diagonal, not masked after the fact, so the kernel's work follows T x W, not
+    T x T; the blocks default to WINDOW_BLOCK so that little outside the band
+    is computed.  The kernel runs under a name of its own (WINDOW_KERNEL).
+    Forward only, as the block mask.
 
     block: B > 1 widens the causal mask to the block mask of a model that
     generates by blocks: query i sees key j where j // B <= i // B, every
@@ -491,11 +519,15 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if block_q is None:
-        block_q = _auto_block(q.shape[1], 256)
+        block_q = _auto_block(q.shape[1], WINDOW_BLOCK if window else 256)
     if block_k is None:
-        block_k = _auto_block(k.shape[1], 512)
+        block_k = _auto_block(k.shape[1], WINDOW_BLOCK if window else 512)
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
+    if window:
+        if not causal or return_lse or block > 1:
+            raise ValueError(f"a window ({window}) is the causal mask narrowed to a band: causal=True, no lse, no block mask")
+        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, window=window)[0]
     if block > 1:
         # forward only, and the kernel's skip of the key blocks above the
         # diagonal takes a query block to end on a mask block's edge
@@ -540,10 +572,11 @@ def merge_attention(o1, lse1, o2, lse2):
     return o, jnp.where(tot == 0.0, NEG_INF, lse)
 
 
-def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0):
+def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0, window=0):
     """Dense jnp attention (fallback + test oracle): [B,T,H,D] -> [B,T,H,D].
     pad: optional [B] left-pad counts (keys < pad[b] masked).  block: B > 1
-    widens the causal mask to the block mask (`flash_attention`)."""
+    widens the causal mask to the block mask, window: W > 0 narrows it to the
+    band 0 <= i - j < W (`flash_attention`)."""
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
@@ -557,6 +590,8 @@ def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0):
         mask = (jnp.arange(t_q)[:, None] // block >= jnp.arange(t_k)[None, :] // block)[None, None]
     elif causal:
         mask = jnp.tril(jnp.ones((t_q, t_k), dtype=bool))[None, None]
+        if window:
+            mask = mask & (jnp.arange(t_q)[:, None] - jnp.arange(t_k)[None, :] < window)
     if pad is not None:
         key_ok = (jnp.arange(t_k)[None, :] >= pad[:, None])[:, None, None, :]
         mask = key_ok if mask is None else (mask & key_ok)
@@ -580,18 +615,20 @@ def _left_pad_to_tile(q, k, v, pad):
     return widen(q), widen(k), widen(v), pad, extra
 
 
-def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None, block: int = 0):
+def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None, block: int = 0,
+              window: int = 0):
     """Dispatcher: the Pallas flash kernel on a TPU, the jnp reference on any
     other backend.  A sequence that is not a multiple of the kernel's tile is
     left-padded up to one and the new columns masked as pad tokens (their
     query rows are dropped), so the algorithm never changes with the shape.
     block: the block mask (`flash_attention`); the columns added on the left
-    then have to be whole blocks, which they are for a sequence of whole blocks."""
+    then have to be whole blocks, which they are for a sequence of whole blocks.
+    window: the band (`flash_attention`)."""
     if _platform() != "tpu":
-        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)
+        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)
     t, t_kv = q.shape[1], k.shape[1]
     if t % _TILE == 0 and t_kv % _TILE == 0:
-        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)
+        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)
     if t != t_kv:
         raise ValueError(
             f"flash kernel needs T and T_kv to be multiples of {_TILE} when "
@@ -600,7 +637,7 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=N
     q, k, v, pad, extra = _left_pad_to_tile(q, k, v, pad)
     if block > 1 and extra % block:
         raise ValueError(f"a sequence of {t} under the block mask of {block}: {extra} columns on the left shift its blocks")
-    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block)[:, extra:]
+    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)[:, extra:]
 
 
 # --------------------------------------------------------------------------
@@ -670,14 +707,17 @@ def decode_span(first, last, live, t_max: int, kv: int):
 
 
 def _decode_kernel(layer_ref, span_ref, q_ref, k_ref, v_ref, zeros_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                   scale, block_k, t_max, kv, heads):
+                   scale, block_k, t_max, kv, heads, ring=False):
     """One (row, key block) of the work.  q_ref [Tq * H, D]; k_ref, v_ref
     [block_k * KV, D]: the block's slots as stored, a slot's KV cached heads one
     after the other.  Every query head meets every cached head of the block in
     one contraction and the mask keeps its own: the stationary operand of both
     contractions is the block itself whatever the grouping, and no head is
     taken out of a tile.  zeros_ref is the output before the kernel (aliased):
-    the rows the work does not name."""
+    the rows the work does not name.  ring: the row's `t_max` slots (one key
+    block) are written round, position p at slot p mod t_max: a slot is seen
+    where the position it holds, the newest that falls on it, lies in
+    [first, last)."""
     del zeros_ref
     i = pl.program_id(0)
     b, j = span_ref[2, i], span_ref[3, i]
@@ -702,9 +742,14 @@ def _decode_kernel(layer_ref, span_ref, q_ref, k_ref, v_ref, zeros_ref, o_ref, m
         ok = col % kv == row % heads // (heads // kv)  # a query head's own cached head
         if not whole:
             slot = j * block_k + col // kv
-            ok = ok & (slot >= first) & (slot < last)
             at = j * block_k + lax.broadcasted_iota(jnp.int32, (v.shape[0], 1), 0) // kv
-            v = jnp.where((at >= first) & (at < last), v, jnp.zeros_like(v))
+            if ring:
+                # how far behind the newest position, last - 1, the position a slot holds lies
+                seen = lambda a: (last - 1 - a + t_max) % t_max < last - first
+            else:
+                seen = lambda a: (a >= first) & (a < last)
+            ok = ok & seen(slot)
+            v = jnp.where(seen(at), v, jnp.zeros_like(v))
         s = jnp.where(ok, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -716,16 +761,20 @@ def _decode_kernel(layer_ref, span_ref, q_ref, k_ref, v_ref, zeros_ref, o_ref, m
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    whole = (j * block_k >= first) & ((j + 1) * block_k <= last)
-    pl.when(whole)(functools.partial(block, True))
-    pl.when(~whole)(functools.partial(block, False))
+    if ring:
+        block(False)
+    else:
+        whole = (j * block_k >= first) & ((j + 1) * block_k <= last)
+        pl.when(whole)(functools.partial(block, True))
+        pl.when(~whole)(functools.partial(block, False))
 
     @pl.when(j == hi)
     def _():
         o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, interpret: bool = False):
+def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, interpret: bool = False,
+                     ring: bool = False):
     """Attention of a decode step, as a Pallas kernel over the caches where they
     lie.  q: [B, Tq, H, D]; k, v: the WHOLE stacks [n_attn, B, T_max, KV, D(v)]
     as `models.generate.init_cache` makes them, and `layer`, the index of the
@@ -733,6 +782,13 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
     of the layer).  span: `decode_span`'s, of this cache: row b's Tq queries all
     see slots [first[b], last[b]) of its own cache, in both directions, and a
     row that holds no request returns zeros.  Returns [B, Tq, H, Dv].
+
+    ring: the stacks are a window layer's, T_max slots a row written round
+    (position p at slot p mod T_max), and T_max is one key block: a live row is
+    one step and one fetch, `span` is `decode_span` over (first, last) as
+    positions with this T_max, and a slot is seen where the newest position
+    that falls on it lies in [first, last) (at most T_max of them).  Keys are
+    stored turned, so their order in the ring does not matter to the softmax.
 
     The grid is the work `span` lists and no longer: a step a key block
     (`decode_key_block(T_max, KV)` slots, all their cached heads) that holds a
@@ -744,6 +800,8 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
     b, tq, h, d = q.shape
     n, _, t_max, kv, dv = v.shape
     block_k = decode_key_block(t_max, kv)
+    if ring and block_k != t_max:
+        raise ValueError(f"a ring of {t_max} slots of {kv} cached heads is not one key block ({block_k})")
 
     def kv_map(i, layer_ref, span_ref):
         return layer_ref[0], span_ref[2, i], span_ref[3, i], 0
@@ -756,7 +814,7 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
     m = tq * h
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=d ** -0.5 if scale is None else scale, block_k=block_k,
-                          t_max=t_max, kv=kv, heads=h),
+                          t_max=t_max, kv=kv, heads=h, ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(span[4, 0],),

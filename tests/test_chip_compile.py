@@ -375,6 +375,59 @@ def test_latent_prefill_expands_through_the_flash_kernel(v5e, on_tpu):
     assert _has_kernel(_compiled_admit_prefill(transformer.TransformerConfig(**AXK13), 1024, 4352, v5e[0]))
 
 
+# K-EXAONE's widths as one chip of 16 holds a layer (64 query heads on 8 cached heads of 128, a
+# window of 128 in three layers of four, a leading dense layer of 18,432, 8 held of 128
+# sigmoid-routed experts of 2,048 and a shared one), one period: benchmarks/configs/k-exaone-236b-a23b-ep16-serve1.json
+KEXAONE4 = dict(
+    vocab_size=512, n_layers=4, d_model=6144, n_heads=64, n_kv_heads=8, d_head=128, d_ff=18432,
+    layer_mixers=("attn_win", "attn_win", "attn_win", "attn"), attn_window=128, rotary_full=False, norm_output=True,
+    qk_norm=True, qk_norm_per_head=True, rope_theta=1e6, n_dense_layers=1, d_expert=2048, n_experts=128,
+    n_experts_per_tok=8, experts_held=(0, 8), moe_gated=True, moe_renormalize=True, moe_scoring="sigmoid",
+    moe_routed_scale=2.5, n_shared_experts=1, param_dtype=jnp.bfloat16,
+)
+
+
+def test_window_decode_step_reads_and_writes_both_stacks_where_they_lie(v5e):
+    """The decode step at K-EXAONE's widths and the cell's cache (32 slots x
+    8,448 in the full layer, x 256 in the three window layers) as the chip runs
+    it: both pairs of stacks are the layer loop's carry, written a row a slot
+    and layer in place (the ring at pos mod 256) and read by the decode kernel
+    as stored, each through its own span.  Its temporaries, 106 MB at this
+    depth, hold nothing of the cache: they are the query projections of the two
+    stacks of one layer here (`win_dense_blocks`, `blocks`), 50 MB each, which
+    the compiler lays out anew for the norm a head (a stack of several layers
+    is read where it lies); no buffer has a cache stack's size but the donated
+    one, written in place."""
+    cfg = transformer.TransformerConfig(**KEXAONE4)
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], 32, 8448, on_kernel=True)
+    assert {n: c.shape for n, c in cache.items()} == {
+        "k": (1, 32, 8448, 8, 128), "v": (1, 32, 8448, 8, 128), "kw": (3, 32, 256, 8, 128), "vw": (3, 32, 256, 8, 128)}
+    kernels = re.findall(r"%(decode_attn[\w.]*) = \S+ custom-call\(", compiled.as_text())
+    assert len(kernels) >= 2 and _has_kernel(compiled)  # a window layers' run and the full layer's
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * 6144 * 8192 * 2
+    stacks = {c.size for c in cache.values()}
+    seen = set()
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if n in stacks and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            assert op == "fusion" and '"aliasing_operands":{"lists":[{' in line, line[:200]
+            seen.add(n)
+    assert seen == stacks
+    held = 2 * 8 * 6144 * 2048
+    assert sum(1 for _, n, _ in _buffers(compiled) if n == held) >= 3  # the held experts' stacks are seen whole
+
+
+@pytest.mark.parametrize("bucket", [1024, 8192])
+def test_window_prefill_goes_through_the_banded_kernel(v5e, on_tpu, bucket):
+    """An admit's prefill at K-EXAONE's widths: the window layers attend
+    through the banded kernel under its own name, the full layer through
+    `flash_fwd`; the ring keeps the bucket's last 256 columns."""
+    compiled = _compiled_admit_prefill(transformer.TransformerConfig(**KEXAONE4), bucket, 8448, v5e[0])
+    text = compiled.as_text()
+    assert re.search(r"%swa_flash[\w.]* = .* custom-call\(", text) and re.search(r"%flash_fwd[\w.]* = .* custom-call\(", text)
+    print(bucket, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
+
+
 def _computations(text):
     """({name: its instructions' lines}, the entry's name) of an optimized program's text."""
     comps, entry, inside = {}, None, None

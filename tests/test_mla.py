@@ -119,35 +119,48 @@ def test_yarn_frequencies_and_the_softmax_scale_by_hand():
     assert plain.attn_scale == 64 ** -0.5
 
 
-def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+# the window-and-full mixture of tests/test_swa.py: the same expert layer under a norm of each half's OUTPUT
+SWA = dict(vocab_size=97, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_head=16, d_ff=160,
+           layer_mixers=("attn_win", "attn_win", "attn_win", "attn"), attn_window=8, attn_ring=16, rotary_full=False,
+           norm_output=True, qk_norm=True, qk_norm_per_head=True, **SHARE)
+
+
+@pytest.mark.parametrize("arch", ["mla_moe", "swa_moe"])
+def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(arch):
     """What ties the share to the model (model-configs guide, section 4): the
     routed parts that the 16 shares give, each from the program's own expert
     layer told which 2 of 32 experts it holds, with the shared expert counted
-    once, are the uncut reference layer's FFN."""
-    cfg, _ = _model()
+    once, are the uncut reference layer's FFN; and the program's second half
+    over the uncut layer is the reference's, the norm before the FFN
+    (references/mla_moe.py) or after it (references/swa_moe.py)."""
+    ref = manifest.load_reference(arch)
+    cfg = _model()[0] if arch == "mla_moe" else TransformerConfig(**SWA, dtype=jnp.float32, param_dtype=jnp.float32)
     whole = dataclasses.replace(cfg, experts_held=None)
     bp = jax.tree_util.tree_map(lambda w: w[0], init_params(jax.random.key(9), whole)["blocks"])
+    bp["ln2"] = bp["ln2"] * jnp.linspace(0.7, 1.3, 64)
     assert bp["w_gate"].shape == (32, 64, 24) and bp["router"].shape == (64, 32)
     x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 17, 64)), jnp.float32)
-    y = reference._rms_norm(x, bp["ln2"])
+    y = x if cfg.norm_output else ref._rms_norm(x, bp["ln2"])
     with jax.default_matmul_precision("highest"):
         routed, weight = reference._routed(y.reshape(-1, 64), bp, 4, True, 2.5, 0)
         shared = (jax.nn.silu(y @ bp["shared_gate"]) * (y @ bp["shared_up"])) @ bp["shared_down"]
-        want = x + routed.reshape(x.shape) + shared
+        ffn = routed.reshape(x.shape) + shared
+        want = x + (ref._rms_norm(ffn, bp["ln2"]) if cfg.norm_output else ffn)
     assert np.all(np.sum(np.asarray(weight) > 0, axis=-1) == 4)
     np.testing.assert_allclose(np.sum(np.asarray(weight), axis=-1), 2.5, rtol=1e-5)  # renormalised, scaled
     total, assignments = None, 0
     for share in range(16):
         held = dataclasses.replace(cfg, experts_held=(2 * share, 2))
         mine = {k: (v[2 * share:2 * share + 2] if k in EXPERT_MATRICES else v) for k, v in bp.items()}
-        if share == 0:  # the whole second half once: residual, this share's part, the shared expert
-            part, _, counts = transformer._ffn_half(mine, x, held)
+        if share == 0:  # the whole FFN once: this share's part and the shared expert
+            part, _, counts = transformer._ffn(mine, y, held)
         else:  # the other shares' routed parts alone
             part, _, counts = transformer._moe(mine, y, held)
         total = part if total is None else total + part
         assignments += int(counts[1])
-    np.testing.assert_allclose(total, want, atol=2e-5)
+    np.testing.assert_allclose(total, ffn, atol=2e-5)
     assert assignments == 2 * 17 * 4  # every (token, expert) pair fell on exactly one share
+    np.testing.assert_allclose(transformer._ffn_half(bp, x, whole)[0], want, atol=2e-5)
 
 
 @pytest.mark.parametrize("program", ["train", "prefill", "decode"])
@@ -321,3 +334,4 @@ def test_configurations_that_are_not_built_are_refused_by_name():
     with pytest.raises(NotImplementedError, match="latent attention"):
         transformer.param_specs(cfg)
     assert TransformerConfig(n_experts=8, experts_held=[2, 4]).experts_held == (2, 4)  # hashable however it came
+
