@@ -569,17 +569,61 @@ def _rope(q, k, positions, cfg: TransformerConfig):
     )
 
 
+# The rows (batch x positions) up to which a projection that is reshaped to heads is kept apart
+# from its reshape (`_project_heads`): the largest measured size at which that gains.  On a v5e,
+# apart against folded (PERF.md section 6, PR 44): a prefill of 64 / 256 / 512 rows at Mistral-7B's
+# widths 10.97 / 12.44 / 22.97 ms against 11.94 / 14.14 / 24.55, of 1,024 / 2,048 / 4,096 / 8,192
+# rows at K-EXAONE's 50.9 / 93.6 / 185.3 / 395.7 against 54.9 / 99.9 / 190.4 / 396.3: what a
+# layer's copied weights cost is fixed and the product grows with the rows, so at 8,192 nothing is
+# gained (and with it that cell's peak of device memory read 0.58 GB higher), and the train step of
+# `train-fsdp4` (32,768 rows, forward and backward) is 1.0% slower apart (22,964 tokens/s
+# against 23,194).  Above the boundary a product keeps the program the compiler makes it.
+STORED_PRODUCT_ROWS = 4096
+
+
+def _project_heads(y, w):
+    """y [B, T, E] @ w [E, F] -> [B, T, F], for a product whose result is viewed
+    as heads [B, T, H, D].  Up to STORED_PRODUCT_ROWS rows the product is kept a
+    value of its own, two-dimensional as the weight is stored, and the reshape
+    works on the result.  Given the reshape to fold into the product, the chip's
+    compiler makes the heads an output axis and wants the weight with E
+    innermost, the stored [E, F] transposed: in a decode step a layer's matrix
+    sliced out of its stack into fast memory, copied into the other layout and
+    only then multiplied, three serial operations a projection at every layer of
+    every step, 2.2 ms of an 11.3 ms step at Mistral-7B's widths.  Kept apart,
+    the product is one fusion that takes the weight's stack and the layer's index
+    and reads the matrix once, where it lies, as the FFN's and wo do.
+
+    The `optimization_barrier` is a workaround for that fold in this compiler,
+    not part of the arithmetic: the plain product on [B * T, E], an explicit
+    einsum to heads and the transposed product all compile to the copies.  Its
+    guard is tests/test_chip_compile.py
+    test_decode_step_multiplies_by_the_projections_where_they_are_stored, which
+    compiles the decode step for a v5e: if a later compiler no longer folds the
+    reshape in, that test passes without the barrier and it can go.  The rows
+    are all the code sees, so a forward or a train step of at most
+    STORED_PRODUCT_ROWS rows takes the barrier too (its transpose is a barrier
+    on the cotangent): meant so, a layer's weights cost such a pass what they
+    cost a prefill of as many rows; not measured on the chip."""
+    out = y @ w.astype(y.dtype)
+    if y.shape[0] * y.shape[1] <= STORED_PRODUCT_ROWS:
+        out = lax.optimization_barrier(out)
+    return out
+
+
 def _project_qkv(bp, y, cfg: TransformerConfig):
     """q [B, T, H, D], k and v [B, T, KV, D] of one block from its normed
     input y [B, T, E]: the three projections and, with `cfg.qk_norm`, the
     RMSNorm of q and k: over the whole projected vector, or over each head's
     own d_head (`cfg.qk_norm_per_head`).  The one place every forward, prefill
-    and decode block projects."""
+    and decode block projects.  The reshape to heads stays outside the product
+    (`_project_heads`): folded into it, a decode step copies wq, wk and wv into
+    another layout at every layer instead of reading them where they are stored."""
     b, t, _ = y.shape
-    d, dt = cfg.d_head, y.dtype
+    d = cfg.d_head
 
     def project(w, heads, norm=None):
-        out = y @ bp[w].astype(dt)
+        out = _project_heads(y, bp[w])
         if norm is not None and cfg.qk_norm and not cfg.qk_norm_per_head:
             out = _rms_norm(out, bp[norm])
         out = out.reshape(b, t, heads, d)
@@ -605,7 +649,7 @@ def _project_latent(bp, y, cfg: TransformerConfig, positions):
     h, dn, dr, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     with jax.named_scope("attn.mla.q"):
         c_q = _rms_norm(y @ bp["wq_a"].astype(dt), bp["q_a_norm"])
-        q = (c_q @ bp["wq_b"].astype(dt)).reshape(b, t, h, dn + dr)
+        q = _project_heads(c_q, bp["wq_b"]).reshape(b, t, h, dn + dr)
     with jax.named_scope("attn.mla.kv"):
         kv = y @ bp["wkv_a"].astype(dt)
         c_kv = _rms_norm(kv[..., :r], bp["kv_a_norm"])

@@ -392,19 +392,20 @@ def test_window_decode_step_reads_and_writes_both_stacks_where_they_lie(v5e):
     8,448 in the full layer, x 256 in the three window layers) as the chip runs
     it: both pairs of stacks are the layer loop's carry, written a row a slot
     and layer in place (the ring at pos mod 256) and read by the decode kernel
-    as stored, each through its own span.  Its temporaries, 106 MB at this
-    depth, hold nothing of the cache: they are the query projections of the two
-    stacks of one layer here (`win_dense_blocks`, `blocks`), 50 MB each, which
-    the compiler lays out anew for the norm a head (a stack of several layers
-    is read where it lies); no buffer has a cache stack's size but the donated
-    one, written in place."""
+    as stored, each through its own span.  Its temporaries, 5.5 MB at this
+    depth, hold nothing of the cache and nothing of a weight: with the reshape
+    to heads folded into the projections they were 106 MB, a copy in another
+    layout of the query projection (100 MB a layer) of each stack of one layer
+    here (`win_dense_blocks`, `blocks`), and 864 MB at the cell's eight layers
+    (`transformer._project_heads`); no buffer has a cache stack's size but the
+    donated one, written in place."""
     cfg = transformer.TransformerConfig(**KEXAONE4)
     compiled, _, cache = _compiled_decode_step(cfg, v5e[0], 32, 8448, on_kernel=True)
     assert {n: c.shape for n, c in cache.items()} == {
         "k": (1, 32, 8448, 8, 128), "v": (1, 32, 8448, 8, 128), "kw": (3, 32, 256, 8, 128), "vw": (3, 32, 256, 8, 128)}
     kernels = re.findall(r"%(decode_attn[\w.]*) = \S+ custom-call\(", compiled.as_text())
     assert len(kernels) >= 2 and _has_kernel(compiled)  # a window layers' run and the full layer's
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * 6144 * 8192 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6  # a tenth of one layer's wq
     stacks = {c.size for c in cache.values()}
     seen = set()
     for _, n, line in _buffers(compiled, width=None):
@@ -515,7 +516,14 @@ def test_block_step_writes_its_rows_in_place_and_sorts_no_vocabulary(v5e):
     assert sorts and max(sorts) <= slots * b * cfg.n_experts < cfg.vocab_size, sorts
 
 
-@pytest.mark.parametrize("model", ["MISTRAL4", "OLMOE3", "SDAR3", "JAMBA8"])
+# Jamba2-3B's widths at the depth and in the order `jamba-closed6` runs them: 28 layers, attention
+# at 7 and 21.  The kernel's step is read at this depth, not at JAMBA8's: there the three attention
+# layers' keys are a stack of 19 MB, which the compiler may stage whole in fast memory around a
+# row's write (it does once no layer's wq is copied there), and that buffer counts as a temporary
+JAMBA28 = dict(JAMBA8, n_layers=28, attn_layer_period=14, attn_layer_offset=7)
+
+
+@pytest.mark.parametrize("model", ["MISTRAL4", "OLMOE3", "SDAR3", "JAMBA28"])
 def test_decode_step_attends_through_the_kernel_over_the_stacks_as_stored(v5e, model):
     """The decode step of each serving configuration's widths as the chip runs
     it (32 slots x 768; SDAR's is a pass of blocks of 4): the attention core is
@@ -538,6 +546,47 @@ def test_decode_step_attends_through_the_kernel_over_the_stacks_as_stored(v5e, m
     # the dense contraction's program, which the other tests of the step read, has no such kernel
     dense, _, _ = _compiled_decode_step(cfg, v5e[0])
     assert "decode_attn" not in dense.as_text()
+
+
+# the widths -> the slots and extent of the cell's cache, where they are not `_compiled_decode_step`'s own
+# (said as the other tests of a step say them: one compile a model)
+_PROJECTED = {"MISTRAL4": (), "OLMOE3": (), "SDAR3": (), "JAMBA28": (), "KEXAONE4": (32, 8448), "AXK13": (32, 4352)}
+
+
+@pytest.mark.parametrize("model", list(_PROJECTED))
+def test_decode_step_multiplies_by_the_projections_where_they_are_stored(v5e, model):
+    """The decode step at each serving configuration's widths (32 slots; SDAR's
+    is `_pass_step_rowpos`, a pass over blocks of 4; Jamba's at its cell's 28
+    layers, two of them attention) as the chip's compiler leaves it: of the size of a layer's wq, wk or wv (under latent attention
+    wq_b) it keeps the stacks themselves, seen by the layer loop or moved whole
+    between memory spaces, and nothing else: each projection is one fusion that
+    takes the stack and the layer's index.  With the reshape to heads folded
+    into the product (`transformer._project_heads`) every layer's matrix was
+    sliced out of its stack into fast memory by one fusion and transposed by a
+    copy before its product read it: `constant_dynamic-slice_fusion`
+    bf16[1,4096,4096] and `copy` bf16[1,4096,4096]{1,2,0} at Mistral's widths,
+    2.2 ms of an 11.3 ms step; a stack of one layer was copied in HBM, 100 MB
+    at K-EXAONE's.  (Latent attention's wkv_b, which the absorbed core
+    multiplies a head at a time, is still copied a layer: ROADMAP S18.)"""
+    cfg = transformer.TransformerConfig(**globals()[model])
+    compiled, params, _ = _compiled_decode_step(cfg, v5e[0], *_PROJECTED[model], on_kernel=True)
+    names = ("wq_b",) if cfg.latent else ("wq", "wk", "wv")
+    every = transformer.layer_stacks(params).values()
+    stacks = {stack[n].shape for stack in every for n in names if n in stack}
+    whole = {a.shape for stack in every for a in stack.values()}  # wo's has wq's sizes the other way round
+    # a value is a layer's matrix by its last two sizes, either way round (the copies were the transpose)
+    matrices = {shape[1:] for shape in stacks} | {shape[:0:-1] for shape in stacks}
+    seen, made = set(), []
+    for dtype, _, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        dims = tuple(int(d) for d in re.match(r"\w+\[([\d,]+)\]", shape).group(1).split(","))
+        if dtype != "bf16" or len(dims) not in (2, 3) or dims[-2:] not in matrices:
+            continue
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            seen.add(dims)
+        elif not (op in ("copy-done", "slice-done") and dims in whole and "{2,1,0:" in shape):
+            made.append(line[:200])  # a whole stack on its way to fast memory as it is stored is the compiler's to move
+    assert stacks <= seen and made == []
 
 
 def test_a_cache_of_one_row_keeps_the_dense_contraction(v5e, on_tpu):
