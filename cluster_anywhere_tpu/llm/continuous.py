@@ -61,7 +61,7 @@ goes through the same scheduler, cache and admit; what differs is the step:
   served token was fixed, which the tokens do not say.
 
 This module is the scheduler, the per-row sampler and the jitted wrapper.
-The model's mathematics is models/generate.py's: `prefill`, and `decode_rows`,
+The model's mathematics is models/generate.py's: `prefill_counted`, and `decode_rows`,
 the decode program's body, which also owns the cache's layout.  serve_llm.py
 is the deployment that drives it.
 """
@@ -81,7 +81,7 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, cache_bytes_per_token, cache_kind_bytes, decode_rows, init_cache, install_rows, key_slots, prefill,
+    _nucleus_mask, cache_bytes_per_token, cache_kind_bytes, decode_rows, init_cache, install_rows, key_slots, prefill_counted,
     recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
@@ -657,8 +657,9 @@ class ContinuousBatcher:
         sp.set(sample_rows=step.sample_rows, truncate_rows=step.truncate_rows)
         self.stats["sort_steps"] += step.truncate_rows > 0
         if touched is not None:
-            # a replica that holds a share of the experts reads two numbers: the held
-            # experts given a row, and the assignments that fell on them (layer means)
+            # a replica that holds a share of the experts reads the held experts given a
+            # row, then the assignments that fell on them (layer means; the third, the share
+            # of the layers that took the compact buffer, is an admit's to report)
             touched, *held = np.ravel(touched)
             sp.set(moe_rows=len(step.rows), moe_experts_touched=float(touched))
             if held:
@@ -818,17 +819,18 @@ class ContinuousBatcher:
         program a bucket, traced at the bucket's first admit and one dispatch
         thereafter (the padded ids and the pad count go as the host arrays
         they are).  Returns (first-token logits [1, V], its cache rows as a
-        batch of one, pad)."""
+        batch of one, pad, the held expert layers and the compact ones on the
+        device: `prefill_counted`'s, None where the replica holds every expert)."""
         with tracing.span("llm.admit.prefill"):
             padded = np.zeros((1, bucket), np.int32)
             pad = bucket - len(prompt)
             padded[0, pad:] = prompt  # LEFT pad: generate.py's prefill contract
-            programs = prefill._cache_size()
-            logits, rows = prefill(
+            programs = prefill_counted._cache_size()
+            logits, rows, held = prefill_counted(
                 self.params, padded, self.cfg, self.t_max, pad=np.asarray([pad], np.int32)
             )
-            self.stats["prefill_traces"] += prefill._cache_size() - programs
-        return logits, rows, pad
+            self.stats["prefill_traces"] += prefill_counted._cache_size() - programs
+        return logits, rows, pad, held
 
     def block_plan(self, n: int, max_new: int):
         """How a prompt of n tokens enters a model that generates by blocks of
@@ -853,7 +855,7 @@ class ContinuousBatcher:
         tail = len(prompt) - whole
         sp.set(bucket=bucket, prefix_hit=0, block_tail=tail)
         if whole:
-            _, rows, _ = self._prefill_padded(prompt[:whole], bucket)
+            _, rows, _, _ = self._prefill_padded(prompt[:whole], bucket)
             with tracing.span("llm.admit.install"):
                 self.cache = _install_slot(self.cache, rows, slot)
         self._blk_tokens[:, slot] = 0
@@ -866,11 +868,13 @@ class ContinuousBatcher:
 
     def _admit_full_prefill(self, req: Request, sp: tracing.span):
         """Cold admit: prefill the whole prompt.  Returns (first-token logits
-        [1,V], its rows as a batch of one, pad, next_pos).  `sp` is the
-        request's `llm.admit` span."""
+        [1,V], its rows as a batch of one, pad, next_pos, the prefill's held
+        expert layers: `_prefill_padded`).  `sp` is the request's `llm.admit`
+        span."""
         bucket = self._bucket(len(req.prompt_ids), req.max_new_tokens)
         sp.set(bucket=bucket, prefix_hit=0)
-        return (*self._prefill_padded(req.prompt_ids, bucket), bucket)
+        logits, rows, pad, held = self._prefill_padded(req.prompt_ids, bucket)
+        return logits, rows, pad, bucket, held
 
     def _admit_prefix_cached(self, req: Request, split: int, sp: tracing.span):
         """Chunked admit via the prefix cache: the block-aligned prefix
@@ -878,7 +882,9 @@ class ContinuousBatcher:
         teacher-forces through _suffix_step token by token.  Hit and miss
         run the SAME suffix computation on the same prefix rows, so the
         produced tokens are bit-identical either way — a hit just skips the
-        prefix prefill (the TTFT win on shared-system-prompt traffic)."""
+        prefix prefill (the TTFT win on shared-system-prompt traffic).  Returns
+        what `_admit_full_prefill` does; a hit ran no prefill and counts no
+        held layers."""
         prompt = req.prompt_ids
         suffix = prompt[split:]
         # bucket must leave room for the stepped suffix AND decode
@@ -886,8 +892,9 @@ class ContinuousBatcher:
         key = PrefixCache.key(prompt[:split], bucket)
         entry = self.prefix_cache.get(key)
         sp.set(bucket=bucket, prefix_hit=int(entry is not None))
+        held = None
         if entry is None:
-            _, rows, pad = self._prefill_padded(prompt[:split], bucket)
+            _, rows, pad, held = self._prefill_padded(prompt[:split], bucket)
             # store a snapshot BEFORE stepping: _suffix_step donates its rows
             self.prefix_cache.put(key, jax.tree_util.tree_map(jnp.copy, rows), pad)
             self.stats["prefix_misses"] += 1
@@ -906,7 +913,7 @@ class ContinuousBatcher:
                     jnp.asarray([bucket + i], np.int32),
                     pad_arr, cfg=self.cfg,
                 )
-        return logits, rows, pad, bucket + len(suffix)
+        return logits, rows, pad, bucket + len(suffix), held
 
     def _admit(self, out: Optional[Dict[int, List[int]]] = None) -> None:
         while self.queue and None in self._by_slot:
@@ -978,9 +985,9 @@ class ContinuousBatcher:
             else 0
         )
         if split:
-            logits, rows, pad, next_pos = self._admit_prefix_cached(req, split, sp)
+            logits, rows, pad, next_pos, held = self._admit_prefix_cached(req, split, sp)
         else:
-            logits, rows, pad, next_pos = self._admit_full_prefill(req, sp)
+            logits, rows, pad, next_pos, held = self._admit_full_prefill(req, sp)
         with tracing.span("llm.admit.install"):
             self.cache = _install_slot(self.cache, rows, slot)
         with tracing.span("llm.admit.sample"):
@@ -988,7 +995,12 @@ class ContinuousBatcher:
                 logits, self._rng, np.float32([req.temperature]), np.int32([req.top_k]),
                 np.float32([req.top_p]),
             )
+            # a replica that holds a share of the experts reads, with the token, how many
+            # of the prefill's expert layers took the compact buffer (parallel/moe.py)
+            first, held = jax.device_get((first, held))
             first = int(first)
+        if held is not None:
+            sp.set(moe_held_layers=int(held[0]), moe_compact_layers=int(held[1]))
         self._tokens[slot], self._fresh[slot] = first, 1
         self._pos[slot] = next_pos  # next write lands after the prompt
         self._pads[slot] = pad

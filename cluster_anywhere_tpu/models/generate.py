@@ -76,8 +76,9 @@ scan) is it with every row at the same position, and the continuous batcher's
 jitted step (llm/continuous.py) is it between unpacking its slot vectors and
 sampling.
 
-Compiled entry points: `prefill` (one program a prompt shape: the continuous
-batcher's admit calls it as it is, a bucket a program), `generate` (prefill
+Compiled entry points: `prefill_counted` (one program a prompt shape: the
+continuous batcher's admit calls it as it is, a bucket a program; `prefill` is
+the same program without its count of held expert layers), `generate` (prefill
 and the scanned decode loop as one program) and `_stream_fns`' pair.
 `decode_rows`, `decode_one` and `_sample` are bodies: plain functions that run
 inside their caller's program (the batcher's `_decode_step_rowpos` and
@@ -544,7 +545,8 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None,
     x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core, kind)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
-    return _ffn_half(bp, x, cfg, live, experts)[0], layer_cache
+    x, _, touched = _ffn_half(bp, x, cfg, live, experts)
+    return x, layer_cache, touched
 
 
 def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, experts=None):
@@ -575,36 +577,41 @@ def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, ex
 
 def _ssm_prefill_block(bp, x, pad, cfg: TransformerConfig, experts=None):
     """One state-space block over the whole prompt from the zero state;
-    returns the state after the last token, (window [B, K-1, C], h [B, C, N]).
+    returns the state after the last token, (window [B, K-1, C], h [B, C, N]),
+    and the experts touched or None.
     pad: [B] left-pad counts or None: a pad's input and step size are zeroed, so
     the state, and the logits, are the unpadded prompt's in any bucket."""
     keep = None if pad is None else jnp.arange(x.shape[1])[None, :] >= pad[:, None]
-    x, _, layer_state = _ssm_block_forward(bp, x, cfg, keep, experts)
-    return x, layer_state
+    x, _, layer_state, touched = _ssm_block_forward(bp, x, cfg, keep, experts)
+    return x, layer_state, touched
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "t_max"))
-def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
-    """ids: [B, T_prompt] -> (last-token logits [B, V], cache).
+def prefill_counted(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
+    """ids: [B, T_prompt] -> (last-token logits [B, V], cache, held layers).
     pad: optional [B] left-pad counts (see _prefill_block).  Compiled: one
     program for each shape of `ids` (with or without `pad`), `cfg` and
     `t_max`, traced at its first call and called thereafter; under another
     jit (`generate`, `_stream_fns`) it is a nested call of that program.
     A model that generates by blocks is given whole blocks of the prompt (pad
     and T_prompt multiples of `cfg.block_length`) and no logits come back: its
-    last position's logits are that position's own token, which is known."""
+    last position's logits are that position's own token, which is known.
+    Held layers: where the device holds a share of the experts
+    (`cfg.experts_held`), int32 [2]: the expert layers, and those of them whose
+    rows went through the compact buffer (parallel/moe.py); else None."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
     def attn(kind, x, bp, experts, _cache, _layer):
-        x, kv = _prefill_block(bp, x, pad, cfg, t_max, experts, kind)
-        return x, None, kv
+        x, kv, touched = _prefill_block(bp, x, pad, cfg, t_max, experts, kind)
+        return x, None, (kv, touched)
 
     def ssm(x, bp, experts, _cache, _layer):
-        x, state = _ssm_prefill_block(bp, x, pad, cfg, experts)
-        return x, None, state
+        x, state, touched = _ssm_prefill_block(bp, x, pad, cfg, experts)
+        return x, None, (state, touched)
 
-    x, _, rows = _scan_blocks(_bodies(attn, ssm), x, params, cfg)
+    x, _, outs = _scan_blocks(_bodies(attn, ssm), x, params, cfg)
+    rows = {kind: made for kind, (made, _) in outs.items()}
     # each kind's rows into the stacks of the state it keeps, at its layers' places there
     index = _state_index(cfg)
     cache: Dict[str, Any] = {}
@@ -614,7 +621,21 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
         for i, name in enumerate(names if kinds else ()):
             joined = rows[kinds[0]][i] if len(kinds) == 1 else jnp.concatenate([rows[kind][i] for kind in kinds])
             cache[name] = joined if np.array_equal(order, np.arange(len(order))) else joined[order]
-    return None if cfg.generates_blocks else _head(params, x, cfg, row=-1), cache
+    held = None
+    if cfg.experts_held is not None:
+        # a held layer's touched is [layers, 3]: the last says which branch its rows took
+        compact = jnp.concatenate([touched[:, 2] for _, touched in outs.values() if touched is not None])
+        held = jnp.stack([jnp.int32(compact.shape[0]), jnp.sum(compact)])
+    return None if cfg.generates_blocks else _head(params, x, cfg, row=-1), cache, held
+
+
+def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
+    """`prefill_counted`'s program without its count: (last-token logits
+    [B, V], cache).  `pad` goes by keyword, as the batcher's admit passes it:
+    a call's signature is part of what a traced program is found by, and the
+    benchmark's check calls this for the admit's own program, not a second
+    trace of it."""
+    return prefill_counted(params, ids, cfg, t_max, pad=pad)[:2]
 
 
 def _bodies(attn, ssm):
@@ -630,8 +651,9 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     row (`generate`, `decode_one`, a suffix step).  Returns (logits
     [B, V], updated cache, experts touched: the mean over the expert layers of
     the experts that were given a row, None for a dense model; where this
-    device holds a share of the experts, [2]: that of the held, and the mean of
-    the assignments that fell on them).
+    device holds a share of the experts, [3]: that of the held, the mean of
+    the assignments that fell on them, and the share of the layers that took the
+    compact buffer).
 
     tokens [B, T]: one pass of each row's own block of T positions, the first
     of them at pos[b] (a model that generates by blocks).  Returns the logits

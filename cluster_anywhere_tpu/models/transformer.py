@@ -684,8 +684,9 @@ def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
     `experts`: (every layer's experts' matrices, unsliced; this layer's index)
     from a scan that keeps them out of its slices (models/generate.py
     _scan_blocks); None: `bp` holds this layer's own, a stack of one.  Returns
-    (out [B, T, E], aux loss, experts touched: with `cfg.experts_held` [2], the
-    held experts given a row and the assignments that fell on them)."""
+    (out [B, T, E], aux loss, experts touched: with `cfg.experts_held` [3], the
+    held experts given a row, the assignments that fell on them, and 1 where
+    they went through the compact buffer)."""
     from ..parallel.moe import EXPERT_MATRICES, routed_ffn
 
     b, t, e = y.shape
@@ -697,8 +698,8 @@ def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
     )
     touched = r.experts_touched
     if cfg.experts_held is not None:
-        # beside the held experts given a row, the assignments that fell on them
-        touched = jnp.stack([touched, r.assignments])
+        # beside the held experts given a row, the assignments that fell on them and which branch took them
+        touched = jnp.stack([touched, r.assignments, r.compact])
     return r.out.reshape(b, t, e), r.aux_loss, touched
 
 
@@ -985,11 +986,12 @@ def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
     """One state-space block over whole sequences from the zero state, for
     training and for a prompt's prefill.  keep: [B, T] bool, False at a left
     pad, None for none (`_ssm_half`); experts: `_ffn_half`'s.  Returns (x, the
-    MoE load-balance loss: 0 dense, the state after the last position)."""
+    MoE load-balance loss: 0 dense, the state after the last position, experts
+    touched or None)."""
     core = lambda xs: _ssm_mix(bp, xs, _ssm_zero_state(cfg, x.shape[0]), cfg, keep)
     x, state = _ssm_half(bp, x, cfg, core, keep)
-    x, aux, _ = _ffn_half(bp, x, cfg, keep, experts)
-    return x, jnp.zeros((), jnp.float32) if aux is None else aux, state
+    x, aux, touched = _ffn_half(bp, x, cfg, keep, experts)
+    return x, jnp.zeros((), jnp.float32) if aux is None else aux, state, touched
 
 
 def layer_stacks(params) -> Dict[str, Any]:

@@ -20,6 +20,23 @@ computed, the others sort behind the last group with the rows that are not
 live and add nothing.  What the other devices' experts would add is theirs to
 compute: nothing here stands in for it.
 
+A held share's layer has two branches, and the rows decide between them as
+the program runs.  The assignments that fall on the share are the head of the
+sorted rows, about N x k x held / routed of N x k.  Where that leaves a buffer
+of at most half the rows (`compact_buffer_rows`: a prefill's bucket and a
+decode step's slots alike, by their static row count) the first C sorted rows
+alone, a static COMPACT_SHARE times that even share, are gathered, go through
+the same three grouped matmuls and are weighted and summed into their tokens'
+rows in float32 (`_combine_compact`: each token's k places looked up among the
+C rows), so nothing of the size of N x k rows is read, written or sorted back.
+`lax.cond(rows in groups <= C, compact, every row)`: a router that sends the
+share more than C rows takes the branch over all N x k rows, which is the path
+of a device that holds every expert, so no assignment is dropped and the two
+branches give the same sums up to float32 reassociation.
+`RoutedOutput.compact` says which ran.  The compact buffer is also what the
+exchange over 'ep' will fill: the rows a chip receives from the all-to-all are
+exactly the sorted head that it holds experts for.
+
 `moe_ffn` is expert parallelism for training over an 'ep' mesh axis:
 switch-style top-1 routing with a capacity, tokens exchanged with
 `lax.all_to_all`, ungated experts.  Its dispatch/combine use STATIC-SHAPE
@@ -48,6 +65,34 @@ from jax import lax
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down", "w_in", "w_out")
 
 
+# A held share's compact buffer (`routed_ffn`, module docstring) is this many
+# times the even share of the assignments, N * k * held / routed.  Measured on
+# the chip (scripts/moe_compact_sweep.py `held`, PERF.md section 6, PR 45): of
+# 1,134 (prompt, expert layer) pairs of `kexaone-longrag-closed6`'s own seeded
+# models (9 seeds; 8 of 128 held) 87.8% were given at most 2 x the even share
+# of their bucket, 95.9% at most 3 x, 98.7% at most 4 x, 99.8% at most 6 x (the
+# most 6.08 x: a seeded router is not balanced); of 972 of `axk1-rag-closed6`
+# (12 of 192) all at most 2 x.  At 3 x and 4 x the layer costs the same (11.1
+# and 11.6 ms at 8,192 rows, all N x k rows 21.1), so the larger holds.
+COMPACT_SHARE = 4
+# The compact combine looks its rows up in column blocks of at most this many
+# bytes of the computed rows.  The chip's compiler keeps a source of up to 117
+# MB (8,192 x 7,168 bf16) in fast memory for the k gathers that read it and
+# leaves a larger one in HBM, where a gather of 8,192 rows is 0.77 ms in place
+# of 0.16: at 8,192 rows x 8 of width 6,144 (C = 16,384, 201 MB) the layer
+# reads 13.4 ms with one block and 11.6 with two (my chip runs, PR 45).
+COMPACT_LOOKUP_BYTES = 96 * 2 ** 20
+
+
+def compact_buffer_rows(n: int, k: int, held: int, routed: int) -> int:
+    """The sorted rows a share of `held` of `routed` experts computes of N x k
+    assignments when no more than that many fall on it: COMPACT_SHARE times
+    the even share, in whole sublanes.  0 where the layer keeps all N x k: a
+    share so large, or rows so few, that the buffer is over half of them."""
+    c = -(-COMPACT_SHARE * n * k * held // (8 * routed)) * 8
+    return c if 2 * c <= n * k else 0
+
+
 class MoEOutput(NamedTuple):
     out: jax.Array
     aux_loss: jax.Array  # load-balancing loss (Switch Transformer style)
@@ -58,6 +103,49 @@ class RoutedOutput(NamedTuple):
     aux_loss: jax.Array  # load-balancing loss, as moe_ffn's
     experts_touched: jax.Array  # int32: experts (of those held) that were given at least one row
     assignments: jax.Array  # int32: (row, expert) pairs that were computed: live rows x k, of which on experts held
+    compact: jax.Array  # int32: 1 where a held share's rows went through the compact buffer (module docstring), else 0
+
+
+def _combine_every_row(out, gate, order, back, in_groups):
+    """All N x k sorted rows `out` [N * k, E], each weighted by its gate [N, k]
+    in float32, returned to token order and summed over k: [N, E] in out's dtype."""
+    n, k = gate.shape
+    with jax.named_scope("moe.combine"):
+        # rows past the last group belong to no expert: their product is not defined
+        weighted = jnp.where((jnp.arange(n * k) < in_groups)[:, None], out.astype(jnp.float32), 0.0)
+        weighted = weighted * gate.reshape(n * k)[order][:, None]
+        return jnp.sum(weighted[back].reshape(n, k, -1), axis=1).astype(out.dtype)
+
+
+def _combine_compact(out, gate, back, in_groups):
+    """The same sums from the sorted head alone, `out` [C, E] with every
+    group's rows in it (`in_groups <= C`): each token's k places are looked up
+    in the C computed rows, an assignment that was computed elsewhere (or by
+    nobody: a row not live) at weight 0, one [N, .] gather a place, weighted
+    and added in float32 in the places' order.  Linear in C and N x k; no
+    [N * k, E] array is made.  The C rows are looked up a block of columns at
+    a time, each block a value of its own (the barrier), of at most
+    COMPACT_LOOKUP_BYTES: the chip's compiler keeps a block of that size in
+    fast memory for its k gathers, and one that is larger in HBM."""
+    n, k = gate.shape
+    c, e = out.shape
+    blocks = -(-c * e * out.dtype.itemsize // COMPACT_LOOKUP_BYTES)
+    width = -(-e // (128 * blocks)) * 128
+    with jax.named_scope("moe.combine"):
+        at = back.reshape(n, k)
+        weight = jnp.where(at < in_groups, gate, 0.0)
+        at = jnp.minimum(at, c - 1)
+        computed = (jnp.arange(c) < in_groups)[:, None]
+        sums = []
+        for lo in range(0, e, width):
+            block = jnp.where(computed, out[:, lo:lo + width], 0)
+            if blocks > 1:
+                block = lax.optimization_barrier(block)
+            total = jnp.zeros((n, block.shape[-1]), jnp.float32)
+            for j in range(k):
+                total = total + block[at[:, j]].astype(jnp.float32) * weight[:, j, None]
+            sums.append(total.astype(out.dtype))
+        return sums[0] if len(sums) == 1 else jnp.concatenate(sums, axis=-1)
 
 
 def routed_ffn(
@@ -124,24 +212,37 @@ def routed_ffn(
             # a row that takes no expert sorts behind the last group
             expert = jnp.where(jnp.repeat(live, k), expert, n_experts)
         order = jnp.argsort(expert, stable=True)  # sorted row -> assignment
-        group_sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[expert].add(1)[:n_experts]
-        rows = x[order // k]  # [N*k, E], expert by expert
-    with jax.named_scope("moe.experts"):
-        n_layers = experts["w_down" if gated else "w_out"].shape[0]
-        every = jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(group_sizes).reshape(-1)
-        grouped = lambda a, w: lax.ragged_dot(a, w.reshape(-1, *w.shape[2:]).astype(dt), every)
-        if gated:
-            hidden = jax.nn.silu(grouped(rows, experts["w_gate"])) * grouped(rows, experts["w_up"])
-            out = grouped(hidden, experts["w_down"])
-        else:
-            out = grouped(jax.nn.silu(grouped(rows, experts["w_in"])), experts["w_out"])
-    with jax.named_scope("moe.combine"):
-        # rows past the last group belong to no expert: their product is not defined
-        in_a_group = jnp.arange(n * k) < jnp.sum(group_sizes)
-        out = jnp.where(in_a_group[:, None], out.astype(jnp.float32), 0.0)
-        out = out * gate.reshape(n * k)[order][:, None]
-        back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
-        out = jnp.sum(out[back].reshape(n, k, -1), axis=1).astype(dt)
+        group_sizes = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0, dtype=jnp.int32)
+        in_groups = jnp.sum(group_sizes)  # the sorted rows that belong to an expert held here: the sorted head
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))  # assignment -> sorted row
+    n_layers = experts["w_down" if gated else "w_out"].shape[0]
+    every = jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(group_sizes).reshape(-1)
+    grouped = lambda a, w: lax.ragged_dot(a, w.reshape(-1, *w.shape[2:]).astype(dt), every)
+
+    def through_experts(c: int):
+        """The first `c` sorted rows gathered, through their experts: [c, E] in
+        x's dtype.  Rows past the last group belong to no expert: their
+        product is not defined, and `moe.combine` masks them."""
+        with jax.named_scope("moe.dispatch"):
+            rows = x[order[:c] // k]  # [c, E], expert by expert
+        with jax.named_scope("moe.experts"):
+            if gated:
+                hidden = jax.nn.silu(grouped(rows, experts["w_gate"])) * grouped(rows, experts["w_up"])
+                return grouped(hidden, experts["w_down"])
+            return grouped(jax.nn.silu(grouped(rows, experts["w_in"])), experts["w_out"])
+
+    def every_row():
+        return _combine_every_row(through_experts(n * k), gate, order, back, in_groups)
+
+    def compact_rows(c: int):
+        return _combine_compact(through_experts(c), gate, back, in_groups)
+
+    c = 0 if held is None else compact_buffer_rows(n, k, n_experts, n_routed)
+    if c:
+        compact = in_groups <= c
+        out = lax.cond(compact, lambda: compact_rows(c), every_row)
+    else:
+        compact, out = jnp.zeros((), bool), every_row()
     # load-balance aux loss over the live rows: share of the assignments an
     # expert was given times its mean probability, summed over experts
     rows_live = jnp.ones((n,), jnp.float32) if live is None else live.astype(jnp.float32)
@@ -149,7 +250,7 @@ def routed_ffn(
     frac = group_sizes.astype(jnp.float32) / (n_live * k)
     mean_prob = jnp.sum(probs * rows_live[:, None], axis=0) / n_live
     aux = jnp.sum(frac * mean_prob) * n_routed
-    return RoutedOutput(out, aux, jnp.sum(group_sizes > 0).astype(jnp.int32), jnp.sum(group_sizes))
+    return RoutedOutput(out, aux, jnp.sum(group_sizes > 0).astype(jnp.int32), in_groups, compact.astype(jnp.int32))
 
 
 def moe_ffn(

@@ -103,7 +103,7 @@ def test_dispatcher_pads_to_the_kernel_tile(v5e, on_tpu, t):
 
 
 def _compiled_admit_prefill(cfg, bucket, t_max, device):
-    """`generate.prefill`, the jitted function itself, compiled as an admit
+    """`generate.prefill_counted`, the jitted function itself, compiled as an admit
     calls it: a batch-1 left-padded prompt of one bucket's length."""
     one = SingleDeviceSharding(device)
     on_chip = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
@@ -112,7 +112,7 @@ def _compiled_admit_prefill(cfg, bucket, t_max, device):
     )
     ids = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one)
     pad = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one)
-    return generate.prefill.lower(params, ids, cfg, t_max, pad=pad).compile()
+    return generate.prefill_counted.lower(params, ids, cfg, t_max, pad=pad).compile()
 
 
 @pytest.mark.parametrize("bucket", [128, 256])
@@ -480,6 +480,52 @@ def test_decode_step_sorts_the_vocabulary_only_under_a_conditional(v5e, model):
     assert 'op_name="jit(<lambda>)/sample/cond"' in choice
     branches = re.search(r"branch_computations=\{([^}]*)\}", choice).group(1).replace("%", "").split(", ")
     assert [len(wide_sorts(reached([b]))) for b in branches] == [0, 0, 2]
+
+
+@pytest.mark.parametrize("model", ["kexaone", "axk1"])
+def test_a_held_shares_prefill_keeps_every_assignments_row_under_a_conditional(v5e, on_tpu, model):
+    """An admit's prefill in the 4,096 bucket at the widths of the two
+    configurations that hold a share of their experts, as the chip's compiler
+    leaves it: the expert layer works on a compact buffer of 4 x the even share
+    of the sorted rows (8,192 of 32,768), and an array of all N x k rows of the
+    model's width ([32768, E] in any type: the gathered rows, the experts'
+    result, its float32 copies in the combine, 0.4 to 1.6 GB each at 8,192) is
+    only in the computation that one `conditional` calls where more rows fell
+    on the share than the buffer holds, never in what every admit runs."""
+    cfg = transformer.TransformerConfig(**(KEXAONE4 if model == "kexaone" else AXK13))
+    bucket, k, e = 4096, cfg.n_experts_per_tok, cfg.d_model
+    compiled = _compiled_admit_prefill(cfg, bucket, bucket + 256, v5e[0])
+    comps, entry = _computations(compiled.as_text())
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", compiled.as_text()))
+
+    def reached(roots):
+        """The computations `roots` call, through anything but a conditional's choice."""
+        seen, todo = set(), list(roots)
+        while todo:
+            name = todo.pop()
+            if name not in seen:
+                seen.add(name)
+                todo += [n for line in comps[name] if " conditional(" not in line
+                         for n in re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[-1]) if n in comps]
+        return seen
+
+    def every_rows(names):
+        """Values of [N x k, E] that a computation keeps in memory (not a fusion's inside)."""
+        return [line.strip()[:100] for name in names - fused for line in comps[name]
+                if re.search(rf"= \(?\w+\[{bucket * k},{e}\]", line)]
+
+    always = reached([entry])
+    assert len(always) > 10 and every_rows(always) == []
+    # one conditional a run of expert layers (its layer loop's body): branch 0, `false`, keeps all
+    # N x k rows (gathered, through the experts, twice in float32), branch 1 the compact buffer
+    choices = [line for name in always for line in comps[name] if " conditional(" in line]
+    assert len(choices) == (2 if model == "kexaone" else 1)
+    for choice in choices:
+        branches = re.search(r"branch_computations=\{([^}]*)\}", choice).group(1).replace("%", "").split(", ")
+        kept = [len(every_rows(reached([b]))) for b in branches]
+        assert kept[0] >= 3 and kept[1] == 0, kept
+    compact = bucket * k * cfg.experts_held[1] * 4 // cfg.n_experts
+    assert any(re.search(rf"= \(?bf16\[{compact},{e}\]", line) for b in reached([branches[1]]) - fused for line in comps[b])
 
 
 # SDAR-30B-A3B's widths (128 experts of 2048 x 768, 8 a token, 32 Q / 4 KV heads

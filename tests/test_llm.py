@@ -573,7 +573,7 @@ def _compile_program(which):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     if which == "prefill":
         # the function under the jit: a jit keeps what it traced, scopes and all
-        fn = jax.jit(lambda p, ids, pad: generate.prefill.__wrapped__(p, ids, cfg, t_max, pad))
+        fn = jax.jit(lambda p, ids, pad: generate.prefill_counted.__wrapped__(p, ids, cfg, t_max, pad))
         return fn.lower(params, i32(1, 16), i32(1)).compile()
     step, _ = transformer.make_train_step(cfg, None)
     opt = jax.eval_shape(lambda p: optax.adamw(3e-4, weight_decay=0.01).init(p), params)
@@ -810,15 +810,15 @@ def test_a_warm_admit_runs_its_buckets_one_compiled_prefill(model, llm_spans):
     cfg = TransformerConfig(**dict(model, d_ff=48))
     cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=6, t_max=40,
                            prefill_buckets=(8, 16))
-    programs = generate.prefill._cache_size()
+    programs = generate.prefill_counted._cache_size()
     token = tracing.push_execution(TRACE)
     try:
         for n in (3, 12):  # cold: one program a bucket
             cb.submit(list(range(1, n + 1)), max_new_tokens=4)
             events, _, _ = _watch_admit(cb)
-            assert {("jaxpr_trace", "prefill"), ("jaxpr_to_mlir_module", "jit(prefill)"),
-                    ("backend_compile", "jit(prefill)")} <= set(events)
-        assert generate.prefill._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
+            assert {("jaxpr_trace", "prefill_counted"), ("jaxpr_to_mlir_module", "jit(prefill_counted)"),
+                    ("backend_compile", "jit(prefill_counted)")} <= set(events)
+        assert generate.prefill_counted._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
         # warm, the last with no padding; a request's knobs are the sampler's operands
         for n, knobs in ((5, {}), (9, dict(temperature=0.7, top_k=3, top_p=0.9)), (8, dict(temperature=1.2))):
             cb.submit(list(range(2, n + 2)), max_new_tokens=4, **knobs)
@@ -826,9 +826,42 @@ def test_a_warm_admit_runs_its_buckets_one_compiled_prefill(model, llm_spans):
             assert events == [] and before_sample == [] and sample == [], (n, events, sample)
     finally:
         tracing.pop_execution(token)
-    assert generate.prefill._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
+    assert generate.prefill_counted._cache_size() == programs + 2 and cb.stats["prefill_traces"] == 2
     admits = [e for e in llm_spans() if e["name"] == "llm.admit"]
     assert [(a["bucket"], a["traced"]) for a in admits] == [(8, 1), (16, 1), (8, 0), (16, 0), (8, 0)]
+
+
+def test_the_plain_prefill_finds_the_program_an_admit_traced():
+    """`generate.prefill` is `prefill_counted`'s program without its count,
+    and the benchmark's check calls it for the admit's own program (device
+    arrays, the pad by keyword: `benchmarks/harness/reference.py`): after an
+    admit of the bucket it traces, lowers and compiles nothing.  Handing the
+    pad on by position was a second signature, and a second trace, lowering
+    and load of every bucket the check touches: 4 s each on the chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.models import generate
+    from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(**dict(_TINY_MIXTURE, d_ff=40, experts_held=(2, 2)))
+    cb = ContinuousBatcher(init_params(jax.random.key(0), cfg), cfg, slots=2, t_max=40, prefill_buckets=(16,))
+    cb.submit(list(range(1, 12)), max_new_tokens=4)
+    cb._admit()
+    padded = np.zeros((1, 16), np.int32)
+    padded[0, 5:] = np.arange(1, 12)
+    ids, pad, events = jnp.asarray(padded), jnp.asarray([5], np.int32), []
+    on_event = lambda event, duration, **kw: events.append(
+        (event.rsplit("/", 1)[-1].removesuffix("_duration"), kw.get("fun_name")))
+    monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        logits, rows = generate.prefill(cb.params, ids, cfg, cb.t_max, pad=pad)
+    finally:
+        monitoring.unregister_event_duration_listener(on_event)
+    assert logits.shape == (1, 64) and set(rows) == {"k", "v"}
+    assert not {"jaxpr_to_mlir_module", "backend_compile"} & {event for event, _ in events}, events
 
 
 def test_the_prefix_cached_admit_prefills_through_the_same_program(llm_spans):
@@ -851,7 +884,7 @@ def test_the_prefix_cached_admit_prefills_through_the_same_program(llm_spans):
         for first in (1, 2, 1):  # a miss, a miss of the same split, a hit
             cb.submit(list(range(first, first + 19)), max_new_tokens=3)
             events, before_sample, _ = _watch_admit(cb)
-            seen.append((cb.stats["prefill_traces"], ("jaxpr_trace", "prefill") in events))
+            seen.append((cb.stats["prefill_traces"], ("jaxpr_trace", "prefill_counted") in events))
             if len(seen) > 1:
                 assert set(events) <= {("jaxpr_trace", "convert_element_type")}, events
                 # the snapshot's copy and the suffix's scalar uploads, no slice of the rows
@@ -917,7 +950,7 @@ def test_every_program_traces_the_one_block(model, program, monkeypatch):
     if program == "forward":
         fn, args = lambda p, i: transformer.forward(p, i, cfg), (params, ids)
     elif program == "prefill":
-        fn, args = lambda p, i, pad: generate.prefill.__wrapped__(p, i, cfg, t_max, pad), (params, ids, row)
+        fn, args = lambda p, i, pad: generate.prefill_counted.__wrapped__(p, i, cfg, t_max, pad), (params, ids, row)
     elif program == "decode_one":
         fn = lambda p, c, tok, pos: generate.decode_one(p, c, tok, pos, cfg)
         args = (params, cache, row, jax.ShapeDtypeStruct((), jnp.int32))
@@ -933,7 +966,7 @@ def test_every_program_traces_the_one_block(model, program, monkeypatch):
 
 def test_the_batcher_holds_no_model_mathematics():
     """`llm/continuous.py` is the scheduler, the sampler and the jitted
-    wrapper: of `models/` it takes `prefill`, the decode program's body and the
+    wrapper: of `models/` it takes `prefill_counted`, the decode program's body and the
     nucleus mask its sampler shares, and it names no block, norm or layer loop."""
     import ast
     import inspect
@@ -950,14 +983,14 @@ def test_the_batcher_holds_no_model_mathematics():
     }
     # and the cache's layout, which is `generate.py`'s: what a slot holds, how one
     # request's rows are written over it, how much of it is recurrent state
-    assert imported == {"prefill", "decode_rows", "_nucleus_mask", "TransformerConfig",
+    assert imported == {"prefill_counted", "decode_rows", "_nucleus_mask", "TransformerConfig",
                         "init_cache", "install_rows", "recurrent_state_bytes", "cache_bytes_per_token",
                         "key_slots", "cache_kind_bytes"}
     for name in ("_rms_norm", "_scan_blocks", "_scan_layers", "_block_", "_half", "_ssm_mix", "_project_qkv",
                  "_rope", "lax.scan", '"k"', '"v"', '"h"', "n_kv_heads", "d_inner"):
         assert name not in source, name
     called = [n.func.id for n in nodes if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)]
-    assert called.count("prefill") == 1  # one pad-and-prefill for both admits
+    assert called.count("prefill_counted") == 1  # one pad-and-prefill for both admits
 
 
 def _rows_of_the_next_step(cb):

@@ -125,21 +125,24 @@ SWA = dict(vocab_size=97, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_hea
            norm_output=True, qk_norm=True, qk_norm_per_head=True, **SHARE)
 
 
+@pytest.mark.parametrize("t", [17, 256], ids=["34-rows", "a-prefills-512-rows"])
 @pytest.mark.parametrize("arch", ["mla_moe", "swa_moe"])
-def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(arch):
+def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(arch, t):
     """What ties the share to the model (model-configs guide, section 4): the
     routed parts that the 16 shares give, each from the program's own expert
     layer told which 2 of 32 experts it holds, with the shared expert counted
     once, are the uncut reference layer's FFN; and the program's second half
     over the uncut layer is the reference's, the norm before the FFN
-    (references/mla_moe.py) or after it (references/swa_moe.py)."""
+    (references/mla_moe.py) or after it (references/swa_moe.py).  At 34 rows as
+    at a prefill's 512 every share computes its part from the compact buffer
+    (parallel/moe.py)."""
     ref = manifest.load_reference(arch)
     cfg = _model()[0] if arch == "mla_moe" else TransformerConfig(**SWA, dtype=jnp.float32, param_dtype=jnp.float32)
     whole = dataclasses.replace(cfg, experts_held=None)
     bp = jax.tree_util.tree_map(lambda w: w[0], init_params(jax.random.key(9), whole)["blocks"])
     bp["ln2"] = bp["ln2"] * jnp.linspace(0.7, 1.3, 64)
     assert bp["w_gate"].shape == (32, 64, 24) and bp["router"].shape == (64, 32)
-    x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 17, 64)), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(2, t, 64)), jnp.float32)
     y = x if cfg.norm_output else ref._rms_norm(x, bp["ln2"])
     with jax.default_matmul_precision("highest"):
         routed, weight = reference._routed(y.reshape(-1, 64), bp, 4, True, 2.5, 0)
@@ -148,7 +151,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
         want = x + (ref._rms_norm(ffn, bp["ln2"]) if cfg.norm_output else ffn)
     assert np.all(np.sum(np.asarray(weight) > 0, axis=-1) == 4)
     np.testing.assert_allclose(np.sum(np.asarray(weight), axis=-1), 2.5, rtol=1e-5)  # renormalised, scaled
-    total, assignments = None, 0
+    total, assignments, compact = None, 0, 0
     for share in range(16):
         held = dataclasses.replace(cfg, experts_held=(2 * share, 2))
         mine = {k: (v[2 * share:2 * share + 2] if k in EXPERT_MATRICES else v) for k, v in bp.items()}
@@ -158,8 +161,10 @@ def test_the_sixteen_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer
             part, _, counts = transformer._moe(mine, y, held)
         total = part if total is None else total + part
         assignments += int(counts[1])
+        compact += int(counts[2])
     np.testing.assert_allclose(total, ffn, atol=2e-5)
-    assert assignments == 2 * 17 * 4  # every (token, expert) pair fell on exactly one share
+    assert assignments == 2 * t * 4  # every (token, expert) pair fell on exactly one share
+    assert compact == 16
     np.testing.assert_allclose(transformer._ffn_half(bp, x, whole)[0], want, atol=2e-5)
 
 
@@ -206,8 +211,10 @@ def test_a_stack_of_dense_then_expert_layers_scans_by_runs(program, model):
         for name in ("ckv", "kr"):
             changed = np.any(np.asarray(after[name]) != before[name], axis=-1)
             assert changed.shape == (4, 1, 16) and np.array_equal(np.nonzero(changed)[2], [5, 5, 5, 5])
-        # a share's step says what fell on it: the held experts given a row and the assignments, layer means
-        assert touched.shape == (2,) and 0 <= float(touched[0]) <= 2 and float(touched[0]) <= float(touched[1]) <= 4
+        # a share's step says what fell on it: the held experts given a row and the assignments, layer means,
+        # and the share of the layers whose rows went through the compact buffer
+        assert touched.shape == (3,) and 0 <= float(touched[0]) <= 2 and float(touched[0]) <= float(touched[1]) <= 4
+        assert 0 <= float(touched[2]) <= 1
 
 
 def test_the_batcher_installs_latent_rows_and_snapshots_a_prefix_of_them(model):

@@ -1,6 +1,7 @@
 """The dropless routed expert path (parallel/moe.py routed_ffn) against a
-per-token loop in float32, and the places the program calls it from: the
-one-device forward and loss, prefill, and the decode step with its live mask."""
+per-token loop in float32, a held share's compact buffer against all N x k
+rows, and the places the program calls it from: the one-device forward and
+loss, prefill, and the decode step with its live mask."""
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +11,7 @@ import pytest
 from cluster_anywhere_tpu.llm import continuous
 from cluster_anywhere_tpu.models import generate, transformer
 from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
+from cluster_anywhere_tpu.parallel import moe
 from cluster_anywhere_tpu.parallel.moe import EXPERT_MATRICES, init_moe_params, routed_ffn
 
 E, F, X = 16, 24, 8
@@ -141,6 +143,129 @@ def test_a_layer_of_the_stack_is_that_layer_alone(gated):
         assert int(got.experts_touched) == int(scanned.experts_touched[i]) == int(want.experts_touched)
 
 
+# -- a held share: the compact buffer and all N x k rows ------------------------
+
+X32, PREFILL = 32, 512  # 16 shares of 2 experts; a prefill's rows
+
+
+def routed_share(x, bp, share, **kw):
+    """`routed_ffn` told that it holds the share's 2 of the layer's 32 experts, a stack of one."""
+    mine = {k: bp[k][None, 2 * share:2 * share + 2] for k in EXPERT_MATRICES if k in bp}
+    return routed_ffn(x, bp["router"], mine, held=(2 * share, 2), **kw)
+
+
+def every_row(monkeypatch):
+    """`routed_ffn` as it was before the compact buffer: none is under half the rows."""
+    monkeypatch.setattr(moe, "COMPACT_SHARE", 10 ** 6)
+
+
+@pytest.mark.parametrize("with_live", [False, True], ids=["all-live", "padded"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+def test_a_held_shares_compact_buffer_gives_what_every_row_gives(gated, scoring, with_live, monkeypatch):
+    """A share of 2 of 32 experts at a prefill's 512 rows x 4: the first 512
+    sorted rows (4 x the even share of 128) go through the experts and each
+    token's places are looked up among them; the result is the one all 2,048
+    rows give, to a float32 sum's rounding, and `RoutedOutput.compact` says
+    which ran."""
+    bp = init_moe_params(jax.random.key(3), E, F, X32, jnp.float32, gated=gated)
+    bp["router"] = bp["router"] * 40  # scores that differ
+    x = tokens(PREFILL, seed=4)
+    live = jnp.arange(PREFILL) >= 37 if with_live else None
+    assert moe.compact_buffer_rows(PREFILL, 4, 2, X32) == 512
+    kw = dict(k=4, renormalize=True, scoring=scoring, scale=2.5, live=live)
+    with jax.default_matmul_precision("highest"):
+        got = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+        every_row(monkeypatch)
+        want = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+    for g, w in zip(got, want):
+        assert int(g.compact) == 1 and int(w.compact) == 0
+        assert 0 < int(g.assignments) == int(w.assignments) <= 512 and int(g.experts_touched) == int(w.experts_touched)
+        assert np.abs(np.asarray(w.out)).max() > 1e-3
+        np.testing.assert_allclose(g.out, w.out, atol=1e-6 * float(np.abs(np.asarray(w.out)).max()) + 1e-7)
+        assert float(g.aux_loss) == pytest.approx(float(w.aux_loss), rel=1e-6)
+        if with_live:
+            assert not np.asarray(g.out)[:37].any()
+
+
+@pytest.mark.parametrize("crowded", [False, True], ids=["even", "crowded"])
+def test_the_sixteen_shares_add_up_whichever_branch_each_took(crowded):
+    """Dropless behind the conditional: with a router that sends every token's
+    first two choices to experts 0 and 1, share 0 is given 1,024 rows, twice its
+    buffer, and takes all N x k rows; the other fifteen stay compact; the
+    sixteen parts are still the uncut layer, row by row.  With an even router
+    all sixteen are compact."""
+    bp = init_moe_params(jax.random.key(5), E, F, X32, jnp.float32, gated=True)
+    bp["router"] = bp["router"] * 40
+    x = tokens(PREFILL, seed=6)
+    if crowded:
+        x = x.at[:, 0].set(1.0)
+        bp["router"] = bp["router"].at[0, 0].set(90.0).at[0, 1].set(80.0)
+    kw = dict(k=4, renormalize=True, scoring="sigmoid", scale=2.5)
+    with jax.default_matmul_precision("highest"):
+        whole = routed_ffn(x, bp["router"], {k: bp[k][None] for k in EXPERT_MATRICES if k in bp}, **kw)
+        parts = [routed_share(x, bp, s, **kw) for s in range(16)]
+    assert int(whole.compact) == 0 and int(whole.assignments) == PREFILL * 4
+    assert [int(p.compact) for p in parts] == [0 if crowded else 1] + [1] * 15
+    assert int(parts[0].assignments) == (1024 if crowded else int(parts[0].assignments)) and sum(
+        int(p.assignments) for p in parts) == PREFILL * 4  # every (token, expert) pair fell on exactly one share
+    np.testing.assert_allclose(sum(p.out for p in parts), whole.out, atol=2e-5)
+
+
+HELD = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4, d_head=8, d_ff=48,
+            n_experts=32, n_experts_per_tok=2, moe_gated=True, experts_held=(4, 2),
+            dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def test_one_device_loss_with_a_held_share_has_one_gradient_through_either_branch(monkeypatch):
+    """The one-device loss differentiates through `routed_ffn`: at 2 x 256 rows
+    a held share's layers take the compact buffer (the spy reads each layer's
+    flag as the loss runs), and loss and gradient are those of all N x k rows."""
+    cfg = TransformerConfig(**HELD, moe_aux_weight=0.01)
+    params = init_params(jax.random.key(7), cfg)
+    assert params["blocks"]["w_gate"].shape == (2, 2, 32, 48) and params["blocks"]["router"].shape == (2, 32, 32)
+    batch = {"ids": jnp.asarray(np.random.default_rng(1).integers(0, 64, (2, 257)), jnp.int32)}
+    took, inner = [], moe.routed_ffn
+
+    def spy(*args, **kw):
+        r = inner(*args, **kw)
+        jax.debug.callback(lambda c: took.append(int(c)), r.compact)
+        return r
+
+    monkeypatch.setattr(moe, "routed_ffn", spy)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(transformer.make_loss_fn(cfg))(params, batch)
+        jax.effects_barrier()
+        assert took and set(took) == {1}
+        every_row(monkeypatch)
+        del took[:]
+        want, want_grads = jax.value_and_grad(transformer.make_loss_fn(cfg))(params, batch)
+        jax.effects_barrier()
+        assert took and set(took) == {0}
+    assert float(loss) == pytest.approx(float(want), abs=1e-6)
+    for name in ("router", "w_gate", "w_up", "w_down", "wq", "wo"):
+        g, w = np.asarray(grads["blocks"][name]), np.asarray(want_grads["blocks"][name])
+        assert np.abs(w).max() > 0 and np.max(np.abs(g - w)) < 1e-5 * max(1.0, np.abs(w).max()), name
+
+
+@pytest.mark.parametrize("rows, held", [(PREFILL, None), (1, (0, 2)), (PREFILL, (0, 16))],
+                         ids=["every-expert-held", "a-suffix-steps-one-row", "half-the-experts"])
+def test_no_conditional_is_traced_where_no_buffer_would_gain(rows, held):
+    """Every expert held (OLMoE, SDAR, the one-device train step), rows so few
+    or a share so large that the buffer is over half of N x k: the program is
+    the one before the compact buffer, with no conditional."""
+    bp = init_moe_params(jax.random.key(0), E, F, X32, jnp.float32, gated=True)
+    stack = {k: bp[k][None, :X32 if held is None else held[1]] for k in EXPERT_MATRICES if k in bp}
+    fn = lambda x: routed_ffn(x, bp["router"], stack, k=4, held=held)
+    assert " cond[" not in str(jax.make_jaxpr(fn)(tokens(rows)))
+    assert int(fn(tokens(rows)).compact) == 0
+    # where it does gain there is exactly one: a prefill's rows, and a decode step's 32 slots
+    small = {k: v[:, :2] for k, v in stack.items()}
+    for n in (PREFILL, 32):
+        assert moe.compact_buffer_rows(n, 4, 2, X32) == n  # 4 x the even share of n * 4 * 2 / 32: a quarter of the rows
+        traced = str(jax.make_jaxpr(lambda x: routed_ffn(x, bp["router"], small, k=4, held=(0, 2)))(tokens(n)))
+        assert traced.count(" cond[") == 1
+
 # -- where the program calls it from ------------------------------------------
 
 SMALL = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4, d_head=8, d_ff=48,
@@ -228,19 +353,24 @@ def test_decode_step_is_told_which_slots_are_live():
     assert step((5, 9, 11, 3), (1, 0, 0, 0), init_params(jax.random.key(4), dense), dense)[1] is None
 
 
-def test_batcher_reports_rows_experts_and_assignments(monkeypatch):
-    cfg = TransformerConfig(**SMALL)
-    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=4, t_max=32,
-                                      prefill_buckets=(8, 16))
+@pytest.fixture
+def seen(monkeypatch):
+    """(span name, attributes) of every `set` on a span the batcher opens."""
     seen = []
-    real = continuous.tracing.span
 
-    class Span(real):
+    class Span(continuous.tracing.span):
         def set(self, **attrs):
             seen.append((self.name, attrs))
             super().set(**attrs)
 
     monkeypatch.setattr(continuous.tracing, "span", Span)
+    return seen
+
+
+def test_batcher_reports_rows_experts_and_assignments(seen):
+    cfg = TransformerConfig(**SMALL)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=4, t_max=32,
+                                      prefill_buckets=(8, 16))
     reqs = [cb.submit(list(range(1, n + 1)), max_new_tokens=4) for n in (5, 11)]
     cb.pump()
     assert all(r.done and len(r.out_tokens) == 4 for r in reqs)
@@ -254,3 +384,25 @@ def test_batcher_reports_rows_experts_and_assignments(monkeypatch):
     bare, _ = generate.prefill(cb.params, ids, cfg, 32)
     padded, _ = generate.prefill(cb.params, jnp.pad(ids, ((0, 0), (3, 0))), cfg, 32, pad=jnp.asarray([3]))
     assert np.max(np.abs(np.asarray(bare - padded))) < 1e-5
+
+
+def test_an_admit_of_a_held_share_reports_its_held_and_compact_layers(seen):
+    """A replica that holds a share of the experts reads, with an admit's first
+    token, how many of the prefill's expert layers there were and how many took
+    the compact buffer: both, in a bucket of 8 rows as in one of 512."""
+    cfg = TransformerConfig(**HELD)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=520,
+                                      prefill_buckets=(8, 512))
+    rng = np.random.default_rng(3)
+    reqs = [cb.submit(rng.integers(1, 64, n).tolist(), max_new_tokens=3) for n in (5, 300)]
+    cb.pump()
+    assert all(r.done and len(r.out_tokens) == 3 for r in reqs)
+    admits = [a for name, a in seen if name == "llm.admit" and "moe_held_layers" in a]
+    assert [(a["moe_held_layers"], a["moe_compact_layers"]) for a in admits] == [(2, 2), (2, 2)]
+    # a replica that holds every expert says nothing of it
+    del seen[:]
+    cfg = TransformerConfig(**SMALL)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=32, prefill_buckets=(8,))
+    cb.submit([1, 2, 3], max_new_tokens=2)
+    cb.pump()
+    assert [a for name, a in seen if name == "llm.admit"] and not [a for _, a in seen if "moe_held_layers" in a]
