@@ -91,10 +91,6 @@ class CallSite:
     kind: str                       # "request" | "notify" | "spec"
     fields: Optional[Set[str]]      # None = dynamic (**kwargs / template)
 
-    @property
-    def loc(self) -> str:
-        return f"{self.file}:{self.line}"
-
 
 @dataclasses.dataclass
 class Contract:
@@ -571,19 +567,21 @@ def extract_contract(files) -> Contract:
 
 
 def contract_to_json(contract: Contract) -> dict:
+    """The committed form.  It names handlers and callers by file and
+    qualified name, never by line: an edit that moves code but changes no
+    handler or call site leaves the file as it is."""
     surfaces: Dict[str, dict] = {}
-    callers: Dict[str, List[str]] = {}
-    for c in sorted(contract.call_sites, key=lambda c: (c.file, c.line)):
-        callers.setdefault(c.method, []).append(c.loc)
+    callers: Dict[str, Set[str]] = {}
+    for c in contract.call_sites:
+        callers.setdefault(c.method, set()).add(f"{c.file}:{c.context}")
     for h in sorted(contract.handlers, key=lambda h: (h.surface, h.method)):
         surf = surfaces.setdefault(h.surface, {"file": h.file, "methods": {}})
         surf["methods"][h.method] = {
-            "line": h.line,
             "context": h.context,
             "required": sorted(h.required),
             "optional": sorted(h.optional),
             "opaque": h.opaque,
-            "callers": callers.get(h.method, []),
+            "callers": sorted(callers.get(h.method, ())),
         }
     return {
         "version": 1,

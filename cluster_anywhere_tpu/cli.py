@@ -643,8 +643,6 @@ def cmd_events(args):
             print(json.dumps(r, indent=2, default=str))
             return
         evs = r.get("events", [])
-        if not r.get("enabled", True):
-            print("flight recorder disabled (flightrec_plane=False)")
         print(f"== ca events: {len(evs)} shown / {r.get('total', 0)} in ring ==")
         for e in evs:
             print(_format_flight_event(e))
@@ -668,8 +666,6 @@ def cmd_incident(args):
             print(json.dumps(r, indent=2, default=str))
             return
         evs = r.get("events", [])
-        if not r.get("enabled", True):
-            print("flight recorder disabled (flightrec_plane=False)")
         if not evs:
             print(f"no flight-recorder events in the last {args.window:g}s")
             return
@@ -737,7 +733,7 @@ def cmd_metrics(args):
         if not addr:
             print(
                 f"ca metrics: no scrape endpoint known for node {node_id!r} "
-                f"(node down, or metrics_plane disabled)",
+                f"(node down)",
                 file=sys.stderr,
             )
             sys.exit(1)
@@ -1072,24 +1068,10 @@ def cmd_microbenchmark(args):
         head_saturation(quick=getattr(args, "quick", False))
         return
     if getattr(args, "lease_plane", False):
-        # owns its own multi-node clusters (local-grant vs head-grant A/B)
+        # owns its own multi-node cluster
         from .microbenchmark import run_lease_plane
 
         run_lease_plane(quick=getattr(args, "quick", False))
-        return
-    if getattr(args, "owner_plane", False):
-        # owns its own clusters (owner-resident vs centralized object A/B
-        # plus the GC-with-the-head-down proof)
-        from .microbenchmark import run_owner_plane
-
-        run_owner_plane(quick=getattr(args, "quick", False))
-        return
-    if getattr(args, "metrics_plane", False):
-        # owns its own clusters (node-scrape vs head-RPC metrics A/B plus
-        # the scrape-with-the-head-down proof)
-        from .microbenchmark import run_metrics_plane
-
-        run_metrics_plane(quick=getattr(args, "quick", False))
         return
     if getattr(args, "transfer", False):
         # owns its own clusters (serial vs windowed pulls on a latency-
@@ -1106,8 +1088,8 @@ def cmd_microbenchmark(args):
         run_serve_plane(quick=getattr(args, "quick", False))
         return
     if getattr(args, "dag", False):
-        # owns its own clusters (compiled-DAG vs RPC actor-call latency and
-        # throughput, 3-actor chain A/B, serve TTFT on/off A/B)
+        # owns its own cluster (compiled-DAG vs RPC actor-call latency and
+        # throughput, 3-actor chain A/B)
         from .microbenchmark import run_dag_plane
 
         run_dag_plane(quick=getattr(args, "quick", False))
@@ -1127,8 +1109,8 @@ def cmd_microbenchmark(args):
         run_partition_chaos(quick=getattr(args, "quick", False))
         return
     if getattr(args, "obsplane", False):
-        # owns its own clusters (flight-recorder cost model: armed record
-        # rate, disabled-path gate, journal memory, tasks/s on/off A/B)
+        # process-local (flight-recorder cost model: armed record rate,
+        # unarmed gate, journal memory)
         from .microbenchmark import run_obsplane
 
         run_obsplane(quick=getattr(args, "quick", False))
@@ -1453,22 +1435,12 @@ def main(argv=None):
     )
     sp.add_argument(
         "--lease-plane", dest="lease_plane", action="store_true",
-        help="node-local vs head lease granting tasks/s + head-RPC proof",
-    )
-    sp.add_argument(
-        "--owner-plane", dest="owner_plane", action="store_true",
-        help="owner-resident vs centralized object settlement A/B + "
-        "head-down GC proof",
-    )
-    sp.add_argument(
-        "--metrics-plane", dest="metrics_plane", action="store_true",
-        help="node-scrape vs head-RPC metrics A/B: head metric traffic "
-        "per scrape + head-down scrape proof",
+        help="node-local lease granting tasks/s + head-RPC proof",
     )
     sp.add_argument(
         "--transfer", action="store_true",
         help="bulk-transfer A/B: serial vs windowed pulls (latency-injected "
-        "link), 1 vs 2 sources, f32 vs int8/bf16 quantized ring",
+        "link), 2-source pulls, f32 vs int8/bf16 quantized ring",
     )
     sp.add_argument(
         "--serve", dest="serve_plane", action="store_true",
@@ -1478,7 +1450,7 @@ def main(argv=None):
     sp.add_argument(
         "--dag", action="store_true",
         help="compiled-DAG plane A/B: compiled tick vs RPC actor-call "
-        "latency/throughput, 3-actor chain, serve TTFT on/off",
+        "latency/throughput, 3-actor chain",
     )
     sp.add_argument(
         "--train-elastic", dest="train_elastic", action="store_true",
@@ -1494,8 +1466,8 @@ def main(argv=None):
     )
     sp.add_argument(
         "--obsplane", action="store_true",
-        help="flight-recorder cost model: armed record events/s, disabled "
-        "gate rate, journal memory at cap, tasks/s with the plane on/off",
+        help="flight-recorder cost model: armed record events/s, unarmed "
+        "gate rate, journal memory at cap",
     )
     sp.add_argument(
         "--ha", action="store_true",

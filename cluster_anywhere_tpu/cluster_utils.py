@@ -155,9 +155,9 @@ class Cluster:
 
     def promote_standby(self, rank: int = 0, timeout: float = 10) -> dict:
         """Explicitly promote the rank's standby (the `ca head promote`
-        path); returns its ha_status afterwards.  With ha_auto_promote on,
-        standbys promote themselves after the grace window and this is only
-        needed for deterministic tests / manual failover."""
+        path); returns its ha_status afterwards.  Standbys promote
+        themselves after the grace window, so this is only needed for
+        deterministic tests / manual failover."""
         from .core.protocol import BlockingClient
 
         c = BlockingClient(self.standby_addr(rank))
@@ -288,9 +288,7 @@ class Cluster:
     def connect(self) -> dict:
         from .core import api
 
-        # the driver must run the SAME plane configuration as the cluster it
-        # joins (e.g. owner_plane off in an A/B) — a default-config driver
-        # would settle its objects owner-resident against a centralized head
+        # the driver runs the same configuration as the cluster it joins
         info = api.init(address=self.session_dir, config=self.config)
         self._connected = True
         return info

@@ -46,7 +46,6 @@ class CAConfig:
     # the head delegates bounded per-pool lease capacity ("lease blocks") to
     # node agents; submitters dial agents directly for the hot unit-shape
     # lease class, keeping per-task traffic off the head
-    lease_delegation: bool = True
     # max delegated workers per (node, pool); 0 = auto (the node's CPU count)
     lease_block_max: int = 0
     # submitter-side lease-directory cache TTL (one lease_dir RPC per pool
@@ -57,8 +56,7 @@ class CAConfig:
     # owner-resident object lifetime: borrowers settle inc/dec with the
     # OWNER process's ledger over direct connections; the head keeps only
     # the registry (obj_created/obj_release) and adopts orphaned ledgers on
-    # owner death.  Off = classic centralized holders at the head.
-    owner_plane: bool = True
+    # owner death.
     # owner_sync digest cadence (ledger deltas ride the housekeeping loop)
     owner_sync_period_s: float = 1.0
     # how long the head (and owner ledgers) hold a refcount inc that arrived
@@ -79,7 +77,6 @@ class CAConfig:
     # when the directory reports several live copies, split the byte range
     # across them and pull concurrently (failed sources re-assign their
     # remaining chunks to survivors instead of failing the transfer)
-    transfer_multi_source: bool = True
     # host collective ring default payload encoding ("" = f32 wire bytes,
     # untouched default; "int8"/"bf16" = EQuARX-style block-quantized ring).
     # Per-call allreduce(..., quantize=...) overrides the group default.
@@ -92,8 +89,7 @@ class CAConfig:
     testing_transfer_delay_s: float = 0.0
     # delta-synced node state (ray_syncer analogue): agents send versioned
     # component deltas (node_sync) instead of full per-tick heartbeats; an
-    # idle node's tick is a bare keepalive.  Off = legacy full node_heartbeat.
-    delta_sync: bool = True
+    # idle node's tick is a bare keepalive.
 
     # --- health / failure detection ---
     health_check_period_s: float = 2.0
@@ -121,10 +117,6 @@ class CAConfig:
     io_timeout_s: float = 60.0
 
     # --- HA plane (warm-standby head replication / epoch-fenced failover) ---
-    # master switch for the head-replication machinery.  With no standby
-    # subscribed the active head's only HA cost is a per-snapshot-tick flag
-    # check, so this stays on by default.
-    ha_plane: bool = True
     # table-delta replication tick on the active head (rides the persist
     # loop); also the standby-liveness heartbeat period on the stream
     ha_repl_interval_s: float = 0.25
@@ -140,13 +132,6 @@ class CAConfig:
     # closed AND redials failing) before self-promotion; each standby rank
     # waits one extra grace period per rank so replicas don't race
     ha_failover_grace_s: float = 2.0
-    # standby self-promotes after the grace window (off = promotion only via
-    # `ca head promote` / head_promote RPC)
-    ha_auto_promote: bool = True
-    # restarting head probes the current head.addr occupant before claiming
-    # authority: a live head with a >= epoch means THIS process is the stale
-    # one — demote at boot instead of split-braining the registry
-    ha_boot_probe: bool = True
 
     # --- tasks / actors ---
     default_max_retries: int = 3
@@ -163,8 +148,7 @@ class CAConfig:
     dag_execute_timeout_s: float = 300.0
     # serving plane: stream ContinuousLLMServer tokens to the proxy over a
     # pre-opened shm channel (per-token cost = one channel write) instead of
-    # streaming-RPC frames.  Off = every token rides an RPC frame.
-    serve_compiled_dag: bool = True
+    # streaming-RPC frames.
     # slots in the per-request token channel (tokens in flight before the
     # replica-side writer blocks on the proxy reader)
     serve_dag_stream_buffers: int = 8
@@ -173,7 +157,6 @@ class CAConfig:
     session_dir_root: str = "/tmp/ca_tpu"
     log_to_driver: bool = True
     # --- log plane (util/logplane.py; raylet log-monitor analogue) ---
-    log_capture: bool = True  # structured stdout/stderr capture in spawned procs
     log_rotate_bytes: int = 1024 * 1024  # per-process JSONL cap before .1 rollover
     log_ship_interval_s: float = 0.25  # agent/head tail-and-ship period
     log_ship_batch: int = 500  # max records per shipped log_batch
@@ -182,9 +165,8 @@ class CAConfig:
     # --- metrics plane (util/timeseries.py, node-agent /metrics scrape) ---
     # head-free scrape topology: workers ship metric deltas to their node's
     # agent, which serves `GET /metrics` over HTTP (Prometheus exposition)
-    # and piggybacks the deltas onto node_sync ticks head-ward.  Off =
-    # legacy per-worker metrics_report RPCs straight to the head.
-    metrics_plane: bool = True
+    # and piggybacks the deltas onto node_sync ticks head-ward.  A process
+    # with no agent (CA_AGENT_ADDR unset) reports straight to the head.
     # head-side time-series retention: tier-0 sampling cadence (seconds) and
     # ring length; tier 1 is timeseries_tier1_mult x coarser, same length.
     # 0 disables retention entirely.
@@ -199,9 +181,7 @@ class CAConfig:
     # mints/refusals, drain FSM transitions, netchaos firings, DAG
     # recompiles/timeouts, serve shed/drain, train barrier phases, transfer
     # failover, owner adoption), shipped head-ward on the metrics-delta
-    # path.  Off = util.flightrec.REC stays None and every record site is a
-    # single `is None` branch.
-    flightrec_plane: bool = True
+    # path.
     # per-process ring capacity (drop-oldest beyond this)
     flightrec_ring_len: int = 4096
     # head-side merged journal capacity

@@ -412,14 +412,12 @@ class Head:
         self.flightrec: deque = deque(
             maxlen=int(getattr(config, "flightrec_head_len", 50_000))
         )
-        self._flightrec_on = bool(getattr(config, "flightrec_plane", True))
-        if self._flightrec_on:
-            from ..util import flightrec as _flightrec
+        from ..util import flightrec as _flightrec
 
-            _flightrec.init(
-                cap=int(getattr(config, "flightrec_ring_len", 4096)),
-                node_id=LOCAL_NODE, proc="head",
-            )
+        _flightrec.init(
+            cap=int(getattr(config, "flightrec_ring_len", 4096)),
+            node_id=LOCAL_NODE, proc="head",
+        )
         # metrics plane: time-series retention (ring buffers, two downsample
         # tiers) sampled off this table + head stats by the monitor loop, so
         # dashboards/`ca top` get rates and history without Prometheus
@@ -452,18 +450,17 @@ class Head:
         self._log_subs: Dict[str, Any] = {}  # client_id -> writer
         self.stats["log_lines_shipped"] = 0
         self.stats["log_lines_dropped"] = 0
-        if config.log_capture:
-            # the head captures its own output the same way workers do
-            # (nodes/n0/head.jsonl rides the local tail loop)
-            try:
-                from ..util.logplane import install_capture
+        # the head captures its own output the same way workers do
+        # (nodes/n0/head.jsonl rides the local tail loop)
+        try:
+            from ..util.logplane import install_capture
 
-                install_capture(
-                    session_dir, LOCAL_NODE, "head",
-                    max_bytes=config.log_rotate_bytes,
-                )
-            except Exception:
-                pass
+            install_capture(
+                session_dir, LOCAL_NODE, "head",
+                max_bytes=config.log_rotate_bytes,
+            )
+        except Exception:
+            pass
         # structured lifecycle event log (util/event.h analogue): JSONL file
         self._event_log = open(os.path.join(session_dir, "events.jsonl"), "a", buffering=1)
         # transit tokens acked by the receiver BEFORE the sender's pin landed
@@ -535,10 +532,7 @@ class Head:
                     port = int(prev.rpartition(":")[2])
                 except ValueError:
                     port = 0
-            if (
-                self._restored and cur and prev and cur != prev
-                and bool(getattr(config, "ha_boot_probe", True))
-            ):
+            if self._restored and cur and prev and cur != prev:
                 self._ha_sock_deferred = True
         else:
             self.sock_path = os.path.join(
@@ -851,18 +845,17 @@ class Head:
         import json as _json
 
         ts = time.time()
-        if self._flightrec_on:
-            # mirror into the merged journal: head decisions and shipped
-            # worker slices interleave in one queryable ring
-            plane = "head"
-            for prefix, p in self._FLIGHTREC_PLANES:
-                if kind.startswith(prefix):
-                    plane = p
-                    break
-            self.flightrec.append(
-                {"ts": ts, "plane": plane, "event": kind, "node": LOCAL_NODE,
-                 "proc": "head", **fields}
-            )
+        # mirror into the merged journal: head decisions and shipped
+        # worker slices interleave in one queryable ring
+        plane = "head"
+        for prefix, p in self._FLIGHTREC_PLANES:
+            if kind.startswith(prefix):
+                plane = p
+                break
+        self.flightrec.append(
+            {"ts": ts, "plane": plane, "event": kind, "node": LOCAL_NODE,
+             "proc": "head", **fields}
+        )
         try:
             self._event_log.write(
                 _json.dumps({"ts": ts, "event": kind, **fields}) + "\n"
@@ -874,7 +867,7 @@ class Head:
         """Merge a shipped journal slice (metrics_report / node_sync
         piggyback) into the cluster ring.  Slices from different nodes
         interleave by arrival; queries sort by timestamp."""
-        if not evs or not self._flightrec_on:
+        if not evs:
             return
         for ev in evs:
             if isinstance(ev, dict):
@@ -1155,7 +1148,6 @@ class Head:
         ]
         grace = float(getattr(self.config, "ha_failover_grace_s", 2.0))
         grace *= 1.0 + self.ha_rank  # rank stagger
-        auto = bool(getattr(self.config, "ha_auto_promote", True))
         from .worker import _redial_backoff
 
         down_since: Optional[float] = None
@@ -1224,7 +1216,7 @@ class Head:
             if self._ha_active_conn is not None:
                 continue
             now = loop.time()
-            if auto and down_since is not None and now - down_since > grace:
+            if down_since is not None and now - down_since > grace:
                 await self._ha_promote(reason="active head unreachable")
                 return
             attempt += 1
@@ -1380,8 +1372,6 @@ class Head:
         live head before claiming authority: if that head answers with an
         epoch >= ours, THIS process is the stale one — demote at boot
         instead of split-braining the registry.  True = demoted."""
-        if not bool(getattr(self.config, "ha_boot_probe", True)):
-            return False
         try:
             other = open(
                 os.path.join(self.session_dir, "head.addr")
@@ -1772,8 +1762,6 @@ class Head:
         raylet capacity split).  Runs only when no central work is queued:
         pending leases/PGs get first claim on fresh idle workers, which also
         keeps delegation and revocation from ping-ponging."""
-        if not self.config.lease_delegation:
-            return
         if self._needs_reclaim():
             # the queued work needs CENTRAL capacity; ttl-marked escalation
             # probes don't block delegation — their submitters poll the
@@ -2815,7 +2803,7 @@ class Head:
     # --------------------------------------------------------------- handler
     _READONLY_METHODS = frozenset(
         {
-            "heartbeat", "node_heartbeat", "node_sync", "kv_get", "kv_keys",
+            "heartbeat", "node_sync", "kv_get", "kv_keys",
             "get_function",
             "obj_locate", "pull_chunk", "nodes", "cluster_resources", "stats",
             "client_addr", "lease_dir",
@@ -3195,26 +3183,6 @@ class Head:
             **extra,
         )
         self._service_queue()
-
-    async def _h_node_heartbeat(self, state, msg, reply, reply_err):
-        node = self.nodes.get(msg.get("node_id", state.get("node_id")))
-        if node is not None:
-            node.last_heartbeat = time.monotonic()
-            if "mem_pressured" in msg:
-                node.mem_pressured = bool(msg["mem_pressured"])
-            if "load" in msg:
-                node.load = msg["load"]
-            if "lease_stats" in msg:
-                # agent-side block occupancy (delegated vs used) for
-                # `ca status` / /api/nodes / lease_dir freshness
-                node.lease_used = msg["lease_stats"] or {}
-            if "metrics" in msg:
-                # metrics-plane piggyback: the node's queued worker deltas
-                from ..util.metrics import merge_metric_records
-
-                merge_metric_records(self.metrics, msg["metrics"])
-            if "flightrec" in msg:
-                self._ingest_flightrec(msg["flightrec"])
 
     async def _h_node_sync(self, state, msg, reply, reply_err):
         """Delta-synced node state (the ray_syncer analogue, head-ward):
@@ -4504,7 +4472,7 @@ class Head:
             }
             if blocks:
                 nodes.append({"node_id": n.node_id, "addr": n.addr, "pools": blocks})
-        reply(nodes=nodes, delegation=self.config.lease_delegation)
+        reply(nodes=nodes)
 
     async def _h_nodes(self, state, msg, reply, reply_err):
         from .nodeagent import node_load_sample
@@ -4725,10 +4693,7 @@ class Head:
         limit = int(limit)
         if limit and len(events) > limit:
             events = events[-limit:]
-        return {
-            "events": events, "total": len(self.flightrec),
-            "enabled": self._flightrec_on,
-        }
+        return {"events": events, "total": len(self.flightrec)}
 
     async def _h_flightrec(self, state, msg, reply, reply_err):
         """Flight-recorder query: the cluster-merged decision journal,
@@ -5083,7 +5048,7 @@ class Head:
                     node.last_heartbeat += deaf_s
                 self._log_event("head_deaf", seconds=round(deaf_s, 3))
                 continue
-            if self._flightrec_on and _flightrec.REC is not None:
+            if _flightrec.REC is not None:
                 # head-process recorder (netchaos and other shared code
                 # running here) drains straight into the merged ring — the
                 # head is its own aggregator, no piggyback needed

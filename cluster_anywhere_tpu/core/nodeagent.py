@@ -204,16 +204,15 @@ class NodeAgent:
         self.serve_addr_spec = os.environ.get("CA_AGENT_SERVE", "tcp:127.0.0.1:0")
         self.node_dir = os.path.join(self.session_dir, "nodes", self.node_id)
         os.makedirs(self.node_dir, exist_ok=True)
-        if self.config.log_capture:
-            # the agent captures its own output the same way its workers do:
-            # agent.jsonl rides the same tail-and-ship loop, so agent prints
-            # reach subscribed drivers prefixed "(agent ... node=...)"
-            from ..util.logplane import install_capture
+        # the agent captures its own output the same way its workers do:
+        # agent.jsonl rides the same tail-and-ship loop, so agent prints
+        # reach subscribed drivers prefixed "(agent ... node=...)"
+        from ..util.logplane import install_capture
 
-            install_capture(
-                self.session_dir, self.node_id, "agent",
-                max_bytes=self.config.log_rotate_bytes,
-            )
+        install_capture(
+            self.session_dir, self.node_id, "agent",
+            max_bytes=self.config.log_rotate_bytes,
+        )
         self.shm_ns_dir = os.path.join("/dev/shm", self.session_name, self.node_id)
         os.makedirs(self.shm_ns_dir, exist_ok=True)
         self.server = Server(
@@ -278,13 +277,12 @@ class NodeAgent:
         # resets, drain handling) and forwards workers' journal slices
         # head-ward on the same node_sync piggyback as metric deltas
         self._flightrec_pending: list = []
-        if getattr(self.config, "flightrec_plane", True):
-            from ..util import flightrec
+        from ..util import flightrec
 
-            flightrec.init(
-                cap=getattr(self.config, "flightrec_ring_len", 4096),
-                node_id=self.node_id, proc="agent",
-            )
+        flightrec.init(
+            cap=getattr(self.config, "flightrec_ring_len", 4096),
+            node_id=self.node_id, proc="agent",
+        )
 
     # --------------------------------------------------------------- workers
     def _spawn_worker(self, wid: str, purpose: str, pool: str) -> None:
@@ -690,33 +688,7 @@ class NodeAgent:
         while not self._shutdown.is_set():
             await asyncio.sleep(min(period, 1.0))
             try:
-                if getattr(self.config, "delta_sync", True):
-                    self._send_node_sync()
-                else:
-                    hb = {"node_id": self.node_id, "load": node_load_sample()}
-                    if self.mem_monitor is not None:
-                        hb["mem_pressured"] = self.mem_monitor.is_pressured()
-                    # delegated/used block occupancy rides the heartbeat (the
-                    # same dissemination path as load): the head's `ca
-                    # status`, /api/nodes, and revocation sizing read it
-                    hb["lease_stats"] = self.granter.stats()
-                    pending = (
-                        self._take_pending_metrics()
-                        if self._metrics_pending else []
-                    )
-                    if pending:
-                        hb["metrics"] = pending
-                    frp = self._take_pending_flightrec()
-                    if frp:
-                        hb["flightrec"] = frp
-                    try:
-                        self.head.notify("node_heartbeat", **self._auth(hb))
-                    except Exception:
-                        if pending:
-                            self._restage_pending_metrics(pending)
-                        if frp:
-                            self._restage_pending_flightrec(frp)
-                        raise
+                self._send_node_sync()
             except Exception:
                 pass
             # reap exited worker processes and report them (the head cannot
@@ -859,9 +831,8 @@ class NodeAgent:
     async def _amain(self):
         await self.server.start()
         self.serve_addr = self.server.bound_addrs[0]
-        if getattr(self.config, "metrics_plane", True):
-            # scrape endpoint first: metrics_addr travels in the register
-            await self._start_metrics_http()
+        # scrape endpoint first: metrics_addr travels in the register
+        await self._start_metrics_http()
         from ..util.aio import dial  # lazy: util/__init__ reaches into core
 
         netchaos.register_addr(self.head_addr, "n0")

@@ -27,7 +27,7 @@ Lease plane: the node-local lease granting subsystem rides this same frame
 protocol and its batch envelopes.  Head -> agent: `lease_block` (delegate
 workers into a block), `lease_block_revoke` (reclaim unleased slots).
 Agent -> head: `lease_block_return` (returned slots), plus per-pool
-`lease_stats` piggybacked on `node_heartbeat`.  Submitter -> agent:
+`lease_stats` piggybacked on `node_sync`.  Submitter -> agent:
 `lease_grant` / `lease_release` — the hot lease class, which therefore
 never crosses the head's loop in steady state.  Submitter -> head:
 `request_lease` may carry `ttl` (escalation probe; the head replies

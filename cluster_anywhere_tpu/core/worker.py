@@ -364,7 +364,6 @@ class LeasePool:
             and self.strategy is None
             and self.shape == {"CPU": 1.0}
             and not self.worker.client_mode
-            and self.worker.config.lease_delegation
         )
 
     def _adopt_lease(self, lease: "_Lease"):
@@ -745,9 +744,8 @@ class Worker:
         # drivers have no ledger — their puts are hosted (and their holders
         # kept) by the head — but still ROUTE updates for borrowed refs to
         # the owning worker over TCP.
-        self._owner_plane = bool(getattr(self.config, "owner_plane", True))
         self.owner_ledger: Optional[OwnerLedger] = None
-        if self._owner_plane and not client_mode:
+        if not client_mode:
             self.owner_ledger = OwnerLedger(
                 self.client_id,
                 on_clear=self._ledger_clear,
@@ -876,16 +874,15 @@ class Worker:
         netchaos.maybe_install_from_config(self.config, self.node_id)
         # flight recorder: journal this process's plane decisions; slices
         # ship on the metrics-delta piggyback (util/metrics.flush_once)
-        if getattr(self.config, "flightrec_plane", True):
-            from ..util import flightrec, metrics as _metrics
+        from ..util import flightrec, metrics as _metrics
 
-            flightrec.init(
-                cap=getattr(self.config, "flightrec_ring_len", 4096),
-                node_id=self.node_id, proc=self.client_id,
-            )
-            # the journal ships on the metrics flush: arm the flusher now —
-            # a process that never mints a Metric must still ship its events
-            _metrics._ensure_flusher()
+        flightrec.init(
+            cap=getattr(self.config, "flightrec_ring_len", 4096),
+            node_id=self.node_id, proc=self.client_id,
+        )
+        # the journal ships on the metrics flush: arm the flusher now —
+        # a process that never mints a Metric must still ship its events
+        _metrics._ensure_flusher()
         # log plane: lazily-built printer for log_batch pushes (drivers
         # subscribed via log_sub; see util/logplane.DriverLogPrinter)
         self._log_printer = None
@@ -1323,9 +1320,14 @@ class Worker:
                         self._redial_next = now + _redial_backoff(
                             self._redial_attempts
                         )
+            # while a drain's window is open the survivors are short of
+            # capacity: an idle lease goes back at this tick, so that what the
+            # head evacuates (an actor) finds room when a task ends and not
+            # an idle timeout later
+            idle_s = 0.0 if self._draining_nodes else self.config.lease_idle_timeout_s
             to_return = []
             for pool in self._lease_pools.values():
-                to_return.extend(pool.reap_idle(now, self.config.lease_idle_timeout_s))
+                to_return.extend(pool.reap_idle(now, idle_s))
             self.return_leases(to_return)
             if self._draining_nodes:
                 # expired preemption windows (the node is gone or the drain
@@ -1359,8 +1361,7 @@ class Worker:
             if self.owner_ledger is not None:
                 self._owner_plane_tick(now)
             if (
-                self._owner_plane
-                and len(self._borrowed_owners) > 4096
+                len(self._borrowed_owners) > 4096
                 and now - self._last_borrow_prune > 10.0
             ):
                 # bound the borrowed-owner map: drop routing entries for
@@ -1498,7 +1499,7 @@ class Worker:
             return entries or []
         try:
             r = await self.head.call("lease_dir", timeout=5)
-            entries = (r.get("nodes") or []) if r.get("delegation", True) else []
+            entries = r.get("nodes") or []
         except asyncio.CancelledError:
             raise
         except Exception:
@@ -1512,7 +1513,7 @@ class Worker:
         most-free-first; a denial (exhausted block) or unreachable agent
         falls through to the next, then to (None, True) — the caller falls
         back to the head.  (None, False) means NO delegated blocks exist
-        (single-node cluster, delegation off): the caller must behave
+        (single-node cluster): the caller must behave
         exactly like the classic central path — no probe ttl, no growth
         capping — or head-only topologies lose demand signal and
         concurrency."""
@@ -1655,7 +1656,7 @@ class Worker:
         AUTHORITY (ownership plane): oids this process owns apply directly
         to its own OwnerLedger (no IO at all); borrowed oids ride a direct
         `owner_refs` notify to the owner process's ledger; only oids with no
-        known live owner — plane off, owner unknown, owner unreachable/dead
+        known live owner — owner unknown, owner unreachable/dead
         — fall back to the head's centralized obj_refs path, which is also
         the failover authority after the head adopts a dead owner's ledger.
 
@@ -1671,9 +1672,6 @@ class Worker:
         if not self._ref_pending:
             return
         pending, self._ref_pending = self._ref_pending, {}
-        if not self._owner_plane:
-            self._send_head_refs(list(pending.items()))
-            return
         # partition each (as_id, ttl) window's oids by authority
         local: List[tuple] = []   # (as_id, ttl, inc, dec) for my own ledger
         remote: Dict[str, List[tuple]] = {}  # owner cid -> windows
@@ -1715,7 +1713,7 @@ class Worker:
     def _ref_dest(self, oid: bytes) -> Optional[str]:
         """Which authority settles this oid's holder updates: "" = this
         process's own ledger, a client id = that owner's ledger, None = the
-        head (plane off / owner unknown / resurrection after settle)."""
+        head (owner unknown / resurrection after settle)."""
         led = self.owner_ledger
         if led is not None and led.tracks(oid):
             return ""
@@ -1729,7 +1727,7 @@ class Worker:
     def note_borrowed_owner(self, oid_b: bytes, owner: str) -> None:
         """An ObjectRef handle for another process's object materialized
         here: remember who settles its counts (ObjectRef.__init__)."""
-        if self._owner_plane and owner != self.client_id:
+        if owner != self.client_id:
             self._borrowed_owners[oid_b] = owner
 
     def _send_head_refs(self, items) -> None:
@@ -1909,13 +1907,8 @@ class Worker:
         """Containment edges for a container THIS process owns: each nested
         ref gains a "cnt:<my-cid>:<container>" holder at its own authority,
         and the ledger remembers the edge list so settling the container
-        releases them (head-resident obj_contains when the plane is off)."""
+        releases them."""
         led = self.owner_ledger
-        if not self._owner_plane:
-            self._notify_threadsafe(
-                "obj_contains", oid=container_b, refs=list(nested)
-            )
-            return
         if led is None or not led.tracks(container_b):
             # ledgerless owner (client mode): the HEAD is this container's
             # lifetime authority.  The edges still register at each inner
@@ -1951,15 +1944,12 @@ class Worker:
 
     def result_contains_pairs(
         self, container_b: bytes, nested: List[bytes], owner: str
-    ) -> Optional[list]:
+    ) -> list:
         """Worker-side half of owner-resident containment for a task RETURN
         (the container's owner is the submitter): register the edges at each
         nested ref's authority under the SUBMITTER's edge id and hand back
         the (oid, owner) pairs to ship with the result, so the submitter's
-        ledger can release them when the container settles.  Returns None on
-        the centralized path (caller falls back to obj_contains)."""
-        if not self._owner_plane:
-            return None
+        ledger can release them when the container settles."""
         pairs = [
             [ioid, self._borrowed_owners.get(ioid) or self.client_id]
             for ioid in nested
@@ -2575,7 +2565,7 @@ class Worker:
             if nested:
                 # borrowed refs inside the stored value live as long as the
                 # containing object (containment edges at each inner object's
-                # authority; head-resident when the plane is off)
+                # authority)
                 self._register_contains(oid.binary(), nested)
 
     def _client_upload(self, oid: ObjectID, data: bytes, raws: List[Any]) -> Tuple[str, int]:
@@ -3034,8 +3024,6 @@ class Worker:
         owner_pin RPC otherwise.  None = no authoritative answer (owner
         unknown/unreachable, entry gone) — the caller falls back to the
         head, which arbitrates for adopted/centralized objects."""
-        if not self._owner_plane:
-            return None
         pin_id = f"{self.client_id}#v"
         led = self.owner_ledger
         if led is not None and led.tracks(oid_b):
@@ -3180,8 +3168,7 @@ class Worker:
     def _pull_sources(self, reply: dict) -> List[dict]:
         """Dialable holders for a located object: the directory's `sources`
         list (primary first, then secondary copies), de-duplicated, with a
-        legacy single-source fallback for mixed-version heads.  With
-        transfer_multi_source off only the primary is used."""
+        legacy single-source fallback for mixed-version heads."""
         srcs: List[dict] = []
         seen = set()
         for s in reply.get("sources") or ():
@@ -3195,8 +3182,6 @@ class Worker:
                 name = "spill:" + reply["spill_path"]
             if name and reply.get("pull_addr"):
                 srcs.append({"addr": reply["pull_addr"], "shm_name": name})
-        if not getattr(self.config, "transfer_multi_source", True):
-            srcs = srcs[:1]
         return srcs
 
     async def _pull_object(self, oid_b: bytes):
@@ -3559,9 +3544,9 @@ class Worker:
         OWNER arbitrates when it is this process (ownership plane: the
         free-now-vs-defer decision is one ledger transition, the head just
         learns `obj_spilled` asynchronously for its snapshot); the head
-        arbitrates for slices backing other owners' objects and on the
-        centralized path.  Either way a slice under zero-copy pins is
-        relocated but its memory reclaim is deferred to the last pin drop.
+        arbitrates for slices backing other owners' objects.  Either way a
+        slice under zero-copy pins is relocated but its memory reclaim is
+        deferred to the last pin drop.
         Serialized: concurrent inline + background passes would re-spill the
         same slices."""
         if (self.head is None or self.head.closed) and self.owner_ledger is None:
@@ -3743,8 +3728,6 @@ class Worker:
         head).  The receiver seeds its routing from this BEFORE unpacking,
         so an ack for a payload that never unpacks still reaches the ledger
         holding the pin instead of tombstoning the token at the head."""
-        if not self._owner_plane:
-            return ["" for _ in nested]
         out = []
         for oid in nested:
             d = self._ref_dest(oid)
@@ -3757,7 +3740,7 @@ class Worker:
         routes to the authority the sender actually pinned at, even when
         the payload fails to unpack and no ObjectRef ever rehydrates."""
         owners = env.get("rown")
-        if not owners or not self._owner_plane:
+        if not owners:
             return
         for oid, owner in zip(env.get("roids") or (), owners):
             if owner and owner != self.client_id:
@@ -3774,9 +3757,6 @@ class Worker:
         registered there by the sender's transit_pin): our own ledger, the
         owner's ledger over a direct connection, or the head fallback."""
         def _send():
-            if not self._owner_plane:
-                self._transit_done_head(token, roids, register)
-                return
             groups: Dict[Optional[str], List[bytes]] = {}
             for oid in roids:
                 groups.setdefault(self._ref_dest(oid), []).append(oid)

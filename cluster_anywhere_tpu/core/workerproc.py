@@ -71,15 +71,14 @@ class WorkerProcess:
         self.config = CAConfig.from_json(os.environ["CA_CONFIG_JSON"])
         set_config(self.config)
         self.node_id = os.environ.get("CA_NODE_ID", "n0")
-        if self.config.log_capture:
-            # log plane capture: stdout/stderr pass through to the raw .log
-            # fd AND stamp each line (task/actor identity from the ambient
-            # execution context) into nodes/<node_id>/<wid>.jsonl, which the
-            # node's agent (or the head, on n0) tails and ships to drivers
-            logplane.install_capture(
-                self.session_dir, self.node_id, self.worker_id,
-                max_bytes=self.config.log_rotate_bytes,
-            )
+        # log plane capture: stdout/stderr pass through to the raw .log
+        # fd AND stamp each line (task/actor identity from the ambient
+        # execution context) into nodes/<node_id>/<wid>.jsonl, which the
+        # node's agent (or the head, on n0) tails and ships to drivers
+        logplane.install_capture(
+            self.session_dir, self.node_id, self.worker_id,
+            max_bytes=self.config.log_rotate_bytes,
+        )
         self.loop = asyncio.new_event_loop()
         if hasattr(asyncio, "eager_task_factory"):
             self.loop.set_task_factory(asyncio.eager_task_factory)
@@ -213,13 +212,9 @@ class WorkerProcess:
             # register at each nested ref's lifetime authority under the
             # SUBMITTER's edge id, and the pairs travel with the result so
             # the submitter's ledger releases them when the container dies
-            pairs = self.worker.result_contains_pairs(oid_bytes, nested, owner)
-            if pairs is None:
-                self.worker._notify_threadsafe(
-                    "obj_contains", oid=oid_bytes, refs=nested
-                )
-            else:
-                out["contains"] = pairs
+            out["contains"] = self.worker.result_contains_pairs(
+                oid_bytes, nested, owner
+            )
         return out
 
     def _package_results(

@@ -244,9 +244,8 @@ async function refreshSparks() {
 }
 async function refreshFlight() {
   const r = await (await fetch("/api/flightrec?limit=25")).json();
-  document.getElementById("frstats").textContent = r.enabled
-    ? " " + (r.total||0) + " events retained"
-    : " (disabled: flightrec_plane=false)";
+  document.getElementById("frstats").textContent =
+    " " + (r.total||0) + " events retained";
   const evs = (r.events||[]).slice().reverse();
   document.getElementById("flightrec").innerHTML =
     row(["time", "node/proc", "event", "detail", "trace"], "th") +

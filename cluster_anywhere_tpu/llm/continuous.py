@@ -221,8 +221,8 @@ def _decode_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     (models/generate.py), so the step writes one row a slot and layer of
     [L,S,Tmax,KV,D] x2 in place and copies nothing of that size:
     tests/test_chip_compile.py holds the chip's program to it
-    (`test_decode_step_writes_the_cache_in_place`), tests/test_llm.py the
-    rows it may change."""
+    (`test_decode_step_writes_the_cache_in_place`), tests/test_llm_programs.py
+    the rows it may change."""
     host_tokens, pos, pads, top_ks, fresh, live = ints
     temps, top_ps = floats
     tokens = jnp.where(fresh != 0, host_tokens, prev)

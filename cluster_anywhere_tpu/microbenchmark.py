@@ -448,14 +448,12 @@ def run_collective_bw(quick: bool = False) -> List[Tuple[str, float, str]]:
 
 
 def run_lease_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
-    """`ca microbenchmark --lease-plane`: A/B the lease plane.  A task flood
-    against a multi-node cluster with node-local granting ON (agents grant
-    out of head-delegated lease blocks) vs OFF (every lease crosses the
-    head's loop), with the head's request_lease RPC delta printed as the
+    """`ca microbenchmark --lease-plane`: the lease plane.  A task flood
+    against a multi-node cluster whose agents grant out of head-delegated
+    lease blocks, with the head's request_lease RPC delta printed as the
     structural proof — local granting should leave it ~0 in steady state."""
     from .cluster_utils import Cluster
     from .core import api as ca
-    from .core.config import CAConfig
     from .core.worker import LEASE_STATS, global_worker
 
     results: List[Tuple[str, float, str]] = []
@@ -466,10 +464,8 @@ def run_lease_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
 
     n = 1000 if quick else 4000
 
-    def flood(delegation: bool):
-        cfg = CAConfig()
-        cfg.lease_delegation = delegation
-        cluster = Cluster(head_resources={"CPU": 0}, config=cfg)
+    def flood():
+        cluster = Cluster(head_resources={"CPU": 0})
         cluster.add_node(num_cpus=2)
         cluster.add_node(num_cpus=2)
         cluster.connect()
@@ -481,16 +477,13 @@ def run_lease_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
             w = global_worker()
             ca.get([noop.remote() for _ in range(100)], timeout=120)
             # let the warm leases idle-return so the measured flood actually
-            # exercises the grant path (and, with delegation on, gives the
-            # head a beat to hand the freed idle workers to the agents)
+            # exercises the grant path (and gives the head a beat to hand
+            # the freed idle workers to the agents)
             deadline = time.monotonic() + 15
             while time.monotonic() < deadline:
                 stats = w.head_call("stats")["stats"]
-                if not delegation or stats.get("lease_delegated_slots", 0) >= 2:
-                    if stats.get("idle_workers", 0) or stats.get(
-                        "lease_delegated_slots", 0
-                    ):
-                        break
+                if stats.get("lease_delegated_slots", 0) >= 2:
+                    break
                 time.sleep(0.2)
             local0 = LEASE_STATS["local_grants"]
             before = w.head_call("stats")["rpc_counts"].get("request_lease", 0)
@@ -503,7 +496,7 @@ def run_lease_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
             # EVERY burst re-acquires leases — the lease-churn traffic class
             # the delegation moves off the head (a steady warm flood hides
             # it behind lease reuse).  Per-burst head lease ops is the
-            # structural number: ~0 local vs several per burst central.
+            # structural number: ~0 when the agents grant.
             bursts = 4 if quick else 8
             lease_ops = ("request_lease", "return_lease")
             rc0 = w.head_call("stats")["rpc_counts"]
@@ -517,231 +510,11 @@ def run_lease_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
         finally:
             cluster.shutdown()
 
-    rate, head_rpcs, local, per_burst = flood(True)
+    rate, head_rpcs, local, per_burst = flood()
     record("lease plane local-grant tasks", rate, "/s")
     print(f"  head request_lease RPCs during flood: {head_rpcs} "
           f"(local grants: {local})")
     record("lease plane head lease-ops/burst (local)", per_burst, "ops")
-    rate_off, head_rpcs_off, _, per_burst_off = flood(False)
-    record("lease plane head-grant tasks", rate_off, "/s")
-    print(f"  head request_lease RPCs during flood: {head_rpcs_off}")
-    record("lease plane head lease-ops/burst (central)", per_burst_off, "ops")
-    return results
-
-
-def run_owner_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
-    """`ca microbenchmark --owner-plane`: A/B the ownership plane.  A
-    steady-state object workload — driver creates shm objects, workers
-    borrow them (inline holder lists smuggle the refs: transit pins +
-    borrower registration + release, the lease-plane test pattern extended
-    to objects) — with owner-resident settlement ON vs OFF.  The structural
-    proof is the head's per-object obj_refs message count: ~0 with the
-    plane on (inc/dec/pins/acks settle at owner ledgers over direct
-    connections) vs >= 1 centralized.  A final phase kills the head
-    mid-workload and shows cluster-wide GC still completing (owner ledgers
-    are the lifetime authority; the head is only the registry)."""
-    import numpy as np
-
-    from .cluster_utils import Cluster
-    from .core import api as ca
-    from .core.config import CAConfig
-    from .core.worker import global_worker
-
-    results: List[Tuple[str, float, str]] = []
-
-    def record(name: str, value: float, unit: str):
-        results.append((name, value, unit))
-        print(f"{name}: {value:,.2f} {unit}")
-
-    n = 150 if quick else 600
-    arr = np.arange(4000)  # ~32KB: shm-backed, registered at the head
-    want = int(arr.sum())
-
-    def arena_bytes(w) -> int:
-        return sum(
-            a.size - sum(sz for _, sz in a.free)
-            for a in w.shm_store._arenas.values()
-        )
-
-    def workload(owner_plane: bool):
-        cfg = CAConfig()
-        cfg.owner_plane = owner_plane
-        cluster = Cluster(head_resources={"CPU": 4}, config=cfg)
-        cluster.connect()
-        try:
-            @ca.remote
-            def borrow(holder):
-                return int(ca.get(holder[0]).sum())
-
-            # warm the pool + connections
-            ca.get(
-                [borrow.remote([ca.put(arr)]) for _ in range(20)], timeout=120
-            )
-            w = global_worker()
-            time.sleep(1.0)  # let warmup refcounts settle before counting
-            ops = ("obj_refs", "transit_done")
-            rc0 = w.head_call("stats")["rpc_counts"]
-            t0 = time.perf_counter()
-            refs = [ca.put(arr) for _ in range(n)]
-            outs = ca.get([borrow.remote([r]) for r in refs], timeout=600)
-            assert all(o == want for o in outs)
-            del refs, outs
-            # settlement proof: every arena slice reclaimed, not just fast
-            deadline = time.monotonic() + 30
-            while time.monotonic() < deadline and arena_bytes(w) > 0:
-                time.sleep(0.2)
-            leaked = arena_bytes(w)
-            dt = time.perf_counter() - t0
-            rc1 = w.head_call("stats")["rpc_counts"]
-            per_obj = {
-                m: (rc1.get(m, 0) - rc0.get(m, 0)) / n for m in ops
-            }
-            return n / dt, per_obj, leaked
-        finally:
-            cluster.shutdown()
-
-    rate_on, per_on, leaked_on = workload(True)
-    record("owner plane objects (ledger)", rate_on, "obj/s")
-    record("owner plane head obj_refs/object (ledger)", per_on["obj_refs"], "ops")
-    record(
-        "owner plane head transit_done/object (ledger)",
-        per_on["transit_done"], "ops",
-    )
-    print(f"  leaked arena bytes after settle: {leaked_on}")
-    rate_off, per_off, leaked_off = workload(False)
-    record("owner plane objects (centralized)", rate_off, "obj/s")
-    record(
-        "owner plane head obj_refs/object (centralized)",
-        per_off["obj_refs"], "ops",
-    )
-    record(
-        "owner plane head transit_done/object (centralized)",
-        per_off["transit_done"], "ops",
-    )
-    print(f"  leaked arena bytes after settle: {leaked_off}")
-
-    # --- GC with the head down mid-workload (ownership plane only) --------
-    cluster = Cluster(head_resources={"CPU": 2})
-    cluster.connect()
-    try:
-        w = global_worker()
-        big = np.zeros(200_000)  # 1.6MB: shm-backed from the first put
-        refs = [ca.put(big) for _ in range(20)]
-        assert arena_bytes(w) > 0
-        cluster.kill_head()
-        time.sleep(0.5)
-        del refs
-        deadline = time.monotonic() + 20
-        while time.monotonic() < deadline and arena_bytes(w) > 0:
-            time.sleep(0.2)
-        leaked = arena_bytes(w)
-        record("owner plane GC with head down (leaked bytes)", leaked, "B")
-        cluster.restart_head()
-    finally:
-        cluster.shutdown()
-    return results
-
-
-def run_metrics_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
-    """`ca microbenchmark --metrics-plane`: A/B the metrics plane.  With the
-    plane ON, agent-node workers ship metric deltas to their node agent
-    (piggybacked head-ward on node_sync) and Prometheus scrapes the agents'
-    HTTP endpoints — a scrape costs the head ZERO RPCs.  With it OFF, every
-    worker reports straight to the head each flush and a scrape is a
-    `metrics_snapshot` head RPC.  The structural rows are head metrics-RPC
-    traffic per scrape in each mode; the final phase kills the head and
-    shows the node endpoint still serving exposition text (scrape survives
-    a dead head)."""
-    import urllib.request
-
-    from .cluster_utils import Cluster
-    from .core import api as ca
-    from .core.config import CAConfig
-    from .core.worker import global_worker
-
-    results: List[Tuple[str, float, str]] = []
-
-    def record(name: str, value: float, unit: str):
-        results.append((name, value, unit))
-        print(f"{name}: {value:,.2f} {unit}")
-
-    n_scrapes = 5 if quick else 20
-    scrape_gap = 0.25  # leaves room for flush ticks between scrapes
-
-    def node_scrape(cluster, nid: str) -> str:
-        addr = open(
-            os.path.join(cluster.session_dir, "nodes", nid, "metrics.addr")
-        ).read().strip()
-        with urllib.request.urlopen(addr + "/metrics", timeout=10) as r:
-            return r.read().decode()
-
-    def workload(plane_on: bool):
-        cfg = CAConfig()
-        cfg.metrics_plane = plane_on
-        cluster = Cluster(head_resources={"CPU": 1}, config=cfg)
-        nid = cluster.add_node(num_cpus=2)
-        cluster.connect()
-        try:
-            @ca.remote
-            def noisy(i):
-                from cluster_anywhere_tpu.util.metrics import Counter
-
-                Counter("mb_metricsplane_total", "a/b traffic source").inc()
-                return i
-
-            ca.get([noisy.remote(i) for i in range(40)], timeout=120)
-            time.sleep(2.0)  # a couple of flush ticks settle the pipeline
-            w = global_worker()
-            rc0 = w.head_call("stats")["rpc_counts"]
-            for _ in range(n_scrapes):
-                if plane_on:
-                    text = node_scrape(cluster, nid)
-                    assert "ca_node_agent" in text
-                else:
-                    w.head_call("metrics_snapshot")
-                time.sleep(scrape_gap)
-            rc1 = w.head_call("stats")["rpc_counts"]
-            per_scrape = {
-                m: (rc1.get(m, 0) - rc0.get(m, 0)) / n_scrapes
-                for m in ("metrics_snapshot", "metrics_report")
-            }
-            return per_scrape, cluster, nid
-        except BaseException:
-            cluster.shutdown()
-            raise
-
-    per_on, cluster_on, nid_on = workload(True)
-    record(
-        "metrics plane head snapshot RPCs/scrape (node scrape)",
-        per_on["metrics_snapshot"], "ops",
-    )
-    record(
-        "metrics plane head report RPCs/scrape (node scrape)",
-        per_on["metrics_report"], "ops",
-    )
-    # --- scrape with the head DOWN (the plane's reason to exist) ----------
-    try:
-        cluster_on.kill_head()
-        time.sleep(0.5)
-        text = node_scrape(cluster_on, nid_on)
-        ok = 1.0 if ("ca_node_agent_scrapes_total" in text and "# TYPE" in text) else 0.0
-        record("metrics plane scrape with head down (1=ok)", ok, "")
-        cluster_on.restart_head()
-    finally:
-        cluster_on.shutdown()
-
-    per_off, cluster_off, _ = workload(False)
-    try:
-        record(
-            "metrics plane head snapshot RPCs/scrape (head RPC)",
-            per_off["metrics_snapshot"], "ops",
-        )
-        record(
-            "metrics plane head report RPCs/scrape (head RPC)",
-            per_off["metrics_report"], "ops",
-        )
-    finally:
-        cluster_off.shutdown()
     return results
 
 
@@ -753,7 +526,7 @@ def run_transfer_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
     measures pipelining, not this host's memcpy speed), with the structural
     columns — window occupancy (avg per-pull peak in-flight pull_chunk
     RPCs) and head RPCs per pulled object (must not grow with the window).
-    (2) 1-source vs 2-source pulls of an object with two live copies.
+    (2) Pulls of an object with two live copies, drawn from both holders.
     (3) f32 vs int8/bf16 quantized host collective ring at 64 MB
     (effective bytes/s = input bytes reduced per second)."""
     from .cluster_utils import Cluster
@@ -773,12 +546,11 @@ def run_transfer_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
     nobj = 2 if quick else 4
     size = 4 * 1024**2 if quick else 8 * 1024**2
 
-    def pull_bench(window: int, two_sources: bool = False, multi: bool = True):
+    def pull_bench(window: int, two_sources: bool = False):
         cfg = CAConfig()
         cfg.transfer_window = window
         cfg.transfer_chunk_bytes = chunk
         cfg.testing_transfer_delay_s = delay
-        cfg.transfer_multi_source = multi
         cluster = Cluster(head_resources={"CPU": 1}, config=cfg)
         n1 = cluster.add_node(num_cpus=2)
         n2 = cluster.add_node(num_cpus=2) if two_sources else None
@@ -839,11 +611,8 @@ def run_transfer_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
     record("transfer pull windowed window occupancy", occ_w, "rpcs")
     record("transfer pull windowed head RPCs/object", head_rpc_w, "ops")
     record("transfer pull windowed speedup", bps_w / bps, "x")
-    bps_1, _, _, d1 = pull_bench(window=4, two_sources=True, multi=False)
-    record("transfer pull 1-source (2 copies live)", bps_1 / 1e6, "MB/s")
-    bps_2, _, _, d2 = pull_bench(window=4, two_sources=True, multi=True)
+    bps_2, _, _, d2 = pull_bench(window=4, two_sources=True)
     record("transfer pull 2-source (2 copies live)", bps_2 / 1e6, "MB/s")
-    record("transfer pull multi-source speedup", bps_2 / bps_1, "x")
     record(
         "transfer pull 2-source pulls drawing from both holders",
         d2["multi_source_pulls"], "pulls",
@@ -1257,19 +1026,14 @@ def run_serve_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
 
 
 def run_dag_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
-    """`ca microbenchmark --dag`: compiled-DAG plane A/B.
+    """`ca microbenchmark --dag`: compiled-DAG plane against actor calls.
 
     (1) Actor-call A/B on one actor: per-call RPC latency (sync p50) and
         async throughput vs compiled-DAG tick latency over pre-opened shm
         channels (driver write -> futex wake -> compute -> futex wake ->
         driver read; zero RPCs in steady state) and pipelined throughput at
         max_inflight_executions.
-    (2) 3-actor chain A/B: chained RPC per item vs one compiled graph.
-    (3) Serve TTFT A/B: ContinuousLLMServer SSE below the knee with
-        config.serve_compiled_dag OFF vs ON — a fresh cluster per mode,
-        env-toggled so the proxy process inherits the setting."""
-    import socket
-
+    (2) 3-actor chain A/B: chained RPC per item vs one compiled graph."""
     from .core import api as ca
     from .dag import InputNode
 
@@ -1279,7 +1043,6 @@ def run_dag_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
         results.append((name, value, unit))
         print(f"{name}: {value:,.2f} {unit}")
 
-    # ---------------- phase 1+2: actor-call / chain A/B -------------------
     owns = not ca.is_initialized()
     if owns:
         ca.init(num_cpus=4)
@@ -1359,66 +1122,6 @@ def run_dag_plane(quick: bool = False) -> List[Tuple[str, float, str]]:
     if owns:
         ca.shutdown()
 
-    # ---------------- phase 3: serve TTFT A/B -----------------------------
-    if not owns:
-        print("(serve TTFT A/B skipped: caller owns the cluster; the A/B "
-              "needs a fresh cluster per mode)")
-        return results
-    from . import serve
-    from .llm.processor import ProcessorConfig
-    from .llm.serve_llm import build_continuous_llm_deployment
-
-    def free_port() -> int:
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        p = s.getsockname()[1]
-        s.close()
-        return p
-
-    host = "127.0.0.1"
-    mnt = 8 if quick else 16
-    n_req = 8 if quick else 16
-    prev = os.environ.get("CA_SERVE_COMPILED_DAG")
-    try:
-        for label, flag in (("rpc-stream", "0"), ("compiled", "1")):
-            # env-toggled BEFORE init so the proxy's process inherits it
-            os.environ["CA_SERVE_COMPILED_DAG"] = flag
-            ca.init(num_cpus=4)
-            port = free_port()
-            serve.start(host=host, port=port)
-            cfg = ProcessorConfig(max_prompt_len=64, max_new_tokens=mnt)
-            app = build_continuous_llm_deployment(
-                cfg, slots=4, num_replicas=1, sse_ingress=True,
-            )
-            serve.run(app, name="llmdag", route_prefix="/llmdag")
-            time.sleep(1.0)
-
-            def body(i: int) -> dict:
-                return {
-                    "prompt": f"request {i:04d} " + "x" * 16,
-                    "max_new_tokens": mnt,
-                }
-
-            for i in range(2):  # compile prefill/decode before timing
-                st, _, _, _ = _sse_request(host, port, "/llmdag", body(i))
-                assert st == 200, f"warmup request failed: HTTP {st}"
-            ttfts, events = [], 0
-            for i in range(n_req):
-                st, ttft, _, ne = _sse_request(host, port, "/llmdag", body(10 + i))
-                if st == 200 and ttft is not None:
-                    ttfts.append(ttft)
-                    events += ne
-            record(f"dag serve {label} TTFT p50", _pct(ttfts, 0.5) * 1e3, "ms")
-            record(f"dag serve {label} TTFT p99", _pct(ttfts, 0.99) * 1e3, "ms")
-            record(f"dag serve {label} events", float(events), "ev")
-            serve.delete("llmdag")
-            serve.shutdown()
-            ca.shutdown()
-    finally:
-        if prev is None:
-            os.environ.pop("CA_SERVE_COMPILED_DAG", None)
-        else:
-            os.environ["CA_SERVE_COMPILED_DAG"] = prev
     return results
 
 
@@ -1888,14 +1591,8 @@ def run_obsplane(quick: bool = False) -> List[Tuple[str, float, str]]:
 
     Process-local rows: armed `record()` events/s (the full cost — dict
     build, trace probe, lock, ring append), the disabled-path gate rate
-    (`REC is None`: one attribute load + branch, the off switch's whole
-    cost), and the journal's memory footprint with the default ring at
-    cap.  Cluster rows: simple-task round-trip throughput with
-    flightrec_plane on vs off — the acceptance A/B: disabled within
-    noise, enabled cost bounded by the journal's own record rate."""
-    from .cluster_utils import Cluster
-    from .core import api as ca
-    from .core.config import CAConfig
+    (`REC is None` before `init()`: one attribute load + branch), and the
+    journal's memory footprint with the default ring at cap."""
     from .util import flightrec
 
     results: List[Tuple[str, float, str]] = []
@@ -1904,7 +1601,7 @@ def run_obsplane(quick: bool = False) -> List[Tuple[str, float, str]]:
         results.append((name, value, unit))
         print(f"{name}: {value:,.1f} {unit}")
 
-    # --- process-local: the record path and the off switch ---------------
+    # --- process-local: the record path and the unarmed gate -------------
     n = 50_000 if quick else 400_000
     saved = flightrec.REC
     try:
@@ -1938,34 +1635,6 @@ def run_obsplane(quick: bool = False) -> List[Tuple[str, float, str]]:
         )
     finally:
         flightrec.REC = saved
-
-    # --- cluster A/B: task throughput with the plane on vs off -----------
-    def tput(plane_on: bool) -> float:
-        cfg = CAConfig()
-        cfg.flightrec_plane = plane_on
-        cluster = Cluster(head_resources={"CPU": 2}, config=cfg)
-        cluster.connect()
-        try:
-            @ca.remote
-            def echo(i):
-                return i
-
-            ca.get([echo.remote(i) for i in range(20)], timeout=120)
-            m = 200 if quick else 1000
-            t0 = time.perf_counter()
-            ca.get([echo.remote(i) for i in range(m)], timeout=300)
-            return m / (time.perf_counter() - t0)
-        finally:
-            cluster.shutdown()
-
-    # two alternating rounds, best-of-each: the FIRST cluster a process
-    # starts pays one-time warmup (imports, forkserver) that would be
-    # misread as plane overhead if one arm always went first
-    on = max(tput(True), tput(True))
-    off = max(tput(False), tput(False))
-    record("obsplane tasks/s flightrec on", on, "/s")
-    record("obsplane tasks/s flightrec off", off, "/s")
-    record("obsplane off/on throughput ratio", off / max(on, 1e-9), "")
     return results
 
 
@@ -1976,7 +1645,6 @@ def main(
     scalability: bool = False,
     collective: bool = False,
     lease_plane: bool = False,
-    owner_plane: bool = False,
     transfer: bool = False,
     serve_plane: bool = False,
     train_elastic: bool = False,
@@ -1993,8 +1661,6 @@ def main(
         run_collective_bw(quick=quick)
     elif lease_plane:
         run_lease_plane(quick=quick)
-    elif owner_plane:
-        run_owner_plane(quick=quick)
     elif transfer:
         run_transfer_plane(quick=quick)
     elif serve_plane:
@@ -2019,7 +1685,6 @@ if __name__ == "__main__":
         scalability="--scalability" in sys.argv,
         collective="--collective" in sys.argv,
         lease_plane="--lease-plane" in sys.argv,
-        owner_plane="--owner-plane" in sys.argv,
         transfer="--transfer" in sys.argv,
         serve_plane="--serve" in sys.argv,
         train_elastic="--train-elastic" in sys.argv,

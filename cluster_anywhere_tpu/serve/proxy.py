@@ -522,25 +522,22 @@ class ProxyActor:
     async def _open_stream(self, handle, req: Request, loop, rt: _RequestTrace):
         """Pick the token transport for one SSE request.
 
-        Compiled-DAG path (config.serve_compiled_dag, default on): ONE RPC
-        handshake asks the replica's `dag_stream` for a pre-opened shm
-        channel spec, then every token travels writer->futex->reader with
-        no RPC at all (see serve/dag_stream.py).  Falls back to the
-        per-token streaming-RPC path when the deployment has no dag_stream
-        method or the segment can't be mapped (cross-host replica), and
-        remembers the failure per deployment.
+        Compiled-DAG path: ONE RPC handshake asks the replica's `dag_stream`
+        for a pre-opened shm channel spec, then every token travels
+        writer->futex->reader with no RPC at all (see serve/dag_stream.py).
+        Falls back to the per-token streaming-RPC path when the deployment
+        has no dag_stream method or the segment can't be mapped (cross-host
+        replica), and remembers the failure per deployment.
 
         The whole of it, until a reader is in hand, is the phase
         `serve.proxy.open_stream`; the handshake's wait for a pool thread and
         the router's phases are its children.
         """
-        from ..core.config import get_config
-
         t0 = time.monotonic()
         own = _tracing.child_context(rt.ctx) if rt.ctx is not None else None
         transport, fallback = "rpc", False
         try:
-            if get_config().serve_compiled_dag and self._dag_stream_ok.get(rt.dep, True):
+            if self._dag_stream_ok.get(rt.dep, True):
                 try:
                     spec = await rt.in_pool(
                         loop,

@@ -18,9 +18,8 @@ agents forward on `node_sync` ticks) — zero new standalone RPCs.  The head
 merges per-process journals into one cluster ring served by the `flightrec`
 RPC (`ca events`, `ca incident`, dashboard `/api/flightrec`).
 
-Off switch: `flightrec_plane=False` leaves the module-global `REC` as None
-and every record site is a single `REC is None` branch — no allocation, no
-lock, no dict build on the disabled path.
+Before `init()` the module-global `REC` is None and every record site is a
+single `REC is None` branch — no allocation, no lock, no dict build.
 
 Typed failures (`FencedError`, `DeadActorError`, `DagTimeoutError`,
 `ObjectLostError`) attach `recent()` slices at raise time so an exception
@@ -197,7 +196,7 @@ def init(
 
 
 def shutdown() -> None:
-    """Disarm (tests / flightrec_plane=False)."""
+    """Disarm (tests)."""
     global REC
     REC = None
 

@@ -346,13 +346,9 @@ def register_flush_hook(fn: Callable[[], None]) -> None:
 
 
 def _agent_ship_addr() -> Optional[str]:
-    """This process's node-agent metrics sink, when the metrics plane is on.
-    Agent-spawned workers carry CA_AGENT_ADDR; head-node workers and drivers
-    have no agent and keep the direct head path."""
-    from ..core.config import get_config
-
-    if not getattr(get_config(), "metrics_plane", True):
-        return None
+    """This process's node-agent metrics sink.  Agent-spawned workers carry
+    CA_AGENT_ADDR; head-node workers and drivers have no agent and keep the
+    direct head path."""
     import os
 
     return os.environ.get("CA_AGENT_ADDR") or None

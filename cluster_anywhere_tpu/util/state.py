@@ -593,7 +593,7 @@ def flightrec_events(
     barriers, transfer failovers, owner adoption), shipped on the metrics
     piggyback and merged into one ts-ordered cluster ring.  Filters:
     `trace` (trace id), `plane`, `node`, `event` (substring), `since`
-    (epoch seconds).  Returns {"events", "total", "enabled"}."""
+    (epoch seconds).  Returns {"events", "total"}."""
     return _head(
         "flightrec", trace=trace, plane=plane, node=node, event=event,
         since=since, limit=limit,
@@ -632,7 +632,6 @@ def incident(
         "nodes": sorted(nodes),
         "span_s": (evs[-1]["ts"] - evs[0]["ts"]) if len(evs) > 1 else 0.0,
         "total": r.get("total", len(evs)),
-        "enabled": r.get("enabled", True),
     }
 
 

@@ -3,6 +3,7 @@ task_canceller role): queued tasks drop immediately, running tasks get
 TaskCancelledError raised in their executing thread, force kills the
 worker, cancelled tasks never retry, finished tasks are untouched."""
 
+import os
 import time
 
 import pytest
@@ -19,19 +20,24 @@ def cluster():
     ca.shutdown()
 
 
-def test_cancel_running_task_interrupts():
+def test_cancel_running_task_interrupts(tmp_path):
     """A pure-Python loop hits the async-raised TaskCancelledError at a
     bytecode boundary; get() surfaces it."""
+    started = str(tmp_path / "started")
 
     @ca.remote
-    def spin():
+    def spin(started):
+        open(started, "w").close()
         t0 = time.time()
         while time.time() - t0 < 60:
             sum(range(1000))  # bytecode boundaries for the async exception
         return "finished"
 
-    ref = spin.remote()
-    time.sleep(1.0)  # let it start executing
+    ref = spin.remote(started)
+    deadline = time.time() + 30  # a worker may take seconds to start
+    while time.time() < deadline and not os.path.exists(started):
+        time.sleep(0.05)
+    assert os.path.exists(started)  # it is executing
     ca.cancel(ref)
     t0 = time.time()
     with pytest.raises(ca.exceptions.TaskCancelledError):

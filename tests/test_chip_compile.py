@@ -145,8 +145,8 @@ def test_decode_block_reads_the_cache_as_stored(v5e):
     """One decode block at the serving benchmark's widths (Mistral-7B: 32 Q /
     8 KV heads x 128, 32 slots, t_max 768) as the chip's compiler leaves it:
     no buffer as large as the layer's cache repeated to 32 heads, and none in
-    f32 as large as the cache.  The jaxpr guard in test_llm.py cannot see a
-    broadcast that XLA materialises in front of a dot; this does."""
+    f32 as large as the cache.  The jaxpr guard in test_llm_programs.py cannot
+    see a broadcast that XLA materialises in front of a dot; this does."""
     cfg = transformer.TransformerConfig(
         vocab_size=259, n_layers=1, d_model=4096, n_heads=32, n_kv_heads=8, d_head=128,
         d_ff=14336, param_dtype=jnp.bfloat16,

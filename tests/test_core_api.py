@@ -161,3 +161,20 @@ def test_direct_call_raises(ca_cluster_module):
 
     with pytest.raises(TypeError):
         f()
+
+
+def test_a_plane_has_no_off_switch():
+    """The host planes run one way: the only on/off settings are whether the
+    pool is prestarted and whether this driver prints the cluster's logs, and
+    a plane's old switch is refused before anything is started."""
+    import dataclasses
+
+    from cluster_anywhere_tpu.core.config import CAConfig
+
+    bools = {f.name for f in dataclasses.fields(CAConfig) if isinstance(f.default, bool)}
+    assert bools == {"worker_prestart", "log_to_driver"}
+    if ca.is_initialized():  # an unknown key is looked at after this
+        ca.shutdown()
+    with pytest.raises(ValueError, match="unknown config key 'owner_plane'"):
+        ca.init(owner_plane=False)
+    assert not ca.is_initialized()

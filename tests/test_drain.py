@@ -63,7 +63,7 @@ def test_drain_fsm_idle_node():
         c.shutdown()
 
 
-def test_drain_acceptance_tasks_actor_object():
+def test_drain_acceptance_tasks_actor_object(tmp_path):
     """The acceptance scenario: draining a node with in-flight zero-retry
     tasks, a live zero-restart actor, and a sole-copy object yields every
     task result (budget untouched), the actor serving on a survivor before
@@ -92,7 +92,8 @@ def test_drain_acceptance_tasks_actor_object():
                 return os.environ.get("CA_NODE_ID")
 
         @ca.remote
-        def slow(t):
+        def slow(t, started):
+            open(os.path.join(started, os.urandom(4).hex()), "w").close()
             time.sleep(t)
             return os.environ.get("CA_NODE_ID")
 
@@ -110,16 +111,30 @@ def test_drain_acceptance_tasks_actor_object():
         ).remote()
         ca.wait([obj], timeout=30)
         # in-flight tasks with ZERO retry budget, outliving the deadline
-        refs = [slow.options(max_retries=0).remote(2.5) for _ in range(4)]
-        time.sleep(0.8)  # let them start
+        refs = [
+            slow.options(max_retries=0).remote(2.5, str(tmp_path)) for _ in range(4)
+        ]
+        # in flight: one on each of the three CPUs the actor left (a worker
+        # may take over a second to start), the fourth behind one of them
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and len(os.listdir(tmp_path)) < 3:
+            time.sleep(0.02)
+        assert len(os.listdir(tmp_path)) >= 3
+        time.sleep(0.8)
         t0 = time.monotonic()
         r = ca.drain_node(victim, reason="preemption", deadline_s=4.0)
         assert r["state"] == "draining"
         # the actor serves again on a survivor BEFORE the deadline expires
-        # (checked first: proactive migration must not wait out the window)
+        # (checked first: proactive migration must not wait out the window).
+        # `drain_node` returns before the victim's instance is stopped, which
+        # may still answer the first calls: the wait is for the survivor's
         assert ca.get(actor.incr.remote(), timeout=30) >= 1
+        where = ca.get(actor.node.remote(), timeout=10)
+        while where != survivor and time.monotonic() - t0 < 4.0:
+            time.sleep(0.05)
+            where = ca.get(actor.node.remote(), timeout=10)
         assert time.monotonic() - t0 < 4.0
-        assert ca.get(actor.node.remote(), timeout=10) == survivor
+        assert where == survivor
         # every result arrives even though max_retries=0: deadline kills are
         # system failures, retried without touching the budget
         got = ca.get(refs, timeout=60)

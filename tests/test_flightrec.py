@@ -121,7 +121,7 @@ def test_memory_bytes_is_positive_and_bounded():
 
 # ----------------------------------------------------------- disabled path
 def test_disabled_path_is_inert():
-    """flightrec_plane=False leaves REC as None: module-level record() is a
+    """Before init() REC is None: module-level record() is a
     no-op, recent() is [], and error black boxes are empty lists — no
     allocation, no counter bumps."""
     assert flightrec.REC is None
@@ -271,7 +271,6 @@ def test_fence_incident_timeline_on_killed_node():
 
         w = global_worker()
         r = w.head_call("flightrec", limit=5000)
-        assert r["enabled"] is True
         evs = r["events"]
         by_event = {}
         for e in evs:
@@ -290,7 +289,7 @@ def test_fence_incident_timeline_on_killed_node():
 
         # incident() aggregates the same window into planes/nodes/span
         inc = state.incident(window_s=600.0)
-        assert inc["enabled"] and inc["events"]
+        assert inc["events"]
         assert inc["planes"].get("fence", 0) >= 1
         assert inc["span_s"] >= 0
 

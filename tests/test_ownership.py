@@ -431,19 +431,24 @@ def test_early_ref_grace_window_bounds_pending_refs():
 
         from cluster_anywhere_tpu.util import state
 
+        def registered(oid, bound=10.0):
+            """The head's record of `oid`, once obj_created has landed."""
+            deadline = time.monotonic() + bound
+            rec = None
+            while time.monotonic() < deadline and rec is None:
+                rec = next(
+                    (x for x in state.list_objects()
+                     if x["object_id"] == oid.hex()), None,
+                )
+                time.sleep(0.1)
+            return rec
+
         # within the window: early inc, then obj_created -> holder adopted
         oid1 = os.urandom(20)
         notify("obj_refs", inc=[oid1], as_id="ghost-holder")
         time.sleep(0.3)
         notify("obj_created", oid=oid1, size=1, owner="ghost-owner")
-        deadline = time.monotonic() + 5
-        rec = None
-        while time.monotonic() < deadline and rec is None:
-            rec = next(
-                (x for x in state.list_objects()
-                 if x["object_id"] == oid1.hex()), None,
-            )
-            time.sleep(0.1)
+        rec = registered(oid1)
         assert rec is not None and rec["num_holders"] == 1, rec
 
         # past the window: the early inc is swept before obj_created lands
@@ -456,11 +461,7 @@ def test_early_ref_grace_window_bounds_pending_refs():
             time.sleep(0.2)
         assert w.head_call("stats")["stats"].get("early_refs_expired", 0) >= 1
         notify("obj_created", oid=oid2, size=1, owner="ghost-owner")
-        time.sleep(0.5)
-        rec2 = next(
-            (x for x in state.list_objects()
-             if x["object_id"] == oid2.hex()), None,
-        )
+        rec2 = registered(oid2)
         assert rec2 is not None and rec2["num_holders"] == 0, rec2
     finally:
         ca.shutdown()
