@@ -81,7 +81,7 @@ import numpy as np
 from jax import lax
 
 from ..models.generate import (
-    _nucleus_mask, cache_bytes_per_token, cache_kind_bytes, decode_rows, init_cache, install_rows, key_slots, prefill_counted,
+    _nucleus_mask, cache_bytes_per_token, cache_context_bytes_per_token, cache_kind_bytes, decode_rows, init_cache, install_rows, key_slots, prefill_counted,
     recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
@@ -436,7 +436,7 @@ class ContinuousBatcher:
         # the slots of a layer's keys that a step could read (as many of its values, or its
         # latent rows; over layers of two extents, their mean): what `cache_rows_read` is a
         # share of; 0 for recurrent state alone
-        self._cache_rows = key_slots(self.cache)[0]
+        self._cache_rows = key_slots(self.cache, cfg=cfg)[0]
         self._cache_bytes = cache_kind_bytes(self.cache)
         # the decode step's per-slot inputs as its program takes them: two host
         # arrays, the scheduler's vectors their rows, written between steps (an
@@ -516,14 +516,25 @@ class ContinuousBatcher:
             # of cache_rows_read, the part read in window layers' rings (a live row's whole
             # ring a layer); stays 0 without window layers
             "window_rows_read": 0,
+            # of cache_rows_read, the part read of the one stack that several layers share (the
+            # full layer that writes it and the cross layers above it); stays 0 where none share
+            "shared_rows_read": 0,
+            # the positions the admits' prefills computed in the first layer (the prompts'
+            # buckets) and in the last (the same, or 1 a prompt where the stack's second half
+            # is computed at a prompt's last position alone: models/generate.py _prefill_tail),
+            # and the second as a share of the first, percent
+            "prefill_positions_total": 0, "prefill_tail_positions_total": 0, "prefill_tail_share": 100.0,
             # the cache's bytes by the extent of its rows: the stacks as long as a context
             # (keys and values, latent rows), and the window layers' rings
             "cache_full_bytes": self._cache_bytes["full"], "cache_window_bytes": self._cache_bytes["window"],
             # the rings' share of the two, percent
             "cache_window_share": 100.0 * self._cache_bytes["window"] / max(sum(self._cache_bytes.values()), 1),
-            # what one token of a context takes in the cache over all the layers
-            # that attend, by the cache's own shapes: a constant of the deployment
-            "cache_bytes_per_token": cache_bytes_per_token(self.cache),
+            # what a token takes in the cache over all the layers that keep keys and values
+            # (a window layer's ring too, while it holds the token), and the part of it that one
+            # more token of context adds (the stacks as long as a context alone), by the
+            # cache's own shapes: constants of the deployment
+            "cache_bytes_per_token": cache_bytes_per_token(self.cache, cfg),
+            "cache_context_bytes_per_token": cache_context_bytes_per_token(self.cache, cfg),
         }
 
     # ------------------------------------------------------------- interface
@@ -637,17 +648,20 @@ class ContinuousBatcher:
         helper, and the window layers' part of them (models/generate.py
         key_slots: the mean over the attention layers where their extents
         differ).  The host's arithmetic on its own vectors."""
-        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens, self.cfg.attn_window)
+        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens, self.cfg.attn_window, self.cfg)
 
     def _count_rows_read(self, rows_read: tuple, sp: tracing.span) -> None:
         if self._cache_rows:
-            read, window = rows_read
+            read, window, shared = rows_read
             sp.set(cache_rows_read=read, cache_rows=self._cache_rows)
             self.stats["cache_rows_read"] += read
             self.stats["cache_rows"] += self._cache_rows
             if self._cache_bytes["window"]:
                 sp.set(window_rows_read=window)
                 self.stats["window_rows_read"] += window
+            if self.cfg.shared_readers:
+                sp.set(shared_rows_read=shared, shared_readers=self.cfg.shared_readers)
+                self.stats["shared_rows_read"] += shared
 
     def _land(self, step: _StepInFlight, out: Dict[int, List[int]], sp: tracing.span) -> None:
         """Read a dispatched step and hand each row's token to the request that
@@ -814,13 +828,15 @@ class ContinuousBatcher:
         split = ((len(prompt) - 1) // self._split_quantum) * self._split_quantum
         return split if split >= self.prefix_block else 0
 
-    def _prefill_padded(self, prompt: np.ndarray, bucket: int):
+    def _prefill_padded(self, prompt: np.ndarray, bucket: int, sp: tracing.span):
         """Left-pad `prompt` to `bucket` and prefill it: one compiled batch-1
         program a bucket, traced at the bucket's first admit and one dispatch
         thereafter (the padded ids and the pad count go as the host arrays
         they are).  Returns (first-token logits [1, V], its cache rows as a
         batch of one, pad, the held expert layers and the compact ones on the
-        device: `prefill_counted`'s, None where the replica holds every expert)."""
+        device: `prefill_counted`'s, None where the replica holds every expert).
+        `sp`, the request's `llm.admit` span, is told the positions computed in
+        the first layer and in the last (`stats`)."""
         with tracing.span("llm.admit.prefill"):
             padded = np.zeros((1, bucket), np.int32)
             pad = bucket - len(prompt)
@@ -830,6 +846,12 @@ class ContinuousBatcher:
                 self.params, padded, self.cfg, self.t_max, pad=np.asarray([pad], np.int32)
             )
             self.stats["prefill_traces"] += prefill_counted._cache_size() - programs
+            tail = 1 if self.cfg.carries else bucket
+            sp.set(prefill_positions=bucket, tail_positions=tail)
+            self.stats["prefill_positions_total"] += bucket
+            self.stats["prefill_tail_positions_total"] += tail
+            self.stats["prefill_tail_share"] = (
+                100.0 * self.stats["prefill_tail_positions_total"] / self.stats["prefill_positions_total"])
         return logits, rows, pad, held
 
     def block_plan(self, n: int, max_new: int):
@@ -855,7 +877,7 @@ class ContinuousBatcher:
         tail = len(prompt) - whole
         sp.set(bucket=bucket, prefix_hit=0, block_tail=tail)
         if whole:
-            _, rows, _, _ = self._prefill_padded(prompt[:whole], bucket)
+            _, rows, _, _ = self._prefill_padded(prompt[:whole], bucket, sp)
             with tracing.span("llm.admit.install"):
                 self.cache = _install_slot(self.cache, rows, slot)
         self._blk_tokens[:, slot] = 0
@@ -873,7 +895,7 @@ class ContinuousBatcher:
         span."""
         bucket = self._bucket(len(req.prompt_ids), req.max_new_tokens)
         sp.set(bucket=bucket, prefix_hit=0)
-        logits, rows, pad, held = self._prefill_padded(req.prompt_ids, bucket)
+        logits, rows, pad, held = self._prefill_padded(req.prompt_ids, bucket, sp)
         return logits, rows, pad, bucket, held
 
     def _admit_prefix_cached(self, req: Request, split: int, sp: tracing.span):
@@ -894,7 +916,7 @@ class ContinuousBatcher:
         sp.set(bucket=bucket, prefix_hit=int(entry is not None))
         held = None
         if entry is None:
-            _, rows, pad, held = self._prefill_padded(prompt[:split], bucket)
+            _, rows, pad, held = self._prefill_padded(prompt[:split], bucket, sp)
             # store a snapshot BEFORE stepping: _suffix_step donates its rows
             self.prefix_cache.put(key, jax.tree_util.tree_map(jnp.copy, rows), pad)
             self.stats["prefix_misses"] += 1

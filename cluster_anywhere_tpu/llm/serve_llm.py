@@ -280,6 +280,13 @@ class ContinuousLLMServer:
                  "slots of a layer's keys the cache held over those steps: what cache_rows_read is a share of"),
                 ("window_rows_read", "ca_serve_window_rows_read_total",
                  "of the slots fetched, those in window layers' rings"),
+                ("shared_rows_read", "ca_serve_shared_rows_read_total",
+                 "of the slots fetched, those of the one stack that a full layer writes and the cross layers read"),
+                ("prefill_positions_total", "ca_serve_prefill_positions_total",
+                 "positions the admits' prefills computed in the first layer: the prompts' buckets"),
+                ("prefill_tail_positions_total", "ca_serve_prefill_tail_positions_total",
+                 "positions they computed in the last layer: 1 a prompt where the stack's second half runs at "
+                 "a prompt's last position alone"),
             ):
                 self._llm_metrics[key] = m.Counter(name, desc)
             # the cache's bytes by the extent of its rows: constants of the deployment

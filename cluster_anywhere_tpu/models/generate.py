@@ -113,9 +113,9 @@ from ..ops.attention import (
 )
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
-    _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gqa_repeat, _head,
-    _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, core_scope, is_window,
-    layer_stacks,
+    _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gmu_block, _gqa_repeat, _hand_on,
+    _head, _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, _x, carried,
+    core_scope, is_window, layer_stacks,
 )
 
 # what a layer of each kind of state keeps of a sequence between two tokens, as
@@ -124,6 +124,10 @@ from .transformer import (
 LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr"), "attn_win": ("kw", "vw")}
 # the scope a kind's state is read, written and installed under
 STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache", "attn_win": "attn.cache"}
+# the kinds of layer that keep no rows of their own: a gated memory unit keeps nothing
+# between two tokens (its memory is the step's own), a cross layer reads the stack of keys
+# and values that the one full layer before it writes
+NO_ROWS = ("gmu", "attn_cross")
 
 
 # The rotated key's last axis in the cache is padded with zeros to a multiple
@@ -141,8 +145,12 @@ def _lanes(a):
     return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, -a.shape[-1] % LATENT_LANES)])
 
 
-def _state_kind(kind: str, cfg: TransformerConfig) -> str:
-    """The kind of state (LAYER_STATE's key) a layer of `kind` keeps."""
+def _state_kind(kind: str, cfg: TransformerConfig) -> Optional[str]:
+    """The kind of state (LAYER_STATE's key) a layer of `kind` keeps, or of a
+    cross layer (which keeps none: NO_ROWS) reads; None for a layer that
+    touches none."""
+    if kind == "gmu":
+        return None
     if kind == "ssm":
         return kind
     if is_window(kind):
@@ -155,7 +163,7 @@ def window_extent(cfg: TransformerConfig, t_max: int) -> int:
     the least the decode kernel's key block allows that holds the window (256
     at 8 cached heads: ops/attention.py decode_key_block); the whole context
     where that is no longer.  Position p lies at slot p mod the extent."""
-    block = max(DECODE_BLOCK_K, DECODE_BLOCK_ROWS // cfg.n_kv_heads)
+    block = max(DECODE_BLOCK_K, DECODE_BLOCK_ROWS // cfg.cached_heads)
     return min(cfg.attn_ring or -(-cfg.attn_window // block) * block, t_max)
 
 
@@ -163,18 +171,27 @@ def _state_index(cfg: TransformerConfig):
     """{kind: for each of the kind's layers, in order, its index in the stacks
     of the state it keeps}: kinds that keep one kind of state (a mixture's
     leading dense layers and its expert layers) share those stacks in the
-    model's order."""
+    model's order.  A kind that keeps no rows (NO_ROWS) has no place in any
+    stack: its layers are numbered among themselves."""
     seen, out = {}, {}
     for kind in cfg.layer_kinds:
-        state = _state_kind(kind, cfg)
+        state = kind if kind in NO_ROWS else _state_kind(kind, cfg)
         out.setdefault(kind, []).append(seen.get(state, 0))
         seen[state] = seen.get(state, 0) + 1
     return out
 
 
+def shared_layer(cfg: TransformerConfig) -> int:
+    """Where, in the full layers' stacks k and v, the keys and values lie that
+    the cross layers read: those of the full layer before them."""
+    return _state_index(cfg)["attn"][-1]
+
+
 def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
     """The layer loop (`transformer._scan_layers`) of the programs that keep a
-    cache: `bodies[kind](x, bp, experts, cache, layer) -> (x, cache, ys)`.
+    cache: `bodies[kind](x, bp, experts, cache, layer) -> (x, cache, ys)`; x is
+    what a layer hands the next, `transformer.Carried` where that is more than
+    the residual stream (`carried`).
     `cache` is the loop's carry beside x: every kind's whole stacks, which a
     decode body reads and writes at `layer`, the layer's number within its
     kind (LAYER_STATE says which arrays are a kind's).  None for a prefill,
@@ -203,12 +220,12 @@ def _scan_blocks(bodies, x, params, cfg: TransformerConfig, cache=None):
     stacks = layer_stacks(params)
     (x, cache), outs = _scan_layers(body, (x, cache), stacks, cfg,
                                     unsliced={kind: EXPERT_MATRICES for kind, b in stacks.items() if "router" in b},
-                                    indexed=cache is not None)
+                                    indexed=cache is not None or cfg.diff_attn)
     return x, cache, outs
 
 
 def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pad=None, scale=None,
-                      also=None, seen=None):
+                      also=None, seen=None, out_dtype=None):
     """q: [B, Tq, H, D]; caches: [B, T_max, KV, D] as stored, never repeated to
     H heads and never copied to f32.  The query is viewed as [B, Tq, KV, R, D]
     (R = H // KV query heads share one cached head; R == 1 is multi-head
@@ -220,7 +237,8 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
     (q2 [B, Tq, H, D2], k2 [B, T_max, KV, D2]), a second part of every query and
     key kept in a cache of its own, whose products add to the scores.  seen:
     [B, T_max] bool, the slots a row's queries see, in place of valid_len and
-    pad (a window layer's ring: `_ring_seen`).
+    pad (a window layer's ring: `_ring_seen`).  out_dtype: the result's type
+    where it is not the queries' (`_core_dtype`).
     Returns [B, Tq, H, Dv], Dv the cached values' width."""
     b, tq, h, d = q.shape
     t_max, kv = k_cache.shape[1:3]
@@ -241,7 +259,14 @@ def _masked_attention(q, k_cache, v_cache, valid_len, cfg: TransformerConfig, pa
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache, preferred_element_type=jnp.float32)
-    return out.astype(q.dtype).reshape(b, tq, h, v_cache.shape[-1])
+    return out.astype(out_dtype or q.dtype).reshape(b, tq, h, v_cache.shape[-1])
+
+
+def _core_dtype(cfg: TransformerConfig):
+    """The type an attention core's result is asked in where it is not the
+    queries': float32 under differential attention, whose two maps' results
+    are subtracted before anything is rounded (`transformer._diff_combine`)."""
+    return jnp.float32 if cfg.diff_attn else None
 
 
 def _latent_attention(q_lat, q_rope, ckv, kr, valid_len, cfg: TransformerConfig, pad=None):
@@ -273,21 +298,26 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     the window layers' kw, vw [n_win, B, W, KV, D], W = `window_extent`: an
     extent of their own, whatever the context's length;
     a state-space layer's convolution window [n_ssm, B, K-1, C] and its h
-    [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length."""
-    kinds = cfg.layer_kinds
+    [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length.  A layer
+    that keeps no rows (NO_ROWS) adds nothing; KV and D are the heads as they
+    are cached (`cfg.cached_heads`, `cfg.cached_width`), and under
+    `cfg.flat_heads` the four stacks of keys and values are [n, B, T * KV, D]."""
+    kinds = [kind for kind in cfg.layer_kinds if kind not in NO_ROWS]
     n_ssm = kinds.count("ssm")
     n_win = sum(map(is_window, kinds))
     n_attn = len(kinds) - n_ssm - n_win
     cache = {}
+    # [T, KV, D], or flat: a slot's cached heads as rows of their own (`cfg.flat_heads`)
+    slots = lambda t: (t * cfg.flat_heads,) if cfg.flat_heads else (t, cfg.cached_heads)
     if n_win:
-        shape = (n_win, batch, window_extent(cfg, t_max), cfg.n_kv_heads, cfg.d_head)
+        shape = (n_win, batch, *slots(window_extent(cfg, t_max)), cfg.cached_width)
         cache.update(kw=jnp.zeros(shape, cfg.dtype), vw=jnp.zeros(shape, cfg.dtype))
     if n_attn and cfg.latent:
         rope = -(-cfg.qk_rope_head_dim // LATENT_LANES) * LATENT_LANES
         cache.update(ckv=jnp.zeros((n_attn, batch, t_max, cfg.kv_lora_rank), cfg.dtype),
                      kr=jnp.zeros((n_attn, batch, t_max, rope), cfg.dtype))
     elif n_attn:
-        shape = (n_attn, batch, t_max, cfg.n_kv_heads, cfg.d_head)
+        shape = (n_attn, batch, *slots(t_max), cfg.cached_width)
         cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
     if n_ssm:
         cache.update(
@@ -317,14 +347,32 @@ def recurrent_state_bytes(cache) -> int:
     return sum(int(cache[n].size) * cache[n].dtype.itemsize for n in LAYER_STATE["ssm"] if n in cache)
 
 
-def cache_bytes_per_token(cache) -> int:
+def _token_bytes(cache, kinds, cfg: Optional[TransformerConfig]) -> int:
+    """The bytes of one slot of the stacks of `kinds`, summed over their layers
+    (a flat stack holds a slot as `cfg.flat_heads` rows)."""
+    names = [n for kind in kinds for n in LAYER_STATE[kind] if n in cache]
+    heads = max(cfg.flat_heads, 1) if cfg is not None else 1
+    return sum(int(cache[n].size) * cache[n].dtype.itemsize // (cache[n].shape[1] * cache[n].shape[2])
+               for n in names) * heads
+
+
+def cache_bytes_per_token(cache, cfg: Optional[TransformerConfig] = None) -> int:
     """The bytes one token of one sequence takes in a cache over all the layers
     that attend (its keys and values, or its latent row and rotated key; in a
     window layer too, while the layer holds it: each array by its own extent).
-    0 for a cache of recurrent state alone."""
-    names = [n for kind in ("attn", "latent", "attn_win") for n in LAYER_STATE[kind] if n in cache]
-    return sum(int(cache[n].size) * cache[n].dtype.itemsize // (cache[n].shape[1] * cache[n].shape[2])
-               for n in names)
+    A layer that reads another layer's stack keeps nothing and adds nothing.
+    0 for a cache of recurrent state alone.  cfg: the cache's configuration,
+    where its stacks may be flat."""
+    return _token_bytes(cache, ("attn", "latent", "attn_win"), cfg)
+
+
+def cache_context_bytes_per_token(cache, cfg: Optional[TransformerConfig] = None) -> int:
+    """The bytes one MORE token of a sequence's context adds to a cache: the
+    part of `cache_bytes_per_token` that lies in stacks as long as the context.
+    A window layer's ring and a recurrent state are a slot's whatever its
+    context holds (`cache_kind_bytes`, `recurrent_state_bytes`).  0 for a cache
+    of rings or recurrent state alone."""
+    return _token_bytes(cache, ("attn", "latent"), cfg)
 
 
 def cache_kind_bytes(cache) -> Dict[str, int]:
@@ -335,34 +383,45 @@ def cache_kind_bytes(cache) -> Dict[str, int]:
     return {"full": size(("attn", "latent")), "window": size(("attn_win",))}
 
 
-def key_slots(cache, first=None, last=None, window: int = 0):
+def key_slots(cache, first=None, last=None, window: int = 0, cfg: Optional[TransformerConfig] = None):
     """(the slots of one layer's keys (and as many of its values, or its latent
-    rows) in a cache that attends, the window layers' part of that number).
+    rows) in a cache that attends, the window layers' part of that number, the
+    part of the one stack that `readers` layers share).
     Where the layers' extents differ (window layers keep a ring beside the full
-    layers' T_max) it is the sum over the attention layers divided by their
-    number, so one extent reads as ever.  Without first and last: every row's
+    layers' T_max) it is the sum over the layers that READ a stack divided by
+    their number, so one extent reads as ever; cfg: the cache's configuration,
+    where `cfg.shared_readers` layers read the full layers' last stack, which
+    then counts so many times, or the stacks are flat (`cfg.flat_heads` rows a
+    slot).  Without first and last: every row's
     extent, what a decode step's attention may read of a layer.  Given first
     and last (numpy [rows], the host's: the rows attend to [first, last) of
     their own, a window layer to the last `window` of those), the slots the
     step fetches: whole key blocks of those rows under the decode kernel
-    (ops/attention.py decode_rows_read; a window layer's row is one block),
+    (ops/attention.py decode_rows_read; a window layer's row is its ring),
     every slot of every row under latent attention, whose core contracts with
-    the layer whole.  (0, 0) for a cache of recurrent state alone."""
-    per_layer = {}  # the stack's name -> (its layers, the slots of one)
+    the layer whole.  (0, 0, 0) for a cache of recurrent state alone."""
+    readers, flat_heads = (cfg.shared_readers, cfg.flat_heads) if cfg is not None else (0, 0)
+    per_layer = {}  # the stack's name -> (the layers that read it, the slots of one)
     for name in ("k", "ckv", "kw"):
         if name not in cache:
             continue
         n, rows, t_max, *heads = cache[name].shape
+        if flat_heads and name != "ckv":
+            t_max, heads = t_max // flat_heads, [flat_heads]
+        if name == "k" and readers:
+            n += readers - 1  # the layer that writes the shared stack is one of its readers
         if first is None or name == "ckv":
             per_layer[name] = n, rows * t_max
         else:
             lo = np.maximum(first, last - window) if name == "kw" else first
-            per_layer[name] = n, int(decode_rows_read(lo, last, t_max, heads[0]).sum())
+            per_layer[name] = n, int(decode_rows_read(lo, last, t_max, heads[0], ring=name == "kw").sum())
     layers = sum(n for n, _ in per_layer.values())
     if not layers:
-        return 0, 0
+        return 0, 0, 0
     ring = per_layer.pop("kw", (0, 0))
-    return (sum(n * slots for n, slots in per_layer.values()) + ring[0] * ring[1]) // layers, ring[0] * ring[1] // layers
+    shared = readers * per_layer["k"][1] if readers else 0
+    return ((sum(n * slots for n, slots in per_layer.values()) + ring[0] * ring[1]) // layers,
+            ring[0] * ring[1] // layers, shared // layers)
 
 
 def _on_kernel(cache) -> bool:
@@ -382,12 +441,14 @@ def _span(cache, valid_len, pads, live, cfg: TransformerConfig):
     keys and values}: the full layers' [pads, valid_len) of T_max, the window
     layers' last `cfg.attn_window` of those, as positions, over their ring."""
     spans = {}
+    extent_heads = lambda a: (a.shape[2] // cfg.flat_heads, cfg.flat_heads) if cfg.flat_heads else a.shape[2:4]
     if "k" in cache:
-        _, _, t_max, kv, _ = cache["k"].shape
+        t_max, kv = extent_heads(cache["k"])
         spans["attn"] = decode_span(pads, valid_len, live, t_max, kv)
     if "kw" in cache:
-        _, _, extent, kv, _ = cache["kw"].shape
-        spans["attn_win"] = decode_span(jnp.maximum(pads, valid_len - cfg.attn_window), valid_len, live, extent, kv)
+        extent, kv = extent_heads(cache["kw"])
+        spans["attn_win"] = decode_span(jnp.maximum(pads, valid_len - cfg.attn_window), valid_len, live, extent, kv,
+                                        ring=True)
     return spans
 
 
@@ -422,35 +483,48 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
     (`_state_kind`: k, v, or a window layer's ring kw, vw, at pos[b] mod its
     extent), and row b attends to the slots its kind sees, through the decode
     kernel over the stacks as they lie (`_on_kernel`) or the dense contraction
-    over the layer taken out of them.  live, span, kind: `_block_decode_rowpos`'s.
-    Returns (attn [B, T, H, D], the cache after)."""
+    over the layer taken out of them.  k = v = None: a cross layer's, which
+    writes nothing and attends to the stack another layer wrote, where it lies
+    (`shared_layer`).  live, span, kind: `_block_decode_rowpos`'s.
+    Returns (attn [B, T, H, Dv], the cache after)."""
     t = q.shape[1]
     state = _state_kind(kind, cfg)
     ring = state == "attn_win"
     if ring and t > 1:
         raise NotImplementedError("a pass over a block of positions through a window layer's ring")
     k_name, v_name = LAYER_STATE[state]
-    with jax.named_scope(STATE_SCOPE[state]):
-        rows = jnp.arange(q.shape[0])
-        if t == 1:
-            at, new = (layer, rows, pos % cache[k_name].shape[2] if ring else pos), lambda a: a[:, 0]
-        else:
-            at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
-        k_all = cache[k_name].at[at].set(new(k))
-        v_all = cache[v_name].at[at].set(new(v))
+    heads = cfg.flat_heads  # the stacks are [n, B, T * KV, D]: a slot's heads are rows pos KV .. pos KV + KV - 1
+    extent = cache[k_name].shape[2] // max(heads, 1)
+    if k is None:
+        layer, k_all, v_all = shared_layer(cfg), cache[k_name], cache[v_name]
+    else:
+        with jax.named_scope(STATE_SCOPE[state]):
+            rows = jnp.arange(q.shape[0])
+            if t == 1 and heads:
+                slot = pos % extent if ring else pos
+                at, new = (layer, rows[:, None], slot[:, None] * heads + jnp.arange(heads)), lambda a: a[:, 0]
+            elif t == 1:
+                at, new = (layer, rows, pos % extent if ring else pos), lambda a: a[:, 0]
+            else:
+                at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
+            k_all = cache[k_name].at[at].set(new(k))
+            v_all = cache[v_name].at[at].set(new(v))
     if _on_kernel(cache):
         # the stacks and the layer's index: a layer's slice handed to a kernel is a copy of it
-        with jax.named_scope(core_scope(kind)):
+        with jax.named_scope(core_scope(kind, cfg)):
             attn = decode_attention(q, k_all, v_all, layer,
                                     (_span(cache, pos + t, pads, live, cfg) if span is None else span)[state],
-                                    ring=ring)
+                                    scale=cfg.d_head ** -0.5, ring=ring, out_dtype=_core_dtype(cfg), kv=heads)
     else:
         with jax.named_scope(STATE_SCOPE[state]):
             k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
-        with jax.named_scope(core_scope(kind)):
+            if heads:
+                k_layer, v_layer = (a.reshape(a.shape[0], extent, heads, a.shape[-1]) for a in (k_layer, v_layer))
+        with jax.named_scope(core_scope(kind, cfg)):
             seen = _ring_seen(jnp.maximum(pads, pos + 1 - cfg.attn_window), pos + 1,
                               k_layer.shape[1]) if ring else None
-            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads, seen=seen)  # per-row length
+            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads, seen=seen,
+                                     out_dtype=_core_dtype(cfg))  # per-row length
     return attn, {**cache, k_name: k_all, v_name: v_all}
 
 
@@ -487,22 +561,40 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
         core = functools.partial(_latent_decode_core, bp, cache, layer, pos, pads, cfg)
     else:
         core = functools.partial(_kv_decode_core, cache, layer, pos, pads, cfg, live=live, span=span, kind=kind)
-    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core, kind)
+    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core, kind, layer)
     if live is not None:
         live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
     x, _, touched = _ffn_half(bp, x, cfg, live, experts)
     return x, cache, touched
 
 
-def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None, kind: str = "attn"):
-    """One block over the whole prompt; returns padded caches [B,Tmax,KV,D]
+def _prefill_tail(cfg: TransformerConfig, kind: str) -> bool:
+    """Whether a prefill computes a layer of `kind` at the prompt's last
+    position alone.  Where the stack ends in layers that read another layer's
+    keys and values or its memory (`cfg.carries`), nothing above the full layer
+    that writes those keys and values mixes positions: the logits at the last
+    position need that layer's keys and values of every position, and its
+    queries and everything above at the last alone.  The loop goes on carrying
+    x whole (a scan's carry keeps its shape) and such a layer reads and writes
+    its last row."""
+    return kind in NO_ROWS or (kind == "attn" and cfg.shared_readers > 0)
+
+
+def _prefill_block(bp, s, pad, cfg: TransformerConfig, t_max: int, experts=None, kind: str = "attn", layer=None):
+    """One block over the whole prompt (s: x [B, T, E], or `transformer.Carried`
+    with it; `_prefill_tail` layers compute the last position alone, a cross
+    layer against the keys and values the loop carries, and the full layer
+    below them hands its own on); returns padded caches [B,Tmax,KV,D]
     (under latent attention the latents [B,Tmax,R] and rotated keys [B,Tmax,rope up to 128s];
     of a window layer, `kind`, the ring [B,W,KV,D] of its last W = `window_extent`
     columns, column j at slot j mod W, attended under the banded mask).
     pad: [B] per-row left-pad counts or None. Real tokens sit at columns
     [pad[b], T); they get RoPE positions starting at 0 and never attend to
-    pad-token keys (ADVICE r1: unmasked pads skewed generation)."""
+    pad-token keys (ADVICE r1: unmasked pads skewed generation).  layer: the
+    layer's number among its kind, where `_attention_half` asks for it."""
+    x = _x(s)
     b, t, _ = x.shape
+    tail = _prefill_tail(cfg, kind)
 
     def latent_core(q, k_rope, c_kv):
         with jax.named_scope("attn.cache"):
@@ -519,10 +611,13 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None,
 
     def stored(a):
         """a [B, T, KV, D] as the cache keeps it: from slot 0 of T_max on, or
-        the last `extent` columns round a window layer's ring."""
+        the last `extent` columns round a window layer's ring (and flat under
+        `cfg.flat_heads`: [B, extent * KV, D])."""
         if t >= extent:  # only a ring is shorter than a prompt
-            return jnp.roll(a[:, t - extent:], (t - extent) % extent, axis=1)
-        return lax.dynamic_update_slice(jnp.zeros((b, extent, *a.shape[2:]), x.dtype), a, (0, 0, 0, 0))
+            kept = jnp.roll(a[:, t - extent:], (t - extent) % extent, axis=1)
+        else:
+            kept = lax.dynamic_update_slice(jnp.zeros((b, extent, *a.shape[2:]), x.dtype), a, (0, 0, 0, 0))
+        return kept.reshape(b, -1, a.shape[-1]) if cfg.flat_heads else kept
 
     def core(q, k, v):
         with jax.named_scope("attn.cache"):
@@ -531,22 +626,43 @@ def _prefill_block(bp, x, pad, cfg: TransformerConfig, t_max: int, experts=None,
         # k/v need the GQA repeat).  On a TPU the dispatcher runs the pad-masked
         # Pallas flash kernel at every prompt length (ops/attention.py), so
         # prefill never materializes the [T, T] score matrix.
-        with jax.named_scope(core_scope(kind)):
+        with jax.named_scope(core_scope(kind, cfg)):
             k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
             # a model that generates by blocks prefills under its block mask:
             # the prompt, and with it the pad, is then a multiple of the block
             attn = attention(q, k, v, causal=True, pad=pad, block=cfg.block_length,
-                             window=cfg.attn_window * window)
-            return attn.astype(x.dtype), (k_cache, v_cache)
+                             window=cfg.attn_window * window, scale=cfg.d_head ** -0.5, out_dtype=_core_dtype(cfg))
+            return attn.astype(_core_dtype(cfg) or x.dtype), (k_cache, v_cache)
+
+    def last_core(q, k, v):
+        """The last position's queries against every position's keys and
+        values: the layer's own, which it stores and hands on, or (k = v =
+        None: a cross layer) those the loop carries."""
+        made = None
+        if k is not None:
+            with jax.named_scope("attn.cache"):
+                made = (stored(k), stored(v)), (k, v)
+        else:
+            k, v = s.k, s.v
+        with jax.named_scope(core_scope(kind, cfg)):
+            return _masked_attention(q, k, v, t, cfg, pad, out_dtype=_core_dtype(cfg)), made
 
     positions = jnp.arange(t)
     if pad is not None:
         positions = jnp.maximum(positions[None, :] - pad[:, None], 0)  # [B, T]
-    x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core, kind)
+    if tail:
+        # the last column is a prompt's last token whatever its pad
+        x_last, made = _attention_half(bp, x[:, -1:], cfg, positions[..., -1:], last_core, kind, layer,
+                                       kv_of=x if "wk" in bp else None)
+        x_last, _, touched = _ffn_half(bp, x_last, cfg, None, experts)
+        layer_cache, handed = made or (None, (s.k, s.v))
+        x = lax.dynamic_update_slice(x, x_last, (0, t - 1, 0))
+        return s._replace(x=x, k=handed[0], v=handed[1]), layer_cache, touched
+    x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core, kind, layer)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
     x, _, touched = _ffn_half(bp, x, cfg, live, experts)
-    return x, layer_cache, touched
+    return _hand_on(s, x), layer_cache, touched
 
 
 def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, experts=None):
@@ -558,7 +674,8 @@ def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, ex
     A row's position and pads do not enter: the state is all a recurrence
     knows of what came before.  An empty slot's row moves its state on like
     any other (what it holds is overwritten whole when the slot is given out:
-    `install_rows`).  Returns (x, the cache after, experts touched or None)."""
+    `install_rows`).  Returns (x, the cache after, experts touched or None, the
+    mixer's read-out y [B, 1, C] before its gate: the step's memory)."""
 
     names = LAYER_STATE["ssm"]
 
@@ -568,22 +685,22 @@ def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, ex
         y, state = _ssm_mix(bp, xs, state, cfg)
         with jax.named_scope(STATE_SCOPE["ssm"]):
             after = {n: lax.dynamic_update_index_in_dim(cache[n], new, layer, 0) for n, new in zip(names, state)}
-        return y, {**cache, **after}
+        return y, ({**cache, **after}, y)
 
-    x, cache = _ssm_half(bp, x, cfg, core)
+    x, (cache, y) = _ssm_half(bp, x, cfg, core)
     x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
-    return x, cache, touched
+    return x, cache, touched, y
 
 
 def _ssm_prefill_block(bp, x, pad, cfg: TransformerConfig, experts=None):
     """One state-space block over the whole prompt from the zero state;
     returns the state after the last token, (window [B, K-1, C], h [B, C, N]),
-    and the experts touched or None.
+    the experts touched or None, and the mixer's read-out y [B, T, C].
     pad: [B] left-pad counts or None: a pad's input and step size are zeroed, so
     the state, and the logits, are the unpadded prompt's in any bucket."""
     keep = None if pad is None else jnp.arange(x.shape[1])[None, :] >= pad[:, None]
-    x, _, layer_state, touched = _ssm_block_forward(bp, x, cfg, keep, experts)
-    return x, layer_state, touched
+    x, _, layer_state, touched, y = _ssm_block_forward(bp, x, cfg, keep, experts)
+    return x, layer_state, touched, y
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "t_max"))
@@ -602,16 +719,23 @@ def prefill_counted(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
 
-    def attn(kind, x, bp, experts, _cache, _layer):
-        x, kv, touched = _prefill_block(bp, x, pad, cfg, t_max, experts, kind)
-        return x, None, (kv, touched)
+    def attn(kind, s, bp, experts, _cache, layer):
+        s, kv, touched = _prefill_block(bp, s, pad, cfg, t_max, experts, kind, layer)
+        return s, None, (kv, touched)
 
-    def ssm(x, bp, experts, _cache, _layer):
-        x, state, touched = _ssm_prefill_block(bp, x, pad, cfg, experts)
-        return x, None, (state, touched)
+    def ssm(s, bp, experts, _cache, _layer):
+        x, state, touched, y = _ssm_prefill_block(bp, _x(s), pad, cfg, experts)
+        return _hand_on(s, x, m=y[:, -1:]), None, (state, touched)
 
-    x, _, outs = _scan_blocks(_bodies(attn, ssm), x, params, cfg)
-    rows = {kind: made for kind, (made, _) in outs.items()}
+    def gmu(s, bp, _experts, _cache, _layer):
+        # of the last position alone (`_prefill_tail`): the memory the loop carries is that position's
+        last = _gmu_block(bp, s._replace(x=s.x[:, -1:]), cfg)
+        return s._replace(x=lax.dynamic_update_slice(s.x, last.x, (0, s.x.shape[1] - 1, 0))), None, (None, None)
+
+    t = ids.shape[1]
+    x, _, outs = _scan_blocks(_bodies(attn, ssm, gmu), carried(x, cfg, 1, t), params, cfg)
+    x = _x(x)
+    rows = {kind: made for kind, (made, _) in outs.items() if kind not in NO_ROWS}
     # each kind's rows into the stacks of the state it keeps, at its layers' places there
     index = _state_index(cfg)
     cache: Dict[str, Any] = {}
@@ -638,9 +762,10 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     return prefill_counted(params, ids, cfg, t_max, pad=pad)[:2]
 
 
-def _bodies(attn, ssm):
+def _bodies(attn, ssm, gmu=None):
     """`_scan_blocks`' bodies: every attention kind's is `attn(kind, ...)`."""
-    return {kind: ssm if kind == "ssm" else functools.partial(attn, kind) for kind in _INIT_KIND}
+    own = {"ssm": ssm, "gmu": gmu}
+    return {kind: own[kind] if kind in own else functools.partial(attn, kind) for kind in _INIT_KIND}
 
 
 def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
@@ -669,13 +794,19 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     # what the attention kernel is told of the rows: made once, every layer reads the same
     span = _span(cache, pos + (tokens.shape[1] if blocks else 1), pads, live, cfg) if _on_kernel(cache) else None
 
-    def attn(kind, x, bp, experts, cache, layer):
-        return _block_decode_rowpos(bp, x, cache, layer, pos, cfg, pads, live, experts, span, kind)
+    def attn(kind, s, bp, experts, cache, layer):
+        x, cache, touched = _block_decode_rowpos(bp, _x(s), cache, layer, pos, cfg, pads, live, experts, span, kind)
+        return _hand_on(s, x), cache, touched
 
-    def ssm(x, bp, experts, cache, layer):
-        return _ssm_block_decode(bp, x, cache, layer, cfg, live, experts)
+    def ssm(s, bp, experts, cache, layer):
+        x, cache, touched, y = _ssm_block_decode(bp, _x(s), cache, layer, cfg, live, experts)
+        return _hand_on(s, x, m=y), cache, touched
 
-    x, cache, touched = _scan_blocks(_bodies(attn, ssm), x, params, cfg, cache)
+    def gmu(s, bp, _experts, cache, _layer):
+        return _gmu_block(bp, s, cfg), cache, None
+
+    x, cache, touched = _scan_blocks(_bodies(attn, ssm, gmu), carried(x, cfg, 1), params, cfg, cache)
+    x = _x(x)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None
     logits = _head(params, x, cfg).astype(jnp.float32) if blocks else _head(params, x, cfg, row=0)

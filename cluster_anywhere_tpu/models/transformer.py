@@ -44,7 +44,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -192,6 +192,23 @@ class TransformerConfig:
     # each half's OUTPUT is normed, not its input: x + norm(attn(x)), then
     # x + norm(ffn(x)); `ln1` and `ln2` are those norms' weights
     norm_output: bool = False
+    # LayerNorm (the mean subtracted, a weight and a bias) where an RMSNorm stands: every
+    # block's two norms and the final one are made with a bias beside their weight
+    # (`ln1_b`), and `_norm` norms by what it is handed; norm_eps is every norm's epsilon
+    layer_norm: bool = False
+    norm_eps: float = 1e-6
+    # a bias on the attention projections (`bq`, `bk`, `bv`, `bo` beside the matrices)
+    attn_bias: bool = False
+    # whether a state-space block is made with an RMSNorm on its step size, B and C
+    # (Jamba's three; plain Mamba-1 has none): `_ssm_mix` norms what its layer holds
+    ssm_inner_norms: bool = True
+    # differential attention (`_diff_heads`, `_diff_combine`): adjacent query heads are
+    # a pair, a pair's result is softmax(q1 k1^T) V - lambda softmax(q2 k2^T) V over the
+    # value V of both its cached heads, normed over V's width and scaled by 1 -
+    # lambda_init; lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init with four
+    # learned vectors a layer, lambda_init = 0.8 - 0.6 exp(-0.3 depth).  A pair's two
+    # cached heads are cached as one of twice the width (`cached_heads`).
+    diff_attn: bool = False
 
     def __post_init__(self):
         if self.generates_blocks and self.block_length % self.denoise_steps:
@@ -209,18 +226,42 @@ class TransformerConfig:
             raise NotImplementedError("a pass over blocks of positions through a latent cache")
         if self.layer_mixers is not None:
             object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))  # hashable whatever carried it
-            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm"})
+            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm", "gmu", "attn_cross"})
             if unknown or len(self.layer_mixers) != self.n_layers or self.attn_layer_period:
                 raise ValueError(
-                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm for each of the "
-                    f"{self.n_layers} layers, and no attn_layer_period beside it"
+                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm, gmu, attn_cross for each "
+                    f"of the {self.n_layers} layers, and no attn_layer_period beside it"
                 )
+            tail = [i for i, m in enumerate(self.layer_mixers) if m in ("gmu", "attn_cross")]
+            if tail:
+                # the second half of a decoder-hybrid-decoder: it reads what ONE full layer cached
+                # and what the state-space layer before it read out, and mixes no positions itself
+                head = self.layer_mixers[:tail[0]]
+                if (self.layer_mixers[tail[0]:].count("attn") + self.layer_mixers[tail[0]:].count("attn_win")
+                        + self.layer_mixers[tail[0]:].count("ssm") or tail != list(range(tail[0], self.n_layers))):
+                    raise NotImplementedError(
+                        f"layer_mixers={self.layer_mixers}: the layers that read another layer's keys and values "
+                        "or memory (attn_cross, gmu) are the stack's last, with no layer of another kind among them")
+                if "attn_cross" in self.layer_mixers and (head.count("attn") != 1 or head[-1] != "attn"):
+                    raise NotImplementedError("attn_cross layers read the one attn layer, which stands right below "
+                                              f"the first of them: {head.count('attn')} stand before it")
+                if "gmu" in self.layer_mixers and "ssm" not in head:
+                    raise ValueError("a gmu layer gates the read-out of a state-space layer before it: none stands there")
+                if self.latent or self.generates_blocks or self.n_experts:
+                    raise NotImplementedError("gmu and attn_cross layers under latent attention, generation by "
+                                              "blocks or a mixture of experts")
             window = "attn_win" in self.layer_mixers
             if window and not 0 < self.attn_window <= (self.attn_ring or self.attn_window):
                 raise ValueError(f"window layers see attn_window={self.attn_window} positions, "
                                  f"within attn_ring={self.attn_ring} slots")
             if window and (self.latent or self.generates_blocks):
                 raise NotImplementedError("a window layer under latent attention, or in a model that generates by blocks")
+        if self.diff_attn and (self.latent or self.generates_blocks or self.n_dense_layers or self.n_heads % 2
+                               or self.n_kv_heads % 2 or self.n_heads % self.n_kv_heads or self.rotary
+                               or self.qk_norm or self.pp > 1 or self.sp > 1):
+            raise NotImplementedError(
+                "differential attention pairs adjacent heads of plain grouped-query attention on one device: even "
+                "head counts, no latent attention, no blocks, no leading dense layers, no rotary embedding, no q/k norm")
         if self.n_dense_layers and (not self.n_experts or self.attn_layer_period
                                     or "ssm" in (self.layer_mixers or ())[:self.n_dense_layers]):
             raise NotImplementedError(
@@ -276,6 +317,52 @@ class TransformerConfig:
             mixers = ("attn",) * self.n_layers
         return tuple(m + "_dense" if i < self.n_dense_layers else m for i, m in enumerate(mixers))
 
+    @property
+    def cached_heads(self) -> int:
+        """The heads a token's keys (and values) are cached as, of `cached_width`
+        each: under differential attention a pair's two heads lie side by side as
+        one head of twice the width, which is the pair's value and meets a query
+        padded with zeros where the other head's key lies (`_diff_heads`)."""
+        return self.n_kv_heads // 2 if self.diff_attn else self.n_kv_heads
+
+    @property
+    def cached_width(self) -> int:
+        return 2 * self.d_head if self.diff_attn else self.d_head
+
+    @property
+    def flat_heads(self) -> int:
+        """The cached heads a slot of the key/value stacks holds as ROWS, where
+        the stacks are kept flat, [n, B, T * KV, D] (models/generate.py
+        init_cache); 0: they are [n, B, T, KV, D].  The decode kernel reads a
+        stack as rows of D, a slot's heads one after the other; the chip tiles
+        an array's last two axes by (8, 128), so [T, KV, D] is those rows as
+        they lie only where KV is a multiple of 8, and anything else is copied
+        whole into that shape at every layer of every step.  Differential
+        attention caches 10 pairs at Phi-4-mini-flash's widths: its stacks are
+        the rows themselves.  The switch is the configuration served with such
+        a count, not the count: the other configurations' stacks keep the
+        layout their programs were measured with (tests/test_program_text.py)."""
+        return self.cached_heads if self.diff_attn else 0
+
+    @property
+    def carries(self) -> bool:
+        """Whether a layer hands the next more than x (`Carried`): some layer
+        reads another layer's keys and values, or its memory."""
+        return any(m in ("gmu", "attn_cross") for m in self.layer_mixers or ())
+
+    @property
+    def shared_readers(self) -> int:
+        """The layers that read the one stack of keys and values the cross
+        layers share: they and the layer that writes it.  0: no layer shares."""
+        cross = (self.layer_mixers or ()).count("attn_cross")
+        return cross + 1 if cross else 0
+
+    def lambda_inits(self, kind: str) -> Tuple[float, ...]:
+        """Differential attention's lambda_init = 0.8 - 0.6 exp(-0.3 depth) of
+        each layer of `kind`, in their order: depth is the layer's index in
+        the model."""
+        return tuple(0.8 - 0.6 * math.exp(-0.3 * i) for i, k in enumerate(self.layer_kinds) if k == kind)
+
     def rotates(self, kind: str) -> bool:
         """Whether a layer of `kind` turns its queries and keys."""
         return self.rotary and (self.rotary_full or is_window(kind))
@@ -310,6 +397,15 @@ def _yarn_mscale(factor: float, m: float) -> float:
 # ---------------------------------------------------------------------------
 
 
+def _init_norm(name: str, cfg: TransformerConfig, key=None):
+    """A block's norm over d_model (or the final one): its weight, and under
+    `cfg.layer_norm` a bias `<name>_b` drawn small from `key`."""
+    out = {name: jnp.ones((cfg.d_model,), cfg.param_dtype)}
+    if cfg.layer_norm:
+        out[name + "_b"] = jax.random.normal(key, (cfg.d_model,), cfg.param_dtype) * 0.02
+    return out
+
+
 def _init_ffn(ks, cfg: TransformerConfig):
     """A block's second half from three keys: its norm and the dense SwiGLU
     matrices, or the experts behind their router."""
@@ -319,7 +415,7 @@ def _init_ffn(ks, cfg: TransformerConfig):
         from ..parallel.moe import init_moe_params
 
         fx = cfg.d_expert or f
-        out = {"ln2": jnp.ones((e,), pd),
+        out = {**_init_norm("ln2", cfg, jax.random.fold_in(ks[0], 1)),
                **init_moe_params(ks[0], e, fx, cfg.n_experts, pd, gated=cfg.moe_gated,
                                  held=cfg.experts_held and cfg.experts_held[1])}
         if cfg.n_shared_experts:
@@ -331,14 +427,16 @@ def _init_ffn(ks, cfg: TransformerConfig):
             )
         return out
     return {
-        "ln2": jnp.ones((e,), pd),
+        **_init_norm("ln2", cfg, jax.random.fold_in(ks[0], 1)),
         "w_gate": jax.random.normal(ks[0], (e, f), pd) * s(e),
         "w_up": jax.random.normal(ks[1], (e, f), pd) * s(e),
         "w_down": jax.random.normal(ks[2], (f, e), pd) * s(f),
     }
 
 
-def _init_block(key, cfg: TransformerConfig):
+def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True):
+    """An attention block; `keys_and_values` False: one that projects queries
+    alone and reads another layer's keys and values (kind "attn_cross")."""
     e, h, kv, d = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     ks = jax.random.split(key, 7)
     s = lambda fan_in: fan_in ** -0.5
@@ -348,7 +446,7 @@ def _init_block(key, cfg: TransformerConfig):
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
         ka = jax.random.split(ks[0], 5)
         out = {
-            "ln1": jnp.ones((e,), pd),
+            **_init_norm("ln1", cfg, jax.random.fold_in(key, 1)),
             "wq_a": jax.random.normal(ka[0], (e, rq), pd) * s(e),
             "q_a_norm": jnp.ones((rq,), pd),
             "wq_b": jax.random.normal(ka[1], (rq, h * (dn + dr)), pd) * s(rq),
@@ -360,12 +458,22 @@ def _init_block(key, cfg: TransformerConfig):
         out.update(_init_ffn(ks[4:], cfg))
         return out
     out = {
-        "ln1": jnp.ones((e,), pd),
+        **_init_norm("ln1", cfg, jax.random.fold_in(key, 1)),
         "wq": jax.random.normal(ks[0], (e, h * d), pd) * s(e),
-        "wk": jax.random.normal(ks[1], (e, kv * d), pd) * s(e),
-        "wv": jax.random.normal(ks[2], (e, kv * d), pd) * s(e),
         "wo": jax.random.normal(ks[3], (h * d, e), pd) * s(h * d),
     }
+    if keys_and_values:
+        out.update(wk=jax.random.normal(ks[1], (e, kv * d), pd) * s(e),
+                   wv=jax.random.normal(ks[2], (e, kv * d), pd) * s(e))
+    if cfg.attn_bias:
+        widths = {"bq": h * d, "bk": kv * d, "bv": kv * d, "bo": e}
+        out.update({b: jax.random.normal(jax.random.fold_in(key, 2 + i), (n,), pd) * 0.02
+                    for i, (b, n) in enumerate(widths.items()) if "w" + b[1] in out})
+    if cfg.diff_attn:
+        # the four vectors lambda is made of, drawn N(0, 0.1), and the weight of the norm over a pair's result
+        out.update({name: jax.random.normal(jax.random.fold_in(key, 6 + i), (d,), pd) * 0.1
+                    for i, name in enumerate(("lq1", "lk1", "lq2", "lk2"))})
+        out["subln"] = jnp.ones((2 * d,), pd)
     if cfg.qk_norm:
         per_head = cfg.qk_norm_per_head
         out.update({"q_norm": jnp.ones((d if per_head else h * d,), pd),
@@ -375,7 +483,8 @@ def _init_block(key, cfg: TransformerConfig):
 
 
 def _init_ssm_block(key, cfg: TransformerConfig):
-    """A state-space block (Mamba-1 with Jamba's three inner norms), initialised
+    """A state-space block (Mamba-1, with Jamba's three inner norms under
+    `cfg.ssm_inner_norms`), initialised
     as Mamba is: A = -(1..N) in every channel, the step size's bias the inverse
     softplus of a step log-uniform in [1e-3, 1e-1], D = 1."""
     e, c, n, r, kw = cfg.d_model, cfg.d_inner, cfg.ssm_d_state, cfg.ssm_dt_rank, cfg.ssm_d_conv
@@ -385,13 +494,12 @@ def _init_ssm_block(key, cfg: TransformerConfig):
     k_dt, k_bias = jax.random.split(ks[3])
     step = jnp.exp(jax.random.uniform(k_bias, (c,)) * (jnp.log(1e-1) - jnp.log(1e-3)) + jnp.log(1e-3))
     out = {
-        "ln1": jnp.ones((e,), pd),
+        **_init_norm("ln1", cfg, ks[6]),
         "ssm_in": jax.random.normal(ks[0], (e, 2 * c), pd) * s(e),
         "conv_w": jax.random.normal(ks[1], (kw, c), pd) * s(kw),
         "ssm_x": jax.random.normal(ks[2], (c, r + 2 * n), pd) * s(c),
-        "dt_norm": jnp.ones((r,), pd),
-        "b_norm": jnp.ones((n,), pd),
-        "c_norm": jnp.ones((n,), pd),
+        **({"dt_norm": jnp.ones((r,), pd), "b_norm": jnp.ones((n,), pd), "c_norm": jnp.ones((n,), pd)}
+           if cfg.ssm_inner_norms else {}),
         "ssm_dt": jax.random.normal(k_dt, (r, c), pd) * s(r),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
         "a_log": jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)), (c, n)).astype(pd),
@@ -409,16 +517,32 @@ def _init_dense_block(key, cfg: TransformerConfig):
     return _init_block(key, dataclasses.replace(cfg, n_experts=0, n_dense_layers=0, experts_held=None))
 
 
+def _init_gmu_block(key, cfg: TransformerConfig):
+    """A gated memory unit's block: its norm, the gate's projection up to the
+    memory's width and the projection back down."""
+    e, c, pd = cfg.d_model, cfg.d_inner, cfg.param_dtype
+    ks = jax.random.split(key, 4)
+    return {
+        **_init_norm("ln1", cfg, ks[3]),
+        "gmu_in": jax.random.normal(ks[0], (e, c), pd) * e ** -0.5,
+        "gmu_out": jax.random.normal(ks[1], (c, e), pd) * c ** -0.5,
+        **_init_ffn(jax.random.split(ks[2], 3), cfg),
+    }
+
+
 _INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block),
               "attn_dense": ("dense_blocks", _init_dense_block),
-              "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _init_dense_block)}
+              "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _init_dense_block),
+              "gmu": ("gmu_blocks", _init_gmu_block),
+              "attn_cross": ("cross_blocks", functools.partial(_init_block, keys_and_values=False))}
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     """`blocks`: the attention layers' parameters stacked [n, ...]; `ssm_blocks`:
     the state-space layers', where the pattern has any; `dense_blocks`: a
     mixture's leading dense layers'; `win_blocks`, `win_dense_blocks`: the
-    window layers' of either FFN (`_INIT_KIND`: a stack a kind).  Layer i's
+    window layers' of either FFN; `gmu_blocks`, `cross_blocks`: the gated memory
+    units' and the cross layers' (`_INIT_KIND`: a stack a kind).  Layer i's
     key is the i-th of one split whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
@@ -437,7 +561,7 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
         out["blocks"] = jax.tree_util.tree_map(
             lambda x: x.reshape(cfg.pp, cfg.layers_per_stage, *x.shape[1:]), out["blocks"]
         )
-    out["ln_f"] = jnp.ones((cfg.d_model,), cfg.param_dtype)
+    out.update(_init_norm("ln_f", cfg, jax.random.fold_in(k_head, 1)))
     if not cfg.tie_embeddings:
         out["lm_head"] = (
             jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size), cfg.param_dtype)
@@ -522,6 +646,25 @@ def shard_params(params, cfg: TransformerConfig, mesh):
 def _rms_norm(x, w, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * lax.rsqrt(var + eps).astype(x.dtype)) * w.astype(x.dtype)
+
+
+def _norm(x, bp, name: str, cfg: TransformerConfig):
+    """x normed over its last axis by the norm `name` of `bp`, whatever that
+    is: an RMSNorm where `bp` holds a weight `name`, a LayerNorm in float32
+    ((x - mean) / sqrt(var + eps) * w + b) where it holds a bias `<name>_b`
+    beside it, and x itself where it holds neither (a state-space layer made
+    without the inner norms).  The epsilon is the configuration's.  Every
+    block's norms, the inner ones and the final one go through here, so no
+    call site chooses."""
+    if name not in bp:
+        return x
+    if name + "_b" not in bp:
+        return _rms_norm(x, bp[name], cfg.norm_eps)
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    out = xf * lax.rsqrt(var + cfg.norm_eps) * bp[name].astype(jnp.float32) + bp[name + "_b"].astype(jnp.float32)
+    return out.astype(x.dtype)
 
 
 def _rope_freqs(cfg: TransformerConfig):
@@ -611,28 +754,69 @@ def _project_heads(y, w):
     return out
 
 
-def _project_qkv(bp, y, cfg: TransformerConfig):
+def _project_qkv(bp, y, cfg: TransformerConfig, y_kv=None):
     """q [B, T, H, D], k and v [B, T, KV, D] of one block from its normed
-    input y [B, T, E]: the three projections and, with `cfg.qk_norm`, the
+    input y [B, T, E]: the three projections (each with its bias where the
+    block holds one) and, with `cfg.qk_norm`, the
     RMSNorm of q and k: over the whole projected vector, or over each head's
     own d_head (`cfg.qk_norm_per_head`).  The one place every forward, prefill
     and decode block projects.  The reshape to heads stays outside the product
     (`_project_heads`): folded into it, a decode step copies wq, wk and wv into
-    another layout at every layer instead of reading them where they are stored."""
-    b, t, _ = y.shape
+    another layout at every layer instead of reading them where they are stored.
+    y_kv [B, T_kv, E]: the rows k and v are made of where they are not the
+    queries' (a prefill that asks at its last position alone).  A block that
+    holds no wk (a cross layer) gives k = v = None."""
     d = cfg.d_head
 
-    def project(w, heads, norm=None):
-        out = _project_heads(y, bp[w])
+    def project(src, w, heads, norm=None):
+        out = _project_heads(src, bp[w])
+        if "b" + w[1] in bp:
+            out = out + bp["b" + w[1]].astype(out.dtype)
         if norm is not None and cfg.qk_norm and not cfg.qk_norm_per_head:
             out = _rms_norm(out, bp[norm])
-        out = out.reshape(b, t, heads, d)
+        out = out.reshape(*src.shape[:2], heads, d)
         if norm is not None and cfg.qk_norm and cfg.qk_norm_per_head:
             out = _rms_norm(out, bp[norm])
         return out
 
-    return (project("wq", cfg.n_heads, "q_norm"), project("wk", cfg.n_kv_heads, "k_norm"),
-            project("wv", cfg.n_kv_heads))
+    q = project(y, "wq", cfg.n_heads, "q_norm")
+    if "wk" not in bp:
+        return q, None, None
+    y_kv = y if y_kv is None else y_kv
+    return q, project(y_kv, "wk", cfg.n_kv_heads, "k_norm"), project(y_kv, "wv", cfg.n_kv_heads)
+
+
+def _diff_heads(q, k, v):
+    """Differential attention's q, k, v as a plain grouped-query core takes
+    them.  A pair's two cached heads side by side are one cached head of twice
+    the width, [B, T, KV / 2, 2 D] (a view of the rows as projected): as a value
+    it is the pair's V, and as a key it gives q1 . k1 to a query padded with
+    zeros over its second half and q2 . k2 to one padded over its first.  So
+    q [B, T, H, D] becomes [B, T, H, 2 D], head h in half h mod 2, and the core's
+    result at head h is softmax(q_h k_(h mod 2)^T) V: both maps of every pair,
+    each over the whole V, from kernels that know nothing of pairs.  k, v None
+    (a cross layer's) stay None."""
+    b, t, h, d = q.shape
+    half = jnp.arange(h)[:, None] % 2 == jnp.arange(2)[None, :]  # [H, 2]
+    q = jnp.where(half[:, :, None], q[:, :, :, None, :], 0).reshape(b, t, h, 2 * d)
+    pair = lambda a: None if a is None else a.reshape(*a.shape[:2], a.shape[2] // 2, 2 * d)
+    return q, pair(k), pair(v)
+
+
+def _diff_combine(bp, attn, lambda_init, cfg: TransformerConfig):
+    """attn [B, T, H, 2 D] float32, head 2p the pair's first map over V and
+    head 2p + 1 its second -> [B, T, H / 2, 2 D] in cfg.dtype: A1 V - lambda A2 V,
+    an RMSNorm over the pair's 2 D (weight `subln`), times 1 - lambda_init.
+    All of it in float32: the two results are close, and what is left of them
+    is normed."""
+    f = jnp.float32
+    b, t, h, w = attn.shape
+    dot = lambda a, c: jnp.sum(bp[a].astype(f) * bp[c].astype(f))
+    lam = jnp.exp(dot("lq1", "lk1")) - jnp.exp(dot("lq2", "lk2")) + lambda_init
+    maps = attn.astype(f).reshape(b, t, h // 2, 2, w)
+    out = maps[:, :, :, 0] - lam * maps[:, :, :, 1]
+    out = _norm(out, bp, "subln", cfg) * (1.0 - lambda_init)
+    return out.astype(cfg.dtype)
 
 
 def _project_latent(bp, y, cfg: TransformerConfig, positions):
@@ -728,6 +912,8 @@ def _per_shard(attn_fn, mesh, manual_axes=frozenset()):
 
 
 def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes, window: int = 0):
+    """The training core: [B, T, H, D] each.  Under differential attention the
+    result is float32 (`_diff_combine` subtracts before it rounds)."""
     impl = cfg.resolved_attn()
     over_sp = impl in ("ring", "ulysses") and "sp" in manual_axes
     if over_sp and (cfg.generates_blocks or window):
@@ -737,19 +923,19 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes, window: int =
         fn = functools.partial(ring_attention if impl == "ring" else ulysses_attention, axis_name="sp", causal=True)
     else:  # dense: the dispatcher picks by backend
         fn = functools.partial(dense_attention, causal=True, block=cfg.block_length, scale=cfg.attn_scale,
-                               window=window)
+                               window=window, out_dtype=jnp.float32 if cfg.diff_attn else None)
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
 def _gqa_repeat(x, cfg: TransformerConfig):
     """k or v [B, T, KV, D] repeated to the query's H heads, for a core that
     wants them alike (the flash kernel, ring, Ulysses)."""
-    if cfg.n_kv_heads != cfg.n_heads:
-        x = jnp.repeat(x, cfg.n_heads // cfg.n_kv_heads, axis=2)
+    if x.shape[2] != cfg.n_heads:
+        x = jnp.repeat(x, cfg.n_heads // x.shape[2], axis=2)
     return x
 
 
-def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = "attn"):
+def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = "attn", layer=None, kv_of=None):
     """A block's first half, for training, prefill and decode alike:
     x + wo(core(rope(qkv(norm(x))))), the rotary embedding where the
     configuration has one for a layer of `kind` (`cfg.rotates`); under
@@ -759,29 +945,44 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = 
     hands back what its caller keeps of k and v.  Returns (x, extra).
     Latent attention (`cfg.latent`) makes them its own way: the core is given
     the query, the shared rotated key and the latent (`_project_latent`).
+    Differential attention (`cfg.diff_attn`) gives the core `_diff_heads` of
+    them and makes of its float32 result `_diff_combine` under scope
+    `attn.diff`; `layer` is then the layer's number among its kind, by which
+    its lambda_init is found.  kv_of [B, T_kv, E]: the rows whose keys and
+    values the layer makes where they are more than x's (`_project_qkv`).  A
+    cross layer's core is given k = v = None and brings another layer's.
 
     The scope names are the same in every layer and every program: a device
     trace sums a kind of work over the depth (forward, recomputation and
     backward carry the name; metadata only)."""
     b, t, _ = x.shape
-    y = x
+    y, y_kv = x, kv_of
     if not cfg.norm_output:
         with jax.named_scope("norm"):
-            y = _rms_norm(x, bp["ln1"])
+            y = _norm(x, bp, "ln1", cfg)
+            if kv_of is not None:
+                y_kv = _norm(kv_of, bp, "ln1", cfg)
     if cfg.latent:
         q, k, v = _project_latent(bp, y, cfg, positions)
     else:
         with jax.named_scope("attn.qkv"):
-            q, k, v = _project_qkv(bp, y, cfg)
+            q, k, v = _project_qkv(bp, y, cfg, y_kv)
+            if cfg.diff_attn:
+                q, k, v = _diff_heads(q, k, v)
         if cfg.rotates(kind):
             with jax.named_scope("attn.rope"):
                 q, k = _rope(q, k, positions, cfg)
     attn, extra = core(q, k, v)
+    if cfg.diff_attn:
+        with jax.named_scope("attn.diff"):
+            attn = _diff_combine(bp, attn, jnp.asarray(cfg.lambda_inits(kind), jnp.float32)[layer], cfg)
     with jax.named_scope("attn.out"):
         out = attn.reshape(b, t, -1) @ bp["wo"].astype(x.dtype)
+        if "bo" in bp:
+            out = out + bp["bo"].astype(x.dtype)
     if cfg.norm_output:
         with jax.named_scope("norm"):
-            out = _rms_norm(out, bp["ln1"])
+            out = _norm(out, bp, "ln1", cfg)
     return x + out, extra
 
 
@@ -863,7 +1064,7 @@ def _ssm_mix(bp, xs, state, cfg: TransformerConfig, keep=None):
     with jax.named_scope("ssm.scan"):
         low = xc @ bp["ssm_x"].astype(xs.dtype)
         step, b, c = (
-            _rms_norm(part, bp[w]) for part, w in zip(
+            _norm(part, bp, w, cfg) for part, w in zip(
                 jnp.split(low, (r, r + n), axis=-1), ("dt_norm", "b_norm", "c_norm"))
         )
         dt = (step @ bp["ssm_dt"].astype(xs.dtype)).astype(f) + bp["dt_bias"].astype(f)
@@ -894,7 +1095,7 @@ def _ssm_half(bp, x, cfg: TransformerConfig, core, keep=None):
     at a left pad, whose xs are zeroed here so that the convolution sees what
     an unpadded prompt sees before its start.  Returns (x, extra)."""
     with jax.named_scope("norm"):
-        u = _rms_norm(x, bp["ln1"])
+        u = _norm(x, bp, "ln1", cfg)
     with jax.named_scope("ssm.in"):
         xs, z = jnp.split(u @ bp["ssm_in"].astype(x.dtype), 2, axis=-1)
         if keep is not None:
@@ -949,37 +1150,111 @@ def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axe
     if cfg.norm_output:
         out, aux, touched = _ffn(bp, x, cfg, live, experts, manual_axes)
         with jax.named_scope("norm"):
-            return x + _rms_norm(out, bp["ln2"]), aux, touched
+            return x + _norm(out, bp, "ln2", cfg), aux, touched
     with jax.named_scope("norm"):
-        y = _rms_norm(x, bp["ln2"])
+        y = _norm(x, bp, "ln2", cfg)
     out, aux, touched = _ffn(bp, y, cfg, live, experts, manual_axes)
     return x + out, aux, touched
 
 
-def core_scope(kind: str) -> str:
-    """The scope a layer of `kind` attends under: a window layer's beside the others'."""
-    return "attn.core.window" if is_window(kind) else "attn.core"
+def core_scope(kind: str, cfg: Optional[TransformerConfig] = None) -> str:
+    """The scope a layer of `kind` attends under: a window layer's beside the
+    others'; and where layers share one stack of keys and values
+    (`cfg.shared_readers`), by the stack a layer reads: the rings, the full
+    layer that writes the stack, the cross layers that read it."""
+    if is_window(kind):
+        return "attn.core.window"
+    if cfg is not None and cfg.shared_readers:
+        return "attn.core.cross" if kind == "attn_cross" else "attn.core.full"
+    return "attn.core"
 
 
-def _block_forward(bp, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), kind: str = "attn"):
-    """One transformer block. x: [B, T_local, E].  manual_axes: the mesh axes
-    the caller's shard_map is already manual over (pp/sp/ep subset); kind: the
-    layer's (a window layer attends to its window: forward only on a TPU).
-    Returns (x, the MoE load-balance loss: 0 dense)."""
+class Carried(NamedTuple):
+    """What a layer hands the next where that is more than x (`cfg.carries`):
+    the layer loop's carry in x's place, every field of one shape from the
+    first layer to the last (a scan's carry keeps its type), zeros until the
+    layer that makes it.  A model whose layers hand on x alone carries x itself."""
+
+    x: Any
+    # the memory the gated memory units gate: the newest state-space layer's read-out y
+    # before its own gate, [B, T, C] (a prefill and a decode step: its last position, [B, 1, C])
+    m: Any = None
+    # the keys and values the cross layers read, as `_diff_heads` gives them, in a program that
+    # keeps no cache for them to lie in (training's forward; a prefill, over its bucket)
+    k: Any = None
+    v: Any = None
+
+
+def carried(x, cfg: TransformerConfig, t_m: int, t_kv: int = 0):
+    """x [B, T, E] as the layer loop carries it: itself, or under
+    `cfg.carries` `Carried` with zeros for a memory of t_m positions and, where
+    t_kv, for shared keys and values of t_kv positions."""
+    if not cfg.carries:
+        return x
+    b = x.shape[0]
+    kv = jnp.zeros((b, t_kv, cfg.cached_heads, cfg.cached_width), cfg.dtype) if t_kv else None
+    return Carried(x, jnp.zeros((b, t_m, cfg.d_inner), cfg.dtype), kv, kv)
+
+
+def _x(s):
+    """The residual stream of what a layer is handed (`Carried`, or x itself)."""
+    return s.x if isinstance(s, Carried) else s
+
+
+def _hand_on(s, x, **made):
+    """What a layer hands the next: x, and in a `Carried` what it `made` beside it."""
+    return s._replace(x=x, **made) if isinstance(s, Carried) else x
+
+
+def _gmu_half(bp, x, m, cfg: TransformerConfig):
+    """A gated memory unit's first half: x + w_out(m * silu(w_in(norm(x)))), m
+    [B, T, C] the memory of x's own positions (`Carried.m`).  It mixes no
+    positions and keeps nothing between two tokens.  x: [B, T, E]."""
+    with jax.named_scope("norm"):
+        u = _norm(x, bp, "ln1", cfg)
+    with jax.named_scope("gmu.in"):
+        gate = u @ bp["gmu_in"].astype(x.dtype)
+    with jax.named_scope("gmu.gate"):
+        gated = m.astype(x.dtype) * jax.nn.silu(gate)
+    with jax.named_scope("gmu.out"):
+        return x + gated @ bp["gmu_out"].astype(x.dtype)
+
+
+def _gmu_block(bp, s, cfg: TransformerConfig, live=None):
+    """One gated-memory block of every program, over what the loop carries:
+    x's positions are the memory's.  live: `_ffn_half`'s."""
+    x = _gmu_half(bp, s.x, s.m, cfg)
+    x, _, _ = _ffn_half(bp, x, cfg, live)
+    return s._replace(x=x)
+
+
+def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), kind: str = "attn",
+                   layer=None):
+    """One transformer block. s: x [B, T_local, E], or `Carried`.  manual_axes:
+    the mesh axes the caller's shard_map is already manual over (pp/sp/ep
+    subset); kind: the layer's (a window layer attends to its window: forward
+    only on a TPU; a cross layer to the keys and values the loop carries, which
+    the full layer before it left there); layer: its number among its kind.
+    Returns (what it hands on, the MoE load-balance loss: 0 dense)."""
+    x = _x(s)
     t = x.shape[1]
     offset = lax.axis_index("sp") * t if "sp" in manual_axes and cfg.sp > 1 else 0
 
     def core(q, k, v):
-        with jax.named_scope(core_scope(kind)):
+        with jax.named_scope(core_scope(kind, cfg)):
+            if k is None:
+                k, v = s.k, s.v
+            made = (k, v)
             if cfg.latent:
                 k, v = _latent_expand(bp, k, v, cfg)
             else:
                 k, v = _gqa_repeat(k, cfg), _gqa_repeat(v, cfg)
-            return _attention(q, k, v, cfg, mesh, manual_axes, cfg.attn_window * is_window(kind)), None
+            return _attention(q, k, v, cfg, mesh, manual_axes, cfg.attn_window * is_window(kind)), made
 
-    x, _ = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind)
+    x, (k, v) = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind, layer)
     x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
-    return x, jnp.zeros((), jnp.float32) if aux is None else aux
+    made = dict(k=k, v=v) if kind == "attn" else {}
+    return _hand_on(s, x, **made), jnp.zeros((), jnp.float32) if aux is None else aux
 
 
 def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
@@ -987,11 +1262,16 @@ def _ssm_block_forward(bp, x, cfg: TransformerConfig, keep=None, experts=None):
     training and for a prompt's prefill.  keep: [B, T] bool, False at a left
     pad, None for none (`_ssm_half`); experts: `_ffn_half`'s.  Returns (x, the
     MoE load-balance loss: 0 dense, the state after the last position, experts
-    touched or None)."""
-    core = lambda xs: _ssm_mix(bp, xs, _ssm_zero_state(cfg, x.shape[0]), cfg, keep)
-    x, state = _ssm_half(bp, x, cfg, core, keep)
+    touched or None, the mixer's read-out y [B, T, C] before its gate: what a
+    gated memory unit further up gates)."""
+
+    def core(xs):
+        y, state = _ssm_mix(bp, xs, _ssm_zero_state(cfg, x.shape[0]), cfg, keep)
+        return y, (state, y)
+
+    x, (state, y) = _ssm_half(bp, x, cfg, core, keep)
     x, aux, touched = _ffn_half(bp, x, cfg, keep, experts)
-    return x, jnp.zeros((), jnp.float32) if aux is None else aux, state, touched
+    return x, jnp.zeros((), jnp.float32) if aux is None else aux, state, touched, y
 
 
 def layer_stacks(params) -> Dict[str, Any]:
@@ -1002,21 +1282,37 @@ def layer_stacks(params) -> Dict[str, Any]:
 
 def _layer_runs(kinds):
     """[(kind, the run's first layer counted among its kind, its length)] for
-    each maximal run of one kind: 7 ssm, attn, 13 ssm, attn, 6 ssm."""
-    runs, seen = [], {}
-    for kind in kinds:
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, seen.get(kind, 0), 1])
-        seen[kind] = seen.get(kind, 0) + 1
-    return [tuple(r) for r in runs]
+    each maximal run of one kind: 7 ssm, attn, 13 ssm, attn, 6 ssm.  Where
+    kinds alternate (a layer's kind is not the next one's) and a period of
+    distinct kinds comes at least twice in a row, the run is the period:
+    ((its kinds), (each one's first layer among its kind), the repetitions);
+    [ssm, attn_win] x 8, ssm, attn, [gmu, attn_cross] x 7 is four runs, not 32."""
+    runs, seen, i = [], {}, 0
+    while i < len(kinds):
+        period, n = (kinds[i],), 1
+        while kinds[i + n:i + n + 1] == period:
+            n += 1
+        if n == 1:
+            for p in range(2, len(set(kinds)) + 1):
+                unit = tuple(kinds[i:i + p])
+                if len(set(unit)) == p and tuple(kinds[i + p:i + 2 * p]) == unit:
+                    period = unit
+                    while tuple(kinds[i + n * p:i + (n + 1) * p]) == unit:
+                        n += 1
+                    break
+        starts = tuple(seen.get(kind, 0) for kind in period)
+        runs.append((period[0], starts[0], n) if len(period) == 1 else (period, starts, n))
+        for kind in period:
+            seen[kind] = seen.get(kind, 0) + n
+        i += n * len(period)
+    return runs
 
 
 def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unroll=1, indexed=False):
     """The layer loop of every program: each maximal run of one kind of layer
     is one `lax.scan` of `body(kind, carry, bp, held, layer) -> (carry, ys)`;
-    a model of one kind is one run.  stacks: `layer_stacks`.  Returns
+    a model of one kind is one run, and a period of alternating kinds that
+    repeats is one scan of the period, the body once a kind (`_layer_runs`).  stacks: `layer_stacks`.  Returns
     (carry, {kind: ys over that kind's layers}).
 
     What a program keeps from one call to the next (a cache) is part of
@@ -1038,24 +1334,36 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     asked for it."""
     kinds = cfg.layer_kinds
     outs: Dict[str, list] = {}
-    for kind, start, n in _layer_runs(kinds):
-        blocks = stacks[kind]
-        total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        if len(set(kinds)) == 1:
-            n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
-        held = {k: blocks[k] for k in (unsliced or {}).get(kind, ()) if k in blocks}
-        rest = {k: v for k, v in blocks.items() if k not in held}
+    for period, starts, n in _layer_runs(kinds):
+        if isinstance(period, str):
+            period, starts = (period,), (starts,)
+        members, xs = [], {}  # a kind of the period: (kind, held, rest); its layers' parameters or indices
+        for j, (kind, start) in enumerate(zip(period, starts)):
+            blocks = stacks[kind]
+            total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+            if len(set(kinds)) == 1:
+                n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
+            held = {k: blocks[k] for k in (unsliced or {}).get(kind, ()) if k in blocks}
+            rest = {k: v for k, v in blocks.items() if k not in held}
+            members.append((kind, held, rest))
+            if n == total:
+                xs[f"bp{j}"] = rest
+            if held or indexed or n != total:
+                xs[f"layer{j}"] = jnp.arange(start, start + n)
 
-        def step(carry, xs, kind=kind, held=held, rest=rest):
-            layer = xs.get("layer")
-            bp = xs["bp"] if "bp" in xs else jax.tree_util.tree_map(lambda w: w[layer], rest)
-            return body(kind, carry, bp, held, layer)
+        def step(carry, xs, members=members):
+            """One layer, or one period: a layer of each of its kinds, in their order."""
+            ys = []
+            for j, (kind, held, rest) in enumerate(members):
+                layer = xs.get(f"layer{j}")
+                bp = xs[f"bp{j}"] if f"bp{j}" in xs else jax.tree_util.tree_map(lambda w: w[layer], rest)
+                carry, y = body(kind, carry, bp, held, layer)
+                ys.append(y)
+            return carry, tuple(ys)
 
-        xs = {"bp": rest} if n == total else {}
-        if held or indexed or n != total:
-            xs["layer"] = jnp.arange(start, start + n)
         carry, ys = lax.scan(step, carry, xs, unroll=unroll)
-        outs.setdefault(kind, []).append(ys)
+        for kind, y in zip(period, ys):
+            outs.setdefault(kind, []).append(y)
     join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
     return carry, {kind: jax.tree_util.tree_map(join, *runs) for kind, runs in outs.items()}
 
@@ -1067,22 +1375,29 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
     # an attention kind's block is the same whatever its FFN: that is what its weights hold
     blocks = {
         kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind)
-        for kind in _INIT_KIND if kind != "ssm"
+        for kind in _INIT_KIND if kind not in ("ssm", "gmu")
     }
-    blocks["ssm"] = lambda bp, x: _ssm_block_forward(bp, x, cfg)[:2]
+
+    def ssm(bp, s, layer=None):
+        x, aux, _, _, y = _ssm_block_forward(bp, _x(s), cfg)
+        return _hand_on(s, x, m=y), aux
+
+    blocks["ssm"] = ssm
+    blocks["gmu"] = lambda bp, s, layer=None: (_gmu_block(bp, s, cfg), jnp.zeros((), jnp.float32))
     if cfg.remat:
         blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
 
-    def body(kind, carry, bp, _held, _layer):
+    def body(kind, carry, bp, _held, layer):
         x, aux = carry
-        x, a = blocks[kind](bp, x)
+        x, a = blocks[kind](bp, x, layer=layer)
         return (x, aux + a), None
 
+    t = x.shape[1]
     (x, aux), _ = _scan_layers(
-        body, (x, jnp.zeros((), jnp.float32)), stacks, cfg,
-        unroll=True if cfg.unroll_layers else 1,
+        body, (carried(x, cfg, t, t), jnp.zeros((), jnp.float32)), stacks, cfg,
+        unroll=True if cfg.unroll_layers else 1, indexed=cfg.diff_attn,
     )
-    return x, aux
+    return _x(x), aux
 
 
 def _head(params, x, cfg: TransformerConfig, row=None):
@@ -1091,7 +1406,7 @@ def _head(params, x, cfg: TransformerConfig, row=None):
     as a sampler takes them.  A tied head is the embedding, contracted over its
     own width."""
     with jax.named_scope("norm"):
-        x = _rms_norm(x, params["ln_f"])
+        x = _norm(x, params, "ln_f", cfg)
     with jax.named_scope("head"):
         if row is not None:
             x = x[:, row]

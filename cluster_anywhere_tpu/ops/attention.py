@@ -317,7 +317,7 @@ WINDOW_KERNEL = "swa_flash"
 WINDOW_BLOCK = 128
 
 
-def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0, window=0):
+def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_block=0, window=0, out_dtype=None):
     b, t, h, d = q.shape
     t_kv, dv = k.shape[1], v.shape[-1]
     qf, kf, vf = _to_bhtd(q), _to_bhtd(k), _to_bhtd(v)
@@ -348,7 +348,7 @@ def _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, mask_blo
             pl.BlockSpec((None, 1, t), lambda bi, qi: (bi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
@@ -482,6 +482,7 @@ def flash_attention(
     return_lse: bool = False,
     block: int = 0,
     window: int = 0,
+    out_dtype=None,
 ):
     """Pallas flash attention.  q: [B, T, H, D]; k: [B, T_kv, H, D]; v:
     [B, T_kv, H, Dv], of the keys' width or another; returns [B, T, H, Dv].
@@ -515,6 +516,10 @@ def flash_attention(
     With return_lse=True also returns the per-row log-sum-exp [B, H, T] —
     the carry ring attention needs to merge per-block results
     (merge_attention).
+
+    out_dtype: the result's type where it is not the queries' (the kernel
+    accumulates in float32 whatever it is given: differential attention
+    subtracts two results before anything is rounded).  Forward only.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -527,7 +532,7 @@ def flash_attention(
     if window:
         if not causal or return_lse or block > 1:
             raise ValueError(f"a window ({window}) is the causal mask narrowed to a band: causal=True, no lse, no block mask")
-        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, window=window)[0]
+        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, window=window, out_dtype=out_dtype)[0]
     if block > 1:
         # forward only, and the kernel's skip of the key blocks above the
         # diagonal takes a query block to end on a mask block's edge
@@ -536,7 +541,11 @@ def flash_attention(
                 f"the block mask (block={block}) is the causal mask widened to a block's end: "
                 f"causal=True, no lse, block_q ({block_q}) a multiple of block"
             )
-        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, block)[0]
+        return _fwd_impl(q, k, v, pad, True, scale, block_q, block_k, interpret, block, out_dtype=out_dtype)[0]
+    if out_dtype is not None:
+        if return_lse:
+            raise ValueError("out_dtype is forward only: no lse, no gradient")
+        return _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret, out_dtype=out_dtype)[0]
     if return_lse:
         return _flash_with_lse(q, k, v, pad, causal, scale, block_q, block_k, interpret)
     return _flash(q, k, v, pad, causal, scale, block_q, block_k, interpret)
@@ -572,11 +581,12 @@ def merge_attention(o1, lse1, o2, lse2):
     return o, jnp.where(tot == 0.0, NEG_INF, lse)
 
 
-def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0, window=0):
+def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0, window=0, out_dtype=None):
     """Dense jnp attention (fallback + test oracle): [B,T,H,D] -> [B,T,H,D].
     pad: optional [B] left-pad counts (keys < pad[b] masked).  block: B > 1
     widens the causal mask to the block mask, window: W > 0 narrows it to the
-    band 0 <= i - j < W (`flash_attention`)."""
+    band 0 <= i - j < W, out_dtype: the result's type where it is not the
+    queries' (`flash_attention`)."""
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
@@ -598,7 +608,7 @@ def reference_attention(q, k, v, causal=True, scale=None, pad=None, block=0, win
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(out_dtype or q.dtype)
 
 
 _TILE = 128  # the compiled kernel's q/k blocks are multiples of the lane width
@@ -616,19 +626,21 @@ def _left_pad_to_tile(q, k, v, pad):
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=None, block: int = 0,
-              window: int = 0):
+              window: int = 0, out_dtype=None):
     """Dispatcher: the Pallas flash kernel on a TPU, the jnp reference on any
     other backend.  A sequence that is not a multiple of the kernel's tile is
     left-padded up to one and the new columns masked as pad tokens (their
     query rows are dropped), so the algorithm never changes with the shape.
     block: the block mask (`flash_attention`); the columns added on the left
     then have to be whole blocks, which they are for a sequence of whole blocks.
-    window: the band (`flash_attention`)."""
+    window: the band, out_dtype: the result's type (`flash_attention`)."""
     if _platform() != "tpu":
-        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)
+        return reference_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window,
+                                   out_dtype=out_dtype)
     t, t_kv = q.shape[1], k.shape[1]
     if t % _TILE == 0 and t_kv % _TILE == 0:
-        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)
+        return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window,
+                               out_dtype=out_dtype)
     if t != t_kv:
         raise ValueError(
             f"flash kernel needs T and T_kv to be multiples of {_TILE} when "
@@ -637,7 +649,8 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=N
     q, k, v, pad, extra = _left_pad_to_tile(q, k, v, pad)
     if block > 1 and extra % block:
         raise ValueError(f"a sequence of {t} under the block mask of {block}: {extra} columns on the left shift its blocks")
-    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window)[:, extra:]
+    return flash_attention(q, k, v, causal=causal, scale=scale, pad=pad, block=block, window=window,
+                           out_dtype=out_dtype)[:, extra:]
 
 
 # --------------------------------------------------------------------------
@@ -649,13 +662,23 @@ def attention(q, k, v, causal: bool = True, scale: Optional[float] = None, pad=N
 # fetches, so few cached heads a slot take more slots a step (one cached head: the whole row)
 DECODE_BLOCK_K = 256
 DECODE_BLOCK_ROWS = 2048
+# a window layer's ring is one key block whatever its extent, up to so many of the blocks above
+# (a window of 512 at 10 cached heads of 128: 1.3 MB each of keys and values in fast memory)
+DECODE_RING_BLOCKS = 2
 
 
-def decode_key_block(t_max: int, kv: int) -> int:
+def decode_key_block(t_max: int, kv: int, ring: bool = False) -> int:
     """The decode kernel's key block over a cache of `t_max` slots of `kv`
     cached heads: the largest divisor of t_max that is a multiple of 8 and
-    within the cap above, t_max itself where there is none."""
+    within the cap above, t_max itself where there is none; and a window
+    layer's ring whole (`ring`): a live row is one step and one fetch, so a
+    ring may be as long as DECODE_RING_BLOCKS times that cap and no longer."""
     cap = max(DECODE_BLOCK_K, DECODE_BLOCK_ROWS // kv)
+    if ring:
+        if t_max > DECODE_RING_BLOCKS * cap:
+            raise ValueError(f"a ring of {t_max} slots of {kv} cached heads is not one key block "
+                             f"(at most {DECODE_RING_BLOCKS * cap} slots)")
+        return t_max
     return max((b for b in range(8, min(cap, t_max) + 1, 8) if t_max % b == 0), default=t_max)
 
 
@@ -669,10 +692,11 @@ def decode_block_span(first, last, block_k: int, t_max: int):
     return lo, ((last - 1) // block_k).clip(lo, top)
 
 
-def decode_rows_read(first, last, t_max: int, kv: int):
+def decode_rows_read(first, last, t_max: int, kv: int, ring: bool = False):
     """The cache slots the decode kernel fetches, a layer's K (and as many of
-    its V), for rows that attend to [first, last): whole key blocks."""
-    block_k = decode_key_block(t_max, kv)
+    its V), for rows that attend to [first, last): whole key blocks (`ring`:
+    the row's ring)."""
+    block_k = decode_key_block(t_max, kv, ring)
     lo, hi = decode_block_span(first, last, block_k, t_max)
     return (hi - lo + 1) * block_k
 
@@ -683,7 +707,7 @@ def decode_on_kernel() -> bool:
     return _platform() == "tpu"
 
 
-def decode_span(first, last, live, t_max: int, kv: int):
+def decode_span(first, last, live, t_max: int, kv: int, ring: bool = False):
     """What `decode_attention` is told of a step's rows, made once a step (every
     layer's call reads the same): int32 [5, B * key blocks a row].  Rows 0 and 1:
     first, last (row b's queries see slots [first[b], last[b]) of its own cache
@@ -691,9 +715,11 @@ def decode_span(first, last, live, t_max: int, kv: int):
     entry a grid step: the (row, key block) pairs that hold a slot of a row's
     [first, last), the rows that hold a request only (live: [B], None: every
     row) and in their order, a row's blocks ascending; behind them the last
-    entry again (where the index maps look one step ahead).  [4, 0]: how many."""
+    entry again (where the index maps look one step ahead).  [4, 0]: how many.
+    ring: the cache is a window layer's ring, first and last positions: its
+    `t_max` slots are one key block (`decode_key_block`)."""
     b = first.shape[0]
-    block_k = decode_key_block(t_max, kv)
+    block_k = decode_key_block(t_max, kv, ring)
     first, last = first.astype(jnp.int32), last.astype(jnp.int32)
     lo, hi = decode_block_span(first, last, block_k, t_max)
     blocks = hi - lo + 1 if live is None else jnp.where(live, hi - lo + 1, 0)
@@ -774,7 +800,7 @@ def _decode_kernel(layer_ref, span_ref, q_ref, k_ref, v_ref, zeros_ref, o_ref, m
 
 
 def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, interpret: bool = False,
-                     ring: bool = False):
+                     ring: bool = False, out_dtype=None, kv: int = 0):
     """Attention of a decode step, as a Pallas kernel over the caches where they
     lie.  q: [B, Tq, H, D]; k, v: the WHOLE stacks [n_attn, B, T_max, KV, D(v)]
     as `models.generate.init_cache` makes them, and `layer`, the index of the
@@ -785,9 +811,13 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
 
     ring: the stacks are a window layer's, T_max slots a row written round
     (position p at slot p mod T_max), and T_max is one key block: a live row is
-    one step and one fetch, `span` is `decode_span` over (first, last) as
-    positions with this T_max, and a slot is seen where the newest position
-    that falls on it lies in [first, last) (at most T_max of them).  Keys are
+    one step and one fetch, `span` is `decode_span(..., ring=True)` over (first,
+    last) as positions with this T_max, and a slot is seen where the newest
+    position that falls on it lies in [first, last) (at most T_max of them).
+    out_dtype: the result's type where it is not the queries' (float32 for
+    differential attention, which subtracts two results before it rounds).
+    kv: the cached heads a slot, where the stacks come flat, [n_attn, B, T_max *
+    KV, D(v)]: the rows as the kernel reads them.  Keys are
     stored turned, so their order in the ring does not matter to the softmax.
 
     The grid is the work `span` lists and no longer: a step a key block
@@ -798,10 +828,13 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
     row's blocks; the probabilities go into the second contraction in the
     cache's dtype."""
     b, tq, h, d = q.shape
-    n, _, t_max, kv, dv = v.shape
-    block_k = decode_key_block(t_max, kv)
-    if ring and block_k != t_max:
-        raise ValueError(f"a ring of {t_max} slots of {kv} cached heads is not one key block ({block_k})")
+    if kv:
+        n, _, rows, dv = v.shape
+        t_max = rows // kv
+    else:
+        n, _, t_max, kv, dv = v.shape
+    block_k = decode_key_block(t_max, kv, ring)
+    out_dtype = out_dtype or q.dtype
 
     def kv_map(i, layer_ref, span_ref):
         return layer_ref[0], span_ref[2, i], span_ref[3, i], 0
@@ -828,12 +861,12 @@ def decode_attention(q, k, v, layer, span, *, scale: Optional[float] = None, int
             scratch_shapes=[pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, 1), jnp.float32),
                             pltpu.VMEM((m, dv), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, m, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, m, dv), out_dtype),
         input_output_aliases={5: 0},  # the zeros: what a row without work returns
         interpret=interpret,
         name="decode_attn",  # the kernel's name in a device trace
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), span, q.reshape(b, m, d), flat(k), flat(v),
-      jnp.zeros((b, m, dv), q.dtype))
+      jnp.zeros((b, m, dv), out_dtype))
     return out.reshape(b, tq, h, dv)
 
 

@@ -429,6 +429,46 @@ def test_window_prefill_goes_through_the_banded_kernel(v5e, on_tpu, bucket):
     print(bucket, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
 
 
+# Phi-4-mini-flash-reasoning's widths and its whole map of 32 layers (a smaller vocabulary: the head is no part of this)
+PHI4FLASH = dict(
+    vocab_size=512, n_layers=32, d_model=2560, n_heads=40, n_kv_heads=20, d_head=64, d_ff=10240,
+    layer_mixers=("ssm", "attn_win") * 8 + ("ssm", "attn") + ("gmu", "attn_cross") * 7, attn_window=512,
+    rotary=False, tie_embeddings=True, layer_norm=True, norm_eps=1e-5, attn_bias=True, diff_attn=True,
+    ssm_d_state=16, ssm_d_conv=4, ssm_expand=2, ssm_dt_rank=160, ssm_inner_norms=False, param_dtype=jnp.bfloat16,
+)
+
+
+def test_shared_stack_decode_step_reads_the_one_stack_where_it_lies(v5e):
+    """The decode step at Phi-4-mini-flash-reasoning's widths and the cell's
+    cache (32 slots x 4,096) as the chip runs it: eight layers (the full layer
+    and the seven cross layers) attend to ONE stack of keys and values, the
+    cross layers keep none of their own, and the program holds no second
+    [32, 4096, 20 x 64] anywhere: the stack and the eight rings are the layer
+    loop's carry, written a row a slot in place and read by the decode kernel
+    as stored, a slot's ten cached pairs as rows.  Kept as [T, 10, 128] the
+    stacks were copied whole into the kernel's rows at every layer (2.4 GB of
+    temporaries: the chip tiles 10 rows up to 16); flat, the temporaries hold
+    nothing of the cache.  The 32 layers are four loops, not 32."""
+    cfg = transformer.TransformerConfig(**PHI4FLASH)
+    assert len(transformer._layer_runs(cfg.layer_kinds)) == 4
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], 32, 4096, on_kernel=True)
+    assert {n: c.shape for n, c in cache.items()} == {
+        "k": (1, 32, 40960, 128), "v": (1, 32, 40960, 128), "kw": (8, 32, 5120, 128), "vw": (8, 32, 5120, 128),
+        "conv": (9, 32, 3, 5120), "h": (9, 32, 5120, 16)}
+    text = compiled.as_text()
+    kernels = re.findall(r"%(decode_attn[\w.]*) = \S+ custom-call\(", text)
+    assert len(kernels) == 3 and _has_kernel(compiled)  # the rings' run, the full layer's, the cross layers' run
+    assert len(re.findall(r" while\(", text)) <= 6  # four runs of layers (and the sampler's), not one a layer
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6  # a sixth of one layer's w_gate; no logits of 200,064 here
+    stack = 32 * 4096 * 20 * 64  # one layer's keys of every slot: the size no temporary may have
+    assert cache["k"].size == cache["kw"].size == stack
+    for _, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if n >= stack and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            # the donated stacks written in place; a layer's FFN matrices are as large and are parameters
+            assert op == "fusion" and '"aliasing_operands":{"lists":[{' in line, line[:200]
+
+
 def _computations(text):
     """({name: its instructions' lines}, the entry's name) of an optimized program's text."""
     comps, entry, inside = {}, None, None

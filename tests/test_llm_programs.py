@@ -403,6 +403,7 @@ def test_the_batcher_holds_no_model_mathematics():
     # request's rows are written over it, how much of it is recurrent state
     assert imported == {"prefill_counted", "decode_rows", "_nucleus_mask", "TransformerConfig",
                         "init_cache", "install_rows", "recurrent_state_bytes", "cache_bytes_per_token",
+                        "cache_context_bytes_per_token",
                         "key_slots", "cache_kind_bytes"}
     for name in ("_rms_norm", "_scan_blocks", "_scan_layers", "_block_", "_half", "_ssm_mix", "_project_qkv",
                  "_rope", "lax.scan", '"k"', '"v"', '"h"', "n_kv_heads", "d_inner"):

@@ -68,6 +68,8 @@ def test_the_kinds_of_layer_their_stacks_and_their_runs(model):
     assert generate.window_extent(wide, 8448) == 512 and generate.window_extent(wide, 300) == 300
     assert generate.window_extent(dataclasses.replace(wide, n_kv_heads=8, n_heads=8), 8448) == 256
     assert generate.cache_bytes_per_token(cache) == 8 * 2 * 2 * 16 * 4
+    # what one more token of context adds: the two full layers' keys and values (a ring is a slot's)
+    assert generate.cache_context_bytes_per_token(cache) == 2 * 2 * 2 * 16 * 4
     assert generate.cache_kind_bytes(cache) == {"full": 2 * 2 * 3 * T_MAX * 2 * 16 * 4, "window": 2 * 6 * 3 * 16 * 2 * 16 * 4}
     for bad in (dict(layer_mixers=LLLG[:3]), dict(attn_window=0), dict(attn_ring=4), dict(attn_layer_period=2),
                 dict(layer_mixers=("ssm",) + LLLG[1:])):
@@ -155,7 +157,7 @@ def test_the_decode_kernel_reads_a_ring(kv, heads):
     pads = jnp.asarray([3, 0, 100, 0], jnp.int32)
     live = jnp.asarray([True, True, True, False])
     first = jnp.maximum(pads, last - window)
-    span = decode_span(first, last, live, extent, kv)
+    span = decode_span(first, last, live, extent, kv, ring=True)
     assert int(span[4, 0]) == 3  # one key block a live row
     got = decode_attention(q, k, v, 1, span, ring=True, interpret=True)
     cfg = TransformerConfig(n_heads=heads, n_kv_heads=kv, d_head=d)
@@ -206,8 +208,8 @@ def test_the_batcher_serves_through_two_extents_and_counts_both(model):
     dense_cfg = TransformerConfig(vocab_size=97, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2, d_head=16, d_ff=64,
                                   dtype=jnp.float32)
     cache = generate.init_cache(dense_cfg, 4, 64)
-    assert generate.key_slots(cache) == (4 * 64, 0)
-    assert generate.key_slots(cache, np.asarray([0, 2]), np.asarray([10, 40])) == (2 * 64, 0)
+    assert generate.key_slots(cache) == (4 * 64, 0, 0)
+    assert generate.key_slots(cache, np.asarray([0, 2]), np.asarray([10, 40])) == (2 * 64, 0, 0)
 
 
 def test_installing_rows_overwrites_a_slots_ring_whole(model):
