@@ -48,15 +48,23 @@ goes through the same scheduler, cache and admit; what differs is the step:
   against the cache and themselves, writes the block's keys and values in
   place, and chooses on the device what to fix (`_choose_block`).
 - a pass that finds every position of a block fixed has thereby stored the
-  block's keys and values as those of its tokens; the host moves the slot on to
-  its next block.  So a block of B costs its passes and one more.
+  block's keys and values as those of its tokens; the slot moves on to its next
+  block.  So a block of B costs its passes and one more.
 - a step hands a request 0 to B tokens, in position order: a token goes out
   once every position before it is fixed.  An admit prefills the prompt's
   whole blocks and hands out nothing; the prompt's tail is the fixed part of
   the first block.
-- a pass is read by the call that dispatched it: what a slot feeds next (which
-  positions go out, an answer that ends inside a block, the move to the next
-  block) is the host's to decide from what it read, not the pass's output.
+- a pass is read one pass behind, as a causal step is: what a slot feeds next
+  is the pass's own output, or, after a storing pass, an empty block B slots
+  on, and the program decides which from the flags that went in to the pass; it
+  stays on the device (`prev`; an admitted slot starts from the host's rows,
+  marked `fresh`).  So step() dispatches pass N+1 and only then reads pass N.
+  The host keeps a mirror of every slot's block and position, one pass behind
+  the device's, and decides alone what the device is never told: which
+  positions go out, where an answer ends, `fixed_at`, `block_tail`.  An answer's
+  end is in what a pass fixes, so the pass in flight holds a request that ends
+  once more: every request costs one row computed late and dropped
+  (`late_rows`), whose slot the next call may have given away by then.
 - `fixed_at(request_id)` is the record of the pass of its block at which each
   served token was fixed, which the tokens do not say.
 
@@ -138,8 +146,10 @@ class Request:
 
 @dataclass
 class _StepInFlight:
-    """A causal decode step that was dispatched and is not read yet."""
-    nxt: Any  # [S] int32 on the device: every slot's next token
+    """A decode step (a pass of blocks) that was dispatched and is not read yet."""
+    # on the device: every slot's next token [S] int32, or every slot's block after
+    # the pass [2B, S]
+    made: Any
     touched: Any  # experts touched, on the device; None for a dense model
     # (slot, request) of the rows the step holds, as the slots were at dispatch:
     # a row's token goes to THAT request, or nowhere if it has ended since
@@ -148,7 +158,9 @@ class _StepInFlight:
     # among them), as the sampler saw their knobs: how many sample, how many truncate
     sample_rows: int
     truncate_rows: int
-    cache_rows_read: tuple  # `ContinuousBatcher._rows_read` of those slots, as their rows stood then
+    # `ContinuousBatcher._rows_read` of those slots, as their rows stood then; None for a
+    # pass of blocks, reckoned at its read (the host's mirror is its input only then)
+    cache_rows_read: Optional[tuple]
 
 
 def _sample_rowwise(logits, rngs, temps, top_ks, top_ps):
@@ -271,28 +283,40 @@ def _choose_block(logits, fixed, live, temps, rng, cfg: TransformerConfig):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _pass_step_rowpos(params, cache, ints, floats, rng, *, cfg):
+def _pass_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     """One pass of every slot's own block (a model that generates by blocks of
-    B = cfg.block_length).  ints: [3 + 2B, S] int32, the rows pos (the cache
-    slot of the block's first position), pads, live, then the block's B tokens
-    and its B fixed flags; floats: [2, S], the rows temps, top_ps (the second
-    unused: a request that asks top-p is refused).  A position that is not
-    fixed goes in as cfg.mask_token_id whatever its token says, and fixedness
-    is the flag alone.  Returns (the blocks after the pass [2B, S]: tokens, then
-    flags; cache; the carried key; experts touched).  The B rows of keys and
-    values a slot and layer are written in place at [layer, b, pos : pos + B]
+    B = cfg.block_length).  ints: [4 + 2B, S] int32, the rows pads, live, fresh,
+    then a slot's state as the host has it: pos (the cache slot of the block's
+    first position), the block's B tokens and its B fixed flags; floats:
+    [2, S], the rows temps, top_ps (the second unused: a request that asks top-p
+    is refused).  prev: [1 + 2B, S] int32, the state the pass before left every
+    slot for this one, still on the device: a live slot takes it, which the host
+    may not have read yet, except where fresh is set (a slot admitted since: the
+    admit's position, the prompt's tail and its flags are the host's rows).  A
+    position that is not fixed goes in as cfg.mask_token_id whatever its token
+    says, and fixedness is the flag alone.  Returns (the blocks after the pass
+    [2B, S]: tokens, then flags; the state for the next pass [1 + 2B, S]; cache;
+    the carried key; experts touched).  The next state is decided from what
+    went in: a block that came in with every position fixed has been stored by
+    this pass, so the slot goes on to an empty block at pos + B; any other is
+    the block after the pass, where it stands.  The B rows of keys and values
+    a slot and layer are written in place at [layer, b, pos : pos + B]
     (models/generate.py, the decode block), as the causal step writes its
     one: tests/test_chip_compile.py holds the chip's program to that and to no
     sort of the vocabulary."""
     b = cfg.block_length
-    pos, pads, live = ints[0], ints[1], ints[2] != 0
-    tokens, fixed = ints[3:3 + b].T, ints[3 + b:].T != 0
+    pads, live, fresh = ints[0], ints[1] != 0, ints[2] != 0
+    # a slot that holds no request rests on the host's rows, as it always did
+    state = jnp.where(fresh | ~live, ints[3:], prev)
+    pos, tokens, fixed = state[0], state[1:1 + b].T, state[1 + b:].T != 0
     key, sub = jax.random.split(rng)
     ids = jnp.where(fixed, tokens, cfg.mask_token_id)
     logits, cache, touched = decode_rows(params, cache, ids, pos, pads, cfg, live)
     chosen, fix = _choose_block(logits, fixed, live, floats[0], sub, cfg)
     after = jnp.concatenate([jnp.where(fix, chosen, tokens).T, (fixed | fix).T.astype(jnp.int32)])
-    return after, cache, key, touched
+    stored = jnp.all(fixed, axis=1)
+    nxt = jnp.concatenate([jnp.where(stored, pos + b, pos)[None], jnp.where(stored, 0, after)])
+    return after, nxt, cache, key, touched
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -440,30 +464,36 @@ class ContinuousBatcher:
         self._cache_bytes = cache_kind_bytes(self.cache)
         # the decode step's per-slot inputs as its program takes them: two host
         # arrays, the scheduler's vectors their rows, written between steps (an
-        # admit, a cancel) and as a step is dispatched or read.  A causal step
-        # is handed a copy of each (one dispatch, no eager upload), since it may
-        # still be reading them when the scheduler writes next (on the CPU
-        # backend a host array may be aliased, not copied); a pass of blocks is
-        # read back before anything is written, and takes them as they are.
+        # admit, a cancel) and as a step is dispatched or read.  A step is handed
+        # a copy of each (one dispatch, no eager upload), since it may still be
+        # reading them when the scheduler writes next (on the CPU backend a host
+        # array may be aliased, not copied).  _fresh marks the slots admitted
+        # since the last dispatch, which start from the host's rows; every other
+        # live slot feeds what the step before left it on the device, `_prev`;
+        # _live: the slots the step holds, written as it is dispatched
         self._floats = np.zeros((2, slots), np.float32)
         if self._block:
-            # _pos: the cache slot of the block's first position; _blk_tokens,
-            # _blk_fixed: [B, S], the block's tokens and which of them are fixed
-            self._ints = np.zeros((3 + 2 * self._block, slots), np.int32)
-            self._pos, self._pads, self._live = self._ints[:3]
-            self._blk_tokens, self._blk_fixed = self._ints[3:3 + self._block], self._ints[3 + self._block:]
+            # the host's mirror of a slot's state, which a pass's read moves on
+            # (one pass behind the device's): _pos, the cache slot of the block's
+            # first position; _blk_tokens, _blk_fixed: [B, S], the block's tokens
+            # and which of them are fixed.  The program reads it of a fresh slot
+            self._ints = np.zeros((4 + 2 * self._block, slots), np.int32)
+            self._pads, self._live, self._fresh, self._pos = self._ints[:4]
+            self._blk_tokens, self._blk_fixed = self._ints[4:4 + self._block], self._ints[4 + self._block:]
             self._topks = np.zeros(slots, np.int32)  # top-k is refused: the row stays 0 and is not uploaded
+            state = (1 + 2 * self._block, slots)
         else:
             self._ints = np.zeros((6, slots), np.int32)
-            # _tokens: an admit's first token, which _fresh marks for the slot's
-            # first step (a later step feeds the device's own, `_prev`); _pos:
-            # cache slot of the NEXT write, moved on as a step is dispatched;
-            # _live: the slots the step holds, written as it is dispatched
+            # _tokens: an admit's first token, the row of a fresh slot's first
+            # step; _pos: cache slot of the NEXT write, moved on as a step is
+            # dispatched
             self._tokens, self._pos, self._pads, self._topks, self._fresh, self._live = self._ints
-            # the last dispatched step's tokens, on the device
-            self._prev = jnp.zeros(slots, jnp.int32)
-        # a causal step while it is dispatched and unread: each step() dispatches
-        # one and reads the one before (a pass of blocks is read where it is dispatched)
+            state = (slots,)
+        # what the last dispatched step left for the next, on the device: every
+        # slot's token, or its position and block (`_pass_step_rowpos`)
+        self._prev = jnp.zeros(state, jnp.int32)
+        # a step while it is dispatched and unread: each step() dispatches one
+        # and reads the one before
         self._flight: Optional[_StepInFlight] = None
         self._temps, self._topps = self._floats
         self._topps[:] = 1.0
@@ -504,9 +534,10 @@ class ContinuousBatcher:
             # decode steps that sorted the vocabulary: a live row sampled with
             # top-k or top-p; stays 0 under greedy or temperature-only traffic
             "sort_steps": 0,
-            # causal steps dispatched while the one before was unread (the device
-            # had its next program queued), and rows such a step computed for a
-            # request that had ended meanwhile (by eos or a cancel: dropped)
+            # steps dispatched while the one before was unread (the device had its
+            # next program queued), and rows such a step computed for a request
+            # that had ended meanwhile (by eos or a cancel; a pass of blocks: by
+            # any end, one a request): dropped
             "steps_ahead": 0, "late_rows": 0,
             # of a layer's keys, the slots the steps' attention fetches (the live rows' own
             # [pads, pos + its tokens), in whole key blocks: ops/attention.py
@@ -593,18 +624,13 @@ class ContinuousBatcher:
         slot, then read the step the call before dispatched.  Returns
         {request_id: [new tokens]}: the tokens of the step that was read and
         the prefill-sampled first token of requests admitted in this call, so
-        streaming consumers see every token exactly once.  (A model that
-        generates by blocks reads the pass it dispatched: `_step_blocks`.)"""
+        streaming consumers see every token exactly once.  A model that
+        generates by blocks goes the same way, a pass its step: an admit hands
+        out nothing, and a read pass hands a request 0 to B tokens."""
         sp = tracing.span("llm.step")
         with sp:
             out: Dict[int, List[int]] = {}
             self._admit(out)
-            if self._block:
-                live = [s for s, r in enumerate(self._by_slot) if r is not None]
-                sp.set(live=len(live))
-                if live:
-                    self._step_blocks(live, out, sp)
-                return out
             landing = self._flight
             self._flight = self._dispatch(landing)
             ahead = landing is not None and self._flight is not None
@@ -615,16 +641,18 @@ class ContinuousBatcher:
             return out
 
     def _dispatch(self, landing: Optional[_StepInFlight]) -> Optional[_StepInFlight]:
-        """Dispatch one token more for every slot whose request has not reached
+        """Dispatch one step more for every slot whose request has not reached
         its length once `landing` (the step in flight, unread) is read; None
-        where there is no such slot.  A row's input is the device's own token
-        of the step before, so the host has nothing to wait for: a request that
-        ends by eos in `landing` is in this step too, one row computed late."""
-        flying = {r.request_id for _, r in landing.rows} if landing is not None else ()
-        rows = [
-            (s, r) for s, r in enumerate(self._by_slot)
-            if r is not None and len(r.out_tokens) + (r.request_id in flying) < r.max_new_tokens
-        ]
+        where there is no such slot.  A row's input is what the step before
+        left it on the device, so the host has nothing to wait for: a request
+        that ends by eos in `landing` is in this step too, one row computed
+        late.  Where a pass of blocks is in flight, the host cannot tell which
+        requests it ends (the count of an answer is in what the pass fixes):
+        every slot that holds a request is in the next pass."""
+        rows = [(s, r) for s, r in enumerate(self._by_slot) if r is not None]
+        if not self._block:
+            flying = {r.request_id for _, r in landing.rows} if landing is not None else ()
+            rows = [(s, r) for s, r in rows if len(r.out_tokens) + (r.request_id in flying) < r.max_new_tokens]
         if not rows:
             return None
         slots = [s for s, _ in rows]
@@ -632,14 +660,20 @@ class ContinuousBatcher:
             self._live[:] = 0
             self._live[slots] = 1
             ints, floats = self._ints.copy(), self._floats.copy()
-            rows_read = self._rows_read(slots, 1)
             self._fresh[:] = 0
-            self._pos[slots] += 1
+            rows_read = None  # of a pass of blocks: reckoned at its read, where its position moves too
+            if not self._block:
+                rows_read = self._rows_read(slots, 1)
+                self._pos[slots] += 1
         with tracing.span("llm.step.dispatch"):
-            self._prev, self.cache, self._rng, touched = _decode_step_rowpos(
+            step = _pass_step_rowpos if self._block else _decode_step_rowpos
+            # first what the host reads, last what the next step feeds: a causal step's
+            # tokens are both, a pass returns its blocks and the state it leaves
+            *made, self.cache, self._rng, touched = step(
                 self.params, self.cache, ints, floats, self._prev, self._rng, cfg=self.cfg,
             )
-        return _StepInFlight(self._prev, touched, rows, self._sample_rows, self._truncate_rows, rows_read)
+            made, self._prev = made[0], made[-1]
+        return _StepInFlight(made, touched, rows, self._sample_rows, self._truncate_rows, rows_read)
 
     def _rows_read(self, slots: List[int], tokens: int) -> tuple:
         """Of a layer's keys, the cache slots the attention fetches in a step
@@ -664,98 +698,99 @@ class ContinuousBatcher:
                 self.stats["shared_rows_read"] += shared
 
     def _land(self, step: _StepInFlight, out: Dict[int, List[int]], sp: tracing.span) -> None:
-        """Read a dispatched step and hand each row's token to the request that
-        held the slot at dispatch, unless that request has ended since."""
+        """Read a dispatched step and hand what each row made to the request
+        that held the slot at dispatch, unless that request has ended since:
+        such a row is dropped, and its slot, which may hold another request by
+        now, is left alone."""
         with tracing.span("llm.step.readback"):
-            nxt, touched = jax.device_get((step.nxt, step.touched))
-        sp.set(sample_rows=step.sample_rows, truncate_rows=step.truncate_rows)
+            made, touched = jax.device_get((step.made, step.touched))
+        positions = len(step.rows) * (self._block or 1)  # late rows among them: the device ran them
+        said: Dict[str, Any] = dict(sample_rows=step.sample_rows, truncate_rows=step.truncate_rows)
         self.stats["sort_steps"] += step.truncate_rows > 0
         if touched is not None:
             # a replica that holds a share of the experts reads the held experts given a
             # row, then the assignments that fell on them (layer means; the third, the share
             # of the layers that took the compact buffer, is an admit's to report)
             touched, *held = np.ravel(touched)
-            sp.set(moe_rows=len(step.rows), moe_experts_touched=float(touched))
+            said.update(moe_rows=positions, moe_experts_touched=float(touched))
             if held:
-                sp.set(moe_held_assignments=float(held[0]))
-            self.stats["moe_assignments"] += len(step.rows) * self.cfg.n_experts_per_tok
+                said.update(moe_held_assignments=float(held[0]))
+            self.stats["moe_assignments"] += positions * self.cfg.n_experts_per_tok
         if self._ssm_step_bytes:
-            sp.set(ssm_state_bytes=self._ssm_step_bytes)
+            said.update(ssm_state_bytes=self._ssm_step_bytes)
             self.stats["ssm_state_bytes"] += self._ssm_step_bytes
-        self._count_rows_read(step.cache_rows_read, sp)
+        rows_read = step.cache_rows_read
+        if self._block:
+            # the mirror is this pass's input until the scatter below moves it on (a late
+            # row's slot that was given away since stands at its new request's)
+            rows_read = self._rows_read([s for s, _ in step.rows], self._block)
+        self._count_rows_read(rows_read, sp)
         self.stats["decode_steps"] += 1
+        rows = [(s, req) for s, req in step.rows if not req.done]
+        self.stats["late_rows"] += len(step.rows) - len(rows)
         with tracing.span("llm.step.scatter"):
-            for s, req in step.rows:
-                if req.done:
-                    self.stats["late_rows"] += 1
-                    continue
-                tok = int(nxt[s])
+            if self._block:
+                said.update(self._scatter_blocks(rows, made, out), block_rows=positions)
+                self.stats["block_passes"] += len(step.rows)
+            else:
+                self._scatter_tokens(rows, made, out)
+        sp.set(**said)
+
+    def _scatter_tokens(self, rows: List[tuple], nxt: np.ndarray, out: Dict[int, List[int]]) -> None:
+        """A causal step's tokens to their requests; a request's last frees its slot."""
+        for s, req in rows:
+            tok = int(nxt[s])
+            req.out_tokens.append(tok)
+            out.setdefault(req.request_id, []).append(tok)
+            self.stats["tokens_out"] += 1
+            if len(req.out_tokens) >= req.max_new_tokens or (
+                req.eos_id is not None and tok == req.eos_id
+            ):
+                self._finish(s, req)
+
+    def _scatter_blocks(self, rows: List[tuple], after: np.ndarray, out: Dict[int, List[int]]) -> Dict[str, int]:
+        """What a pass of blocks did to each of `rows`, from the blocks `after`
+        it [2B, S] and the host's mirror, which is the pass's input: the mirror
+        moves on as the program moved the device's state (`_pass_step_rowpos`),
+        and the tokens that every position before them is fixed for go out.
+        Returns what `llm.step` says of the pass."""
+        b = self._block
+        fixed_now = handed = stored = 0
+        for s, req in rows:
+            if self._blk_fixed[:, s].all():
+                # the pass found nothing masked: it stored the block
+                stored += 1
+                self._pos[s] += b
+                self._blk_tokens[:, s] = 0
+                self._blk_fixed[:, s] = 0
+                req.block_pass, req.block_out, req.pass_of = 0, 0, [-1] * b
+                continue
+            for i in np.nonzero(after[b:, s] != self._blk_fixed[:, s])[0]:
+                req.pass_of[i] = req.block_pass
+                fixed_now += 1
+            self._blk_tokens[:, s], self._blk_fixed[:, s] = after[:b, s], after[b:, s]
+            req.block_pass += 1
+            new = out.setdefault(req.request_id, [])
+            while req.block_out < b and self._blk_fixed[req.block_out, s] and not req.done:
+                tok = int(self._blk_tokens[req.block_out, s])
                 req.out_tokens.append(tok)
-                out.setdefault(req.request_id, []).append(tok)
-                self.stats["tokens_out"] += 1
+                req.fixed_at.append(req.pass_of[req.block_out])
+                new.append(tok)
+                req.block_out += 1
                 if len(req.out_tokens) >= req.max_new_tokens or (
                     req.eos_id is not None and tok == req.eos_id
                 ):
+                    req.block_tail = [
+                        (i, req.pass_of[i], int(self._blk_tokens[i, s]))
+                        for i in range(req.block_out, b) if self._blk_fixed[i, s]
+                    ]
                     self._finish(s, req)
-
-    def _step_blocks(self, live: List[int], out: Dict[int, List[int]], sp: tracing.span) -> None:
-        """One pass of every live slot's block (module doc): the step of a
-        model that generates by blocks, between the admits and the return."""
-        b = self._block
-        with tracing.span("llm.step.upload"):
-            self._live[:] = [r is not None for r in self._by_slot]
-            rows_read = self._rows_read(live, b)
-        with tracing.span("llm.step.dispatch"):
-            after, self.cache, self._rng, touched = _pass_step_rowpos(
-                self.params, self.cache, self._ints, self._floats, self._rng, cfg=self.cfg,
-            )
-        with tracing.span("llm.step.readback"):
-            after, touched = jax.device_get((after, touched))
-        fixed_now = handed = stored = 0
-        with tracing.span("llm.step.scatter"):
-            for s in live:
-                req = self._by_slot[s]
-                if self._blk_fixed[:, s].all():
-                    # the pass found nothing masked: it stored the block
-                    stored += 1
-                    self._pos[s] += b
-                    self._blk_tokens[:, s] = 0
-                    self._blk_fixed[:, s] = 0
-                    req.block_pass, req.block_out, req.pass_of = 0, 0, [-1] * b
-                    continue
-                for i in np.nonzero(after[b:, s] != self._blk_fixed[:, s])[0]:
-                    req.pass_of[i] = req.block_pass
-                    fixed_now += 1
-                self._blk_tokens[:, s], self._blk_fixed[:, s] = after[:b, s], after[b:, s]
-                req.block_pass += 1
-                new = out.setdefault(req.request_id, [])
-                while req.block_out < b and self._blk_fixed[req.block_out, s] and not req.done:
-                    tok = int(self._blk_tokens[req.block_out, s])
-                    req.out_tokens.append(tok)
-                    req.fixed_at.append(req.pass_of[req.block_out])
-                    new.append(tok)
-                    req.block_out += 1
-                    if len(req.out_tokens) >= req.max_new_tokens or (
-                        req.eos_id is not None and tok == req.eos_id
-                    ):
-                        req.block_tail = [
-                            (i, req.pass_of[i], int(self._blk_tokens[i, s]))
-                            for i in range(req.block_out, b) if self._blk_fixed[i, s]
-                        ]
-                        self._finish(s, req)
-                handed += len(new)
-                if not new:
-                    del out[req.request_id]
-        said = dict(block_rows=len(live) * b, tokens_fixed=fixed_now, tokens_out=handed, store_rows=stored)
-        if touched is not None:
-            said.update(moe_rows=len(live) * b, moe_experts_touched=float(touched))
-            self.stats["moe_assignments"] += len(live) * b * self.cfg.n_experts_per_tok
-        sp.set(**said)
-        self._count_rows_read(rows_read, sp)
-        self.stats["decode_steps"] += 1
+            handed += len(new)
+            if not new:
+                del out[req.request_id]
         self.stats["tokens_out"] += handed
-        self.stats["block_passes"] += len(live)
         self.stats["block_tokens_fixed"] += fixed_now
+        return dict(tokens_fixed=fixed_now, tokens_out=handed, store_rows=stored)
 
     def fixed_at(self, request_id: int) -> List[int]:
         """The pass of its block (0: the block's first) at which each token
@@ -886,6 +921,7 @@ class ContinuousBatcher:
         req.block_pass, req.block_out, req.pass_of = 0, tail, [-1] * b
         self._pos[slot] = bucket
         self._pads[slot] = pad
+        self._fresh[slot] = 1
         return whole
 
     def _admit_full_prefill(self, req: Request, sp: tracing.span):

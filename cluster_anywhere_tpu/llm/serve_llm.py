@@ -271,7 +271,7 @@ class ContinuousLLMServer:
                 ("sort_steps", "ca_serve_sort_steps_total",
                  "decode steps that sorted the vocabulary: a live request sampled with top-k or top-p"),
                 ("steps_ahead", "ca_serve_steps_ahead_total",
-                 "causal decode steps dispatched while the step before was unread: the device had its next program queued"),
+                 "decode steps dispatched while the step before was unread: the device had its next program queued"),
                 ("late_rows", "ca_serve_late_rows_total",
                  "rows a decode step computed for a request that had ended while the step was in flight: dropped"),
                 ("cache_rows_read", "ca_serve_cache_rows_read_total",

@@ -17,6 +17,7 @@ program writes the file anew (the second argument) and its diff says which.
 About 20 s on the CPU: nothing is compiled and no weight is made."""
 import hashlib
 import importlib
+import inspect
 import json
 import os
 import re
@@ -60,10 +61,12 @@ def hashes(root: str) -> dict:
         params = jax.eval_shape(lambda k: transformer.init_params(k, cfg), jax.random.key(0))
         cache = jax.eval_shape(lambda: generate.init_cache(cfg, slots, t_max))
         key = jax.eval_shape(lambda: jax.random.key(0))
-        rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 6
-        step = continuous._pass_step_rowpos if cfg.generates_blocks else continuous._decode_step_rowpos
-        ints, floats = shape((rows, slots), jnp.int32), shape((2, slots), jnp.float32)
-        prev = () if cfg.generates_blocks else (shape((slots,), jnp.int32),)
+        b = cfg.block_length if cfg.generates_blocks else 0
+        step = continuous._pass_step_rowpos if b else continuous._decode_step_rowpos
+        # a checkout whose pass of blocks is read where it is dispatched hands it the host's rows alone
+        behind = "prev" in inspect.signature(step.__wrapped__).parameters
+        ints, floats = shape((3 + behind + 2 * b if b else 6, slots), jnp.int32), shape((2, slots), jnp.float32)
+        prev = (shape((1 + 2 * b, slots) if b else (slots,), jnp.int32),) if behind else ()
         out[f"{name}.decode"] = sha(text(lambda *a: step.__wrapped__(*a, cfg=cfg), params, cache, ints, floats, *prev, key,
                                          donate_argnums=(1,)))
         buckets = continuous.prefill_buckets_for(dep["max_prompt_len"])

@@ -197,17 +197,18 @@ def _compiled_decode_step(cfg, device, slots=32, t_max=768, on_kernel=False):
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     # the causal step takes the step before's tokens from the device beside the host's rows
     # (six: the last says which slots the step holds); a model that generates
-    # by blocks of B takes a block's B tokens and B fixed flags a slot, and its own step
-    rows = 3 + 2 * cfg.block_length if cfg.generates_blocks else 6
-    step = continuous._pass_step_rowpos if cfg.generates_blocks else continuous._decode_step_rowpos
-    ints = on_chip(jax.ShapeDtypeStruct((rows, slots), jnp.int32))
+    # by blocks of B takes a slot's position, its block's B tokens and B fixed flags, from the
+    # pass before on the device and from the host (four rows more), and its own step
+    b = cfg.block_length if cfg.generates_blocks else 0
+    step = continuous._pass_step_rowpos if b else continuous._decode_step_rowpos
+    ints = on_chip(jax.ShapeDtypeStruct((4 + 2 * b if b else 6, slots), jnp.int32))
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
-    prev = () if cfg.generates_blocks else (on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),)
+    prev = on_chip(jax.ShapeDtypeStruct((1 + 2 * b, slots) if b else (slots,), jnp.int32))
     fn = lambda *a: step.__wrapped__(*a, cfg=cfg)
     with pytest.MonkeyPatch.context() as patch:
         if on_kernel:
             patch.setattr(attention, "_platform", lambda: "tpu")
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, *prev, key).compile()
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, ints, floats, prev, key).compile()
     return compiled, params, cache
 
 

@@ -1,4 +1,5 @@
-"""The causal step reads one step behind."""
+"""The decode step is read one step behind: a causal token a slot, or a pass of
+every slot's block."""
 
 import numpy as np
 import pytest
@@ -14,18 +15,20 @@ from _llm_tiny import (  # noqa: F401 (llm_spans is a fixture)
 )
 
 
-def _watch_dispatches(monkeypatch):
-    """Records what every `_decode_step_rowpos` call was handed: [(ints, a copy
-    of it as it was, floats, a copy)]."""
+def _watch_dispatches(monkeypatch, step="_decode_step_rowpos"):
+    """Records what every call of the step's program (`_pass_step_rowpos`: a
+    pass of blocks) was handed: [(ints, a copy of it as it was, floats, a copy,
+    the device's `prev` it was given, the one it returned)]."""
     from cluster_anywhere_tpu.llm import continuous
 
-    handed, real = [], continuous._decode_step_rowpos
+    handed, real = [], getattr(continuous, step)
 
     def spy(params, cache, ints, floats, prev, rng, *, cfg):
-        handed.append((ints, ints.copy(), floats, floats.copy()))
-        return real(params, cache, ints, floats, prev, rng, cfg=cfg)
+        made = real(params, cache, ints, floats, prev, rng, cfg=cfg)
+        handed.append((ints, ints.copy(), floats, floats.copy(), prev, made[-4]))
+        return made
 
-    monkeypatch.setattr(continuous, "_decode_step_rowpos", spy)
+    monkeypatch.setattr(continuous, step, spy)
     return handed
 
 
@@ -106,8 +109,8 @@ def test_a_batcher_that_reads_one_step_behind_answers_as_generate_does(model, mo
     assert cb.stats["decode_steps"] == len(handed) == calls - 1 and cb.stats["steps_ahead"] == ahead == calls - 2
     # every position any step was given lies in the cache; the request that fills its rows
     # was last dispatched at the last but one, and its idle row rests on the last
-    assert all(0 <= was[1].min() and was[1].max() < t_max for _, was, _, _ in handed)
-    assert max(was[1][edge.slot] for _, was, _, _ in handed) == t_max - 2 and cb._pos[edge.slot] == t_max - 1
+    assert all(0 <= h[1][1].min() and h[1][1].max() < t_max for h in handed)
+    assert max(h[1][1][edge.slot] for h in handed) == t_max - 2 and cb._pos[edge.slot] == t_max - 1
 
 
 def test_a_step_is_dispatched_before_the_step_before_it_is_read(llm_spans, monkeypatch):
@@ -150,7 +153,7 @@ def test_a_step_is_dispatched_before_the_step_before_it_is_read(llm_spans, monke
     # three dispatches, each handed arrays of its own: the scheduler moved its positions on and
     # took the fresh marks back right after each, and what the step was handed still reads as it did
     assert len(handed) == 3
-    for ints, was, floats, floats_was in handed:
+    for ints, was, floats, floats_was, _, _ in handed:
         assert not np.shares_memory(ints, cb._ints) and not np.shares_memory(floats, cb._floats)
         assert np.array_equal(ints, was) and np.array_equal(floats, floats_was)
     fresh, pos = [h[1][4].tolist() for h in handed], [h[1][1].tolist() for h in handed]
@@ -166,3 +169,168 @@ def test_a_step_is_dispatched_before_the_step_before_it_is_read(llm_spans, monke
     shipped = dict(shipped)
     assert shipped["ca_serve_steps_ahead_total"] == 2 and shipped["ca_serve_late_rows_total"] == 1
     assert shipped["ca_serve_decode_steps_total"] == 3
+
+
+def _state(cb, slot):
+    """The host's mirror of a slot of a block batcher, as the program carries
+    it: the block's position, its tokens, its fixed flags."""
+    return [int(cb._pos[slot]), *cb._blk_tokens[:, slot].tolist(), *cb._blk_fixed[:, slot].tolist()]
+
+
+@pytest.mark.parametrize("sharpen", [0.0, 40.0], ids=["a-position-a-pass", "sharpened-head"])
+def test_a_block_batcher_that_reads_one_pass_behind_serves_the_plain_loop(sharpen, monkeypatch):
+    """Requests of unlike lengths through three slots, each pass dispatched
+    before the one before is read: one ends by eos inside a block, one fills its
+    cache rows to the last, one is cancelled while a pass holds its row, and
+    each of them leaves the pass in flight one row computed late and dropped;
+    the next call admits a waiting request into the freed slot while that pass
+    still runs, and the request starts from its own rows (`fresh`).  Every
+    stream, its `fixed_at` and its `block_tail` are the plain loop's over the
+    reference, a request at a time; after every read the state the device
+    carries for a slot is the host's mirror of it, a storing pass's move and an
+    admit's tail among them; and no pass was given a block outside the cache."""
+    from test_llm_blocks import MASK, plain_generate, program
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+
+    cfg, params = program(sharpen)
+    b, t_max = cfg.block_length, 64
+    rng = np.random.default_rng(7)
+    prompt = lambda n: rng.integers(0, MASK, n).tolist()
+    # a prompt whose answer brings a token it has not held before as its 3rd to 6th: that
+    # token as the request's eos ends it there
+    for _ in range(20):
+        stopped = prompt(6)
+        full = plain_generate(params, cfg, stopped, 10)[0]
+        at = next((j for j in range(2, 6) if full[j] not in full[:j]), None)
+        if at is not None:
+            break
+    assert at is not None
+    handed = _watch_dispatches(monkeypatch, "_pass_step_rowpos")
+    cb = ContinuousBatcher(params, cfg, slots=3, t_max=t_max, prefill_buckets=(16, 32), prefix_cache_entries=0)
+    sent = {}  # name -> (request, the plain loop's (answer, passes, _, tail))
+
+    def submit(name, ids, n, **kw):
+        sent[name] = (cb.submit(ids, max_new_tokens=n, **kw), plain_generate(params, cfg, ids, n, eos=kw.get("eos_id")))
+        return sent[name][0]
+
+    edge = submit("edge", prompt(12), 48)  # three blocks in bucket 16, twelve more: its last ends the cache
+    assert cb.block_plan(12, 48)[1] + 48 == t_max
+    stops = submit("stops", stopped, 10, eos_id=full[at])
+    submit("short", prompt(9), 5)
+    submit("waits", prompt(3), 7)  # these two take the first two slots that free
+    gone = submit("gone", prompt(18), 12)
+    streams, calls, ahead, took_over, moves = {}, 0, 0, {}, set()
+    while cb.has_work:
+        landing, slots_before = cb._flight, list(cb._by_slot)
+        late = [r for _, r in landing.rows if r.done] if landing is not None else []
+        before = {s: _state(cb, s) for s, _ in landing.rows} if landing is not None else {}
+        out = cb.step()
+        calls += 1
+        ahead += landing is not None and cb._flight is not None
+        for rid, toks in out.items():
+            streams.setdefault(rid, []).extend(toks)
+        if landing is not None:
+            # what the pass that was read left on the device for the next is what the host
+            # has made of its mirror, for every row whose request the host still served
+            _, ints, _, _, _, carried = handed[-1 - (cb._flight is not None)]
+            for s, r in landing.rows:
+                if all(r is not x for x in late):
+                    assert np.asarray(carried)[:, s].tolist() == _state(cb, s)
+                    stored = all(before[s][1 + b:])
+                    assert _state(cb, s)[0] == before[s][0] + b * stored
+                    if stored:
+                        moves.add("stored")
+                    elif ints[2][s] and any(before[s][1 + b:]):
+                        moves.add("fresh-tail")
+        for name, ended in (("stops", stops), ("gone", gone)):
+            # the call after its end: its slot was free as the call began, the pass then in
+            # flight still held its row, and the call's admit put the next request into the slot
+            if ended.done and slots_before[ended.slot] is None and any(r is ended for r in late):
+                took_over.setdefault(name, cb._by_slot[ended.slot])
+        if len(gone.out_tokens) >= 3 and not gone.done:
+            assert any(r is gone for _, r in cb._flight.rows)  # a pass holds its row: computed for nothing
+            assert cb.cancel(gone.request_id)
+            submit("last", prompt(7), 5)
+    assert calls < 120 and all(r.done for r, _ in sent.values()) and moves == {"stored", "fresh-tail"}
+    tails = []
+    for name, (req, (want, passes, _, tail)) in sent.items():
+        n = len(req.out_tokens)
+        assert (n == len(want)) != (req is gone) and n >= 3, name
+        assert req.out_tokens == want[:n] == streams[req.request_id] and req.fixed_at == passes[:n], name
+        if req is not gone:  # the batcher keeps the record of those that finished
+            assert cb.fixed_at(req.request_id) == passes and cb.block_tail(req.request_id) == tail, name
+            tails += tail
+    assert stops.out_tokens[-1] == full[at] and len(stops.out_tokens) == at + 1 and tails
+    assert cb.stats["tokens_out"] == sum(len(t) for t in streams.values())
+    # an answer's end is in what a pass fixes: each request, ended or cancelled, was in one pass
+    # more than the host served it in, and the two slots were given away while that pass ran
+    assert cb.stats["late_rows"] == len(sent) == 6 and cb.stats["cancelled"] == 1
+    assert took_over["stops"] is not None and took_over["gone"] is sent["last"][0]
+    # every call but the first read a pass, and every call but the last dispatched one before it read
+    assert cb.stats["decode_steps"] == len(handed) == calls - 1 and cb.stats["steps_ahead"] == ahead == calls - 2
+    assert cb.stats["block_passes"] == sum(int(h[1][1].sum()) for h in handed)
+    # the block every live row of every pass was given lies in the cache; the request that fills
+    # its rows ran its last passes on the cache's last block
+    given = [np.where((was[2] != 0) | (was[1] == 0), was[3], np.asarray(prev)[0])[was[1] != 0] for _, was, _, _, prev, _ in handed]
+    assert all(0 <= pos.min() and pos.max() + b <= t_max for pos in given)
+    assert max(pos.max() for pos in given) == t_max - b == cb._pos[edge.slot]
+
+
+def test_a_pass_is_dispatched_before_the_pass_before_it_is_read(llm_spans, monkeypatch):
+    """The order is held for a pass of blocks as for a causal step: in a call
+    that has a pass in flight and dispatches another (`ahead=1`),
+    `llm.step.dispatch` closes before `llm.step.readback` opens; `llm.step`
+    says of the pass that was READ how many rows it held, a late one among them;
+    the arrays a pass was handed are its own; a slot is marked fresh for its
+    first pass alone; and `steps_ahead`, `late_rows` count what the calls come to."""
+    from test_llm_blocks import program
+
+    from cluster_anywhere_tpu.llm import ContinuousBatcher
+    from cluster_anywhere_tpu.util import tracing
+
+    cfg, params = program()
+    handed = _watch_dispatches(monkeypatch, "_pass_step_rowpos")
+    cb = ContinuousBatcher(params, cfg, slots=2, t_max=32, prefill_buckets=(8, 16), prefix_cache_entries=0)
+    token = tracing.push_execution(TRACE)
+    try:
+        a, b = cb.submit(list(range(1, 7)), max_new_tokens=9), cb.submit(list(range(1, 6)), max_new_tokens=2)
+        flights = []  # a call: the rows of the pass it read, whether it dispatched one
+        while not b.done:
+            landing = cb._flight
+            out = cb.step()
+            flights.append((len(landing.rows) if landing is not None else 0, cb._flight is not None))
+            assert (not out) == (landing is None) or not b.done  # an admit hands out nothing
+        # `b` ended at that read: the pass dispatched just before holds its row once more
+        assert not a.done and cb._flight.rows == [(a.slot, a), (b.slot, b)]
+        assert cb.step().keys() <= {a.request_id}  # read it: `b`'s row dropped; dispatched `a` alone
+        assert cb._flight.rows == [(a.slot, a)] and cb.cancel(a.request_id)  # while a pass holds its row
+        assert cb.has_work and cb.step() == {} and not cb.has_work  # read that pass and dropped the row
+        assert cb.step() == {}  # nothing in flight, nothing live: no pass
+    finally:
+        tracing.pop_execution(token)
+    flights += [(2, True), (1, False), (0, False)]
+    assert flights[:2] == [(0, True), (2, True)] and len(b.out_tokens) == 2 and 0 < len(a.out_tokens) < 9
+    counted = dict(decode_steps=len(flights) - 2, steps_ahead=len(flights) - 3, late_rows=2, finished=1, cancelled=1,
+                   tokens_out=len(a.out_tokens) + 2)
+    assert {k: cb.stats[k] for k in counted} == counted
+    events = llm_spans()
+    steps = [e for e in events if e["name"] == "llm.step"]
+    assert [(e["live"], e["ahead"]) for e in steps] == [(live, int(live > 0 and more)) for live, more in flights]
+    # a pass that was read says what it held, late rows among them: the device ran them
+    assert [e.get("block_rows", 0) for e in steps] == [4 * live for live, _ in flights]
+    part = lambda step, name: [e for e in events if e["name"] == name and e["trace"].get("psid") == step["trace"]["sid"]]
+    for step, (live, more) in zip(steps, flights):
+        dispatch, readback = part(step, "llm.step.dispatch"), part(step, "llm.step.readback")
+        assert (len(dispatch), len(readback)) == (int(more), int(live > 0))
+        if step["ahead"]:
+            closes = dispatch[0]["mono"] + (dispatch[0]["end"] - dispatch[0]["start"])
+            assert closes <= readback[0]["mono"]
+    # each pass was handed arrays of its own, which read as they did when the scheduler has
+    # written its vectors again; both slots were fresh for the first pass and none after it
+    assert len(handed) == len(flights) - 2
+    for ints, was, floats, floats_was, _, _ in handed:
+        assert not np.shares_memory(ints, cb._ints) and not np.shares_memory(floats, cb._floats)
+        assert np.array_equal(ints, was) and np.array_equal(floats, floats_was)
+    assert [h[1][2].tolist() for h in handed] == [[1, 1]] + [[0, 0]] * (len(handed) - 1) and not cb._fresh.any()
+    assert [h[1][1].tolist() for h in handed][-2:] == [[1, 1], [1, 0]]

@@ -44,19 +44,22 @@ def program(sharpen=0.0, **over):
     return cfg, params
 
 
-def plain_generate(params, cfg, prompt, max_new):
+def plain_generate(params, cfg, prompt, max_new, eos=None):
     """The published loop over the plain reference's whole forward, a sequence
-    at a time, nothing kept between two passes.  Returns (the answer, the pass
-    of its block that fixed each of its tokens, the tokens a pass fixed)."""
+    at a time, nothing kept between two passes; the answer ends at max_new
+    tokens or with the token eos.  Returns (the answer, the pass of its block
+    that fixed each of its tokens, the tokens a pass fixed, what the last block
+    held fixed past the answer's end: [(position in the block, pass, token)])."""
     b, n = cfg.block_length, len(prompt)
     m, thr = b // cfg.denoise_steps, np.log(cfg.confidence_threshold)
     seq, out, at, sizes = list(prompt), [], [], []
-    while len(out) < max_new:
+    ended = lambda: len(out) >= max_new or (eos is not None and out[-1:] == [eos])
+    while not ended():
         start = len(seq) - len(seq) % b
         block = seq[start:] + [cfg.mask_token_id] * (b - len(seq) + start)
         masked = [i >= len(seq) - start for i in range(b)]
         when, p, gave = [-1] * b, 0, len(seq) - start
-        while len(out) < max_new and gave < b:
+        while not ended() and gave < b:
             logp = np.asarray(jax.nn.log_softmax(reference.forward(params, seq[:start] + block, cfg)[start:], axis=-1))
             tok, conf = logp.argmax(-1), np.where(masked, logp.max(-1), -np.inf)
             high = conf > thr
@@ -65,12 +68,12 @@ def plain_generate(params, cfg, prompt, max_new):
             for i in np.nonzero(fix)[0]:
                 block[i], masked[i], when[i] = int(tok[i]), False, p
             p += 1
-            while gave < b and not masked[gave] and len(out) < max_new:
+            while gave < b and not masked[gave] and not ended():
                 out.append(block[gave])
                 at.append(when[gave])
                 gave += 1
         seq = seq[:start] + block
-    return out, at, sizes
+    return out, at, sizes, [(i, when[i], block[i]) for i in range(gave, b) if not masked[i]]
 
 
 def test_prefill_then_passes_through_the_cache_give_the_references_logits():
@@ -127,9 +130,9 @@ def test_the_batchers_streams_are_a_plain_loop_over_the_reference(sharpen):
             handed[rid].extend(toks)
     sizes = []
     for r in reqs:
-        want, at, fixed = plain_generate(params, cfg, r.prompt_ids.tolist(), r.max_new_tokens)
+        want, at, fixed, tail = plain_generate(params, cfg, r.prompt_ids.tolist(), r.max_new_tokens)
         assert r.out_tokens == want == handed[r.request_id] and len(want) == r.max_new_tokens
-        assert cb.fixed_at(r.request_id) == at
+        assert cb.fixed_at(r.request_id) == at and cb.block_tail(r.request_id) == tail
         sizes += fixed
     assert cb.stats["tokens_out"] == sum(new for _, new in asked) == sum(per_step)
     assert cb.stats["block_tokens_fixed"] >= cb.stats["tokens_out"]
@@ -153,10 +156,12 @@ def test_a_prompt_token_equal_to_the_mask_id_stays_fixed():
     prompt = np.asarray([5, 9, 3, 4, 8, MASK, 11], np.int32)  # tail: 8, MASK, 11 and one masked position
     cb = ContinuousBatcher(params, cfg, slots=2, t_max=32, prefill_buckets=(8, 16), prefix_cache_entries=0)
     req = cb.submit(prompt, max_new_tokens=6)
-    cb.step()
+    cb.step()  # the admit, and the first pass dispatched
+    assert cb._blk_fixed[:, req.slot].tolist() == [1, 1, 1, 0]
+    cb.step()  # the first pass read
     assert cb._blk_tokens[:3, req.slot].tolist() == [8, MASK, 11] and cb._blk_fixed[:, req.slot].tolist() == [1, 1, 1, 1]
     cb.pump()
-    want, at, _ = plain_generate(params, cfg, prompt.tolist(), 6)
+    want, at, _, _ = plain_generate(params, cfg, prompt.tolist(), 6)
     assert req.out_tokens == want and req.fixed_at == at and at[0] == 0
     # the same prompt with another token there is another answer: the position was read as given
     other = prompt.copy()

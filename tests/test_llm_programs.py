@@ -558,7 +558,8 @@ def test_a_step_says_how_much_of_the_cache_its_live_rows_could_reach(model, llm_
     """`cache_rows_read` on `llm.step` and in `cb.stats`: of a layer's keys, the
     slots the step's attention kernel fetches, the live rows' own [pads, pos +
     the step's tokens) in whole key blocks, by the kernel's own helper on the
-    host's vectors as the step was dispatched; `cache_rows`, the slots x t_max
+    host's vectors as the step was dispatched (a pass of blocks: as it is read,
+    where the host's mirror is its input); `cache_rows`, the slots x t_max
     it is a share of.  After an admit, a request's end and a cancel, a freed
     slot's stale pos and pads count for nothing."""
     import importlib
@@ -591,8 +592,11 @@ def test_a_step_says_how_much_of_the_cache_its_live_rows_could_reach(model, llm_
                 if i == 12:
                     assert cb.cancel(reqs[1].request_id)
                 cb._admit()
+                # a causal step is reckoned as it is dispatched; a pass of blocks as it is read, on
+                # the host's mirror, which is the pass's input only then (a row that the cancel
+                # made late among them: the device ran it)
                 held = [s for s, _ in _rows_of_the_next_step(cb)] if not blocks else \
-                    [s for s, r in enumerate(cb._by_slot) if r is not None]
+                    [s for s, _ in cb._flight.rows] if cb._flight is not None else []
                 if held:
                     first, last = cb._pads[held], cb._pos[held] + tokens
                     want.append(int(sum((-(-l // 8) - f // 8) * 8 for f, l in zip(first, last))))
