@@ -48,6 +48,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -688,28 +689,78 @@ def _rope_freqs(cfg: TransformerConfig):
                    / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
 
 
+def _pair_swap(d: int, dtype):
+    """S [d, d] with (x @ S)[2i] = -x[2i + 1] and (x @ S)[2i + 1] = x[2i]: every
+    column holds one +-1, so the product is exact in any float type."""
+    i = np.arange(d)
+    s = np.zeros((d, d), np.float32)
+    s[i ^ 1, i] = np.where(i % 2 == 0, -1.0, 1.0)
+    return jnp.asarray(s, dtype)
+
+
+@jax.custom_vjp
+def _turn(x, cos, sin):
+    """x [B, T, H, D] with every adjacent pair (x[2i], x[2i + 1]) turned by its
+    angle: x * cos + (x @ S) * sin in float32 (S: `_pair_swap`), cos and sin
+    [B|1, T, 1, D] holding a pair's value on both its lanes.  The same two
+    products and one sum an element as x1 cos - x2 sin, x2 cos + x1 sin on the
+    strided halves, and the same bits; what differs is how a lane's partner
+    reaches it.  The halves `x[..., 0::2]`, `x[..., 1::2]` and the stack that
+    puts them back are copies between layouts on the chip, the pad and scatter
+    of their gradient too: the product with S leaves every lane where it lies,
+    on the matrix unit, and the compiler makes the rest its epilogue, one pass
+    that reads x and writes the result.  S's entries are +-1 and the product
+    accumulates in float32, so it is exact for bf16; a float32 x asks for the
+    exact passes.
+
+    The gradient is stated, the turn back (cos, -sin) of the cotangent in the
+    same float32 and rounded once, as the strided halves' gradient was: it is
+    that gradient to the bit for bf16 (tests/test_rope.py).  Derived, the
+    transpose of the product hands back a bf16 cotangent of its own, the
+    x * cos branch another, and their sum is rounded a third time: a quarter
+    of a bf16 gradient's elements then differ from the definition's.  (On the
+    chip the derived one also widens the cotangent and copies it into the
+    forward's layout before it meets S; one reading, 219.7 against 217.6 ms
+    below, is no more than a hint.)
+
+    Which form, chosen by microbenchmarks on a v5e (PERF.md section 6, PR 49:
+    the least of 10 calls, one seed, one device; what the change is worth is
+    read in the cells' windows, there too).  The strided halves / jnp.roll by
+    +1 and -1 with a select / this product with its gradient derived / as here
+    / a Pallas kernel of pltpu.roll and a select.  Inside the programs:
+    Mistral-7B's decode step of 8 layers at 32 slots 5.320 / 5.244 / 5.232 /
+    5.234 / 5.227 ms; a prefill of 8,192 rows through 2 layers of 64 + 8 heads
+    70.5 / 72.5 / 62.5 / 62.5 / 62.1 ms; the train step of 2 layers over 2 x
+    4,096 rows, forward, recomputed and backward, 227.4 / 235.4 / 219.7 / 217.6
+    / 217.9 ms.  Alone, q and k of the train step's shape, forward and with
+    the gradient: 1.01 and 2.92 / 1.90 and 4.62 / 0.23 and 0.32 / 0.23 and 0.32
+    / 0.49 and 0.72 ms (jnp.roll keeps four float32 copies of q).  One form
+    serves every shape; the kernel gains 0.2 ms a layer at 8,192 rows and
+    nothing elsewhere, and would be a second path wherever there is no TPU.
+    cos and sin are made again at every layer of the decode step's loop: made
+    once before it, 5.227 ms (0.9 us a layer), so the call stays where it is."""
+    swap = jnp.matmul(x, _pair_swap(x.shape[-1], x.dtype), preferred_element_type=jnp.float32,
+                      precision=lax.Precision.HIGHEST if x.dtype == jnp.float32 else None)
+    return (x.astype(jnp.float32) * cos + swap * sin).astype(x.dtype)
+
+
+_turn.defvjp(lambda x, cos, sin: (_turn(x, cos, sin), (cos, sin)),
+             lambda tables, g: (_turn(g, tables[0], -tables[1]), None, None))
+
+
 def _rope(q, k, positions, cfg: TransformerConfig):
     """Rotary embeddings; q,k: [B, T, H, D]. positions: [T] global positions,
     or [B, T] per-row positions (left-padded prompts shift each row's real
     tokens to start at position 0)."""
     freqs, magnitude = _rope_freqs(cfg)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, D/2]
+    angles = positions[..., None].astype(jnp.float32) * jnp.repeat(freqs, 2)  # [..., T, D]
     if angles.ndim == 2:
         angles = angles[None]  # broadcast over batch
-    cos = jnp.cos(angles)[:, :, None, :]  # [B|1, T, 1, D/2]
+    cos = jnp.cos(angles)[:, :, None, :]  # [B|1, T, 1, D]
     sin = jnp.sin(angles)[:, :, None, :]
     if magnitude != 1.0:
         cos, sin = cos * magnitude, sin * magnitude
-
-    def rot(x):
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-        xr1 = x1 * cos - x2 * sin
-        xr2 = x2 * cos + x1 * sin
-        return jnp.stack([xr1, xr2], axis=-1).reshape(x.shape)
-
-    return rot(q.astype(jnp.float32)).astype(q.dtype), rot(k.astype(jnp.float32)).astype(
-        k.dtype
-    )
+    return _turn(q, cos, sin), _turn(k, cos, sin)
 
 
 # The rows (batch x positions) up to which a projection that is reshaped to heads is kept apart

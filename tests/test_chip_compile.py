@@ -127,7 +127,9 @@ def _buffers(compiled, width=100):
     keeps in memory: each instruction outside a fusion's body, its text cut to
     `width` characters."""
     text = compiled.as_text()
-    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
+    # a fusion that writes an operand in place names the aliasing before its body (the sharded train step has
+    # them; the decode steps of the six serving widths have none and list what they listed without this)
+    fused = set(re.findall(r"kind=k\w+, (?:output_to_operand_aliasing=\{.*?\}, )?calls=%([\w.\-]+)", text))
     out, inside = [], None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
@@ -676,6 +678,36 @@ def test_decode_step_multiplies_by_the_projections_where_they_are_stored(v5e, mo
     assert stacks <= seen and made == []
 
 
+def _under_rope(compiled):
+    """(dtype, elements, operation, instruction) of every value the optimized
+    program keeps in memory under the scope `attn.rope`."""
+    return [(dtype, n, re.match(r"(?:ROOT )?%[\w.\-]+ = \S+ ([\w\-]+)\(", line).group(1), line[:200])
+            for dtype, n, line in _buffers(compiled, width=None) if "attn.rope" in line]
+
+
+@pytest.mark.parametrize("model", ["MISTRAL4", "KEXAONE4", "SDAR3", "AXK13"])
+def test_decode_step_turns_the_pairs_where_they_lie(v5e, model):
+    """The decode step at the rotating serving configurations' widths as the
+    chip's compiler leaves it: under `attn.rope` q and k are each one fusion,
+    the product with the pair-swap matrix and the turn its epilogue
+    (`transformer._turn`), and nothing there is as large as q in float32.  On
+    the strided halves Mistral's step held two float32 copies of q and of k,
+    a pad and two more copies in bf16 a layer (`copy` f32[32,32,128]{1,0,2},
+    `pad_maximum_fusion` bf16[32,1,32,64,2], `copy` bf16[32,1,32,64,2]).  Where
+    a row is one position and a head 128 wide, no `copy` at all; a pass over
+    blocks of 4 positions still copies its 4 cached heads' k (128 kB) into the
+    cache's tiling, and the latent model slices its rotary 64 out of 192."""
+    cfg = transformer.TransformerConfig(**globals()[model])
+    compiled, _, _ = _compiled_decode_step(cfg, v5e[0], *_PROJECTED[model], on_kernel=True)
+    held = _under_rope(compiled)
+    q = 32 * max(cfg.block_length, 1) * cfg.n_heads * cfg.rope_dim
+    assert any(op == "fusion" and dtype == "bf16" and n == q for dtype, n, op, _ in held)
+    assert [line for dtype, n, _, line in held if dtype == "f32" and n >= q] == []
+    assert [line for _, _, op, line in held if op in ("pad", "gather", "scatter")] == []
+    if model in ("MISTRAL4", "KEXAONE4"):
+        assert [line for _, _, op, line in held if op == "copy"] == []
+
+
 def test_a_cache_of_one_row_keeps_the_dense_contraction(v5e, on_tpu):
     """An admit's suffix step (one request's rows, a cache of batch one) at
     Mistral's widths: no decode kernel, and nothing of a stack's size beside
@@ -740,6 +772,12 @@ def test_train_step_compiles_on_a_mesh(v5e, on_tpu, spec):
     }
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, batch).compile()
     assert _has_kernel(compiled)
+    # forward, recomputation and backward turn q and k where they lie (`transformer._turn`): nothing under
+    # `attn.rope` is as large as a device's q in float32 (the strided halves' copies, their gradient's pad)
+    held = _under_rope(compiled)
+    q = 8 // (spec.dp * spec.fsdp) * 1024 * cfg.n_heads // spec.tp * cfg.d_head
+    assert held  # the scope is seen
+    assert [line for dtype, n, op, line in held if (dtype == "f32" and n >= q) or op in ("pad", "gather", "scatter")] == []
     # params + Adam moments really are spread: a quarter each under fsdp x tp
     if spec.fsdp * spec.tp == 4:
         whole = 3 * sum(x.size * 4 for x in jax.tree_util.tree_leaves(params))
