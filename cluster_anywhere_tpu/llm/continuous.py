@@ -521,6 +521,12 @@ class ContinuousBatcher:
             # (token, expert) pairs a layer's routed experts were given, in
             # admits and steps; stays 0 for a dense model
             "moe_assignments": 0,
+            # the steps' `moe_experts_touched` summed (each the mean over the expert layers
+            # of the experts given a row): over `decode_steps`, what a step's experts read
+            "moe_experts_touched": 0.0,
+            # a replica that holds a share of the experts: its admits' expert layers, and
+            # those of them that took the compact buffer (parallel/moe.py)
+            "moe_held_layers": 0, "moe_compact_layers": 0,
             # admits that found no compiled prefill for their padded length
             # and traced one; stays where it is once every bucket is warm
             "prefill_traces": 0,
@@ -716,6 +722,7 @@ class ContinuousBatcher:
             if held:
                 said.update(moe_held_assignments=float(held[0]))
             self.stats["moe_assignments"] += positions * self.cfg.n_experts_per_tok
+            self.stats["moe_experts_touched"] += float(touched)
         if self._ssm_step_bytes:
             said.update(ssm_state_bytes=self._ssm_step_bytes)
             self.stats["ssm_state_bytes"] += self._ssm_step_bytes
@@ -883,6 +890,8 @@ class ContinuousBatcher:
             self.stats["prefill_traces"] += prefill_counted._cache_size() - programs
             tail = 1 if self.cfg.carries else bucket
             sp.set(prefill_positions=bucket, tail_positions=tail)
+            if self.cfg.ssm_n_heads:  # a Mamba-2 prefill's scan runs in chunks (models/transformer.py _ssd_scan)
+                sp.set(ssm_chunks=-(-bucket // self.cfg.ssm_chunk))
             self.stats["prefill_positions_total"] += bucket
             self.stats["prefill_tail_positions_total"] += tail
             self.stats["prefill_tail_share"] = (
@@ -1059,6 +1068,8 @@ class ContinuousBatcher:
             first = int(first)
         if held is not None:
             sp.set(moe_held_layers=int(held[0]), moe_compact_layers=int(held[1]))
+            self.stats["moe_held_layers"] += int(held[0])
+            self.stats["moe_compact_layers"] += int(held[1])
         self._tokens[slot], self._fresh[slot] = first, 1
         self._pos[slot] = next_pos  # next write lands after the prompt
         self._pads[slot] = pad

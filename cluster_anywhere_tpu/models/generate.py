@@ -8,6 +8,7 @@ of state the configuration's layers keep, each stacked over the layers of its
 kind alone,
     k, v: [n_attn, B, T_max, H_kv, D]
     conv: [n_ssm, B, K-1, C], h: [n_ssm, B, C, N] (float32)
+        (a Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N])
     ckv: [n_attn, B, T_max, R], kr: [n_attn, B, T_max, rope up to 128s]  (latent
         attention: a token is one latent row and the one rotated key every
         head shares, no heads axis; `LATENT_LANES` says why the key is padded)
@@ -114,8 +115,8 @@ from ..ops.attention import (
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
     _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gmu_block, _gqa_repeat, _hand_on,
-    _head, _latent_expand, _latent_up, _scan_layers, _ssm_block_forward, _ssm_half, _ssm_mix, _x, carried,
-    core_scope, is_window, layer_stacks,
+    _head, _latent_expand, _latent_up, _mamba2_half, _mamba2_zero_state, _scan_layers, _ssm_block_forward, _ssm_half,
+    _ssm_mix, _x, carried, core_scope, is_window, layer_stacks,
 )
 
 # what a layer of each kind of state keeps of a sequence between two tokens, as
@@ -126,8 +127,8 @@ LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr")
 STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache", "attn_win": "attn.cache"}
 # the kinds of layer that keep no rows of their own: a gated memory unit keeps nothing
 # between two tokens (its memory is the step's own), a cross layer reads the stack of keys
-# and values that the one full layer before it writes
-NO_ROWS = ("gmu", "attn_cross")
+# and values that the one full layer before it writes, an FFN alone mixes no positions
+NO_ROWS = ("gmu", "attn_cross", "ffn")
 
 
 # The rotated key's last axis in the cache is padded with zeros to a multiple
@@ -149,10 +150,10 @@ def _state_kind(kind: str, cfg: TransformerConfig) -> Optional[str]:
     """The kind of state (LAYER_STATE's key) a layer of `kind` keeps, or of a
     cross layer (which keeps none: NO_ROWS) reads; None for a layer that
     touches none."""
-    if kind == "gmu":
+    if kind in ("gmu", "ffn"):
         return None
-    if kind == "ssm":
-        return kind
+    if kind in ("ssm", "mamba2"):  # a Mamba-2 layer's window and h are the "ssm" rows, at its own shapes
+        return "ssm"
     if is_window(kind):
         return "attn_win"
     return "latent" if cfg.latent else "attn"
@@ -298,12 +299,13 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     the window layers' kw, vw [n_win, B, W, KV, D], W = `window_extent`: an
     extent of their own, whatever the context's length;
     a state-space layer's convolution window [n_ssm, B, K-1, C] and its h
-    [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length.  A layer
+    [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length (a
+    Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N]).  A layer
     that keeps no rows (NO_ROWS) adds nothing; KV and D are the heads as they
     are cached (`cfg.cached_heads`, `cfg.cached_width`), and under
     `cfg.flat_heads` the four stacks of keys and values are [n, B, T * KV, D]."""
     kinds = [kind for kind in cfg.layer_kinds if kind not in NO_ROWS]
-    n_ssm = kinds.count("ssm")
+    n_ssm = kinds.count("ssm") + kinds.count("mamba2")
     n_win = sum(map(is_window, kinds))
     n_attn = len(kinds) - n_ssm - n_win
     cache = {}
@@ -320,9 +322,12 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
         shape = (n_attn, batch, *slots(t_max), cfg.cached_width)
         cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
     if n_ssm:
+        # Mamba-2: [heads, head_dim, N] a slot, and a window over x, B and C together
+        state = ((cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state) if "mamba2" in kinds
+                 else (cfg.d_inner, cfg.ssm_d_state))
         cache.update(
-            conv=jnp.zeros((n_ssm, batch, cfg.ssm_d_conv - 1, cfg.d_inner), cfg.dtype),
-            h=jnp.zeros((n_ssm, batch, cfg.d_inner, cfg.ssm_d_state), SSM_STATE_DTYPE),
+            conv=jnp.zeros((n_ssm, batch, cfg.ssm_d_conv - 1, cfg.conv_width), cfg.dtype),
+            h=jnp.zeros((n_ssm, batch, *state), SSM_STATE_DTYPE),
         )
     return cache
 
@@ -564,7 +569,7 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core, kind, layer)
     if live is not None:
         live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
-    x, _, touched = _ffn_half(bp, x, cfg, live, experts)
+    x, _, touched = _ffn_half(bp, x, cfg, live, experts, kind=kind)
     return x, cache, touched
 
 
@@ -577,7 +582,7 @@ def _prefill_tail(cfg: TransformerConfig, kind: str) -> bool:
     queries and everything above at the last alone.  The loop goes on carrying
     x whole (a scan's carry keeps its shape) and such a layer reads and writes
     its last row."""
-    return kind in NO_ROWS or (kind == "attn" and cfg.shared_readers > 0)
+    return cfg.carries and (kind in NO_ROWS or (kind == "attn" and cfg.shared_readers > 0))
 
 
 def _prefill_block(bp, s, pad, cfg: TransformerConfig, t_max: int, experts=None, kind: str = "attn", layer=None):
@@ -654,14 +659,14 @@ def _prefill_block(bp, s, pad, cfg: TransformerConfig, t_max: int, experts=None,
         # the last column is a prompt's last token whatever its pad
         x_last, made = _attention_half(bp, x[:, -1:], cfg, positions[..., -1:], last_core, kind, layer,
                                        kv_of=x if "wk" in bp else None)
-        x_last, _, touched = _ffn_half(bp, x_last, cfg, None, experts)
+        x_last, _, touched = _ffn_half(bp, x_last, cfg, None, experts, kind=kind)
         layer_cache, handed = made or (None, (s.k, s.v))
         x = lax.dynamic_update_slice(x, x_last, (0, t - 1, 0))
         return s._replace(x=x, k=handed[0], v=handed[1]), layer_cache, touched
     x, layer_cache = _attention_half(bp, x, cfg, positions, latent_core if cfg.latent else core, kind, layer)
     # the left padding takes no expert
     live = None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
-    x, _, touched = _ffn_half(bp, x, cfg, live, experts)
+    x, _, touched = _ffn_half(bp, x, cfg, live, experts, kind=kind)
     return _hand_on(s, x), layer_cache, touched
 
 
@@ -692,14 +697,35 @@ def _ssm_block_decode(bp, x, cache, layer, cfg: TransformerConfig, live=None, ex
     return x, cache, touched, y
 
 
+def _mamba2_block_decode(bp, x, cache, layer, cfg: TransformerConfig):
+    """One Mamba-2 layer, one token a row, from each row's own state.  x:
+    [B, 1, E]; cache: the state-space stacks conv [n, B, K-1, C + 2 G N] and h
+    [n, B, H, P, N] (among whatever else it holds) and layer: this one's number
+    among them.  As `_ssm_block_decode`: the layer's state is read out of the
+    stacks and the new one written in its place, whole, once; a donated cache's
+    1.5 GB of state at Nemotron-H's widths are updated where they lie
+    (tests/test_chip_compile.py).  Returns (x, the cache after)."""
+    names = LAYER_STATE["ssm"]
+    with jax.named_scope(STATE_SCOPE["ssm"]):
+        state = tuple(lax.dynamic_index_in_dim(cache[n], layer, keepdims=False) for n in names)
+    x, state = _mamba2_half(bp, x, cfg, state)
+    with jax.named_scope(STATE_SCOPE["ssm"]):
+        after = {n: lax.dynamic_update_index_in_dim(cache[n], new, layer, 0) for n, new in zip(names, state)}
+    return x, {**cache, **after}
+
+
+def _pad_keep(pad, t: int):
+    """[B, T] bool, False at a left pad; None where there are none."""
+    return None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
+
+
 def _ssm_prefill_block(bp, x, pad, cfg: TransformerConfig, experts=None):
     """One state-space block over the whole prompt from the zero state;
     returns the state after the last token, (window [B, K-1, C], h [B, C, N]),
     the experts touched or None, and the mixer's read-out y [B, T, C].
     pad: [B] left-pad counts or None: a pad's input and step size are zeroed, so
     the state, and the logits, are the unpadded prompt's in any bucket."""
-    keep = None if pad is None else jnp.arange(x.shape[1])[None, :] >= pad[:, None]
-    x, _, layer_state, touched, y = _ssm_block_forward(bp, x, cfg, keep, experts)
+    x, _, layer_state, touched, y = _ssm_block_forward(bp, x, cfg, _pad_keep(pad, x.shape[1]), experts)
     return x, layer_state, touched, y
 
 
@@ -733,7 +759,17 @@ def prefill_counted(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
         return s._replace(x=lax.dynamic_update_slice(s.x, last.x, (0, s.x.shape[1] - 1, 0))), None, (None, None)
 
     t = ids.shape[1]
-    x, _, outs = _scan_blocks(_bodies(attn, ssm, gmu), carried(x, cfg, 1, t), params, cfg)
+    keep = _pad_keep(pad, t)  # the left padding keeps its state and takes no expert
+
+    def mamba2(x, bp, _experts, _cache, _layer):
+        x, state = _mamba2_half(bp, x, cfg, _mamba2_zero_state(cfg, x.shape[0]), keep)
+        return x, None, (state, None)
+
+    def ffn(x, bp, experts, _cache, _layer):
+        x, _, touched = _ffn_half(bp, x, cfg, keep, experts)
+        return x, None, (None, touched)
+
+    x, _, outs = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn), carried(x, cfg, 1, t), params, cfg)
     x = _x(x)
     rows = {kind: made for kind, (made, _) in outs.items() if kind not in NO_ROWS}
     # each kind's rows into the stacks of the state it keeps, at its layers' places there
@@ -762,9 +798,9 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     return prefill_counted(params, ids, cfg, t_max, pad=pad)[:2]
 
 
-def _bodies(attn, ssm, gmu=None):
+def _bodies(attn, ssm, gmu=None, mamba2=None, ffn=None):
     """`_scan_blocks`' bodies: every attention kind's is `attn(kind, ...)`."""
-    own = {"ssm": ssm, "gmu": gmu}
+    own = {"ssm": ssm, "gmu": gmu, "mamba2": mamba2, "ffn": ffn}
     return {kind: own[kind] if kind in own else functools.partial(attn, kind) for kind in _INIT_KIND}
 
 
@@ -805,7 +841,14 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     def gmu(s, bp, _experts, cache, _layer):
         return _gmu_block(bp, s, cfg), cache, None
 
-    x, cache, touched = _scan_blocks(_bodies(attn, ssm, gmu), carried(x, cfg, 1), params, cfg, cache)
+    def mamba2(x, bp, _experts, cache, layer):
+        return (*_mamba2_block_decode(bp, x, cache, layer, cfg), None)
+
+    def ffn(x, bp, experts, cache, _layer):
+        x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
+        return x, cache, touched
+
+    x, cache, touched = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn), carried(x, cfg, 1), params, cfg, cache)
     x = _x(x)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None

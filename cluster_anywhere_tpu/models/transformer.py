@@ -29,6 +29,11 @@ leading dense layers (kind "attn_dense": a run of its own in the loop), shared
 experts beside the routed ones (`_ffn_half`) and, on one chip of several that
 divide a layer's experts, a held share of them (parallel/moe.py routed_ffn).
 
+A layer may also be ONE half alone (`HALF_KINDS`: a Mamba-2 mixer
+`_mamba2_half` over `_ssd_scan`, attention, an FFN), x + f(norm(x)) with the
+one norm, in a stack whose mixers and FFNs stand in any order; `_ffn_half`
+hands x back where a block holds no second half.
+
 Which layer mixes how may also be a tuple (`cfg.layer_mixers`): window layers
 ("attn_win": the last `cfg.attn_window` positions, `_attention(window=)`)
 beside full ones, each kind's weights a stack of its own (`_INIT_KIND`); which
@@ -179,8 +184,10 @@ class TransformerConfig:
     experts_held: Optional[Tuple[int, int]] = None
     # each layer's mixer, in the model's order: "attn" (every earlier position),
     # "attn_win" (the last attn_window positions: itself and attn_window - 1
-    # before it) or "ssm".  None: the period and offset above.  `layer_kinds`
-    # says how a mixer and a leading dense FFN make one kind.
+    # before it), "ssm", "gmu" or "attn_cross" (below), each a block of two
+    # halves with its FFN; or, in a stack of half layers, what the ONE half is:
+    # "mamba2", "attn_alone" or "ffn" (HALF_KINDS).  None: the period and offset
+    # above.  `layer_kinds` says how a mixer and a leading dense FFN make one kind.
     layer_mixers: Optional[Tuple[str, ...]] = None
     attn_window: int = 0
     # the slots a window layer's cache keeps of a row, written round (position p
@@ -210,6 +217,21 @@ class TransformerConfig:
     # learned vectors a layer, lambda_init = 0.8 - 0.6 exp(-0.3 depth).  A pair's two
     # cached heads are cached as one of twice the width (`cached_heads`).
     diff_attn: bool = False
+    # a layer may be ONE half alone (`layer_mixers` "mamba2", "attn_alone", "ffn": HALF_KINDS), x + f(norm(x)) with
+    # the one norm; such a stack's mixers and FFNs stand in any order, two mixers in a row among them.
+    # Mamba-2 (mixer "mamba2": `_mamba2_half`): ssm_n_heads heads of ssm_head_dim channels (their product is
+    # the inner width, whatever ssm_expand says), one scalar decay a head, B and C of ssm_d_state shared by the
+    # heads of each of ssm_n_groups groups, a causal convolution over x, B and C together, a state of
+    # [heads, head_dim, ssm_d_state] a layer, a gated RMSNorm over the groups before the output projection, and
+    # a prefill in chunks of ssm_chunk positions whose inside is matrix products (`_ssd_scan`)
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_n_groups: int = 1
+    ssm_chunk: int = 128
+    # an expert's activation (parallel/moe.py ACTIVATIONS): "silu", or "relu2", relu(x)^2; the shared
+    # expert's too, which is gated where the routed ones are and (act(x w_in)) w_out where they are not
+    moe_act: str = "silu"
+    d_shared: int = 0  # the shared expert's width; 0: n_shared_experts * d_expert
 
     def __post_init__(self):
         if self.generates_blocks and self.block_length % self.denoise_steps:
@@ -227,12 +249,25 @@ class TransformerConfig:
             raise NotImplementedError("a pass over blocks of positions through a latent cache")
         if self.layer_mixers is not None:
             object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))  # hashable whatever carried it
-            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm", "gmu", "attn_cross"})
+            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm", "gmu", "attn_cross", *HALF_KINDS})
             if unknown or len(self.layer_mixers) != self.n_layers or self.attn_layer_period:
                 raise ValueError(
-                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm, gmu, attn_cross for each "
-                    f"of the {self.n_layers} layers, and no attn_layer_period beside it"
+                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm, gmu, attn_cross, "
+                    f"{', '.join(HALF_KINDS)} for each of the {self.n_layers} layers, and no attn_layer_period beside it"
                 )
+            if self.half_layers:
+                if (set(self.layer_mixers) - set(HALF_KINDS) or self.latent or self.generates_blocks or self.diff_attn
+                        or self.n_dense_layers or self.norm_output or self.pp > 1 or self.sp > 1):
+                    raise NotImplementedError(
+                        f"layer_mixers={self.layer_mixers}: a stack of half layers ({', '.join(HALF_KINDS)}) holds no "
+                        "paired block, and runs without latent or differential attention, blocks of positions, "
+                        "leading dense layers, normed outputs, pipeline stages or a sequence axis")
+                if "mamba2" in self.layer_mixers and (
+                        self.ssm_n_heads <= 0 or self.ssm_head_dim <= 0 or self.ssm_n_heads % self.ssm_n_groups
+                        or self.d_inner % self.ssm_n_groups or self.ssm_chunk <= 0):
+                    raise ValueError(
+                        f"a mamba2 layer takes ssm_n_heads={self.ssm_n_heads} heads of ssm_head_dim={self.ssm_head_dim} "
+                        f"in ssm_n_groups={self.ssm_n_groups} equal groups, and chunks of ssm_chunk={self.ssm_chunk}")
             tail = [i for i, m in enumerate(self.layer_mixers) if m in ("gmu", "attn_cross")]
             if tail:
                 # the second half of a decoder-hybrid-decoder: it reads what ONE full layer cached
@@ -301,8 +336,9 @@ class TransformerConfig:
         loop scans as one run and what is stacked on a leading axis of its
         own, parameters (`_INIT_KIND`) and cache rows (models/generate.py
         LAYER_STATE) alike.  It is the layer's mixer, "attn", "attn_win" (a
-        window layer) or "ssm", from `layer_mixers` or, without one, from the
-        period and offset; and a mixture's leading `n_dense_layers`, which keep
+        window layer), "ssm", "gmu", "attn_cross" or a half layer's own
+        ("mamba2", "attn_alone", "ffn": HALF_KINDS), from `layer_mixers` or,
+        without one, from the period and offset; and a mixture's leading `n_dense_layers`, which keep
         a dense FFN, are a kind of their mixer's with "_dense" behind it:
         "attn_dense", "attn_win_dense".  The two properties are independent:
         the suffix decides the FFN's weights and nothing else, the mixer the
@@ -342,8 +378,10 @@ class TransformerConfig:
         attention caches 10 pairs at Phi-4-mini-flash's widths: its stacks are
         the rows themselves.  The switch is the configuration served with such
         a count, not the count: the other configurations' stacks keep the
-        layout their programs were measured with (tests/test_program_text.py)."""
-        return self.cached_heads if self.diff_attn else 0
+        layout their programs were measured with (tests/test_program_text.py).
+        A stack of half layers caches 2 heads at Nemotron-H's widths and is
+        flat as well."""
+        return self.cached_heads if self.diff_attn or self.half_layers else 0
 
     @property
     def carries(self) -> bool:
@@ -369,8 +407,20 @@ class TransformerConfig:
         return self.rotary and (self.rotary_full or is_window(kind))
 
     @property
+    def half_layers(self) -> bool:
+        """Whether the stack is made of half layers (HALF_KINDS)."""
+        return any(m in HALF_KINDS for m in self.layer_mixers or ())
+
+    @property
     def d_inner(self) -> int:
-        return self.ssm_expand * self.d_model
+        """A state-space mixer's inner width: Mamba-2's heads x their width, Mamba-1's ssm_expand x d_model."""
+        return self.ssm_n_heads * self.ssm_head_dim if self.ssm_n_heads else self.ssm_expand * self.d_model
+
+    @property
+    def conv_width(self) -> int:
+        """The channels a state-space mixer's convolution runs over, which its window in the cache keeps:
+        Mamba-1's x; Mamba-2's x, B and C side by side."""
+        return self.d_inner + (2 * self.ssm_n_groups * self.ssm_d_state if self.ssm_n_heads else 0)
 
     @property
     def layers_per_stage(self) -> int:
@@ -382,6 +432,13 @@ class TransformerConfig:
         if self.attn_impl != "auto":
             return self.attn_impl
         return "ring" if self.sp > 1 else "dense"
+
+
+# the kinds of layer that are one half alone, x + f(norm(x)) with one norm `ln1` (the FFN's: `ln2`, as `_ffn_half`
+# reads it): a Mamba-2 mixer, attention, an FFN.  Each kind's weights are a stack of its own and the layer loop scans
+# their periods ([mamba2, ffn] x n, [ffn, mamba2] x n) and single layers (`_layer_runs`), a sequence of them that
+# repeats as one loop of loops (`_run_groups`).
+HALF_KINDS = ("mamba2", "attn_alone", "ffn")
 
 
 def is_window(kind: str) -> bool:
@@ -420,12 +477,16 @@ def _init_ffn(ks, cfg: TransformerConfig):
                **init_moe_params(ks[0], e, fx, cfg.n_experts, pd, gated=cfg.moe_gated,
                                  held=cfg.experts_held and cfg.experts_held[1])}
         if cfg.n_shared_experts:
-            fs, kg, ku, kd = cfg.n_shared_experts * fx, *jax.random.split(ks[1], 3)
-            out.update(
-                shared_gate=jax.random.normal(kg, (e, fs), pd) * s(e),
-                shared_up=jax.random.normal(ku, (e, fs), pd) * s(e),
-                shared_down=jax.random.normal(kd, (fs, e), pd) * s(fs),
-            )
+            fs, kg, ku, kd = cfg.d_shared or cfg.n_shared_experts * fx, *jax.random.split(ks[1], 3)
+            if cfg.moe_gated:
+                out.update(
+                    shared_gate=jax.random.normal(kg, (e, fs), pd) * s(e),
+                    shared_up=jax.random.normal(ku, (e, fs), pd) * s(e),
+                    shared_down=jax.random.normal(kd, (fs, e), pd) * s(fs),
+                )
+            else:  # as the routed experts are: no gate
+                out.update(shared_in=jax.random.normal(ku, (e, fs), pd) * s(e),
+                           shared_out=jax.random.normal(kd, (fs, e), pd) * s(fs))
         return out
     return {
         **_init_norm("ln2", cfg, jax.random.fold_in(ks[0], 1)),
@@ -435,9 +496,10 @@ def _init_ffn(ks, cfg: TransformerConfig):
     }
 
 
-def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True):
+def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True, ffn: bool = True):
     """An attention block; `keys_and_values` False: one that projects queries
-    alone and reads another layer's keys and values (kind "attn_cross")."""
+    alone and reads another layer's keys and values (kind "attn_cross"); `ffn`
+    False: the attention half alone (kind "attn_alone")."""
     e, h, kv, d = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     ks = jax.random.split(key, 7)
     s = lambda fan_in: fan_in ** -0.5
@@ -479,7 +541,8 @@ def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True):
         per_head = cfg.qk_norm_per_head
         out.update({"q_norm": jnp.ones((d if per_head else h * d,), pd),
                     "k_norm": jnp.ones((d if per_head else kv * d,), pd)})
-    out.update(_init_ffn(ks[4:], cfg))
+    if ffn:
+        out.update(_init_ffn(ks[4:], cfg))
     return out
 
 
@@ -513,6 +576,45 @@ def _init_ssm_block(key, cfg: TransformerConfig):
     return out
 
 
+def _init_mamba2_block(key, cfg: TransformerConfig):
+    """A Mamba-2 mixer alone (kind "mamba2"), initialised as Mamba-2 is: A = -(a
+    number uniform in [1, 16]) a head, the step size's bias the inverse
+    softplus of a step log-uniform in [1e-3, 1e-1], D = 1, the gated norm's
+    weight 1.  `ssm_in` projects to [z (d_inner), x B C (conv_width), dt
+    (heads)] side by side, then columns of zeros up to whole tiles of lanes
+    (parallel/moe.py LANES: the chip lays [2688, 10304] out with 2688 innermost,
+    a loop inside a loop takes the stack with its last axis innermost, and the
+    compiled step then copied all 23 layers' matrices, 1.27 GB, at every call;
+    at 10,368 the stored layout is the one every loop reads); the convolution's
+    taps are stored [K, conv_width]."""
+    from ..parallel.moe import LANES
+
+    e, c, w, hh, kw = cfg.d_model, cfg.d_inner, cfg.conv_width, cfg.ssm_n_heads, cfg.ssm_d_conv
+    ks = jax.random.split(key, 7)
+    s = lambda fan_in: fan_in ** -0.5
+    pd = cfg.param_dtype
+    step = jnp.exp(jax.random.uniform(ks[3], (hh,)) * (jnp.log(1e-1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    out = {
+        **_init_norm("ln1", cfg, ks[6]),
+        "ssm_in": jnp.pad(jax.random.normal(ks[0], (e, c + w + hh), pd) * s(e), ((0, 0), (0, -(c + w + hh) % LANES))),
+        "conv_w": jax.random.normal(ks[1], (kw, w), pd) * s(kw),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+        "a_log": jnp.log(jax.random.uniform(ks[2], (hh,), minval=1.0, maxval=16.0)).astype(pd),
+        "ssm_d": jnp.ones((hh,), pd),
+        "ssm_norm": jnp.ones((c,), pd),
+        "ssm_out": jax.random.normal(ks[4], (c, e), pd) * s(c),
+    }
+    if cfg.ssm_conv_bias:
+        out["conv_b"] = jax.random.normal(ks[5], (w,), pd) * 0.02
+    return out
+
+
+def _init_ffn_block(key, cfg: TransformerConfig):
+    """An FFN alone (kind "ffn"): `_init_ffn`'s norm `ln2` and its dense
+    matrices or experts."""
+    return _init_ffn(jax.random.split(key, 3), cfg)
+
+
 def _init_dense_block(key, cfg: TransformerConfig):
     """An attention block of a mixture that keeps the dense FFN of d_ff."""
     return _init_block(key, dataclasses.replace(cfg, n_experts=0, n_dense_layers=0, experts_held=None))
@@ -535,7 +637,10 @@ _INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_b
               "attn_dense": ("dense_blocks", _init_dense_block),
               "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _init_dense_block),
               "gmu": ("gmu_blocks", _init_gmu_block),
-              "attn_cross": ("cross_blocks", functools.partial(_init_block, keys_and_values=False))}
+              "attn_cross": ("cross_blocks", functools.partial(_init_block, keys_and_values=False)),
+              "mamba2": ("mamba2_blocks", _init_mamba2_block),
+              "attn_alone": ("alone_blocks", functools.partial(_init_block, ffn=False)),
+              "ffn": ("ffn_blocks", _init_ffn_block)}
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -543,7 +648,8 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     the state-space layers', where the pattern has any; `dense_blocks`: a
     mixture's leading dense layers'; `win_blocks`, `win_dense_blocks`: the
     window layers' of either FFN; `gmu_blocks`, `cross_blocks`: the gated memory
-    units' and the cross layers' (`_INIT_KIND`: a stack a kind).  Layer i's
+    units' and the cross layers'; `mamba2_blocks`, `alone_blocks`, `ffn_blocks`:
+    the half layers' (`_INIT_KIND`: a stack a kind).  Layer i's
     key is the i-th of one split whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
@@ -929,7 +1035,7 @@ def _moe(bp, y, cfg: TransformerConfig, live=None, experts=None):
     r = routed_ffn(
         y.reshape(b * t, e), bp["router"], stack, layer, k=cfg.n_experts_per_tok,
         renormalize=cfg.moe_renormalize, live=None if live is None else live.reshape(b * t),
-        scoring=cfg.moe_scoring, scale=cfg.moe_routed_scale, held=cfg.experts_held,
+        scoring=cfg.moe_scoring, scale=cfg.moe_routed_scale, held=cfg.experts_held, act=cfg.moe_act,
     )
     touched = r.experts_touched
     if cfg.experts_held is not None:
@@ -1157,15 +1263,142 @@ def _ssm_half(bp, x, cfg: TransformerConfig, core, keep=None):
     return x, extra
 
 
+def _ssd_scan(dt, a, b, c, xs, h0, chunk: int):
+    """Mamba-2's recurrence, h_t[h] = exp(dt_t[h] a[h]) h_{t-1}[h] + dt_t[h] xs_t[h] (x) b_t[g], y_t[h] = h_t[h] c_t[g],
+    head h in group g = h // (H / G), from h0.  dt: [B, T, H]; a: [H]; b, c: [B, T, G, N]; xs: [B, T, H, P]; h0:
+    [B, H, P, N], all float32.  Returns (y [B, T, H, P], h_T).
+
+    One token is one step, elementwise over the state.  A sequence runs in chunks of `chunk` positions as SSD
+    does (arXiv:2405.21060, section 6), the tail of the last chunk steps of dt = 0.  Inside a chunk, under scope
+    `ssm.scan.chunk`, matrix products: Y = ((C B^T) * L) (dt xs) with L[i, j] = exp(sum_{j<k<=i} dt_k a) for
+    i >= j, and the chunk's own state sum_j exp(sum_{k>j} dt_k a) dt_j xs_j (x) B_j.  Between chunks, under
+    `ssm.scan.carry`, T / chunk dependent steps hand the state on, and what a chunk's first state adds to its
+    positions, C_i exp(sum_{k<=i} dt_k a) h_prev, is one more product.  Nothing of size [T, H, P, N] is kept:
+    the chunks' first states are [T / chunk, H, P, N].  The operands are float32 and the products exact
+    (`Precision.HIGHEST`: three per cent of a prefill's operations at Nemotron-H's widths), so a state the
+    prefill hands the decode steps is the recurrence's to float32's rounding.  A step with dt = 0 (a pad)
+    leaves h as it is."""
+    bsz, t, hh = dt.shape
+    g, n = b.shape[2:]
+    r = hh // g  # heads a group
+    p = xs.shape[-1]
+    if t == 1:
+        dt1 = dt[:, 0]
+        per_head = lambda v: jnp.repeat(v[:, 0], r, axis=1)  # [B, G, N] -> [B, H, N]
+        h = (jnp.exp(dt1 * a)[..., None, None] * h0
+             + (dt1[..., None] * xs[:, 0])[..., None] * per_head(b)[:, :, None, :])
+        return jnp.sum(h * per_head(c)[:, :, None, :], axis=-1)[:, None], h
+    q = chunk
+    nc = -(-t // q)
+    exact = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    # [B, T, ...] -> [B, chunks, Q, ...]: heads as [G, R], so that a group's B and C meet its heads without a copy
+    chunked = lambda v, *tail: jnp.pad(v, ((0, 0), (0, nc * q - t)) + ((0, 0),) * (v.ndim - 2)).reshape(bsz, nc, q, *tail)
+    dt_q, b_q, c_q, x_q = chunked(dt, g, r), chunked(b, g, n), chunked(c, g, n), chunked(xs, g, r, p)
+    with jax.named_scope("ssm.scan.chunk"):
+        since = jnp.cumsum(dt_q * a.reshape(g, r), axis=2)  # [B, C, Q, G, R]: log of the decay from the chunk's start
+        dtx = dt_q[..., None] * x_q
+        span = since[:, :, :, None] - since[:, :, None]  # [B, C, i, j, G, R]
+        causal = (jnp.arange(q)[:, None] >= jnp.arange(q)[None, :])[:, :, None, None]
+        decay = jnp.exp(jnp.where(causal, span, -jnp.inf))
+        scores = exact("bcign,bcjgn->bcijg", c_q, b_q)
+        y = exact("bcijgr,bcjgrp->bcigrp", scores[..., None] * decay, dtx)
+        # the chunk's own state: every position's input decayed to the chunk's end
+        to_end = jnp.exp(since[:, :, -1:] - since)
+        own = exact("bcjgrp,bcjgn->bcgrpn", to_end[..., None] * dtx, b_q)
+    with jax.named_scope("ssm.scan.carry"):
+        whole = jnp.exp(since[:, :, -1])  # [B, C, G, R]: a chunk's decay from its start to its end
+
+        def across(h, at):
+            whole_c, own_c = at
+            return whole_c[..., None, None] * h + own_c, h
+
+        h_t, first = lax.scan(across, h0.reshape(bsz, g, r, p, n),
+                              (jnp.moveaxis(whole, 1, 0), jnp.moveaxis(own, 1, 0)))
+        first = jnp.moveaxis(first, 0, 1)  # [B, C, G, R, P, N]: each chunk's state before its first position
+        y = y + jnp.exp(since)[..., None] * exact("bcign,bcgrpn->bcigrp", c_q, first)
+    return y.reshape(bsz, nc * q, hh, p)[:, :t], h_t.reshape(bsz, hh, p, n)
+
+
+def _mamba2_zero_state(cfg: TransformerConfig, batch: int):
+    """The state before a sequence starts: (window, h) as `_mamba2_half` takes them."""
+    with jax.named_scope("ssm.state"):
+        return (jnp.zeros((batch, cfg.ssm_d_conv - 1, cfg.conv_width), cfg.dtype),
+                jnp.zeros((batch, cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state), SSM_STATE_DTYPE))
+
+
+def _mamba2_half(bp, x, cfg: TransformerConfig, state, keep=None):
+    """A Mamba-2 layer, which is this half alone: x + `_mamba2_mixer` of it.
+    Returns (x, the state after the last position)."""
+    out, state = _mamba2_mixer(bp, x, cfg, state, keep)
+    return x + out, state
+
+
+def _mamba2_mixer(bp, x, cfg: TransformerConfig, state, keep=None):
+    """A Mamba-2 mixer with its norm, for training, prefill and decode alike:
+    w_out(norm_g(y * silu(z))), [z, xBC, dt] = w_in(norm(x)),
+    xBC through the causal depthwise convolution and silu, split into xs
+    [heads, head_dim], B and C [groups, d_state]; y the recurrence's read-out
+    (`_ssd_scan`) + D xs; norm_g an RMSNorm over each group's d_inner / groups
+    channels with one weight over all.  x: [B, T, E]; state: (the convolution's
+    window, the last ssm_d_conv - 1 rows of xBC [B, K-1, conv_width]; h [B, H,
+    P, N]) as the tokens before left it, zeros before a sequence starts
+    (`_mamba2_zero_state`); keep: [B, T] bool, False at a left pad, None for
+    none.  Returns (the mixer's result [B, T, E], the state after the last
+    position).
+
+    At a pad xBC = 0, so the convolution sees what an unpadded prompt sees
+    before its start, and dt = 0: exp(0) = 1 and the input term is 0, so h
+    passes the pads unchanged and the state after the last token is the
+    unpadded prompt's.  dt, the decays, h (in the cache too) and y before the
+    gate are float32 (SSM_STATE_DTYPE), the rest cfg.dtype."""
+    window, h = state
+    bsz, t, _ = x.shape
+    c, w, hh, g, n, f = cfg.d_inner, cfg.conv_width, cfg.ssm_n_heads, cfg.ssm_n_groups, cfg.ssm_d_state, SSM_STATE_DTYPE
+    with jax.named_scope("norm"):
+        u = _norm(x, bp, "ln1", cfg)
+    with jax.named_scope("ssm.in"):
+        z, xbc, dt, _ = jnp.split(u @ bp["ssm_in"].astype(x.dtype), (c, c + w, c + w + hh), axis=-1)  # _: the columns of zeros
+        if keep is not None:
+            xbc = jnp.where(keep[..., None], xbc, 0)
+    with jax.named_scope("ssm.conv"):
+        padded = jnp.concatenate([window.astype(x.dtype), xbc], axis=1)  # [B, K-1+T, W]
+        taps = bp["conv_w"].astype(x.dtype)
+        xc = sum(padded[:, j:j + t] * taps[j] for j in range(cfg.ssm_d_conv))
+        if "conv_b" in bp:
+            xc = xc + bp["conv_b"].astype(x.dtype)
+        xc = jax.nn.silu(xc)
+    with jax.named_scope("ssm.state"):
+        window = padded[:, t:].astype(window.dtype)
+    with jax.named_scope("ssm.scan"):
+        xs, b, cc = jnp.split(xc.astype(f), (c, c + g * n), axis=-1)
+        xs = xs.reshape(bsz, t, hh, cfg.ssm_head_dim)
+        dt = jax.nn.softplus(dt.astype(f) + bp["dt_bias"].astype(f))
+        if keep is not None:
+            dt = jnp.where(keep[..., None], dt, 0.0)
+        y, h_new = _ssd_scan(dt, -jnp.exp(bp["a_log"].astype(f)), b.reshape(bsz, t, g, n), cc.reshape(bsz, t, g, n),
+                             xs, h.astype(f), cfg.ssm_chunk)
+        y = (y + bp["ssm_d"].astype(f)[:, None] * xs).reshape(bsz, t, c)
+    with jax.named_scope("ssm.state"):
+        h_new = h_new.astype(h.dtype)
+    with jax.named_scope("ssm.norm"):
+        gated = (y * jax.nn.silu(z.astype(f))).reshape(bsz, t, g, c // g)
+        gated = gated * lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+        gated = (gated.reshape(bsz, t, c) * bp["ssm_norm"].astype(f)).astype(x.dtype)
+    with jax.named_scope("ssm.out"):
+        return gated @ bp["ssm_out"].astype(x.dtype), (window, h_new)
+
+
 def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
     """A block's FFN over what the block gives it, y [B, T, E]: dense SwiGLU or
     a mixture of experts (by what `bp` holds: a router or none), the mixture
-    with the shared experts' gated MLP beside it where the configuration has
-    any.  On one device the mixture is the dropless routed path (`_moe`, which
+    with the shared experts' MLP beside it where the configuration has any
+    (gated, or the experts' own activation without a gate: by what `bp` holds).  On one device the mixture is the dropless routed path (`_moe`, which
     says what `live` and `experts` are); with 'ep' among the caller's manual
     axes its experts are sharded and tokens travel to them (parallel/moe.py
     moe_ffn).  Returns (out [B, T, E], aux loss, experts touched): None for a
     dense model, and no count from moe_ffn."""
+    from ..parallel.moe import ACTIVATIONS
+
     b, t, e = y.shape
     dt = y.dtype
     with jax.named_scope("ffn"):
@@ -1176,8 +1409,12 @@ def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=fro
             out, aux, touched = _moe(bp, y, cfg, live, experts)
             if cfg.n_shared_experts:
                 with jax.named_scope("moe.shared"):
-                    shared = jax.nn.silu(y @ bp["shared_gate"].astype(dt)) * (y @ bp["shared_up"].astype(dt))
-                    out = out + shared @ bp["shared_down"].astype(dt)
+                    if "shared_gate" in bp:
+                        shared = jax.nn.silu(y @ bp["shared_gate"].astype(dt)) * (y @ bp["shared_up"].astype(dt))
+                        out = out + shared @ bp["shared_down"].astype(dt)
+                    else:  # no gate, the experts' own activation
+                        shared = ACTIVATIONS[cfg.moe_act](y @ bp["shared_in"].astype(dt))
+                        out = out + shared @ bp["shared_out"].astype(dt)
             return out, aux, touched
         # tokens flatten, travel to their expert's device, come back (traced
         # under shard_map manual over 'ep': see forward())
@@ -1190,14 +1427,19 @@ def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=fro
             bp["w_out"].astype(dt),
             axis_name="ep",
             capacity_factor=cfg.capacity_factor,
+            act=cfg.moe_act,
         )
         return r.out.reshape(b, t, e), r.aux_loss.astype(jnp.float32), None
 
 
-def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
+def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset(), kind=None):
     """A block's second half: x + FFN(norm(x)), or under `cfg.norm_output`
     x + norm(FFN(x)); `_ffn` says what the FFN is and what the other arguments
-    are.  Returns (x, aux loss, experts touched)."""
+    are.  kind: the layer's, where the caller serves more than one: attention
+    alone ("attn_alone": HALF_KINDS) has no second half and x comes back as it
+    is.  Returns (x, aux loss, experts touched)."""
+    if kind == "attn_alone":
+        return x, None, None
     if cfg.norm_output:
         out, aux, touched = _ffn(bp, x, cfg, live, experts, manual_axes)
         with jax.named_scope("norm"):
@@ -1303,7 +1545,7 @@ def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozens
             return _attention(q, k, v, cfg, mesh, manual_axes, cfg.attn_window * is_window(kind)), made
 
     x, (k, v) = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind, layer)
-    x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
+    x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes, kind=kind)
     made = dict(k=k, v=v) if kind == "attn" else {}
     return _hand_on(s, x, **made), jnp.zeros((), jnp.float32) if aux is None else aux
 
@@ -1359,12 +1601,50 @@ def _layer_runs(kinds):
     return runs
 
 
+def _run_groups(runs):
+    """`_layer_runs`' runs with each sequence of two or more that comes again at
+    once, the same periods at the same lengths, made one group: [((the
+    sequence's runs, their starts the first repetition's), the repetitions)],
+    a run by itself a sequence of one that comes once.  Nemotron-H's 52 layers,
+    MEMEM (*EMEMEM) x 4 (*EMEMEMEM) x 2 E, are [M, E] x 2, M, (*, [E, M] x 3) x 4,
+    (*, [E, M] x 4) x 2, E: five loops that hold ten layer bodies, where their
+    fifteen runs hold twenty-two.
+
+    A sequence is made of single layers and periods.  One that holds a run of
+    two or more layers of ONE kind stays as it is: inside a repetition such a
+    run is a slice of its kind's stacks at an offset the program computes, and
+    the chip's compiler copies every such slice into fast memory a repetition
+    (tests/test_chip_compile.py: a state-space run of two between attention
+    layers, three slices a repetition), where a run by itself reads each
+    layer's matrices where they lie."""
+    shape = lambda run: (run[0], run[2])
+    sliced = lambda run: isinstance(run[0], str) and run[2] > 1
+    groups, i = [], 0
+    while i < len(runs):
+        found = None
+        for size in range(2, (len(runs) - i) // 2 + 1):
+            unit = [shape(r) for r in runs[i:i + size]]
+            reps = 1
+            while [shape(r) for r in runs[i + reps * size:i + (reps + 1) * size]] == unit:
+                reps += 1
+            if reps > 1 and not any(map(sliced, runs[i:i + size])):
+                found = (size, reps)
+                break
+        size, reps = found or (1, 1)
+        groups.append((tuple(runs[i:i + size]), reps))
+        i += size * reps
+    return groups
+
+
 def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unroll=1, indexed=False):
     """The layer loop of every program: each maximal run of one kind of layer
     is one `lax.scan` of `body(kind, carry, bp, held, layer) -> (carry, ys)`;
     a model of one kind is one run, and a period of alternating kinds that
-    repeats is one scan of the period, the body once a kind (`_layer_runs`).  stacks: `layer_stacks`.  Returns
-    (carry, {kind: ys over that kind's layers}).
+    repeats is one scan of the period, the body once a kind (`_layer_runs`).
+    A sequence of runs that repeats is one scan of the sequence, its runs the
+    loops inside it (`_run_groups`): a program is compiled a loop body at a
+    time, and its size and the time to compile it go by the bodies it holds.
+    stacks: `layer_stacks`.  Returns (carry, {kind: ys over that kind's layers}).
 
     What a program keeps from one call to the next (a cache) is part of
     `carry`, through every run of every kind, as whole stacks [n_kind, ...]
@@ -1385,7 +1665,11 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     asked for it."""
     kinds = cfg.layer_kinds
     outs: Dict[str, list] = {}
-    for period, starts, n in _layer_runs(kinds):
+
+    def scan_run(carry, period, starts, n, ahead=None):
+        """One run's scan.  ahead: the layers of each of the period's kinds that the
+        repetitions of the run's group before this one hold (traced), or None
+        outside a group.  Returns (carry, [(kind, its ys)])."""
         if isinstance(period, str):
             period, starts = (period,), (starts,)
         members, xs = [], {}  # a kind of the period: (kind, held, rest); its layers' parameters or indices
@@ -1399,7 +1683,9 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
             members.append((kind, held, rest))
             if n == total:
                 xs[f"bp{j}"] = rest
-            if held or indexed or n != total:
+            if ahead is not None:
+                xs[f"layer{j}"] = start + ahead[j] + jnp.arange(n)
+            elif held or indexed or n != total:
                 xs[f"layer{j}"] = jnp.arange(start, start + n)
 
         def step(carry, xs, members=members):
@@ -1413,9 +1699,33 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
             return carry, tuple(ys)
 
         carry, ys = lax.scan(step, carry, xs, unroll=unroll)
-        for kind, y in zip(period, ys):
-            outs.setdefault(kind, []).append(y)
+        return carry, list(zip(period, ys))
+
     join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    for runs, reps in _run_groups(_layer_runs(kinds)):
+        if reps == 1:
+            (run,) = runs
+            carry, made = scan_run(carry, *run)
+        else:
+            per_rep = {}  # kind -> its layers in one repetition of the sequence
+            for period, _, n in runs:
+                for kind in ((period,) if isinstance(period, str) else period):
+                    per_rep[kind] = per_rep.get(kind, 0) + n
+
+            def repetition(carry, rep, runs=runs, per_rep=per_rep):
+                made: Dict[str, list] = {}
+                for period, starts, n in runs:
+                    period_kinds = (period,) if isinstance(period, str) else period
+                    carry, ys = scan_run(carry, period, starts, n, ahead=[rep * per_rep[kind] for kind in period_kinds])
+                    for kind, y in ys:
+                        made.setdefault(kind, []).append(y)
+                return carry, {kind: jax.tree_util.tree_map(join, *ys) for kind, ys in made.items()}
+
+            carry, made = lax.scan(repetition, carry, jnp.arange(reps))
+            # [repetitions, a repetition's layers of the kind, ...] -> the kind's layers in their order
+            made = [(kind, jax.tree_util.tree_map(lambda y: y.reshape(-1, *y.shape[2:]), ys)) for kind, ys in made.items()]
+        for kind, y in made:
+            outs.setdefault(kind, []).append(y)
     return carry, {kind: jax.tree_util.tree_map(join, *runs) for kind, runs in outs.items()}
 
 
@@ -1426,7 +1736,7 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
     # an attention kind's block is the same whatever its FFN: that is what its weights hold
     blocks = {
         kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind)
-        for kind in _INIT_KIND if kind not in ("ssm", "gmu")
+        for kind in _INIT_KIND if kind not in ("ssm", "gmu", "mamba2", "ffn")
     }
 
     def ssm(bp, s, layer=None):
@@ -1435,6 +1745,14 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
 
     blocks["ssm"] = ssm
     blocks["gmu"] = lambda bp, s, layer=None: (_gmu_block(bp, s, cfg), jnp.zeros((), jnp.float32))
+    blocks["mamba2"] = lambda bp, x, layer=None: (
+        _mamba2_half(bp, x, cfg, _mamba2_zero_state(cfg, x.shape[0]))[0], jnp.zeros((), jnp.float32))
+
+    def ffn(bp, x, layer=None):
+        x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
+        return x, jnp.zeros((), jnp.float32) if aux is None else aux
+
+    blocks["ffn"] = ffn
     if cfg.remat:
         blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
 

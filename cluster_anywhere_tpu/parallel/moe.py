@@ -11,7 +11,8 @@ returned to token order and summed over k.  No capacity and no drop, whatever
 the imbalance.  Rows marked not live (the empty slots of a decode batch, the
 left padding of a prompt) take no expert.  Experts are gated
 (`silu(x w_gate) * (x w_up)) w_down`, OLMoE's kind) or ungated
-(`silu(x w_in) w_out`).  The scores may be a sigmoid's in place of the
+(`silu(x w_in) w_out`), and the activation may be relu(x)^2 in silu's place
+(ACTIVATIONS).  The scores may be a sigmoid's in place of the
 softmax's, and the k weights scaled.  A device that holds a share of the
 experts (`held`: expert parallelism's share, here without the exchange) is
 told which: the router stays as wide as the model's experts and a token takes
@@ -36,6 +37,20 @@ branches give the same sums up to float32 reassociation.
 `RoutedOutput.compact` says which ran.  The compact buffer is also what the
 exchange over 'ep' will fill: the rows a chip receives from the all-to-all are
 exactly the sorted head that it holds experts for.
+
+A held share of ungated experts given few rows (FEW_ROWS: a decode step's
+slots, and a prefill's smaller buckets) takes neither: it loops over the
+experts that were given a row, as many turns as there are, and each turn reads
+that expert's two matrices where they lie in the stack and takes every row
+through them, weighted by what the row gives that expert (0 for most).  A
+step's 8 live rows give a share of 16 of 128 experts six assignments a layer
+on some 2 experts, and the grouped matmul takes 0.17 ms for each expert that
+has a row, however few (20 MB at a seventh of the chip's bandwidth); the loop
+takes the time to read the touched experts once (FEW_ROWS has the readings).
+The gated form keeps the grouped matmul: its three cells' programs are not this
+loop's to move unmeasured.  The loop has as many turns as the rows decide, so
+it is a forward path only: a gradient through so few rows of a held share is
+refused by jax, by name.
 
 `moe_ffn` is expert parallelism for training over an 'ep' mesh axis:
 switch-style top-1 routing with a capacity, tokens exchanged with
@@ -63,6 +78,19 @@ from jax import lax
 
 # the experts' matrices among a block's weights (init_moe_params), each [X, ...]
 EXPERT_MATRICES = ("w_gate", "w_up", "w_down", "w_in", "w_out")
+# the chip's lanes.  An ungated expert's first matrix whose width F is over one tile of lanes and no multiple
+# of it is stored [X, E, F up to whole tiles], the columns past F zeros (`init_moe_params`): act(0) = 0 and
+# `routed_ffn` hands the second matrix the first F columns, so the expert is F wide.  The chip lays an array
+# [.., 2688, 1856] out with 2688 innermost (1,856 is 14.5 tiles), the grouped matmul's kernel takes [X, E, F]
+# with F innermost, and the compiler then copied every layer's experts from the one layout into the other at
+# every call: 3.4 GB twice at Nemotron-H's 23 layers of 16 (its own report, compiled for a described v5e;
+# tests/test_chip_compile.py holds the decode step to no such copy).  With F innermost the chip pads 1,856 to
+# 1,920 itself: stored at 1,920 the matrix takes the bytes it would take, and is read where it lies.
+LANES = 128
+
+
+# an expert's activation, by the configuration's name for it (`TransformerConfig.moe_act`)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu2": lambda h: jnp.square(jax.nn.relu(h))}
 
 
 # A held share's compact buffer (`routed_ffn`, module docstring) is this many
@@ -82,6 +110,19 @@ COMPACT_SHARE = 4
 # of 0.16: at 8,192 rows x 8 of width 6,144 (C = 16,384, 201 MB) the layer
 # reads 13.4 ms with one block and 11.6 with two (my chip runs, PR 45).
 COMPACT_LOOKUP_BYTES = 96 * 2 ** 20
+
+
+# A held share of ungated experts given at most this many rows loops over its touched experts (`routed_ffn`,
+# module docstring) in place of the grouped matmul.  Measured on the chip at Nemotron-3-Nano's widths (an expert
+# 2,688 x 1,856 stored 1,920 wide, 20.3 MB; 16 held of 128, six a token; scripts/moe_few_rows_sweep.py, PERF.md
+# section 6, PR 50), microseconds a layer, grouped matmul -> loop.  A decode step's 32 rows, 8 live, all sending t
+# assignments to the same t held experts: t = 1: 217 -> 58, 2: 390 -> 79, 3: 564 -> 112, 4: 737 -> 146, 6: 1,085 ->
+# 213; spread over 8 experts 1,430 -> 280, over all 16: 2,817 -> 548.  The grouped matmul is 44 + 173 a touched
+# expert (117 GB/s), the loop 12 + 33.5 (606 GB/s of the chip's 819).  Every row live, a random router: 32 rows
+# 2,470 -> 481, 64: 2,822 -> 531, 128: 3,352 -> 576, 256: 3,550 -> 665, 512: 3,883 -> 1,098 (all 16 touched: the
+# loop takes every row through every touched expert, 11 GFLOP an expert at 512 rows, and is still 3.5 times
+# faster).  1,024 rows were not measured: the largest bucket keeps the compact grouped path.
+FEW_ROWS = 512
 
 
 def compact_buffer_rows(n: int, k: int, held: int, routed: int) -> int:
@@ -160,12 +201,14 @@ def routed_ffn(
     scoring: str = "softmax",  # or "sigmoid": the scores the k largest are taken of
     scale: float = 1.0,  # what the k weights are multiplied by
     held: Optional[Tuple[int, int]] = None,  # (first, count) of the router's experts in `experts`; None = all
+    act: str = "silu",  # the experts' activation: ACTIVATIONS' key
 ) -> RoutedOutput:
     """The dropless routed expert FFN of one device (module docstring).  Every
     live row's k assignments are computed; a row that is not live is given to
     no expert, adds to no group and comes back as zeros.  The experts are
     gated if `experts` holds w_gate, w_up [L, X, E, F] and w_down [L, X, F, E],
-    else ungated: w_in, w_out.
+    else ungated: w_in [L, X, E, F] (or wider by columns of zeros: LANES) and
+    w_out [L, X, F, E]; `act` is their activation, silu or relu(x)^2.
 
     The experts come as every layer's, with the layer's index, because inside
     a scan over the layers they are best not sliced.  The grouped matmul is a
@@ -185,6 +228,7 @@ def routed_ffn(
     n_routed = router.shape[-1]
     first, n_experts = (0, n_routed) if held is None else held  # n_experts: those with a group here
     gated = "w_gate" in experts
+    activation = ACTIVATIONS[act]
     with jax.named_scope("moe.router"):
         logits = jnp.dot(x, router.astype(dt), preferred_element_type=jnp.float32)
         if scoring == "softmax":
@@ -227,9 +271,10 @@ def routed_ffn(
             rows = x[order[:c] // k]  # [c, E], expert by expert
         with jax.named_scope("moe.experts"):
             if gated:
-                hidden = jax.nn.silu(grouped(rows, experts["w_gate"])) * grouped(rows, experts["w_up"])
+                hidden = activation(grouped(rows, experts["w_gate"])) * grouped(rows, experts["w_up"])
                 return grouped(hidden, experts["w_down"])
-            return grouped(jax.nn.silu(grouped(rows, experts["w_in"])), experts["w_out"])
+            hidden = activation(grouped(rows, experts["w_in"]))
+            return grouped(hidden[:, :experts["w_out"].shape[-2]], experts["w_out"])  # without w_in's columns of zeros
 
     def every_row():
         return _combine_every_row(through_experts(n * k), gate, order, back, in_groups)
@@ -237,8 +282,30 @@ def routed_ffn(
     def compact_rows(c: int):
         return _combine_compact(through_experts(c), gate, back, in_groups)
 
+    def touched_experts():
+        """Every row through each held expert that was given one, an expert a
+        turn, weighted by what the row gives it and summed in float32: [N, E]."""
+        w_in, w_out = (experts[name].reshape(-1, *experts[name].shape[2:]) for name in ("w_in", "w_out"))
+        with jax.named_scope("moe.dispatch"):
+            held_idx = expert.reshape(n, k)[:, :, None] == jnp.arange(n_experts)  # [N, k, X]
+            weight = jnp.sum(jnp.where(held_idx, gate[:, :, None], 0.0), axis=1)  # [N, X]: 0 where the row takes none of it
+            touched = jnp.argsort(group_sizes == 0, stable=True)  # the experts given a row first
+
+        def turn(i, total):
+            with jax.named_scope("moe.experts"):
+                at = layer * n_experts + touched[i]
+                hidden = activation(x @ lax.dynamic_index_in_dim(w_in, at, keepdims=False).astype(dt))
+                out = hidden[:, :w_out.shape[-2]] @ lax.dynamic_index_in_dim(w_out, at, keepdims=False).astype(dt)
+            with jax.named_scope("moe.combine"):
+                return total + out.astype(jnp.float32) * lax.dynamic_index_in_dim(weight, touched[i], 1)
+
+        total = lax.fori_loop(0, jnp.sum(group_sizes > 0), turn, jnp.zeros(x.shape, jnp.float32))
+        return total.astype(dt)
+
     c = 0 if held is None else compact_buffer_rows(n, k, n_experts, n_routed)
-    if c:
+    if held is not None and not gated and n <= FEW_ROWS:
+        compact, out = jnp.zeros((), bool), touched_experts()
+    elif c:
         compact = in_groups <= c
         out = lax.cond(compact, lambda: compact_rows(c), every_row)
     else:
@@ -261,8 +328,10 @@ def moe_ffn(
     *,
     axis_name: str = "ep",
     capacity_factor: float = 1.25,
+    act: str = "silu",  # the experts' activation: ACTIVATIONS' key
 ) -> MoEOutput:
-    """Call inside shard_map (manual over `axis_name`)."""
+    """Call inside shard_map (manual over `axis_name`).  `w_in` may be wider than
+    `w_out` is tall by columns of zeros (LANES)."""
     ep = lax.psum(1, axis_name)
     n_local, e_model = x.shape
     local_experts = w_in.shape[0]
@@ -295,8 +364,8 @@ def moe_ffn(
         local_experts, ep * capacity, e_model
     )
 
-    h = jax.nn.silu(jnp.einsum("xne,xef->xnf", expert_in, w_in))
-    expert_out = jnp.einsum("xnf,xfe->xne", h, w_out)
+    h = ACTIVATIONS[act](jnp.einsum("xne,xef->xnf", expert_in, w_in))
+    expert_out = jnp.einsum("xnf,xfe->xne", h[..., :w_out.shape[-2]], w_out)
 
     # route back
     expert_out = expert_out.reshape(local_experts, ep, capacity, e_model).transpose(
@@ -319,7 +388,9 @@ def moe_ffn(
 def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.float32,
                     gated: bool = False, held: Optional[int] = None):
     """A router over n_experts and the experts' matrices: all n_experts of them,
-    or the `held` that this device keeps."""
+    or the `held` that this device keeps.  An ungated expert's first matrix of
+    a width over LANES that is no multiple of it is made with columns of zeros up
+    to whole tiles (LANES says why)."""
     n_routed, n_experts = n_experts, held or n_experts
     if gated:
         k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -332,8 +403,11 @@ def init_moe_params(key, e_model: int, f_hidden: int, n_experts: int, dtype=jnp.
     k1, k2, k3 = jax.random.split(key, 3)
     scale_in = (2.0 / e_model) ** 0.5
     scale_out = (2.0 / f_hidden) ** 0.5
+    w_in = jax.random.normal(k2, (n_experts, e_model, f_hidden), dtype) * scale_in
+    if f_hidden > LANES and f_hidden % LANES:
+        w_in = jnp.pad(w_in, ((0, 0), (0, 0), (0, -f_hidden % LANES)))
     return {
         "router": jax.random.normal(k1, (e_model, n_routed), dtype) * 0.02,
-        "w_in": jax.random.normal(k2, (n_experts, e_model, f_hidden), dtype) * scale_in,
+        "w_in": w_in,
         "w_out": jax.random.normal(k3, (n_experts, f_hidden, e_model), dtype) * scale_out,
     }

@@ -24,7 +24,7 @@ import re
 import sys
 
 CELLS = ("chat-closed6", "olmoe-closed6", "jamba-closed6", "sdar-closed6", "axk1-rag-closed6",
-         "kexaone-longrag-closed6", "phi4flash-reason-closed8")
+         "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8")
 
 
 def hashes(root: str) -> dict:

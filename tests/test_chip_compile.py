@@ -472,6 +472,66 @@ def test_shared_stack_decode_step_reads_the_one_stack_where_it_lies(v5e):
             assert op == "fusion" and '"aliasing_operands":{"lists":[{' in line, line[:200]
 
 
+# Nemotron-3-Nano-30B-A3B's widths as one chip of 8 holds a layer (52 layers that are each ONE of a Mamba-2 mixer of
+# 64 heads x 64 over a state of 128 in 8 groups, 16 held of 128 sigmoid-routed relu^2 experts of 1,856 with an ungated
+# shared expert of 3,712, or attention 32 / 2 x 128 without a positional embedding), the published pattern's first 21
+# layers (its (*, [E, M] x 3) twice, so one loop of loops: transformer._run_groups):
+# benchmarks/configs/nemotron-3-nano-30b-a3b-ep8-serve1.json
+NEMOTRONH21 = dict(
+    vocab_size=512, n_layers=21, d_model=2688, n_heads=32, n_kv_heads=2, d_head=128, d_ff=1856, rotary=False,
+    norm_eps=1e-5, layer_mixers=tuple({"M": "mamba2", "E": "ffn", "*": "attn_alone"}[m] for m in "MEMEM*EMEMEM*EMEMEM*E"),
+    ssm_n_heads=64, ssm_head_dim=64, ssm_n_groups=8, ssm_d_state=128, ssm_chunk=128, n_experts=128, n_experts_per_tok=6,
+    experts_held=(0, 16), moe_act="relu2", moe_renormalize=True, moe_scoring="sigmoid", moe_routed_scale=2.5,
+    d_expert=1856, n_shared_experts=1, d_shared=3712, param_dtype=jnp.bfloat16,
+)
+
+
+def test_half_layer_decode_step_updates_the_state_and_reads_two_flat_heads_where_they_lie(v5e):
+    """The decode step at Nemotron-3-Nano's widths and the cell's cache (32 slots
+    x 4,096) as the chip runs it.  The Mamba-2 layers' state, [6, 32, 64, 64, 128]
+    float32 (0.6 GB here, 1.5 GB over the published 23), is the layer loop's
+    carry, read and written a layer at a time in place, through a loop of loops
+    too: the program holds no second copy of it, nor of a layer's.  The two
+    cached heads' stacks are flat ([T x 2, 128] a slot: 2 is no multiple of the
+    chip's 8 sublanes, and as [T, 2, 128] every layer's stack was copied into
+    the kernel's rows) and are written a row a slot in place.  The held experts
+    are read an expert a turn of the loop over those that were given a row
+    (parallel/moe.py FEW_ROWS), each matrix sliced out of the stack inside the
+    product that reads it: no grouped matmul, and no buffer of a matrix's size.
+    The mixers' in-projection, 10,304 wide, is stored 10,368 wide: at 10,304
+    the chip laid the stack out with the model's width innermost and the loop
+    of loops took it with its last axis innermost, a copy of every layer's
+    matrix, 1.27 GB over the published 23, at every step."""
+    cfg = transformer.TransformerConfig(**NEMOTRONH21)
+    runs = transformer._layer_runs(cfg.layer_kinds)
+    assert len(runs) == 8  # [M, E] x 2, M, *, [E, M] x 3, *, [E, M] x 3, *, E
+    assert [reps for _, reps in transformer._run_groups(runs)] == [1, 1, 2, 1, 1]
+    compiled, params, cache = _compiled_decode_step(cfg, v5e[0], 32, 4096, on_kernel=True)
+    assert {n: c.shape for n, c in cache.items()} == {
+        "k": (3, 32, 8192, 128), "v": (3, 32, 8192, 128), "conv": (9, 32, 3, 6144), "h": (9, 32, 64, 64, 128)}
+    assert params["ffn_blocks"]["w_in"].shape == (9, 16, 2688, 1920) and params["ffn_blocks"]["w_out"].shape == (9, 16, 1856, 2688)
+    assert params["mamba2_blocks"]["ssm_in"].shape == (9, 2688, 10368)
+    text = compiled.as_text()
+    assert len(re.findall(r"%(decode_attn[\w.]*) = \S+ custom-call\(", text)) == 2 and _has_kernel(compiled)
+    assert "ragged-dot" not in text
+    # a sixth of one layer's in_proj: nothing of the state's, a stack's or an expert's size
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+    a_state, a_stack, an_expert = 32 * 64 * 64 * 128, 32 * 8192 * 128, 2688 * 1856
+    for dtype, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if n >= min(a_state, a_stack) and "S(1)" not in shape and op not in ("parameter", "get-tuple-element", "bitcast"):
+            # the donated state and stacks written in place, or a view of a weight's stack inside the loop
+            in_place = op == "fusion" and '"aliasing_operands":{"lists":[{' in line
+            assert in_place or op in ("while", "tuple", "copy-done", "copy-start"), line[:200]
+        if dtype == "bf16" and n >= an_expert:  # no copy of an expert's matrix, nor of a layer's experts, in any layout
+            assert op in ("parameter", "get-tuple-element", "bitcast", "while", "tuple") or in_place_stack(line), line[:200]
+
+
+def in_place_stack(line) -> bool:
+    """A fusion that writes one of the cache's stacks where it lies."""
+    return " fusion(" in line and '"aliasing_operands":{"lists":[{' in line
+
+
 def _computations(text):
     """({name: its instructions' lines}, the entry's name) of an optimized program's text."""
     comps, entry, inside = {}, None, None

@@ -168,6 +168,7 @@ def test_a_held_shares_compact_buffer_gives_what_every_row_gives(gated, scoring,
     token's places are looked up among them; the result is the one all 2,048
     rows give, to a float32 sum's rounding, and `RoutedOutput.compact` says
     which ran."""
+    monkeypatch.setattr(moe, "FEW_ROWS", 0)  # ungated experts at so few rows take the loop below; more rows take this
     bp = init_moe_params(jax.random.key(3), E, F, X32, jnp.float32, gated=gated)
     bp["router"] = bp["router"] * 40  # scores that differ
     x = tokens(PREFILL, seed=4)
@@ -186,6 +187,37 @@ def test_a_held_shares_compact_buffer_gives_what_every_row_gives(gated, scoring,
         assert float(g.aux_loss) == pytest.approx(float(w.aux_loss), rel=1e-6)
         if with_live:
             assert not np.asarray(g.out)[:37].any()
+
+
+@pytest.mark.parametrize("with_live", [False, True], ids=["all-live", "padded"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("rows", [8, PREFILL])
+def test_a_held_share_of_ungated_experts_given_few_rows_loops_over_the_experts_touched(rows, scoring, with_live, monkeypatch):
+    """At most FEW_ROWS rows (a decode step's, a prefill's smaller buckets): a
+    turn of a loop for each held expert that was given a row, every row through
+    it at the weight the row gives it.  The result, the counts and the loss are
+    the ones all N x k sorted rows give through the grouped matmul; relu^2 as
+    well as silu; an expert stored wider than it is (LANES) stays as wide as it is."""
+    assert rows <= moe.FEW_ROWS
+    bp = init_moe_params(jax.random.key(3), E, 200, X32, jnp.float32)
+    assert bp["w_in"].shape[-1] == 256 and bp["w_out"].shape[-2] == 200
+    bp["router"] = bp["router"] * 40
+    x = tokens(rows, seed=4)
+    live = jnp.arange(rows) >= rows // 8 if with_live else None
+    kw = dict(k=4, renormalize=True, scoring=scoring, scale=2.5, live=live, act="relu2" if scoring == "sigmoid" else "silu")
+    with jax.default_matmul_precision("highest"):
+        got = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+        monkeypatch.setattr(moe, "FEW_ROWS", 0)
+        every_row(monkeypatch)
+        want = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+    assert sum(int(w.assignments) for w in want) > 0
+    for g, w in zip(got, want):
+        assert int(g.compact) == 0 == int(w.compact)
+        assert int(g.assignments) == int(w.assignments) and int(g.experts_touched) == int(w.experts_touched)
+        np.testing.assert_allclose(g.out, w.out, atol=1e-6 * float(np.abs(np.asarray(w.out)).max()) + 1e-7)
+        assert float(g.aux_loss) == pytest.approx(float(w.aux_loss), rel=1e-6)
+        if with_live:
+            assert not np.asarray(g.out)[:rows // 8].any()
 
 
 @pytest.mark.parametrize("crowded", [False, True], ids=["even", "crowded"])
