@@ -47,18 +47,24 @@ goes through the same scheduler, cache and admit; what differs is the step:
   carry a block's B tokens and fixed flags; the program runs the B positions
   against the cache and themselves, writes the block's keys and values in
   place, and chooses on the device what to fix (`_choose_block`).
-- a pass that finds every position of a block fixed has thereby stored the
-  block's keys and values as those of its tokens; the slot moves on to its next
-  block.  So a block of B costs its passes and one more.
+- a pass that leaves every position of a block fixed leaves the block pending:
+  its keys and values in the cache are those of the pass's input, the last
+  positions still masked.  The slot moves on to its next block, and that
+  block's first pass runs the pending block's final tokens beside its own B
+  positions (2B a row; a row with nothing pending has the first half dead) and
+  so stores their keys and values, which its own positions see.  So a block of
+  B costs its denoising passes and no more; the head and the choice run over
+  the block's own B positions alone.
 - a step hands a request 0 to B tokens, in position order: a token goes out
   once every position before it is fixed.  An admit prefills the prompt's
   whole blocks and hands out nothing; the prompt's tail is the fixed part of
   the first block.
 - a pass is read one pass behind, as a causal step is: what a slot feeds next
-  is the pass's own output, or, after a storing pass, an empty block B slots
-  on, and the program decides which from the flags that went in to the pass; it
-  stays on the device (`prev`; an admitted slot starts from the host's rows,
-  marked `fresh`).  So step() dispatches pass N+1 and only then reads pass N.
+  is the pass's own output, or, where that is a block with every position
+  fixed, an empty block B slots on with that block pending, and the program
+  decides which from what the pass fixed; it stays on the device (`prev`; an
+  admitted slot starts from the host's rows, marked `fresh`, with nothing
+  pending).  So step() dispatches pass N+1 and only then reads pass N.
   The host keeps a mirror of every slot's block and position, one pass behind
   the device's, and decides alone what the device is never told: which
   positions go out, where an answer ends, `fixed_at`, `block_tail`.  An answer's
@@ -148,7 +154,7 @@ class Request:
 class _StepInFlight:
     """A decode step (a pass of blocks) that was dispatched and is not read yet."""
     # on the device: every slot's next token [S] int32, or every slot's block after
-    # the pass [2B, S]
+    # the pass [2B, S] (tokens, then fixed flags)
     made: Any
     touched: Any  # experts touched, on the device; None for a dense model
     # (slot, request) of the rows the step holds, as the slots were at dispatch:
@@ -285,37 +291,48 @@ def _choose_block(logits, fixed, live, temps, rng, cfg: TransformerConfig):
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _pass_step_rowpos(params, cache, ints, floats, prev, rng, *, cfg):
     """One pass of every slot's own block (a model that generates by blocks of
-    B = cfg.block_length).  ints: [4 + 2B, S] int32, the rows pads, live, fresh,
-    then a slot's state as the host has it: pos (the cache slot of the block's
-    first position), the block's B tokens and its B fixed flags; floats:
-    [2, S], the rows temps, top_ps (the second unused: a request that asks top-p
-    is refused).  prev: [1 + 2B, S] int32, the state the pass before left every
-    slot for this one, still on the device: a live slot takes it, which the host
-    may not have read yet, except where fresh is set (a slot admitted since: the
-    admit's position, the prompt's tail and its flags are the host's rows).  A
-    position that is not fixed goes in as cfg.mask_token_id whatever its token
-    says, and fixedness is the flag alone.  Returns (the blocks after the pass
-    [2B, S]: tokens, then flags; the state for the next pass [1 + 2B, S]; cache;
-    the carried key; experts touched).  The next state is decided from what
-    went in: a block that came in with every position fixed has been stored by
-    this pass, so the slot goes on to an empty block at pos + B; any other is
-    the block after the pass, where it stands.  The B rows of keys and values
-    a slot and layer are written in place at [layer, b, pos : pos + B]
-    (models/generate.py, the decode block), as the causal step writes its
-    one: tests/test_chip_compile.py holds the chip's program to that and to no
-    sort of the vocabulary."""
+    B = cfg.block_length), which also stores the block before where that is
+    pending.  ints: [4 + 2B, S] int32, the rows pads, live, fresh, then a
+    slot's state as the host has it: pos (the cache slot of the block's first
+    position), the block's B tokens and its B fixed flags; floats: [2, S], the
+    rows temps, top_ps (the second unused: a request that asks top-p is
+    refused).  prev: [2 + 3B, S] int32, the state the pass before left every
+    slot for this one, still on the device: the host's rows, then whether the
+    block before is pending and its B final tokens.  A live slot takes it, which
+    the host may not have read yet, except where fresh is set (a slot admitted
+    since: the admit's position, the prompt's tail and its flags are the
+    host's rows, and nothing is pending).  A position that is not fixed goes in
+    as cfg.mask_token_id whatever its token says, and fixedness is the flag
+    alone.  Returns (the blocks after the pass [2B, S]: tokens, then flags; the
+    state for the next pass [2 + 3B, S]; cache; the carried key; experts
+    touched).
+
+    A row runs 2B positions, pos - B .. pos + B - 1 (`decode_rows`, `pending`):
+    the pending block's final tokens, whose keys and values are thereby stored
+    as those of its tokens, and the block itself, which sees them; for a row
+    with nothing pending the first half is dead.  The logits and the choice are
+    the block's alone.  A pass that leaves every position of its block fixed
+    leaves it pending: the next state is pos + B, an empty block, and the
+    block's tokens to store, so a block of B costs its denoising passes and no
+    more.  The rows of keys and values a slot and layer are written in place
+    at [layer, b, pos - B : pos + B] (models/generate.py, the decode block), as
+    the causal step writes its one: tests/test_chip_compile.py holds the chip's
+    program to that, to no sort of the vocabulary and to logits of B positions
+    a slot."""
     b = cfg.block_length
     pads, live, fresh = ints[0], ints[1] != 0, ints[2] != 0
-    # a slot that holds no request rests on the host's rows, as it always did
-    state = jnp.where(fresh | ~live, ints[3:], prev)
-    pos, tokens, fixed = state[0], state[1:1 + b].T, state[1 + b:].T != 0
+    # a slot that holds no request rests on the host's rows, as it always did; they hold nothing pending
+    state = jnp.where(fresh | ~live, jnp.pad(ints[3:], ((0, 1 + b), (0, 0))), prev)
+    pos, tokens, fixed = state[0], state[1:1 + b].T, state[1 + b:1 + 2 * b].T != 0
+    pending, before = state[1 + 2 * b] != 0, state[2 + 2 * b:].T
     key, sub = jax.random.split(rng)
-    ids = jnp.where(fixed, tokens, cfg.mask_token_id)
-    logits, cache, touched = decode_rows(params, cache, ids, pos, pads, cfg, live)
+    ids = jnp.concatenate([before, jnp.where(fixed, tokens, cfg.mask_token_id)], axis=1)
+    logits, cache, touched = decode_rows(params, cache, ids, pos, pads, cfg, live, pending)
     chosen, fix = _choose_block(logits, fixed, live, floats[0], sub, cfg)
     after = jnp.concatenate([jnp.where(fix, chosen, tokens).T, (fixed | fix).T.astype(jnp.int32)])
-    stored = jnp.all(fixed, axis=1)
-    nxt = jnp.concatenate([jnp.where(stored, pos + b, pos)[None], jnp.where(stored, 0, after)])
+    whole = jnp.all(fixed | fix, axis=1)  # the block is left pending
+    nxt = jnp.concatenate([jnp.where(whole, pos + b, pos)[None], jnp.where(whole, 0, after),
+                           whole[None].astype(jnp.int32), jnp.where(whole, after[:b], 0)])
     return after, nxt, cache, key, touched
 
 
@@ -481,7 +498,10 @@ class ContinuousBatcher:
             self._pads, self._live, self._fresh, self._pos = self._ints[:4]
             self._blk_tokens, self._blk_fixed = self._ints[4:4 + self._block], self._ints[4 + self._block:]
             self._topks = np.zeros(slots, np.int32)  # top-k is refused: the row stays 0 and is not uploaded
-            state = (1 + 2 * self._block, slots)
+            # whether the pass the mirror stands before stores the slot's block before: the
+            # host's alone (the program keeps its own flag, and that block's tokens, in `_prev`)
+            self._blk_pending = np.zeros(slots, bool)
+            state = (2 + 3 * self._block, slots)
         else:
             self._ints = np.zeros((6, slots), np.int32)
             # _tokens: an admit's first token, the row of a fresh slot's first
@@ -490,7 +510,8 @@ class ContinuousBatcher:
             self._tokens, self._pos, self._pads, self._topks, self._fresh, self._live = self._ints
             state = (slots,)
         # what the last dispatched step left for the next, on the device: every
-        # slot's token, or its position and block (`_pass_step_rowpos`)
+        # slot's token, or its position, its block and the block it has yet to store
+        # (`_pass_step_rowpos`)
         self._prev = jnp.zeros(state, jnp.int32)
         # a step while it is dispatched and unread: each step() dispatches one
         # and reads the one before
@@ -537,6 +558,8 @@ class ContinuousBatcher:
             # step is one for every live slot) and the positions they fixed;
             # stay 0 for one causal token a step
             "block_passes": 0, "block_tokens_fixed": 0,
+            # of block_passes, those that stored the block before while they ran their own
+            "block_stores_fused": 0,
             # decode steps that sorted the vocabulary: a live row sampled with
             # top-k or top-p; stays 0 under greedy or temperature-only traffic
             "sort_steps": 0,
@@ -729,8 +752,11 @@ class ContinuousBatcher:
         rows_read = step.cache_rows_read
         if self._block:
             # the mirror is this pass's input until the scatter below moves it on (a late
-            # row's slot that was given away since stands at its new request's)
-            rows_read = self._rows_read([s for s, _ in step.rows], self._block)
+            # row's slot that was given away since stands at its new request's); a row that
+            # stores the block before fetches the slots before its own block once more
+            slots = [s for s, _ in step.rows]
+            storing = [s for s in slots if self._blk_pending[s]]
+            rows_read = tuple(map(sum, zip(self._rows_read(slots, self._block), self._rows_read(storing, 0))))
         self._count_rows_read(rows_read, sp)
         self.stats["decode_steps"] += 1
         rows = [(s, req) for s, req in step.rows if not req.done]
@@ -757,24 +783,21 @@ class ContinuousBatcher:
 
     def _scatter_blocks(self, rows: List[tuple], after: np.ndarray, out: Dict[int, List[int]]) -> Dict[str, int]:
         """What a pass of blocks did to each of `rows`, from the blocks `after`
-        it [2B, S] and the host's mirror, which is the pass's input: the mirror
-        moves on as the program moved the device's state (`_pass_step_rowpos`),
-        and the tokens that every position before them is fixed for go out.
-        Returns what `llm.step` says of the pass."""
+        it [2B, S] and the host's mirror, which is the pass's input: the tokens
+        that every position before them is fixed for go out, and the mirror
+        moves on as the program moved the device's state (`_pass_step_rowpos`):
+        a block that the pass left with every position fixed is pending, and the
+        slot stands at an empty block B slots on, whose first pass (pass 0 in
+        `pass_of`) stores it.  Returns what `llm.step` says of the pass."""
         b = self._block
-        fixed_now = handed = stored = 0
+        fixed_now = handed = stored = fused = 0
         for s, req in rows:
-            if self._blk_fixed[:, s].all():
-                # the pass found nothing masked: it stored the block
-                stored += 1
-                self._pos[s] += b
-                self._blk_tokens[:, s] = 0
-                self._blk_fixed[:, s] = 0
-                req.block_pass, req.block_out, req.pass_of = 0, 0, [-1] * b
-                continue
-            for i in np.nonzero(after[b:, s] != self._blk_fixed[:, s])[0]:
+            fused += int(self._blk_pending[s])
+            newly = np.nonzero(after[b:, s] != self._blk_fixed[:, s])[0]
+            for i in newly:
                 req.pass_of[i] = req.block_pass
-                fixed_now += 1
+            fixed_now += len(newly)
+            stored += not len(newly)  # nothing was masked: no admit and no pass leaves a slot so
             self._blk_tokens[:, s], self._blk_fixed[:, s] = after[:b, s], after[b:, s]
             req.block_pass += 1
             new = out.setdefault(req.request_id, [])
@@ -795,9 +818,17 @@ class ContinuousBatcher:
             handed += len(new)
             if not new:
                 del out[req.request_id]
+            # a block left whole: the slot's next pass stores it and runs the first pass of the next
+            self._blk_pending[s] = not req.done and self._blk_fixed[:, s].all()
+            if self._blk_pending[s]:
+                self._pos[s] += b
+                self._blk_tokens[:, s] = 0
+                self._blk_fixed[:, s] = 0
+                req.block_pass, req.block_out, req.pass_of = 0, 0, [-1] * b
         self.stats["tokens_out"] += handed
         self.stats["block_tokens_fixed"] += fixed_now
-        return dict(tokens_fixed=fixed_now, tokens_out=handed, store_rows=stored)
+        self.stats["block_stores_fused"] += fused
+        return dict(tokens_fixed=fixed_now, tokens_out=handed, store_rows=stored, fused_store_rows=fused)
 
     def fixed_at(self, request_id: int) -> List[int]:
         """The pass of its block (0: the block's first) at which each token
@@ -928,6 +959,7 @@ class ContinuousBatcher:
         self._blk_tokens[:tail, slot] = prompt[whole:]
         self._blk_fixed[:, slot] = np.arange(b) < tail
         req.block_pass, req.block_out, req.pass_of = 0, tail, [-1] * b
+        self._blk_pending[slot] = False
         self._pos[slot] = bucket
         self._pads[slot] = pad
         self._fresh[slot] = 1

@@ -457,6 +457,16 @@ def _span(cache, valid_len, pads, live, cfg: TransformerConfig):
     return spans
 
 
+def _spans(cache, pos, t: int, pads, live, cfg: TransformerConfig, pending=None):
+    """(`_span` of a decode step whose rows hold t positions from pos on,); where
+    the first half of them is the block before (`pending`: `_kv_decode_core`),
+    the two halves': the first for the rows that have a block pending alone, up
+    to pos; the second from pos on, as a pass without it."""
+    if pending is None:
+        return (_span(cache, pos + t, pads, live, cfg),)
+    return _span(cache, pos, pads, pending, cfg), _span(cache, pos + t // 2, pads, live, cfg)
+
+
 def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, k_rope, c_kv):
     """The decode block's core under latent attention, one token a row: row b's
     latent c_kv [B, 1, R] and rotated key k_rope [B, 1, rope] are written at
@@ -481,7 +491,8 @@ def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, 
     return attn, {**cache, "ckv": ckv_all, "kr": kr_all}
 
 
-def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, live=None, span=None, kind: str = "attn"):
+def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, live=None, span=None, kind: str = "attn",
+                    pending=None):
     """The decode block's core over cached keys and values.  q: [B, T, H, D],
     k, v: [B, T, KV, D] of the step's own positions (T = 1, or a block's): they
     are written at [layer, b, pos[b] ...] of the stacks of the layer's state
@@ -490,7 +501,12 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
     kernel over the stacks as they lie (`_on_kernel`) or the dense contraction
     over the layer taken out of them.  k = v = None: a cross layer's, which
     writes nothing and attends to the stack another layer wrote, where it lies
-    (`shared_layer`).  live, span, kind: `_block_decode_rowpos`'s.
+    (`shared_layer`).  live, span, kind, pending: `_block_decode_rowpos`'s.
+    pending [B] bool: T is two blocks, the first the block before, from
+    pos[b] - T / 2 on: its keys and values are written there for the rows that
+    have one pending and for no other row (a scatter that drops the others'
+    rows: nothing of the stack is read), and its queries see the slots before
+    pos[b]; the second half as a pass without it.
     Returns (attn [B, T, H, Dv], the cache after)."""
     t = q.shape[1]
     state = _state_kind(kind, cfg)
@@ -500,6 +516,7 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
     k_name, v_name = LAYER_STATE[state]
     heads = cfg.flat_heads  # the stacks are [n, B, T * KV, D]: a slot's heads are rows pos KV .. pos KV + KV - 1
     extent = cache[k_name].shape[2] // max(heads, 1)
+    half = t // 2
     if k is None:
         layer, k_all, v_all = shared_layer(cfg), cache[k_name], cache[v_name]
     else:
@@ -510,16 +527,23 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
                 at, new = (layer, rows[:, None], slot[:, None] * heads + jnp.arange(heads)), lambda a: a[:, 0]
             elif t == 1:
                 at, new = (layer, rows, pos % extent if ring else pos), lambda a: a[:, 0]
-            else:
+            elif pending is None:
                 at, new = (layer, rows[:, None], pos[:, None] + jnp.arange(t)), lambda a: a
-            k_all = cache[k_name].at[at].set(new(k))
-            v_all = cache[v_name].at[at].set(new(v))
+            else:
+                # a row with no block pending writes its first half past the stack's end: nowhere
+                mine = pending[:, None] | (jnp.arange(t) >= half)
+                at, new = (layer, rows[:, None], jnp.where(mine, pos[:, None] + jnp.arange(-half, half), extent)), lambda a: a
+            k_all = cache[k_name].at[at].set(new(k), mode="drop")
+            v_all = cache[v_name].at[at].set(new(v), mode="drop")
+    # the queries that share a span: all of them, or a half each
+    parts = [q] if pending is None else [q[:, :half], q[:, half:]]
     if _on_kernel(cache):
+        if span is None:
+            span = _spans(cache, pos, t, pads, live, cfg, pending)
         # the stacks and the layer's index: a layer's slice handed to a kernel is a copy of it
         with jax.named_scope(core_scope(kind, cfg)):
-            attn = decode_attention(q, k_all, v_all, layer,
-                                    (_span(cache, pos + t, pads, live, cfg) if span is None else span)[state],
-                                    scale=cfg.d_head ** -0.5, ring=ring, out_dtype=_core_dtype(cfg), kv=heads)
+            attn = [decode_attention(part, k_all, v_all, layer, sp[state], scale=cfg.d_head ** -0.5, ring=ring,
+                                     out_dtype=_core_dtype(cfg), kv=heads) for part, sp in zip(parts, span)]
     else:
         with jax.named_scope(STATE_SCOPE[state]):
             k_layer, v_layer = (lax.dynamic_index_in_dim(a, layer, keepdims=False) for a in (k_all, v_all))
@@ -528,13 +552,15 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
         with jax.named_scope(core_scope(kind, cfg)):
             seen = _ring_seen(jnp.maximum(pads, pos + 1 - cfg.attn_window), pos + 1,
                               k_layer.shape[1]) if ring else None
-            attn = _masked_attention(q, k_layer, v_layer, pos + t, cfg, pads, seen=seen,
-                                     out_dtype=_core_dtype(cfg))  # per-row length
+            ends = [pos + t] if pending is None else [pos, pos + half]  # per-row lengths
+            attn = [_masked_attention(part, k_layer, v_layer, end, cfg, pads, seen=seen, out_dtype=_core_dtype(cfg))
+                    for part, end in zip(parts, ends)]
+    attn = attn[0] if len(attn) == 1 else jnp.concatenate(attn, axis=1)
     return attn, {**cache, k_name: k_all, v_name: v_all}
 
 
 def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads, live=None,
-                         experts=None, span=None, kind: str = "attn"):
+                         experts=None, span=None, kind: str = "attn", pending=None):
     """One block, one token, PER-ROW cache positions (continuous batching:
     every slot decodes at its own depth).  x: [B, 1, E]; pos/pads: [B];
     cache: the attention layers' stacks k, v [n_attn, B, Tmax, KV, D] (among
@@ -545,7 +571,7 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     attends to slots [pads[b], pos[b]] of the layer, read where it lies.
     live: [B] bool, the rows that hold a request: an empty slot's row reads
     nothing of the cache on a TPU (the kernel returns it zeros) and takes no
-    expert (None: every row does both).  span: `_span` of the rows, made
+    expert (None: every row does both).  span: `_spans` of the rows, made
     once a step by `decode_rows`; None: made here.  Returns (x, the cache
     after, experts touched or None: `_ffn_half`).
 
@@ -557,17 +583,30 @@ def _block_decode_rowpos(bp, x, cache, layer, pos, cfg: TransformerConfig, pads,
     x: [B, T, E] with T > 1 is one pass of a model that generates by blocks
     (`cfg.block_length` = T): row b's T positions lie at slots pos[b] ..
     pos[b] + T - 1, their k/v are written there (again at every pass of the
-    block: only the pass that finds every position fixed writes those of the
-    tokens), and each attends to slots [pads[b], pos[b] + T): every earlier
-    block and the whole of its own, in both directions."""
+    block: those of the block's final tokens are written by the next block's
+    first pass, below), and each attends to slots [pads[b], pos[b] + T): every
+    earlier block and the whole of its own, in both directions.
+
+    pending: [B] bool, of the live rows, with x: [B, 2T, E]: the pass also
+    stores the block before.  Row b's first T positions are that block's final
+    tokens, at slots pos[b] - T .. pos[b] - 1: where pending[b], their k/v are
+    written there and each attends to [pads[b], pos[b]), as a pass over that
+    block alone would; where not, the half is dead: it writes nothing, reads
+    nothing and takes no expert.  The second T positions are the pass above,
+    and see the first half's k/v of this layer where they were just written."""
     t = x.shape[1]
     positions = (pos - pads)[:, None]
     if cfg.latent:
         core = functools.partial(_latent_decode_core, bp, cache, layer, pos, pads, cfg)
     else:
-        core = functools.partial(_kv_decode_core, cache, layer, pos, pads, cfg, live=live, span=span, kind=kind)
-    x, cache = _attention_half(bp, x, cfg, positions if t == 1 else positions + jnp.arange(t), core, kind, layer)
-    if live is not None:
+        core = functools.partial(_kv_decode_core, cache, layer, pos, pads, cfg, live=live, span=span, kind=kind,
+                                 pending=pending)
+    if t > 1:
+        positions = positions + (jnp.arange(t) if pending is None else jnp.arange(-(t // 2), t // 2))
+    x, cache = _attention_half(bp, x, cfg, positions, core, kind, layer)
+    if pending is not None:
+        live = jnp.repeat(jnp.stack([pending, live], axis=1), t // 2, axis=1)  # [B, 2T]: a half each
+    elif live is not None:
         live = live[:, None] if t == 1 else jnp.broadcast_to(live[:, None], x.shape[:2])
     x, _, touched = _ffn_half(bp, x, cfg, live, experts, kind=kind)
     return x, cache, touched
@@ -804,7 +843,7 @@ def _bodies(attn, ssm, gmu=None, mamba2=None, ffn=None):
     return {kind: own[kind] if kind in own else functools.partial(attn, kind) for kind in _INIT_KIND}
 
 
-def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None):
+def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=None, pending=None):
     """The decode program's body: one token for every row of the cache, each
     at its own depth.  tokens, pos, pads: [B] (`_block_decode_rowpos` says what
     each row does with its own); live: [B] bool, the rows that hold a request
@@ -818,20 +857,30 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
 
     tokens [B, T]: one pass of each row's own block of T positions, the first
     of them at pos[b] (a model that generates by blocks).  Returns the logits
-    of every position [B, T, V], each its own position's token."""
+    of every position [B, T, V], each its own position's token.
+
+    pending [B] bool, with tokens [B, 2T]: the pass stores the block before
+    while it runs the block at pos[b].  The first T tokens are that block's
+    final ones, run at pos[b] - T for the rows that have it pending (for the
+    others the half is dead: `_block_decode_rowpos`); the logits are the second
+    half's alone, [B, T, V]: the head is not run over tokens that are fixed."""
     blocks = tokens.ndim == 2
     if blocks and (not cfg.generates_blocks or set(cfg.layer_kinds) != {"attn"}):
         raise NotImplementedError("a pass over blocks of positions: attention layers, cfg.block_length > 1")
+    if pending is not None:
+        live = jnp.ones_like(pending) if live is None else live
+        pending = pending & live
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
         if not blocks:
             x = x[:, None, :]  # [B,1,E]
 
     # what the attention kernel is told of the rows: made once, every layer reads the same
-    span = _span(cache, pos + (tokens.shape[1] if blocks else 1), pads, live, cfg) if _on_kernel(cache) else None
+    span = _spans(cache, pos, tokens.shape[1] if blocks else 1, pads, live, cfg, pending) if _on_kernel(cache) else None
 
     def attn(kind, s, bp, experts, cache, layer):
-        x, cache, touched = _block_decode_rowpos(bp, _x(s), cache, layer, pos, cfg, pads, live, experts, span, kind)
+        x, cache, touched = _block_decode_rowpos(bp, _x(s), cache, layer, pos, cfg, pads, live, experts, span, kind,
+                                                 pending)
         return _hand_on(s, x), cache, touched
 
     def ssm(s, bp, experts, cache, layer):
@@ -852,6 +901,8 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
     x = _x(x)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None
+    if pending is not None:
+        x = x[:, x.shape[1] // 2:]
     logits = _head(params, x, cfg).astype(jnp.float32) if blocks else _head(params, x, cfg, row=0)
     return logits, cache, touched
 

@@ -66,9 +66,17 @@ def hashes(root: str) -> dict:
         # a checkout whose pass of blocks is read where it is dispatched hands it the host's rows alone
         behind = "prev" in inspect.signature(step.__wrapped__).parameters
         ints, floats = shape((3 + behind + 2 * b if b else 6, slots), jnp.int32), shape((2, slots), jnp.float32)
-        prev = (shape((1 + 2 * b, slots) if b else (slots,), jnp.int32),) if behind else ()
-        out[f"{name}.decode"] = sha(text(lambda *a: step.__wrapped__(*a, cfg=cfg), params, cache, ints, floats, *prev, key,
-                                         donate_argnums=(1,)))
+        # a pass's state holds the block it has yet to store; a checkout from before that, position and block alone
+        for state in ((2 + 3 * b, 1 + 2 * b) if b else (None,)):
+            prev = (shape((state, slots) if b else (slots,), jnp.int32),) if behind else ()
+            try:
+                out[f"{name}.decode"] = sha(text(lambda *a: step.__wrapped__(*a, cfg=cfg), params, cache, ints, floats, *prev,
+                                                 key, donate_argnums=(1,)))
+                break
+            except (TypeError, ValueError) as e:  # the program takes a state of another size
+                refused = e
+        else:
+            raise refused
         buckets = continuous.prefill_buckets_for(dep["max_prompt_len"])
         for bucket in (buckets[0], buckets[-1]):
             ids, pad = shape((1, bucket), jnp.int32), shape((1,), jnp.int32)

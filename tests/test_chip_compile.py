@@ -200,12 +200,13 @@ def _compiled_decode_step(cfg, device, slots=32, t_max=768, on_kernel=False):
     # the causal step takes the step before's tokens from the device beside the host's rows
     # (six: the last says which slots the step holds); a model that generates
     # by blocks of B takes a slot's position, its block's B tokens and B fixed flags, from the
-    # pass before on the device and from the host (four rows more), and its own step
+    # pass before on the device (with whether the block before is to be stored, and its B tokens)
+    # and from the host (four rows more), and its own step
     b = cfg.block_length if cfg.generates_blocks else 0
     step = continuous._pass_step_rowpos if b else continuous._decode_step_rowpos
     ints = on_chip(jax.ShapeDtypeStruct((4 + 2 * b if b else 6, slots), jnp.int32))
     floats = on_chip(jax.ShapeDtypeStruct((2, slots), jnp.float32))
-    prev = on_chip(jax.ShapeDtypeStruct((1 + 2 * b, slots) if b else (slots,), jnp.int32))
+    prev = on_chip(jax.ShapeDtypeStruct((2 + 3 * b, slots) if b else (slots,), jnp.int32))
     fn = lambda *a: step.__wrapped__(*a, cfg=cfg)
     with pytest.MonkeyPatch.context() as patch:
         if on_kernel:
@@ -644,13 +645,17 @@ SDAR3 = dict(
 
 def test_block_step_writes_its_rows_in_place_and_sorts_no_vocabulary(v5e):
     """The step of a model that generates by blocks (one pass of every slot's
-    block of 4, 32 slots, t_max 768, the cache donated) as the chip's compiler
-    leaves it: the cache is the layer loop's carry as in the causal step, the 4
-    rows a slot and layer are written into the whole stacks in place, nothing
-    is a copy of a stack or writes a whole layer's keys, and the choice of what
+    block of 4 and, in it, the block before's final tokens: 8 positions a slot,
+    32 slots, t_max 768, the cache donated) as the chip's compiler leaves it:
+    the cache is the layer loop's carry as in the causal step, the 8 rows a slot
+    and layer are written into the whole stacks in place (the 4 of a slot with
+    nothing pending dropped by the scatter, not written back as they were),
+    nothing is a copy of a stack or writes a whole layer's keys, and the choice of what
     a pass fixes (a maximum and a log-sum-exp a position) sorts nothing of the
     vocabulary's size: the sorts are the expert path's, the router's k largest of
-    128 probabilities a row and the 32 x 4 x 8 assignments by expert."""
+    128 probabilities a row and the 32 x 8 x 8 assignments by expert.  The head
+    runs over the block alone: the float32 logits are [32, 4, V], 78 MB, and no
+    value of the program holds logits of 8 positions a slot."""
     cfg = transformer.TransformerConfig(**SDAR3)
     slots, b = 32, cfg.block_length
     compiled, _, cache = _compiled_decode_step(cfg, v5e[0], slots=slots)
@@ -662,7 +667,10 @@ def test_block_step_writes_its_rows_in_place_and_sorts_no_vocabulary(v5e):
     _assert_the_stacks_are_written_in_place(compiled, cache)
     sorts = [int(math.prod(int(d) for d in m.split(",")))
              for m in re.findall(r"= \(?\w+\[([\d,]+)\][^=]*? sort\(", compiled.as_text())]
-    assert sorts and max(sorts) <= slots * b * cfg.n_experts < cfg.vocab_size, sorts
+    assert sorts and max(sorts) <= slots * 2 * b * cfg.n_experts < cfg.vocab_size, sorts
+    held = _buffers(compiled, width=None)
+    assert [line[:200] for _, n, line in held if n == slots * 2 * b * cfg.vocab_size] == []
+    assert [line for dtype, n, line in held if dtype == "f32" and n == slots * b * cfg.vocab_size]
 
 
 # Jamba2-3B's widths at the depth and in the order `jamba-closed6` runs them: 28 layers, attention
@@ -760,7 +768,8 @@ def test_decode_step_turns_the_pairs_where_they_lie(v5e, model):
     cfg = transformer.TransformerConfig(**globals()[model])
     compiled, _, _ = _compiled_decode_step(cfg, v5e[0], *_PROJECTED[model], on_kernel=True)
     held = _under_rope(compiled)
-    q = 32 * max(cfg.block_length, 1) * cfg.n_heads * cfg.rope_dim
+    # a pass of blocks turns the block before's positions with its own: 2 x 4 a slot
+    q = 32 * max(2 * cfg.block_length, 1) * cfg.n_heads * cfg.rope_dim
     assert any(op == "fusion" and dtype == "bf16" and n == q for dtype, n, op, _ in held)
     assert [line for dtype, n, _, line in held if dtype == "f32" and n >= q] == []
     assert [line for _, _, op, line in held if op in ("pad", "gather", "scatter")] == []

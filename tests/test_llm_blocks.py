@@ -140,12 +140,83 @@ def test_the_batchers_streams_are_a_plain_loop_over_the_reference(sharpen):
         assert cb.stats["block_passes"] < cb.stats["tokens_out"]  # under one pass a token
         assert {2, 3, 4} & set(sizes) and {1, 4} <= set(per_step) and max(sizes) <= 4
     else:
-        # one position a pass, so a whole block is four passes and the storing one
-        assert cb.stats["block_passes"] > cb.stats["block_tokens_fixed"]
+        # one position a pass and no pass that only stores: a whole block is four passes, and a
+        # request is in as many as fixed a position of it and the one in flight when it ended
+        assert cb.stats["block_passes"] == cb.stats["block_tokens_fixed"] + cb.stats["late_rows"]
+        # every block of an answer but its last is stored, by the first pass of the block after it
+        assert cb.stats["late_rows"] == len(reqs)
+        assert cb.stats["block_stores_fused"] == sum(-(-(n % 4 + new) // 4) - 1 for n, new in asked) == 12
         assert set(sizes) == {1} and cb.fixed_at(reqs[0].request_id)[:8] != [0, 1, 2, 3] * 2
         assert sorted(cb.fixed_at(reqs[0].request_id)[:4]) == [0, 1, 2, 3]
     with pytest.raises(KeyError):
         cb.fixed_at(10_000)
+
+
+@pytest.mark.parametrize("pos, pad, pending, tail", [
+    (12, 4, False, 0), (12, 4, True, 0), (0, 0, False, 3), (16, 8, False, 1), (28, 8, True, 0),
+], ids=["nothing-pending", "pending", "prompt-shorter-than-a-block", "prompt-tail", "pending-block-ends-at-t_max-less-B"])
+def test_a_pass_that_stores_the_block_before_is_the_two_passes_it_replaces(pos, pad, pending, tail):
+    """`decode_rows` over [S, 2B] tokens with a pending mask against a storing
+    pass of the block before followed by a pass of the block (`_pass_logits`, a
+    slot at a time): the cache rows [pos - B, pos + B) of every layer and the
+    block's logits.  The slot under test lies between one that has a block
+    pending at another depth and one that holds no request and says it has one
+    pending: a row with nothing pending, live or not, leaves [pos - B, pos) as it
+    was bit for bit, and no row writes anywhere else (a position before the
+    cache's start does not come round to its end)."""
+    cfg, params = program()
+    b, t_max, slots = cfg.block_length, 32, 3
+    rng = np.random.default_rng(pos + tail)
+    cache = {name: jnp.asarray(rng.standard_normal(a.shape), a.dtype) for name, a in generate.init_cache(cfg, slots, t_max).items()}
+    poss, pads = np.asarray([8, pos, 20], np.int32), np.asarray([0, pad, 4], np.int32)
+    flags, live = np.asarray([True, pending, True]), np.asarray([True, True, False])
+    before = rng.integers(0, MASK, (slots, b)).astype(np.int32)
+    block = np.full((slots, b), MASK, np.int32)
+    block[1, :tail] = rng.integers(0, MASK, tail)
+    block[0, 2] = 17  # a position fixed by an earlier pass of its block
+    step = jax.jit(lambda c, pending: generate.decode_rows(
+        params, c, jnp.asarray(np.concatenate([before, block], axis=1)), jnp.asarray(poss), jnp.asarray(pads), cfg,
+        jnp.asarray(live), pending))
+    logits, after, touched = step(cache, jnp.asarray(flags))
+    assert logits.shape == (slots, b, cfg.vocab_size) and logits.dtype == jnp.float32 and 2.0 <= float(touched) <= 8.0
+    for s in range(slots):
+        at, mine = int(poss[s]), lambda c: {name: np.asarray(a[:, s]) for name, a in c.items()}
+        was, now = mine(cache), mine(after)
+        written = np.zeros(t_max, bool)
+        written[at:at + b] = True  # a slot without a request writes its block's rows too, into its own slot
+        if flags[s] and live[s]:
+            written[at - b:at] = True
+        for name in ("k", "v"):
+            assert np.array_equal(now[name][:, ~written], was[name][:, ~written]), (s, name)
+        if not live[s]:
+            continue
+        rows, pad1 = {name: a[:, s:s + 1] for name, a in cache.items()}, pads[s:s + 1]
+        if flags[s]:
+            _, rows = continuous._pass_logits(params, rows, before[s:s + 1], poss[s:s + 1] - b, pad1, cfg=cfg)
+        want, rows = continuous._pass_logits(params, rows, block[s:s + 1], poss[s:s + 1], pad1, cfg=cfg)
+        assert np.max(np.abs(np.asarray(logits[s]) - np.asarray(want[0]))) < 2e-5, s
+        for name in ("k", "v"):
+            assert np.max(np.abs(now[name] - np.asarray(rows[name][:, 0]))[:, max(at - b, 0):at + b]) < 2e-5, (s, name)
+    # the storing half is there: without it the block's logits are those of another cache
+    if pending:
+        apart, _, _ = step(cache, jnp.asarray([True, False, True]))
+        assert np.max(np.abs(np.asarray(apart[1]) - np.asarray(logits[1]))) > 1e-3
+
+
+def test_answers_that_end_where_the_cache_ends_are_the_plain_loops():
+    """An answer whose last block ends at the cache's last slot: the pass in
+    flight when the host learns of the end runs the row a block further on,
+    past the cache (its block's rows are written nowhere and what it makes is
+    dropped), and stores the last block, which nobody reads."""
+    cfg, params = program()
+    cb = ContinuousBatcher(params, cfg, slots=2, t_max=32, prefill_buckets=(8, 16), prefix_cache_entries=0)
+    rng = np.random.default_rng(5)
+    reqs = [cb.submit(rng.integers(0, MASK, n), max_new_tokens=new) for n, new in ((24, 8), (21, 11), (16, 16))]
+    cb.pump()
+    for r in reqs:
+        want, at, _, tail = plain_generate(params, cfg, r.prompt_ids.tolist(), r.max_new_tokens)
+        assert r.out_tokens == want and cb.fixed_at(r.request_id) == at and cb.block_tail(r.request_id) == tail
+    assert cb.stats["late_rows"] == 3 and cb.stats["block_stores_fused"] == 1 + 2 + 3
 
 
 def test_a_prompt_token_equal_to_the_mask_id_stays_fixed():
@@ -157,9 +228,14 @@ def test_a_prompt_token_equal_to_the_mask_id_stays_fixed():
     cb = ContinuousBatcher(params, cfg, slots=2, t_max=32, prefill_buckets=(8, 16), prefix_cache_entries=0)
     req = cb.submit(prompt, max_new_tokens=6)
     cb.step()  # the admit, and the first pass dispatched
-    assert cb._blk_fixed[:, req.slot].tolist() == [1, 1, 1, 0]
-    cb.step()  # the first pass read
-    assert cb._blk_tokens[:3, req.slot].tolist() == [8, MASK, 11] and cb._blk_fixed[:, req.slot].tolist() == [1, 1, 1, 1]
+    assert cb._blk_fixed[:, req.slot].tolist() == [1, 1, 1, 0] and not cb._blk_pending[req.slot]
+    # the pass fixes the one masked position, so it leaves the block pending and the slot at an empty block 4 on:
+    # what the next pass stores is the prompt's tail as it was given and the token this one fixed
+    left = np.asarray(cb._prev)[:, req.slot].tolist()
+    assert left[:10] == [8 + 4] + [0] * 8 + [1] and left[10:13] == [8, MASK, 11]
+    cb.step()  # the first pass read: the mirror moved as the device's state did
+    assert cb._blk_pending[req.slot] and cb._pos[req.slot] == 8 + 4 and not cb._blk_fixed[:, req.slot].any()
+    assert req.out_tokens == left[13:] and req.fixed_at == [0]
     cb.pump()
     want, at, _, _ = plain_generate(params, cfg, prompt.tolist(), 6)
     assert req.out_tokens == want and req.fixed_at == at and at[0] == 0
@@ -267,8 +343,8 @@ def test_what_a_block_generating_replica_refuses():
 
 
 def test_the_step_and_the_admit_say_what_the_passes_did(monkeypatch):
-    """`llm.step` carries block_rows, tokens_fixed, tokens_out, store_rows beside
-    the expert path's moe_rows (positions) and moe_experts_touched; `llm.admit`
+    """`llm.step` carries block_rows, tokens_fixed, tokens_out, store_rows,
+    fused_store_rows beside the expert path's moe_rows (positions) and moe_experts_touched; `llm.admit`
     carries block_tail and the experts' assignments of what it prefilled; the
     batcher's counts are their sums, and serve_llm ships them as counters."""
     cfg, params = program()
@@ -294,9 +370,15 @@ def test_the_step_and_the_admit_say_what_the_passes_did(monkeypatch):
     assert sum(s["tokens_out"] for s in steps) == 11 == cb.stats["tokens_out"]
     assert sum(s["tokens_fixed"] for s in steps) == cb.stats["block_tokens_fixed"] >= 11
     assert sum(s["block_rows"] for s in steps) == 4 * cb.stats["block_passes"]
-    # the first request's first block (2 of the prompt, 2 masked) and its second are stored; its
-    # third ends the answer; the second request's first block is stored, its second ends it
-    assert sum(s["store_rows"] for s in steps) == 3 and cb.stats["decode_steps"] == len(steps)
+    # the first request's first block (2 of the prompt, 2 masked) and its second are stored, each by
+    # the first pass of the block after it; its third ends the answer; the second request's first
+    # block is stored, its second ends it.  No pass only stores: the passes are those that fix a
+    # position, 2 + 4 + 3 (the answer's last token is fixed third in its block) and 3 + 2, and the
+    # one in flight when each answer ended; the steps are the longer request's (12 with two
+    # storing passes)
+    assert sum(s["fused_store_rows"] for s in steps) == 3 == cb.stats["block_stores_fused"]
+    assert sum(s["store_rows"] for s in steps) == 0 and cb.stats["decode_steps"] == len(steps) == 9 + 1
+    assert cb.stats["block_passes"] == (9 + 1) + (5 + 1) and cb.stats["late_rows"] == 2
     assert cb.stats["moe_assignments"] == (4 + 8) * 2 + cb.stats["block_passes"] * 4 * 2
     assert [len(r.out_tokens) for r in reqs] == [7, 4]
     from cluster_anywhere_tpu.llm import serve_llm

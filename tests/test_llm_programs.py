@@ -559,7 +559,8 @@ def test_a_step_says_how_much_of_the_cache_its_live_rows_could_reach(model, llm_
     slots the step's attention kernel fetches, the live rows' own [pads, pos +
     the step's tokens) in whole key blocks, by the kernel's own helper on the
     host's vectors as the step was dispatched (a pass of blocks: as it is read,
-    where the host's mirror is its input); `cache_rows`, the slots x t_max
+    where the host's mirror is its input, and [pads, pos) once more for a row
+    that stores the block before); `cache_rows`, the slots x t_max
     it is a share of.  After an admit, a request's end and a cancel, a freed
     slot's stale pos and pads count for nothing."""
     import importlib
@@ -599,6 +600,9 @@ def test_a_step_says_how_much_of_the_cache_its_live_rows_could_reach(model, llm_
                     [s for s, _ in cb._flight.rows] if cb._flight is not None else []
                 if held:
                     first, last = cb._pads[held], cb._pos[held] + tokens
+                    if blocks:  # a row that stores the block before fetches [pads, pos) for it once more
+                        storing = [s for s in held if cb._blk_pending[s]]
+                        first, last = np.append(first, cb._pads[storing]), np.append(last, cb._pos[storing])
                     want.append(int(sum((-(-l // 8) - f // 8) * 8 for f, l in zip(first, last))))
                 cb.step()
         finally:
