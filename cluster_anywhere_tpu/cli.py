@@ -412,6 +412,8 @@ def cmd_status(args):
                 print(f"  {k}: {v:g}")
             for k, v in sorted(sp["quantiles"].items()):
                 print(f"  {k}: {v:.4g}" if isinstance(v, float) else f"  {k}: {v}")
+            for k, v in sorted(sp.get("jax", {}).items()):
+                print(f"  jax_{k}: {v:.4g}")
     except Exception:
         pass
     # compiled-DAG plane: execute/result volume, channel traffic, and the

@@ -161,6 +161,10 @@ class ActorRec:
     # it application-aware (replacements first, in-flight streams finish)
     # instead of the head's restart-FSM migration killing it mid-request
     drain_migration: bool = True
+    # the creator's trace context ({"tid", "sid"}), where the creation was traced:
+    # handed to the first incarnation's worker, whose constructor runs under it
+    # (a restart belongs to no creation's trace)
+    trace: Optional[dict] = None
     # where this incarnation's resources are currently charged:
     # "pg" (bundle.used) | "node" (node.avail) | None (not charged) — guards
     # against double-crediting when a PG is removed before the actor's
@@ -2065,6 +2069,7 @@ class Head:
                 self._pub("actors", self._actor_info(a))
             return
         a.addr = rec.addr
+        trace, a.trace = a.trace, None
         try:
             conn = await self._worker_conn(rec)
             await conn.call(
@@ -2076,6 +2081,7 @@ class Head:
                 concurrency_groups=a.concurrency_groups,
                 incarnation=a.incarnation,
                 runtime_env=a.runtime_env,
+                tr=trace,  # protocol.TRACE_FIELD
             )
             if a.incarnation != placing_inc:
                 # superseded while spawning: the newer incarnation owns the
@@ -3401,6 +3407,7 @@ class Head:
             runtime_env=msg.get("runtime_env"),
             strategy=msg.get("strategy"),
             drain_migration=msg.get("drain_migration", True),
+            trace=msg.get("tr"),  # protocol.TRACE_FIELD
         )
         if a.name:
             if a.name in self.named_actors:

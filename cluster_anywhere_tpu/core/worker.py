@@ -14,6 +14,7 @@ MemoryStore (condition-variable waits) so `get`/`wait` never touch the loop.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import os
 import random
@@ -4320,7 +4321,7 @@ class Worker:
         if opts.get("runtime_env"):
             wire_env = self._prepare_runtime_env(opts["runtime_env"])  # user thread
 
-        async def _create():
+        async def _create(tr):
             if blob is not None:
                 await self.head.call("register_function", fn_id=fn_id, blob=blob)
                 self.fn_manager.mark_exported(fn_id)
@@ -4349,10 +4350,16 @@ class Worker:
                 strategy=opts.get("strategy"),
                 drain_migration=bool(opts.get("drain_migration", True)),
                 timeout=None,
+                tr={"tid": tr["tid"], "sid": tr["sid"]} if tr else None,  # protocol.TRACE_FIELD
             )
             return reply
 
-        reply = self.run_coro(_create())
+        # the creator's side of a creation under a trace: placement, the worker's
+        # start and the constructor, which runs under this span's context on the
+        # worker (`actor.init`), as a task runs under its submitter's
+        with (TRACE_HOOK.span("actor.create", cls=getattr(cls, "__name__", "actor"))
+              if TRACE_HOOK is not None else contextlib.nullcontext()) as tr:
+            reply = self.run_coro(_create(tr))
         self._actor_addr_cache[actor_id.hex()] = (reply["addr"], reply["incarnation"])
         return actor_id, reply["addr"]
 

@@ -12,6 +12,10 @@ remote()/get()/put() (nested tasks), sharing the process's asyncio loop.
 
 from __future__ import annotations
 
+import time
+
+_T_BOOT = time.monotonic()  # this module's first line: where span `worker.boot` starts
+
 import asyncio
 import concurrent.futures
 import contextlib
@@ -101,6 +105,10 @@ class WorkerProcess:
         self._dag_executor: Optional[concurrent.futures.ThreadPoolExecutor] = None
         self.actor: Optional[ActorContext] = None
         self._exiting = False
+        # time.monotonic() when this worker had registered with the head, and
+        # whether span `worker.boot` is still to be written (`_boot_span`)
+        self._ready_mono = 0.0
+        self._boot_unwritten = True
         # producer-side backpressure state per streaming task:
         # task_id -> {"acked": int, "event": threading.Event}
         self._streams: Dict[bytes, dict] = {}
@@ -457,9 +465,25 @@ class WorkerProcess:
             **extra,
         )
 
+    def _boot_span(self) -> None:
+        """Span `worker.boot`, once, from two stamps: this module's first line
+        (the interpreter's own start and the package's import lie before it) to
+        the worker registered with the head and ready for its first task; `pool`
+        and `chips` as the worker's environment pins them.  Written under the
+        first traced execution this worker sees, a traced actor's creation
+        among them (`tracing.emit`: event sink only, nothing without a context)."""
+        if self._boot_unwritten:
+            self._boot_unwritten = False
+            from . import accelerators
+
+            chips = 0 if os.environ.get("JAX_PLATFORMS") == "cpu" else accelerators.num_tpu_chips()
+            tracing.emit("worker.boot", _T_BOOT, self._ready_mono,
+                         pool=accelerators.worker_pool(chips), chips=chips)
+
     def _record_running(self, task_id: bytes, name: Optional[str], kind: str, tr: dict):
         """Lifecycle RUNNING phase (only for traced tasks: `tr` came over
         the wire, so tracing was enabled at the submitter)."""
+        self._boot_span()
         # called with the execution's context just installed: `exec_sid` is the
         # id the task's own spans name as their parent, which a reader of the
         # ring maps back to the task (util/state.serve_requests)
@@ -997,8 +1021,13 @@ class WorkerProcess:
                 from .runtime_env import RuntimeEnvContext
 
                 RuntimeEnvContext(msg["runtime_env"], self.worker).apply()
-            args, kwargs = self._resolve_args(specs, kwspecs)
-            return cls(*args, **kwargs)
+            # a traced creation: the arguments' loading (their modules' imports) and
+            # the constructor are children of the creator's `actor.create`
+            with tracing.under(msg.get("tr")):  # protocol.TRACE_FIELD
+                self._boot_span()
+                with tracing.span("actor.init", cls=getattr(cls, "__name__", "actor")):
+                    args, kwargs = self._resolve_args(specs, kwspecs)
+                    return cls(*args, **kwargs)
 
         instance = await self.loop.run_in_executor(self.executor, _make)
         self.actor = ActorContext(
@@ -1074,6 +1103,7 @@ class WorkerProcess:
         # side effects must not complete — instead of waiting a watch tick
         self.worker._on_fenced_cb = self._fenced_now
         await self.worker.connect_async()
+        self._ready_mono = time.monotonic()
         spawn_bg(self._heartbeat_loop())
         spawn_bg(self._watch_head())
         # park forever; the head kills us at job teardown

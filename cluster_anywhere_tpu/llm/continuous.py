@@ -115,6 +115,13 @@ def prefill_buckets_for(max_prompt_len: int) -> tuple:
     while 2 * ladder[-1] < max_prompt_len:
         ladder.append(2 * ladder[-1])
     return tuple(b for b in ladder if b < max_prompt_len) + (max_prompt_len,)
+
+
+# a total of `tracing.jax_build_totals` -> the counts of `stats` it adds to
+_BUILD_STATS = {
+    "trace_s": ("program_build_s", "program_trace_s"), "lower_s": ("program_build_s", "program_trace_s"),
+    "backend_s": ("program_build_s",), "builds": ("program_builds",), "cache_misses": ("program_cache_misses",),
+}
 _NO_TRUNCATION = (
     "{what}: a replica that generates by blocks of {b} chooses each position's token and its "
     "confidence without sorting the vocabulary; temperature alone is served, top-k and top-p are not"
@@ -366,6 +373,11 @@ def _suffix_step(params, rows, token, pos, pad, *, cfg):
     return decode_rows(params, rows, token, pos, pad, cfg)[:2]
 
 
+def tree_bytes(tree) -> int:
+    """The bytes of a pytree's arrays (weights, a cache, a slot's rows)."""
+    return sum(int(a.size) * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+
+
 class PrefixCache:
     """Bounded LRU of prefilled prompt-prefix rows (a cache of batch one: the
     prefix's keys and values, and a recurrence's state after its last token),
@@ -403,11 +415,7 @@ class PrefixCache:
         return len(self._d)
 
     def memory_bytes(self) -> int:
-        return sum(
-            int(a.size) * a.dtype.itemsize
-            for e in self._d.values()
-            for a in jax.tree_util.tree_leaves(e["rows"])
-        )
+        return sum(tree_bytes(e["rows"]) for e in self._d.values())
 
 
 class ContinuousBatcher:
@@ -551,6 +559,13 @@ class ContinuousBatcher:
             # admits that found no compiled prefill for their padded length
             # and traced one; stays where it is once every bucket is warm
             "prefill_traces": 0,
+            # what building this batcher's programs cost, from jax's own events on the
+            # thread that steps it (`count_build`; they stay 0 where nobody registered
+            # it): seconds tracing, lowering and in the backend (a compilation, or a
+            # fetch from the persistent cache), of them the first two alone (which no
+            # cache saves), the programs handed to the backend, and those of them
+            # compiled anew and written to the persistent cache
+            "program_build_s": 0.0, "program_trace_s": 0.0, "program_builds": 0, "program_cache_misses": 0,
             # recurrent state read and written by the steps and installed by the
             # admits; stays 0 for a model of attention layers alone
             "ssm_state_bytes": 0,
@@ -643,6 +658,15 @@ class ContinuousBatcher:
                 self.stats["cancelled"] += 1
                 return True
         return False
+
+    def count_build(self, kind: str, amount: float) -> None:
+        """A sink of `tracing.on_jax_build` for the thread that steps this
+        batcher (a server registers it for its pump): every program here is built
+        on that thread at its first call with a new shape (the prefill a bucket,
+        the decode step or the pass, the suffix, install and sampling programs),
+        and what jax says that cost goes into `stats`."""
+        for key in _BUILD_STATS.get(kind, ()):
+            self.stats[key] += amount
 
     @property
     def has_work(self) -> bool:
@@ -1035,7 +1059,7 @@ class ContinuousBatcher:
         )
         with sp:
             slot = self._by_slot.index(None)
-            traces = self.stats["prefill_traces"]
+            traces, built = self.stats["prefill_traces"], self.stats["program_build_s"]
             prefilled = len(req.prompt_ids)
             if self._block:
                 prefilled = self._admit_blocks(req, slot, sp)
@@ -1047,6 +1071,8 @@ class ContinuousBatcher:
                 self.stats["tokens_out"] += 1
             # 1 where this admit traced its bucket's prefill program
             sp.set(traced=self.stats["prefill_traces"] - traces)
+            if self.stats["program_build_s"] > built:  # and what the programs it built cost
+                sp.set(build_ms=1e3 * (self.stats["program_build_s"] - built))
             req.slot = slot
             self._by_slot[slot] = req
             self._temps[slot] = req.temperature

@@ -10,6 +10,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..util import tracing
+
 
 class ByteTokenizer:
     """Offline byte-level tokenizer (ids: 0=pad, 1=bos, 2=eos, byte b -> b+3).
@@ -109,24 +111,34 @@ def engine_placement(params) -> Dict[str, Any]:
     }
 
 
+def model_params(model: ModelSpec, tcfg):
+    """The model's weights on the device: read from `model.params_path` where
+    there is one, else made from `model.seed`.  Span `llm.replica.init.params`
+    (`source`: `path` or `seed`) holds the work and not its dispatch: the
+    weights are there when it ends."""
+    import jax
+
+    with tracing.span("llm.replica.init.params", source="path" if model.params_path else "seed"):
+        if model.params_path:
+            from . import _params_io
+
+            params = _params_io.load_params(model.params_path)
+        else:
+            from ..models.transformer import init_params
+
+            params = init_params(jax.random.key(model.seed), tcfg)
+        return jax.block_until_ready(params)
+
+
 class _InferenceWorker:
     """Actor-pool UDF: holds compiled model + params for its lifetime
     (reference: stages run in vLLM engine actors)."""
 
     def __init__(self, cfg: ProcessorConfig):
-        import jax
-
         self.cfg = cfg
         self.tok = cfg.tokenizer or ByteTokenizer()
         self.tcfg = cfg.model.transformer_config(self.tok.vocab_size)
-        from ..models.transformer import init_params
-
-        if cfg.model.params_path:
-            from . import _params_io
-
-            self.params = _params_io.load_params(cfg.model.params_path)
-        else:
-            self.params = init_params(jax.random.key(cfg.model.seed), self.tcfg)
+        self.params = model_params(cfg.model, self.tcfg)
         engine_placement(self.params)
         self._step = 0
 

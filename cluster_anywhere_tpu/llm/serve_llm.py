@@ -17,6 +17,7 @@ from .processor import (
     ProcessorConfig,
     _InferenceWorker,
     engine_placement,
+    model_params,
 )
 
 
@@ -157,14 +158,16 @@ class ContinuousLLMServer:
 
         import jax
 
-        from ..models.transformer import init_params
-        from .continuous import ContinuousBatcher, prefill_buckets_for
+        from .continuous import ContinuousBatcher, prefill_buckets_for, tree_bytes
 
         from ..serve.replica import get_request_context, observe_phase, phase
 
+        # the clock of every SPAN event's `mono` and of a load generator's stamps
+        t_init = time.monotonic()
         # jax is loaded from here on: compilations and device memory reach
         # the cluster's metrics from the process that holds the chip
         tracing.enable_jax_profiling()
+        built = tracing.jax_build_totals()  # this thread's, before the constructor's own
         # "<app>/<deployment>" where a replica builds this ("" in-process):
         # the tag its requests' phases are counted under
         self._deployment = get_request_context().deployment
@@ -172,41 +175,67 @@ class ContinuousLLMServer:
         self.config = config
         self.tok = config.tokenizer or ByteTokenizer()
         tcfg = config.model.transformer_config(self.tok.vocab_size)
-        if config.model.params_path:
-            from . import _params_io
-
-            params = _params_io.load_params(config.model.params_path)
-        else:
-            params = init_params(jax.random.key(config.model.seed), tcfg)
-        self.engine_device = engine_placement(params)
-        print(
-            "[llm] engine on {platform} ({device_kind}) x{count}".format(
-                **self.engine_device
-            ),
-            flush=True,
-        )
         t_max = config.max_prompt_len + config.max_new_tokens
-        # the batcher's own bucket ladder, up to the longest prompt this
-        # deployment admits: a short prompt prefills a short program
-        self.cb = ContinuousBatcher(
-            params, tcfg, slots=slots, t_max=t_max,
-            prefill_buckets=prefill_buckets_for(config.max_prompt_len), top_k=config.top_k,
-            prefix_cache_entries=getattr(config, "prefix_cache_entries", 8),
-            prefix_block=getattr(config, "prefix_block", 16),
-        )
+        sp = tracing.span("llm.replica.init", slots=slots, t_max=t_max)
+        with sp:
+            # the first call that needs the backend starts it (seconds, on a TPU):
+            # here, so that the weights' span below holds the weights alone
+            jax.devices()
+            t_backend = time.monotonic()
+            params = model_params(config.model, tcfg)
+            t_params = time.monotonic()
+            self.engine_device = engine_placement(params)
+            print(
+                "[llm] engine on {platform} ({device_kind}) x{count}".format(
+                    **self.engine_device
+                ),
+                flush=True,
+            )
+            # the batcher's own bucket ladder, up to the longest prompt this
+            # deployment admits: a short prompt prefills a short program
+            with tracing.span("llm.replica.init.batcher"):
+                self.cb = ContinuousBatcher(
+                    params, tcfg, slots=slots, t_max=t_max,
+                    prefill_buckets=prefill_buckets_for(config.max_prompt_len), top_k=config.top_k,
+                    prefix_cache_entries=getattr(config, "prefix_cache_entries", 8),
+                    prefix_block=getattr(config, "prefix_block", 16),
+                )
+                jax.block_until_ready(self.cb.cache)
+            # what the constructor's own programs cost (the weights made from a seed
+            # are eager operations, each a small program the first time)
+            init_build_s = sum(
+                now - built[kind] for kind, now in tracing.jax_build_totals().items()
+                if kind in ("trace_s", "lower_s", "backend_s"))
+            sp.set(
+                backend_ms=1e3 * (t_backend - t_init), build_ms=1e3 * init_build_s,
+                param_bytes=tree_bytes(params), cache_bytes=tree_bytes(self.cb.cache),
+                buckets=len(self.cb.prefill_buckets),
+            )
         self.cb.observe_phase = functools.partial(observe_phase, self._deployment)
         self._metrics_synced: dict = {}
         self._lock = threading.Lock()  # batcher is single-threaded inside
-        # seconds callers waited for that lock in _submit, beside the
-        # batcher's own counts (updated under the lock)
-        self.cb.stats["lock_wait_s"] = 0.0
+        # beside the batcher's own counts: seconds callers waited for that lock
+        # in _submit (updated under the lock), and what set-up cost before the
+        # first request, written once: the instant the constructor was entered,
+        # its seconds, and of them the backend's start, the weights, and the
+        # building of its own programs (`program_build_s` and its kin, the
+        # programs built on the pump's thread since, are the batcher's own)
+        self.cb.stats.update(
+            lock_wait_s=0.0, replica_init_mono=t_init, replica_init_s=time.monotonic() - t_init,
+            backend_init_s=t_backend - t_init, params_init_s=t_params - t_backend,
+            init_build_s=init_build_s,
+        )
         self._queues: dict = {}  # request_id -> queue of token ids (+ None EOF)
         self._reqs: dict = {}  # request_id -> Request (done detection)
         self._queue_cls = queue.Queue
         self._stop = False
         self._engine_error: Optional[BaseException] = None
-        self._pump = threading.Thread(target=self._pump_loop, daemon=True)
+        self._pump = threading.Thread(target=self._pump_loop, daemon=True, name="llm-pump")
         self._pump.start()
+        # every program of the batcher's is built on the pump's thread, at its
+        # first call with a new shape: what that costs reaches the batcher's
+        # counts from jax's own events, and the pump's loop gains no statement
+        tracing.on_jax_build(self._pump.ident, self.cb.count_build)
 
     def check_health(self):
         """Serve controller hook: a dead pump means every request on this
@@ -221,6 +250,7 @@ class ContinuousLLMServer:
         self._stop = True
         if self._pump.is_alive():
             self._pump.join(timeout=5)
+        tracing.on_jax_build(self._pump.ident, None)
 
     def __del__(self):  # best-effort; serve teardown also kills the process
         try:
@@ -229,13 +259,20 @@ class ContinuousLLMServer:
             pass
 
     _llm_metrics: dict = {}  # class-level: one registry entry per process
+    _llm_gauges: dict = {}
+    # the gauge's part -> the count it is set from (a set-up phase outlasts the
+    # 30 s that ca_serve_phase_seconds' buckets end at)
+    _READY_PARTS = (("init", "replica_init_s"), ("params", "params_init_s"), ("build", "program_build_s"))
 
     def _sync_engine_metrics(self):
         """Ship the batcher's counters (prefix-cache hits/misses/tokens
         reused, decode steps; requests submitted, tokens handed out, seconds
         queued, in admit and waiting for the replica's lock) as ca_serve_*
         cluster metrics — the series behind the envelope's "hits skip
-        prefill" claim; mean queue wait and mean admit are each two rates."""
+        prefill" claim; mean queue wait and mean admit are each two rates.
+        What set-up cost goes as one gauge, ca_serve_replica_ready_seconds
+        {deployment, part}: the constructor (`init`), of it the weights
+        (`params`), and every program built since (`build`)."""
         if not self._llm_metrics:
             from ..util import metrics as m
 
@@ -305,6 +342,18 @@ class ContinuousLLMServer:
                 self.engine_device["count"],
                 tags={k: self.engine_device[k] for k in ("platform", "device_kind")},
             )
+            self._llm_gauges["ready"] = m.Gauge(
+                "ca_serve_replica_ready_seconds",
+                "seconds of a replica's set-up: its constructor (init), of it the weights (params), "
+                "and its programs traced, lowered, compiled or fetched since (build)",
+                tag_keys=("deployment", "part"),
+            )
+        ready = self._llm_gauges["ready"]
+        for part, key in self._READY_PARTS:
+            cur = self.cb.stats.get(key, 0.0)
+            if cur != self._metrics_synced.get(key, 0.0):
+                ready.set(cur, tags={"deployment": self._deployment, "part": part})
+                self._metrics_synced[key] = cur
         for key, counter in self._llm_metrics.items():
             cur = self.cb.stats.get(key, 0)
             delta = cur - self._metrics_synced.get(key, 0)

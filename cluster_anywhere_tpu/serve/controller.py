@@ -23,7 +23,7 @@ from typing import Any, Dict, List
 
 from ..core import api as ca
 from ..core.actor import get_actor, kill
-from ..util import flightrec
+from ..util import flightrec, tracing
 from .config import DeploymentConfig, DeploymentStatus
 from .replica import Replica
 
@@ -68,6 +68,9 @@ class _DeploymentState:
         # last observation that informed one (ca status / /api/serve)
         self.last_scale: Optional[Dict[str, Any]] = None
         self.last_autoscale_obs: Optional[Dict[str, Any]] = None
+        # the trace context of the deploy call, where it was traced: the reconcile
+        # thread starts the replicas under it, so a deploy's trace holds their set-up
+        self.trace = tracing.current()
 
     def key(self) -> str:
         return f"{self.app}/{self.name}"
@@ -461,14 +464,15 @@ class ServeController:
                 **st.cfg.actor_options(),
             )
             try:
-                h = Rep.remote(
-                    st.deployment_def,
-                    st.init_args,
-                    st.init_kwargs,
-                    st.cfg.user_config,
-                    rid,
-                    deployment_name=f"{st.app}:{st.name}",
-                )
+                with tracing.under(st.trace):
+                    h = Rep.remote(
+                        st.deployment_def,
+                        st.init_args,
+                        st.init_kwargs,
+                        st.cfg.user_config,
+                        rid,
+                        deployment_name=f"{st.app}:{st.name}",
+                    )
                 t = ca.get(h.telemetry.remote(), timeout=60)
             except Exception as e:
                 st.status = "UNHEALTHY"

@@ -162,6 +162,7 @@ class Replica:
         handle_specs: Optional[Dict[str, Any]] = None,
         deployment_name: Optional[str] = None,
     ):
+        t_start = time.monotonic()  # where span `serve.replica.start` starts
         # late-bind nested DeploymentHandles (model composition): bound
         # sub-deployments arrive as specs and materialize into handles here
         from .router import DeploymentHandle
@@ -189,10 +190,18 @@ class Replica:
         else:
             # the constructor can ask which deployment it is being built for
             token = _request_context.set(RequestContext(deployment=self._phase_dep))
+            # span `serve.replica.start`: this actor's creation to its user class
+            # constructed, whose own spans are its children (nothing where the
+            # creation is not traced)
+            own = tracing.child_context()
             try:
-                self.instance = deployment_def(*init_args, **init_kwargs)
+                with tracing.under(own):
+                    self.instance = deployment_def(*init_args, **init_kwargs)
             finally:
                 _request_context.reset(token)
+            if own is not None:
+                tracing.emit("serve.replica.start", t_start, time.monotonic(), own=own,
+                             deployment=self._phase_dep)
         self.num_ongoing = 0
         self.total_requests = 0
         if user_config is not None:

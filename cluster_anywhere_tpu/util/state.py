@@ -246,7 +246,11 @@ def serve_plane() -> Dict[str, Any]:
     ca_serve_* counters and gauges, the request/backpressure latency
     quantiles, and p50 / p99 / count of every phase of a request's way
     (`ca_serve_phase_seconds`, over the whole window and every request) — the
-    one-call view of admission, routing, prefix reuse, and drain health."""
+    one-call view of admission, routing, prefix reuse, and drain health.
+    `"jax"` is what building programs cost the cluster's processes
+    (`tracing.enable_jax_profiling`): `compiles`, `cache_hits`,
+    `cache_misses`, and the sums of `ca_jax_compile_seconds` by part
+    (`trace_s`, `lower_s`, `backend_s`, `cache_fetch_s`)."""
     from .metrics import get_metrics_snapshot, histogram_quantile, merged_histogram
 
     deployments: Dict[str, Any] = {}
@@ -270,6 +274,7 @@ def serve_plane() -> Dict[str, Any]:
     counters: Dict[str, int] = {}
     gauges: Dict[str, float] = {}
     quantiles: Dict[str, float] = {}
+    jax_programs: Dict[str, float] = {}
     try:
         snap = get_metrics_snapshot()
         for name, rec in snap.items():
@@ -279,8 +284,20 @@ def serve_plane() -> Dict[str, Any]:
                 )
             elif name.startswith("ca_serve_") and rec.get("type") == "gauge":
                 # the proxy's streams open, executor work pending and pool
-                # size; the replicas' engine devices (summed over their tags)
-                gauges[name[len("ca_serve_"):]] = float(sum(rec.get("data", {}).values()))
+                # size; the replicas' engine devices (summed over their tags);
+                # a gauge with a `part` tag (a replica's set-up seconds) a part
+                parts = _cells_by_tag(rec, "part")
+                for part, cells in (parts or {"": rec.get("data", {})}).items():
+                    key = name[len("ca_serve_"):] + (f".{part}" if part else "")
+                    gauges[key] = float(sum(cells.values()))
+        # what building jax programs cost, over every process that armed the hook
+        # (replicas, trainers): counts, and seconds by part of a build
+        for kind in ("compiles", "cache_hits", "cache_misses"):
+            rec = snap.get(f"ca_jax_{kind}_total")
+            if rec:
+                jax_programs[kind] = int(sum(rec.get("data", {}).values()))
+        for event, cells in _cells_by_tag(snap.get("ca_jax_compile_seconds"), "event").items():
+            jax_programs[f"{event}_s"] = float(sum(c.get("sum", 0.0) for c in cells.values()))
         series = [
             (snap.get("ca_serve_request_latency_seconds"), "request_latency"),
             (snap.get("ca_serve_backpressure_seconds"), "backpressure"),
@@ -306,6 +323,7 @@ def serve_plane() -> Dict[str, Any]:
         "counters": counters,
         "gauges": gauges,
         "quantiles": quantiles,
+        "jax": jax_programs,
     }
 
 

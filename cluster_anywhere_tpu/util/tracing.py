@@ -59,9 +59,12 @@ clocks to agree (`util/state.serve_requests`).
 
 JAX hooks: `enable_jax_profiling()` (called by `enable()` when jax is
 already imported, by the LLM engine and by the train backend once they
-have imported it) counts backend compilations into `ca_jax_compiles_total`
-and a `ca_jax_compile_seconds` histogram, and samples per-device memory
-into `ca_device_memory_bytes` gauges at each metrics flush.
+have imported it) keeps what building programs costs, by the thread that
+built them (`jax_build_totals`; `on_jax_build` registers a thread's sink) and
+in `ca_jax_compiles_total`, `ca_jax_cache_hits_total`,
+`ca_jax_cache_misses_total` and the histogram `ca_jax_compile_seconds`, and
+samples per-device memory into `ca_device_memory_bytes` gauges at each
+metrics flush.
 """
 
 from __future__ import annotations
@@ -71,7 +74,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from . import metrics
 
@@ -503,17 +506,82 @@ class under:
 # ---------------------------------------------------------------- JAX hooks
 _jax_hooked = False
 
+# jax.monitoring's events (jax 0.9) by their exact names, each with the total it
+# adds to.  A program new to the process is traced to a jaxpr, lowered to a
+# module and handed to the backend, which compiles it or fetches it from the
+# persistent cache (`backend_s` holds either; `cache_fetch_s` is the fetch alone
+# and lies inside it).  `/jax/compilation_cache/compile_time_saved_sec` is no time
+# spent and is left out.
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_DURATIONS = {
+    _JAX_TRACE: "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch_s",
+}
+_JAX_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    # a program compiled anew and written to the persistent cache (jax writes
+    # none that compiled in under its `persistent_cache_min_compile_time_secs`)
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+JAX_BUILD_KINDS = (*_JAX_DURATIONS.values(), "builds", *_JAX_COUNTS.values())
+# JAX builds a program on the thread that calls it, and reports there: the totals
+# are kept a thread (`threading.get_ident()`), each written by its own thread alone
+_build_totals: Dict[int, Dict[str, float]] = {}
+_build_sinks: Dict[int, Callable[[str, float], None]] = {}
+_trace_depth: Dict[int, int] = {}  # jaxpr traces open on a thread: a jit inside a jit
+
+
+def jax_build_totals(thread: Optional[int] = None) -> Dict[str, float]:
+    """What the programs built on one thread (default: the caller's) have cost
+    since `enable_jax_profiling()`: seconds tracing (`trace_s`; a jit traced
+    inside another counts once, in the outer one), lowering (`lower_s`) and in
+    the backend (`backend_s`: a compilation, or a fetch from the persistent
+    cache, whose own seconds are `cache_fetch_s`), and counts: `builds`
+    (programs handed to the backend), `cache_hits`, `cache_misses`.  A call of
+    a shape the process has seen adds nothing."""
+    totals = _build_totals.get(threading.get_ident() if thread is None else thread, {})
+    return {kind: totals.get(kind, 0.0) for kind in JAX_BUILD_KINDS}
+
+
+def on_jax_build(thread: int, fn: Optional[Callable[[str, float], None]]) -> None:
+    """Register `fn(kind, amount)` for the programs built on one thread: it
+    runs on that thread, at the event, once for each total of
+    `jax_build_totals` that the event adds to (`amount`: seconds, or 1 for a
+    count).  One sink a thread; None takes it away.  Whoever owns a thread that
+    builds programs keeps its own counts current this way, with no statement on
+    the path that calls them.  Registering starts the thread's totals at zero:
+    an ident may have been a thread's that has ended."""
+    if fn is None:
+        _build_sinks.pop(thread, None)
+    else:
+        _build_totals.pop(thread, None)
+        _build_sinks[thread] = fn
+
+
+def _count_build(kind: str, amount: float) -> None:
+    ident = threading.get_ident()
+    totals = _build_totals.setdefault(ident, {})
+    totals[kind] = totals.get(kind, 0.0) + amount
+    sink = _build_sinks.get(ident)
+    if sink is not None:
+        sink(kind, amount)
+
 
 def enable_jax_profiling() -> bool:
-    """Surface device-side cost in the same pipeline: a `ca_jax_compiles_total`
-    counter and a `ca_jax_compile_seconds` histogram fed by jax.monitoring's
-    backend-compile events (a program that was not in this process yet:
-    compiled, or fetched from the persistent cache), and
-    `ca_device_memory_bytes` gauges sampled at each metrics flush.  The
-    engine and the train backend call it once they have imported jax; a
-    profiler session has the compilations themselves on its own clock.
-    Returns False when jax (or its monitoring API) is unavailable — callers
-    treat that as "nothing to profile", never an error."""
+    """Surface device-side cost in the same pipeline, from jax.monitoring's
+    events: what building programs costs, by thread (`jax_build_totals`,
+    `on_jax_build`) and for the cluster's metrics: `ca_jax_compiles_total` (a
+    program that was not in this process yet: compiled, or fetched from the
+    persistent cache), `ca_jax_cache_hits_total`, `ca_jax_cache_misses_total`,
+    and the histogram `ca_jax_compile_seconds{event}` (`trace`, `lower`,
+    `backend`, `cache_fetch`), whose sums `util/state.serve_plane()["jax"]` and
+    `ca status` print; and `ca_device_memory_bytes` gauges sampled at each
+    metrics flush.  The engine and the train backend call it once they have
+    imported jax; a profiler session has the compilations themselves on its
+    own clock.  Returns False when jax (or its monitoring API) is unavailable —
+    callers treat that as "nothing to profile", never an error."""
     global _jax_hooked
     if _jax_hooked:
         return True
@@ -525,26 +593,63 @@ def enable_jax_profiling() -> bool:
 
     compile_hist = metrics.Histogram(
         "ca_jax_compile_seconds",
-        "jit/pjit backend compilation time",
+        "seconds building a program new to the process, by part: trace, lower, backend "
+        "(compiled, or fetched from the persistent cache), cache_fetch (the fetch alone)",
         tag_keys=("event",),
     )
-    compile_count = metrics.Counter(
-        "ca_jax_compiles_total",
-        "programs this process compiled or fetched from the persistent cache",
-    )
+    counters = {
+        "builds": metrics.Counter(
+            "ca_jax_compiles_total",
+            "programs this process compiled or fetched from the persistent cache",
+        ),
+        "cache_hits": metrics.Counter(
+            "ca_jax_cache_hits_total", "programs fetched from jax's persistent compilation cache",
+        ),
+        "cache_misses": metrics.Counter(
+            "ca_jax_cache_misses_total",
+            "programs compiled anew and written to jax's persistent compilation cache",
+        ),
+    }
+    hist_tags = {kind: {"event": kind[: -len("_s")]} for kind in _JAX_DURATIONS.values()}
+
+    def _on_trace_start(event: str, value, **kw):
+        if event == _JAX_TRACE:
+            ident = threading.get_ident()
+            _trace_depth[ident] = _trace_depth.get(ident, 0) + 1
 
     def _on_duration(event: str, duration: float, **kw):
-        if "compile" not in event:
+        kind = _JAX_DURATIONS.get(event)
+        if kind is None:
             return
         try:
-            compile_hist.observe(duration, {"event": event})
-            if "backend_compile" in event:
-                compile_count.inc()
+            if event == _JAX_TRACE:
+                # a trace that began before the hook was armed ends at depth 0
+                ident = threading.get_ident()
+                depth = _trace_depth[ident] = max(_trace_depth.get(ident, 0) - 1, 0)
+                if depth:
+                    return  # inside another trace, which holds these seconds
+            compile_hist.observe(duration, hist_tags[kind])
+            _count_build(kind, duration)
+            if kind == "backend_s":
+                counters["builds"].inc()
+                _count_build("builds", 1)
+        except Exception:
+            return
+
+    def _on_event(event: str, **kw):
+        kind = _JAX_COUNTS.get(event)
+        if kind is None:
+            return
+        try:
+            counters[kind].inc()
+            _count_build(kind, 1)
         except Exception:
             return
 
     try:
+        monitoring.register_scalar_listener(_on_trace_start)
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception:
         return False
 

@@ -533,3 +533,81 @@ def test_serve_requests_gives_each_phase_its_self_time_on_a_hand_made_ring():
         "self_p50_ms": pytest.approx(300.0), "self_p99_ms": pytest.approx(300.0)}
     assert state.serve_requests(events=[e for e in ring if e["name"] != "serve:POST /llm"]) == {
         "requests": [], "phases": {}}
+
+
+# -- set-up: before the first request (ISSUE 52) ------------------------------
+SETUP_TREE = {
+    "worker.boot": "actor.create",
+    "actor.init": "actor.create",
+    "serve.replica.start": "actor.init",
+    "llm.replica.init": "serve.replica.start",
+    "llm.replica.init.params": "llm.replica.init",
+    "llm.replica.init.batcher": "llm.replica.init",
+}
+
+
+@limited(180)
+def test_a_deploy_under_tracing_is_one_trace_from_the_workers_first_line_to_the_weights(front):
+    """A deploy while the driver traces: the replica's creation (`actor.create`,
+    in the controller), its worker's boot, the constructor's run on the worker
+    (`actor.init` > `serve.replica.start` > `llm.replica.init` and its two
+    children) are one trace of the deploy call's, every span with `mono`; the
+    fixture's own two deploys, untraced, left none of them; and the operator's
+    reading has the replica's set-up seconds by part and what jax's programs cost."""
+    from cluster_anywhere_tpu.llm import ModelSpec, ProcessorConfig
+
+    names = set(SETUP_TREE) | {"actor.create"}
+    assert not [e for e in _ring() if e.get("state") == "SPAN" and e["name"] in names]
+    cfg = ProcessorConfig(model=ModelSpec(preset="tiny"), max_prompt_len=16, max_new_tokens=8,
+                          prefix_cache_entries=0)
+    tracing.enable()
+    try:
+        app = serve.deployment(DagIngress, name="late", max_ongoing_requests=2).bind(cfg, 2)
+        serve.run(app, name="late", route_prefix="/late", wait_timeout_s=120)
+    finally:
+        tracing.disable()
+    deadline = time.monotonic() + 20.0
+    while True:
+        spans = [e for e in _ring() if e.get("state") == "SPAN" and e["name"] in names]
+        starts = [e for e in spans if e["name"] == "serve.replica.start"]
+        if (starts and {e["name"] for e in spans if e["trace"]["tid"] == starts[0]["trace"]["tid"]} == names) \
+                or time.monotonic() > deadline:
+            break
+        time.sleep(0.25)
+    (start,) = starts
+    assert start["deployment"] == "late/late"
+    mine = [e for e in spans if e["trace"]["tid"] == start["trace"]["tid"]]
+    by_name = {e["name"]: e for e in mine}
+    assert set(by_name) == names and len(mine) == len(names)
+    assert all("mono" in e and e["end"] >= e["start"] for e in mine)
+    by_sid = {e["trace"]["sid"]: e["name"] for e in mine}
+    assert {n: by_sid.get(e["trace"].get("psid")) for n, e in by_name.items() if n != "actor.create"} == SETUP_TREE
+    assert by_name["actor.create"]["cls"] == by_name["actor.init"]["cls"] == "Replica"
+    assert by_name["worker.boot"]["pool"] == "cpu" and by_name["worker.boot"]["chips"] == 0
+    # in the order they happened, each inside the one that holds it
+    assert by_name["actor.create"]["mono"] <= by_name["worker.boot"]["mono"] <= by_name["actor.init"]["mono"]
+    for child, parent in SETUP_TREE.items():
+        if child != "worker.boot":
+            assert by_name[parent]["mono"] <= by_name[child]["mono"]
+            assert by_name[child]["end"] <= by_name[parent]["end"] + 0.05, child
+    # the worker and the controller are other processes than this one, which enabled tracing
+    assert by_name["llm.replica.init"]["worker_id"] != by_name["actor.create"]["worker_id"]
+    # the operator's view, with no trace: the gauge by part, and jax's seconds by part of a build
+    st, body = _post(front, "/late", {"prompt": "now", "max_new_tokens": 3})
+    assert st == 200, body
+    deadline = time.monotonic() + 20.0
+    while True:
+        plane = state.serve_plane()
+        ready = {k: v for k, v in plane["gauges"].items() if k.startswith("replica_ready_seconds.")}
+        if all(ready.get(f"replica_ready_seconds.{p}", 0.0) > 0.0 for p in ("init", "params", "build")) \
+                or time.monotonic() > deadline:
+            break
+        time.sleep(0.5)
+    assert sorted(ready) == [f"replica_ready_seconds.{p}" for p in ("build", "init", "params")]
+    assert all(v > 0.0 for v in ready.values())
+    # three replicas' constructors (the gauge sums them); a replica's weights are inside its constructor
+    assert ready["replica_ready_seconds.params"] < ready["replica_ready_seconds.init"]
+    jax_cost = plane["jax"]
+    assert jax_cost["compiles"] > 0 and jax_cost["backend_s"] > 0.0 and jax_cost["trace_s"] > 0.0
+    assert set(jax_cost) <= {"compiles", "cache_hits", "cache_misses", "trace_s", "lower_s", "backend_s",
+                             "cache_fetch_s"}
