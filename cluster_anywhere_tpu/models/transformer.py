@@ -55,13 +55,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from ..ops.attention import FLASH_LSE, FLASH_OUT
 from ..ops.attention import attention as dense_attention
 from ..parallel.pipeline import pipeline_apply
 from ..parallel.ring_attention import ring_attention
 from ..parallel.ulysses import ulysses_attention
+from ..util import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +84,10 @@ class TransformerConfig:
     pp: int = 1
     sp: int = 1
     num_microbatches: int = 1
+    # recompute what does not fit: every block is a `jax.checkpoint` that keeps its input and runs
+    # forward again in the backward pass, all of it or, where the device's memory has room beside
+    # the step's state (`_remat_keeps` decides as the step is traced), only the two norms and the
+    # FFN half: the attention half's q, k, v, the core's result and h are kept (KEPT_NAMES)
     remat: bool = False
     # unroll the layer scan: XLA overlaps each layer's weight streaming with
     # the previous layer's compute across iteration boundaries (a rolled
@@ -1521,19 +1528,31 @@ def _gmu_block(bp, s, cfg: TransformerConfig, live=None):
     return s._replace(x=x)
 
 
+# What a checkpointed block (`cfg.remat`) keeps of its attention half where the device has room
+# (`_remat_keeps`), by the names `jax.checkpoint`'s policy knows them under: q, k and v as
+# `_block_forward`'s core is given them (turned, k and v at their own heads, before `_gqa_repeat`),
+# the half's result h = x + wo(attn), from which the FFN half's recomputation starts, and the two
+# residuals the flash kernel's forward rule makes (ops/attention.py).  Outside a checkpoint a name
+# is the identity and lowers to nothing; the programs that serve (models/generate.py) trace none.
+KEPT_NAMES = ("attn.q", "attn.k", "attn.v", "attn.h", FLASH_OUT, FLASH_LSE)
+
+
 def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), kind: str = "attn",
-                   layer=None):
+                   layer=None, keep: bool = False):
     """One transformer block. s: x [B, T_local, E], or `Carried`.  manual_axes:
     the mesh axes the caller's shard_map is already manual over (pp/sp/ep
     subset); kind: the layer's (a window layer attends to its window: forward
     only on a TPU; a cross layer to the keys and values the loop carries, which
-    the full layer before it left there); layer: its number among its kind.
+    the full layer before it left there); layer: its number among its kind;
+    keep: the block is a checkpoint that keeps KEPT_NAMES, which are given here.
     Returns (what it hands on, the MoE load-balance loss: 0 dense)."""
     x = _x(s)
     t = x.shape[1]
     offset = lax.axis_index("sp") * t if "sp" in manual_axes and cfg.sp > 1 else 0
 
     def core(q, k, v):
+        if keep:
+            q, k, v = (a if a is None else checkpoint_name(a, "attn." + name) for a, name in zip((q, k, v), "qkv"))
         with jax.named_scope(core_scope(kind, cfg)):
             if k is None:
                 k, v = s.k, s.v
@@ -1545,6 +1564,8 @@ def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozens
             return _attention(q, k, v, cfg, mesh, manual_axes, cfg.attn_window * is_window(kind)), made
 
     x, (k, v) = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind, layer)
+    if keep:
+        x = checkpoint_name(x, "attn.h")
     x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes, kind=kind)
     made = dict(k=k, v=v) if kind == "attn" else {}
     return _hand_on(s, x, **made), jnp.zeros((), jnp.float32) if aux is None else aux
@@ -1729,14 +1750,100 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     return carry, {kind: jax.tree_util.tree_map(join, *runs) for kind, runs in outs.items()}
 
 
-def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset()):
+# The bytes of a device's memory that `_remat_keeps` leaves to a train step's own temporaries.  The
+# chip's compiler counts 4.46 GB of them for the bare checkpoint of `train-fsdp4`'s step (Mistral-7B's
+# widths 12 layers deep, 2 x 4,096 rows and a quarter of the float32 weights and moments a chip:
+# `memory_analysis().temp_size_in_bytes` of the step compiled for a described v5e 2x2, PERF.md
+# section 6, PR 53): the gradients before the optimizer has taken them, 2.89 GB, the logits in
+# cfg.dtype and in float32, 1.6 GB, a layer's recomputed FFN.  The same step with KEPT_NAMES counts
+# 7.28 GB, 2.81 more, where `_kept_bytes` says 2.83: 15.94 GB with its 8.66 of state, of the 16.91 a
+# v5e reports.  A device's `peak_bytes_in_use` does not hold a program's temporaries (8.50 GB with
+# and without the names, a step of 2 layers on one chip), so the compiler's count is the one there is.
+REMAT_TEMP_BYTES = 9 * 2 ** 29
+
+# the kinds of layer that attend to nothing: a checkpoint of theirs holds none of KEPT_NAMES
+_NO_ATTENTION = ("ssm", "gmu", "mamba2", "ffn")
+
+
+def _memory_limit(mesh) -> Optional[int]:
+    """The least `bytes_limit` that this process's devices of the mesh report
+    (without a mesh: its first device), None where a device reports none (the
+    CPU)."""
+    devices = jax.local_devices()[:1] if mesh is None else [
+        d for d in mesh.devices.flat if d.process_index == jax.process_index()]
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    return min(limits) if limits and all(limits) else None
+
+
+def _bytes_a_chip(params, cfg: TransformerConfig, mesh) -> int:
+    """The bytes of `params` (arrays, tracers or shapes) that one device holds: all
+    of them without a mesh, a leaf's share under `param_specs` on one."""
+    if mesh is None or mesh.size == 1:
+        return sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    shards = lambda spec: math.prod(mesh.shape[a] for part in spec if part for a in (part if isinstance(part, tuple) else (part,)))
+    held = jax.tree_util.tree_map(lambda x, spec: x.size * x.dtype.itemsize // shards(spec), params, param_specs(cfg),
+                                  is_leaf=lambda x: isinstance(x, P))
+    return sum(jax.tree_util.tree_leaves(held))
+
+
+def _kept_bytes(cfg: TransformerConfig, rows: int) -> int:
+    """The bytes KEPT_NAMES hold of one attention layer over `rows` (batch x
+    positions): q, k, v as `_attention_half` hands them to its core, the core's
+    result as wide as the values (float32 under differential attention), h,
+    each in cfg.dtype, and the kernel's log-sum-exp, a float32 a head."""
+    h = cfg.n_heads
+    if cfg.latent:
+        q, kv, out = h * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), cfg.qk_rope_head_dim + cfg.kv_lora_rank, h * cfg.v_head_dim
+    else:
+        q, kv, out = h * cfg.cached_width, 2 * cfg.cached_heads * cfg.cached_width, h * cfg.cached_width * (1 + cfg.diff_attn)
+    return rows * ((q + kv + out + cfg.d_model) * jnp.dtype(cfg.dtype).itemsize + 4 * h)
+
+
+def _remat_keeps(cfg: TransformerConfig, mesh, shape, state_bytes: int) -> bool:
+    """Whether the checkpointed blocks of a step over ids of `shape` [B, T]
+    keep KEPT_NAMES (`_stage_forward`): they do where the bytes that puts on a
+    chip, `_kept_bytes` of the rows a chip sees (the axes that divide the batch
+    or, under a ring, the positions; tp counts as dividing nothing) in every
+    attention layer of its stage (under pp over the schedule's m + pp - 1 steps
+    of B / m rows), fit the device's memory (`_memory_limit`) less
+    `state_bytes`, what the chip holds through the step, and REMAT_TEMP_BYTES.
+    All of the layers or none: one scan traces its body once.  A device that
+    reports no limit (the CPU) keeps them: nothing says they do not fit, and
+    the tests off the chip then run the path the chip runs.  Decided as the
+    step is traced and said once there, in a span `train.remat`: kept,
+    kept_bytes, kept_layers (0 where not kept), budget_bytes (-1: no limit)."""
+    layers = sum(kind not in _NO_ATTENTION for kind in cfg.layer_kinds) // cfg.pp
+    rows = shape[0] * shape[1]
+    if mesh is not None:
+        over = ["dp", "fsdp"] + ["sp"] * (cfg.resolved_attn() != "dense") + ["ep"] * bool(cfg.n_experts)
+        rows = -(-rows // math.prod(mesh.shape[axis] for axis in over))
+    if cfg.pp > 1:
+        rows = -(-rows * (cfg.num_microbatches + cfg.pp - 1) // cfg.num_microbatches)
+    kept_bytes, limit = layers * _kept_bytes(cfg, rows), _memory_limit(mesh)
+    budget = -1 if limit is None else limit - state_bytes - REMAT_TEMP_BYTES
+    kept = layers > 0 and (limit is None or kept_bytes <= budget)
+    with tracing.span("train.remat", kept=kept, kept_bytes=kept_bytes, kept_layers=layers * kept, budget_bytes=budget):
+        pass
+    return kept
+
+
+def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), keep: bool = False):
     """The layer loop over this stage's layers.  stacks: `layer_stacks`, leaves
     [L_stage, ...].  Returns (x, aux) — aux is the summed MoE load-balance loss
-    (0 dense)."""
+    (0 dense).
+
+    Under `cfg.remat` every block is a `jax.checkpoint`: a layer's input is
+    kept and the backward pass runs the block forward again.  keep
+    (`_remat_keeps`: the device has room) hands the checkpoint a policy by
+    names, under which an attention block also keeps KEPT_NAMES and its
+    backward pass runs again the two norms, `_gqa_repeat` and the FFN half
+    alone; a block of a kind that attends to nothing holds no such name and
+    recomputes whole either way, as does a core that makes other residuals
+    than the flash kernel's (the dense reference, a ring)."""
     # an attention kind's block is the same whatever its FFN: that is what its weights hold
     blocks = {
-        kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind)
-        for kind in _INIT_KIND if kind not in ("ssm", "gmu", "mamba2", "ffn")
+        kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind, keep=keep)
+        for kind in _INIT_KIND if kind not in _NO_ATTENTION
     }
 
     def ssm(bp, s, layer=None):
@@ -1754,7 +1861,8 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
 
     blocks["ffn"] = ffn
     if cfg.remat:
-        blocks = {kind: jax.checkpoint(block) for kind, block in blocks.items()}
+        policy = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES) if keep else None
+        blocks = {kind: jax.checkpoint(block, policy=policy) for kind, block in blocks.items()}
 
     def body(kind, carry, bp, _held, layer):
         x, aux = carry
@@ -1788,7 +1896,15 @@ def _head(params, x, cfg: TransformerConfig, row=None):
 
 def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = False):
     """ids: [B, T] int32 -> logits [B, T, V] (with the MoE load-balance aux
-    loss when return_aux; 0 for dense configs)."""
+    loss when return_aux; 0 for dense configs).  Under `cfg.remat` a gradient
+    through it keeps what fits beside the weights alone (`_remat_keeps`)."""
+    return _forward(params, ids, cfg, mesh, return_aux)
+
+
+def _forward(params, ids, cfg: TransformerConfig, mesh, return_aux: bool, state: float = 1.0):
+    """`forward`, told what stays on a chip through the step it is part of:
+    `state` times the weights' bytes (`make_train_step`: the optimizer's
+    state beside them)."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]  # [B, T, E]
     manual_axes = set()
@@ -1803,6 +1919,7 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
 
     if manual_axes or (mesh is not None and mesh.size > 1):
         _one_device_only(cfg, f"forward on a mesh of {1 if mesh is None else mesh.size} devices")
+    keep = cfg.remat and _remat_keeps(cfg, mesh, ids.shape, int(state * _bytes_a_chip(params, cfg, mesh)))
     if manual_axes:
         if mesh is None:
             raise ValueError("mesh required for pp/sp execution")
@@ -1826,7 +1943,7 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
                     f"ep axis ({mesh_ep})"
                 )
         x, aux = _apply_blocks_manual(
-            params["blocks"], x, cfg, mesh, frozenset(manual_axes)
+            params["blocks"], x, cfg, mesh, frozenset(manual_axes), keep
         )
     else:
         if mesh is not None and mesh.size > 1:
@@ -1838,16 +1955,17 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
             x = lax.with_sharding_constraint(
                 x, NamedSharding(mesh, P(("dp", "fsdp"), None, None))
             )
-        x, aux = _stage_forward(layer_stacks(params), x, cfg, mesh)
+        x, aux = _stage_forward(layer_stacks(params), x, cfg, mesh, keep=keep)
 
     logits = _head(params, x, cfg)
     return (logits, aux) if return_aux else logits
 
 
-def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
+def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes, keep: bool = False):
     """Run the block stack under shard_map, manual over {'pp','sp','ep'}
     (subset), GSPMD-auto over dp/fsdp/tp.  With 'ep' manual, the batch dim
-    shards over experts' owner devices (tokens all_to_all inside moe_ffn)."""
+    shards over experts' owner devices (tokens all_to_all inside moe_ffn).
+    keep: `_stage_forward`'s."""
     sp_manual = "sp" in manual_axes
     pp_manual = "pp" in manual_axes
     ep_manual = "ep" in manual_axes
@@ -1856,7 +1974,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
         if pp_manual:
             my_blocks = jax.tree_util.tree_map(lambda p: p[0], blocks_local)
             stage = lambda bp, a: _stage_forward(
-                {"attn": bp}, a, cfg=cfg, mesh=mesh, manual_axes=manual_axes
+                {"attn": bp}, a, cfg=cfg, mesh=mesh, manual_axes=manual_axes, keep=keep
             )
             if cfg.n_experts:
                 # MoE through the pipeline: each stage's MoE layers
@@ -1882,7 +2000,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes):
                 aux = jnp.zeros((), jnp.float32)
         else:
             x_out, aux = _stage_forward(
-                {"attn": blocks_local}, x_local, cfg, mesh, manual_axes
+                {"attn": blocks_local}, x_local, cfg, mesh, manual_axes, keep
             )
         # the P() out-spec claims aux is replicated across EVERY manual axis;
         # each shard computed it over its own tokens, so reduce over all
@@ -1932,17 +2050,21 @@ def cross_entropy_loss(logits, targets, mask=None):
     return jnp.mean(nll)
 
 
-def make_loss_fn(cfg: TransformerConfig, mesh=None):
-    def loss_fn(params, batch):
-        ids = batch["ids"]  # [B, T+1]
-        logits, aux = forward(params, ids[:, :-1], cfg, mesh, return_aux=True)
-        with jax.named_scope("loss"):
-            loss = cross_entropy_loss(logits, ids[:, 1:])
-            if cfg.n_experts:
-                loss = loss + cfg.moe_aux_weight * aux
-        return loss
+def _loss(params, batch, cfg: TransformerConfig, mesh, state: float = 1.0):
+    """The next-token loss of batch["ids"] [B, T+1]; state: `_forward`'s."""
+    ids = batch["ids"]
+    logits, aux = _forward(params, ids[:, :-1], cfg, mesh, True, state)
+    with jax.named_scope("loss"):
+        loss = cross_entropy_loss(logits, ids[:, 1:])
+        if cfg.n_experts:
+            loss = loss + cfg.moe_aux_weight * aux
+    return loss
 
-    return loss_fn
+
+def make_loss_fn(cfg: TransformerConfig, mesh=None):
+    """loss_fn(params, batch) -> the loss.  Its gradient under `cfg.remat`
+    keeps what fits beside the weights alone (`_remat_keeps`)."""
+    return functools.partial(_loss, cfg=cfg, mesh=mesh)
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer=None, learning_rate=3e-4):
@@ -1952,10 +2074,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None, learning_rate=
 
     if optimizer is None:
         optimizer = optax.adamw(learning_rate, weight_decay=0.01)
-    loss_fn = make_loss_fn(cfg, mesh)
 
     def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        # what `cfg.remat` keeps is decided beside everything the step holds: an optimizer's state is
+        # made of the parameters' shapes (`optimizer.init`) and inherits their shardings
+        state = 1.0 + _bytes_a_chip(opt_state, cfg, None) / _bytes_a_chip(params, cfg, None)
+        loss, grads = jax.value_and_grad(functools.partial(_loss, cfg=cfg, mesh=mesh, state=state))(params, batch)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)

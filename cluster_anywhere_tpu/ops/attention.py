@@ -46,6 +46,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -426,8 +427,15 @@ def _flash(q, k, v, pad, causal, scale, block_q, block_k, interpret):
     return out
 
 
+# The names under which `_flash`'s forward rule hands its two own residuals to a `jax.checkpoint`
+# policy by names (models/transformer.py KEPT_NAMES): kept, a checkpointed block's backward pass
+# reads them and runs no second `flash_fwd`.  The rule is traced only where a gradient is taken.
+FLASH_OUT, FLASH_LSE = "flash.out", "flash.lse"
+
+
 def _flash_fwd(q, k, v, pad, causal, scale, block_q, block_k, interpret):
     out, lse = _fwd_impl(q, k, v, pad, causal, scale, block_q, block_k, interpret)
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, pad, out, lse)
 
 

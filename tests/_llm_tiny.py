@@ -9,17 +9,18 @@ TRACE = {"tid": "feedfacefeedface", "sid": "0badf00d"}
 
 @pytest.fixture
 def llm_spans(monkeypatch):
-    """Reads the `llm.*` SPAN events out of tracing's event buffer.  Where
-    this process is a cluster driver, its housekeeping ships that buffer to
-    the head every second: it is held back while the test reads."""
+    """Reads the `llm.*` SPAN events (or those of another prefix) out of
+    tracing's event buffer.  Where this process is a cluster driver, its
+    housekeeping ships that buffer to the head every second: it is held back
+    while the test reads."""
     from cluster_anywhere_tpu.util import tracing
 
     drain = tracing.drain_events
     monkeypatch.setattr(tracing, "drain_events", lambda: [])
     drain()  # what earlier tests left
     assert not tracing.is_enabled()
-    return lambda: [
-        e for e in drain() if e["state"] == "SPAN" and e["name"].startswith("llm.")
+    return lambda prefix="llm.": [
+        e for e in drain() if e["state"] == "SPAN" and e["name"].startswith(prefix)
     ]
 
 

@@ -808,18 +808,11 @@ def test_block_masked_prefill_compiles(v5e, on_tpu, bucket):
     assert _has_kernel(compiled)
 
 
-@pytest.mark.parametrize(
-    "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2_tp2"]
-)
-def test_train_step_compiles_on_a_mesh(v5e, on_tpu, spec):
-    """GSPMD cannot partition a Mosaic kernel: the step compiles on a
-    multi-device mesh only with the kernel under shard_map."""
+def _compiled_train_step(cfg, mesh, batch, seq):
+    """(the train step of `make_train_step` over AdamW compiled for the mesh's described devices, its parameters'
+    shapes as sharded there): the weights and moments under `param_specs`, `batch` sequences of `seq` + 1 ids."""
     import optax
 
-    cfg = transformer.TransformerConfig(
-        vocab_size=32000, n_layers=2, max_seq_len=1024, unroll_layers=False, **FLAGSHIP
-    )
-    mesh = Mesh(np.asarray(v5e).reshape(spec.axis_sizes()), AXES)
     step, _ = transformer.make_train_step(cfg, mesh)
     sharded = lambda tree: jax.tree_util.tree_map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
@@ -834,12 +827,21 @@ def test_train_step_compiles_on_a_mesh(v5e, on_tpu, spec):
         s._replace(mu=sharded(s.mu), nu=sharded(s.nu), count=count) if hasattr(s, "mu") else s
         for s in adam
     )
-    batch = {
-        "ids": jax.ShapeDtypeStruct(
-            (8, 1025), jnp.int32, sharding=transformer.make_batch_sharding(cfg, mesh)
-        )
-    }
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, batch).compile()
+    ids = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32, sharding=transformer.make_batch_sharding(cfg, mesh))
+    return jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, {"ids": ids}).compile(), params
+
+
+@pytest.mark.parametrize(
+    "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2_tp2"]
+)
+def test_train_step_compiles_on_a_mesh(v5e, on_tpu, spec):
+    """GSPMD cannot partition a Mosaic kernel: the step compiles on a
+    multi-device mesh only with the kernel under shard_map."""
+    cfg = transformer.TransformerConfig(
+        vocab_size=32000, n_layers=2, max_seq_len=1024, unroll_layers=False, **FLAGSHIP
+    )
+    mesh = Mesh(np.asarray(v5e).reshape(spec.axis_sizes()), AXES)
+    compiled, params = _compiled_train_step(cfg, mesh, 8, 1024)
     assert _has_kernel(compiled)
     # forward, recomputation and backward turn q and k where they lie (`transformer._turn`): nothing under
     # `attn.rope` is as large as a device's q in float32 (the strided halves' copies, their gradient's pad)
@@ -851,3 +853,36 @@ def test_train_step_compiles_on_a_mesh(v5e, on_tpu, spec):
     if spec.fsdp * spec.tp == 4:
         whole = 3 * sum(x.size * 4 for x in jax.tree_util.tree_leaves(params))
         assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * whole
+
+
+V5E_BYTES_LIMIT = 16909336064  # `memory_stats()["bytes_limit"]` of a v5e chip (PERF.md section 6, PR 53)
+
+
+def test_the_train_cells_step_keeps_the_attention_half_and_fits(v5e, on_tpu, monkeypatch):
+    """`train-fsdp4`'s step as one of its four chips runs it: Mistral-7B's
+    widths 12 layers deep, fsdp=4, 8 x 4,096 tokens, `remat`.  Under a v5e's
+    limit the checkpointed blocks keep their attention halves
+    (`transformer._remat_keeps`; a described device reports no limit, so the
+    test states it): the compiled step calls `flash_fwd` once a layer and each
+    backward kernel once; of an `attn.*` scope the recomputation holds only
+    `_gqa_repeat`'s k and v and the rotary tables, nothing under `attn.qkv` or
+    `attn.out` and nothing of q's size [2, 4096, 32, 128]; and the state and the
+    temporaries together stay under the limit."""
+    monkeypatch.setattr(transformer, "_memory_limit", lambda mesh: V5E_BYTES_LIMIT)
+    cfg = transformer.TransformerConfig(**dict(MISTRAL4, vocab_size=32768, n_layers=12, max_seq_len=4096, rope_theta=1e6,
+                                               param_dtype=jnp.float32, remat=True))
+    mesh = Mesh(np.asarray(v5e).reshape(MeshSpec(fsdp=4).axis_sizes()), AXES)
+    compiled, _ = _compiled_train_step(cfg, mesh, 8, 4096)
+    text = compiled.as_text()
+    calls = {name: len(re.findall(rf"custom-call.*{name}", text)) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert calls == dict.fromkeys(calls, cfg.n_layers)
+    again = re.findall(r"= (\w+)\[([\d,]*)\][^\n]*rematted_computation/(attn\.[\w.]+)/", text)
+    assert {scope for _, _, scope in again} == {"attn.core", "attn.rope"}
+    q = 2 * 4096 * 32 * 128
+    sizes = lambda scope: {(dtype, shape) for dtype, shape, s in again if s == scope and math.prod(map(int, shape.split(","))) * 4 >= q}
+    assert sizes("attn.rope") == set() and sizes("attn.core") == {("bf16", "2,4096,8,4,128")}
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.output_size_in_bytes - memory.alias_size_in_bytes + memory.temp_size_in_bytes
+    assert 3 * 2.88e9 < memory.argument_size_in_bytes and held < V5E_BYTES_LIMIT
+    # the names' 2.83 GB are in the temporaries: the bare checkpoint's are 4.46 GB (transformer.REMAT_TEMP_BYTES)
+    assert 4.46e9 + 0.9 * 12 * transformer._kept_bytes(cfg, 2 * 4096) < memory.temp_size_in_bytes

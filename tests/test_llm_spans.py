@@ -151,6 +151,35 @@ def test_jax_hook_counts_backend_compilations(monkeypatch):
     assert len(counted) == 1 and len(tracing._events) == before
 
 
+def test_a_traced_train_step_says_once_what_remat_keeps(llm_spans):
+    """`train.remat` (models/transformer.py `_remat_keeps`): one span as the
+    step is traced, with the decision and what it was made from; a step that
+    runs the traced program again says nothing more."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from cluster_anywhere_tpu.models import transformer
+    from cluster_anywhere_tpu.util import tracing
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_head=16, d_ff=128, max_seq_len=64, remat=True)
+    step, init_state = transformer.make_train_step(cfg, None, optimizer=optax.sgd(1e-2))
+    params, opt_state = init_state(jax.random.key(0))
+    batch = {"ids": jnp.zeros((2, 33), jnp.int32)}
+    jstep = jax.jit(step)
+    token = tracing.push_execution(TRACE)
+    try:
+        params, opt_state, _ = jstep(params, opt_state, batch)
+        jstep(params, opt_state, batch)
+    finally:
+        tracing.pop_execution(token)
+    (event,) = llm_spans("train.remat")
+    rows = 2 * 32
+    want = {"kept": True, "kept_layers": 2, "kept_bytes": 2 * rows * (2 * (64 + 2 * 32 + 64 + 64) + 4 * 4), "budget_bytes": -1}
+    assert {k: event[k] for k in want} == want and event["trace"]["tid"] == TRACE["tid"]
+
+
 def _instruction_count(compiled) -> int:
     import re
 
