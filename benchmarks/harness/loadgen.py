@@ -9,7 +9,11 @@ distributions, not a new draw a run.  `--seed` decides the order the sizes come
 in and the token ids themselves (and, in the driver, the weights).  So the
 spread between runs is the spread of order, not of the draw; PERF.md gives
 both.  (A closed loop's callers each hold more sizes than a window uses, so
-there the order also decides which of them the window reaches.)
+there the order also decides which of them the window reaches; a mix whose
+requests are few and long, so that this draw shows in its rate, says
+`"caller_sizes": "quantiles"` and `"caller_rounds"`: a caller then goes round
+one short cycle of the distributions' own quantiles, and any window holds
+whole cycles and a remainder of less than one.)
 
 Kinds:
   open_poisson     independent users: requests are due on a schedule whether
@@ -27,12 +31,26 @@ from __future__ import annotations
 
 import asyncio
 import json
+import statistics
 import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 CONNECT_TIMEOUT_S = 30.0
+
+
+def _quantile_lengths(n: int, spec: Dict[str, Any]) -> np.ndarray:
+    """The n lengths that cut a mix's {"dist", "min", "max", ...} into n parts of
+    equal weight (its quantiles at (i + 1/2) / n), ascending: no draw."""
+    q = (np.arange(n) + 0.5) / n
+    if spec["dist"] == "lognormal":
+        z = np.array([statistics.NormalDist().inv_cdf(float(v)) for v in q])
+        x = spec["median"] * np.exp(spec["sigma"] * z)
+        return np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64)
+    if spec["dist"] == "uniform":
+        return np.floor(spec["min"] + q * (spec["max"] + 1 - spec["min"])).astype(np.int64)
+    raise ValueError(f"unknown length distribution {spec['dist']!r}")
 
 
 def _lengths(rng, n: int, spec: Dict[str, Any]) -> np.ndarray:
@@ -181,19 +199,34 @@ def closed_loop_plan(cell: Dict[str, Any], seconds: float, seed: int,
     `caller_requests` sizes are one frozen draw from `shape_seed`, more than a
     window can use (a caller that does run out stops).  `--seed` permutes each
     caller's sizes and makes the token ids, as in the open mix; which of its
-    sizes a caller reaches before the window closes is then the seed's."""
+    sizes a caller reaches before the window closes is then the seed's.
+
+    Where a window reaches few requests a caller, that choice is most of the
+    spread between seeds.  Such a mix says `"caller_sizes": "quantiles"`: a
+    caller's `caller_requests` prompt lengths are the distribution's own
+    quantiles and so are its output lengths, paired once from `shape_seed`;
+    and `"caller_rounds": r`: a caller goes r times round its sizes in the one
+    order the seed gave them, the token ids new each time.  Any
+    `caller_requests` requests in a row are then the whole set, whatever the
+    seed, and a window holds whole sets and less than one more."""
     traffic, callers = cell["traffic_file"], cell["callers"]
     shape = np.random.default_rng(traffic["shape_seed"])
     rng = np.random.default_rng(seed)
     n, ramp = traffic["caller_requests"], traffic["ramp_s"]
-    sizes = [
-        np.stack([_lengths(shape, n, traffic["prompt_len"]), _lengths(shape, n, traffic["output_len"])], 1)
-        for _ in range(callers)
-    ]
+    how, rounds = traffic.get("caller_sizes", "draw"), traffic.get("caller_rounds", 1)
+    if how == "draw":
+        def lengths(key):
+            return _lengths(shape, n, traffic[key])
+    elif how == "quantiles":
+        def lengths(key):
+            return shape.permutation(_quantile_lengths(n, traffic[key]))
+    else:
+        raise ValueError(f"unknown caller_sizes {how!r}")
+    sizes = [np.stack([lengths("prompt_len"), lengths("output_len")], 1) for _ in range(callers)]
     return [
         {"caller": c, "start": -ramp + ramp * c / callers,
          "requests": [{"prompt_ids": rng.integers(0, vocab, int(p)), "max_new_tokens": int(o)}
-                      for p, o in sizes[c][rng.permutation(n)]]}
+                      for p, o in np.tile(sizes[c][rng.permutation(n)], (rounds, 1))]}
         for c in range(callers)
     ]
 

@@ -341,7 +341,7 @@ def time_by_scope(events) -> Optional[Dict[str, float]]:
     an operation a scope only where its op_name holds a known one."""
     total: Dict[str, float] = {}
     for t, name, scope in self_times(_first_device(events)):
-        key = "collective" if trace_reduce.COLLECTIVE.search(name) else scope
+        key = "collective" if trace_reduce.is_collective(name) else scope
         total[key] = total.get(key, 0.0) + t
     return total if any(k not in ("", "collective") for k in total) else None
 

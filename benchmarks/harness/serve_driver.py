@@ -18,6 +18,16 @@ HOST, ROUTE = "127.0.0.1", "/llm"
 TRACE_SLICE_S = 4.0  # a traced run profiles this much of the window's end
 
 
+def weights_seed(config_file: Dict[str, Any], seed: int) -> int:
+    """The seed the replica makes its random weights from: the run's own, unless
+    the configuration names one draw for every run (`"weights": {"seed": n}`).
+    One does where the work depends on the draw: of a held share of the
+    experts, how many of a token's lie on this chip is the router's draw, and
+    a step's time with it.  --seed then makes the traffic and the check's
+    prompts, and every seed's run serves the same model."""
+    return int(config_file.get("weights", {}).get("seed", seed)) % (2 ** 31)
+
+
 def _deploy(cell: Dict[str, Any], seed: int, port: int):
     import jax.numpy as jnp
 
@@ -30,7 +40,7 @@ def _deploy(cell: Dict[str, Any], seed: int, port: int):
     serve.start(host=HOST, port=port)
     pcfg = ProcessorConfig(
         model=ModelSpec(
-            preset="custom", seed=seed % (2 ** 31),
+            preset="custom", seed=weights_seed(cfg, seed),
             config_overrides=manifest.reference_of(cell).program_config(cfg, param_dtype=jnp.bfloat16),
         ),
         tokenizer=IdTokenizer(cfg["config"]["vocab_size"]),

@@ -21,6 +21,25 @@ ANNOTATIONS = ("admit", "decode_step", "train_step", "input")
 COLLECTIVE = re.compile(
     r"all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute", re.I
 )
+# the opcode of an HLO line, "%name = <result's type> opcode(operands), ...":
+# the first word before a "(" that a space precedes (a type's own brackets,
+# "f32[8]{0:T(8,128)S(1)}", follow a colon, a bracket or a digit); and the
+# computation a fusion runs, "..., kind=kCustom, calls=%all-reduce-scatter.85"
+_OPCODE = re.compile(r" ([a-z][\w-]*)\(")
+_CALLS = re.compile(r"\bcalls=%?([\w.-]+)")
+
+
+def is_collective(name: str) -> bool:
+    """Whether the operation a trace names (by its whole HLO line on this
+    runtime, or a name alone) IS a collective: its own name, before " = ", its
+    opcode, or the computation it calls says so (the TPU compiler runs a
+    reduce-scatter as a custom fusion that calls `%all-reduce-scatter.N`), an
+    asynchronous pair's `-start` and `-done` included.  Its operands do not: a
+    fusion that multiplies what `%all-gather.3` brought is compute."""
+    own, _, rest = name.partition(" = ")
+    opcode, called = _OPCODE.search(" " + rest), _CALLS.search(rest)
+    parts = (own, opcode.group(1) if opcode else "", called.group(1) if called else "")
+    return any(COLLECTIVE.search(part) for part in parts)
 
 
 def extract(xplane_path: str) -> Dict[str, Any]:
@@ -97,7 +116,7 @@ def collective_percent(events) -> Optional[float]:
     w, evs = span(events), _first_device(events)
     if w is None:
         return None
-    t = sum(b - a for a, b in _union((e[0], e[0] + e[1]) for e in evs if COLLECTIVE.search(e[2])))
+    t = sum(b - a for a, b in _union((e[0], e[0] + e[1]) for e in evs if is_collective(e[2])))
     return 100.0 * t / (w[1] - w[0])
 
 

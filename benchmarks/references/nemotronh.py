@@ -518,7 +518,7 @@ def decode_step_bytes(c: Dict[str, Any], slots: int, t_max: int, bytes_per: int 
 # harness/reference.py says which program each of the three serving tolerances holds.
 # The readings are the chip's at the published widths, all 52 layers, 16 of 128 experts and
 # 16,384 vocabulary rows held, taken as the cell's check takes them (the four check streams of
-# traffic/reason-closed-warm64.json served together, prompts 100, 200, 480, 1000 and 64 tokens
+# traffic/reason-closed.json served together, prompts 100, 200, 480, 1000 and 64 tokens
 # each, teacher-forced through this reference in float32).  PERF.md section 6 (PR 50) has
 # every number and its seeds; the controls are `scripts/nemotronh_controls.py`'s.
 #
@@ -624,7 +624,15 @@ LOSS_TOL = 0.01
 #   first is held by the next number alone (25 x under), the second by `ssm_prefill_state` (4.7 x) and the next.
 #   ssm_state_step: the program 0.00003-0.00009 (the two paths' bf16 projections round a few outputs apart; float32
 #   alone reads 1e-7 on the CPU); state-bf16 0.0139, recurrence-bf16 0.0247.  6 x over, 25 x under.
-#   ssm_out: the program 0.0042-0.0077; the norm without its gate ("no-gate") 1.0; recurrence-bf16 0.0132.
+#   ssm_out: the program 0.0042-0.0077 in those 26 runs and in six of PR 54's seven seeds (0.0044-0.0062), and
+#   **0.01482 on seed 2540000104, twice** (my chip runs, PR 54: a traced and an untraced run of the cell, the same
+#   to the last digit, every other number of the check inside its range): the number is the largest over 256 rows of
+#   a row's own relative error, a widest gap, and one row of a seed's draw whose result is small reads twice the rest.
+#   Lower reading 0.01482 (33 runs); the norm without its gate ("no-gate") 1.0, the upper one.  The bound stood at
+#   0.012 until PR 54, 1.56 x over 26 runs' largest, and read a sound run as not correct; it is 0.05, 3.4 x over the
+#   lower reading and 20 x under the upper.  The recurrence in bf16 reads 0.0132 here, under the lower reading, so
+#   it is not this number's to catch: `ssm_prefill_state` holds it (0.056, 4.7 x its bound) and `ssm_state_step`
+#   (0.0247, 45 x); a state handed on in bf16 reads inside it too and is `ssm_state_step`'s (0.0139, 25 x).
 #   attn_decode: the program 0.00166-0.00178 in all 26, a maximum with no tail: it is the bf16 rounding of the
 #   core's own result, which cannot pass 2^-9 = 0.00195 of a row's norm.  The scores, softmax and weighted sum in
 #   bf16 ("bf16-softmax", at this cell's 2 flat heads) read 0.00216: the same fault reads 0.0058 and more in
@@ -636,7 +644,7 @@ LOSS_TOL = 0.01
 PREFILL_ROWS_ERR_TOL = 0.7
 SSM_PREFILL_STATE_ERR_TOL = 0.012
 SSM_STATE_ERR_TOL = 0.02
-SSM_OUT_ERR_TOL = 0.012
+SSM_OUT_ERR_TOL = 0.05
 SSM_STATE_STEP_ERR_TOL = 5.5e-4
 ATTN_DECODE_ERR_TOL = 0.00205
 MOE_ROUTER_SET_TOL = _mla.MOE_ROUTER_SET_TOL

@@ -454,7 +454,8 @@ LOSS_TOL = 0.01
 # says what the two numbers are and how the probe experts read the program's
 # choice), in this program's shapes: each prompt's whole blocks alone
 # [1, bucket, E], left-padded, the pads not live, and the answer's blocks as
-# steps of [slots, 4, E], a stream a slot, the other slots not live
+# passes of [slots, 8, E], a stream a slot, the block a pass stores before the
+# block it works on, the other slots and a first pass's first half not live
 # (`program_shapes`; tests/benchmark/test_benchmark_sdar.py holds that a batcher
 # serving the streams traces `transformer._moe` with exactly these shapes).  Rows:
 # what every layer's experts are given at every position of the streams' final
@@ -506,8 +507,14 @@ def program_shapes(cb, streams):
     stream through the end of its answer's last block) lie in the calls of
     `_moe` that serving the streams together makes.  Returns (prefills: a
     (first row, rows, pads on the left) a stream whose prompt has a whole block;
-    passes: [steps, slots, B], the row a slot's position holds at a step, or
-    the number of rows where the slot is not live; that number)."""
+    passes: [steps, slots, 2B], the row a slot's position holds at a step, or
+    the number of rows where the position is not live: a pass runs the block
+    before in its first half, which it stores, and the block itself in its
+    second (`llm/continuous.py _pass_step_rowpos`), so a slot's first pass and a
+    slot that is not live have the first half dead; read: [steps, slots, 2B],
+    the one place of the two a row lies in whose result is read, where the pass
+    stores it, and a stream's last block, which no pass stores, in its own; that
+    number)."""
     assert len(streams) <= cb.slots, "the check streams are served together, a slot each"
     b = cb.cfg.block_length
     kept = [_kept[s["request_id"]] for s in streams]
@@ -516,13 +523,16 @@ def program_shapes(cb, streams):
     prefills = [(int(off), k["whole"], cb.block_plan(len(s["prompt_ids"]), len(s["served"]))[2])
                 for off, k, s in zip(first, kept, streams) if k["whole"]]
     steps = [(n - k["whole"]) // b for n, k in zip(lengths, kept)]
-    passes = np.full((max(steps), cb.slots, b), first[-1], np.int32)
+    passes = np.full((max(steps), cb.slots, 2 * b), first[-1], np.int32)
+    read = np.zeros(passes.shape, bool)
     for slot, (off, k, t) in enumerate(zip(first, kept, steps)):
-        passes[:t, slot] = (off + k["whole"] + np.arange(t * b)).reshape(t, b)
-    return prefills, passes, int(first[-1])
+        blocks = (off + k["whole"] + np.arange(t * b)).reshape(t, b)
+        passes[:t, slot, b:], passes[1:t, slot, :b] = blocks, blocks[:-1]
+        read[1:t, slot, :b], read[t - 1, slot, b:] = True, True
+    return prefills, passes, read, int(first[-1])
 
 
-def _program(cfg, prefills, passes, n: int):
+def _program(cfg, prefills, passes, read, n: int):
     """The compiled program of one layer's calls of `_moe`, laid out by
     `program_shapes`: (given: [stream] of [T, E] float32, every layer's routers,
     every layer's experts, the layer's index) -> (the n rows as a block hands
@@ -531,7 +541,7 @@ def _program(cfg, prefills, passes, n: int):
     references/olmoe.py says how the probe experts tell)."""
     from cluster_anywhere_tpu.models.transformer import _moe
 
-    held = np.nonzero(passes.reshape(-1) < n)[0]  # the (step, slot, position) triples that hold a row
+    held = np.nonzero(read.reshape(-1))[0]  # the (step, slot, position) triples a row's result is read at
     at = passes.reshape(-1)[held]
 
     @jax.jit
@@ -549,7 +559,7 @@ def _program(cfg, prefills, passes, n: int):
             out, marks = both(jnp.pad(rows[off:off + t], ((pad, 0), (0, 0)))[None],
                               jnp.asarray(np.arange(pad + t) >= pad)[None])
             got, chosen = got.at[off:off + t].set(out[0, pad:]), chosen.at[off:off + t].set(marks[0, pad:])
-        steps = jnp.pad(rows, ((0, 1), (0, 0)))[passes]  # [steps, slots, B, E]
+        steps = jnp.pad(rows, ((0, 1), (0, 0)))[passes]  # [steps, slots, 2B, E]
         out, marks = lax.map(lambda step: both(*step), (steps, jnp.asarray(passes < n)))
         got = got.at[at].set(out.reshape(-1, out.shape[-1])[held])
         return rows, got, chosen.at[at].set(marks.reshape(-1, cfg.n_experts)[held])
@@ -594,9 +604,9 @@ def mechanism_checks(cb, streams):
     for s in streams:
         if s["request_id"] not in _kept:
             _replay(cb, s)
-    prefills, passes, n = program_shapes(cb, streams)
+    prefills, passes, read, n = program_shapes(cb, streams)
     kept = [_kept.pop(s["request_id"]) for s in streams]
-    program = _program(cfg, prefills, passes, n)
+    program = _program(cfg, prefills, passes, read, n)
     experts = {name: params["blocks"][name] for name in EXPERT_MATRICES if name in params["blocks"]}
     numbers = []
     for layer in range(cfg.n_layers):

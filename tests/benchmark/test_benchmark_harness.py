@@ -165,6 +165,72 @@ def test_closed_plan_is_the_seeds_and_only_the_seeds():
         loadgen.make_plan({"traffic_file": dict(CLOSED, kind="bursty"), "callers": 3}, 1.0, 1, 100)
 
 
+def _sizes(entry):
+    return [(len(r["prompt_ids"]), r["max_new_tokens"]) for r in entry["requests"]]
+
+
+@pytest.mark.parametrize("dist", ["uniform", "lognormal"])
+def test_a_closed_mix_of_quantiles_goes_round_one_set_whatever_the_seed(dist):
+    spec = {"uniform": ({"dist": "uniform", "min": 3, "max": 10}, {"dist": "uniform", "min": 2, "max": 9}),
+            "lognormal": ({"dist": "lognormal", "median": 40, "sigma": 0.4, "min": 10, "max": 80},
+                          {"dist": "lognormal", "median": 9, "sigma": 0.6, "min": 2, "max": 24})}[dist]
+    over = dict(caller_requests=8, caller_sizes="quantiles", caller_rounds=3, prompt_len=spec[0], output_len=spec[1])
+    a, b, c = (_closed_plan(s, **over) for s in (3_000_000_019, 3_000_000_019, 5))
+    assert [_sizes(e) for e in a] == [_sizes(e) for e in b]
+    assert all(len(e["requests"]) == 24 for e in a)
+    for x, y in zip(a, c):
+        # any 8 requests in a row are the caller's whole set, the same for every seed, in the seed's order
+        whole = sorted(_sizes(x)[:8])
+        assert all(sorted(_sizes(e)[k:k + 8]) == whole for e in (x, y) for k in range(17))
+        assert _sizes(x)[:8] != _sizes(y)[:8] and _sizes(x)[:8] == _sizes(x)[8:16] == _sizes(x)[16:]
+        # the lengths are the distribution's own quantiles: no draw, so every caller has the same
+        # prompt lengths and the same output lengths, paired its own way
+        assert sorted(p for p, _ in whole) == loadgen._quantile_lengths(8, spec[0]).tolist()
+        assert sorted(o for _, o in whole) == loadgen._quantile_lengths(8, spec[1]).tolist()
+        # a round's token ids are its own
+        assert x["requests"][0]["prompt_ids"].tolist() != x["requests"][8]["prompt_ids"].tolist()
+    assert sorted(_sizes(a[0])[:8]) != sorted(_sizes(a[1])[:8])  # the pairing is the caller's
+    with pytest.raises(ValueError, match="unknown caller_sizes"):
+        _closed_plan(1, caller_sizes="sorted")
+
+
+def test_quantile_lengths_by_hand():
+    assert loadgen._quantile_lengths(4, {"dist": "uniform", "min": 1, "max": 8}).tolist() == [2, 4, 6, 8]
+    q = loadgen._quantile_lengths(5, {"dist": "lognormal", "median": 100, "sigma": 0.5, "min": 60, "max": 1000})
+    z = [statistics.NormalDist().inv_cdf(p) for p in (0.1, 0.3, 0.5, 0.7, 0.9)]
+    assert q.tolist() == [60] + [round(100 * np.exp(0.5 * v)) for v in z[1:]] and q[2] == 100
+    with pytest.raises(ValueError, match="unknown length distribution"):
+        loadgen._quantile_lengths(3, {"dist": "zipf"})
+
+
+def test_the_long_documents_mix_gives_every_seed_the_same_work():
+    """`longrag-closed`: a window reaches about 23 requests a caller, so a caller goes round
+    24 quantiles; 24 in a row hold the same prompt and output tokens for every seed."""
+    cell = manifest.load_cell("kexaone-longrag-closed6")
+    mix = cell["traffic_file"]
+    assert (mix["caller_sizes"], mix["caller_requests"], mix["caller_rounds"]) == ("quantiles", 24, 8)
+    a, b = (loadgen.make_plan(cell, 51.0, s, 19200) for s in (2_540_000_901, 7))
+    assert len(a) == cell["callers"] == 6 and all(len(e["requests"]) == 192 for e in a)
+    work = lambda e, k: (sum(p for p, _ in _sizes(e)[k:k + 24]), sum(o for _, o in _sizes(e)[k:k + 24]))
+    assert len({work(e, k) for e in a + b for k in (0, 5, 29, 168)}) == 1
+    p, o = zip(*_sizes(a[0])[:24])
+    assert (min(p), max(p), min(o), max(o)) == (1814, 8192, 28, 256)
+    assert sum(n > 4096 for n in p) == 12 and sum(n <= 2048 for n in p) == 1  # the buckets' shares
+    # the mixes that a window goes deep into keep their draw
+    for other in ("chat-closed", "rag-closed", "reason-closed", "chat-closed-blocks"):
+        assert "caller_sizes" not in json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", other + ".json")))
+
+
+def test_a_configuration_may_name_one_draw_of_its_weights():
+    assert serve_driver.weights_seed({}, 3_000_000_019) == 3_000_000_019 % 2 ** 31
+    assert serve_driver.weights_seed({"weights": {"seed": 12}}, 3_000_000_019) == 12
+    held = manifest.load_cell("kexaone-longrag-closed6")["config_file"]
+    assert {serve_driver.weights_seed(held, s) for s in (1, 2_900_000_801)} == {2_540_000_222 % 2 ** 31}
+    for name in sorted(os.listdir(os.path.join(manifest.BENCH_DIR, "configs"))):
+        doc = json.load(open(os.path.join(manifest.BENCH_DIR, "configs", name)))
+        assert ("weights" in doc) == (name == "k-exaone-236b-a23b-ep16-serve1.json")
+
+
 def test_closed_loop_keeps_each_caller_to_one_request_due_at_its_last_token():
     plan = _closed_plan(11)
     with StubServer(service_s=0.05) as stub:
@@ -302,6 +368,61 @@ def test_trace_reduce_by_hand():
     assert dict(map(tuple, trace_reduce.idle_gaps(events))) == {
         "train_step": pytest.approx(150e-9), "input": pytest.approx(500e-9)}
     assert trace_reduce.busy({"devices": {}, "host": []}) is None
+
+
+LAYOUT = "{1,0:T(8,128)(2,1)S(1)}"
+HLO_LINES = [  # (an operation as this runtime's trace names it: its whole HLO line; whether it IS a collective)
+    (f"%fusion.102 = bf16[4096,32768]{LAYOUT} fusion(f32[2,4096]{LAYOUT} %get-tuple-element.7, "
+     f"bf16[4096,14336]{LAYOUT} %all-gather.3), kind=kOutput, calls=%fused_computation.102", False),
+    (f"%all-gather.3 = bf16[4096,14336]{LAYOUT} all-gather(bf16[1024,14336]{LAYOUT} %param.4), "
+     "channel_id=7, replica_groups=[1,4]<=[4], dimensions={0}", True),
+    (f"%all-reduce-start.1 = f32[4096]{{0:T(1024)}} all-reduce-start(f32[4096]{{0:T(1024)}} %fusion.9), "
+     "channel_id=2, to_apply=%add", True),
+    (f"%all-reduce-done.1 = f32[4096]{{0:T(1024)}} all-reduce-done(f32[4096]{{0:T(1024)}} %all-reduce-start.1)", True),
+    (f"%reduce-scatter.12 = f32[1024,14336]{LAYOUT} reduce-scatter(f32[4096,14336]{LAYOUT} %fusion.77), "
+     "dimensions={0}, to_apply=%add", True),
+    (f"%all-to-all.2 = (bf16[8,64]{LAYOUT}, bf16[8,64]{LAYOUT}) all-to-all(bf16[8,64]{LAYOUT} %a, bf16[8,64]{LAYOUT} %b)", True),
+    (f"%collective-permute.5 = bf16[2,4096]{LAYOUT} collective-permute(bf16[2,4096]{LAYOUT} %fusion.1), "
+     "source_target_pairs={{0,1},{1,2}}", True),
+    # a result that is a tuple, and a loop whose operand is a tuple that holds a collective's result
+    (f"%multiply_add_fusion.2 = (f32[12,14336,1024]{LAYOUT}, f32[12,14336,1024]{LAYOUT}) fusion("
+     f"f32[12,14336,1024]{LAYOUT} %reduce-scatter.12, f32[] %c), kind=kLoop", False),
+    (f"%while.4 = (s32[], bf16[8,128]{LAYOUT}) while((s32[], bf16[8,128]{LAYOUT}) %tuple.all-gather.1), "
+     "condition=%cond, body=%body", False),
+    # the TPU compiler's reduce-scatter: a custom fusion that calls the collective (my chip run, PR 54)
+    (f"%fusion.89 = bf16[1024,32768]{LAYOUT} fusion(bf16[4096,32768]{LAYOUT} %fusion.102), kind=kCustom, "
+     "calls=%all-reduce-scatter.85", True),
+    # a collective that another pass renamed is known by its opcode, one cut short by its name
+    (f"%ag.7 = bf16[4096,14336]{LAYOUT} all-gather(bf16[1024,14336]{LAYOUT} %param.4), dimensions={{0}}", True),
+    ("%all-gather-start.3 = (bf16[1024,14336]{1,0:T(8,128)(2,1)}, bf16[4096,143", True),
+    ("%fusion.2993 = (bf16[4096]{0:T(1024)(128)(2,1)}, bf16[2,4096]{1,0:T(2,128)(2,1)S(1)}, bf16[2,409", False),
+    # a name alone, as the recorded traces and the tests by hand give it
+    ("all-gather-done.2", True), ("all-reduce.7", True), ("fusion.1", False),
+    # what reads an asynchronous collective's result is compute as well
+    ("%fusion.9 = f32[4096]{0:T(1024)} fusion(f32[4096]{0:T(1024)} %all-reduce-done.1), kind=kLoop, calls=%fused_computation.9", False),
+]
+
+
+@pytest.mark.parametrize("line, collective", HLO_LINES, ids=[ln.split(" = ")[0][:24] + f"-{i}" for i, (ln, _) in enumerate(HLO_LINES)])
+def test_a_collective_is_known_by_what_the_operation_is_not_by_its_operands(line, collective):
+    """PR 53's traced pair read `collective_exposed.train` 5.7 -> 18.3 on a change
+    that added no collective: the recomputed FFN products took what an
+    all-gather had brought as an operand, and the pattern was searched in the
+    whole line.  The name before " = ", the opcode and, for a fusion, the
+    computation it calls decide (PR 54)."""
+    assert trace_reduce.is_collective(line) is collective
+    assert bool(trace_reduce.COLLECTIVE.search(line)) or not collective  # the old search saw every one, and more
+
+
+def test_the_collectives_share_and_the_scopes_go_through_the_one_matcher():
+    product, gather = HLO_LINES[0][0], HLO_LINES[1][0]
+    ops = [[0, 100, gather, "ffn"], [100, 300, product, "ffn"], [400, 100, "%fusion.5 = f32[8]{0} fusion()", "attn.core"]]
+    events = {"devices": {"/device:TPU:0": [e[:3] for e in ops]}, "host": []}
+    assert trace_reduce.collective_percent(events) == pytest.approx(100 * 100 / 500)
+    by_scope = program_trace.time_by_scope({"spans": [], "ops": {"/device:TPU:0": ops}})
+    assert by_scope == {"collective": 100.0, "ffn": 300.0, "attn.core": 100.0}
+    # the parent's search gave the product to the collectives: 80% exposed, `ffn` nothing
+    assert sum(e[1] for e in ops if trace_reduce.COLLECTIVE.search(e[2])) == 400
 
 
 # -- the reduction from records to metrics -----------------------------------
@@ -521,41 +642,112 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_only(tmp_path):
 
 # -- families: one entry a metric, a cell joins from its own file (PR 38) -------
 
-with open(os.path.join(DATA, "per_layer_renames_pr38.json")) as _f:
-    RENAMES = json.load(_f)
+def _renames(pr):
+    with open(os.path.join(DATA, f"per_layer_renames_pr{pr}.json")) as f:
+        return json.load(f)
+
+
+# PR 38 retired the copies a closed cell; PR 54 those a configuration (a `.swa`,
+# `.sambay`, `.nemotronh` of what another cell's file read already)
+RENAMES = {38: _renames(38), 54: _renames(54)}
 SAME_KEYS = ("reader", "args", "unit", "layer", "moves", "source")
-# the committed files' metrics by cell and name, read once for the 130 cases below
+# the committed files' metrics by cell and name, read once for the cases below
 METRICS_OF = {cell: {m["name"]: m for m in manifest.layer_metrics_for(cell)} for cell in CELLS}
 
 
-@pytest.mark.parametrize("row", RENAMES, ids=[r["name"] for r in RENAMES])
+@pytest.mark.parametrize("row", RENAMES[38] + RENAMES[54],
+                         ids=[r["name"] for r in RENAMES[38]] + [f"{r['name']}@{r['cell']}" for r in RENAMES[54]])
 def test_a_retired_name_is_read_under_its_new_one(row):
-    """PR 38 retired 58 per-cell copies.  In the cell each was read in, the name
-    the table gives now reads the same quantity: the same reader, arguments,
-    unit, layer, moved metric and source that the retired file had."""
-    assert set(row) <= {"name", "cell", "now", *SAME_KEYS}
+    """PR 38 retired 58 per-cell copies and PR 54 24 per-configuration ones.  In
+    the cell each was read in, the name the table gives now reads the same
+    quantity: the same reader, arguments, unit, layer, moved metric and source
+    that the retired file had.  Two of PR 54's kept files took other arguments
+    to serve every cell (`args_now`; the test after the next holds that they
+    read the same values)."""
+    assert set(row) <= {"name", "cell", "now", "args_now", *SAME_KEYS}
     by_name = METRICS_OF[row["cell"]]
     assert row["name"] not in by_name and row["name"] not in {m["name"] for m in DOC["per_layer"]}
+    assert not os.path.exists(os.path.join(manifest.BENCH_DIR, "layer_metrics", row["name"] + ".json"))
     now = by_name[row["now"]]
-    assert {k: now.get(k) for k in SAME_KEYS} == {k: row.get(k) for k in SAME_KEYS}
+    was = dict(row, args=row["args_now"]) if "args_now" in row else row
+    assert {k: now.get(k) for k in SAME_KEYS} == {k: was.get(k) for k in SAME_KEYS}
     # no second file of the cell reads the same thing
     assert [m["name"] for m in by_name.values()
-            if (m["reader"], m.get("args")) == (row["reader"], row.get("args"))] == [row["now"]]
+            if (m["reader"], m.get("args")) == (now["reader"], now.get("args"))] == [row["now"]]
 
 
-def test_the_rename_table_is_whole():
-    assert len(RENAMES) == 58 == len({r["name"] for r in RENAMES})
-    assert {r["cell"] for r in RENAMES} == {"olmoe-closed6", "jamba-closed6", "sdar-closed6"}
-    assert len({r["now"] for r in RENAMES}) == 15 + 1 + 2 + 5
-    # a metric is one entry and one file (74 of each when the copies went), and the list has room
+def test_the_rename_tables_are_whole():
+    assert len(RENAMES[38]) == 58 == len({r["name"] for r in RENAMES[38]})
+    assert {r["cell"] for r in RENAMES[38]} == {"olmoe-closed6", "jamba-closed6", "sdar-closed6"}
+    assert len({r["now"] for r in RENAMES[38]}) == 15 + 1 + 2 + 5
+    # PR 54: 24 names, each read in one cell, to the 16 that stay; a kept name is one of its copies'
+    assert len(RENAMES[54]) == 24 == len({r["name"] for r in RENAMES[54]}) and len({r["now"] for r in RENAMES[54]}) == 16
+    assert {r["cell"] for r in RENAMES[54]} == {
+        "axk1-rag-closed6", "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8"}
+    assert {r["now"] for r in RENAMES[54] if "args_now" in r} == {"ssm_scan_share.ssm", "held_assignments_share.mla"}
+    assert not {r["name"] for r in RENAMES[54]} & {r["now"] for r in RENAMES[38] + RENAMES[54]}
+    # a metric is one entry and one file (74 of each when PR 38's copies went, 127 before PR 54's, 103 after),
+    # and the list has room for a configuration's own
     files = [f for f in os.listdir(os.path.join(manifest.BENCH_DIR, "layer_metrics")) if f.endswith(".json")]
-    assert 74 <= len(DOC["per_layer"]) == len(files) <= 128
+    assert 74 <= len(DOC["per_layer"]) == len(files) <= 105
+
+
+def _hand_ctx(cell):
+    """A traced slice by hand in the cell's own context: operations under every
+    scope of every served state-space kind (a cell's trace holds only the scopes
+    its reference names: program_trace.known_names), and steps that count a held
+    share's assignments."""
+    c = manifest.load_cell(cell)
+    known = program_trace.known_names({"cell": c})[0]
+    scopes = ["ssm.in", "ssm.conv", "ssm.scan/ssm.scan.chunk", "ssm.scan/ssm.scan.carry", "ssm.scan/ssm.norm", "ssm.state",
+              "ssm.out", "ffn", "attn.core"]
+    ops = [[100.0 * i, 10.0 * (i + 1), "%fusion.1", program_trace.scope_of(f"jit(step)/while/body/{sc}/mul", known)]
+           for i, sc in enumerate(scopes)]
+    spans = [[1, 0.0, 8e6, "llm.step", {"live": 6, "moe_rows": 6, "moe_held_assignments": 5.0}],
+             [1, 9e6, 8e6, "llm.step", {"live": 5, "moe_rows": 5, "moe_held_assignments": 3.0}]]
+    return {"cell": c, "device": {}, "program_trace": {"spans": spans, "ops": {"/device:TPU:0": ops}}}
+
+
+@pytest.mark.parametrize("name, cell, was", [
+    (r["now"], r["cell"], r["args"]) for r in RENAMES[54] if "args_now" in r
+] + [("ssm_scan_share.ssm", "jamba-closed6", {"scopes": ["ssm.conv", "ssm.scan", "ssm.state"]}),
+     ("held_assignments_share.mla", "axk1-rag-closed6",
+      {"span": "llm.step", "over": "moe_held_assignments", "under": "moe_rows", "scale": 12.5})],
+    ids=lambda v: v if isinstance(v, str) else "")
+def test_a_merged_metric_reads_what_each_cells_copy_read(name, cell, was):
+    """The two files that serve every cell by other arguments than their copies
+    had: the scopes of every state-space kind (a cell's operations carry its own
+    reference's alone), and 100 over the configuration's experts a token (12.5
+    at 8, 16.666666666666668 at 6: the copies' numbers to the last digit)."""
+    now = METRICS_OF[cell][name]
+    read = manifest.load_reader(now["reader"])
+    ctx = _hand_ctx(cell)
+    value = read(ctx, **now["args"])
+    assert value == read(ctx, **was) and value > 0  # equal, not close
+    if name == "ssm_scan_share.ssm":
+        mamba2 = "ssm.norm" in manifest.reference_of(ctx["cell"]).SCOPES
+        assert value == pytest.approx(100 * (20 + 30 + 40 + 50 + 60) / 450)  # all but `ssm.in`, `ssm.out`, `ffn`, `attn.core`
+        by_scope = program_trace.time_by_scope(ctx["program_trace"])
+        assert ("ssm.scan.chunk" in by_scope) == ("ssm.norm" in by_scope) == mamba2
+    else:
+        per_tok = ctx["cell"]["config_file"]["config"]["num_experts_per_tok"]
+        assert value == (100.0 / per_tok) * 8.0 / 11.0 and was["scale"] == 100.0 / per_tok
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_reads_a_quantity_under_one_name(cell):
+    """No two of a cell's files are one reader with one set of arguments: a copy
+    under a second suffix is an entry of the 128 spent on nothing (PR 38, PR 54)."""
+    seen = {}
+    for name, m in METRICS_OF[cell].items():
+        seen.setdefault(json.dumps([m["reader"], m.get("args")], sort_keys=True), []).append(name)
+    assert not {k: v for k, v in seen.items() if len(v) > 1}
 
 
 @pytest.mark.parametrize("entry", DOC["per_layer"], ids=[m["name"] for m in DOC["per_layer"]])
 def test_an_entrys_workloads_are_what_the_manifest_resolves(entry):
     resolved = [c for c in CELLS if entry["name"] in METRICS_OF[c]]
-    assert entry.get("workloads", CELLS) == resolved
+    assert entry.get("workloads", CELLS) == resolved and resolved
     assert os.path.isfile(os.path.join(manifest.BENCH_DIR, "layer_metrics", entry["name"] + ".json"))
 
 
@@ -634,6 +826,46 @@ def test_a_closed_cell_joins_the_families_from_its_own_file(tmp_path):
     # the cells that were there read what they read
     for cell in CELLS:
         assert [m["name"] for m in manifest.layer_metrics_for(cell, str(bench))] == list(METRICS_OF[cell])
+    assert all(p.read_bytes() == data for p, data in before.items())  # no file was edited
+
+
+def test_a_training_cell_joins_the_family_train_from_its_own_file(tmp_path):
+    """What the next training configuration depends on (PR 54): a second training
+    cell reads the step's share of the peak, the scope shares, the kernels', the
+    collectives' and the controller's numbers by naming `train` in its own new
+    file, with a mix of its own mesh, and brings one metric of its own
+    mechanism; no file that was there changed."""
+    bench = tmp_path / "benchmarks"
+    shutil.copytree(manifest.BENCH_DIR, bench, ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    mix = json.loads((bench / "traffic" / "train-steps.json").read_text())
+    mix["job"].update(batch=4, seq=8192, mesh={"ep": 4})
+    (bench / "traffic" / "toy-train-steps.json").write_text(json.dumps(mix))
+    (bench / "cells" / "toy-train-ep4.json").write_text(json.dumps(
+        {"config": "mistral-7b-v0.3-fsdp4", "traffic": "toy-train-steps", "chips": 4, "families": ["train"],
+         "why": "a second training cell"}))
+    bare = [m["name"] for m in manifest.layer_metrics_for("toy-train-ep4", str(bench))]
+    assert bare == list(METRICS_OF["train-fsdp4"]) and len(bare) == 13
+    (bench / "layer_metrics" / "exchange_share.toy.json").write_text(json.dumps(
+        {"unit": "%", "layer": "expert path (parallel/moe.py)", "moves": "train_tok_s", "source": "device_trace",
+         "cells": ["toy-train-ep4"], "reader": "scope_share", "args": {"scopes": ["moe.exchange"]}}))
+    got = {m["name"]: m for m in manifest.layer_metrics_for("toy-train-ep4", str(bench))}
+    fsdp = {m["name"]: m for m in manifest.layer_metrics_for("train-fsdp4", str(bench))}
+    train = {n for n, m in got.items() if m.get("family") == "train"}
+    assert train == {"mfu.train", "step_ms_p50", "attn_share.train", "ffn_share.train", "head_loss_share.train",
+                     "optimizer_share.train", "flash_share.train", "collective_exposed.train", "device_idle.train",
+                     "fit_overhead_s", "fit_restarts", "worker_stall_max_s"}
+    assert set(got) == train | {"hbm_peak_gb", "exchange_share.toy"}
+    # they are the files train-fsdp4 reads, the same readers with the same arguments
+    assert {n: m for n, m in got.items() if n != "exchange_share.toy"} == fsdp and "exchange_share.toy" not in fsdp
+    assert manifest.load_cell("train-fsdp4", str(bench))["families"] == ["train"]
+    # the mix is the driver's: the mesh, the batch and the sequence are the file's own
+    cell = manifest.load_cell("toy-train-ep4", str(bench))
+    assert cell["traffic_file"]["driver"] == "train_driver" and cell["traffic_file"]["job"]["mesh"] == {"ep": 4}
+    # no serving cell reads a training metric, and the cells that were there read what they read
+    for name in CELLS:
+        names = [m["name"] for m in manifest.layer_metrics_for(name, str(bench))]
+        assert names == list(METRICS_OF[name]) and (name == "train-fsdp4" or not train & set(names))
     assert all(p.read_bytes() == data for p, data in before.items())  # no file was edited
 
 

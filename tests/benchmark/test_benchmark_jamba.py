@@ -233,7 +233,8 @@ def test_the_mixer_reader_counts_the_steps_bytes_over_the_time_under_the_scopes(
     assert read(dict(ctx, program_trace=None)) is None
     assert read(dict(ctx, cell=manifest.load_cell("chat-closed6"))) is None
     # the cell reads what chat-closed6 reads through the families `closed` and `causal`, the
-    # two of `attn`, and five of its own
+    # two of `attn`, and the five that were its own through `ffn_dense` and `ssm`, which the
+    # later hybrids join from their own files (PR 54)
     names = {m["name"] for m in manifest.layer_metrics_for(CELL)}
     closed = {m["name"] for m in manifest.layer_metrics_for("chat-closed6") if m["name"].endswith(".closed")}
     assert len(closed) >= 16 and closed <= names
@@ -241,7 +242,9 @@ def test_the_mixer_reader_counts_the_steps_bytes_over_the_time_under_the_scopes(
         "attn_share.closed", "cache_share.closed", "ffn_share.ssm", "ssm_proj_share.ssm",
         "ssm_scan_share.ssm", "ssm_state_bytes.ssm", "ssm_hbm_share.ssm", "hbm_peak_gb"}
     listed = {m["name"]: m for m in manifest.load_manifest()["per_layer"]}
-    assert all(listed[n]["workloads"] == [CELL] for n in names if n.endswith(".ssm"))
+    assert all(listed[n]["workloads"][0] == CELL for n in names if n.endswith(".ssm"))
+    assert listed["ffn_share.ssm"]["workloads"] == [CELL, "phi4flash-reason-closed8"]
+    assert listed["ssm_hbm_share.ssm"]["workloads"] == [CELL, "phi4flash-reason-closed8", "nemotron3nano-reason-closed8"]
     assert all(CELL in listed[n]["workloads"] for n in names - {"hbm_peak_gb"})
 
 

@@ -165,42 +165,47 @@ def test_a_program_without_the_fields_refuses_the_configuration_by_name(monkeypa
         reference.program_config(tiny_config(mlp_hidden_act="silu")["config_file"])
 
 
-NEMOTRONH = {"ssm_hbm_share.nemotronh", "ssm_scan_share.nemotronh", "ssm_proj_share.nemotronh", "ssm_state_bytes.nemotronh",
-             "moe_experts_share.nemotronh", "experts_hbm_share.nemotronh", "held_assignments_share.nemotronh",
-             "held_compact_share.nemotronh", "shared_expert_share.nemotronh", "ffn_share.nemotronh",
-             "cache_bytes_per_token.nemotronh", "experts_touched_mean.nemotronh"}
+# its own family's three, and what it reads through the families it shares with the cells named
+OWN = {"moe_experts_share.nemotronh", "experts_hbm_share.nemotronh", "held_compact_share.nemotronh"}
+SHARED = {"ssm_hbm_share.ssm": "ssm", "ssm_scan_share.ssm": "ssm", "ssm_proj_share.ssm": "ssm",
+          "ssm_state_bytes.ssm": "ssm", "held_assignments_share.mla": "held", "shared_expert_share.mla": "shared_expert",
+          "ffn_share.mla": "ffn_moe", "cache_bytes_per_token.mla": "cache_bytes", "experts_touched_mean.moe": "experts_touched"}
+NEMOTRONH = OWN | set(SHARED)
 
 
 def test_the_cells_files_through_the_manifest():
-    """The cell joins `closed`, `causal`, `attn` and its own `nemotronh` from its
-    own file, and not `moe` (whose roofline share counts every layer as an
-    expert layer); BENCHMARK.json lists it where the manifest resolves it; the
-    mix is `reason-closed` value for value but for the fifth warm-up length."""
-    names = {m["name"] for m in manifest.layer_metrics_for(CELL)}
+    """The cell joins `closed`, `causal`, `attn`, its own `nemotronh` and, since
+    PR 54, the families of the metrics it shares with other cells, from its own
+    file, and not `moe` (whose roofline share counts every layer as an expert
+    layer); BENCHMARK.json lists it where the manifest resolves it; the mix is
+    `reason-closed`, which warms all five buckets since PR 54."""
+    metrics = {m["name"]: m for m in manifest.layer_metrics_for(CELL)}
+    names = set(metrics)
     assert NEMOTRONH <= names and {"cache_read_share.closed", "attn_share.closed", "decode_batch_mean.closed"} <= names
-    assert not {n for n in names if n.endswith((".mla", ".moe", ".ssm", ".blk", ".swa", ".sambay"))}
+    assert {n: metrics[n]["family"] for n in SHARED} == SHARED and {metrics[n]["family"] for n in OWN} == {"nemotronh"}
+    assert not {n for n in names if n.endswith((".blk", ".swa", ".sambay"))} and "experts_hbm_share.moe" not in names
     bench = manifest.load_manifest()
     listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [CELL])}
-    assert listed == names and len(bench["per_layer"]) == 123 <= 128
+    assert listed == names and len(bench["per_layer"]) <= 128
     for m in bench["per_layer"]:
         if m["name"] in NEMOTRONH:
-            assert m["workloads"] == [CELL] and m["moves"] == "serve_out_tok_s"
+            assert (m["workloads"] == [CELL]) == (m["name"] in OWN) and m["moves"] == "serve_out_tok_s"
     cell = manifest.load_cell(CELL)
     assert [w for w in bench["workloads"] if w["name"] == CELL] == [
-        {"name": CELL, "config": CONFIG, "traffic": "reason-closed-warm64", "chips": 1, "why": cell["why"]}]
+        {"name": CELL, "config": CONFIG, "traffic": "reason-closed", "chips": 1, "why": cell["why"]}]
     assert bench["workloads"][-1]["name"] == CELL and len(cell["why"]) <= 200
-    assert cell["callers"] == 8 and cell["families"] == ["closed", "causal", "attn", "nemotronh"]
+    assert cell["callers"] == 8 and cell["families"] == [
+        "closed", "causal", "attn", "nemotronh", "ffn_moe", "ssm", "shared_expert", "held", "cache_bytes", "experts_touched"]
     entry = bench["configs"][-1]
     assert entry["name"] == CONFIG and entry["reduced"] == ["n_routed_experts", "vocab_size"]
     assert entry["source"] == cell["config_file"]["source"] == (
         "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/blob/main/config.json")
     assert next(m for m in bench["end_to_end"] if m["name"] == "serve_out_tok_s")["workloads"][-1] == CELL
-    load = lambda name: json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", name + ".json")))
-    mine, reason = load("reason-closed-warm64"), load("reason-closed")
-    assert {k for k in reason if mine[k] != reason[k]} == {"warmup_prompt_lens"} and set(mine) == set(reason)
+    # PR 50's copy of the mix with the fifth warm-up length is the mix itself since PR 54
+    assert not os.path.exists(os.path.join(manifest.BENCH_DIR, "traffic", "reason-closed-warm64.json"))
     from cluster_anywhere_tpu.llm.continuous import prefill_buckets_for
 
-    assert tuple(mine["warmup_prompt_lens"]) == prefill_buckets_for(1024) == (64, 128, 256, 512, 1024)
+    assert tuple(cell["traffic_file"]["warmup_prompt_lens"]) == prefill_buckets_for(1024) == (64, 128, 256, 512, 1024)
 
 
 def _served_together(cfg, params, lens=(11, 40, 70), new_tokens=9):
@@ -314,26 +319,26 @@ def test_the_new_reader_against_hand_counts():
     got = manifest.read_layer_metrics(CELL, ctx)
     assert got["experts_hbm_share.nemotronh"]["value"] == pytest.approx(want)
     assert got["moe_experts_share.nemotronh"]["value"] == pytest.approx(100 * 9 / 40)
-    assert got["experts_touched_mean.nemotronh"]["value"] == pytest.approx(5.5)
+    assert got["experts_touched_mean.moe"]["value"] == pytest.approx(5.5)
     # a slice without a grouped matmul (no prompt of the largest bucket was admitted in it): the loop's products alone
     loop_only = copy.deepcopy(events)
     loop_only["ops"]["/device:TPU:0"][0] = op(0, 8e6, "moe.experts")
     assert experts(dict(ctx, program_trace=loop_only)) == pytest.approx(want)
     assert experts(dict(ctx, program_trace=loop_only), share_of="busy") == pytest.approx(100 * 9 / 40)
     assert manifest.load_reader("experts_kernel")(dict(ctx, program_trace=loop_only), share_of="busy") is None
-    assert got["shared_expert_share.nemotronh"]["value"] == pytest.approx(100 * 1 / 40)
-    assert got["ffn_share.nemotronh"]["value"] == pytest.approx(100 * 3 / 40)  # the kernel itself carries no scope
-    assert got["ssm_scan_share.nemotronh"]["value"] == pytest.approx(100 * 10 / 40)
-    assert got["ssm_proj_share.nemotronh"]["value"] == pytest.approx(100 * 6 / 40)
-    assert got["ssm_hbm_share.nemotronh"]["value"] == pytest.approx(
+    assert got["shared_expert_share.mla"]["value"] == pytest.approx(100 * 1 / 40)
+    assert got["ffn_share.mla"]["value"] == pytest.approx(100 * 3 / 40)  # the kernel itself carries no scope
+    assert got["ssm_scan_share.ssm"]["value"] == pytest.approx(100 * 10 / 40)
+    assert got["ssm_proj_share.ssm"]["value"] == pytest.approx(100 * 6 / 40)
+    assert got["ssm_hbm_share.ssm"]["value"] == pytest.approx(
         100 * 2 * reference.mixer_step_bytes(config, 32) / (16e-3 * 819e9))
-    assert 0 < got["ssm_hbm_share.nemotronh"]["value"] < 100
-    assert got["ssm_state_bytes.nemotronh"] == {"value": 3_141_271_552.0, "unit": "bytes"}
+    assert 0 < got["ssm_hbm_share.ssm"]["value"] < 100
+    assert got["ssm_state_bytes.ssm"] == {"value": 3_141_271_552.0, "unit": "bytes"}
     assert 2 * 32 * reference.slot_state_bytes(config) == 3_141_271_552
-    assert got["held_assignments_share.nemotronh"]["value"] == pytest.approx(100 * 12 / (6 * 16))
+    assert got["held_assignments_share.mla"]["value"] == pytest.approx(100 * 12 / (6 * 16))
     # of the replica's eleven admits two were of the largest bucket: read from its totals, not from the slice's one admit
     assert got["held_compact_share.nemotronh"]["value"] == pytest.approx(100 * 2 / 11)
-    assert got["cache_bytes_per_token.nemotronh"] == {"value": 6144.0, "unit": "bytes"}
+    assert got["cache_bytes_per_token.mla"] == {"value": 6144.0, "unit": "bytes"}
     assert NEMOTRONH <= set(got)
     # a program without the kernel or the count (the parent, a dense model), a run without a trace, a reference
     # that does not say how many layers hold experts: nothing, and no error
@@ -389,8 +394,8 @@ def test_serve_rehearsal_of_nemotron3nano_reason_closed8():
     assert stats["cache_bytes_per_token"] == 2 * 2 * 2 * 16 * 2 and stats["ssm_state_bytes"] > 0 and stats["moe_assignments"] > 0
     assert stats["moe_held_layers"] == 4 * stats["admitted"] and stats["moe_experts_touched"] > 0
     layer = manifest.read_layer_metrics(CELL, ctx)
-    assert layer["decode_batch_mean.closed"]["value"] >= 1.0 and layer["cache_bytes_per_token.nemotronh"]["value"] == 256.0
-    assert not {"ssm_hbm_share.nemotronh", "experts_hbm_share.nemotronh", "ffn_share.nemotronh"} & set(layer)
+    assert layer["decode_batch_mean.closed"]["value"] >= 1.0 and layer["cache_bytes_per_token.mla"]["value"] == 256.0
+    assert not {"ssm_hbm_share.ssm", "experts_hbm_share.nemotronh", "ffn_share.mla"} & set(layer)
     ctx["device"].update(platform="tpu", kind="TPU v5 lite", count=1)
     line = bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)
     assert set(line["metrics"]) == {"setup_s", "serve_out_tok_s"} and line["correct"] == check["ok"]

@@ -170,7 +170,7 @@ def test_recorded_trace_gives_the_chip_runs_numbers():
 def test_each_new_metric_reads_the_trace_and_nothing_from_an_older_program(metric):
     read = manifest.load_reader(metric["reader"])
     args = metric.get("args", {})
-    events = by_hand() if metric.get("cells") == ["train-fsdp4"] else recorded()
+    events = by_hand() if metric.get("family") == "train" else recorded()
     value = read({"program_trace": events}, **args)
     assert isinstance(value, float) and 0.0 <= value < 1e4
     if metric["unit"] == "%":
@@ -188,7 +188,7 @@ def test_new_metrics_are_the_issues_twenty_and_keep_the_layers_names():
     assert len(NEW) == 20
     layers = {m["layer"] for m in DOC["per_layer"]}
     assert {m["layer"] for m in NEW} <= layers
-    train = {m["name"] for m in NEW if m["cells"] == ["train-fsdp4"]}
+    train = {m["name"] for m in NEW if m.get("family") == "train"}
     assert train == {"attn_share.train", "ffn_share.train", "head_loss_share.train",
                      "optimizer_share.train", "flash_share.train"}
 

@@ -152,27 +152,34 @@ def test_a_program_without_the_fields_refuses_the_configuration_by_name(monkeypa
 
 
 def test_the_cells_files_through_the_manifest():
-    """The cell joins `closed`, `causal`, `attn` and its own `sambay` from its
+    """The cell joins `closed`, `causal`, `attn`, its own `sambay` and, since
+    PR 54, the families of what it shares with other cells (`ffn_dense` and `ssm`
+    with the Mamba-1 hybrid, `window` with the window-and-full mixture) from its
     own file; BENCHMARK.json lists it where the manifest resolves it; the mix is
     `chat-closed` but for lengths and what follows from them."""
-    names = {m["name"] for m in manifest.layer_metrics_for(CELL)}
-    sambay = {"ssm_share.sambay", "gmu_share.sambay", "ffn_share.sambay", "window_attn_share.sambay",
-              "full_attn_share.sambay", "cross_attn_share.sambay", "ssm_hbm_share.sambay",
+    metrics = {m["name"]: m for m in manifest.layer_metrics_for(CELL)}
+    names = set(metrics)
+    sambay = {"ssm_share.sambay", "gmu_share.sambay", "full_attn_share.sambay", "cross_attn_share.sambay",
               "shared_cache_hbm_share.sambay", "shared_rows_read_share.sambay", "cache_bytes_per_token.sambay",
-              "cache_window_share.sambay", "prefill_tail_share.sambay", "ssm_state_bytes.sambay", "ssm_proj_share.sambay",
-              "ssm_scan_share.sambay"}
-    assert sambay <= names and {"cache_read_share.closed", "attn_share.closed", "decode_batch_mean.closed"} <= names
-    assert not {n for n in names if n.endswith((".mla", ".moe", ".ssm", ".blk", ".swa"))}
+              "prefill_tail_share.sambay"}
+    shared = {"ffn_share.ssm": "ffn_dense", "ssm_hbm_share.ssm": "ssm", "ssm_state_bytes.ssm": "ssm",
+              "ssm_proj_share.ssm": "ssm", "ssm_scan_share.ssm": "ssm", "swa_attn_share.swa": "window",
+              "swa_cache_share.swa": "window"}
+    assert sambay | set(shared) <= names
+    assert {"cache_read_share.closed", "attn_share.closed", "decode_batch_mean.closed"} <= names
+    assert {n: metrics[n]["family"] for n in shared} == shared and {metrics[n]["family"] for n in sambay} == {"sambay"}
+    assert not {n for n in names if n.endswith((".mla", ".moe", ".blk", ".nemotronh"))}
+    assert {n for n in names if n.endswith(".swa")} == {"swa_attn_share.swa", "swa_cache_share.swa"}
     bench = manifest.load_manifest()
     listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [CELL])}
     assert listed == names and len(bench["per_layer"]) <= 128
     for m in bench["per_layer"]:
-        if m["name"] in sambay:
-            assert m["workloads"] == [CELL] and m["moves"] == "serve_out_tok_s"
+        if m["name"] in sambay | set(shared):
+            assert (m["workloads"] == [CELL]) == (m["name"] in sambay) and m["moves"] == "serve_out_tok_s"
     cell = manifest.load_cell(CELL)
     assert [w for w in bench["workloads"] if w["name"] == CELL] == [
         {"name": CELL, "config": CONFIG, "traffic": "reason-closed", "chips": 1, "why": cell["why"]}]
-    assert cell["callers"] == 8 and cell["families"] == ["closed", "causal", "attn", "sambay"]
+    assert cell["callers"] == 8 and cell["families"] == ["closed", "causal", "attn", "sambay", "ffn_dense", "ssm", "window"]
     entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == [] and entry["source"] == cell["config_file"]["source"]
     assert entry["source"] == "https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning/blob/main/config.json"
@@ -186,11 +193,11 @@ def test_the_cells_files_through_the_manifest():
     assert mine["prompt_len"] == {"dist": "lognormal", "median": 256, "sigma": 0.8, "min": 64, "max": 1024}
     assert mine["output_len"] == {"dist": "lognormal", "median": 1536, "sigma": 0.5, "min": 512, "max": 3072}
     assert mine["deployment"] == {"slots": 32, "max_prompt_len": 1024, "max_new_tokens": 3072, "prefix_cache_entries": 0}
-    # ISSUE 47's four lengths to the letter.  The deployment's ladder has a fifth program, 64, which a prompt clipped
-    # to exactly 64 tokens (about 4% of the mix) enters and no warm-up length does: PERF.md section 7
+    # a length a bucket of the deployment's ladder: ISSUE 47's four and, since PR 54, the fifth, 64, which a prompt
+    # clipped to exactly 64 tokens (about 4% of the mix) enters (it compiled inside the window where a seed drew it)
     from cluster_anywhere_tpu.llm.continuous import prefill_buckets_for
 
-    assert mine["warmup_prompt_lens"] == [128, 256, 512, 1024] and prefill_buckets_for(1024) == (64, 128, 256, 512, 1024)
+    assert tuple(mine["warmup_prompt_lens"]) == prefill_buckets_for(1024) == (64, 128, 256, 512, 1024)
     assert mine["warmup_new_tokens"] == 4
     assert mine["check"] == {"stream_prompt_lens": [100, 200, 480, 1000], "stream_new_tokens": 64,
                              "repeat_prompt_len": 200, "repeat_new_tokens": 9}
@@ -392,20 +399,20 @@ def test_the_new_reader_against_hand_counts():
     assert got["shared_cache_hbm_share.sambay"]["value"] == pytest.approx(want)
     assert got["full_attn_share.sambay"]["value"] == pytest.approx(100 * 2 / 40)
     assert got["cross_attn_share.sambay"]["value"] == pytest.approx(100 * 6 / 40)
-    assert got["window_attn_share.sambay"]["value"] == pytest.approx(100 * 1 / 40)
+    assert got["swa_attn_share.swa"]["value"] == pytest.approx(100 * 1 / 40)
     assert got["attn_share.closed"]["value"] == 0.0  # every core here runs under a scope of the stack it reads
     assert got["ssm_share.sambay"]["value"] == pytest.approx(100 * 6 / 40)
     assert got["gmu_share.sambay"]["value"] == pytest.approx(100 * 4 / 40)
-    assert got["ffn_share.sambay"]["value"] == pytest.approx(100 * 18 / 40)
+    assert got["ffn_share.ssm"]["value"] == pytest.approx(100 * 18 / 40)
     assert got["shared_rows_read_share.sambay"]["value"] == pytest.approx(100 * 12_288 / 16_384)
     assert got["cache_read_share.closed"]["value"] == pytest.approx(100 * 16_384 / 180_224)
-    assert got["ssm_hbm_share.sambay"]["value"] == pytest.approx(
+    assert got["ssm_hbm_share.ssm"]["value"] == pytest.approx(
         100 * 2 * reference.mixer_step_bytes(config, 32) / (6e-3 * 819e9))
-    assert got["ssm_proj_share.sambay"]["value"] == pytest.approx(100 * 2 / 40)
-    assert got["ssm_scan_share.sambay"]["value"] == pytest.approx(100 * 4 / 40)
-    assert got["ssm_state_bytes.sambay"] == {"value": 206_438_400.0, "unit": "bytes"}
+    assert got["ssm_proj_share.ssm"]["value"] == pytest.approx(100 * 2 / 40)
+    assert got["ssm_scan_share.ssm"]["value"] == pytest.approx(100 * 4 / 40)
+    assert got["ssm_state_bytes.ssm"] == {"value": 206_438_400.0, "unit": "bytes"}
     assert got["cache_bytes_per_token.sambay"] == {"value": 5120.0, "unit": "bytes"}
-    assert got["cache_window_share.sambay"] == {"value": 50.0, "unit": "%"}
+    assert got["swa_cache_share.swa"] == {"value": 50.0, "unit": "%"}
     assert got["prefill_tail_share.sambay"] == {"value": 0.39, "unit": "%"}
     # a slice without an admit leaves every number of the family a number
     assert {m["name"] for m in manifest.layer_metrics_for(CELL) if m.get("family") == "sambay"} <= set(got)
@@ -466,7 +473,7 @@ def test_serve_rehearsal_of_phi4flash_reason_closed8():
     layer = manifest.read_layer_metrics(CELL, ctx)
     assert layer["decode_batch_mean.closed"]["value"] >= 1.0 and layer["cache_bytes_per_token.sambay"]["value"] == 128.0
     assert layer["prefill_tail_share.sambay"]["value"] == pytest.approx(stats["prefill_tail_share"])
-    assert not {"ssm_share.sambay", "shared_cache_hbm_share.sambay", "attn_share.closed", "ffn_share.sambay"} & set(layer)
+    assert not {"ssm_share.sambay", "shared_cache_hbm_share.sambay", "attn_share.closed", "ffn_share.ssm"} & set(layer)
     ctx["device"].update(platform="tpu", kind="TPU v5 lite", count=1)
     line = bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)
     assert set(line["metrics"]) == {"setup_s", "serve_out_tok_s"} and line["correct"] == check["ok"]

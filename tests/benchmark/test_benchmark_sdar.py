@@ -225,7 +225,8 @@ def test_the_mechanism_enters_the_expert_layer_as_the_served_programs_do(monkeyp
     """A batcher that serves the check streams traces `transformer._moe` with
     the shapes, the types and the unsliced stack that `mechanism_checks` gives
     it, and no others: each prompt's whole blocks alone [1, bucket, E], and the
-    pass [slots, 4, E] with its live mask [slots, 4]."""
+    pass [slots, 8, E] with its live mask [slots, 8]: the block the pass stores
+    and the block it works on (`_pass_step_rowpos`, since PR 51)."""
     from cluster_anywhere_tpu.models import transformer
     from cluster_anywhere_tpu.parallel import moe
 
@@ -245,21 +246,29 @@ def test_the_mechanism_enters_the_expert_layer_as_the_served_programs_do(monkeyp
     served = set(calls)
     stack = tuple(sorted((n, params["blocks"][n].shape) for n in moe.EXPERT_MATRICES if n in params["blocks"]))
     assert served == {((1, b, 64), "bfloat16", (1, b), "bool", True, stack) for b in (32, 64, 96)} | {
-        ((6, 4, 64), "bfloat16", (6, 4), "bool", True, stack)}
+        ((6, 8, 64), "bfloat16", (6, 8), "bool", True, stack)}
     del calls[:]
     numbers = reference.mechanism_checks(cb, streams)
     assert all(m["error"] <= m["tolerance"] for m in numbers[:2]), numbers  # the expert layer's two
     assert {c for c in calls if c[-1]} == served and {c[:4] for c in calls} == {c[:4] for c in served}
     for s in streams:
         reference._replay(cb, s)
-    prefills, passes, n = reference.program_shapes(cb, streams)
+    prefills, passes, read, n = reference.program_shapes(cb, streams)
     reference._kept.clear()
     # 13 + 10 -> 24 rows, 40 + 10 -> 52, 70 + 10 -> 80, 23 + 10 -> 36; whole blocks 12, 40, 68, 20
     assert n == 24 + 52 + 80 + 36 and [(t, pad) for _, t, pad in prefills] == [(12, 20), (40, 24), (68, 28), (20, 12)]
-    assert passes.shape == (4, 6, 4) and passes[0, 0].tolist() == [12, 13, 14, 15] and passes[0, 1].tolist() == [64, 65, 66, 67]
+    assert passes.shape == read.shape == (4, 6, 8)
+    # a slot's first pass has nothing to store; its second stores the first's block before its own
+    assert passes[0, 0].tolist() == [n] * 4 + [12, 13, 14, 15] and passes[0, 1].tolist() == [n] * 4 + [64, 65, 66, 67]
+    assert passes[1, 0].tolist() == [12, 13, 14, 15, 16, 17, 18, 19]
     # the fourth stream's answer takes a block more than the others': their slots are not live in that step
-    assert (passes[:, 4:] == n).all() and passes[2, 0, 0] == 20 and (passes[3, :3] == n).all()
-    assert passes[3, 3].tolist() == [n - 4, n - 3, n - 2, n - 1]
+    assert (passes[:, 4:] == n).all() and passes[2, 0, 4] == 20 and (passes[3, :3] == n).all()
+    assert passes[3, 3].tolist() == list(range(n - 8, n))
+    # every row of the answers' blocks is read once: where a pass stores it, the last block in its own pass
+    assert not read[passes == n].any() and not read[0, :, :4].any() and read[1:, :, :4][passes[1:, :, :4] < n].all()
+    assert sorted(passes[read].tolist()) == [
+        *range(12, 24), *range(24 + 40, 24 + 52), *range(76 + 68, 76 + 80), *range(156 + 20, n)]
+    assert read[2, :3, 4:].all() and read[3, 3, 4:].all() and read[:, :, 4:].sum() == 4 * 4
 
 
 def test_counts_against_hand_counts():

@@ -134,15 +134,23 @@ def test_a_program_without_the_fields_refuses_the_configuration_by_name(monkeypa
 
 
 def test_the_cells_files_through_the_manifest():
-    """The cell joins `closed`, `causal`, `attn` and its own `swa` from its own
-    file; BENCHMARK.json lists it where the manifest resolves it; the mix is
-    `rag-closed` but for lengths and what follows from them."""
-    names = {m["name"] for m in manifest.layer_metrics_for(CELL)}
-    swa = {"swa_cache_share.swa", "swa_attn_share.swa", "swa_flash_roofline.swa", "window_rows_read_share.swa",
-           "ffn_share.swa", "moe_experts_share.swa", "shared_expert_share.swa", "held_assignments_share.swa",
-           "moe_dispatch_share.swa", "moe_router_share.swa"}
-    assert swa <= names and {"cache_read_share.closed", "attn_share.closed", "decode_batch_mean.closed"} <= names
-    assert not {n for n in names if n.endswith((".mla", ".moe", ".ssm", ".blk"))}
+    """The cell joins `closed`, `causal`, `attn`, its own `swa` and, since PR 54,
+    the families of what it shares with other cells (the held mixtures', the
+    routed mixtures', the window layers') from its own file; BENCHMARK.json lists
+    it where the manifest resolves it; the mix is `rag-closed` but for lengths
+    and what follows from them."""
+    metrics = {m["name"]: m for m in manifest.layer_metrics_for(CELL)}
+    names = set(metrics)
+    shared = {"swa_cache_share.swa": "window", "swa_attn_share.swa": "window", "ffn_share.mla": "ffn_moe",
+              "moe_experts_share.moe": "moe_kernel", "shared_expert_share.mla": "shared_expert",
+              "held_assignments_share.mla": "held", "held_compact_share.mla": "held_compact",
+              "moe_dispatch_share.moe": "moe_route", "moe_router_share.moe": "moe_route"}
+    assert {n: metrics[n]["family"] for n in shared} == shared
+    assert {n for n, m in metrics.items() if m.get("family") == "swa"} == {"swa_flash_roofline.swa", "window_rows_read_share.swa"}
+    assert {"cache_read_share.closed", "attn_share.closed", "decode_batch_mean.closed"} <= names
+    assert not {n for n in names if n.endswith((".ssm", ".blk", ".sambay", ".nemotronh"))} and "experts_hbm_share.moe" not in names
+    assert manifest.load_cell(CELL)["families"] == [
+        "closed", "causal", "attn", "swa", "ffn_moe", "shared_expert", "held", "held_compact", "moe_kernel", "moe_route", "window"]
     bench = manifest.load_manifest()
     listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [CELL])}
     assert listed == names
@@ -155,7 +163,10 @@ def test_the_cells_files_through_the_manifest():
     load = lambda name: json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", name + ".json")))
     mine, rag = load("longrag-closed"), load("rag-closed")
     differs = {k for k in rag if mine[k] != rag[k]}
-    assert differs == {"what", "shape_seed", "prompt_len", "deployment", "warmup_prompt_lens", "check"} and set(mine) == set(rag)
+    # since PR 54 a caller goes round 24 quantiles where it drew 96 sizes: a window reaches about 23
+    assert differs == {"what", "shape_seed", "caller_requests", "prompt_len", "deployment", "warmup_prompt_lens", "check"}
+    assert set(mine) - set(rag) == {"caller_sizes", "caller_rounds", "caller_sizes_why"} and set(rag) <= set(mine)
+    assert (mine["caller_requests"], mine["caller_sizes"], mine["caller_rounds"]) == (24, "quantiles", 8)
     assert mine["prompt_len"] == dict(rag["prompt_len"], median=4096, min=1024, max=8192)
     assert mine["deployment"] == dict(rag["deployment"], max_prompt_len=8192)
     assert mine["warmup_prompt_lens"] == [1024, 2048, 4096, 8192]
@@ -335,9 +346,9 @@ def test_the_new_reader_against_hand_counts():
     assert got["swa_cache_share.swa"] == {"value": 8.33, "unit": "%"}
     assert got["window_rows_read_share.swa"]["value"] == pytest.approx(100 * 2000 / 7000)
     assert got["cache_read_share.closed"]["value"] == pytest.approx(100 * 7000 / 16000)
-    assert got["shared_expert_share.swa"]["value"] == pytest.approx(10.0)
-    assert got["ffn_share.swa"]["value"] == pytest.approx(50.0)
-    assert got["held_assignments_share.swa"]["value"] == pytest.approx(100 * 5 / (11 * 8))
+    assert got["shared_expert_share.mla"]["value"] == pytest.approx(10.0)
+    assert got["ffn_share.mla"]["value"] == pytest.approx(50.0)
+    assert got["held_assignments_share.mla"]["value"] == pytest.approx(100 * 5 / (11 * 8))
     # a program without the kernel (the parent, another architecture), a slice without an admit, a run
     # without a trace, a reference that counts no band: nothing, and no error
     other = copy.deepcopy(events)
@@ -387,7 +398,7 @@ def test_serve_rehearsal_of_kexaone_longrag_closed6():
     assert stats["window_rows_read"] > 0 and stats["cache_rows_read"] > stats["window_rows_read"]
     layer = manifest.read_layer_metrics(CELL, ctx)
     assert layer["decode_batch_mean.closed"]["value"] >= 1.0 and layer["swa_cache_share.swa"]["value"] == pytest.approx(75.0)
-    assert not {"swa_attn_share.swa", "swa_flash_roofline.swa", "attn_share.closed", "ffn_share.swa"} & set(layer)
+    assert not {"swa_attn_share.swa", "swa_flash_roofline.swa", "attn_share.closed", "ffn_share.mla"} & set(layer)
     ctx["device"].update(platform="tpu", kind="TPU v5 lite", count=1)
     line = bench_run.result_line(ctx["cell"], serve_driver, ctx, trace=False)
     assert set(line["metrics"]) == {"setup_s", "serve_out_tok_s"} and line["correct"]
