@@ -594,6 +594,9 @@ class ContinuousBatcher:
             # of cache_rows_read, the part read of the one stack that several layers share (the
             # full layer that writes it and the cross layers above it); stays 0 where none share
             "shared_rows_read": 0,
+            # under learned sparse attention: the live rows' contexts over the steps (what
+            # cache_rows_read, the selected slots, is a share of) and the indexer keys scanned
+            "context_rows": 0, "index_rows_read": 0,
             # the positions the admits' prefills computed in the first layer (the prompts'
             # buckets) and in the last (the same, or 1 a prompt where the stack's second half
             # is computed at a prompt's last position alone: models/generate.py _prefill_tail),
@@ -734,13 +737,25 @@ class ContinuousBatcher:
         rows' own [pads, pos + tokens) in whole key blocks, by the kernel's own
         helper, and the window layers' part of them (models/generate.py
         key_slots: the mean over the attention layers where their extents
-        differ).  The host's arithmetic on its own vectors."""
-        return key_slots(self.cache, self._pads[slots], self._pos[slots] + tokens, self.cfg.attn_window, self.cfg)
+        differ).  The host's arithmetic on its own vectors.  Under learned
+        sparse attention (`cfg.index_topk`) the fetched slots are a row's
+        selected ones, min(its context, topk), and two numbers follow: the
+        rows' contexts, summed, and the indexer keys the step scans, every
+        slot's whole extent (the scores are one contraction over the layer)."""
+        first, last = self._pads[slots], self._pos[slots] + tokens
+        read = key_slots(self.cache, first, last, self.cfg.attn_window, self.cfg)
+        if "ki" in self.cache:
+            read += (int((last - first).sum()), self.slots * self.t_max)
+        return read
 
     def _count_rows_read(self, rows_read: tuple, sp: tracing.span) -> None:
         if self._cache_rows:
-            read, window, shared = rows_read
+            read, window, shared, *sparse = rows_read
             sp.set(cache_rows_read=read, cache_rows=self._cache_rows)
+            if sparse:
+                sp.set(context_rows=sparse[0], index_rows_read=sparse[1])
+                self.stats["context_rows"] += sparse[0]
+                self.stats["index_rows_read"] += sparse[1]
             self.stats["cache_rows_read"] += read
             self.stats["cache_rows"] += self._cache_rows
             if self._cache_bytes["window"]:
@@ -934,7 +949,8 @@ class ContinuousBatcher:
         device: `prefill_counted`'s, None where the replica holds every expert).
         `sp`, the request's `llm.admit` span, is told the positions computed in
         the first layer and in the last (`stats`)."""
-        with tracing.span("llm.admit.prefill"):
+        pre = tracing.span("llm.admit.prefill")
+        with pre:
             padded = np.zeros((1, bucket), np.int32)
             pad = bucket - len(prompt)
             padded[0, pad:] = prompt  # LEFT pad: generate.py's prefill contract
@@ -945,6 +961,9 @@ class ContinuousBatcher:
             self.stats["prefill_traces"] += prefill_counted._cache_size() - programs
             tail = 1 if self.cfg.carries else bucket
             sp.set(prefill_positions=bucket, tail_positions=tail)
+            if self.cfg.index_topk and bucket > self.cfg.index_topk:
+                # the bucket's queries each select among its keys (a shorter bucket attends densely)
+                pre.set(select_queries=bucket, select_keys=bucket)
             if self.cfg.ssm_n_heads:  # a Mamba-2 prefill's scan runs in chunks (models/transformer.py _ssd_scan)
                 sp.set(ssm_chunks=-(-bucket // self.cfg.ssm_chunk))
             self.stats["prefill_positions_total"] += bucket

@@ -7,6 +7,10 @@ This module owns the cache's layout (`init_cache`, `LAYER_STATE`): every kind
 of state the configuration's layers keep, each stacked over the layers of its
 kind alone,
     k, v: [n_attn, B, T_max, H_kv, D]
+    kv: [n_attn, B, T_max, 2 H_kv, D], ki: [n_attn, B, DI, T_max]  (learned sparse
+        attention: a token's keys and values side by side in ONE stack, and the
+        indexer's one key head of each token with the positions innermost:
+        `init_cache` says why)
     conv: [n_ssm, B, K-1, C], h: [n_ssm, B, C, N] (float32)
         (a Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N])
     ckv: [n_attn, B, T_max, R], kr: [n_attn, B, T_max, rope up to 128s]  (latent
@@ -109,22 +113,27 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..ops import sparse_attention as sparse
 from ..ops.attention import (
     DECODE_BLOCK_K, DECODE_BLOCK_ROWS, attention, decode_attention, decode_on_kernel, decode_rows_read, decode_span,
 )
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
     _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gmu_block, _gqa_repeat, _hand_on,
-    _head, _latent_expand, _latent_up, _mamba2_half, _mamba2_zero_state, _scan_layers, _ssm_block_forward, _ssm_half,
-    _ssm_mix, _x, carried, core_scope, is_window, layer_stacks,
+    _head, _latent_expand, _latent_up, _mamba2_half, _mamba2_zero_state, _scan_layers, _sparse_attention,
+    _ssm_block_forward, _ssm_half, _ssm_mix, _x, carried, core_scope, is_window, layer_stacks,
 )
 
 # what a layer of each kind of state keeps of a sequence between two tokens, as
 # the cache's keys: one stacked array each over the layers that keep that kind.
 # "attn_win": a window layer's keys and values, a ring of `window_extent` slots a row
-LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr"), "attn_win": ("kw", "vw")}
+# Under learned sparse attention (`cfg.index_topk`) an attention layer keeps "attn_kv", a token's keys AND values
+# in ONE stack (`init_cache` says why), and beside it "index", the indexer's one key head of each token
+LAYER_STATE = {"attn": ("k", "v"), "ssm": ("conv", "h"), "latent": ("ckv", "kr"), "attn_win": ("kw", "vw"),
+               "attn_kv": ("kv",), "index": ("ki",)}
 # the scope a kind's state is read, written and installed under
-STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache", "attn_win": "attn.cache"}
+STATE_SCOPE = {"attn": "attn.cache", "ssm": "ssm.state", "latent": "attn.cache", "attn_win": "attn.cache",
+               "attn_kv": "attn.cache", "index": "attn.cache"}
 # the kinds of layer that keep no rows of their own: a gated memory unit keeps nothing
 # between two tokens (its memory is the step's own), a cross layer reads the stack of keys
 # and values that the one full layer before it writes, an FFN alone mixes no positions
@@ -156,6 +165,8 @@ def _state_kind(kind: str, cfg: TransformerConfig) -> Optional[str]:
         return "ssm"
     if is_window(kind):
         return "attn_win"
+    if cfg.index_topk:
+        return "attn_kv"
     return "latent" if cfg.latent else "attn"
 
 
@@ -293,7 +304,9 @@ def _ring_seen(first, last, extent: int):
 
 def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     """Every kind of state the configuration's layers keep, each stacked over
-    the layers that keep it alone: k, v [n_attn, B, t_max, KV, D], or under
+    the layers that keep it alone: k, v [n_attn, B, t_max, KV, D] (under learned
+    sparse attention kv [n_attn, B, t_max, 2 KV, D], keys and values in one stack,
+    and the indexer's keys ki [n_attn, B, DI, t_max]), or under
     latent attention ckv [n_attn, B, t_max, R] and kr [n_attn, B, t_max, rope up
     to LATENT_LANES] (a token's latent and its rotated key);
     the window layers' kw, vw [n_win, B, W, KV, D], W = `window_extent`: an
@@ -318,6 +331,17 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
         rope = -(-cfg.qk_rope_head_dim // LATENT_LANES) * LATENT_LANES
         cache.update(ckv=jnp.zeros((n_attn, batch, t_max, cfg.kv_lora_rank), cfg.dtype),
                      kr=jnp.zeros((n_attn, batch, t_max, rope), cfg.dtype))
+    elif n_attn and cfg.index_topk:
+        # learned sparse attention: a decode step GATHERS a row's selected slots, and a gather costs by the row it
+        # fetches, not by the row's bytes: 8,192 rows of 1 KB (4 cached heads of 128) took 183 us and 8,192 rows
+        # of 2 KB 172 us on a v5e (PERF.md section 6, PR 56), so a token's keys and values lie side by side in ONE
+        # stack, heads 0 .. KV-1 the keys and KV .. 2 KV-1 the values, and one gather fetches both
+        cache.update(kv=jnp.zeros((n_attn, batch, t_max, 2 * cfg.cached_heads, cfg.cached_width), cfg.dtype))
+        # the indexer's keys, one head of index_head_dim a token, with the positions innermost: a last axis 64 wide
+        # is no whole tile of lanes (LATENT_LANES, above; a kernel handed such a stack is handed a copy of all of
+        # it, 0.43 GB a call at 48 x 4 x 8,704), and [DI, T_max] is the second operand of the scores' product as the
+        # matrix unit takes it (a step's scan of a layer: 63 us so against 75, PERF.md section 6, PR 56)
+        cache.update(ki=jnp.zeros((n_attn, batch, cfg.index_head_dim, t_max), cfg.dtype))
     elif n_attn:
         shape = (n_attn, batch, *slots(t_max), cfg.cached_width)
         cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
@@ -361,14 +385,20 @@ def _token_bytes(cache, kinds, cfg: Optional[TransformerConfig]) -> int:
                for n in names) * heads
 
 
+def _index_token_bytes(cache) -> int:
+    """The bytes of one slot's indexer keys over their layers ([n, B, DI, T]: the positions are the last axis)."""
+    ki = cache.get("ki")
+    return 0 if ki is None else int(ki.size) * ki.dtype.itemsize // (ki.shape[1] * ki.shape[3])
+
+
 def cache_bytes_per_token(cache, cfg: Optional[TransformerConfig] = None) -> int:
     """The bytes one token of one sequence takes in a cache over all the layers
-    that attend (its keys and values, or its latent row and rotated key; in a
+    that attend (its keys and values and, where there is an indexer, its indexer key, or its latent row and rotated key; in a
     window layer too, while the layer holds it: each array by its own extent).
     A layer that reads another layer's stack keeps nothing and adds nothing.
     0 for a cache of recurrent state alone.  cfg: the cache's configuration,
     where its stacks may be flat."""
-    return _token_bytes(cache, ("attn", "latent", "attn_win"), cfg)
+    return _token_bytes(cache, ("attn", "attn_kv", "latent", "attn_win"), cfg) + _index_token_bytes(cache)
 
 
 def cache_context_bytes_per_token(cache, cfg: Optional[TransformerConfig] = None) -> int:
@@ -377,15 +407,15 @@ def cache_context_bytes_per_token(cache, cfg: Optional[TransformerConfig] = None
     A window layer's ring and a recurrent state are a slot's whatever its
     context holds (`cache_kind_bytes`, `recurrent_state_bytes`).  0 for a cache
     of rings or recurrent state alone."""
-    return _token_bytes(cache, ("attn", "latent"), cfg)
+    return _token_bytes(cache, ("attn", "attn_kv", "latent"), cfg) + _index_token_bytes(cache)
 
 
 def cache_kind_bytes(cache) -> Dict[str, int]:
     """{"full": the bytes of the stacks whose extent is the context's (keys and
-    values, latent rows), "window": those of the window layers' rings}."""
+    values and the indexer's keys, latent rows), "window": those of the window layers' rings}."""
     size = lambda kinds: sum(int(cache[n].size) * cache[n].dtype.itemsize
                              for kind in kinds for n in LAYER_STATE[kind] if n in cache)
-    return {"full": size(("attn", "latent")), "window": size(("attn_win",))}
+    return {"full": size(("attn", "attn_kv", "latent", "index")), "window": size(("attn_win",))}
 
 
 def key_slots(cache, first=None, last=None, window: int = 0, cfg: Optional[TransformerConfig] = None):
@@ -404,10 +434,11 @@ def key_slots(cache, first=None, last=None, window: int = 0, cfg: Optional[Trans
     step fetches: whole key blocks of those rows under the decode kernel
     (ops/attention.py decode_rows_read; a window layer's row is its ring),
     every slot of every row under latent attention, whose core contracts with
-    the layer whole.  (0, 0, 0) for a cache of recurrent state alone."""
+    the layer whole, and a row's selected slots alone, min(its context,
+    `cfg.index_topk`), under learned sparse attention.  (0, 0, 0) for a cache of recurrent state alone."""
     readers, flat_heads = (cfg.shared_readers, cfg.flat_heads) if cfg is not None else (0, 0)
     per_layer = {}  # the stack's name -> (the layers that read it, the slots of one)
-    for name in ("k", "ckv", "kw"):
+    for name in ("k", "kv", "ckv", "kw"):
         if name not in cache:
             continue
         n, rows, t_max, *heads = cache[name].shape
@@ -417,6 +448,9 @@ def key_slots(cache, first=None, last=None, window: int = 0, cfg: Optional[Trans
             n += readers - 1  # the layer that writes the shared stack is one of its readers
         if first is None or name == "ckv":
             per_layer[name] = n, rows * t_max
+        elif cfg is not None and cfg.index_topk and t_max > cfg.index_topk:
+            # learned sparse attention gathers a row's selected slots and no other
+            per_layer[name] = n, int(np.minimum(last - first, cfg.index_topk).sum())
         else:
             lo = np.maximum(first, last - window) if name == "kw" else first
             per_layer[name] = n, int(decode_rows_read(lo, last, t_max, heads[0], ring=name == "kw").sum())
@@ -491,8 +525,60 @@ def _latent_decode_core(bp, cache, layer, pos, pads, cfg: TransformerConfig, q, 
     return attn, {**cache, "ckv": ckv_all, "kr": kr_all}
 
 
+def _attend_selected(q, kv_all, layer, at, chosen, cfg: TransformerConfig):
+    """q [B, 1, H, D] against the listed slots of each row and NO other: of the
+    stack kv_all [n, B, T_max, 2 KV, D] the rows [layer, b, at[b]] are gathered
+    in one gather, [B, topk, 2 KV, D], keys and values side by side, and the
+    first chosen[b] of them attended to (`_masked_attention` over the gathered
+    rows)."""
+    kv = cfg.cached_heads
+    listed = kv_all[layer, jnp.arange(q.shape[0])[:, None], at]
+    return _masked_attention(q, listed[:, :, :kv], listed[:, :, kv:], chosen, cfg)
+
+
+def _sparse_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, index, listed: bool = False):
+    """A decode step's core under learned sparse attention, one token a row.
+    Row b's keys and values k, v [B, 1, KV, D] are written side by side at
+    [layer, b, pos[b]] of the stack `kv` and its indexer key at [layer, b, :,
+    pos[b]] of `ki`.  Over a cache longer than `cfg.index_topk` the row's
+    indexer queries score its whole context [pads[b], pos[b]] against the
+    layer's indexer keys (`attn.indexer`), the `cfg.index_topk` best are listed
+    (`attn.select`; fewer while the context is shorter: then all of it), and of
+    `kv` the listed slots of the row and NO other are gathered and attended to
+    (`attn.sparse_core`): the scopes an admit's prefill writes, each one level
+    deep.  A shorter cache's selection leaves nothing out: the layer is taken
+    out of the stack and attended to whole, as ever.
+    index: `transformer._project_index`'s of the step's tokens, or the way to
+    it.  Returns (attn [B, 1, H, D], the cache after), and with `listed` the
+    list and its count behind them."""
+    if q.shape[1] != 1 or k is None:
+        raise NotImplementedError("learned sparse attention decodes one token a row over its own stacks")
+    rows, kv, extent = jnp.arange(q.shape[0]), cfg.cached_heads, cache["kv"].shape[2]
+    with jax.named_scope(STATE_SCOPE["attn_kv"]):
+        kv_all = cache["kv"].at[layer, rows, pos].set(jnp.concatenate([k[:, 0], v[:, 0]], axis=1))
+    with jax.named_scope("attn.indexer"):
+        qi, ki, w = index() if callable(index) else index
+    with jax.named_scope(STATE_SCOPE["index"]):
+        ki_all = cache["ki"].at[layer, rows, :, pos].set(ki[:, 0])
+    after = {**cache, "kv": kv_all, "ki": ki_all}
+    if extent <= cfg.index_topk:
+        with jax.named_scope(STATE_SCOPE["attn_kv"]):
+            kv_layer = lax.dynamic_index_in_dim(kv_all, layer, keepdims=False)
+        with jax.named_scope("attn.sparse_core"):
+            attn = _masked_attention(q, kv_layer[:, :, :kv], kv_layer[:, :, kv:], pos + 1, cfg, pads)
+        return (attn, after, None) if listed else (attn, after)
+    with jax.named_scope("attn.indexer"):
+        ki_layer = lax.dynamic_index_in_dim(ki_all, layer, keepdims=False)  # [B, DI, T_max], as it lies
+        scores = sparse.index_scores_reference(qi, ki_layer, w, keys_last=True)[:, 0]  # [B, T_max]
+    with jax.named_scope("attn.select"):
+        at, chosen = sparse.select_rows(scores, pads, pos + 1, cfg.index_topk)
+    with jax.named_scope("attn.sparse_core"):
+        attn = _attend_selected(q, kv_all, layer, at, chosen, cfg)
+    return (attn, after, (at, chosen)) if listed else (attn, after)
+
+
 def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, live=None, span=None, kind: str = "attn",
-                    pending=None):
+                    pending=None, index=None):
     """The decode block's core over cached keys and values.  q: [B, T, H, D],
     k, v: [B, T, KV, D] of the step's own positions (T = 1, or a block's): they
     are written at [layer, b, pos[b] ...] of the stacks of the layer's state
@@ -507,7 +593,11 @@ def _kv_decode_core(cache, layer, pos, pads, cfg: TransformerConfig, q, k, v, li
     have one pending and for no other row (a scatter that drops the others'
     rows: nothing of the stack is read), and its queries see the slots before
     pos[b]; the second half as a pass without it.
+    index: the way to the indexer's part of the step's tokens under learned
+    sparse attention, whose core is `_sparse_decode_core` over stacks of its own.
     Returns (attn [B, T, H, Dv], the cache after)."""
+    if index is not None:
+        return _sparse_decode_core(cache, layer, pos, pads, cfg, q, k, v, index)
     t = q.shape[1]
     state = _state_kind(kind, cfg)
     ring = state == "attn_win"
@@ -663,7 +753,16 @@ def _prefill_block(bp, s, pad, cfg: TransformerConfig, t_max: int, experts=None,
             kept = lax.dynamic_update_slice(jnp.zeros((b, extent, *a.shape[2:]), x.dtype), a, (0, 0, 0, 0))
         return kept.reshape(b, -1, a.shape[-1]) if cfg.flat_heads else kept
 
-    def core(q, k, v):
+    def core(q, k, v, index=None):
+        if index is not None:
+            # learned sparse attention: keys and values are kept side by side in one stack, and the
+            # indexer's keys, one head a token, beside it with the positions innermost
+            with jax.named_scope("attn.indexer"):
+                index = index()
+            with jax.named_scope("attn.cache"):
+                kv_cache = stored(jnp.concatenate([k, v], axis=2))
+                ki_cache = jnp.swapaxes(stored(index[1][:, :, None, :])[:, :, 0], 1, 2)  # [B, DI, T_max]
+            return _sparse_attention(q, k, v, index, cfg, pad).astype(x.dtype), (kv_cache, ki_cache)
         with jax.named_scope("attn.cache"):
             k_cache, v_cache = stored(k), stored(v)
         # causal attention within the prompt (q already has full heads; only
@@ -815,9 +914,11 @@ def prefill_counted(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     index = _state_index(cfg)
     cache: Dict[str, Any] = {}
     for state, names in LAYER_STATE.items():
-        kinds = [kind for kind in rows if _state_kind(kind, cfg) == state]
+        # the indexer's keys are made by the layers that keep keys and values in one stack, behind it
+        of, skip = ("attn_kv", 1) if state == "index" else (state, 0)
+        kinds = [kind for kind in rows if _state_kind(kind, cfg) == of and len(rows[kind]) > skip]
         order = np.argsort(np.concatenate([index[kind] for kind in kinds])) if kinds else None
-        for i, name in enumerate(names if kinds else ()):
+        for i, name in enumerate(names if kinds else (), skip):
             joined = rows[kinds[0]][i] if len(kinds) == 1 else jnp.concatenate([rows[kind][i] for kind in kinds])
             cache[name] = joined if np.array_equal(order, np.arange(len(order))) else joined[order]
     held = None

@@ -239,8 +239,28 @@ class TransformerConfig:
     # expert's too, which is gated where the routed ones are and (act(x w_in)) w_out where they are not
     moe_act: str = "silu"
     d_shared: int = 0  # the shared expert's width; 0: n_shared_experts * d_expert
+    # learned sparse attention (index_topk > 0; ops/sparse_attention.py): every attention layer carries an
+    # indexer, index_n_heads query heads of index_head_dim against ONE key head that is cached
+    # beside a token's keys and values, I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) in float32 with w a
+    # projection of the layer's normed input times index_n_heads^-1/2 index_head_dim^-1/2; a query attends to
+    # the index_topk earlier positions of largest I (equal scores to the lower position), one set for all its
+    # heads, and to everything while its context is no longer than that.  Served on one device
+    # (`_project_index`, models/generate.py); no mesh shards it and no block, window or latent layer takes it.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
 
     def __post_init__(self):
+        if self.index_topk and (self.index_n_heads <= 0 or self.index_head_dim <= 0 or self.index_head_dim % 2):
+            raise ValueError(
+                f"index_topk={self.index_topk}: an indexer takes index_n_heads={self.index_n_heads} query heads of an "
+                f"even index_head_dim={self.index_head_dim} against one key head")
+        if self.index_topk and (self.latent or self.generates_blocks or self.diff_attn or self.layer_mixers is not None
+                                or self.attn_layer_period or self.pp > 1 or self.sp > 1 or self.ep > 1):
+            raise NotImplementedError(
+                f"index_topk={self.index_topk}: learned sparse attention is every layer's of a stack of plain "
+                "grouped-query attention blocks that yields one token a step, on one device: no latent or "
+                "differential attention, blocks of positions, layer pattern, pipeline stages, sequence or expert axis")
         if self.generates_blocks and self.block_length % self.denoise_steps:
             raise ValueError(
                 f"block_length {self.block_length} is not a multiple of denoise_steps "
@@ -548,6 +568,15 @@ def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True, ffn: 
         per_head = cfg.qk_norm_per_head
         out.update({"q_norm": jnp.ones((d if per_head else h * d,), pd),
                     "k_norm": jnp.ones((d if per_head else kv * d,), pd)})
+    if cfg.index_topk:
+        # the indexer: its queries' and its one key's projections, the key's LayerNorm (weight and bias),
+        # and the projection to a weight a head
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        ki = [jax.random.fold_in(key, 16 + i) for i in range(4)]
+        out.update(wq_idx=jax.random.normal(ki[0], (e, hi * di), pd) * s(e),
+                   wk_idx=jax.random.normal(ki[1], (e, di), pd) * s(e),
+                   k_idx_norm=jnp.ones((di,), pd), k_idx_norm_b=jax.random.normal(ki[2], (di,), pd) * 0.02,
+                   w_idx=jax.random.normal(ki[3], (e, hi), pd) * s(e))
     if ffn:
         out.update(_init_ffn(ks[4:], cfg))
     return out
@@ -728,7 +757,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
 def _one_device_only(cfg: TransformerConfig, what: str) -> None:
     """A layer pattern's training loop over a mesh is not written (ROADMAP M1):
     say which kind of layer stands in the way instead of sharding it wrongly."""
-    kinds = sorted(set(cfg.layer_kinds) - {"attn"}) + ["latent attention"] * cfg.latent
+    kinds = (sorted(set(cfg.layer_kinds) - {"attn"}) + ["latent attention"] * cfg.latent
+             + ["learned sparse attention (index_topk)"] * bool(cfg.index_topk))
     if kinds:
         raise NotImplementedError(
             f"{what}: layers of kind {kinds} (attn_layer_period={cfg.attn_layer_period}, "
@@ -781,14 +811,14 @@ def _norm(x, bp, name: str, cfg: TransformerConfig):
     return out.astype(x.dtype)
 
 
-def _rope_freqs(cfg: TransformerConfig):
-    """(the rotary frequencies [rope_dim / 2], what cos and sin are scaled by).
+def _rope_freqs(cfg: TransformerConfig, d: Optional[int] = None):
+    """(the rotary frequencies [d / 2], d = `cfg.rope_dim` unless given; what cos and sin are scaled by).
     With YaRN (`cfg.rope_factor` > 1; the configuration says what each size
     is) dimension i keeps its frequency where it turns more than beta_fast
     times over the original length, is interpolated (/ factor) where it turns
     fewer than beta_slow times, and is blended linearly between the two
     correction dimensions."""
-    d = cfg.rope_dim
+    d = d or cfg.rope_dim
     freqs = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     if cfg.rope_factor == 1.0:
         return freqs, 1.0
@@ -861,11 +891,12 @@ _turn.defvjp(lambda x, cos, sin: (_turn(x, cos, sin), (cos, sin)),
              lambda tables, g: (_turn(g, tables[0], -tables[1]), None, None))
 
 
-def _rope(q, k, positions, cfg: TransformerConfig):
+def _rope(q, k, positions, cfg: TransformerConfig, d: Optional[int] = None):
     """Rotary embeddings; q,k: [B, T, H, D]. positions: [T] global positions,
     or [B, T] per-row positions (left-padded prompts shift each row's real
-    tokens to start at position 0)."""
-    freqs, magnitude = _rope_freqs(cfg)
+    tokens to start at position 0).  d: the width turned where it is not
+    `cfg.rope_dim` (the indexer's heads: `_project_index`)."""
+    freqs, magnitude = _rope_freqs(cfg, d)
     angles = positions[..., None].astype(jnp.float32) * jnp.repeat(freqs, 2)  # [..., T, D]
     if angles.ndim == 2:
         angles = angles[None]  # broadcast over batch
@@ -948,6 +979,25 @@ def _project_qkv(bp, y, cfg: TransformerConfig, y_kv=None):
         return q, None, None
     y_kv = y if y_kv is None else y_kv
     return q, project(y_kv, "wk", cfg.n_kv_heads, "k_norm"), project(y_kv, "wv", cfg.n_kv_heads)
+
+
+def _project_index(bp, y, cfg: TransformerConfig, positions, y_kv=None):
+    """The indexer's part of a block's normed input y [B, T, E] (`cfg.index_topk`):
+    (qI [B, T, HI, DI], kI [B, T_kv, DI], w [B, T, HI] float32).  qI = y wq_idx
+    by heads; kI = LayerNorm(y wk_idx), one head, what a token is cached as
+    beside its keys and values; both turned over all DI dimensions at the
+    model's theta; w = y w_idx times HI^-1/2 DI^-1/2.  The score of a query
+    against a key is sum_j w[j] relu(qI[j] . kI) (ops/sparse_attention.py)."""
+    b, t, _ = y.shape
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    y_kv = y if y_kv is None else y_kv
+    qi = _project_heads(y, bp["wq_idx"]).reshape(b, t, hi, di)
+    ki = _norm(y_kv @ bp["wk_idx"].astype(y.dtype), bp, "k_idx_norm", cfg)
+    if cfg.rotary:
+        qi, ki = _rope(qi, ki[:, :, None, :], positions, cfg, di)
+        ki = ki[:, :, 0]
+    w = (y @ bp["w_idx"].astype(y.dtype)).astype(jnp.float32) * (hi ** -0.5 * di ** -0.5)
+    return qi, ki, w
 
 
 def _diff_heads(q, k, v):
@@ -1091,6 +1141,34 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh, manual_axes, window: int =
     return _per_shard(fn, mesh, manual_axes)(q, k, v)
 
 
+def _sparse_attention(q, k, v, index, cfg: TransformerConfig, pad=None, chosen: bool = False):
+    """Learned sparse attention over whole sequences (a forward, a prompt's
+    prefill): q [B, T, H, D]; k, v [B, T, KV, D] as projected, not repeated;
+    index: `_project_index`'s; pad [B]: left-pad counts or None.  The indexer's
+    scores of every causal pair, each query's `cfg.index_topk` best as a mask,
+    and attention under the mask, each under its own scope (`attn.indexer`,
+    `attn.select`, `attn.sparse_core`; ops/sparse_attention.py).  A sequence no
+    longer than `cfg.index_topk` leaves no position out: it goes the dense
+    path, to the bit.  chosen: hand back (the result, the mask int8 [B, T, T],
+    None on the dense path) for a test or a check to read the selection."""
+    from ..ops import sparse_attention as sparse
+
+    t = q.shape[1]
+    if t <= cfg.index_topk:
+        with jax.named_scope("attn.sparse_core"):
+            out = dense_attention(q, _gqa_repeat(k, cfg), _gqa_repeat(v, cfg), causal=True, pad=pad,
+                                  scale=cfg.attn_scale)
+            return (out, None) if chosen else out
+    (q, k, v, qi, ki, w), pad, extra = sparse.left_pad_to_tile([q, k, v, *index], pad)
+    with jax.named_scope("attn.indexer"):
+        scores = sparse.index_scores(qi, ki, w, first=pad)
+    with jax.named_scope("attn.select"):
+        mask = sparse.select_mask(scores, *sparse.causal_spans(pad, t + extra), cfg.index_topk)
+    with jax.named_scope("attn.sparse_core"):
+        out = sparse.masked_flash(q, k, v, mask, cfg.attn_scale, first=pad)[:, extra:]
+    return (out, mask[:, extra:, extra:]) if chosen else out
+
+
 def _gqa_repeat(x, cfg: TransformerConfig):
     """k or v [B, T, KV, D] repeated to the query's H heads, for a core that
     wants them alike (the flash kernel, ring, Ulysses)."""
@@ -1136,6 +1214,10 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = 
         if cfg.rotates(kind):
             with jax.named_scope("attn.rope"):
                 q, k = _rope(q, k, positions, cfg)
+    if cfg.index_topk:
+        # the core scores, selects and attends under the selection: it is given the way to the indexer's part
+        # beside q, k, v, and goes it under its own scope (`attn.indexer`)
+        core = functools.partial(core, index=functools.partial(_project_index, bp, y, cfg, positions, y_kv))
     attn, extra = core(q, k, v)
     if cfg.diff_attn:
         with jax.named_scope("attn.diff"):
@@ -1550,9 +1632,13 @@ def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     t = x.shape[1]
     offset = lax.axis_index("sp") * t if "sp" in manual_axes and cfg.sp > 1 else 0
 
-    def core(q, k, v):
+    def core(q, k, v, index=None):
         if keep:
             q, k, v = (a if a is None else checkpoint_name(a, "attn." + name) for a, name in zip((q, k, v), "qkv"))
+        if index is not None:  # learned sparse attention: the way to the indexer's part beside q, k, v
+            with jax.named_scope("attn.indexer"):
+                index = index()
+            return _sparse_attention(q, k, v, index, cfg), (k, v)
         with jax.named_scope(core_scope(kind, cfg)):
             if k is None:
                 k, v = s.k, s.v

@@ -24,7 +24,8 @@ import re
 import sys
 
 CELLS = ("chat-closed6", "olmoe-closed6", "jamba-closed6", "sdar-closed6", "axk1-rag-closed6",
-         "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8")
+         "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8",
+         "keye-longdoc-closed4")
 
 
 def hashes(root: str) -> dict:
@@ -49,6 +50,8 @@ def hashes(root: str) -> dict:
     sha = lambda t: hashlib.sha256(t.encode()).hexdigest()[:16]
     out = {}
     for name in CELLS:
+        if not os.path.exists(os.path.join(benchmarks, "cells", name + ".json")):
+            continue  # a checkout from before the cell
         cell = manifest.load_cell(name, benchmarks)
         ref = manifest.load_reference(cell["config_file"]["reference"], benchmarks)
         try:
@@ -104,6 +107,19 @@ def hashes(root: str) -> dict:
     out["kernel.flash_block"] = jaxpr(lambda q, k, v, p: attention.flash_attention(q, k, v, pad=p, block=4), x, x, x, pad)
     out["kernel.flash_grad"] = jaxpr(
         jax.grad(lambda q, k, v: attention.flash_attention(q, k, v).sum().astype(jnp.float32), argnums=(0, 1, 2)), x, x, x)
+    try:
+        sparse = importlib.import_module("cluster_anywhere_tpu.ops.sparse_attention")
+    except ImportError:  # a checkout from before learned sparse attention
+        return out
+    # its three kernels in `keye-longdoc-closed4`'s shapes: an admit's bucket of 8,192
+    t, f32, i32 = 8192, jnp.float32, jnp.int32
+    out["kernel.dsa_index"] = jaxpr(sparse.index_scores_kernel, shape((1, t, 16, 64), jnp.bfloat16),
+                                    shape((1, t, 64), jnp.bfloat16), shape((1, t, 16), f32))
+    out["kernel.dsa_select"] = jaxpr(lambda s, a, b: sparse.select_mask_kernel(s, a, b, 2048), shape((t, t), f32),
+                                     shape((t,), i32), shape((t,), i32))
+    out["kernel.dsa_flash"] = jaxpr(lambda q, k, v, m, p: sparse.masked_flash_kernel(q, k, v, m, 128 ** -0.5, p),
+                                    shape((1, t, 32, 128), jnp.bfloat16), shape((1, t, 4, 128), jnp.bfloat16),
+                                    shape((1, t, 4, 128), jnp.bfloat16), shape((1, t, t), jnp.int8), shape((1,), i32))
     return out
 
 

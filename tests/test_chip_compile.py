@@ -886,3 +886,52 @@ def test_the_train_cells_step_keeps_the_attention_half_and_fits(v5e, on_tpu, mon
     assert 3 * 2.88e9 < memory.argument_size_in_bytes and held < V5E_BYTES_LIMIT
     # the names' 2.83 GB are in the temporaries: the bare checkpoint's are 4.46 GB (transformer.REMAT_TEMP_BYTES)
     assert 4.46e9 + 0.9 * 12 * transformer._kept_bytes(cfg, 2 * 4096) < memory.temp_size_in_bytes
+
+
+# Keye-VL-2.0's language model as `keye-longdoc-closed4` serves it: all 48 layers, 16 of 128 experts held, an eighth
+# of the vocabulary, an indexer of 16 heads x 64 and a top-2,048 selection (learned sparse attention)
+KEYE = dict(
+    vocab_size=18992, n_layers=48, d_model=2048, n_heads=32, n_kv_heads=4, d_head=128, d_ff=6144, d_expert=768,
+    n_experts=128, n_experts_per_tok=8, moe_gated=True, moe_renormalize=True, experts_held=(0, 16), qk_norm=True,
+    qk_norm_per_head=True, rope_theta=1e7, index_topk=2048, index_n_heads=16, index_head_dim=64,
+    param_dtype=jnp.bfloat16,
+)
+KEYE_SLOTS, KEYE_T_MAX = 4, 8704
+
+
+@pytest.fixture
+def sparse_on_tpu(monkeypatch):
+    monkeypatch.setattr(attention, "_platform", lambda: "tpu")  # ops/sparse_attention.py asks the same function
+
+
+def test_the_sparse_decode_step_gathers_the_selected_rows_and_fits(v5e):
+    """The decode step of 4 slots x 8,704: the two stacks of the cache (keys and values in one, the indexer's keys)
+    are written in place, the step holds ONE gather of [4, 2048, 8, 128] a layer (a row's selected keys and values
+    together) and a top-k over [4, 8704], copies nothing of a stack's size, and weights and cache fit the chip."""
+    cfg = transformer.TransformerConfig(**KEYE)
+    compiled, _, cache = _compiled_decode_step(cfg, v5e[0], slots=KEYE_SLOTS, t_max=KEYE_T_MAX, on_kernel=True)
+    assert set(cache) == {"kv", "ki"} and cache["kv"].shape == (48, 4, 8704, 8, 128) and cache["ki"].shape == (48, 4, 64, 8704)
+    text = compiled.as_text()
+    assert len(re.findall(r"= bf16\[4,2048,8,128\]\S* gather\(", text)) == 1
+    assert re.search(r"sort\([^\n]*attn\.select", text) and "decode_attn" not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(math.prod(a.shape) * 2 for a in cache.values())
+    assert memory.temp_size_in_bytes < 64e6
+    assert 13.0e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < V5E_BYTES_LIMIT
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192])
+def test_the_sparse_prefill_goes_through_its_three_kernels_and_fits(v5e, sparse_on_tpu, bucket):
+    """An admit's prefill of the cell's two buckets: `dsa_index`, `dsa_select` and `dsa_flash` once a layer loop, and
+    with the weights, the rows it hands back, its temporaries and the replica's cache beside it under the chip's limit."""
+    cfg = transformer.TransformerConfig(**KEYE)
+    compiled = _compiled_admit_prefill(cfg, bucket, KEYE_T_MAX, v5e[0])
+    text = compiled.as_text()
+    assert all(len(re.findall(rf"custom-call.*{name}", text)) == 1 for name in ("dsa_index", "dsa_select", "dsa_flash"))
+    assert "flash_fwd" not in text
+    memory = compiled.memory_analysis()
+    cache = jax.eval_shape(lambda: generate.init_cache(cfg, KEYE_SLOTS, KEYE_T_MAX))
+    beside = sum(math.prod(a.shape) * 2 for a in cache.values())
+    assert beside == KEYE_SLOTS * KEYE_T_MAX * 104_448
+    held = memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes + beside
+    assert 9.4e9 < memory.argument_size_in_bytes and held < V5E_BYTES_LIMIT
