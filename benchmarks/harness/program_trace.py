@@ -197,7 +197,8 @@ def load(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:
             from . import cluster
 
             with open(os.path.join(cluster.out_dir(), f"{ctx['cell']['name']}.program_trace.json"), "w") as f:
-                json.dump(summary(ctx["program_trace"], known_names(ctx)[1]), f)
+                json.dump(dict(summary(ctx["program_trace"], known_names(ctx)[1]),
+                               trace_stop_s=ctx.get("trace_stop_s"), trace_bytes=ctx.get("trace_bytes")), f)
     return ctx["program_trace"]
 
 
@@ -282,6 +283,22 @@ def _first_device(events) -> List[list]:
     return events["ops"][sorted(events["ops"])[0]] if events["ops"] else []
 
 
+def _once(events: Dict[str, Any], key: str, make):
+    """`make()` worked out once a trace and kept with it under `key`.  A
+    cell's readers ask for the same walks of the device's operations a dozen
+    times over, each a few seconds a million operations: worked out anew every
+    time, they were most of a traced run's time after its window (PERF.md
+    section 6, PR 58).  What is kept is marked with the lists it was made
+    from, so a trace that is copied and given other spans or operations (the
+    tests do) is walked again."""
+    evs, spans = _first_device(events), events["spans"]
+    mark = (id(evs), len(evs), id(spans), len(spans))
+    kept = events.get(key)
+    if kept is None or kept[0] != mark:
+        kept = events[key] = (mark, make())
+    return kept[1]
+
+
 def idle_by_span(events) -> Optional[Dict[str, float]]:
     """Percent of the traced slice (first device operation to last) in which
     the first device ran nothing, each idle instant given to what the pump's
@@ -291,6 +308,10 @@ def idle_by_span(events) -> Optional[Dict[str, float]]:
     open: `llm.pump.*` or nothing).  The four add up to the slice's idle
     share, the number trace_reduce.idle_percent gives.  None without the
     program's spans."""
+    return _once(events, "_idle_by_span", lambda: _idle_by_span(events))
+
+
+def _idle_by_span(events) -> Optional[Dict[str, float]]:
     thread, evs = pump_thread(events), _first_device(events)
     if thread is None or not evs:
         return None
@@ -333,14 +354,23 @@ def self_times(evs: List[list]) -> List[Tuple[float, str, str]]:
     return [(max(t, 0.0), name, scope) for t, name, scope in out]
 
 
+def device_self_times(events) -> List[Tuple[float, str, str]]:
+    """`self_times` of the first device's operations, once a trace."""
+    return _once(events, "_self_times", lambda: self_times(_first_device(events)))
+
+
 def time_by_scope(events) -> Optional[Dict[str, float]]:
     """Self time of the first device's operations by scope, ns: "" for those
     under none, "collective" for the collectives whatever scope asked for
     them (trace_reduce counts those).  None where no operation names a scope
     (an older program, or a runtime that does not write them): `extract` gave
     an operation a scope only where its op_name holds a known one."""
+    return _once(events, "_time_by_scope", lambda: _time_by_scope(events))
+
+
+def _time_by_scope(events) -> Optional[Dict[str, float]]:
     total: Dict[str, float] = {}
-    for t, name, scope in self_times(_first_device(events)):
+    for t, name, scope in device_self_times(events):
         key = "collective" if trace_reduce.is_collective(name) else scope
         total[key] = total.get(key, 0.0) + t
     return total if any(k not in ("", "collective") for k in total) else None
@@ -371,7 +401,7 @@ def kernel_of(name: str, kernels: Tuple[str, ...] = KERNELS) -> str:
 def kernel_percent(events, kernels: Tuple[str, ...] = KERNELS) -> Optional[float]:
     """Share of the first device's busy time inside the named Pallas kernels;
     None where the trace holds none of them."""
-    times = self_times(_first_device(events))
+    times = device_self_times(events)
     hit = [t for t, name, _ in times if kernel_of(name, kernels)]
     return 100.0 * sum(hit) / sum(t for t, _, _ in times) if hit else None
 

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import glob
 import os
+import socket
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -71,24 +73,73 @@ class CompileCounter:
             self.events.append((time.monotonic(), float(duration)))
 
 
-def start_trace(trace_dir: str) -> None:
+def _session_class():
+    """The profiler session class whose `stop()` hands back the profile, or
+    None where the installed JAX has none."""
+    try:
+        from jax._src.lib import _profiler
+
+        return _profiler.ProfilerSession if hasattr(_profiler.ProfilerSession, "stop") else None
+    except (ImportError, AttributeError):
+        return None
+
+
+def trace_options():
     import jax
 
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0  # the Python tracer alone slows the host severalfold
     opts.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    return opts
 
 
-def stop_trace(trace_dir: str) -> str:
-    """Returns the path of the trace that was written."""
+def start_trace(trace_dir: str):
+    """What `jax.profiler.start_trace` does, short of the module's state: the
+    session is the caller's to hand to `stop_trace`, which can then end it
+    without the export.  None where this JAX has no such session: the trace is
+    then `jax.profiler`'s own, started here."""
     import jax
 
-    jax.profiler.stop_trace()
-    found = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not found:
-        raise RuntimeError(f"the profiler wrote no trace under {trace_dir}")
-    return found[-1]
+    opts = trace_options()
+    cls = _session_class()
+    if cls is None:
+        print("[bench] this JAX has no ProfilerSession.stop(): jax.profiler's own start and stop, "
+              "which export a .trace.json.gz too", file=sys.stderr, flush=True)
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        return None
+    # the backend before the session: on a TPU the tracer otherwise starts before
+    # libtpu does, and the profile holds no device operation
+    jax.devices()
+    return cls(opts)
+
+
+def stop_trace(trace_dir: str, session) -> str:
+    """Ends the trace `start_trace` began and returns the path of its profile,
+    `<trace_dir>/plugins/profile/<stamp>/<host>.xplane.pb`: where
+    `jax.profiler.stop_trace()` puts it, and nothing beside it.  The session's
+    `stop()` (checked on JAX 0.9.0) returns the serialized profile and writes
+    nothing, where `stop_trace()` always exports a `<host>.trace.json.gz` as
+    well, at about 2 s a MB of profile: a viewer's file that no reader here
+    opens, and that whoever wants a viewer can make from the `.xplane.pb` off
+    line.  Without a session (a JAX that has none) this is
+    `jax.profiler.stop_trace()`."""
+    if session is None:
+        import jax
+
+        jax.profiler.stop_trace()
+        found = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        if not found:
+            raise RuntimeError(f"the profiler wrote no trace under {trace_dir}")
+        return found[-1]
+    profile = session.stop()
+    if not profile:
+        raise RuntimeError("the profiler's session gave an empty profile")
+    run_dir = os.path.join(trace_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S"))
+    os.makedirs(run_dir, exist_ok=True)
+    path = os.path.join(run_dir, socket.gethostname() + ".xplane.pb")
+    with open(path, "wb") as f:
+        f.write(profile)
+    return path
 
 
 class BenchIngress(StreamingLLMIngress):
@@ -109,6 +160,7 @@ class BenchIngress(StreamingLLMIngress):
         # bench_id -> request_id of the check streams, which `bench_check` hands to the
         # reference; None once it has run, so the window's requests leave nothing here
         self._check_ids: Optional[Dict[str, int]] = {}
+        self._trace_session = None  # the profiler's, between bench_trace's "start" and "stop"
         cb = self.cb
         admit_inner, step_inner = cb._admit, cb.step
         annotate = jax.profiler.TraceAnnotation
@@ -197,9 +249,9 @@ class BenchIngress(StreamingLLMIngress):
 
     def bench_trace(self, action: str, trace_dir: str) -> str:
         if action == "start":
-            start_trace(trace_dir)
+            self._trace_session = start_trace(trace_dir)
             return ""
-        return stop_trace(trace_dir)
+        return stop_trace(trace_dir, self._trace_session)
 
     def bench_collect(self) -> Dict[str, Any]:
         """Everything recorded so far, and the device's memory peak."""

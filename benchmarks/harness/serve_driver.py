@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
@@ -118,24 +118,31 @@ def measure(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
     plan = loadgen.make_plan(cell, seconds, seed, vocab)
     t_open = time.monotonic() + traffic["ramp_s"] + 0.25
     setup_s = t_open - t_start
-    def traced_slice() -> str:
+    def traced_slice() -> Tuple[str, float]:
+        """The profile's path and the seconds the stop took.  The waits are
+        backstops that end a run that hangs, not budgets: the stop returns when
+        the profile is collected and written (25-36 s for 51-68 MB: PERF.md, PR 58), and
+        the same waits cover `jax.profiler`'s own export where a JAX takes
+        `stop_trace`'s fallback (100-160 s for those profiles: PERF.md, PR 56)."""
         tdir = cluster.trace_dir(cell["name"])
         time.sleep(max(0.0, t_open + seconds - TRACE_SLICE_S - time.monotonic()))
         handle.bench_trace.remote("start", tdir).result(timeout_s=60)
         time.sleep(max(0.0, t_open + seconds - time.monotonic()))
-        return handle.bench_trace.remote("stop", tdir).result(timeout_s=120)
+        t_stop = time.monotonic()
+        path = handle.bench_trace.remote("stop", tdir).result(timeout_s=300)
+        return path, time.monotonic() - t_stop
 
     with ThreadPoolExecutor(max_workers=1) as pool:
         tracing = pool.submit(traced_slice) if trace else None
         records = loadgen.send(cell, HOST, port, ROUTE, plan, seconds, t_open)
-        trace_path = tracing.result(timeout=300) if tracing else None
+        trace_path, trace_stop_s = tracing.result(timeout=480) if tracing else (None, None)
     replica = handle.bench_collect.remote().result(timeout_s=60)
     serve.shutdown()
     cluster.wait_tpu_workers_gone()
     return {
         "cell": cell, "kind": kind, "seconds": float(seconds), "t_open": t_open,
         "setup_s": setup_s, "records": records, "replica": replica, "check": check,
-        "device": replica["device"], "trace_path": trace_path, "chips": 1,
+        "device": replica["device"], "trace_path": trace_path, "trace_stop_s": trace_stop_s, "chips": 1,
     }
 
 

@@ -104,13 +104,15 @@ def train_loop(config: Dict[str, Any]) -> None:
     while time.monotonic() - t_open < seconds:
         steps.append(one_step(next_batch()))
     t_close = steps[-1][0]
-    trace_path = None
+    trace_path = trace_stop_s = None
     watch.mark("after")
     if config["trace_dir"]:
-        start_trace(config["trace_dir"])
+        session = start_trace(config["trace_dir"])
         for _ in range(TRACE_STEPS):
             one_step(next_batch())
-        trace_path = stop_trace(config["trace_dir"])
+        t_stop = time.monotonic()
+        trace_path = stop_trace(config["trace_dir"], session)
+        trace_stop_s = time.monotonic() - t_stop
     watch.stop()
     train.report({
         "stalls": watch.report(),
@@ -118,6 +120,7 @@ def train_loop(config: Dict[str, Any]) -> None:
             x.addressable_shards[0].data.nbytes for x in jax.tree_util.tree_leaves((params, opt_state))
         ), "t_open": t_open, "t_close": t_close, "steps": steps,
         "warm": warm, "ref_loss": ref_loss, "trace_path": trace_path,
+        "trace_stop_s": trace_stop_s,
         "compiles_in_window": len(compiles.events) - n_compiles,
         "loop_s": time.monotonic() - t_loop,
         "tokens_per_step": job["batch"] * job["seq"],
@@ -172,7 +175,7 @@ def measure(cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         "setup_s": rep["t_open"] - t_start, "fit_s": fit_s, "loop_s": rep["loop_s"],
         "steps": rep["steps"], "warm": rep["warm"], "ref_loss": rep["ref_loss"],
         "train_tok_s": len(rep["steps"]) * rep["tokens_per_step"] / window_s,
-        "window_s": window_s, "trace_path": rep["trace_path"],
+        "window_s": window_s, "trace_path": rep["trace_path"], "trace_stop_s": rep["trace_stop_s"],
         "compiles_in_window": rep["compiles_in_window"], "restarts": rep["restarts"],
         "stalls": rep["stalls"], "state_bytes_on_device_0": rep["param_bytes_on_device_0"],
         "check": {

@@ -76,7 +76,7 @@ def read(ctx, what):
     ref = manifest.reference_of(ctx["cell"])
     if not hasattr(ref, "dsa_prefill_flops"):
         return None
-    by = _by_program(program_trace.self_times(program_trace._first_device(events)))
+    by = _by_program(program_trace.device_self_times(events))
     under = lambda kind, scopes: sum(by[kind].get(scope, 0.0) for scope in scopes)
     config = ctx["cell"]["config_file"]["config"]
     layers = config["num_hidden_layers"]

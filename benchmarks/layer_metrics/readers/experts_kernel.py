@@ -25,7 +25,7 @@ def read(ctx, share_of):
     if not events or "cell" not in ctx:
         return None
     ref = manifest.reference_of(ctx["cell"])
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     kernels = sum(t for t, name, _ in times if program_trace.kernel_of(name, tuple(ref.KERNELS)))
     if not kernels:
         return None

@@ -33,7 +33,7 @@ def read(ctx, share_of="hbm_roofline"):
     if not events or "cell" not in ctx:
         return None
     ref = manifest.reference_of(ctx["cell"])
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     experts_ns = sum(t for t, name, scope in times
                      if scope == "moe.experts" or program_trace.kernel_of(name, tuple(ref.KERNELS)))
     if not experts_ns:

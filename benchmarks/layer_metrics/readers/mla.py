@@ -34,7 +34,7 @@ def read(ctx, what):
     ref = manifest.reference_of(ctx["cell"])
     if not hasattr(ref, "mla_core_bytes"):
         return None
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     if not any(scope in ("attn.mla.expand", "attn.mla.absorb") for _, _, scope in times):
         return None
     if what == "expand_share":

@@ -20,7 +20,7 @@ def read(ctx):
     if not events or "cell" not in ctx:
         return None
     steps = [s[4] for s in program_trace.spans_named(events, "llm.step") if "block_rows" in s[4]]
-    busy_ns = sum(t for t, _, _ in program_trace.self_times(program_trace._first_device(events)))
+    busy_ns = sum(t for t, _, _ in program_trace.device_self_times(events))
     if not steps or not busy_ns:
         return None
     cell, ref = ctx["cell"], manifest.reference_of(ctx["cell"])

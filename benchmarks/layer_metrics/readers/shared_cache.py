@@ -29,7 +29,7 @@ def read(ctx):
     ref = manifest.reference_of(ctx["cell"])
     if not hasattr(ref, "shared_cache_step_bytes"):
         return None
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     core_ns = sum(t for t, _, scope in times if scope in SCOPES)
     read_rows = [float(s[4]["shared_rows_read"]) for s in program_trace.spans_named(events, "llm.step")
                  if "shared_rows_read" in s[4]]

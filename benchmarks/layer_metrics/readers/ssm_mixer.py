@@ -28,7 +28,7 @@ def read(ctx):
     ref = manifest.reference_of(ctx["cell"])
     if not hasattr(ref, "mixer_step_bytes"):
         return None
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     mixer_ns = sum(t for t, _, scope in times if scope.startswith("ssm."))
     steps = [s for s in program_trace.spans_named(events, "llm.step") if "ssm_state_bytes" in s[4]]
     if not mixer_ns or not steps:

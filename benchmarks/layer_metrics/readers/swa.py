@@ -29,7 +29,7 @@ def read(ctx, what):
     ref = manifest.reference_of(ctx["cell"])
     if not hasattr(ref, "swa_flash_flops"):
         return None
-    times = program_trace.self_times(program_trace._first_device(events))
+    times = program_trace.device_self_times(events)
     kernel_ns = sum(t for t, name, _ in times if program_trace.kernel_of(name, tuple(ref.WINDOW_KERNELS)))
     lengths = [int(s[4]["prompt_len"]) for s in program_trace.spans_named(events, "llm.admit") if "prompt_len" in s[4]]
     if not kernel_ns or not lengths:

@@ -44,6 +44,9 @@ def result_line(cell, driver, ctx, trace: bool):
     out = dict(driver.outcome(ctx))
     first_failure = out.pop("first_failure", None)
     if trace:
+        # what the profile cost: a cell whose profile grows is seen here before its stop is late
+        ctx["trace_bytes"] = os.path.getsize(ctx["trace_path"])
+        say(trace_stop_s=ctx["trace_stop_s"], trace_bytes=ctx["trace_bytes"])
         events = trace_reduce.extract(ctx["trace_path"])
         ctx["trace"] = events
         busy = trace_reduce.busy(events)

@@ -221,6 +221,27 @@ def test_the_long_documents_mix_gives_every_seed_the_same_work():
         assert "caller_sizes" not in json.load(open(os.path.join(manifest.BENCH_DIR, "traffic", other + ".json")))
 
 
+@pytest.mark.parametrize("seed", [1, 7, 2_580_000_101, 2_999_999_999, 3_000_000_019])
+def test_the_whole_documents_mix_holds_the_same_work_in_every_window(seed):
+    """`longdoc-closed`: a request is 6 s, so a 51 s window reaches 8 requests a caller, and a
+    caller goes round 8 quantiles: whatever the seed and wherever a window begins, any 8
+    requests in a row of a caller are the same 8 (prompt, output) sizes."""
+    cell = manifest.load_cell("keye-longdoc-closed4")
+    mix = cell["traffic_file"]
+    assert (mix["caller_sizes"], mix["caller_requests"], mix["caller_rounds"]) == ("quantiles", 8, 24)
+    plan, other = (loadgen.make_plan(cell, 51.0, s, 19200) for s in (seed, seed + 1))
+    assert len(plan) == cell["callers"] == 4 and all(len(e["requests"]) == 192 for e in plan)
+    for mine, theirs in zip(plan, other):
+        whole = sorted(_sizes(mine)[:8])
+        assert all(sorted(_sizes(e)[k:k + 8]) == whole for e in (mine, theirs) for k in range(185))
+        assert sorted(p for p, _ in whole) == [3072, 3753, 4315, 4846, 5410, 6075, 6984, 8192]
+        assert sorted(o for _, o in whole) == [89, 123, 150, 177, 208, 245, 299, 413]
+        assert _sizes(mine)[:8] != _sizes(theirs)[:8]  # the order is the seed's
+    # the pairing of prompts and outputs is a caller's own, from `shape_seed`: the same for every seed
+    assert len({tuple(sorted(_sizes(e)[:8])) for e in plan}) > 1
+    assert [sorted(_sizes(e)[:8]) for e in plan] == [sorted(_sizes(e)[:8]) for e in other]
+
+
 def test_a_configuration_may_name_one_draw_of_its_weights():
     assert serve_driver.weights_seed({}, 3_000_000_019) == 3_000_000_019 % 2 ** 31
     assert serve_driver.weights_seed({"weights": {"seed": 12}}, 3_000_000_019) == 12
@@ -228,7 +249,11 @@ def test_a_configuration_may_name_one_draw_of_its_weights():
     assert {serve_driver.weights_seed(held, s) for s in (1, 2_900_000_801)} == {2_540_000_222 % 2 ** 31}
     for name in sorted(os.listdir(os.path.join(manifest.BENCH_DIR, "configs"))):
         doc = json.load(open(os.path.join(manifest.BENCH_DIR, "configs", name)))
-        assert ("weights" in doc) == (name == "k-exaone-236b-a23b-ep16-serve1.json")
+        # the two cells whose spread between seeds was the held experts' load by the draw (PR 54, PR 58)
+        assert ("weights" in doc) == (name in ("k-exaone-236b-a23b-ep16-serve1.json",
+                                               "nemotron-3-nano-30b-a3b-ep8-serve1.json"))
+    held = manifest.load_cell("nemotron3nano-reason-closed8")["config_file"]
+    assert {serve_driver.weights_seed(held, s) for s in (1, 2_900_000_801)} == {2_540_000_402 % 2 ** 31}
 
 
 def test_closed_loop_keeps_each_caller_to_one_request_due_at_its_last_token():
@@ -686,10 +711,10 @@ def test_the_rename_tables_are_whole():
         "axk1-rag-closed6", "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8"}
     assert {r["now"] for r in RENAMES[54] if "args_now" in r} == {"ssm_scan_share.ssm", "held_assignments_share.mla"}
     assert not {r["name"] for r in RENAMES[54]} & {r["now"] for r in RENAMES[38] + RENAMES[54]}
-    # a metric is one entry and one file (74 of each when PR 38's copies went, 127 before PR 54's, 103 after),
-    # and the list has room for a configuration's own
+    # a metric is one entry and one file (74 of each when PR 38's copies went, 127 before PR 54's, 103 after,
+    # 111 with PR 56's family): at least those that were there then, at most what the list may hold
     files = [f for f in os.listdir(os.path.join(manifest.BENCH_DIR, "layer_metrics")) if f.endswith(".json")]
-    assert 74 <= len(DOC["per_layer"]) == len(files) <= 105
+    assert 74 <= len(DOC["per_layer"]) == len(files) <= 128
 
 
 def _hand_ctx(cell):

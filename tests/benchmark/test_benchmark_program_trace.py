@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from benchmarks.harness import manifest, program_trace, trace_reduce
+from benchmarks.harness import cluster, manifest, program_trace, trace_reduce
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 DOC = manifest.load_manifest()
@@ -103,6 +103,30 @@ def test_a_parent_operation_is_not_counted_beside_its_body():
     assert program_trace.scope_percent(events, ["attn."]) == pytest.approx(100 * 350 / 750)
     assert program_trace.scope_percent(events, ["ffn", "loss"]) == pytest.approx(100 * 100 / 750)
     assert program_trace.kernel_percent(events) == pytest.approx(100 * 50 / 750)
+
+
+def test_the_walks_of_a_trace_are_made_once_and_read_what_they_read(monkeypatch):
+    """A cell's readers ask for the device's self times, the time by scope and
+    the idle share by span a dozen times over: each is worked out once a trace,
+    kept beside it, and is what working it out anew gives."""
+    for events in (by_hand(), recorded()):
+        plain = program_trace.self_times(program_trace._first_device(events))
+        by_scope, idle = program_trace._time_by_scope(copy.deepcopy(events)), program_trace._idle_by_span(events)
+        walks, inner = [], program_trace.self_times
+        monkeypatch.setattr(program_trace, "self_times", lambda evs: walks.append(len(evs)) or inner(evs))
+        for _ in range(3):
+            assert program_trace.device_self_times(events) == plain
+            assert program_trace.time_by_scope(events) == by_scope and program_trace.idle_by_span(events) == idle
+            assert program_trace.kernel_percent(events) == (
+                100.0 * sum(t for t, n, _ in plain if program_trace.kernel_of(n)) / sum(t for t, _, _ in plain)
+                if any(program_trace.kernel_of(n) for _, n, _ in plain) else None)
+        assert walks == [len(plain)]
+        monkeypatch.setattr(program_trace, "self_times", inner)
+        # the recorded form of a trace leaves what was kept behind, and a copy given other operations is walked again
+        assert set(program_trace.head(events, 0.1)) == {"spans", "ops"}
+        other = copy.deepcopy(events)
+        other["ops"] = {k: v[:1] for k, v in other["ops"].items()}
+        assert program_trace.device_self_times(other) == inner(program_trace._first_device(other)) != plain
 
 
 def test_spans_by_name_argument_and_gap():
@@ -233,6 +257,111 @@ def test_op_names_reads_the_table_of_operations_from_the_file(tmp_path):
         "%fusion.1 = bf16[8] fusion()": "jit(f)/while/body/attn.core/dot_general:",
         "%fusion.2 = bf16[8] fusion()": "jit(f)/ffn/mul:",
     }}
+
+
+def _profiled(tmp_path, start, stop):
+    """A few jitted calls under the benchmark's annotation and the program's
+    span, between `start(dir)` and `stop(dir, what start gave)`."""
+    import jax
+    import jax.numpy as jnp
+
+    from cluster_anywhere_tpu.util import tracing
+
+    step = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((32, 32))
+    step(x).block_until_ready()  # compiled before either profile
+    os.makedirs(str(tmp_path), exist_ok=True)
+    session = start(str(tmp_path))
+    try:
+        for live in (3, 2, 1):
+            sp = tracing.span("llm.step")
+            with jax.profiler.TraceAnnotation("decode_step"), sp:
+                sp.set(live=live)
+                step(x).block_until_ready()
+    finally:  # a process holds one profile at a time: a test that fails leaves none open
+        path = stop(str(tmp_path), session)
+    return path
+
+
+def _what_the_readers_see(path):
+    """Planes, the operations' names (the CPU client writes an operation as an
+    event of its own threads' lines), and both readers' extractions less their
+    clock."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    ops = {e.name for p in data.planes for ln in p.lines if ln.name.startswith("tf_XLA") for e in ln.events}
+    reduced, program = trace_reduce.extract(path), program_trace.extract(path)
+    return {"planes": [p.name for p in data.planes], "ops": ops,
+            "host": [e[2] for e in reduced["host"]], "devices": reduced["devices"],
+            "spans": [s[3:] for s in program["spans"]], "program_ops": program["ops"]}
+
+
+def _jax_profile(tmp_path):
+    """The same calls under `jax.profiler`'s own start and stop, with the harness's options."""
+    import jax
+
+    from benchmarks.harness import replica
+
+    _profiled(tmp_path, lambda trace_dir: jax.profiler.start_trace(trace_dir, profiler_options=replica.trace_options()),
+              lambda trace_dir, _: jax.profiler.stop_trace())
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    return path
+
+
+def test_the_harness_stops_its_own_session_and_leaves_the_profile_alone(tmp_path, monkeypatch, capfd):
+    """`replica.start_trace` / `stop_trace`: one `.xplane.pb` where the profiler
+    puts it and no `.trace.json.gz` beside it, and both readers make of it what
+    they make of one `jax.profiler.stop_trace()` wrote from the same calls."""
+    from benchmarks.harness import replica
+
+    path = _profiled(tmp_path / "own", replica.start_trace, replica.stop_trace)
+    assert glob.glob(os.path.join(str(tmp_path / "own"), "plugins", "profile", "*", "*.xplane.pb")) == [path]
+    assert os.listdir(os.path.dirname(path)) == [os.path.basename(path)] and os.path.getsize(path) > 0
+    theirs = _jax_profile(tmp_path / "jax")
+    assert sorted(f.split(".", 1)[1] for f in os.listdir(os.path.dirname(theirs))) == ["trace.json.gz", "xplane.pb"]
+    assert os.path.basename(theirs) == os.path.basename(path)  # <host>.xplane.pb
+    mine, jaxs = _what_the_readers_see(path), _what_the_readers_see(theirs)
+    assert mine == jaxs
+    assert mine["host"] == ["decode_step"] * 3 and mine["spans"] == [["llm.step", {"live": n}] for n in (3, 2, 1)]
+    assert "/host:CPU" in mine["planes"] and any(n.startswith("dot") for n in mine["ops"])
+    assert "ProfilerSession" not in capfd.readouterr().err  # the fallback was not taken
+    # what the stop cost goes with the summary the builder reads, which no reader reads back
+    monkeypatch.setattr(cluster, "out_dir", lambda: str(tmp_path))
+    ctx = {"cell": manifest.load_cell("chat-closed6"), "trace_path": path, "trace_stop_s": 0.25,
+           "trace_bytes": os.path.getsize(path)}
+    assert [s[3] for s in program_trace.load(ctx)["spans"]] == ["llm.step"] * 3
+    with open(tmp_path / "chat-closed6.program_trace.json") as f:
+        left = json.load(f)
+    assert (left["trace_stop_s"], left["trace_bytes"]) == (0.25, ctx["trace_bytes"]) and left["spans"]["llm.step"]["count"] == 3
+
+
+def test_a_jax_without_the_sessions_stop_takes_jax_profilers_own(tmp_path, monkeypatch, capfd):
+    """The session class as an older JAX has it (no `stop()`): the harness says
+    so and the trace is `jax.profiler`'s, export and all; the path it returns
+    is the profile's all the same."""
+    from jax._src.lib import _profiler
+
+    from benchmarks.harness import replica
+
+    real = _profiler.ProfilerSession
+
+    class Older:
+        def __init__(self, *args):
+            self._session = real(*args)
+
+        def stop_and_export(self, log_dir):
+            self._session.stop_and_export(log_dir)
+
+    monkeypatch.setattr(_profiler, "ProfilerSession", Older)
+    assert replica._session_class() is None
+    path = _profiled(tmp_path, replica.start_trace, replica.stop_trace)
+    assert "no ProfilerSession.stop()" in capfd.readouterr().err
+    assert sorted(f.split(".", 1)[1] for f in os.listdir(os.path.dirname(path))) == ["trace.json.gz", "xplane.pb"]
+    assert _what_the_readers_see(path)["host"] == ["decode_step"] * 3
+    # and where the module has no such class at all
+    monkeypatch.delattr(_profiler, "ProfilerSession")
+    assert replica._session_class() is None
 
 
 def test_extract_finds_the_programs_spans_in_a_trace_the_profiler_wrote(tmp_path):
