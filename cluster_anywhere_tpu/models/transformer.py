@@ -46,6 +46,7 @@ __graft_entry__.py and what benchmarks/ trains and serves.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -86,8 +87,10 @@ class TransformerConfig:
     num_microbatches: int = 1
     # recompute what does not fit: every block is a `jax.checkpoint` that keeps its input and runs
     # forward again in the backward pass, all of it or, where the device's memory has room beside
-    # the step's state (`_remat_keeps` decides as the step is traced), only the two norms and the
-    # FFN half: the attention half's q, k, v, the core's result and h are kept (KEPT_NAMES)
+    # the step's state (`_remat_keeps` decides layer by layer as the step is traced, from the shapes,
+    # the mesh and the device's limit), only the two norms and the FFN half: the attention half's q,
+    # k, v, the core's result and h are kept (KEPT_NAMES), and in as many of the last layers as the
+    # rest of the room holds the dense FFN's two up products too (FFN_NAMES)
     remat: bool = False
     # unroll the layer scan: XLA overlaps each layer's weight streaming with
     # the previous layer's compute across iteration boundaries (a rolled
@@ -1477,22 +1480,25 @@ def _mamba2_mixer(bp, x, cfg: TransformerConfig, state, keep=None):
         return gated @ bp["ssm_out"].astype(x.dtype), (window, h_new)
 
 
-def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset()):
+def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset(), keep: bool = False):
     """A block's FFN over what the block gives it, y [B, T, E]: dense SwiGLU or
     a mixture of experts (by what `bp` holds: a router or none), the mixture
     with the shared experts' MLP beside it where the configuration has any
     (gated, or the experts' own activation without a gate: by what `bp` holds).  On one device the mixture is the dropless routed path (`_moe`, which
     says what `live` and `experts` are); with 'ep' among the caller's manual
     axes its experts are sharded and tokens travel to them (parallel/moe.py
-    moe_ffn).  Returns (out [B, T, E], aux loss, experts touched): None for a
-    dense model, and no count from moe_ffn."""
+    moe_ffn).  keep: the block is a checkpoint that keeps FFN_NAMES, which the
+    dense FFN's two up products are given here (a mixture's experts have none).
+    Returns (out [B, T, E], aux loss, experts touched): None for a dense model,
+    and no count from moe_ffn."""
     from ..parallel.moe import ACTIVATIONS
 
     b, t, e = y.shape
     dt = y.dtype
     with jax.named_scope("ffn"):
         if "router" not in bp:  # a dense model's block, or a mixture's leading dense layer
-            gated = jax.nn.silu(y @ bp["w_gate"].astype(dt)) * (y @ bp["w_up"].astype(dt))
+            named = lambda a, name: checkpoint_name(a, name) if keep else a
+            gated = jax.nn.silu(named(y @ bp["w_gate"].astype(dt), "ffn.gate")) * named(y @ bp["w_up"].astype(dt), "ffn.up")
             return gated @ bp["w_down"].astype(dt), None, None
         if "ep" not in manual_axes:
             out, aux, touched = _moe(bp, y, cfg, live, experts)
@@ -1521,21 +1527,22 @@ def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=fro
         return r.out.reshape(b, t, e), r.aux_loss.astype(jnp.float32), None
 
 
-def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset(), kind=None):
+def _ffn_half(bp, x, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset(), kind=None,
+              keep: bool = False):
     """A block's second half: x + FFN(norm(x)), or under `cfg.norm_output`
     x + norm(FFN(x)); `_ffn` says what the FFN is and what the other arguments
     are.  kind: the layer's, where the caller serves more than one: attention
     alone ("attn_alone": HALF_KINDS) has no second half and x comes back as it
-    is.  Returns (x, aux loss, experts touched)."""
+    is; keep: `_ffn`'s.  Returns (x, aux loss, experts touched)."""
     if kind == "attn_alone":
         return x, None, None
     if cfg.norm_output:
-        out, aux, touched = _ffn(bp, x, cfg, live, experts, manual_axes)
+        out, aux, touched = _ffn(bp, x, cfg, live, experts, manual_axes, keep)
         with jax.named_scope("norm"):
             return x + _norm(out, bp, "ln2", cfg), aux, touched
     with jax.named_scope("norm"):
         y = _norm(x, bp, "ln2", cfg)
-    out, aux, touched = _ffn(bp, y, cfg, live, experts, manual_axes)
+    out, aux, touched = _ffn(bp, y, cfg, live, experts, manual_axes, keep)
     return x + out, aux, touched
 
 
@@ -1617,16 +1624,21 @@ def _gmu_block(bp, s, cfg: TransformerConfig, live=None):
 # residuals the flash kernel's forward rule makes (ops/attention.py).  Outside a checkpoint a name
 # is the identity and lowers to nothing; the programs that serve (models/generate.py) trace none.
 KEPT_NAMES = ("attn.q", "attn.k", "attn.v", "attn.h", FLASH_OUT, FLASH_LSE)
+# What a block that has kept those keeps of a dense FFN beside them, in the layers where the room goes on
+# (`_remat_keeps` counts them): the two up products `y @ w_gate` and `y @ w_up` as `_ffn` makes them,
+# after which its backward pass makes again the two norms, `_gqa_repeat` and the gate alone.
+FFN_NAMES = ("ffn.gate", "ffn.up")
 
 
 def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), kind: str = "attn",
-                   layer=None, keep: bool = False):
+                   layer=None, keep: bool = False, keep_ffn: bool = False):
     """One transformer block. s: x [B, T_local, E], or `Carried`.  manual_axes:
     the mesh axes the caller's shard_map is already manual over (pp/sp/ep
     subset); kind: the layer's (a window layer attends to its window: forward
     only on a TPU; a cross layer to the keys and values the loop carries, which
     the full layer before it left there); layer: its number among its kind;
-    keep: the block is a checkpoint that keeps KEPT_NAMES, which are given here.
+    keep: the block is a checkpoint that keeps KEPT_NAMES, which are given here;
+    keep_ffn: and FFN_NAMES (`_ffn`).
     Returns (what it hands on, the MoE load-balance loss: 0 dense)."""
     x = _x(s)
     t = x.shape[1]
@@ -1652,7 +1664,7 @@ def _block_forward(bp, s, cfg: TransformerConfig, mesh=None, manual_axes=frozens
     x, (k, v) = _attention_half(bp, x, cfg, offset + jnp.arange(t), core, kind, layer)
     if keep:
         x = checkpoint_name(x, "attn.h")
-    x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes, kind=kind)
+    x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes, kind=kind, keep=keep_ffn)
     made = dict(k=k, v=v) if kind == "attn" else {}
     return _hand_on(s, x, **made), jnp.zeros((), jnp.float32) if aux is None else aux
 
@@ -1680,14 +1692,16 @@ def layer_stacks(params) -> Dict[str, Any]:
     return {kind: blocks for kind, blocks in stacks.items() if blocks is not None}
 
 
-def _layer_runs(kinds):
+def _layer_runs(kinds, before=()):
     """[(kind, the run's first layer counted among its kind, its length)] for
     each maximal run of one kind: 7 ssm, attn, 13 ssm, attn, 6 ssm.  Where
     kinds alternate (a layer's kind is not the next one's) and a period of
     distinct kinds comes at least twice in a row, the run is the period:
     ((its kinds), (each one's first layer among its kind), the repetitions);
-    [ssm, attn_win] x 8, ssm, attn, [gmu, attn_cross] x 7 is four runs, not 32."""
-    runs, seen, i = [], {}, 0
+    [ssm, attn_win] x 8, ssm, attn, [gmu, attn_cross] x 7 is four runs, not 32.
+    before: the kinds of the layers that stand before these, which a kind's
+    layers are counted from."""
+    runs, seen, i = [], dict(collections.Counter(before)), 0
     while i < len(kinds):
         period, n = (kinds[i],), 1
         while kinds[i + n:i + n + 1] == period:
@@ -1743,7 +1757,7 @@ def _run_groups(runs):
     return groups
 
 
-def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unroll=1, indexed=False):
+def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unroll=1, indexed=False, span=None):
     """The layer loop of every program: each maximal run of one kind of layer
     is one `lax.scan` of `body(kind, carry, bp, held, layer) -> (carry, ys)`;
     a model of one kind is one run, and a period of alternating kinds that
@@ -1751,7 +1765,10 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     A sequence of runs that repeats is one scan of the sequence, its runs the
     loops inside it (`_run_groups`): a program is compiled a loop body at a
     time, and its size and the time to compile it go by the bodies it holds.
-    stacks: `layer_stacks`.  Returns (carry, {kind: ys over that kind's layers}).
+    stacks: `layer_stacks`; span: (the first, one past the last) of the model's
+    layers that this call runs, None for all (a loop whose body changes at a
+    layer is two calls: one scan traces its body once).  Returns (carry, {kind:
+    ys over that kind's layers}).
 
     What a program keeps from one call to the next (a cache) is part of
     `carry`, through every run of every kind, as whole stacks [n_kind, ...]
@@ -1765,12 +1782,17 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     A run that is its kind's whole stack scans the stacks themselves.  A
     shorter run scans its layers' indices and reads each layer's parameters
     where they lie: a slice of a stack handed to a loop is a copy of those
-    layers at every call (1.3 GB for thirteen state-space layers).  The names
+    layers at every call (1.3 GB for thirteen state-space layers); a loop that
+    is unrolled whole takes the slice all the same: there each layer's slice of
+    it is a slice of the stack, and the gradients come stacked as the
+    optimizer takes them, where the indices' come scattered into a stack of
+    zeros a layer.  The names
     `unsliced` lists for a kind ({kind: names}: a mixture's experts, which a
     kernel reads) are never sliced by either: `held` is their stacks, to be
     read at `layer`, empty where there are none.  `layer` is None where nothing
     asked for it."""
     kinds = cfg.layer_kinds
+    first, last = span or (0, len(kinds))
     outs: Dict[str, list] = {}
 
     def scan_run(carry, period, starts, n, ahead=None):
@@ -1783,13 +1805,15 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
         for j, (kind, start) in enumerate(zip(period, starts)):
             blocks = stacks[kind]
             total = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-            if len(set(kinds)) == 1:
+            if len(set(kinds)) == 1 and span is None:
                 n = total  # one kind: the stack given is the run (a pipeline stage holds its share)
             held = {k: blocks[k] for k in (unsliced or {}).get(kind, ()) if k in blocks}
             rest = {k: v for k, v in blocks.items() if k not in held}
             members.append((kind, held, rest))
             if n == total:
                 xs[f"bp{j}"] = rest
+            elif unroll is True and ahead is None:
+                xs[f"bp{j}"] = jax.tree_util.tree_map(lambda w: w[start:start + n], rest)
             if ahead is not None:
                 xs[f"layer{j}"] = start + ahead[j] + jnp.arange(n)
             elif held or indexed or n != total:
@@ -1809,7 +1833,7 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
         return carry, list(zip(period, ys))
 
     join = lambda *parts: parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
-    for runs, reps in _run_groups(_layer_runs(kinds)):
+    for runs, reps in _run_groups(_layer_runs(kinds[first:last], kinds[:first])):
         if reps == 1:
             (run,) = runs
             carry, made = scan_run(carry, *run)
@@ -1836,16 +1860,15 @@ def _scan_layers(body, carry, stacks, cfg: TransformerConfig, unsliced=None, unr
     return carry, {kind: jax.tree_util.tree_map(join, *runs) for kind, runs in outs.items()}
 
 
-# The bytes of a device's memory that `_remat_keeps` leaves to a train step's own temporaries.  The
-# chip's compiler counts 4.46 GB of them for the bare checkpoint of `train-fsdp4`'s step (Mistral-7B's
-# widths 12 layers deep, 2 x 4,096 rows and a quarter of the float32 weights and moments a chip:
-# `memory_analysis().temp_size_in_bytes` of the step compiled for a described v5e 2x2, PERF.md
-# section 6, PR 53): the gradients before the optimizer has taken them, 2.89 GB, the logits in
-# cfg.dtype and in float32, 1.6 GB, a layer's recomputed FFN.  The same step with KEPT_NAMES counts
-# 7.28 GB, 2.81 more, where `_kept_bytes` says 2.83: 15.94 GB with its 8.66 of state, of the 16.91 a
-# v5e reports.  A device's `peak_bytes_in_use` does not hold a program's temporaries (8.50 GB with
-# and without the names, a step of 2 layers on one chip), so the compiler's count is the one there is.
-REMAT_TEMP_BYTES = 9 * 2 ** 29
+# The share of a device's memory that `_remat_keeps` leaves unspent: 1 / REMAT_MARGIN of its limit, for what
+# the compiler holds that the shapes do not say (under fsdp the gathered weights of the layers in flight and
+# their gradients before they are scattered, a fusion's own temporaries).  With less the chip's compiler, short
+# of room, makes values again on its own, a layer's FFN products among them (PERF.md section 6, PRs 57 and 59).
+REMAT_MARGIN = 64
+
+# The logits one device makes at a time where the head and loss go by chunks of positions (`_loss_chunk`):
+# 32 Mi of them, 64 MB in bfloat16 and 128 in float32.  A batch with fewer goes through whole.
+LOSS_CHUNK = 2 ** 25
 
 # the kinds of layer that attend to nothing: a checkpoint of theirs holds none of KEPT_NAMES
 _NO_ATTENTION = ("ssm", "gmu", "mamba2", "ffn")
@@ -1872,6 +1895,17 @@ def _bytes_a_chip(params, cfg: TransformerConfig, mesh) -> int:
     return sum(jax.tree_util.tree_leaves(held))
 
 
+def _rows_a_chip(cfg: TransformerConfig, mesh, shape) -> int:
+    """The rows (batch x positions) of ids of `shape` [B, T] that one device
+    sees: all of them without a mesh; on one the axes that divide the batch or,
+    under a ring, the positions divide them (tp counts as dividing nothing)."""
+    rows = shape[0] * shape[1]
+    if mesh is None:
+        return rows
+    over = ["dp", "fsdp"] + ["sp"] * (cfg.resolved_attn() != "dense") + ["ep"] * bool(cfg.n_experts)
+    return -(-rows // math.prod(mesh.shape[axis] for axis in over))
+
+
 def _kept_bytes(cfg: TransformerConfig, rows: int) -> int:
     """The bytes KEPT_NAMES hold of one attention layer over `rows` (batch x
     positions): q, k, v as `_attention_half` hands them to its core, the core's
@@ -1885,81 +1919,172 @@ def _kept_bytes(cfg: TransformerConfig, rows: int) -> int:
     return rows * ((q + kv + out + cfg.d_model) * jnp.dtype(cfg.dtype).itemsize + 4 * h)
 
 
-def _remat_keeps(cfg: TransformerConfig, mesh, shape, state_bytes: int) -> bool:
-    """Whether the checkpointed blocks of a step over ids of `shape` [B, T]
-    keep KEPT_NAMES (`_stage_forward`): they do where the bytes that puts on a
-    chip, `_kept_bytes` of the rows a chip sees (the axes that divide the batch
-    or, under a ring, the positions; tp counts as dividing nothing) in every
-    attention layer of its stage (under pp over the schedule's m + pp - 1 steps
-    of B / m rows), fit the device's memory (`_memory_limit`) less
-    `state_bytes`, what the chip holds through the step, and REMAT_TEMP_BYTES.
-    All of the layers or none: one scan traces its body once.  A device that
-    reports no limit (the CPU) keeps them: nothing says they do not fit, and
-    the tests off the chip then run the path the chip runs.  Decided as the
-    step is traced and said once there, in a span `train.remat`: kept,
-    kept_bytes, kept_layers (0 where not kept), budget_bytes (-1: no limit)."""
-    layers = sum(kind not in _NO_ATTENTION for kind in cfg.layer_kinds) // cfg.pp
-    rows = shape[0] * shape[1]
-    if mesh is not None:
-        over = ["dp", "fsdp"] + ["sp"] * (cfg.resolved_attn() != "dense") + ["ep"] * bool(cfg.n_experts)
-        rows = -(-rows // math.prod(mesh.shape[axis] for axis in over))
+def _kept_ffn_bytes(cfg: TransformerConfig, rows: int) -> int:
+    """The bytes FFN_NAMES hold of one dense FFN over `rows`: the two up products in cfg.dtype."""
+    return rows * 2 * cfg.d_ff * jnp.dtype(cfg.dtype).itemsize
+
+
+def _loss_chunk(cfg: TransformerConfig, mesh, shape) -> int:
+    """The positions the head and loss of a step over ids of `shape` [B, T]
+    take at a time (`_chunked_loss`): as many as make LOSS_CHUNK logits on one
+    device, a multiple of 128 where there are as many; 0 where all T make no
+    more than that, and the head and loss go through whole."""
+    chunk = LOSS_CHUNK * shape[1] // (_rows_a_chip(cfg, mesh, shape) * cfg.vocab_size)
+    if chunk >= shape[1]:
+        return 0
+    return chunk - chunk % 128 if chunk >= 128 else max(chunk, 1)
+
+
+def _loss_bytes(cfg: TransformerConfig, rows: int, positions: int, chunk: int) -> int:
+    """The bytes the head and loss hold of `rows` a device over `positions`,
+    `chunk` of them at a time (0: whole): the logits made at a time in cfg.dtype,
+    in float32 and their gradient in float32; by chunks, every row's gradient in
+    cfg.dtype, which is what goes from the forward pass to the backward pass
+    (`_chunked_loss`); and the head's matrix and its gradient, whole, in
+    cfg.dtype."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    at_a_time = -(-rows * chunk // positions) if chunk else rows
+    return cfg.vocab_size * (at_a_time * (size + 8) + bool(chunk) * rows * size + 2 * cfg.d_model * size)
+
+
+def _dense_ffn_layers(cfg: TransformerConfig) -> Tuple[int, ...]:
+    """The layers, by their place in the model, whose checkpoint can keep
+    FFN_NAMES: those that attend and have a dense FFN behind the attention
+    half; none of a mixture's or of a pipeline's stages."""
+    if cfg.n_experts or cfg.pp > 1:
+        return ()
+    return tuple(i for i, kind in enumerate(cfg.layer_kinds) if kind not in _NO_ATTENTION + ("attn_alone",))
+
+
+class Keeps(NamedTuple):
+    """What the checkpointed blocks of a step keep (`_remat_keeps`)."""
+
+    names: bool = False  # KEPT_NAMES, in every layer that attends
+    ffn_layers: int = 0  # FFN_NAMES beside them, in the last so many layers that attend and have a dense FFN
+
+
+def _remat_keeps(cfg: TransformerConfig, mesh, shape, weight_bytes: int, state: float = 1.0, loss_chunk: int = 0) -> Keeps:
+    """What the checkpointed blocks of a step over ids of `shape` [B, T] keep
+    (`_stage_forward`), layer by layer, of what the device's memory
+    (`_memory_limit`) has left beside what the chip holds through the step,
+    `state` times `weight_bytes` (the weights' bytes a chip; `_hidden` says
+    what state is).  First KEPT_NAMES in every attention layer of a stage,
+    `_kept_bytes` of the rows a chip sees (`_rows_a_chip`; under pp over the
+    schedule's m + pp - 1 steps of B / m rows), all of the layers or none:
+    where they do not fit nothing else is asked.  Then FFN_NAMES,
+    `_kept_ffn_bytes`, in as many of those layers as the rest holds, counted
+    from the last: the backward pass begins there, at the moment the step holds
+    most, and a layer that kept its up products needs no room to make them
+    again.  A mixture's layers and a pipeline's stages keep KEPT_NAMES alone.
+
+    The budget is the limit less the state and `temp_bytes`, what the step
+    holds beside both while every name is alive, from the loss to the last
+    layer's backward pass: every layer's input (a checkpoint's own); the
+    weights in cfg.dtype where they are kept in another (the compiler makes
+    each layer's once and keeps it from the forward pass to the backward pass
+    while it has room); the larger of what the head and loss hold
+    (`_loss_bytes`; loss_chunk: `_loss_chunk`'s answer, 0 for logits made
+    whole) and of one layer's halves made again with their gradients (twice
+    the two names' bytes); and a margin, 1 / REMAT_MARGIN of the limit.  The
+    gradients are not in it: a layer's are made as its names and input are let
+    go (218 MB for 303 at `train-fsdp4`'s shapes), and all of them are there
+    only when no name is: they are the bare step's need, which nothing decided
+    here changes.
+
+    A device that reports no limit (the CPU) keeps every name in every layer:
+    nothing says they do not fit, and the tests off the chip then run the path
+    the chip runs.  Decided as the step is traced and said once there, in a
+    span `train.remat`: kept, kept_bytes, kept_layers (0 where not kept),
+    budget_bytes (-1: no limit), kept_ffn_layers, kept_ffn_bytes (of the
+    layers that keep them), temp_bytes, loss_chunk."""
+    kinds = cfg.layer_kinds
+    layers = sum(kind not in _NO_ATTENTION for kind in kinds) // cfg.pp
+    dense = len(_dense_ffn_layers(cfg))
+    seen = rows = _rows_a_chip(cfg, mesh, shape)
     if cfg.pp > 1:
         rows = -(-rows * (cfg.num_microbatches + cfg.pp - 1) // cfg.num_microbatches)
-    kept_bytes, limit = layers * _kept_bytes(cfg, rows), _memory_limit(mesh)
-    budget = -1 if limit is None else limit - state_bytes - REMAT_TEMP_BYTES
+    a_layer, an_ffn = _kept_bytes(cfg, rows), _kept_ffn_bytes(cfg, rows)
+    kept_bytes, limit = layers * a_layer, _memory_limit(mesh)
+    size, kept_as = jnp.dtype(cfg.dtype).itemsize, jnp.dtype(cfg.param_dtype)
+    inputs = len(kinds) // cfg.pp * rows * cfg.d_model * size
+    cast = weight_bytes * size // kept_as.itemsize * (kept_as != jnp.dtype(cfg.dtype))
+    temp = inputs + cast + max(_loss_bytes(cfg, seen, shape[1], loss_chunk), 2 * (a_layer + an_ffn))
+    temp += 0 if limit is None else limit // REMAT_MARGIN
+    budget = -1 if limit is None else limit - int(state * weight_bytes) - temp
     kept = layers > 0 and (limit is None or kept_bytes <= budget)
-    with tracing.span("train.remat", kept=kept, kept_bytes=kept_bytes, kept_layers=layers * kept, budget_bytes=budget):
+    ffn_layers = dense * kept if limit is None else min(dense, max(budget - kept_bytes, 0) // an_ffn) * kept
+    with tracing.span("train.remat", kept=kept, kept_bytes=kept_bytes, kept_layers=layers * kept, budget_bytes=budget,
+                      kept_ffn_layers=ffn_layers, kept_ffn_bytes=ffn_layers * an_ffn, temp_bytes=temp, loss_chunk=loss_chunk):
         pass
-    return kept
+    return Keeps(kept, ffn_layers)
 
 
-def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), keep: bool = False):
+def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=frozenset(), keeps: Keeps = Keeps()):
     """The layer loop over this stage's layers.  stacks: `layer_stacks`, leaves
     [L_stage, ...].  Returns (x, aux) — aux is the summed MoE load-balance loss
     (0 dense).
 
     Under `cfg.remat` every block is a `jax.checkpoint`: a layer's input is
-    kept and the backward pass runs the block forward again.  keep
-    (`_remat_keeps`: the device has room) hands the checkpoint a policy by
-    names, under which an attention block also keeps KEPT_NAMES and its
-    backward pass runs again the two norms, `_gqa_repeat` and the FFN half
-    alone; a block of a kind that attends to nothing holds no such name and
-    recomputes whole either way, as does a core that makes other residuals
-    than the flash kernel's (the dense reference, a ring)."""
-    # an attention kind's block is the same whatever its FFN: that is what its weights hold
-    blocks = {
-        kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind, keep=keep)
-        for kind in _INIT_KIND if kind not in _NO_ATTENTION
-    }
+    kept and the backward pass runs the block forward again.  keeps
+    (`_remat_keeps`: what the device has room for) hands the checkpoint a
+    policy by names.  Under `keeps.names` an attention block also keeps
+    KEPT_NAMES and its backward pass runs again the two norms, `_gqa_repeat`
+    and the FFN half alone; the last `keeps.ffn_layers` of them keep FFN_NAMES
+    too and run again the norms, `_gqa_repeat` and the gate.  The policy
+    changes at one layer, so the loop is two runs there (`_scan_layers`' span),
+    each a scan with its own body.  A block of a kind that attends to nothing
+    holds no such name and recomputes whole either way, as does a core that
+    makes other residuals than the flash kernel's (the dense reference, a
+    ring)."""
 
-    def ssm(bp, s, layer=None):
-        x, aux, _, _, y = _ssm_block_forward(bp, _x(s), cfg)
-        return _hand_on(s, x, m=y), aux
+    def blocks_that_keep(ffn: bool):
+        # an attention kind's block is the same whatever its FFN: that is what its weights hold
+        blocks = {
+            kind: functools.partial(_block_forward, cfg=cfg, mesh=mesh, manual_axes=manual_axes, kind=kind,
+                                    keep=keeps.names, keep_ffn=ffn)
+            for kind in _INIT_KIND if kind not in _NO_ATTENTION
+        }
 
-    blocks["ssm"] = ssm
-    blocks["gmu"] = lambda bp, s, layer=None: (_gmu_block(bp, s, cfg), jnp.zeros((), jnp.float32))
-    blocks["mamba2"] = lambda bp, x, layer=None: (
-        _mamba2_half(bp, x, cfg, _mamba2_zero_state(cfg, x.shape[0]))[0], jnp.zeros((), jnp.float32))
+        def ssm(bp, s, layer=None):
+            x, aux, _, _, y = _ssm_block_forward(bp, _x(s), cfg)
+            return _hand_on(s, x, m=y), aux
 
-    def ffn(bp, x, layer=None):
-        x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
-        return x, jnp.zeros((), jnp.float32) if aux is None else aux
+        blocks["ssm"] = ssm
+        blocks["gmu"] = lambda bp, s, layer=None: (_gmu_block(bp, s, cfg), jnp.zeros((), jnp.float32))
+        blocks["mamba2"] = lambda bp, x, layer=None: (
+            _mamba2_half(bp, x, cfg, _mamba2_zero_state(cfg, x.shape[0]))[0], jnp.zeros((), jnp.float32))
 
-    blocks["ffn"] = ffn
-    if cfg.remat:
-        policy = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES) if keep else None
-        blocks = {kind: jax.checkpoint(block, policy=policy) for kind, block in blocks.items()}
+        def ffn_alone(bp, x, layer=None):
+            x, aux, _ = _ffn_half(bp, x, cfg, manual_axes=manual_axes)
+            return x, jnp.zeros((), jnp.float32) if aux is None else aux
 
-    def body(kind, carry, bp, _held, layer):
-        x, aux = carry
-        x, a = blocks[kind](bp, x, layer=layer)
-        return (x, aux + a), None
+        blocks["ffn"] = ffn_alone
+        if cfg.remat:
+            names = KEPT_NAMES * keeps.names + FFN_NAMES * ffn
+            policy = jax.checkpoint_policies.save_only_these_names(*names) if names else None
+            blocks = {kind: jax.checkpoint(block, policy=policy) for kind, block in blocks.items()}
+        return blocks
+
+    def run(carry, span, ffn: bool):
+        blocks = blocks_that_keep(ffn)
+
+        def body(kind, carry, bp, _held, layer):
+            x, aux = carry
+            x, a = blocks[kind](bp, x, layer=layer)
+            return (x, aux + a), None
+
+        return _scan_layers(body, carry, stacks, cfg, unroll=True if cfg.unroll_layers else 1, indexed=cfg.diff_attn,
+                            span=span)[0]
 
     t = x.shape[1]
-    (x, aux), _ = _scan_layers(
-        body, (carried(x, cfg, t, t), jnp.zeros((), jnp.float32)), stacks, cfg,
-        unroll=True if cfg.unroll_layers else 1, indexed=cfg.diff_attn,
-    )
+    carry = (carried(x, cfg, t, t), jnp.zeros((), jnp.float32))
+    # the layer from which on every dense FFN keeps its names: the `keeps.ffn_layers`-th such layer from the last
+    cut = _dense_ffn_layers(cfg)[-keeps.ffn_layers] if keeps.ffn_layers else len(cfg.layer_kinds)
+    if 0 < cut < len(cfg.layer_kinds):
+        carry = run(run(carry, (0, cut), False), (cut, len(cfg.layer_kinds)), True)
+    else:
+        carry = run(carry, None, cut == 0)
+    x, aux = carry
     return _x(x), aux
 
 
@@ -1984,13 +2109,17 @@ def forward(params, ids, cfg: TransformerConfig, mesh=None, return_aux: bool = F
     """ids: [B, T] int32 -> logits [B, T, V] (with the MoE load-balance aux
     loss when return_aux; 0 for dense configs).  Under `cfg.remat` a gradient
     through it keeps what fits beside the weights alone (`_remat_keeps`)."""
-    return _forward(params, ids, cfg, mesh, return_aux)
+    x, aux = _hidden(params, ids, cfg, mesh)
+    logits = _head(params, x, cfg)
+    return (logits, aux) if return_aux else logits
 
 
-def _forward(params, ids, cfg: TransformerConfig, mesh, return_aux: bool, state: float = 1.0):
-    """`forward`, told what stays on a chip through the step it is part of:
-    `state` times the weights' bytes (`make_train_step`: the optimizer's
-    state beside them)."""
+def _hidden(params, ids, cfg: TransformerConfig, mesh, state: float = 1.0, loss_chunk: int = 0):
+    """`forward` up to the last layer's result, before the final norm and the
+    head: (x [B, T, E], aux), told what stays on a chip through the step it is
+    part of, `state` times the weights' bytes (`make_train_step`: the
+    optimizer's state beside them), and how the head and loss that follow go
+    (`_loss_chunk`)."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]  # [B, T, E]
     manual_axes = set()
@@ -2005,7 +2134,9 @@ def _forward(params, ids, cfg: TransformerConfig, mesh, return_aux: bool, state:
 
     if manual_axes or (mesh is not None and mesh.size > 1):
         _one_device_only(cfg, f"forward on a mesh of {1 if mesh is None else mesh.size} devices")
-    keep = cfg.remat and _remat_keeps(cfg, mesh, ids.shape, int(state * _bytes_a_chip(params, cfg, mesh)))
+    keeps = Keeps()
+    if cfg.remat:
+        keeps = _remat_keeps(cfg, mesh, ids.shape, _bytes_a_chip(params, cfg, mesh), state, loss_chunk)
     if manual_axes:
         if mesh is None:
             raise ValueError("mesh required for pp/sp execution")
@@ -2028,30 +2159,24 @@ def _forward(params, ids, cfg: TransformerConfig, mesh, return_aux: bool, state:
                     f"n_experts={cfg.n_experts} not divisible by the mesh's "
                     f"ep axis ({mesh_ep})"
                 )
-        x, aux = _apply_blocks_manual(
-            params["blocks"], x, cfg, mesh, frozenset(manual_axes), keep
+        return _apply_blocks_manual(params["blocks"], x, cfg, mesh, frozenset(manual_axes), keeps)
+    if mesh is not None and mesh.size > 1:
+        # state the residual stream's layout instead of leaving it to
+        # propagation: between the embedding (columns on tp), wo/w_down
+        # (columns on fsdp) and the attention region (batch on dp x fsdp)
+        # the partitioner otherwise picks its own, and on dp2 x fsdp2 x
+        # tp2 with one row per shard that gave wrong logits
+        x = lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(("dp", "fsdp"), None, None))
         )
-    else:
-        if mesh is not None and mesh.size > 1:
-            # state the residual stream's layout instead of leaving it to
-            # propagation: between the embedding (columns on tp), wo/w_down
-            # (columns on fsdp) and the attention region (batch on dp x fsdp)
-            # the partitioner otherwise picks its own, and on dp2 x fsdp2 x
-            # tp2 with one row per shard that gave wrong logits
-            x = lax.with_sharding_constraint(
-                x, NamedSharding(mesh, P(("dp", "fsdp"), None, None))
-            )
-        x, aux = _stage_forward(layer_stacks(params), x, cfg, mesh, keep=keep)
-
-    logits = _head(params, x, cfg)
-    return (logits, aux) if return_aux else logits
+    return _stage_forward(layer_stacks(params), x, cfg, mesh, keeps=keeps)
 
 
-def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes, keep: bool = False):
+def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes, keeps: Keeps = Keeps()):
     """Run the block stack under shard_map, manual over {'pp','sp','ep'}
     (subset), GSPMD-auto over dp/fsdp/tp.  With 'ep' manual, the batch dim
     shards over experts' owner devices (tokens all_to_all inside moe_ffn).
-    keep: `_stage_forward`'s."""
+    keeps: `_stage_forward`'s."""
     sp_manual = "sp" in manual_axes
     pp_manual = "pp" in manual_axes
     ep_manual = "ep" in manual_axes
@@ -2060,7 +2185,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes, k
         if pp_manual:
             my_blocks = jax.tree_util.tree_map(lambda p: p[0], blocks_local)
             stage = lambda bp, a: _stage_forward(
-                {"attn": bp}, a, cfg=cfg, mesh=mesh, manual_axes=manual_axes, keep=keep
+                {"attn": bp}, a, cfg=cfg, mesh=mesh, manual_axes=manual_axes, keeps=keeps
             )
             if cfg.n_experts:
                 # MoE through the pipeline: each stage's MoE layers
@@ -2086,7 +2211,7 @@ def _apply_blocks_manual(blocks, x, cfg: TransformerConfig, mesh, manual_axes, k
                 aux = jnp.zeros((), jnp.float32)
         else:
             x_out, aux = _stage_forward(
-                {"attn": blocks_local}, x_local, cfg, mesh, manual_axes, keep
+                {"attn": blocks_local}, x_local, cfg, mesh, manual_axes, keeps
             )
         # the P() out-spec claims aux is replicated across EVERY manual axis;
         # each shard computed it over its own tokens, so reduce over all
@@ -2136,12 +2261,101 @@ def cross_entropy_loss(logits, targets, mask=None):
     return jnp.mean(nll)
 
 
-def _loss(params, batch, cfg: TransformerConfig, mesh, state: float = 1.0):
-    """The next-token loss of batch["ids"] [B, T+1]; state: `_forward`'s."""
-    ids = batch["ids"]
-    logits, aux = _forward(params, ids[:, :-1], cfg, mesh, True, state)
+def _chunks(x, w, targets, chunk: int, with_gradient: bool):
+    """`_chunked_loss`'s one pass: a scan over T's chunks (the last padded with
+    rows that count for nothing) of the head's product, the log-sum-exp and,
+    with_gradient, the loss's gradient by the chunk's logits, rounded to their
+    dtype as the transpose of `cross_entropy_loss`'s cast rounds it.  Returns
+    (the loss, x by chunks [n, B, chunk, E], that gradient [n, B, chunk, V] or
+    None)."""
+    b, t, e = x.shape
+    n = -(-t // chunk)
+    by_chunks = lambda a: jnp.pad(a, ((0, 0), (0, n * chunk - t)) + ((0, 0),) * (a.ndim - 2)).reshape(
+        b, n, chunk, *a.shape[2:]).swapaxes(0, 1)
+    xs = by_chunks(x)
+    # what `jnp.mean` hands every row's loss in the backward pass, 0 where a row is the pad's
+    weight = by_chunks(jnp.full((b, t), 1.0 / (b * t), jnp.float32))
+
+    def one(_, chunk_of):
+        xc, tc, ct = chunk_of
+        with jax.named_scope("head"):
+            logits = xc @ w
+        with jax.named_scope("loss"):
+            logits = logits.astype(jnp.float32)
+            top = jnp.max(logits, axis=-1, keepdims=True)
+            exps = jnp.exp(logits - top)
+            total = jnp.sum(exps, axis=-1, keepdims=True)
+            gold = jnp.take_along_axis(logits, tc[..., None], axis=-1)
+            nll = (jnp.log(total) + top - gold)[..., 0]
+            if not with_gradient:
+                return None, (nll, None)
+            ct = ct[..., None]
+            by_logits = exps * (ct / total)
+            at_gold = lax.broadcasted_iota(tc.dtype, logits.shape, logits.ndim - 1) == tc[..., None]
+            return None, (nll, jnp.where(at_gold, by_logits - ct, by_logits).astype(x.dtype))
+
+    _, (nll, by_logits) = lax.scan(one, None, (xs, by_chunks(targets), weight))
     with jax.named_scope("loss"):
-        loss = cross_entropy_loss(logits, ids[:, 1:])
+        return jnp.mean(nll.swapaxes(0, 1).reshape(b, n * chunk)[:, :t]), xs, by_logits
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunked_loss(x, w, targets, chunk: int):
+    """`cross_entropy_loss(x @ w, targets)`, the mean over every row, for x
+    [B, T, E] (the final norm's result) and the head's matrix w [E, V], both
+    in cfg.dtype, `chunk` positions at a time: no more than B x chunk x V
+    logits exist at once, in their dtype or in float32.  The numbers are the
+    whole computation's: the logits are made in x's dtype and cast up exactly,
+    every reduction is over float32.  Under a gradient the one pass also
+    leaves the loss's gradient by the logits, in their dtype (what the whole
+    computation's backward pass hands the head's two products), in place of
+    the logits: the backward pass is those two products, and the head's own
+    product is made once."""
+    return _chunks(x, w, targets, chunk, False)[0]
+
+
+def _chunked_loss_forward(x, w, targets, chunk: int):
+    loss, xs, by_logits = _chunks(x, w, targets, chunk, True)
+    return loss, (xs, w, by_logits, targets)
+
+
+def _chunked_loss_backward(chunk: int, kept, ct):
+    xs, w, by_logits, targets = kept
+    n, b, _, e = xs.shape
+    ct = ct.astype(xs.dtype)
+    with jax.named_scope("head"):
+        by_x = jnp.einsum("nbcv,ev->nbce", by_logits, w).swapaxes(0, 1).reshape(b, n * chunk, e)[:, :targets.shape[1]]
+        by_w = jnp.einsum("nbce,nbcv->ev", xs, by_logits)
+    return by_x * ct, by_w * ct, None
+
+
+_chunked_loss.defvjp(_chunked_loss_forward, _chunked_loss_backward)
+
+
+def _loss(params, batch, cfg: TransformerConfig, mesh, state: float = 1.0):
+    """The next-token loss of batch["ids"] [B, T+1]; state: `_hidden`'s.  Where
+    the logits of all T positions are more than LOSS_CHUNK a device
+    (`_loss_chunk`) the head and loss take the positions by chunks
+    (`_chunked_loss`); a smaller batch makes its logits whole, in cfg.dtype and
+    in float32."""
+    ids = batch["ids"]
+    chunk = _loss_chunk(cfg, mesh, ids[:, :-1].shape)
+    x, aux = _hidden(params, ids[:, :-1], cfg, mesh, state, chunk)
+    if chunk:
+        with jax.named_scope("norm"):
+            x = _norm(x, params, "ln_f", cfg)
+        with jax.named_scope("head"):
+            w = params["embed"].astype(cfg.dtype).T if cfg.tie_embeddings else params["lm_head"].astype(cfg.dtype)
+            if mesh is not None and mesh.size > 1:
+                # gathered once, before the chunks: left to itself the partitioner gathers the matrix inside the
+                # loop, once a chunk (2 ms each of 8 at `train-fsdp4`'s shapes)
+                w = lax.with_sharding_constraint(w, NamedSharding(mesh, P(None, "tp")))
+        loss = _chunked_loss(x, w, ids[:, 1:], chunk)
+    else:
+        logits = _head(params, x, cfg)
+    with jax.named_scope("loss"):
+        if not chunk:
+            loss = cross_entropy_loss(logits, ids[:, 1:])
         if cfg.n_experts:
             loss = loss + cfg.moe_aux_weight * aux
     return loss

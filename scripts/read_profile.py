@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """What `keye-longdoc-closed4`'s readers make of a traced run's `.xplane.pb`,
-whether or not the harness waited for the profile's export (it gives
-`bench_trace("stop")` 120 s, and this cell's profile takes 100-170: PERF.md
-section 7, PR 56), and where the file's bytes lie.
+read by hand (since PR 58 the harness stops the profiler's session itself,
+waits 300 s for it and the stop takes 25-36 s: PERF.md section 6, PR 58), and
+where the file's bytes lie.
 
     python3 scripts/read_profile.py 'bench_out/trace/*/plugins/profile/*/*.xplane.pb' [cell]
 

@@ -861,18 +861,26 @@ V5E_BYTES_LIMIT = 16909336064  # `memory_stats()["bytes_limit"]` of a v5e chip (
 def test_the_train_cells_step_keeps_the_attention_half_and_fits(v5e, on_tpu, monkeypatch):
     """`train-fsdp4`'s step as one of its four chips runs it: Mistral-7B's
     widths 12 layers deep, fsdp=4, 8 x 4,096 tokens, `remat`.  Under a v5e's
-    limit the checkpointed blocks keep their attention halves
-    (`transformer._remat_keeps`; a described device reports no limit, so the
-    test states it): the compiled step calls `flash_fwd` once a layer and each
-    backward kernel once; of an `attn.*` scope the recomputation holds only
-    `_gqa_repeat`'s k and v and the rotary tables, nothing under `attn.qkv` or
-    `attn.out` and nothing of q's size [2, 4096, 32, 128]; and the state and the
-    temporaries together stay under the limit."""
+    limit the checkpointed blocks keep their attention halves, and the last
+    three the FFN's two up products beside them (`transformer._remat_keeps`; a
+    described device reports no limit, so the test states it): the compiled
+    step calls `flash_fwd` once a layer and each backward kernel once; of an
+    `attn.*` scope the recomputation holds only `_gqa_repeat`'s k and v and the
+    rotary tables, nothing under `attn.qkv` or `attn.out` and nothing of q's
+    size [2, 4096, 32, 128]; it makes the FFN's up products again in the nine
+    layers that did not keep them, twice nine and no more (the compiler, short
+    of room, would make more on its own); the head and loss hold no value of
+    rows x vocabulary in float32; and the state and the temporaries together
+    stay under the limit."""
     monkeypatch.setattr(transformer, "_memory_limit", lambda mesh: V5E_BYTES_LIMIT)
+    rule, said = transformer._remat_keeps, []
+    monkeypatch.setattr(transformer, "_remat_keeps", lambda *a, **k: said.append(rule(*a, **k)) or said[-1])
     cfg = transformer.TransformerConfig(**dict(MISTRAL4, vocab_size=32768, n_layers=12, max_seq_len=4096, rope_theta=1e6,
                                                param_dtype=jnp.float32, remat=True))
     mesh = Mesh(np.asarray(v5e).reshape(MeshSpec(fsdp=4).axis_sizes()), AXES)
     compiled, _ = _compiled_train_step(cfg, mesh, 8, 4096)
+    (keeps,) = said
+    assert keeps.names and keeps.ffn_layers == 3
     text = compiled.as_text()
     calls = {name: len(re.findall(rf"custom-call.*{name}", text)) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
     assert calls == dict.fromkeys(calls, cfg.n_layers)
@@ -881,11 +889,15 @@ def test_the_train_cells_step_keeps_the_attention_half_and_fits(v5e, on_tpu, mon
     q = 2 * 4096 * 32 * 128
     sizes = lambda scope: {(dtype, shape) for dtype, shape, s in again if s == scope and math.prod(map(int, shape.split(","))) * 4 >= q}
     assert sizes("attn.rope") == set() and sizes("attn.core") == {("bf16", "2,4096,8,4,128")}
+    up_products = re.findall(r"= bf16\[2,4096,14336\]\S* (?:convolution|fusion)\([^\n]*rematted_computation/ffn/dot_general", text)
+    assert len(up_products) == 2 * (cfg.n_layers - keeps.ffn_layers)
+    assert "f32[2,4096,32768]" not in text and re.search(r"bf16\[8,2,512,32768\]", text)  # the loss's gradient, by chunks
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.output_size_in_bytes - memory.alias_size_in_bytes + memory.temp_size_in_bytes
     assert 3 * 2.88e9 < memory.argument_size_in_bytes and held < V5E_BYTES_LIMIT
-    # the names' 2.83 GB are in the temporaries: the bare checkpoint's are 4.46 GB (transformer.REMAT_TEMP_BYTES)
-    assert 4.46e9 + 0.9 * 12 * transformer._kept_bytes(cfg, 2 * 4096) < memory.temp_size_in_bytes
+    # the names' 2.83 + 1.41 GB are in the temporaries
+    kept = 12 * transformer._kept_bytes(cfg, 2 * 4096) + keeps.ffn_layers * transformer._kept_ffn_bytes(cfg, 2 * 4096)
+    assert 0.9 * kept + 3.5e9 < memory.temp_size_in_bytes
 
 
 # Keye-VL-2.0's language model as `keye-longdoc-closed4` serves it: all 48 layers, 16 of 128 experts held, an eighth

@@ -153,8 +153,8 @@ def test_jax_hook_counts_backend_compilations(monkeypatch):
 
 def test_a_traced_train_step_says_once_what_remat_keeps(llm_spans):
     """`train.remat` (models/transformer.py `_remat_keeps`): one span as the
-    step is traced, with the decision and what it was made from; a step that
-    runs the traced program again says nothing more."""
+    step is traced, with the decision layer by layer and what it was made from;
+    a step that runs the traced program again says nothing more."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -176,7 +176,15 @@ def test_a_traced_train_step_says_once_what_remat_keeps(llm_spans):
         tracing.pop_execution(token)
     (event,) = llm_spans("train.remat")
     rows = 2 * 32
-    want = {"kept": True, "kept_layers": 2, "kept_bytes": 2 * rows * (2 * (64 + 2 * 32 + 64 + 64) + 4 * 4), "budget_bytes": -1}
+    a_layer, an_ffn = rows * (2 * (64 + 2 * 32 + 64 + 64) + 4 * 4), rows * 2 * 128 * 2
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(params))
+    want = {"kept": True, "kept_layers": 2, "kept_bytes": 2 * a_layer, "budget_bytes": -1,
+            # the dense FFN's two up products beside them, in every layer where no limit is reported; the allowance
+            # derived from the shapes (two layers' inputs, the weights in bfloat16, a layer's halves and their
+            # gradients, which are more than the head and loss hold whole); the head and loss not by chunks
+            "kept_ffn_layers": 2, "kept_ffn_bytes": 2 * an_ffn,
+            "temp_bytes": 2 * rows * 64 * 2 + weights // 2 + 2 * (a_layer + an_ffn), "loss_chunk": 0}
+    assert 2 * (a_layer + an_ffn) > 128 * (rows * 10 + 2 * 64 * 2)
     assert {k: event[k] for k in want} == want and event["trace"]["tid"] == TRACE["tid"]
 
 
