@@ -456,6 +456,12 @@ class ContinuousBatcher:
                 raise ValueError(_NO_TRUNCATION.format(what=f"a default top_k of {top_k}", b=self._block))
             if t_max % self._block:
                 raise ValueError(f"a cache of {t_max} slots is no whole number of blocks of {self._block}")
+        if prefix_cache_entries > 0 and "kda" in (cfg.layer_mixers or ()):
+            raise ValueError(
+                f"prefix_cache_entries={prefix_cache_entries}: a replica of kda layers keeps no prefix cache (every "
+                f"entry would hold a snapshot of the matrix state at its split, {cfg.kda_n_heads} x {cfg.kda_head_dim} x "
+                f"{cfg.kda_head_dim} float32 a layer, and a hit would install it: not built); pass 0"
+            )
         self.prefill_buckets = tuple(sorted(prefill_buckets))
         # prefix/KV reuse (0 entries = off, the pre-cache admit path
         # verbatim).  When on, admit splits the prompt at the largest
@@ -569,6 +575,9 @@ class ContinuousBatcher:
             # recurrent state read and written by the steps and installed by the
             # admits; stays 0 for a model of attention layers alone
             "ssm_state_bytes": 0,
+            # one slot's recurrent state over the layers that keep one (a kda layer's matrix state and its
+            # convolution's last inputs among them), by the cache's own shapes: a constant of the deployment
+            "state_bytes_per_slot": self._ssm_slot_bytes,
             # a model that generates by blocks: passes of one slot's block (a
             # step is one for every live slot) and the positions they fixed;
             # stay 0 for one causal token a step

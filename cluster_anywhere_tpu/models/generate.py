@@ -12,7 +12,8 @@ kind alone,
         indexer's one key head of each token with the positions innermost:
         `init_cache` says why)
     conv: [n_ssm, B, K-1, C], h: [n_ssm, B, C, N] (float32)
-        (a Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N])
+        (a Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N]; a kda layer's:
+        [n, B, K-1, 3 H D] and the matrix state [n, B, H, D, D])
     ckv: [n_attn, B, T_max, R], kr: [n_attn, B, T_max, rope up to 128s]  (latent
         attention: a token is one latent row and the one rotated key every
         head shares, no heads axis; `LATENT_LANES` says why the key is padded)
@@ -57,7 +58,19 @@ Which layers' rows share a stack, and where a layer's lie in it, is
 `_state_index`: the model's order among the layers that keep that kind of
 state, whatever their kind's FFN.
 
-Latent attention (`cfg.latent`) has the same two blocks with cores of its own.
+A kda block (Kimi Delta Attention: transformer.py `_kda_mixer`) keeps a MATRIX state a
+head, [n, B, H, D, D] float32, and its convolution's last inputs in the "ssm"
+stacks at its own shapes.  A prefill runs the delta rule in chunks from the zero state
+(pads masked: they leave the state as it is) and hands the state on as its rows; a
+decode step moves it on one position (`_kda_decode_mixer`): on a TPU through
+ops/kda.py's kernel, which is handed the stack as it lies and the layer's index and
+fetches, updates and writes back the rows that hold a request and no others; anywhere
+else the layer's state is read out of the stack and the new one written in its place,
+as a state-space layer's is.
+
+Latent attention (`cfg.latent`) has the same two blocks with cores of its own (its
+queries through a low rank or, `cfg.q_lora_rank` 0, straight to their heads; the shared
+key turned, or under `cfg.rotary` False carried as it is projected).
 The prefill core EXPANDS: every head's key and value are made of the prompt's
 latents (`transformer._latent_expand`) and go through the same flash kernel, at
 a q/k width of nope + rope and a value width of v; what it stores is the
@@ -117,10 +130,12 @@ from ..ops import sparse_attention as sparse
 from ..ops.attention import (
     DECODE_BLOCK_K, DECODE_BLOCK_ROWS, attention, decode_attention, decode_on_kernel, decode_rows_read, decode_span,
 )
+from ..ops.kda import KDA_HEADS, kda_decode_update, live_rows
 from ..parallel.moe import EXPERT_MATRICES
 from .transformer import (
     _INIT_KIND, SSM_STATE_DTYPE, TransformerConfig, _attention_half, _ffn_half, _gmu_block, _gqa_repeat, _hand_on,
-    _head, _latent_expand, _latent_up, _mamba2_half, _mamba2_zero_state, _scan_layers, _sparse_attention,
+    _head, _kda_half, _kda_mixer, _kda_zero_state, _latent_expand, _latent_up, _mamba2_half, _mamba2_zero_state, _scan_layers,
+    _sparse_attention,
     _ssm_block_forward, _ssm_half, _ssm_mix, _x, carried, core_scope, is_window, layer_stacks,
 )
 
@@ -161,7 +176,7 @@ def _state_kind(kind: str, cfg: TransformerConfig) -> Optional[str]:
     touches none."""
     if kind in ("gmu", "ffn"):
         return None
-    if kind in ("ssm", "mamba2"):  # a Mamba-2 layer's window and h are the "ssm" rows, at its own shapes
+    if kind in ("ssm", "mamba2", "kda", "kda_dense"):  # a Mamba-2 or kda layer's window and state are the "ssm" rows, at its own shapes
         return "ssm"
     if is_window(kind):
         return "attn_win"
@@ -313,12 +328,13 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
     extent of their own, whatever the context's length;
     a state-space layer's convolution window [n_ssm, B, K-1, C] and its h
     [n_ssm, B, C, N] in SSM_STATE_DTYPE, whatever the context's length (a
-    Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N]).  A layer
+    Mamba-2 layer's: [n, B, K-1, C + 2 G N] and [n, B, H, P, N]; a kda layer's:
+    [n, B, K-1, 3 H D] and [n, B, H, D, D]).  A layer
     that keeps no rows (NO_ROWS) adds nothing; KV and D are the heads as they
     are cached (`cfg.cached_heads`, `cfg.cached_width`), and under
     `cfg.flat_heads` the four stacks of keys and values are [n, B, T * KV, D]."""
     kinds = [kind for kind in cfg.layer_kinds if kind not in NO_ROWS]
-    n_ssm = kinds.count("ssm") + kinds.count("mamba2")
+    n_ssm = sum(_state_kind(kind, cfg) == "ssm" for kind in kinds)
     n_win = sum(map(is_window, kinds))
     n_attn = len(kinds) - n_ssm - n_win
     cache = {}
@@ -346,8 +362,10 @@ def init_cache(cfg: TransformerConfig, batch: int, t_max: int):
         shape = (n_attn, batch, *slots(t_max), cfg.cached_width)
         cache.update(k=jnp.zeros(shape, cfg.dtype), v=jnp.zeros(shape, cfg.dtype))
     if n_ssm:
-        # Mamba-2: [heads, head_dim, N] a slot, and a window over x, B and C together
-        state = ((cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state) if "mamba2" in kinds
+        # Mamba-2: [heads, head_dim, N] a slot, and a window over x, B and C together; kda: a matrix [D, D] a head,
+        # and a window over q, k and v together
+        state = ((cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_head_dim) if "kda" in kinds else
+                 (cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_d_state) if "mamba2" in kinds
                  else (cfg.d_inner, cfg.ssm_d_state))
         cache.update(
             conv=jnp.zeros((n_ssm, batch, cfg.ssm_d_conv - 1, cfg.conv_width), cfg.dtype),
@@ -852,6 +870,37 @@ def _mamba2_block_decode(bp, x, cache, layer, cfg: TransformerConfig):
     return x, {**cache, **after}
 
 
+def kda_on_kernel(cache, cfg: TransformerConfig) -> bool:
+    """Whether a decode step over `cache` moves the KDA layers' state on through ops/kda.py's kernel: on a TPU, more
+    than one row (`_on_kernel`'s rule and reason), heads as the kernel blocks them."""
+    return (decode_on_kernel() and cfg.kda_n_heads > 0 and cache["h"].shape[1] > 1
+            and cfg.kda_head_dim == 128 and cfg.kda_n_heads % KDA_HEADS == 0)
+
+
+def _kda_decode_mixer(bp, x, cache, layer, cfg: TransformerConfig, rows=None):
+    """A KDA layer's mixer, one token a row, from each row's own state.  x: [B, 1, E]; cache: the recurrent stacks
+    conv [n, B, K-1, 3 H D] and h [n, B, H, D, D] (among whatever else it holds) and layer: this one's number among
+    them.  rows: `ops.kda.live_rows` of the step (made once a step by `decode_rows`): the matrix state is then moved
+    on by the kernel over the stack as it lies, the rows that hold a request alone, and only the convolution's
+    window is read out of its stack and written back; None: as `_mamba2_block_decode`, the layer's state read out
+    of the stacks whole and the new one written in its place.  Returns (the mixer's result [B, 1, E], the cache
+    after)."""
+    conv, h = LAYER_STATE["ssm"]
+    at = lambda name: lax.dynamic_index_in_dim(cache[name], layer, keepdims=False)
+    put = lambda name, new: lax.dynamic_update_index_in_dim(cache[name], new, layer, 0)
+    moved = {}
+
+    def update(q, k, v, g, beta):
+        o, moved[h] = kda_decode_update(q, k, v, g, beta, cache[h], layer, rows)
+        return o
+
+    with jax.named_scope(STATE_SCOPE["ssm"]):
+        state = at(conv), None if rows is not None else at(h)
+    f, (window, s) = _kda_mixer(bp, x, cfg, state, update=None if rows is None else update)
+    with jax.named_scope(STATE_SCOPE["ssm"]):
+        return f, {**cache, conv: put(conv, window), h: put(h, s) if rows is None else moved[h]}
+
+
 def _pad_keep(pad, t: int):
     """[B, T] bool, False at a left pad; None where there are none."""
     return None if pad is None else jnp.arange(t)[None, :] >= pad[:, None]
@@ -907,7 +956,12 @@ def prefill_counted(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
         x, _, touched = _ffn_half(bp, x, cfg, keep, experts)
         return x, None, (None, touched)
 
-    x, _, outs = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn), carried(x, cfg, 1, t), params, cfg)
+    def kda(x, bp, experts, _cache, _layer):
+        x, state = _kda_half(bp, x, cfg, _kda_zero_state(cfg, x.shape[0]), keep)
+        x, _, touched = _ffn_half(bp, x, cfg, keep, experts)
+        return x, None, (state, touched)
+
+    x, _, outs = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn, kda), carried(x, cfg, 1, t), params, cfg)
     x = _x(x)
     rows = {kind: made for kind, (made, _) in outs.items() if kind not in NO_ROWS}
     # each kind's rows into the stacks of the state it keeps, at its layers' places there
@@ -938,9 +992,9 @@ def prefill(params, ids, cfg: TransformerConfig, t_max: int, pad=None):
     return prefill_counted(params, ids, cfg, t_max, pad=pad)[:2]
 
 
-def _bodies(attn, ssm, gmu=None, mamba2=None, ffn=None):
-    """`_scan_blocks`' bodies: every attention kind's is `attn(kind, ...)`."""
-    own = {"ssm": ssm, "gmu": gmu, "mamba2": mamba2, "ffn": ffn}
+def _bodies(attn, ssm, gmu=None, mamba2=None, ffn=None, kda=None):
+    """`_scan_blocks`' bodies: every attention kind's is `attn(kind, ...)`, a kda block's `kda` whatever its FFN."""
+    own = {"ssm": ssm, "gmu": gmu, "mamba2": mamba2, "ffn": ffn, "kda": kda, "kda_dense": kda}
     return {kind: own[kind] if kind in own else functools.partial(attn, kind) for kind in _INIT_KIND}
 
 
@@ -998,7 +1052,13 @@ def decode_rows(params, cache, tokens, pos, pads, cfg: TransformerConfig, live=N
         x, _, touched = _ffn_half(bp, x, cfg, None if live is None else live[:, None], experts)
         return x, cache, touched
 
-    x, cache, touched = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn), carried(x, cfg, 1), params, cfg, cache)
+    kda_rows = live_rows(live, tokens.shape[0]) if kda_on_kernel(cache, cfg) else None
+
+    def kda(x, bp, experts, cache, layer):
+        f, cache = _kda_decode_mixer(bp, x, cache, layer, cfg, kda_rows)
+        return ffn(x + f, bp, experts, cache, layer)
+
+    x, cache, touched = _scan_blocks(_bodies(attn, ssm, gmu, mamba2, ffn, kda), carried(x, cfg, 1), params, cfg, cache)
     x = _x(x)
     touched = [t for t in touched.values() if t is not None]
     touched = jnp.mean(jnp.concatenate(touched).astype(jnp.float32), axis=0) if touched else None

@@ -252,6 +252,16 @@ class TransformerConfig:
     index_topk: int = 0
     index_n_heads: int = 0
     index_head_dim: int = 0
+    # Kimi Delta Attention (mixer "kda": `_kda_mixer`), a linear-attention layer: kda_n_heads heads whose state between
+    # two tokens is a MATRIX S [kda_head_dim, kda_head_dim] in float32, updated by a rank-one delta rule under a decay of
+    # its own for every key channel, S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T, o_t = S_t^T q_t;
+    # q, k, v go through a causal depthwise convolution over ssm_d_conv positions and silu, q and k are L2-normed a head;
+    # the decay g and the output's gate are projections through a low rank of kda_head_dim.  A prompt runs in chunks of
+    # KDA_CHUNK positions whose inside is matrix products (`_kda_chunked`), a decode step is the update once a row
+    # (`_kda_step`).  The state and the convolution's last inputs are a slot's "ssm" rows at their own shapes
+    # (models/generate.py).  One device; one token a step.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
 
     def __post_init__(self):
         if self.index_topk and (self.index_n_heads <= 0 or self.index_head_dim <= 0 or self.index_head_dim % 2):
@@ -269,22 +279,34 @@ class TransformerConfig:
                 f"block_length {self.block_length} is not a multiple of denoise_steps "
                 f"{self.denoise_steps}: every pass fixes the same number of positions"
             )
-        if self.latent and not (self.q_lora_rank and self.qk_nope_head_dim and self.qk_rope_head_dim
-                                and self.v_head_dim):
+        if self.latent and not (self.qk_nope_head_dim and self.qk_rope_head_dim and self.v_head_dim):
             raise ValueError(
-                f"kv_lora_rank={self.kv_lora_rank}: latent attention takes q_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim as well"
+                f"kv_lora_rank={self.kv_lora_rank}: latent attention takes qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim as well (q_lora_rank 0: the query is projected straight to its heads)"
             )
         if self.latent and self.generates_blocks:
             raise NotImplementedError("a pass over blocks of positions through a latent cache")
         if self.layer_mixers is not None:
             object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))  # hashable whatever carried it
-            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm", "gmu", "attn_cross", *HALF_KINDS})
+            unknown = sorted(set(self.layer_mixers) - {"attn", "attn_win", "ssm", "gmu", "attn_cross", "kda", *HALF_KINDS})
             if unknown or len(self.layer_mixers) != self.n_layers or self.attn_layer_period:
                 raise ValueError(
-                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm, gmu, attn_cross, "
+                    f"layer_mixers={self.layer_mixers}: one of attn, attn_win, ssm, gmu, attn_cross, kda, "
                     f"{', '.join(HALF_KINDS)} for each of the {self.n_layers} layers, and no attn_layer_period beside it"
                 )
+            if "kda" in self.layer_mixers:
+                if self.kda_n_heads <= 0 or self.kda_head_dim <= 0:
+                    raise ValueError(
+                        f"a kda layer takes kda_n_heads={self.kda_n_heads} heads of kda_head_dim={self.kda_head_dim}")
+                if (self.generates_blocks or self.half_layers or self.diff_attn or self.index_topk or self.norm_output
+                        or {"ssm", "gmu", "attn_cross", "attn_win"} & set(self.layer_mixers)
+                        or self.pp > 1 or self.sp > 1 or self.ep > 1):
+                    raise NotImplementedError(
+                        f"layer_mixers={self.layer_mixers}: kda layers stand beside attention layers (latent or not) in "
+                        "a stack that yields one causal token a step on one device: no pass over blocks of positions "
+                        "(a block's positions would enter the state in both directions), no half layers, other "
+                        "recurrences, window or cross layers, differential or learned sparse attention, normed "
+                        "outputs, pipeline stages, sequence or expert axis")
             if self.half_layers:
                 if (set(self.layer_mixers) - set(HALF_KINDS) or self.latent or self.generates_blocks or self.diff_attn
                         or self.n_dense_layers or self.norm_output or self.pp > 1 or self.sp > 1):
@@ -448,8 +470,10 @@ class TransformerConfig:
 
     @property
     def conv_width(self) -> int:
-        """The channels a state-space mixer's convolution runs over, which its window in the cache keeps:
-        Mamba-1's x; Mamba-2's x, B and C side by side."""
+        """The channels a recurrent mixer's convolution runs over, which its window in the cache keeps:
+        Mamba-1's x; Mamba-2's x, B and C side by side; a kda layer's q, k and v side by side."""
+        if self.kda_n_heads:
+            return 3 * self.kda_n_heads * self.kda_head_dim
         return self.d_inner + (2 * self.ssm_n_groups * self.ssm_d_state if self.ssm_n_heads else 0)
 
     @property
@@ -469,6 +493,11 @@ class TransformerConfig:
 # their periods ([mamba2, ffn] x n, [ffn, mamba2] x n) and single layers (`_layer_runs`), a sequence of them that
 # repeats as one loop of loops (`_run_groups`).
 HALF_KINDS = ("mamba2", "attn_alone", "ffn")
+# the positions a chunk of a kda prefill (`_kda_chunked`: the family's kernels' chunk), and those of a sub-block of
+# it: inside one the decays between two positions are taken pair by pair, between two of them through the later one's
+# first position (`_kda_pairs`)
+KDA_CHUNK = 64
+KDA_SUB = 16
 
 
 def is_window(kind: str) -> bool:
@@ -538,11 +567,13 @@ def _init_block(key, cfg: TransformerConfig, keys_and_values: bool = True, ffn: 
         rq, r, dn, dr, dv = (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
         ka = jax.random.split(ks[0], 5)
+        # the query through a low rank and its norm, or (q_lora_rank 0) straight to its heads
+        query = {"wq_a": jax.random.normal(ka[0], (e, rq), pd) * s(e), "q_a_norm": jnp.ones((rq,), pd),
+                 "wq_b": jax.random.normal(ka[1], (rq, h * (dn + dr)), pd) * s(rq)} if rq else {
+                     "wq": jax.random.normal(ka[0], (e, h * (dn + dr)), pd) * s(e)}
         out = {
             **_init_norm("ln1", cfg, jax.random.fold_in(key, 1)),
-            "wq_a": jax.random.normal(ka[0], (e, rq), pd) * s(e),
-            "q_a_norm": jnp.ones((rq,), pd),
-            "wq_b": jax.random.normal(ka[1], (rq, h * (dn + dr)), pd) * s(rq),
+            **query,
             "wkv_a": jax.random.normal(ka[2], (e, r + dr), pd) * s(e),
             "kv_a_norm": jnp.ones((r,), pd),
             "wkv_b": jax.random.normal(ka[3], (r, h * (dn + dv)), pd) * s(r),
@@ -648,15 +679,43 @@ def _init_mamba2_block(key, cfg: TransformerConfig):
     return out
 
 
+def _init_kda_block(key, cfg: TransformerConfig):
+    """A Kimi Delta Attention block (kind "kda") and its FFN.  `kda_qkv` projects to [q | k | v], each kda_n_heads x
+    kda_head_dim, the convolution's taps `kda_conv` are stored [K, 3 H D] over the three side by side (no bias); the
+    decay's low-rank pair `kda_f1`, `kda_f2` with `dt_bias` a channel [H D] and `a_log` a head [H], drawn as Mamba-2's are
+    (A uniform in [1, 16], the bias the inverse softplus of a step log-uniform in [1e-3, 1e-1]); `kda_b` to one beta a
+    head; the output gate's pair `kda_g1`, `kda_g2`; `kda_norm` [D], one RMSNorm weight the heads share; `kda_out`."""
+    e, hh, d, kw = cfg.d_model, cfg.kda_n_heads, cfg.kda_head_dim, cfg.ssm_d_conv
+    c, r = hh * d, d  # the two low-rank projections' rank is the head's width
+    ks = jax.random.split(key, 12)
+    s = lambda fan_in: fan_in ** -0.5
+    pd = cfg.param_dtype
+    draw = lambda i, shape, fan_in: jax.random.normal(ks[i], shape, pd) * s(fan_in)
+    step = jnp.exp(jax.random.uniform(ks[3], (c,)) * (jnp.log(1e-1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    return {
+        **_init_norm("ln1", cfg, ks[0]),
+        "kda_qkv": draw(1, (e, 3 * c), e),
+        "kda_conv": draw(2, (kw, 3 * c), kw),
+        "kda_f1": draw(4, (e, r), e), "kda_f2": draw(5, (r, c), r),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+        "a_log": jnp.log(jax.random.uniform(ks[6], (hh,), minval=1.0, maxval=16.0)).astype(pd),
+        "kda_b": draw(7, (e, hh), e),
+        "kda_g1": draw(8, (e, r), e), "kda_g2": draw(9, (r, c), r),
+        "kda_norm": jnp.ones((d,), pd),
+        "kda_out": draw(10, (c, e), c),
+        **_init_ffn(jax.random.split(ks[11], 3), cfg),
+    }
+
+
+def _without_experts(init):
+    """`init` of a mixture's leading layer, which keeps the dense FFN of d_ff."""
+    return lambda key, cfg: init(key, dataclasses.replace(cfg, n_experts=0, n_dense_layers=0, experts_held=None))
+
+
 def _init_ffn_block(key, cfg: TransformerConfig):
     """An FFN alone (kind "ffn"): `_init_ffn`'s norm `ln2` and its dense
     matrices or experts."""
     return _init_ffn(jax.random.split(key, 3), cfg)
-
-
-def _init_dense_block(key, cfg: TransformerConfig):
-    """An attention block of a mixture that keeps the dense FFN of d_ff."""
-    return _init_block(key, dataclasses.replace(cfg, n_experts=0, n_dense_layers=0, experts_held=None))
 
 
 def _init_gmu_block(key, cfg: TransformerConfig):
@@ -673,8 +732,9 @@ def _init_gmu_block(key, cfg: TransformerConfig):
 
 
 _INIT_KIND = {"attn": ("blocks", _init_block), "ssm": ("ssm_blocks", _init_ssm_block),
-              "attn_dense": ("dense_blocks", _init_dense_block),
-              "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _init_dense_block),
+              "attn_dense": ("dense_blocks", _without_experts(_init_block)),
+              "attn_win": ("win_blocks", _init_block), "attn_win_dense": ("win_dense_blocks", _without_experts(_init_block)),
+              "kda": ("kda_blocks", _init_kda_block), "kda_dense": ("kda_dense_blocks", _without_experts(_init_kda_block)),
               "gmu": ("gmu_blocks", _init_gmu_block),
               "attn_cross": ("cross_blocks", functools.partial(_init_block, keys_and_values=False)),
               "mamba2": ("mamba2_blocks", _init_mamba2_block),
@@ -687,8 +747,8 @@ def init_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
     the state-space layers', where the pattern has any; `dense_blocks`: a
     mixture's leading dense layers'; `win_blocks`, `win_dense_blocks`: the
     window layers' of either FFN; `gmu_blocks`, `cross_blocks`: the gated memory
-    units' and the cross layers'; `mamba2_blocks`, `alone_blocks`, `ffn_blocks`:
-    the half layers' (`_INIT_KIND`: a stack a kind).  Layer i's
+    units' and the cross layers'; `kda_blocks`, `kda_dense_blocks`: the kda layers' of either FFN;
+    `mamba2_blocks`, `alone_blocks`, `ffn_blocks`: the half layers' (`_INIT_KIND`: a stack a kind).  Layer i's
     key is the i-th of one split whatever its kind."""
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
     block_keys = jax.random.split(k_blocks, cfg.n_layers)
@@ -1036,10 +1096,14 @@ def _diff_combine(bp, attn, lambda_init, cfg: TransformerConfig):
     return out.astype(cfg.dtype)
 
 
-def _project_latent(bp, y, cfg: TransformerConfig, positions):
+def _project_latent(bp, y, cfg: TransformerConfig, positions, rotate: bool = True):
     """Latent attention's way to a block's q, k, v from its normed input y
     [B, T, E]: q [B, T, H, nope + rope] through the low rank c_q =
-    norm(y wq_a), its rotary part rotated; and what a token is cached as, the
+    norm(y wq_a), or straight through `wq` where the block holds no low rank
+    (`cfg.q_lora_rank` 0), its rotary part rotated, or with `rotate` False carried
+    as it is (the query's and the key's alike: a layer without a positional
+    embedding, whose "rope" dimensions are 64 more that every head shares); and
+    what a token is cached as, the
     rotated key k_rope [B, T, rope] that every head shares and the latent
     c_kv = norm((y wkv_a)[:kv_lora_rank]) [B, T, kv_lora_rank], of which each
     head's keys and values are up-projections (`_latent_expand`, or absorbed
@@ -1049,11 +1113,16 @@ def _project_latent(bp, y, cfg: TransformerConfig, positions):
     dt = y.dtype
     h, dn, dr, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     with jax.named_scope("attn.mla.q"):
-        c_q = _rms_norm(y @ bp["wq_a"].astype(dt), bp["q_a_norm"])
-        q = _project_heads(c_q, bp["wq_b"]).reshape(b, t, h, dn + dr)
+        if "wq" in bp:
+            q = _project_heads(y, bp["wq"]).reshape(b, t, h, dn + dr)
+        else:
+            c_q = _rms_norm(y @ bp["wq_a"].astype(dt), bp["q_a_norm"], cfg.norm_eps)
+            q = _project_heads(c_q, bp["wq_b"]).reshape(b, t, h, dn + dr)
     with jax.named_scope("attn.mla.kv"):
         kv = y @ bp["wkv_a"].astype(dt)
-        c_kv = _rms_norm(kv[..., :r], bp["kv_a_norm"])
+        c_kv = _rms_norm(kv[..., :r], bp["kv_a_norm"], cfg.norm_eps)
+    if not rotate:
+        return q, kv[..., r:], c_kv
     with jax.named_scope("attn.rope"):
         q_rope, k_rope = _rope(q[..., dn:], kv[:, :, None, r:], positions, cfg)
         q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
@@ -1208,7 +1277,7 @@ def _attention_half(bp, x, cfg: TransformerConfig, positions, core, kind: str = 
             if kv_of is not None:
                 y_kv = _norm(kv_of, bp, "ln1", cfg)
     if cfg.latent:
-        q, k, v = _project_latent(bp, y, cfg, positions)
+        q, k, v = _project_latent(bp, y, cfg, positions, cfg.rotates(kind))
     else:
         with jax.named_scope("attn.qkv"):
             q, k, v = _project_qkv(bp, y, cfg, y_kv)
@@ -1478,6 +1547,179 @@ def _mamba2_mixer(bp, x, cfg: TransformerConfig, state, keep=None):
         gated = (gated.reshape(bsz, t, c) * bp["ssm_norm"].astype(f)).astype(x.dtype)
     with jax.named_scope("ssm.out"):
         return gated @ bp["ssm_out"].astype(x.dtype), (window, h_new)
+
+
+def _kda_step(q, k, v, g, beta, s):
+    """One position of the delta rule a row and head: S' = Diag(exp(g)) S, u = beta (v - S'^T k), S = S' + k u^T,
+    o = S^T q.  q, k, v, g: [B, H, D]; beta: [B, H]; s: [B, H, Dk, Dv], all float32.  Returns (o [B, H, Dv], S).
+    Elementwise and sums over the key axis, not dots: the state is read and written once where the sums fuse with
+    what makes them, and o = S'^T q + (q . k) u needs no second pass over the new state."""
+    s = jnp.exp(g)[..., None] * s
+    u = beta[..., None] * (v - jnp.sum(k[..., None] * s, axis=-2))
+    o = jnp.sum(q[..., None] * s, axis=-2) + jnp.sum(q * k, axis=-1, keepdims=True) * u
+    return o, s + k[..., None] * u[..., None, :]
+
+
+def _kda_pairs(a, k, since):
+    """M[i, j] = sum_c a_i[c] k_j[c] exp(since_i[c] - since_j[c]) for the positions i >= j of a chunk, for each `a` of
+    the list (the chunk's keys, its queries), 0 for i < j.  a, k, since: [..., C, D]; since, the log of the decay from
+    the chunk's start through each position, falls with the position, so every exponent taken is <= 0: exp(-since_j)
+    by itself overflows float32 where a channel forgets fast.  The chunk is KDA_SUB-position sub-blocks.  A row i of
+    sub-block I meets a column j of an EARLIER sub-block through I's first position r, exp(since_i - since_r) on the
+    row's side and exp(since_r - since_j) on the column's: a matrix product a sub-block row.  Inside a sub-block the
+    exponents are taken pair by pair, [.., 16, 16, D] at once, and summed over the channels.  Returns [..., C, C] each."""
+    *lead, c, d = k.shape
+    nb, sb = c // KDA_SUB, KDA_SUB
+    blocks = lambda x: x.reshape(*lead, nb, sb, d)
+    exact = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    since_b = blocks(since)
+    first = since_b[..., 0, :]  # [..., nb, D]
+    rows_decay = jnp.exp(since_b - first[..., None, :])
+    earlier = (jnp.arange(c)[None, :] < (jnp.arange(nb) * sb)[:, None])[..., None]  # [nb, C, 1]: column j before sub-block I
+    cols = k[..., None, :, :] * jnp.exp(jnp.where(earlier, first[..., None, :] - since[..., None, :, :], -jnp.inf))
+    lower = (jnp.arange(sb)[:, None] >= jnp.arange(sb)[None, :])[..., None]
+    inside = jnp.exp(jnp.where(lower, since_b[..., :, None, :] - since_b[..., None, :, :], -jnp.inf))  # [..., nb, i, j, D]
+    inside = inside * blocks(k)[..., None, :, :]
+    own = jnp.eye(nb, dtype=k.dtype)[:, None, :, None]  # a sub-block's own columns among the chunk's
+    out = []
+    for x in a:
+        across = exact("...Iic,...Ijc->...Iij", blocks(x) * rows_decay, cols)  # [..., nb, sb, C]
+        within = jnp.sum(blocks(x)[..., :, None, :] * inside, axis=-1)  # [..., nb, sb, sb]
+        out.append((across.reshape(*lead, nb, sb, nb, sb) + within[..., :, :, None, :] * own).reshape(*lead, c, c))
+    return out
+
+
+def _kda_chunked(q, k, v, g, beta, s0, chunk: int):
+    """The delta rule of `_kda_step` over whole sequences from s0, in chunks of `chunk` positions (Kimi Linear,
+    arXiv:2510.26692, section 3; Gated DeltaNet's WY form with a decay a channel).  q, k, v, g: [B, T, H, D]; beta:
+    [B, T, H]; s0: [B, H, Dk, Dv], all float32.  Returns (o [B, T, H, Dv], S after the last position).
+
+    With since_i the log-decay from the chunk's start through position i and S_0 the state before the chunk, S_i =
+    Diag(exp(since_i)) S_0 + sum_{j<=i} Diag(exp(since_i - since_j)) k_j u_j^T, where the u_j solve the unit lower
+    triangular system (I + A) U = beta (V - (K exp(since)) S_0), A[i, j] = beta_i sum_c k_i k_j exp(since_i -
+    since_j) for j < i.  All chunks side by side (`_kda_pairs`, one triangular solve of [K exp(since) | V] a chunk and
+    head); then, chunk after chunk, T / chunk dependent steps of matrix products hand the state on: U = U' - W S, o =
+    (Q exp(since)) S + A_qk U, S = Diag(exp(since_C)) S + (K exp(since_C - since))^T U.  Float32 operands, exact
+    products (`Precision.HIGHEST`: the chunked form is about two per cent of a prefill's operations at Kimi Linear's
+    widths), so the state a prefill hands the decode steps is the recurrence's to float32's rounding.  The tail of the
+    last chunk, and a left pad, are positions of k = 0, beta = 0, g = 0: they leave S as it is."""
+    bsz, t, hh, d = q.shape
+    nc = -(-t // chunk)
+    exact = functools.partial(jnp.einsum, precision=lax.Precision.HIGHEST)
+    # [B, T, H, ...] -> [B, chunks, H, C, ...]
+    chunked = lambda x: jnp.moveaxis(
+        jnp.pad(x, ((0, 0), (0, nc * chunk - t)) + ((0, 0),) * (x.ndim - 2)).reshape(bsz, nc, chunk, *x.shape[2:]), 2, 3)
+    q, k, v, g, beta = chunked(q), chunked(k), chunked(v), chunked(g), chunked(beta)
+    since = jnp.cumsum(g, axis=-2)
+    decay = jnp.exp(since)
+    a_kk, a_qk = _kda_pairs([k, q], k, since)
+    idx = jnp.arange(chunk)
+    system = jnp.eye(chunk, dtype=k.dtype) + jnp.where(idx[:, None] > idx[None, :], a_kk * beta[..., None], 0.0)
+    rhs = beta[..., None] * jnp.concatenate([k * decay, v], axis=-1)
+    solved = jax.scipy.linalg.solve_triangular(system, rhs, lower=True, unit_diagonal=True)
+    w, u_own = solved[..., :d], solved[..., d:]
+    a_qk = jnp.where(idx[:, None] >= idx[None, :], a_qk, 0.0)
+    to_end = k * jnp.exp(since[..., -1:, :] - since)
+
+    def across(s, at):
+        w_c, u_c, q_c, a_c, k_c, whole = at
+        u = u_c - exact("bhck,bhkv->bhcv", w_c, s)
+        o = exact("bhck,bhkv->bhcv", q_c, s) + exact("bhij,bhjv->bhiv", a_c, u)
+        return whole[..., None] * s + exact("bhck,bhcv->bhkv", k_c, u), o
+
+    per_chunk = lambda x: jnp.moveaxis(x, 1, 0)
+    s, o = lax.scan(across, s0, tuple(map(per_chunk, (w, u_own, q * decay, a_qk, to_end, decay[..., -1, :]))))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [chunks, B, H, C, D] -> [B, chunks, C, H, D]
+    return o.reshape(bsz, nc * chunk, hh, d)[:, :t], s
+
+
+def _causal_conv(padded, taps, t: int):
+    """A causal depthwise convolution: padded [B, K-1+T, C], the K - 1 inputs before the sequence's first and then
+    its own; taps [K, C].  Returns [B, T, C]: position i from the inputs i-K+1 .. i."""
+    return sum(padded[:, j:j + t] * taps[j] for j in range(taps.shape[0]))
+
+
+def _kda_zero_state(cfg: TransformerConfig, batch: int):
+    """The state before a sequence starts: (window, S) as `_kda_mixer` takes them."""
+    with jax.named_scope("ssm.state"):
+        return (jnp.zeros((batch, cfg.ssm_d_conv - 1, cfg.conv_width), cfg.dtype),
+                jnp.zeros((batch, cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_head_dim), SSM_STATE_DTYPE))
+
+
+def _kda_gates(bp, u, cfg: TransformerConfig, keep=None):
+    """What a KDA layer's normed input u [B, T, E] says of the delta rule beside q, k, v: (the log-decay g [B, T, H, D]
+    = -exp(a_log) softplus(w_f2 w_f1 u + dt_bias), a key channel's own; beta [B, T, H] = sigmoid(w_b u); the
+    output's gate sigmoid(w_g2 w_g1 u) [B, T, H D]), float32.  keep: [B, T] bool, False at a left pad, where g = 0 and
+    beta = 0: the state passes a pad as it is."""
+    f = SSM_STATE_DTYPE
+    low = lambda first, second: (u @ bp[first].astype(u.dtype)) @ bp[second].astype(u.dtype)
+    g = jax.nn.softplus(low("kda_f1", "kda_f2").astype(f) + bp["dt_bias"].astype(f))
+    g = -jnp.exp(bp["a_log"].astype(f))[:, None] * g.reshape(*u.shape[:2], cfg.kda_n_heads, cfg.kda_head_dim)
+    beta = jax.nn.sigmoid((u @ bp["kda_b"].astype(u.dtype)).astype(f))
+    if keep is not None:
+        g, beta = jnp.where(keep[..., None, None], g, 0.0), jnp.where(keep[..., None], beta, 0.0)
+    return g, beta, jax.nn.sigmoid(low("kda_g1", "kda_g2").astype(f))
+
+
+def _kda_mixer(bp, x, cfg: TransformerConfig, state, keep=None, update=None):
+    """A Kimi Delta Attention mixer with its norm, for a whole sequence, a prompt's prefill and a decode step alike:
+    w_out(norm_h(o) * sigmoid(w_g2 w_g1 u)), u = norm(x); [q | k | v] = silu(conv(w_qkv u)), q and k L2-normed a head
+    and q scaled by D^-1/2; g = -exp(a_log) softplus(w_f2 w_f1 u + dt_bias) a channel, beta = sigmoid(w_b u) a head;
+    o the delta rule's read-out (`_kda_chunked`; one position: `_kda_step`); norm_h an RMSNorm over a head's D with
+    one weight the heads share.  x: [B, T, E]; state: (the convolution's window, the last ssm_d_conv - 1 rows of
+    [q | k | v] as projected [B, K-1, 3 H D]; S [B, H, D, D]) as the tokens before left it, zeros before a sequence
+    starts (`_kda_zero_state`); keep: [B, T] bool, False at a left pad, None for none.  Returns (the mixer's result
+    [B, T, E], the state after the last position).  update: for T = 1, `update(q, k, v, g, beta) -> o [B, H, D]` moves
+    the rows' states on where its caller keeps them and reads them out (a decode step over the cache's stacks through
+    ops/kda.py's kernel: models/generate.py); S is then not this function's to hold, and comes and goes as None.
+
+    At a pad the projection is zeroed, so the convolution sees what an unpadded prompt sees before its start and k = 0,
+    and g = 0 and beta = 0: S passes the pads unchanged and the state after the last token is the unpadded prompt's.
+    q, k, v after the convolution, g, beta, S (in the cache too) and o up to the gate are float32 (SSM_STATE_DTYPE),
+    the rest cfg.dtype."""
+    window, s = state
+    bsz, t, _ = x.shape
+    hh, d, f = cfg.kda_n_heads, cfg.kda_head_dim, SSM_STATE_DTYPE
+    heads = lambda a: a.reshape(bsz, t, hh, d)
+    with jax.named_scope("norm"):
+        u = _norm(x, bp, "ln1", cfg)
+    with jax.named_scope("kda.proj"):
+        qkv = _project_heads(u, bp["kda_qkv"])
+        if keep is not None:
+            qkv = jnp.where(keep[..., None], qkv, 0)
+    with jax.named_scope("kda.conv"):
+        padded = jnp.concatenate([window.astype(x.dtype), qkv], axis=1)  # [B, K-1+T, 3 H D]
+        xc = jax.nn.silu(_causal_conv(padded, bp["kda_conv"].astype(x.dtype), t)).astype(f)
+        q, k, v = (heads(a) for a in jnp.split(xc, 3, axis=-1))
+        unit = lambda a: a * lax.rsqrt(jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+        q, k = unit(q) * d ** -0.5, unit(k)
+    with jax.named_scope("ssm.state"):
+        window = padded[:, t:].astype(window.dtype)
+    with jax.named_scope("kda.gates"):
+        g, beta, gate = _kda_gates(bp, u, cfg, keep)
+    if update is not None:
+        with jax.named_scope("kda.step"):
+            o, s_new = update(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])[:, None], None
+    elif t == 1:
+        with jax.named_scope("kda.step"):
+            o, s_new = _kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s.astype(f))
+            o = o[:, None]
+    else:
+        with jax.named_scope("kda.chunk"):
+            o, s_new = _kda_chunked(q, k, v, g, beta, s.astype(f), KDA_CHUNK)
+    if s_new is not None:
+        with jax.named_scope("ssm.state"):
+            s_new = s_new.astype(s.dtype)
+    with jax.named_scope("kda.out"):
+        o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * bp["kda_norm"].astype(f)
+        gated = (o.reshape(bsz, t, hh * d) * gate).astype(x.dtype)
+        return gated @ bp["kda_out"].astype(x.dtype), (window, s_new)
+
+
+def _kda_half(bp, x, cfg: TransformerConfig, state, keep=None):
+    """A kda block's first half: x + `_kda_mixer` of it.  Returns (x, the state after the last position)."""
+    out, state = _kda_mixer(bp, x, cfg, state, keep)
+    return x + out, state
 
 
 def _ffn(bp, y, cfg: TransformerConfig, live=None, experts=None, manual_axes=frozenset(), keep: bool = False):
@@ -1871,7 +2113,7 @@ REMAT_MARGIN = 64
 LOSS_CHUNK = 2 ** 25
 
 # the kinds of layer that attend to nothing: a checkpoint of theirs holds none of KEPT_NAMES
-_NO_ATTENTION = ("ssm", "gmu", "mamba2", "ffn")
+_NO_ATTENTION = ("ssm", "gmu", "mamba2", "ffn", "kda", "kda_dense")
 
 
 def _memory_limit(mesh) -> Optional[int]:
@@ -2059,6 +2301,9 @@ def _stage_forward(stacks, x, cfg: TransformerConfig, mesh=None, manual_axes=fro
             return x, jnp.zeros((), jnp.float32) if aux is None else aux
 
         blocks["ffn"] = ffn_alone
+        # a kda block: its mixer from the zero state, then the FFN its weights hold
+        blocks["kda"] = blocks["kda_dense"] = lambda bp, x, layer=None: ffn_alone(
+            bp, _kda_half(bp, x, cfg, _kda_zero_state(cfg, x.shape[0]))[0])
         if cfg.remat:
             names = KEPT_NAMES * keeps.names + FFN_NAMES * ffn
             policy = jax.checkpoint_policies.save_only_these_names(*names) if names else None
@@ -2372,6 +2617,10 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer=None, learning_rate=
     (params, opt_state, batch) -> (params, opt_state, loss)."""
     import optax
 
+    if "kda" in (cfg.layer_mixers or ()):
+        raise NotImplementedError(
+            "a training step through kda layers: the chunked delta rule's backward pass (`_kda_chunked`: the "
+            "triangular solve's and the carried state's) is neither written nor measured; kda layers are served")
     if optimizer is None:
         optimizer = optax.adamw(learning_rate, weight_decay=0.01)
 

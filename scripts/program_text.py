@@ -25,7 +25,7 @@ import sys
 
 CELLS = ("chat-closed6", "olmoe-closed6", "jamba-closed6", "sdar-closed6", "axk1-rag-closed6",
          "kexaone-longrag-closed6", "phi4flash-reason-closed8", "nemotron3nano-reason-closed8",
-         "keye-longdoc-closed4")
+         "keye-longdoc-closed4", "kimilinear-reason-closed8")
 
 
 def hashes(root: str) -> dict:
@@ -111,6 +111,14 @@ def hashes(root: str) -> dict:
         sparse = importlib.import_module("cluster_anywhere_tpu.ops.sparse_attention")
     except ImportError:  # a checkout from before learned sparse attention
         return out
+    try:  # the KDA decode update in `kimilinear-reason-closed8`'s shapes: 32 slots of 32 heads over 20 layers' state
+        kda = importlib.import_module("cluster_anywhere_tpu.ops.kda")
+        vec = shape((32, 32, 128), jnp.float32)
+        out["kernel.kda_update"] = jaxpr(lambda q, k, v, g, b, s, r: kda.kda_decode_update(q, k, v, g, b, s, 7, r), vec, vec, vec,
+                                         vec, shape((32, 32), jnp.float32), shape((20, 32, 32, 128, 128), jnp.float32),
+                                         shape((33,), jnp.int32))
+    except ImportError:  # a checkout from before the kernel
+        pass
     # its three kernels in `keye-longdoc-closed4`'s shapes: an admit's bucket of 8,192
     t, f32, i32 = 8192, jnp.float32, jnp.int32
     out["kernel.dsa_index"] = jaxpr(sparse.index_scores_kernel, shape((1, t, 16, 64), jnp.bfloat16),

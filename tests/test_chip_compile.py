@@ -947,3 +947,38 @@ def test_the_sparse_prefill_goes_through_its_three_kernels_and_fits(v5e, sparse_
     assert beside == KEYE_SLOTS * KEYE_T_MAX * 104_448
     held = memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes + beside
     assert 9.4e9 < memory.argument_size_in_bytes and held < V5E_BYTES_LIMIT
+
+
+# Kimi-Linear-48B-A3B's widths (KDA 32 x 128 with a convolution of 4, latent attention 512 + 64 with a direct query
+# and no rotation, 16 of 256 sigmoid experts of 1024 held, a dense first FFN of 9216), five layers deep: the dense
+# first layer, a run of two KDA layers, a latent layer, a KDA layer
+KIMI5 = dict(
+    vocab_size=512, n_layers=5, d_model=2304, n_heads=32, n_kv_heads=32, d_head=192, d_ff=9216,
+    layer_mixers=("kda", "kda", "kda", "attn", "kda"), rotary=False, norm_eps=1e-5,
+    kv_lora_rank=512, q_lora_rank=0, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    kda_n_heads=32, kda_head_dim=128, ssm_d_conv=4,
+    n_dense_layers=1, d_expert=1024, n_shared_experts=1, n_experts=256, n_experts_per_tok=8, moe_gated=True,
+    moe_renormalize=True, moe_scoring="sigmoid", moe_routed_scale=2.446, experts_held=(0, 16), param_dtype=jnp.bfloat16,
+)
+
+
+def test_kda_decode_step_moves_the_matrix_state_on_through_the_kernel_where_it_lies(v5e):
+    """The decode step at Kimi Linear's widths and the cell's cache (32 slots x 4,096) as the chip runs it.  The KDA
+    layers' matrix state, [4, 32, 32, 128, 128] float32 (0.27 GB here, 1.34 GB over the published 20), is handed to
+    ops/kda.py's kernel as the stack it is, once a run of layers' loop body, and is the kernel's result in place: the
+    program holds no copy of it, nor of a layer's (67 MB: in plain XLA a layer's state was taken out of the stack,
+    read twice and written back, 6.3 ms of a 17.7 ms step).  The latent layer's cache is written a row a slot in
+    place, and nothing of its size is copied."""
+    cfg = transformer.TransformerConfig(**KIMI5)
+    assert [r[2] for r in transformer._layer_runs(cfg.layer_kinds)] == [1, 2, 1, 1]
+    compiled, params, cache = _compiled_decode_step(cfg, v5e[0], 32, 4096, on_kernel=True)
+    assert {n: c.shape for n, c in cache.items()} == {
+        "ckv": (1, 32, 4096, 512), "kr": (1, 32, 4096, 128), "conv": (4, 32, 3, 12288), "h": (4, 32, 32, 128, 128)}
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_update[\w.]* = .* custom-call\(", text)) == 3 and _has_kernel(compiled)
+    a_state = 32 * 32 * 128 * 128
+    for dtype, n, line in _buffers(compiled, width=None):
+        shape, op = re.match(r"(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", line).groups()
+        if dtype == "f32" and n >= a_state:
+            assert op in ("parameter", "get-tuple-element", "bitcast", "while", "tuple", "custom-call"), line[:200]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6

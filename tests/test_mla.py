@@ -329,7 +329,7 @@ def test_a_held_share_computes_its_experts_part_and_nothing_else(scoring):
 
 
 def test_configurations_that_are_not_built_are_refused_by_name():
-    with pytest.raises(ValueError, match="latent attention takes q_lora_rank"):
+    with pytest.raises(ValueError, match="latent attention takes qk_nope_head_dim, qk_rope_head_dim and v_head_dim"):
         TransformerConfig(kv_lora_rank=32)
     with pytest.raises(NotImplementedError, match="blocks of positions through a latent cache"):
         TransformerConfig(**LATENT, block_length=4)
