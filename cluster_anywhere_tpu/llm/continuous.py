@@ -99,6 +99,7 @@ from ..models.generate import (
     recurrent_state_bytes,
 )
 from ..models.transformer import TransformerConfig
+from ..parallel.moe import takes_loop
 from ..util import tracing
 
 
@@ -488,6 +489,13 @@ class ContinuousBatcher:
         # live or not), and what an admit installs: 0 for attention alone
         self._ssm_slot_bytes = recurrent_state_bytes(self.cache) // slots
         self._ssm_step_bytes = 2 * slots * self._ssm_slot_bytes
+        # a replica that holds a share of the experts: a decode step's expert layers, and those of them that
+        # loop over the experts touched (parallel/moe.py takes_loop: the step's rows decide as the program is
+        # traced, so the host knows without a readback)
+        held = [] if cfg.experts_held is None else [b for b in params.values() if isinstance(b, dict) and "router" in b]
+        self._moe_step_held_layers = sum(b["router"].shape[0] for b in held)
+        loops = takes_loop(slots * (2 * self._block or 1), cfg.experts_held)
+        self._moe_step_loop_layers = self._moe_step_held_layers if loops else 0
         # the slots of a layer's keys that a step could read (as many of its values, or its
         # latent rows; over layers of two extents, their mean): what `cache_rows_read` is a
         # share of; 0 for recurrent state alone
@@ -562,6 +570,9 @@ class ContinuousBatcher:
             # a replica that holds a share of the experts: its admits' expert layers, and
             # those of them that took the compact buffer (parallel/moe.py)
             "moe_held_layers": 0, "moe_compact_layers": 0,
+            # the same replica's decode steps: their expert layers, and those of them that looped
+            # over the experts touched in place of the grouped matmul
+            "moe_step_held_layers": 0, "moe_step_loop_layers": 0,
             # admits that found no compiled prefill for their padded length
             # and traced one; stays where it is once every bucket is warm
             "prefill_traces": 0,
@@ -791,7 +802,9 @@ class ContinuousBatcher:
             touched, *held = np.ravel(touched)
             said.update(moe_rows=positions, moe_experts_touched=float(touched))
             if held:
-                said.update(moe_held_assignments=float(held[0]))
+                said.update(moe_held_assignments=float(held[0]), moe_loop_layers=self._moe_step_loop_layers)
+                self.stats["moe_step_held_layers"] += self._moe_step_held_layers
+                self.stats["moe_step_loop_layers"] += self._moe_step_loop_layers
             self.stats["moe_assignments"] += positions * self.cfg.n_experts_per_tok
             self.stats["moe_experts_touched"] += float(touched)
         if self._ssm_step_bytes:

@@ -38,19 +38,20 @@ branches give the same sums up to float32 reassociation.
 exchange over 'ep' will fill: the rows a chip receives from the all-to-all are
 exactly the sorted head that it holds experts for.
 
-A held share of ungated experts given few rows (FEW_ROWS: a decode step's
-slots, and a prefill's smaller buckets) takes neither: it loops over the
-experts that were given a row, as many turns as there are, and each turn reads
-that expert's two matrices where they lie in the stack and takes every row
-through them, weighted by what the row gives that expert (0 for most).  A
+A held share given few rows (FEW_ROWS: a decode step's slots, and a prefill's
+smaller buckets) takes neither: it loops over the experts that were given a
+row, as many turns as there are, and each turn reads that expert's matrices
+(two, or a gated expert's three) where they lie in the stack and takes every
+row through them, weighted by what the row gives that expert (0 for most).  A
 step's 8 live rows give a share of 16 of 128 experts six assignments a layer
-on some 2 experts, and the grouped matmul takes 0.17 ms for each expert that
-has a row, however few (20 MB at a seventh of the chip's bandwidth); the loop
-takes the time to read the touched experts once (FEW_ROWS has the readings).
-The gated form keeps the grouped matmul: its three cells' programs are not this
-loop's to move unmeasured.  The loop has as many turns as the rows decide, so
-it is a forward path only: a gradient through so few rows of a held share is
-refused by jax, by name.
+on some 2 experts.  For each expert that has a row, however few, the grouped
+matmul takes 0.17 ms over ungated experts of 20 MB (a seventh of the chip's
+bandwidth) and the loop the time to read it once; gated experts the grouped
+matmul reads nearly as fast as the loop, and what the loop saves there is a
+layer's fixed part: the conditional, the compact rows' gathers and the kernel's
+metadata, 22 to 50 us a layer (FEW_ROWS has the readings).  A loop of as many
+turns as the rows decide is one jax does not differentiate: a gradient asked
+through it is the grouped matmul's over all N x k rows, which are the same sums.
 
 `moe_ffn` is expert parallelism for training over an 'ep' mesh axis:
 switch-style top-1 routing with a capacity, tokens exchanged with
@@ -112,17 +113,41 @@ COMPACT_SHARE = 4
 COMPACT_LOOKUP_BYTES = 96 * 2 ** 20
 
 
-# A held share of ungated experts given at most this many rows loops over its touched experts (`routed_ffn`,
-# module docstring) in place of the grouped matmul.  Measured on the chip at Nemotron-3-Nano's widths (an expert
-# 2,688 x 1,856 stored 1,920 wide, 20.3 MB; 16 held of 128, six a token; scripts/moe_few_rows_sweep.py, PERF.md
-# section 6, PR 50), microseconds a layer, grouped matmul -> loop.  A decode step's 32 rows, 8 live, all sending t
-# assignments to the same t held experts: t = 1: 217 -> 58, 2: 390 -> 79, 3: 564 -> 112, 4: 737 -> 146, 6: 1,085 ->
-# 213; spread over 8 experts 1,430 -> 280, over all 16: 2,817 -> 548.  The grouped matmul is 44 + 173 a touched
-# expert (117 GB/s), the loop 12 + 33.5 (606 GB/s of the chip's 819).  Every row live, a random router: 32 rows
-# 2,470 -> 481, 64: 2,822 -> 531, 128: 3,352 -> 576, 256: 3,550 -> 665, 512: 3,883 -> 1,098 (all 16 touched: the
-# loop takes every row through every touched expert, 11 GFLOP an expert at 512 rows, and is still 3.5 times
-# faster).  1,024 rows were not measured: the largest bucket keeps the compact grouped path.
+# A held share given at most this many rows loops over its touched experts (`routed_ffn`, module docstring) in
+# place of the grouped matmul.  Measured on the chip (scripts/moe_few_rows_sweep.py), microseconds a layer,
+# grouped matmul -> loop.
+#
+# Ungated, Nemotron-3-Nano's widths (an expert 2,688 x 1,856 stored 1,920 wide, 20.3 MB; 16 held of 128, six a
+# token; PERF.md section 6, PR 50).  A decode step's 32 rows, 8 live, all sending t assignments to the same t held
+# experts: t = 1: 217 -> 58, 2: 390 -> 79, 3: 564 -> 112, 4: 737 -> 146, 6: 1,085 -> 213; spread over 8 experts
+# 1,430 -> 280, over all 16: 2,817 -> 548.  The grouped matmul is 44 + 173 a touched expert (117 GB/s), the loop
+# 12 + 33.5 (606 GB/s of the chip's 819).  Every row live, a random router: 32 rows 2,470 -> 481, 64: 2,822 -> 531,
+# 128: 3,352 -> 576, 256: 3,550 -> 665, 512: 3,883 -> 1,098, 1,024: 4,212 -> 2,010 (PR 61; all 16 touched: the
+# loop takes every row through every touched expert, 11 GFLOP an expert at 512 rows).
+#
+# Gated (PERF.md section 6, PR 61; the compact buffer behind its conditional -> the loop).  The grouped matmul
+# reads gated experts at 500-670 GB/s, not at the ungated kind's 117: what the loop takes out is the fixed part
+# of a layer (the conditional, the gathers of the compact rows, the kernel's metadata) and a tenth of the read.
+#   Keye-VL 2,048 x 768, 9.4 MB, 16 of 128, a step's 4 rows: 35 + 17.3 t -> 13 + 17.2 t (t = 1: 53 -> 30, 3: 88 -> 63,
+#     8: 171 -> 150); all live, 64 rows 436 -> 336, 256: 661 -> 422, 512: 678 -> 573, 1,024: 842 -> 975.
+#   Kimi-Linear 2,304 x 1,024, 14.2 MB, 16 of 256, 32 rows of which 8 live: 44 + 28.2 t -> 12.5 + 26.4 t (t = 1:
+#     73 -> 39, 3: 129 -> 92, 8: 269 -> 224); 64 rows 511 -> 430, 256: 918 -> 550, 512: 993 -> 828, 1,024: 1,115 -> 1,411.
+#   K-EXAONE 6,144 x 2,048, 75.5 MB, 8 of 128, 32 rows of which 6 live: 55 + 112 t -> 12 + 108 t (t = 1: 167 -> 120,
+#     3: 391 -> 336, 8: 952 -> 876); 64 rows 1,077 -> 879, 256: 2,005 -> 1,019, 512: 2,155 -> 1,789, 1,024: 2,408 -> 3,449.
+#   A.X-K1 7,168 x 2,048, 88 MB, 12 of 192, 32 rows of which 6 live: 57 + 132 t -> 13 + 124.5 t (t = 1: 191 -> 138,
+#     3: 455 -> 387, 8: 1,113 -> 1,009); 64 rows 1,548 -> 1,265, 256: 3,460 -> 1,745, 512: 3,565 -> 3,031, 1,024:
+#     3,843 -> 5,955.
+# All four cross between 512 and 1,024 rows, whatever the expert's width: past some 250 rows a turn is bound by its
+# products (every row through every touched expert: rows x the expert's parameters x 2 operations at the matrix
+# peak) and the grouped path by reading the held experts at about 300 GB/s, and both grow with the expert's size
+# alike.  So one constant serves both forms and every width; a bucket of 1,024 keeps the compact grouped path.
 FEW_ROWS = 512
+
+
+def takes_loop(n: int, held) -> bool:
+    """Whether `routed_ffn` given `n` rows and this `held` loops over the experts touched (FEW_ROWS).  Known as
+    the program is traced, so its text holds one path, and the batcher knows which without asking the device."""
+    return held is not None and n <= FEW_ROWS
 
 
 def compact_buffer_rows(n: int, k: int, held: int, routed: int) -> int:
@@ -145,6 +170,17 @@ class RoutedOutput(NamedTuple):
     experts_touched: jax.Array  # int32: experts (of those held) that were given at least one row
     assignments: jax.Array  # int32: (row, expert) pairs that were computed: live rows x k, of which on experts held
     compact: jax.Array  # int32: 1 where a held share's rows went through the compact buffer (module docstring), else 0
+
+
+def _sort_by_expert(expert, n_experts: int):
+    """`expert` [N * k]: each assignment's group, `n_experts` for one that has none here.  Returns (sorted row ->
+    assignment, each group's rows [n_experts], the sorted rows that belong to a group: the sorted head,
+    assignment -> sorted row)."""
+    order = jnp.argsort(expert, stable=True)
+    group_sizes = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0, dtype=jnp.int32)
+    in_groups = jnp.sum(group_sizes)
+    back = jnp.zeros(expert.shape, jnp.int32).at[order].set(jnp.arange(expert.shape[0], dtype=jnp.int32))
+    return order, group_sizes, in_groups, back
 
 
 def _combine_every_row(out, gate, order, back, in_groups):
@@ -255,18 +291,16 @@ def routed_ffn(
         if live is not None:
             # a row that takes no expert sorts behind the last group
             expert = jnp.where(jnp.repeat(live, k), expert, n_experts)
-        order = jnp.argsort(expert, stable=True)  # sorted row -> assignment
-        group_sizes = jnp.sum(expert[:, None] == jnp.arange(n_experts), axis=0, dtype=jnp.int32)
-        in_groups = jnp.sum(group_sizes)  # the sorted rows that belong to an expert held here: the sorted head
-        back = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))  # assignment -> sorted row
+        order, group_sizes, in_groups, back = _sort_by_expert(expert, n_experts)
     n_layers = experts["w_down" if gated else "w_out"].shape[0]
-    every = jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(group_sizes).reshape(-1)
-    grouped = lambda a, w: lax.ragged_dot(a, w.reshape(-1, *w.shape[2:]).astype(dt), every)
+    of_layer = lambda group_sizes, layer: jnp.zeros((n_layers, n_experts), jnp.int32).at[layer].set(group_sizes).reshape(-1)
+    every = of_layer(group_sizes, layer)  # every layer's groups: this layer's alone have rows
 
-    def through_experts(c: int):
+    def through_experts(c: int, x, experts, order, every):
         """The first `c` sorted rows gathered, through their experts: [c, E] in
         x's dtype.  Rows past the last group belong to no expert: their
         product is not defined, and `moe.combine` masks them."""
+        grouped = lambda a, w: lax.ragged_dot(a, w.reshape(-1, *w.shape[2:]).astype(dt), every)
         with jax.named_scope("moe.dispatch"):
             rows = x[order[:c] // k]  # [c, E], expert by expert
         with jax.named_scope("moe.experts"):
@@ -276,16 +310,16 @@ def routed_ffn(
             hidden = activation(grouped(rows, experts["w_in"]))
             return grouped(hidden[:, :experts["w_out"].shape[-2]], experts["w_out"])  # without w_in's columns of zeros
 
-    def every_row():
-        return _combine_every_row(through_experts(n * k), gate, order, back, in_groups)
+    def every_row(x=x, gate=gate, experts=experts, order=order, in_groups=in_groups, back=back, every=every):
+        return _combine_every_row(through_experts(n * k, x, experts, order, every), gate, order, back, in_groups)
 
     def compact_rows(c: int):
-        return _combine_compact(through_experts(c), gate, back, in_groups)
+        return _combine_compact(through_experts(c, x, experts, order, every), gate, back, in_groups)
 
-    def touched_experts():
+    def touched_experts(x, gate, experts, expert, group_sizes, layer):
         """Every row through each held expert that was given one, an expert a
         turn, weighted by what the row gives it and summed in float32: [N, E]."""
-        w_in, w_out = (experts[name].reshape(-1, *experts[name].shape[2:]) for name in ("w_in", "w_out"))
+        flat = {name: experts[name].reshape(-1, *experts[name].shape[2:]) for name in EXPERT_MATRICES if name in experts}
         with jax.named_scope("moe.dispatch"):
             held_idx = expert.reshape(n, k)[:, :, None] == jnp.arange(n_experts)  # [N, k, X]
             weight = jnp.sum(jnp.where(held_idx, gate[:, :, None], 0.0), axis=1)  # [N, X]: 0 where the row takes none of it
@@ -294,17 +328,30 @@ def routed_ffn(
         def turn(i, total):
             with jax.named_scope("moe.experts"):
                 at = layer * n_experts + touched[i]
-                hidden = activation(x @ lax.dynamic_index_in_dim(w_in, at, keepdims=False).astype(dt))
-                out = hidden[:, :w_out.shape[-2]] @ lax.dynamic_index_in_dim(w_out, at, keepdims=False).astype(dt)
+                through = lambda a, name: a @ lax.dynamic_index_in_dim(flat[name], at, keepdims=False).astype(dt)
+                if gated:
+                    out = through(activation(through(x, "w_gate")) * through(x, "w_up"), "w_down")
+                else:
+                    out = through(activation(through(x, "w_in"))[:, :flat["w_out"].shape[-2]], "w_out")
             with jax.named_scope("moe.combine"):
                 return total + out.astype(jnp.float32) * lax.dynamic_index_in_dim(weight, touched[i], 1)
 
         total = lax.fori_loop(0, jnp.sum(group_sizes > 0), turn, jnp.zeros(x.shape, jnp.float32))
         return total.astype(dt)
 
+    def touched_experts_bwd(given, g):
+        """The gradient of all N x k rows through the grouped matmul, which are the loop's sums: jax
+        differentiates no loop of as many turns as the rows decide.  The rows are sorted anew from `expert`."""
+        *floats, expert, _, layer = given
+        order, group_sizes, in_groups, back = _sort_by_expert(expert, n_experts)
+        pulled = jax.vjp(lambda *floats: every_row(*floats, order, in_groups, back, of_layer(group_sizes, layer)), *floats)[1](g)
+        return (*pulled, None, None, None)
+
     c = 0 if held is None else compact_buffer_rows(n, k, n_experts, n_routed)
-    if held is not None and not gated and n <= FEW_ROWS:
-        compact, out = jnp.zeros((), bool), touched_experts()
+    if takes_loop(n, held):
+        loop = jax.custom_vjp(touched_experts)
+        loop.defvjp(lambda *given: (touched_experts(*given), given), touched_experts_bwd)
+        compact, out = jnp.zeros((), bool), loop(x, gate, experts, expert, group_sizes, layer)
     elif c:
         compact = in_groups <= c
         out = lax.cond(compact, lambda: compact_rows(c), every_row)

@@ -126,6 +126,7 @@ def layer_part(name):
         return r.out, r.compact
 
     kept = moe.COMPACT_SHARE, moe.COMPACT_LOOKUP_BYTES, moe._combine_compact
+    moe.FEW_ROWS = 0  # since PR 61 a held share given few rows loops over its experts: here every size takes the grouped paths
     buckets = buckets_of(cell)
     # (rows, live rows, [(tag, COMPACT_SHARE, the combine, COMPACT_LOOKUP_BYTES)]): a decode step's 32 slots with 6
     # and with all of them live, the smallest bucket, the two largest

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.harness import manifest
 from cluster_anywhere_tpu.llm import continuous
 from cluster_anywhere_tpu.models import generate, transformer
 from cluster_anywhere_tpu.models.transformer import TransformerConfig, init_params
@@ -168,7 +169,7 @@ def test_a_held_shares_compact_buffer_gives_what_every_row_gives(gated, scoring,
     token's places are looked up among them; the result is the one all 2,048
     rows give, to a float32 sum's rounding, and `RoutedOutput.compact` says
     which ran."""
-    monkeypatch.setattr(moe, "FEW_ROWS", 0)  # ungated experts at so few rows take the loop below; more rows take this
+    monkeypatch.setattr(moe, "FEW_ROWS", 0)  # a held share at so few rows takes the loop below; more rows take this
     bp = init_moe_params(jax.random.key(3), E, F, X32, jnp.float32, gated=gated)
     bp["router"] = bp["router"] * 40  # scores that differ
     x = tokens(PREFILL, seed=4)
@@ -191,25 +192,38 @@ def test_a_held_shares_compact_buffer_gives_what_every_row_gives(gated, scoring,
 
 @pytest.mark.parametrize("with_live", [False, True], ids=["all-live", "padded"])
 @pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
 @pytest.mark.parametrize("rows", [8, PREFILL])
-def test_a_held_share_of_ungated_experts_given_few_rows_loops_over_the_experts_touched(rows, scoring, with_live, monkeypatch):
+def test_a_held_share_given_few_rows_loops_over_the_experts_touched(rows, gated, scoring, with_live, monkeypatch):
     """At most FEW_ROWS rows (a decode step's, a prefill's smaller buckets): a
     turn of a loop for each held expert that was given a row, every row through
-    it at the weight the row gives it.  The result, the counts and the loss are
-    the ones all N x k sorted rows give through the grouped matmul; relu^2 as
-    well as silu; an expert stored wider than it is (LANES) stays as wide as it is."""
-    assert rows <= moe.FEW_ROWS
-    bp = init_moe_params(jax.random.key(3), E, 200, X32, jnp.float32)
-    assert bp["w_in"].shape[-1] == 256 and bp["w_out"].shape[-2] == 200
+    it at the weight the row gives it.  The result, the counts, the loss and the
+    gradient asked through it are the ones all N x k sorted rows give through
+    the grouped matmul; gated and ungated, relu^2 as well as silu; an ungated
+    expert stored wider than it is (LANES) stays as wide as it is."""
+    assert rows <= moe.FEW_ROWS and moe.takes_loop(rows, (0, 2)) and not moe.takes_loop(rows, None)
+    bp = init_moe_params(jax.random.key(3), E, 200, X32, jnp.float32, gated=gated)
+    if not gated:
+        assert bp["w_in"].shape[-1] == 256 and bp["w_out"].shape[-2] == 200
     bp["router"] = bp["router"] * 40
     x = tokens(rows, seed=4)
     live = jnp.arange(rows) >= rows // 8 if with_live else None
     kw = dict(k=4, renormalize=True, scoring=scoring, scale=2.5, live=live, act="relu2" if scoring == "sigmoid" else "silu")
+    seen = tokens(rows, seed=5)
+
+    def pulled(x, bp):  # a share's result against a cotangent, and its load-balance loss
+        r = routed_share(x, bp, 7, **kw)
+        return jnp.sum(r.out * seen) + r.aux_loss
+
     with jax.default_matmul_precision("highest"):
         got = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+        assert " while[" in str(jax.make_jaxpr(lambda x: routed_share(x, bp, 7, **kw).out)(x))
+        grads = jax.grad(pulled, argnums=(0, 1))(x, bp)
         monkeypatch.setattr(moe, "FEW_ROWS", 0)
         every_row(monkeypatch)
         want = [routed_share(x, bp, s, **kw) for s in (0, 7, 15)]
+        assert " while[" not in str(jax.make_jaxpr(lambda x: routed_share(x, bp, 7, **kw).out)(x))
+        want_grads = jax.grad(pulled, argnums=(0, 1))(x, bp)
     assert sum(int(w.assignments) for w in want) > 0
     for g, w in zip(got, want):
         assert int(g.compact) == 0 == int(w.compact)
@@ -218,15 +232,32 @@ def test_a_held_share_of_ungated_experts_given_few_rows_loops_over_the_experts_t
         assert float(g.aux_loss) == pytest.approx(float(w.aux_loss), rel=1e-6)
         if with_live:
             assert not np.asarray(g.out)[:rows // 8].any()
+    for g, w in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want_grads)):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_allclose(g, w, atol=1e-5 * float(np.abs(np.asarray(w)).max()))
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+def test_rows_just_past_the_limit_keep_the_compact_grouped_path(gated):
+    """A bucket the loop would slow (every row goes through every touched
+    expert) is the compact buffer's behind its conditional, as before."""
+    rows = moe.FEW_ROWS + 8
+    bp = init_moe_params(jax.random.key(3), E, F, X32, jnp.float32, gated=gated)
+    x = tokens(rows, seed=4)
+    assert not moe.takes_loop(rows, (14, 2)) and moe.compact_buffer_rows(rows, 4, 2, X32) == rows
+    traced = str(jax.make_jaxpr(lambda x: routed_share(x, bp, 7, k=4).out)(x))
+    assert traced.count(" cond[") == 1 and " while[" not in traced
+    assert int(routed_share(x, bp, 7, k=4).compact) == 1
 
 
 @pytest.mark.parametrize("crowded", [False, True], ids=["even", "crowded"])
-def test_the_sixteen_shares_add_up_whichever_branch_each_took(crowded):
-    """Dropless behind the conditional: with a router that sends every token's
+def test_the_sixteen_shares_add_up_whichever_branch_each_took(crowded, monkeypatch):
+    """Dropless behind the conditional (the path of rows past the few): with a router that sends every token's
     first two choices to experts 0 and 1, share 0 is given 1,024 rows, twice its
     buffer, and takes all N x k rows; the other fifteen stay compact; the
     sixteen parts are still the uncut layer, row by row.  With an even router
     all sixteen are compact."""
+    monkeypatch.setattr(moe, "FEW_ROWS", 0)
     bp = init_moe_params(jax.random.key(5), E, F, X32, jnp.float32, gated=True)
     bp["router"] = bp["router"] * 40
     x = tokens(PREFILL, seed=6)
@@ -249,14 +280,17 @@ HELD = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=4, d_he
             dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-def test_one_device_loss_with_a_held_share_has_one_gradient_through_either_branch(monkeypatch):
-    """The one-device loss differentiates through `routed_ffn`: at 2 x 256 rows
+@pytest.mark.parametrize("length, compact", [(512, 1), (256, 0)], ids=["compact-buffer", "loop"])
+def test_one_device_loss_with_a_held_share_has_one_gradient_through_either_branch(length, compact, monkeypatch):
+    """The one-device loss differentiates through `routed_ffn`: at 2 x 512 rows
     a held share's layers take the compact buffer (the spy reads each layer's
-    flag as the loss runs), and loss and gradient are those of all N x k rows."""
+    flag as the loss runs), at 2 x 256 the loop over the experts touched, and
+    loss and gradient are those of all N x k rows."""
     cfg = TransformerConfig(**HELD, moe_aux_weight=0.01)
     params = init_params(jax.random.key(7), cfg)
     assert params["blocks"]["w_gate"].shape == (2, 2, 32, 48) and params["blocks"]["router"].shape == (2, 32, 32)
-    batch = {"ids": jnp.asarray(np.random.default_rng(1).integers(0, 64, (2, 257)), jnp.int32)}
+    batch = {"ids": jnp.asarray(np.random.default_rng(1).integers(0, 64, (2, length + 1)), jnp.int32)}
+    assert moe.takes_loop(2 * length, cfg.experts_held) == (not compact)
     took, inner = [], moe.routed_ffn
 
     def spy(*args, **kw):
@@ -268,8 +302,9 @@ def test_one_device_loss_with_a_held_share_has_one_gradient_through_either_branc
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.value_and_grad(transformer.make_loss_fn(cfg))(params, batch)
         jax.effects_barrier()
-        assert took and set(took) == {1}
+        assert took and set(took) == {compact}
         every_row(monkeypatch)
+        monkeypatch.setattr(moe, "FEW_ROWS", 0)
         del took[:]
         want, want_grads = jax.value_and_grad(transformer.make_loss_fn(cfg))(params, batch)
         jax.effects_barrier()
@@ -280,23 +315,32 @@ def test_one_device_loss_with_a_held_share_has_one_gradient_through_either_branc
         assert np.abs(w).max() > 0 and np.max(np.abs(g - w)) < 1e-5 * max(1.0, np.abs(w).max()), name
 
 
-@pytest.mark.parametrize("rows, held", [(PREFILL, None), (1, (0, 2)), (PREFILL, (0, 16))],
-                         ids=["every-expert-held", "a-suffix-steps-one-row", "half-the-experts"])
-def test_no_conditional_is_traced_where_no_buffer_would_gain(rows, held):
-    """Every expert held (OLMoE, SDAR, the one-device train step), rows so few
-    or a share so large that the buffer is over half of N x k: the program is
-    the one before the compact buffer, with no conditional."""
+@pytest.mark.parametrize("rows, held", [(PREFILL, None), (1, (0, 2)), (PREFILL, (0, 16)), (2 * PREFILL, (0, 16)), (32, (0, 2))],
+                         ids=["every-expert-held", "a-suffix-steps-one-row", "half-the-experts-few-rows", "half-the-experts",
+                              "a-held-share-at-a-steps-rows"])
+def test_no_conditional_is_traced_where_no_buffer_would_gain(rows, held, monkeypatch):
+    """Every expert held (OLMoE, SDAR, the one-device train step), a share so
+    large that the buffer is over half of N x k: the program is the one before
+    the compact buffer, with no conditional.  Nor has a held share's program
+    one at a decode step's rows or any other few: it loops over the experts
+    touched, and no grouped matmul is in it."""
     bp = init_moe_params(jax.random.key(0), E, F, X32, jnp.float32, gated=True)
     stack = {k: bp[k][None, :X32 if held is None else held[1]] for k in EXPERT_MATRICES if k in bp}
     fn = lambda x: routed_ffn(x, bp["router"], stack, k=4, held=held)
-    assert " cond[" not in str(jax.make_jaxpr(fn)(tokens(rows)))
+    traced = str(jax.make_jaxpr(fn)(tokens(rows)))
+    assert " cond[" not in traced
+    assert (" while[" in traced, "ragged_dot" in traced) == ((True, False) if moe.takes_loop(rows, held) else (False, True))
     assert int(fn(tokens(rows)).compact) == 0
-    # where it does gain there is exactly one: a prefill's rows, and a decode step's 32 slots
+    if held is None:  # a device that holds every expert runs one program whatever the few are (OLMoE's, SDAR's: unmoved)
+        monkeypatch.setattr(moe, "FEW_ROWS", 0)
+        assert str(jax.make_jaxpr(fn)(tokens(rows))) == traced
+        monkeypatch.undo()
+    # where the buffer does gain there is exactly one: a prefill's rows past the few
     small = {k: v[:, :2] for k, v in stack.items()}
-    for n in (PREFILL, 32):
-        assert moe.compact_buffer_rows(n, 4, 2, X32) == n  # 4 x the even share of n * 4 * 2 / 32: a quarter of the rows
-        traced = str(jax.make_jaxpr(lambda x: routed_ffn(x, bp["router"], small, k=4, held=(0, 2)))(tokens(n)))
-        assert traced.count(" cond[") == 1
+    n = 2 * PREFILL
+    assert moe.compact_buffer_rows(n, 4, 2, X32) == n  # 4 x the even share of n * 4 * 2 / 32: a quarter of the rows
+    traced = str(jax.make_jaxpr(lambda x: routed_ffn(x, bp["router"], small, k=4, held=(0, 2)))(tokens(n)))
+    assert traced.count(" cond[") == 1
 
 # -- where the program calls it from ------------------------------------------
 
@@ -421,16 +465,17 @@ def test_batcher_reports_rows_experts_and_assignments(seen):
 def test_an_admit_of_a_held_share_reports_its_held_and_compact_layers(seen):
     """A replica that holds a share of the experts reads, with an admit's first
     token, how many of the prefill's expert layers there were and how many took
-    the compact buffer: both, in a bucket of 8 rows as in one of 512."""
+    the compact buffer: all in a bucket of 1,024 rows, none in one of 8, whose
+    layers loop over the experts touched."""
     cfg = TransformerConfig(**HELD)
-    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=520,
-                                      prefill_buckets=(8, 512))
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=1032,
+                                      prefill_buckets=(8, 1024))
     rng = np.random.default_rng(3)
-    reqs = [cb.submit(rng.integers(1, 64, n).tolist(), max_new_tokens=3) for n in (5, 300)]
+    reqs = [cb.submit(rng.integers(1, 64, n).tolist(), max_new_tokens=3) for n in (5, 600)]
     cb.pump()
     assert all(r.done and len(r.out_tokens) == 3 for r in reqs)
     admits = [a for name, a in seen if name == "llm.admit" and "moe_held_layers" in a]
-    assert [(a["moe_held_layers"], a["moe_compact_layers"]) for a in admits] == [(2, 2), (2, 2)]
+    assert [(a["moe_held_layers"], a["moe_compact_layers"]) for a in admits] == [(2, 0), (2, 2)]
     # a replica that holds every expert says nothing of it
     del seen[:]
     cfg = TransformerConfig(**SMALL)
@@ -438,3 +483,37 @@ def test_an_admit_of_a_held_share_reports_its_held_and_compact_layers(seen):
     cb.submit([1, 2, 3], max_new_tokens=2)
     cb.pump()
     assert [a for name, a in seen if name == "llm.admit"] and not [a for _, a in seen if "moe_held_layers" in a]
+
+
+@pytest.mark.parametrize("few_rows, loop_layers", [(None, 2), (1, 0)], ids=["as-it-is", "a-step-past-the-few"])
+def test_a_decode_step_of_a_held_share_reports_its_layers_that_loop(few_rows, loop_layers, seen, monkeypatch):
+    """Which path a step's expert layers take is decided as its program is
+    traced (`moe.takes_loop`), so the batcher counts it on the host: every step
+    adds the held expert layers and those that loop over the experts touched,
+    and says the latter on its span.  Over a model that holds every expert
+    neither moves."""
+    if few_rows is not None:
+        monkeypatch.setattr(moe, "FEW_ROWS", few_rows)
+    cfg = TransformerConfig(**HELD)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=32, prefill_buckets=(8,))
+    cb.submit([1, 2, 3], max_new_tokens=4)
+    cb.pump()
+    steps = [a for name, a in seen if name == "llm.step" and "moe_rows" in a]
+    assert len(steps) == 3 == cb.stats["decode_steps"] and all(a["moe_loop_layers"] == loop_layers for a in steps)
+    assert (cb.stats["moe_step_held_layers"], cb.stats["moe_step_loop_layers"]) == (3 * 2, 3 * loop_layers)
+    # the benchmark's reader of one total over another (`held_loop_share.moe`, for a `benchmark` PR to add) reads them
+    share = manifest.load_reader("replica_stat_ratio")
+    args = dict(over="moe_step_loop_layers", under="moe_step_held_layers", scale=100.0)
+    assert share({"replica": {"stats": dict(cb.stats)}}, **args) == 50.0 * loop_layers
+    traced = str(jax.make_jaxpr(lambda *a: continuous._decode_step_rowpos.__wrapped__(*a, cfg=cfg))(
+        cb.params, cb.cache, jnp.zeros((6, 2), jnp.int32), jnp.zeros((2, 2), jnp.float32), jnp.zeros(2, jnp.int32),
+        jax.random.key(0)))
+    assert ("ragged_dot" in traced) == (not loop_layers)  # what the batcher counted is what the step's program holds
+    del seen[:]
+    cfg = TransformerConfig(**SMALL)
+    cb = continuous.ContinuousBatcher(init_params(jax.random.key(5), cfg), cfg, slots=2, t_max=32, prefill_buckets=(8,))
+    cb.submit([1, 2, 3], max_new_tokens=3)
+    cb.pump()
+    assert [a for name, a in seen if name == "llm.step" and "moe_rows" in a] and not [a for _, a in seen if "moe_loop_layers" in a]
+    assert (cb.stats["moe_step_held_layers"], cb.stats["moe_step_loop_layers"]) == (0, 0)
+    assert share({"replica": {"stats": dict(cb.stats)}}, **args) is None  # nothing to divide by: the metric stays out of the line
